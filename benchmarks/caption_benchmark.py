@@ -142,9 +142,6 @@ def main() -> int:
         "value": round(end_to_end_tok_s, 2),
         "unit": "tok/s",
         "decode_tokens_per_sec": round(decode_tok_s, 2),
-        "decode_mfu": round(mfu(decode_flops * engine.decode_tokens, engine.decode_time_s), 5)
-        if engine.decode_time_s > 0
-        else 0.0,
         "requests": len(results),
         "output_tokens": out_tokens,
         "elapsed_s": round(elapsed, 2),
@@ -201,9 +198,16 @@ def main() -> int:
                 3,
             ),
         },
-        "peak_flops": chip_peak_flops(),
         "backend": jax.devices()[0].platform,
     }
+    if record["backend"] == "tpu":
+        # device metrics: only a chip run reports them, against its own peak
+        record["peak_flops"] = chip_peak_flops()
+        record["decode_mfu"] = (
+            round(mfu(decode_flops * engine.decode_tokens, engine.decode_time_s), 5)
+            if engine.decode_time_s > 0
+            else 0.0
+        )
     if not args.no_cross_job:
         record["cross_job"] = _cross_job_interleave(engine, make_request, args)
     if not args.no_pipeline:

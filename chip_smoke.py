@@ -1,0 +1,604 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             # one TPU chip: kernels, split, caption-2b
+    python chip_smoke.py --chips 4   # four chips: the sharded paths ONLY
+
+One process does everything, so the chip has one owner (the streaming
+runner's spawned workers are CPU-pinned by the engine itself). Every phase
+raises on failure; nothing is caught and continued. Without a TPU the
+script fails before any phase, prints no result and exits non-zero. The
+last line of standard output is the result:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Phases, default run (one chip), all at full model WIDTH with seeded random
+weights:
+
+- ``kernels``    the five Pallas kernels compiled (not interpreted) and run
+                 at Qwen2-VL-2B shapes against the XLA reference;
+- ``split``      ``cosmos-curate-tpu local split`` in-process on 8 seeded
+                 720p videos — fixed-stride split, ViT-B/16 video embedder,
+                 the default ``base`` captioner — then a 2-video
+                 shot-detection pass under the streaming runner;
+- ``caption-2b`` the caption engine at full Qwen2-VL-2B width AND depth.
+
+``--chips 4`` runs only what exists across chips and what it is compared
+with: the head-parallel caption engine at Qwen2.5-VL-7B widths against the
+same seeded model on one device, and mesh k-means against single-device.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent
+sys.path.insert(0, str(REPO))
+
+# bf16 inputs, fp32 online softmax in the kernel vs fp32 dense softmax with
+# bf16 probabilities in the reference: outputs are O(1), so a few bf16 ulps
+# (2^-8 relative) of disagreement — the tolerance tests/ops uses for bf16.
+BF16_ATOL = BF16_RTOL = 3e-2
+# First-step LOGITS after a whole forward pass (28 layers of bf16
+# activations): kernel and reference paths may drift by this fraction of
+# the largest logit magnitude.
+LOGITS_REL_TOL = 5e-2
+
+PHASES = ("kernels", "split", "caption-2b")
+
+
+_T0 = time.monotonic()
+
+
+def log(msg: str) -> None:
+    print(f"[{time.monotonic() - _T0:6.1f}s] {msg}", flush=True)
+
+
+def require_tpu():
+    """First act after importing JAX: no TPU, no run."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(
+            f"chip_smoke: JAX found no TPU (platform={dev.platform!r}); this "
+            "script proves the system on the chip and does not run without one."
+        )
+    return dev
+
+
+def describe_installation() -> None:
+    from importlib import metadata
+
+    import jax
+
+    from cosmos_curate_tpu import native
+    from cosmos_curate_tpu.utils.jax_cache import enable_persistent_cache
+
+    versions = {p: metadata.version(p) for p in ("jax", "jaxlib", "libtpu", "flax")}
+    dev = jax.devices()[0]
+    log(f"chip_smoke: versions {versions}")
+    log(f"chip_smoke: device_kind {dev.device_kind!r}, {len(jax.devices())} device(s)")
+    log(f"chip_smoke: compile cache at {enable_persistent_cache()}")
+    loaded = {
+        "curate_native": native.load_native() is not None,
+        "h264_encoder": native.load_h264() is not None,
+        "mv_extract": native.load_mv() is not None,
+    }
+    log(f"chip_smoke: native helpers loaded {loaded}")
+
+
+def _assert_close(name: str, got, want, *, atol=BF16_ATOL, rtol=BF16_RTOL) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if got.shape != want.shape:
+        raise AssertionError(f"{name}: shape {got.shape} != reference {want.shape}")
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{name}: non-finite output")
+    err = float(np.abs(got - want).max())
+    np.testing.assert_allclose(got, want, atol=atol, rtol=rtol, err_msg=name)
+    return err
+
+
+def _assert_program_holds(name: str, lowered, *ops: str) -> None:
+    """The compiled program's text names each of ``ops``: the Mosaic kernel
+    (``tpu_custom_call``), a collective."""
+    text = lowered.compile().as_text()
+    missing = [op for op in ops if op not in text]
+    if missing:
+        raise AssertionError(f"{name} program lacks {missing}")
+
+
+# -- phase: kernels ----------------------------------------------------------
+
+
+def phase_kernels(*, hkv: int = 2, group: int = 6, head_dim: int = 128, seed: int = 0) -> None:
+    """Each kernel, compiled for this chip, against the XLA reference (the
+    einsum lines of ``DecoderLayer`` / ``layers.Attention``) on the same
+    chip: Qwen2-VL-2B shapes, contexts 1k and 4k, 16-token pages, block
+    tables fragmented so logical order never matches pool order."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cosmos_curate_tpu.models.vlm.paged_kv import gather_block_views
+    from cosmos_curate_tpu.ops.decode_attention import decode_attention
+    from cosmos_curate_tpu.ops.flash_attention import flash_attention
+    from cosmos_curate_tpu.ops.paged_attention import _paged_reference, paged_attention
+    from cosmos_curate_tpu.ops.prefill_attention import prefill_attention
+
+    bs, layers, layer, chunk = 16, 2, 1, 256
+    rng = np.random.default_rng(seed)
+    for context, rows in ((1024, 8), (4096, 4)):
+        nbl = context // bs
+        n_blocks = rows * nbl + 8  # block 0 is the engine's garbage block
+        shape = (layers, n_blocks, hkv, bs, head_dim)
+        pool_k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        pool_v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        ids = rng.permutation(np.arange(1, n_blocks))[: rows * nbl]
+        tables = jnp.asarray(ids.reshape(rows, nbl), jnp.int32)
+        cache_k, cache_v = (c[layer] for c in gather_block_views(pool_k, pool_v, tables))
+
+        # decode: one token per row at ragged valid lengths
+        kv_len = jnp.asarray(rng.integers(context // 2, context + 1, rows), jnp.int32)
+        q1 = jnp.asarray(rng.standard_normal((rows, 1, hkv, group, head_dim)), jnp.bfloat16)
+        args = (q1, pool_k, pool_v, tables, kv_len - 1, kv_len)
+        want = _paged_reference(*args, layer_index=layer, sm_scale=head_dim**-0.5)
+        paged = jax.jit(
+            functools.partial(
+                paged_attention, layer_index=layer, use_kernel=True, interpret=False
+            )
+        )
+        _assert_program_holds("paged decode", paged.lower(*args), "tpu_custom_call")
+        err = _assert_close(f"paged_decode@{context}", paged(*args), want)
+        log(f"kernels: paged_decode      ctx {context} max_err {err:.4f}")
+        got = decode_attention(q1[:, 0], cache_k, cache_v, kv_len, interpret=False)
+        err = _assert_close(f"decode_attention@{context}", got, want[:, 0])
+        log(f"kernels: decode_attention  ctx {context} max_err {err:.4f}")
+
+        # chunked prefill: a chunk written mid-context, causal inside it
+        write = jnp.asarray(rng.integers(0, context - chunk + 1, rows), jnp.int32)
+        qt = jnp.asarray(
+            rng.standard_normal((rows, chunk, hkv, group, head_dim)), jnp.bfloat16
+        )
+        args = (qt, pool_k, pool_v, tables, write, write + chunk)
+        want = _paged_reference(*args, layer_index=layer, sm_scale=head_dim**-0.5)
+        err = _assert_close(f"paged_prefill@{context}", paged(*args), want)
+        log(f"kernels: paged_prefill     ctx {context} max_err {err:.4f}")
+        got = prefill_attention(qt, cache_k, cache_v, write, write + chunk, interpret=False)
+        err = _assert_close(f"prefill_attention@{context}", got, want)
+        log(f"kernels: prefill_attention ctx {context} max_err {err:.4f}")
+
+    # encoder self-attention (layers.Attention above FLASH_MIN_SEQ); 2049 is
+    # InternVideo2's 8x256+1 tokens — the ragged tail pads inside the op
+    heads = hkv * group
+    for s, causal in ((2048, True), (2049, False)):
+        q, k, v = (
+            jnp.asarray(rng.standard_normal((1, heads, s, head_dim)), jnp.bfloat16)
+            for _ in range(3)
+        )
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q * head_dim**-0.5, k).astype(jnp.float32)
+        if causal:
+            logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits, -jnp.inf)
+        probs = jax.nn.softmax(logits, axis=-1)
+        want = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(jnp.bfloat16), v)
+        got = flash_attention(q, k, v, causal=causal, interpret=False)
+        err = _assert_close(f"flash_attention@{s}", got, want)
+        log(f"kernels: flash_attention   seq {s} causal={causal} max_err {err:.4f}")
+
+
+# -- phase: split ------------------------------------------------------------
+
+
+def _run_split_cli(vids: Path, out: Path, *flags: str) -> tuple[dict, list[dict]]:
+    """``cosmos-curate-tpu local split`` in-process. The default runner
+    dead-letters a batch whose stage died and still exits 0, so the counts
+    are what is checked, from the live status; returns (summary.json,
+    clip metadata)."""
+    from cosmos_curate_tpu.cli.main import main as cli_main
+
+    rc = cli_main(
+        ["local", "split", "--input-path", str(vids), "--output-path", str(out), *flags]
+    )
+    if rc != 0:
+        raise AssertionError(f"local split returned {rc}")
+    summary = json.loads((out / "summary.json").read_text())
+    status = json.loads((out / "report" / "live" / "status.json").read_text())
+    metas = [json.loads(p.read_text()) for p in sorted((out / "metas" / "v0").glob("*.json"))]
+    dead = {n: s["dead_lettered"] + s["errored"] for n, s in status["stages"].items()}
+    if any(dead.values()) or summary["num_errors"]:
+        raise AssertionError(
+            f"split lost work: dead-lettered/errored per stage {dead}, "
+            f"num_errors {summary['num_errors']}"
+        )
+    return summary, metas
+
+
+def _stage_quiet_shot_detector(weights_dir: Path, seed: int) -> None:
+    """Stage seeded TransNetV2 weights whose head is biased to 'no
+    transition'. An untrained detector answers ~0.5 on every frame, which
+    the 0.4 threshold reads as a cut at every frame and the minimum clip
+    length then drops everything; with the bias it reports one scene per
+    video, and the stages behind it get real clips."""
+    import flax.serialization
+    import jax
+    import jax.numpy as jnp
+
+    from cosmos_curate_tpu.models.transnetv2 import INPUT_H, INPUT_W, WINDOW, TransNet, TransNetConfig
+
+    # jitted: one compile, not one per eager op of a 3-D conv net
+    params = jax.jit(TransNet(TransNetConfig()).init)(
+        jax.random.PRNGKey(seed), jnp.zeros((1, WINDOW, INPUT_H, INPUT_W, 3), jnp.uint8)
+    )
+    head = params["params"]["head"]
+    head["bias"] = jnp.full_like(head["bias"], -6.0)
+    path = weights_dir / "transnetv2-tpu" / "params.msgpack"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(flax.serialization.to_bytes(params))
+
+
+def phase_split(tmp: Path, *, seed: int = 0) -> None:
+    import bench  # the corpus generator (seeded per video); imported, not copied
+    from cosmos_curate_tpu.models.registry import WEIGHTS_DIR_ENV
+    from cosmos_curate_tpu.models.vlm import SharedCaptionEngine
+    from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+
+    bench.NUM_VIDEOS = 8
+    vids = bench.make_corpus(tmp / "corpus")
+    log(f"split: corpus of {bench.NUM_VIDEOS} videos made")
+    clips_per_video = int(bench.NUM_SCENES * bench.SCENE_FRAMES / 24.0 / bench.STRIDE_S)
+    want = bench.NUM_VIDEOS * clips_per_video
+
+    t0 = time.monotonic()
+    summary, metas = _run_split_cli(
+        vids, tmp / "out_fixed",
+        "--splitting-algorithm", "fixed-stride", "--fixed-stride-len-s", "1.0",
+        "--min-clip-len-s", "0.5", "--embedding-model", "video",
+        "--captioning", "--caption-model", "base",
+    )
+    if (summary["num_clips"], summary["num_with_embeddings"], summary["num_with_captions"]) != (
+        want, want, want
+    ):
+        raise AssertionError(f"split: want {want} clips, all embedded and captioned: {summary}")
+    # Random weights now and then answer with tokens that decode to nothing
+    # (eos first, or ids past the tokenizer's vocabulary): an empty text is
+    # the model's, a missing one would be the system's. Most must read.
+    texts = [w["captions"]["default"] for m in metas for w in m["windows"]]
+    non_empty = sum(1 for t in texts if t)
+    if len(texts) != want or 2 * non_empty < want:
+        raise AssertionError(f"split: {non_empty} non-empty of {len(texts)} captions, want {want}")
+    base = vlm_flavor("base")
+    stats = SharedCaptionEngine.get(base.cfg, model_id=base.model_id).stats()
+    if stats["paged_kernel_steps"] <= 0:
+        raise AssertionError(f"split: the paged kernel path never ran: {stats}")
+    log(
+        f"split: {want} clips embedded and captioned ({non_empty} non-empty texts) in "
+        f"{time.monotonic() - t0:.1f}s (compiles included); paged_kernel_steps {stats['paged_kernel_steps']}, "
+        f"decode_tokens {stats['decode_tokens']}"
+    )
+    SharedCaptionEngine.reset()  # hand the base captioner's memory back
+    log("split: caption engine released")
+
+    # Second pass: the streaming engine — this chip-owning process runs the
+    # TPU stages, spawned CPU-pinned workers run the rest.
+    _stage_quiet_shot_detector(tmp / "weights", seed)
+    os.environ[WEIGHTS_DIR_ENV] = str(tmp / "weights")
+    two = tmp / "corpus2"
+    two.mkdir()
+    for p in sorted(vids.glob("*.mp4"))[:2]:
+        shutil.copy(p, two / p.name)
+    log("split: seeded shot-detector weights staged")
+    t0 = time.monotonic()
+    summary, metas = _run_split_cli(
+        two, tmp / "out_streaming",
+        "--splitting-algorithm", "transnetv2", "--motion-filter", "score-only",
+        "--embedding-model", "video", "--runner", "streaming",
+    )
+    per_video: dict[str, int] = {}
+    for m in metas:
+        per_video[m["source_video"]] = per_video.get(m["source_video"], 0) + 1
+    if len(per_video) != 2 or summary["num_with_embeddings"] != summary["num_clips"]:
+        raise AssertionError(
+            f"streaming split: clips per video {per_video}, "
+            f"{summary['num_with_embeddings']}/{summary['num_clips']} embedded"
+        )
+    log(
+        f"split: streaming runner pass, {summary['num_clips']} clips from 2 videos, "
+        f"all embedded, in {time.monotonic() - t0:.1f}s"
+    )
+
+
+# -- phase: caption-2b -------------------------------------------------------
+
+
+def _requests(cfg, totals, *, n_frames: int, max_new: int, seed: int):
+    """Seeded caption requests: ``n_frames`` frames plus random prompt ids
+    so that vision + text tokens make each of ``totals``."""
+    import numpy as np
+
+    from cosmos_curate_tpu.models.vlm import CaptionRequest, SamplingConfig
+
+    rng = np.random.default_rng(seed)
+    size = cfg.qwen_vision.image_size if cfg.qwen_vision else cfg.vision.image_size
+    n_vis = cfg.qwen_vision.tokens_out(n_frames) if cfg.qwen_vision else cfg.vision_tokens
+    return [
+        CaptionRequest(
+            request_id=f"r{i}",
+            # ids from the upper half: clear of the tokenizer's specials
+            prompt_ids=rng.integers(cfg.vocab // 2, cfg.vocab, total - n_vis).tolist(),
+            frames=rng.integers(0, 255, (n_frames, size, size, 3), np.uint8),
+            sampling=SamplingConfig(max_new_tokens=max_new),
+        )
+        for i, total in enumerate(totals)
+    ]
+
+
+def _capture_first_logits(engine) -> dict:
+    """request_id -> the prefill's last-position logits row, read where the
+    engine samples its first token from it."""
+    seen: dict = {}
+    start_slot = engine._start_slot
+
+    def spy(lane, slot_idx, req, t_valid, next_rope, logits_row):
+        seen[req.request_id] = logits_row.copy()
+        return start_slot(lane, slot_idx, req, t_valid, next_rope, logits_row)
+
+    engine._start_slot = spy
+    return seen
+
+
+def _drain(engine, requests) -> dict:
+    for r in requests:
+        engine.add_request(r)
+    done = {r.request_id: r for r in engine.run_until_complete()}
+    if len(done) != len(requests):  # the engine logs and drops a failed request
+        raise AssertionError(f"engine finished {sorted(done)} of {len(requests)} requests")
+    return done
+
+
+def _logits_agree(name: str, got: dict, want: dict) -> None:
+    import numpy as np
+
+    for rid, ref in want.items():
+        scale = float(np.abs(ref).max())
+        err = _assert_close(
+            f"{name} first-step logits {rid}", got[rid], ref,
+            atol=LOGITS_REL_TOL * scale, rtol=0,
+        )
+        log(f"{name}: {rid} first-step logits max_err {err:.4f} (max |logit| {scale:.3f})")
+
+
+def phase_caption_2b(*, seed: int = 0) -> None:
+    """Qwen2-VL-2B, nothing cut: 28 layers, 1536 wide, GQA 12/2, vocab
+    151936, the 32x1280 vision tower; the flavor's own KV lanes."""
+    import jax
+
+    from cosmos_curate_tpu.models.vlm import CaptionEngine
+    from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+
+    flavor = vlm_flavor("qwen2vl-2b")
+    cfg, lanes = flavor.cfg, flavor.kv_lanes
+    t0 = time.monotonic()
+    engine = CaptionEngine(cfg, kv_lanes=lanes)
+    engine.setup(seed)
+    jax.block_until_ready(engine.params)
+    log(f"caption-2b: engine set-up {time.monotonic() - t0:.1f}s (seeded init, no compiles yet)")
+
+    # 200-2000 prompt tokens: four ride the 1024 lane, four the 4096 lane
+    totals = (200, 250, 600, 900, 1200, 1500, 1800, 2000)
+    logits = _capture_first_logits(engine)
+    t0 = time.monotonic()
+    done = _drain(engine, _requests(cfg, totals, n_frames=4, max_new=64, seed=seed))
+    short = {rid: r.num_output_tokens for rid, r in done.items() if r.num_output_tokens != 64}
+    stats = engine.stats()
+    if short or stats["paged_kernel_steps"] <= 0:
+        raise AssertionError(f"caption-2b: short generations {short}; stats {stats}")
+    log(
+        f"caption-2b: 8 x 64 tokens in {time.monotonic() - t0:.1f}s (compiles included); "
+        f"paged_kernel_steps {stats['paged_kernel_steps']}, "
+        f"prefill_tokens {stats['prefill_tokens']}"
+    )
+
+    # the same model through the XLA path: gathered views + einsum attention
+    os.environ.update(CURATE_FLASH_DECODE="0", CURATE_FLASH_PREFILL="0")
+    reference = CaptionEngine(cfg, kv_lanes=lanes, params=engine.params, paged_attention="gather")
+    reference.setup(seed)
+    ref_logits = _capture_first_logits(reference)
+    picks = [r for r in _requests(cfg, totals, n_frames=4, max_new=1, seed=seed)
+             if r.request_id in ("r1", "r7")]  # one per lane
+    _drain(reference, picks)
+    _logits_agree("caption-2b", logits, ref_logits)
+    peak = jax.devices()[0].memory_stats()["peak_bytes_in_use"]
+    log(f"caption-2b: peak device memory {peak / 2**30:.2f} GiB")
+
+
+# -- --chips 4: the sharded paths and what they are compared with ------------
+
+
+def _shard_bytes(tree) -> dict:
+    """device id -> bytes of ``tree`` resident there."""
+    import jax
+
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            out[shard.device.id] = out.get(shard.device.id, 0) + shard.data.nbytes
+    return out
+
+
+def phase_sharded(cfg, lanes, totals, *, n_frames: int = 8, max_new: int = 16, seed: int = 0) -> None:
+    """(a) the caption engine head-parallel over a ``model`` mesh of every
+    device against the same seeded model on one device; (b) mesh k-means
+    against single-device k-means."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from cosmos_curate_tpu.dedup.kmeans import kmeans_fit
+    from cosmos_curate_tpu.models.vlm import CaptionEngine
+    from cosmos_curate_tpu.parallel.axes import MODEL
+    from cosmos_curate_tpu.parallel.mesh import best_effort_mesh
+
+    devices = jax.devices()
+    n = len(devices)
+    t0 = time.monotonic()
+    single = CaptionEngine(cfg, kv_lanes=lanes)
+    single.setup(seed)
+    sharded = CaptionEngine(
+        cfg, kv_lanes=lanes, params=single.params, mesh=Mesh(np.array(devices), (MODEL,))
+    )
+    sharded.setup(seed)
+    jax.block_until_ready(sharded.params)
+    log(f"sharded: both engines set up in {time.monotonic() - t0:.1f}s")
+
+    # placement: every device holds its 1/n of what is partitioned — the
+    # parameters by their annotations, the KV pool by its head planes
+    one = _shard_bytes(single.params)
+    total = sum(one.values())
+    per_device = _shard_bytes(sharded.params)
+    replicated = (sum(per_device.values()) - total) / (n - 1) if n > 1 else 0
+    log(
+        f"sharded: parameters {total / 2**30:.2f} GiB on one device; per device of the mesh "
+        f"{ {d: round(b / 2**30, 2) for d, b in per_device.items()} } GiB "
+        f"({replicated / 2**20:.1f} MiB replicated on each)"
+    )
+    want = (total - replicated) / n + replicated
+    if len(per_device) != n or any(abs(b - want) > 0.02 * want for b in per_device.values()):
+        raise AssertionError(f"parameters not spread 1/{n} per device: {per_device}")
+    pool = _shard_bytes((sharded._pool_k, sharded._pool_v))
+    if len(pool) != n or set(pool.values()) != {sharded.kv_bytes() // n}:
+        raise AssertionError(f"KV pool not split 1/{n} by head planes: {pool}")
+    log(f"sharded: KV pool {sharded.kv_bytes() / 2**20:.0f} MiB, 1/{n} on each device")
+    for d in devices:
+        stats = d.memory_stats()  # None on backends that do not report
+        if stats:
+            log(f"sharded: device {d.id} bytes_in_use {stats['bytes_in_use'] / 2**30:.2f} GiB")
+
+    # the decode program: collectives where the row-parallel matmuls end,
+    # and (on the chip) the paged kernel inside the shard_map
+    lane = sharded.lanes[0]
+    zeros = jnp.zeros(lane.n_slots, jnp.int32)
+    want_ops = ["all-reduce"] + (["tpu_custom_call"] if devices[0].platform == "tpu" else [])
+    _assert_program_holds(
+        "sharded decode",
+        sharded._decode.lower(
+            sharded.params, sharded._pool_k, sharded._pool_v, jnp.asarray(lane.table),
+            zeros, zeros, zeros,
+        ),
+        *want_ops,
+    )
+    log(f"sharded: decode program holds {want_ops}")
+
+    logits, ref_logits = _capture_first_logits(sharded), _capture_first_logits(single)
+    kw = dict(n_frames=n_frames, max_new=max_new, seed=seed)
+    done = _drain(sharded, _requests(cfg, totals, **kw))
+    ref = _drain(single, _requests(cfg, totals, **kw))
+    short = {rid: r.num_output_tokens for rid, r in done.items() if r.num_output_tokens != max_new}
+    if short or sharded.stats()["paged_kernel_steps"] <= 0:
+        raise AssertionError(f"sharded: short generations {short}; {sharded.stats()}")
+    _logits_agree("sharded", logits, ref_logits)
+    log(f"sharded: {len(done)} x {max_new} tokens on the mesh, {len(ref)} on one device")
+    sharded.shutdown()
+    single.shutdown()
+    del sharded, single, logits, ref_logits, done, ref
+    gc.collect()
+
+    # (b) dedup's k-means: rows over the mesh's data axes vs one device
+    rng = np.random.default_rng(seed)
+    centers = rng.standard_normal((16, 768)).astype(np.float32)
+    data = np.repeat(centers, 512, axis=0) + 0.05 * rng.standard_normal((8192, 768)).astype(np.float32)
+    mesh = best_effort_mesh()
+    _, on_mesh = kmeans_fit(data, 16, seed=seed, mesh=mesh)
+    _, on_one = kmeans_fit(data, 16, seed=seed, mesh=None)
+    if mesh.size != n or not np.array_equal(on_mesh, on_one):
+        raise AssertionError(
+            f"k-means over mesh {dict(mesh.shape)} disagrees with one device on "
+            f"{int((on_mesh != on_one).sum())} of {len(on_one)} rows"
+        )
+    log(f"sharded: k-means over mesh {dict(mesh.shape)} = single-device assignments (8192 rows)")
+
+
+def run_sharded() -> None:
+    """Qwen2.5-VL-7B widths (3584 wide, 28/4 heads: one KV head per chip),
+    untouched. Depth cut 28 -> 4 so that device 0 can ALSO hold the same
+    seeded model whole, in fp32, for the comparison."""
+    from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+
+    flavor = vlm_flavor("qwen25vl-7b")
+    cfg = dataclasses.replace(flavor.cfg, n_layers=4)
+    phase_sharded(cfg, flavor.kv_lanes, totals=(300, 900, 1500, 2000))
+
+
+# -- entry -------------------------------------------------------------------
+
+
+def main(argv: list[str] | None = None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument(
+        "--chips", type=int, choices=(1, 4), default=1,
+        help="4: run only the sharded paths (needs four chips)",
+    )
+    parser.add_argument(
+        "--phases", default=",".join(PHASES),
+        help=f"one-chip phases to run, comma-separated (default: {','.join(PHASES)})",
+    )
+    args = parser.parse_args(argv)
+    phases = args.phases.split(",")
+    unknown = sorted(set(phases) - set(PHASES))
+    if unknown:
+        parser.error(f"unknown phases {unknown}")
+
+    import jax
+
+    dev = require_tpu()
+    count = len(jax.devices())
+    if args.chips == 4 and count != 4:
+        sys.exit(f"chip_smoke: --chips 4 needs four chips, JAX reports {count}")
+    describe_installation()
+
+    t_start = time.monotonic()
+    if args.chips == 4:
+        run_sharded()
+    else:
+        tmp = Path(tempfile.mkdtemp(prefix="chip_smoke_"))
+        try:
+            for name in phases:
+                t0 = time.monotonic()
+                if name == "kernels":
+                    phase_kernels()
+                elif name == "split":
+                    phase_split(tmp)
+                else:
+                    phase_caption_2b()
+                log(f"chip_smoke: phase {name} passed in {time.monotonic() - t0:.1f}s")
+                gc.collect()
+        finally:
+            shutil.rmtree(tmp, ignore_errors=True)
+    log(f"chip_smoke: all phases passed in {time.monotonic() - t_start:.1f}s")
+    print(
+        json.dumps(
+            {
+                "ok": True,
+                "device": {"platform": dev.platform, "kind": dev.device_kind, "count": count},
+            }
+        ),
+        flush=True,
+    )
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

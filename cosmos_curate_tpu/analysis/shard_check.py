@@ -31,6 +31,7 @@ pre-flight reuses :func:`mesh_tiling_errors` to validate stage-declared
 from __future__ import annotations
 
 import math
+import re
 from dataclasses import dataclass
 from typing import Any, Callable, Sequence
 
@@ -335,15 +336,18 @@ def _check_abstract_flow(
     args.extend(_shape_structs(contract.inputs))
     try:
         jax.eval_shape(forward, *args)
-    except KeyError as e:
-        findings.append(
-            Finding(
-                _SHARD_FILE, 0, "shard-unknown-axis",
-                f"{contract.describe()}: shard_map names axis {e} which is "
-                f"absent from the mesh {dict(mesh)}",
-            )
-        )
     except Exception as e:
+        # jax.shard_map's own complaint about a spec axis the mesh lacks
+        absent = re.search(r"_specs\S* refers to ('[^']+')", str(e))
+        if isinstance(e, ValueError) and absent:
+            findings.append(
+                Finding(
+                    _SHARD_FILE, 0, "shard-unknown-axis",
+                    f"{contract.describe()}: shard_map names axis "
+                    f"{absent.group(1)} which is absent from the mesh {dict(mesh)}",
+                )
+            )
+            return
         findings.append(
             Finding(
                 _SHARD_FILE, 0, "shard-shape-flow",
@@ -503,11 +507,11 @@ def default_contracts(mesh: dict[str, int]) -> list[ShardContract]:
     # models/vlm/paged_kv.py — the caption engine's block-table KV gather:
     # slot rows (tables) shard over the batch axes for data-parallel engine
     # replicas, the block pool is replicated; the real shard_map call site
-    # is traced abstractly (same [L, NB, bs, Hkv, Dh] pool layout the
+    # is traced abstractly (same [L, NB, Hkv, bs, Dh] pool layout the
     # engine compiles, tiny extents)
     from cosmos_curate_tpu.models.vlm.paged_kv import paged_gather
 
-    pool_shape = (2, 9, 4, 2, 8)  # [L, n_blocks, block_size, Hkv, Dh]
+    pool_shape = (2, 9, 2, 4, 8)  # [L, n_blocks, Hkv, block_size, Dh]
     contracts.append(
         ShardContract(
             name="vlm-paged-gather",
@@ -544,11 +548,11 @@ def default_contracts(mesh: dict[str, int]) -> list[ShardContract]:
                     (None, None, MODEL, None, None), name="q",
                 ),
                 AbstractInput(
-                    pool_shape, "bfloat16", (None, None, None, MODEL, None),
+                    pool_shape, "bfloat16", (None, None, MODEL, None, None),
                     name="pool_k",
                 ),
                 AbstractInput(
-                    pool_shape, "bfloat16", (None, None, None, MODEL, None),
+                    pool_shape, "bfloat16", (None, None, MODEL, None, None),
                     name="pool_v",
                 ),
                 AbstractInput((8, 2), "int32", (), name="tables"),
@@ -571,11 +575,11 @@ def default_contracts(mesh: dict[str, int]) -> list[ShardContract]:
             where="models/vlm/paged_kv.py",
             inputs=(
                 AbstractInput(
-                    pool_shape, "bfloat16", (None, None, None, MODEL, None),
+                    pool_shape, "bfloat16", (None, None, MODEL, None, None),
                     name="pool_k",
                 ),
                 AbstractInput(
-                    pool_shape, "bfloat16", (None, None, None, MODEL, None),
+                    pool_shape, "bfloat16", (None, None, MODEL, None, None),
                     name="pool_v",
                 ),
                 AbstractInput((8, 1, 2, 8), "bfloat16", (None, None, MODEL, None), name="k"),

@@ -90,21 +90,13 @@ def kmeans_fit(
     k = min(k, n)
     data = embeddings / np.maximum(np.linalg.norm(embeddings, axis=1, keepdims=True), 1e-8)
     valid = n
-    # Degrade cleanly instead of crashing the dedup run: a 1-device mesh
-    # (the CPU tier-1 environment) adds nothing but sharding overhead, and a
-    # mesh the batch cannot ride (device-put failure, dead backend) must
-    # fall back to the single-device path — same numerics either way.
-    if mesh is not None and getattr(mesh, "size", 1) <= 1:
-        mesh = None
-    if mesh is not None:
+    # a 1-device mesh adds nothing but sharding overhead — same numerics.
+    # A mesh the batch cannot ride is an error, not a reason to use less
+    # of the machine quietly.
+    if mesh is not None and mesh.size > 1:
         from cosmos_curate_tpu.parallel.sharding import shard_batch
 
-        try:
-            data, _pad = shard_batch(mesh, data.astype(np.float32))
-        except Exception as e:
-            logger.warning("mesh sharding unavailable (%s); single-device kmeans", e)
-            mesh = None
-            data = jnp.asarray(data, jnp.float32)
+        data, _pad = shard_batch(mesh, data.astype(np.float32))
     else:
         data = jnp.asarray(data, jnp.float32)
 

@@ -11,8 +11,6 @@ FLOP-bound on TPU and is excluded, matching standard MFU conventions.
 
 from __future__ import annotations
 
-import os
-
 
 def transformer_layer_flops(tokens: int, width: int, *, mlp_ratio: int = 4) -> float:
     """One pre-LN transformer block forward: QKVO projections + attention
@@ -57,31 +55,29 @@ def vlm_decode_flops_per_token(cfg) -> float:
     return cfg.n_layers * (proj + attn + mlp) + head
 
 
-# bf16 peak FLOPs/s per chip by TPU generation (public spec sheets).
-_TPU_PEAK = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
+# Peak bf16 FLOP/s of one chip, keyed by the exact ``device_kind`` string
+# JAX reports for it. One row per chip this repo has actually run on — the
+# string is what ``chip_smoke.py`` printed there — with the source of the
+# number. A device that is not in the table is an error, not a default.
+CHIP_PEAK_FLOPS = {
+    # TPU v5e. Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per
+    # chip (16 GB HBM at 819 GB/s).
+    "TPU v5 lite": 197e12,
 }
-DEFAULT_PEAK = _TPU_PEAK["v5e"]
 
 
 def chip_peak_flops() -> float:
-    """Best-effort peak for the attached chip; BENCH_PEAK_FLOPS overrides."""
-    env = os.environ.get("BENCH_PEAK_FLOPS")
-    if env:
-        return float(env)
-    try:
-        import jax
+    """Published bf16 peak of the attached chip; an unknown kind raises."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-        for name, peak in _TPU_PEAK.items():
-            if name in kind:
-                return peak
-    except Exception:
-        pass
-    return DEFAULT_PEAK
+    kind = jax.devices()[0].device_kind
+    try:
+        return CHIP_PEAK_FLOPS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {kind!r}: add it to "
+            "models/flops.py::CHIP_PEAK_FLOPS with its source"
+        ) from None
 
 
 def mfu(total_flops: float, seconds: float, *, peak: float | None = None) -> float:

@@ -5,8 +5,8 @@ Equivalent capability of the reference's vLLM engine driver
 in-flight batching with two-stage caption refinement; async variant
 vllm_async_stage.py). TPU-first re-design:
 
-- **paged KV cache**: KV memory is ONE block pool ``[L, n_blocks,
-  block_size, Hkv, Dh]`` (models/vlm/paged_kv.py) and every admitted slot
+- **paged KV cache**: KV memory is ONE block pool ``[L, n_blocks, Hkv,
+  block_size, Dh]`` (models/vlm/paged_kv.py) and every admitted slot
   holds a block *table* instead of a worst-case-length cache row — a
   request reserves ``ceil((prompt + max_new + 1) / block_size)`` blocks, so
   pool occupancy (not slot count) is the admission limit, vLLM
@@ -312,6 +312,11 @@ class CaptionEngine:
         # that axis (KV pool + heads sharded, block tables replicated)
         self.mesh = mesh
         self.model = VLM(cfg, mesh=mesh)
+        # per-leaf PartitionSpecs once setup() has read them off the model
+        # (mesh engines only): from then on whatever is assigned to
+        # ``params`` — a checkpoint loaded after setup, another engine's
+        # tree — is placed over the mesh, never left on one device
+        self._param_specs: Any = None
         self.params = params
         self.waiting: list[CaptionRequest] = []
         # (length, n_slots) per decode-batch lane; default = one
@@ -392,7 +397,7 @@ class CaptionEngine:
         self._vision_encodes = 0
         self._vision_reuses = 0
         # shared-prefix KV cache: LRU over prefix token tuples. Entries are
-        # small ([L, Tp, Hkv, Dh] per prefix) next to the lane caches.
+        # small ([L, Hkv, Tp, Dh] per prefix) next to the lane caches.
         self.enable_prefix_cache = enable_prefix_cache
         self.prefix_cache_size = prefix_cache_size
         self.min_prefix_len = min_prefix_len
@@ -473,6 +478,18 @@ class CaptionEngine:
         # outstanding work is an in-flight background prep
         self._work_cv = threading.Condition(self._lock)
 
+    @property
+    def params(self) -> Any:
+        return self._params
+
+    @params.setter
+    def params(self, value: Any) -> None:
+        if value is not None and self._param_specs is not None:
+            from cosmos_curate_tpu.parallel.sharding import place_partitioned
+
+            value = place_partitioned(self.mesh, value, self._param_specs)
+        self._params = value
+
     # read-only aggregate views over the lanes (public slot id = lane.base
     # + lane-local index, unique across lanes)
     @property
@@ -492,25 +509,42 @@ class CaptionEngine:
     # -- setup ----------------------------------------------------------
     def setup(self, seed: int = 0) -> None:
         cfg = self.cfg
-        if self.params is None:
-            size = (
-                cfg.qwen_vision.image_size
-                if cfg.vision_variant in ("qwen2", "qwen3")
-                else cfg.vision.image_size
-            )
-            frames = jnp.zeros((1, 1, size, size, 3), jnp.uint8)
-            ids = jnp.zeros((1, 4), jnp.int32)
-            ck, cv = init_cache(cfg, 1)
-            self.params = self.model.init(
+        size = (
+            cfg.qwen_vision.image_size
+            if cfg.vision_variant in ("qwen2", "qwen3")
+            else cfg.vision.image_size
+        )
+
+        def init():
+            return self.model.init(
                 jax.random.PRNGKey(seed),
-                frames,
-                ids,
-                ck,
-                cv,
+                jnp.zeros((1, 1, size, size, 3), jnp.uint8),
+                jnp.zeros((1, 4), jnp.int32),
+                *init_cache(cfg, 1),
                 method=self.model.init_everything,
             )
+
+        if self.params is None:
+            self.params = init()
+        pool_sharding = None
+        if self.mesh is not None:
+            # Place everything ONCE, here: parameters by the model's
+            # nn.with_partitioning annotations (read off an abstract init,
+            # so a loaded checkpoint's plain tree places the same way), the
+            # block pools by their KV-head planes. Left on the default
+            # device, the head-parallel shard_map would re-distribute the
+            # whole pool on every step.
+            import flax.linen as nn
+            from jax.sharding import PartitionSpec as P
+
+            from cosmos_curate_tpu.parallel.axes import MODEL
+            from cosmos_curate_tpu.parallel.sharding import spec_sharding
+
+            self._param_specs = nn.get_partition_spec(jax.eval_shape(init))
+            self.params = self._params  # the setter places
+            pool_sharding = spec_sharding(self.mesh, P(None, None, MODEL, None, None))
         self._pool_k, self._pool_v = init_block_pool(
-            cfg, self.kv_pool_blocks, self.block_size
+            cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
         )
 
         model = self.model
@@ -555,12 +589,10 @@ class CaptionEngine:
                 write_index,
                 write_index + t_valid,
                 deepstack=ds,
+                logits_at=t_valid - 1,
             )
             pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, nk, nv)
-            last = jnp.take_along_axis(
-                logits, (t_valid - 1)[:, None, None].astype(jnp.int32), axis=1
-            )[:, 0]
-            return last, pool_k, pool_v
+            return logits[:, 0], pool_k, pool_v
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def decode_step(params, pool_k, pool_v, tables, tokens, positions, rope_positions):
@@ -613,12 +645,10 @@ class CaptionEngine:
                 write_index + t_valid,
                 tables,
                 deepstack=ds,
+                logits_at=t_valid - 1,
                 method=model.paged_forward,
             )
-            last = jnp.take_along_axis(
-                logits, (t_valid - 1)[:, None, None].astype(jnp.int32), axis=1
-            )[:, 0]
-            return last, pool_k, pool_v
+            return logits[:, 0], pool_k, pool_v
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def decode_step_paged(params, pool_k, pool_v, tables, tokens, positions, rope_positions):
@@ -648,7 +678,7 @@ class CaptionEngine:
         @jax.jit
         def prefix_prefill(params, embeds, rope_pos, t_valid):
             """Prefill ONE text prefix into a scratch cache and return its
-            K/V block [L, Sp, Hkv, Dh] (sliced to the true length by the
+            K/V block [L, Hkv, Sp, Dh] (sliced to the true length by the
             caller). embeds: [1, Sp, D] (pow2-padded); t_valid: scalar.
             Compiled once per Sp bucket — prefixes are per (flavor,
             prompt_variant), so this runs once per variant, not per
@@ -667,16 +697,19 @@ class CaptionEngine:
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def write_prefix_blocks(pool_k, pool_v, pk, pv, ids):
-            """Store one freshly built prefix K/V ([L, Tp, Hkv, Dh]) into
+            """Store one freshly built prefix K/V ([L, Hkv, Tp, Dh]) into
             its allocated pool blocks ``ids`` ([nb]) — the ONE device write
             per prefix build; admitted requests then reference these blocks
             with zero further copies. Compiled once per Tp (prefixes are
             per (flavor, prompt_variant), so this runs once per variant)."""
-            pad = ids.shape[0] * bs - pk.shape[1]
-            pk = jnp.pad(pk.astype(pool_k.dtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
-            pv = jnp.pad(pv.astype(pool_v.dtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
-            pool_k = pool_k.at[:, ids].set(pk.reshape(pk.shape[0], -1, bs, *pk.shape[2:]))
-            pool_v = pool_v.at[:, ids].set(pv.reshape(pv.shape[0], -1, bs, *pv.shape[2:]))
+            l, hk, tp, dh = pk.shape
+            pad = ((0, 0), (0, 0), (0, ids.shape[0] * bs - tp), (0, 0))
+
+            def blocks(x, dtype):  # -> [L, nb, Hkv, bs, Dh]
+                return jnp.pad(x.astype(dtype), pad).reshape(l, hk, -1, bs, dh).swapaxes(1, 2)
+
+            pool_k = pool_k.at[:, ids].set(blocks(pk, pool_k.dtype))
+            pool_v = pool_v.at[:, ids].set(blocks(pv, pool_v.dtype))
             return pool_k, pool_v
 
         @partial(jax.jit, donate_argnums=(0, 1))
@@ -1752,7 +1785,7 @@ class CaptionEngine:
             jnp.asarray(pos),
             jnp.asarray(tp, jnp.int32),
         )
-        k, v = k[:, :tp], v[:, :tp]
+        k, v = k[:, :, :tp], v[:, :, :tp]
         jax.block_until_ready(v)
         with self._stats_lock:
             self._prefill_time += time.monotonic() - t0

@@ -11,7 +11,7 @@ models behind the plugin ABC). This is our own Flax architecture, TPU-first:
   attention, TP-sharded via the Megatron-style annotations in
   models/layers.py (replaces vLLM's NCCL TP with pjit sharding);
 - inference is cache-centric: ``apply`` consumes and returns a static-shape
-  slot-based KV cache ``[L, B, S, Hkv, Dh]``, so prefill (T=bucket) and
+  slot-based KV cache ``[L, B, Hkv, S, Dh]``, so prefill (T=bucket) and
   decode (T=1) are the same compiled family of programs. No dynamic shapes.
 """
 
@@ -596,7 +596,8 @@ class DecoderLayer(nn.Module):
     ):
         """One decoder layer with slot KV cache.
 
-        x: [B, T, D]; cache_k/v: [B, S, Hkv, Dh]; positions: [B, T] rope
+        x: [B, T, D]; cache_k/v: [B, Hkv, S, Dh] (heads-major, so a
+        kernel's K/V tile is ``[S-block, Dh]``); positions: [B, T] rope
         positions (or [B, T, 3] m-rope components — under m-rope, rope
         position ≠ cache index, so causality derives from write_index, not
         positions); write_index: [B] offset where this chunk's K/V land;
@@ -604,14 +605,14 @@ class DecoderLayer(nn.Module):
         active rows). Returns (y, new_cache_k, new_cache_v).
 
         Paged mode (``block_tables`` set): cache_k/v are the FULL block
-        pools ``[L, NB, bs, Hkv, Dh]`` and block_tables is ``[B, nbl]``.
+        pools ``[L, NB, Hkv, bs, Dh]`` and block_tables is ``[B, nbl]``.
         K/V scatter through the table and attention reads the pool in place
         (ops/paged_attention.py) — no contiguous working-set view exists.
         Returns the updated pools in place of cache rows.
         """
         cfg = self.cfg
         b, t, _ = x.shape
-        s = cache_k.shape[1] if block_tables is None else None
+        s = cache_k.shape[2] if block_tables is None else None
         h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
         y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x)
@@ -648,12 +649,14 @@ class DecoderLayer(nn.Module):
                     layer_index=layer_index,
                 )
             else:
-                bs_blk = cache_k.shape[2]
+                bs_blk = cache_k.shape[3]
                 pos = write_index[:, None] + jnp.arange(t)[None, :]  # [B, T]
                 blk = jnp.take_along_axis(block_tables, pos // bs_blk, axis=1)
                 off = pos % bs_blk
-                new_k = cache_k.at[layer_index, blk, off].set(k.astype(cache_k.dtype))
-                new_v = cache_v.at[layer_index, blk, off].set(v.astype(cache_v.dtype))
+                # [B, T] index pairs around the head slice: each token
+                # writes its [Hkv, Dh] slab, one row per head plane
+                new_k = cache_k.at[layer_index, blk, :, off].set(k.astype(cache_k.dtype))
+                new_v = cache_v.at[layer_index, blk, :, off].set(v.astype(cache_v.dtype))
             qk = q.reshape(b, t, hk, group, dh)
             if head_parallel:
                 attn = paged_head_attention(
@@ -669,13 +672,17 @@ class DecoderLayer(nn.Module):
         else:
             # scatter this chunk into the cache at each row's write_index
             def write_row(cache, chunk, idx):
-                return jax.lax.dynamic_update_slice(cache, chunk, (idx, 0, 0))
+                return jax.lax.dynamic_update_slice(cache, chunk, (0, idx, 0))
 
-            new_k = jax.vmap(write_row)(cache_k, k.astype(cache_k.dtype), write_index)
-            new_v = jax.vmap(write_row)(cache_v, v.astype(cache_v.dtype), write_index)
+            new_k = jax.vmap(write_row)(
+                cache_k, k.astype(cache_k.dtype).swapaxes(1, 2), write_index
+            )
+            new_v = jax.vmap(write_row)(
+                cache_v, v.astype(cache_v.dtype).swapaxes(1, 2), write_index
+            )
 
             # GQA attention of q against the whole (masked) cache. Heads stay
-            # grouped ([B, T, Hkv, G, Dh] vs the KV's [B, S, Hkv, Dh]) — no
+            # grouped ([B, T, Hkv, G, Dh] vs the KV's [B, Hkv, S, Dh]) — no
             # jnp.repeat materialization, so HBM traffic is the true KV size
             # (the decode step is KV-bandwidth-bound; for 12/2 GQA a repeat
             # would read 6x the bytes).
@@ -695,7 +702,7 @@ class DecoderLayer(nn.Module):
             else:
                 qg = (q * (dh**-0.5)).reshape(b, t, hk, group, dh)
                 logits = jnp.einsum(
-                    "btkgd,bskd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
+                    "btkgd,bksd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
                 )
                 k_pos = jnp.arange(s)[None, None, None, None, :]  # cache slot index
                 # causality is over cache order (write_index + chunk offset) —
@@ -705,7 +712,7 @@ class DecoderLayer(nn.Module):
                 written = k_pos < kv_len[:, None, None, None, None]
                 logits = jnp.where(causal & written, logits, -1e30)
                 probs = jax.nn.softmax(logits, axis=-1)
-                attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(self.dtype), new_v)
+                attn = jnp.einsum("bkgts,bksd->btkgd", probs.astype(self.dtype), new_v)
         attn = attn.reshape(b, t, h * dh)
         x = x + dense(cfg.dim, "in", name="o", use_bias=False, dtype=self.dtype)(attn)
 
@@ -814,16 +821,31 @@ class VLM(nn.Module):
             deepstack=deepstack,
         )
 
+    def _logits(self, x, logits_at):
+        """Final norm + LM head. ``logits_at`` ([B] positions) keeps one
+        position per row BEFORE the head: a prefill needs only its last
+        valid position, and at a real vocabulary the full ``[B, T, vocab]``
+        fp32 logits of a long bucket outweigh the model (8 x 1024 x 151936
+        x 4 B = 5 GB)."""
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None].astype(jnp.int32), axis=1)
+        x = self.ln_f(x)
+        if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
+            return self.lm_head(x.astype(jnp.float32))
+        return self.embed.attend(x.astype(jnp.float32))
+
     def __call__(
-        self, embeds, cache_k, cache_v, positions, write_index, kv_len, deepstack=None
+        self, embeds, cache_k, cache_v, positions, write_index, kv_len, deepstack=None,
+        logits_at=None,
     ):
         """Forward over input *embeddings* (text and vision already spliced).
 
-        embeds: [B, T, D]; cache_k/v: [L, B, S, Hkv, Dh]; deepstack:
+        embeds: [B, T, D]; cache_k/v: [L, B, Hkv, S, Dh]; deepstack:
         optional [L_ds, B, T, D] visual features added to the hidden states
         AFTER each of the first L_ds layers (zeros at text positions — HF
         Qwen3VL deepstack semantics; prefill-only, decode passes None).
-        Returns (logits [B, T, vocab], new_cache_k, new_cache_v).
+        Returns (logits [B, T, vocab] — or [B, 1, vocab] at ``logits_at``
+        — new_cache_k, new_cache_v).
         """
         x = embeds.astype(self.dtype)
         n_ds = 0 if deepstack is None else deepstack.shape[0]
@@ -834,25 +856,19 @@ class VLM(nn.Module):
                 x = x + deepstack[i].astype(x.dtype)
             new_ks.append(nk)
             new_vs.append(nv)
-        x = self.ln_f(x)
-        if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
-            logits = self.lm_head(x.astype(jnp.float32))
-        else:
-            logits = self.embed.attend(x.astype(jnp.float32))
-        return logits, jnp.stack(new_ks), jnp.stack(new_vs)
+        return self._logits(x, logits_at), jnp.stack(new_ks), jnp.stack(new_vs)
 
     def paged_forward(
         self, embeds, pool_k, pool_v, positions, write_index, kv_len, block_tables,
-        deepstack=None,
+        deepstack=None, logits_at=None,
     ):
         """Forward straight against the paged KV pool — no working-set view.
 
         embeds: [B, T, D]; pool_k/pool_v: the FULL block pools
-        ``[L, NB, bs, Hkv, Dh]`` threaded through every layer (each layer
+        ``[L, NB, Hkv, bs, Dh]`` threaded through every layer (each layer
         scatters its chunk through ``block_tables`` [B, nbl] and attends in
         place via ops/paged_attention.py); write_index/kv_len as in
-        ``__call__``. Returns (logits [B, T, vocab], pool_k, pool_v) — the
-        updated pools, never a ``jnp.stack`` of per-layer copies, so XLA
+        ``__call__``. Returns (logits, pool_k, pool_v) — the updated pools, never a ``jnp.stack`` of per-layer copies, so XLA
         donation keeps the scatters in-place.
         """
         x = embeds.astype(self.dtype)
@@ -864,14 +880,9 @@ class VLM(nn.Module):
             )
             if i < n_ds:
                 x = x + deepstack[i].astype(x.dtype)
-        x = self.ln_f(x)
-        if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
-            logits = self.lm_head(x.astype(jnp.float32))
-        else:
-            logits = self.embed.attend(x.astype(jnp.float32))
-        return logits, pool_k, pool_v
+        return self._logits(x, logits_at), pool_k, pool_v
 
 
 def init_cache(cfg: VLMConfig, batch: int, dtype=jnp.bfloat16, length: int | None = None):
-    shape = (cfg.n_layers, batch, length or cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, length or cfg.max_seq, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

@@ -2,7 +2,7 @@
 
 vLLM's PagedAttention block-table design (Kwon et al. 2023 — PAPERS.md)
 re-shaped for XLA's static-shape compilation: KV memory is ONE block pool
-``[L, n_blocks, block_size, Hkv, Dh]`` and every slot owns a block *table*
+``[L, n_blocks, Hkv, block_size, Dh]`` and every slot owns a block *table*
 instead of a worst-case-length cache row, so a request's KV footprint is
 ``ceil(len / block_size)`` blocks. Rather than a dynamic per-read gather
 inside the attention kernel (hostile to XLA), the engine's prefill/decode
@@ -112,24 +112,27 @@ class BlockAllocator:
         return self._refs[block_id]
 
 
-def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16):
-    """The K and V block pools: ``[L, n_blocks, block_size, Hkv, Dh]``."""
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sharding=None):
+    """The K and V block pools: ``[L, n_blocks, Hkv, block_size, Dh]`` —
+    heads-major, so one head's page is a contiguous ``[block_size, Dh]``
+    tile (the shape the TPU's compiler accepts as a kernel block).
+    ``sharding`` creates them already placed (head planes over a mesh)."""
+    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
+    return jnp.zeros(shape, dtype, device=sharding), jnp.zeros(shape, dtype, device=sharding)
 
 
 def gather_block_views(pool_k, pool_v, tables):
     """Per-slot contiguous KV views through the block tables.
 
-    pool_k/v: ``[L, NB, bs, Hkv, Dh]``; tables: ``[N, nbl]`` int32 block
-    ids. Returns ``[L, N, nbl * bs, Hkv, Dh]`` views — the same shape the
+    pool_k/v: ``[L, NB, Hkv, bs, Dh]``; tables: ``[N, nbl]`` int32 block
+    ids. Returns ``[L, N, Hkv, nbl * bs, Dh]`` views — the same shape the
     slot-row engine's cache rows had, so the model and its compiled
     programs are unchanged."""
-    l = pool_k.shape[0]
-    bs = pool_k.shape[2]
+    l, _, hk, bs, dh = pool_k.shape
     n, nbl = tables.shape
-    vk = pool_k[:, tables].reshape(l, n, nbl * bs, *pool_k.shape[3:])
-    vv = pool_v[:, tables].reshape(l, n, nbl * bs, *pool_v.shape[3:])
+    # [L, N, nbl, Hkv, bs, Dh] -> blocks of one head side by side
+    vk = pool_k[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
+    vv = pool_v[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
     return vk, vv
 
 
@@ -140,11 +143,10 @@ def scatter_block_views(pool_k, pool_v, tables, view_k, view_v):
     identical values by the engine's copy-on-write invariant — see the
     module docstring — so the scatter's undefined duplicate-write order
     cannot change pool contents."""
-    l = pool_k.shape[0]
-    bs = pool_k.shape[2]
+    l, _, hk, bs, dh = pool_k.shape
     n, nbl = tables.shape
-    bk = view_k.reshape(l, n, nbl, bs, *view_k.shape[3:])
-    bv = view_v.reshape(l, n, nbl, bs, *view_v.shape[3:])
+    bk = view_k.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3)
+    bv = view_v.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3)
     return pool_k.at[:, tables].set(bk), pool_v.at[:, tables].set(bv)
 
 
@@ -157,27 +159,26 @@ def paged_head_update(mesh, pool_k, pool_v, k, v, tables, write_index, *, layer_
     ``AbstractMesh`` so shardcheck's ``vlm-paged-head-scatter`` contract
     traces this call site device-free (analysis/shard_check.py).
 
-    pool_k/v: ``[L, NB, bs, Hkv, Dh]``; k/v: ``[B, T, Hkv, Dh]`` (the
+    pool_k/v: ``[L, NB, Hkv, bs, Dh]``; k/v: ``[B, T, Hkv, Dh]`` (the
     chunk, rope already applied); tables: ``[B, nbl]``; write_index:
     ``[B]``. Returns the updated pools."""
-    import jax.numpy as _jnp
     from jax.sharding import PartitionSpec as P
 
     from cosmos_curate_tpu.parallel.axes import MODEL
     from cosmos_curate_tpu.parallel.sharding import shard_map
 
     axis = MODEL if MODEL in mesh.axis_names else None
-    pspec = P(None, None, None, axis, None)
+    pspec = P(None, None, axis, None, None)
     kspec = P(None, None, axis, None)
 
     def _update(pk, pv, k_, v_, tbl, wi):
-        bs = pk.shape[2]
+        bs = pk.shape[3]
         t = k_.shape[1]
-        pos = wi[:, None] + _jnp.arange(t)[None, :]  # [B, T]
-        blk = _jnp.take_along_axis(tbl, pos // bs, axis=1)
+        pos = wi[:, None] + jnp.arange(t)[None, :]  # [B, T]
+        blk = jnp.take_along_axis(tbl, pos // bs, axis=1)
         off = pos % bs
-        npk = pk.at[layer_index, blk, off].set(k_.astype(pk.dtype))
-        npv = pv.at[layer_index, blk, off].set(v_.astype(pv.dtype))
+        npk = pk.at[layer_index, blk, :, off].set(k_.astype(pk.dtype))
+        npv = pv.at[layer_index, blk, :, off].set(v_.astype(pv.dtype))
         return npk, npv
 
     return shard_map(

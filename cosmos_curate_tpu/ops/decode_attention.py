@@ -7,14 +7,15 @@ SPEED_OF_LIGHT.md). This kernel streams K/V blocks through VMEM with an
 online softmax and two decode-specific wins over the generic flash kernel:
 
 - **no transpose/repeat**: operates directly on the cache layout
-  ``[B, S, Hkv, D]`` (BlockSpec picks the head plane), and queries stay
-  grouped ``[B, Hkv, G, D]`` so GQA reads each KV byte once;
+  ``[B, Hkv, S, D]`` (BlockSpec picks the head plane; a K/V tile is
+  ``[block_k, D]``), and queries stay grouped ``[B, Hkv, G, D]`` so GQA
+  reads each KV byte once;
 - **early exit**: the per-row valid length is scalar-prefetched, and KV
   blocks at or beyond it are skipped entirely (`pl.when`) — decode cost
   follows the *actual* sequence length, not the padded cache size.
 
-Off-TPU the kernel runs in interpreter mode (CPU tests exercise the same
-code path).
+On the CPU the kernel runs in interpreter mode (CPU tests exercise the
+same code path).
 """
 
 from __future__ import annotations
@@ -25,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from cosmos_curate_tpu.ops.tiling import round_up, sublanes
 
 _NEG_INF = -1e30
 
@@ -47,8 +50,8 @@ def _decode_kernel(
 
     @pl.when(k_start < kv_len)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [g_pad, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [block_k, d]
+        q = q_ref[...].astype(jnp.float32) * sm_scale  # [g_pad, d]
+        k = k_ref[...].astype(jnp.float32)  # [block_k, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g_pad, block_k]
@@ -62,7 +65,7 @@ def _decode_kernel(
         l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p,
-            v_ref[0, :, 0, :].astype(jnp.float32),
+            v_ref[...].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -70,7 +73,7 @@ def _decode_kernel(
 
     @pl.when(ki == num_k - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -87,21 +90,21 @@ def decode_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     """q: [B, Hkv, G, D] (one token per row, grouped GQA queries);
-    k_cache/v_cache: [B, S, Hkv, D]; kv_len: [B] valid lengths (the new
+    k_cache/v_cache: [B, Hkv, S, D]; kv_len: [B] valid lengths (the new
     token's K/V already written). Returns [B, Hkv, G, D]."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.devices()[0].platform == "cpu"
     b, hk, g, d = q.shape
-    s = k_cache.shape[1]
+    s = k_cache.shape[2]
     block_k = min(block_k, s)
     if s % block_k:
         pad = block_k - s % block_k
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
         s += pad
-    g_pad = max(8, g)  # sublane minimum
+    g_pad = round_up(g, sublanes(q.dtype))
     if g_pad != g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
 
@@ -116,11 +119,11 @@ def decode_attention(
             grid=grid,
             # index maps receive the scalar-prefetch ref as a trailing arg
             in_specs=[
-                pl.BlockSpec((1, 1, g_pad, d), lambda b_, h, ki, *_: (b_, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d), lambda b_, h, ki, *_: (b_, ki, h, 0)),
-                pl.BlockSpec((1, block_k, 1, d), lambda b_, h, ki, *_: (b_, ki, h, 0)),
+                pl.BlockSpec((None, None, g_pad, d), lambda b_, h, ki, *_: (b_, h, 0, 0)),
+                pl.BlockSpec((None, None, block_k, d), lambda b_, h, ki, *_: (b_, h, ki, 0)),
+                pl.BlockSpec((None, None, block_k, d), lambda b_, h, ki, *_: (b_, h, ki, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, g_pad, d), lambda b_, h, ki, *_: (b_, h, 0, 0)),
+            out_specs=pl.BlockSpec((None, None, g_pad, d), lambda b_, h, ki, *_: (b_, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((g_pad, d), jnp.float32),
                 pltpu.VMEM((g_pad, 128), jnp.float32),

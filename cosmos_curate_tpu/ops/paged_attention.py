@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from cosmos_curate_tpu.ops.tiling import round_up, sublanes
+
 _NEG_INF = -1e30
 
 
@@ -63,13 +65,14 @@ def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_in
     outputs are bit-equal to the gather programs."""
     b, t, hk, g, d = q.shape
     nbl = tables.shape[1]
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     s = nbl * bs
-    new_k = pool_k[layer_index][tables].reshape(b, s, hk, d)
-    new_v = pool_v[layer_index][tables].reshape(b, s, hk, d)
+    # [B, nbl, Hkv, bs, D] -> the slot-row view [B, Hkv, S, D]
+    new_k = pool_k[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
+    new_v = pool_v[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
     qg = q * sm_scale
     logits = jnp.einsum(
-        "btkgd,bskd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
+        "btkgd,bksd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
     )
     k_pos = jnp.arange(s)[None, None, None, None, :]
     q_seq = write_index[:, None] + jnp.arange(t)[None, :]
@@ -77,7 +80,7 @@ def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_in
     written = k_pos < kv_len[:, None, None, None, None]
     logits = jnp.where(causal & written, logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bkgts,bskd->btkgd", probs.astype(q.dtype), new_v)
+    return jnp.einsum("bkgts,bksd->btkgd", probs.astype(q.dtype), new_v)
 
 
 def _paged_decode_kernel(
@@ -100,8 +103,8 @@ def _paged_decode_kernel(
 
     @pl.when(k_start < kv_len)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [g_pad, d]
-        k = k_ref[0, 0, :, 0, :].astype(jnp.float32)  # [bs, d]
+        q = q_ref[...].astype(jnp.float32) * sm_scale  # [g_pad, d]
+        k = k_ref[...].astype(jnp.float32)  # [bs, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [g_pad, bs]
@@ -115,7 +118,7 @@ def _paged_decode_kernel(
         l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p,
-            v_ref[0, 0, :, 0, :].astype(jnp.float32),
+            v_ref[...].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -123,7 +126,7 @@ def _paged_decode_kernel(
 
     @pl.when(ji == num_j - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(
@@ -157,18 +160,21 @@ def _paged_prefill_kernel(
     write = write_ref[b]
     kv_len = kvlen_ref[b]
     k_start = ji * bs
-    rows = block_q * g
+    rows = g * block_q
     last_pos = write + qi * block_q + block_q - 1
 
     @pl.when((k_start <= last_pos) & (k_start < kv_len))
     def _step():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(rows, q_ref.shape[-1])
+        # rows are group-major: row r is query t_local = r % block_q of
+        # group r // block_q, so the [g, block_q, d] tile flattens without
+        # moving data (block_q is a whole number of sublane tiles)
+        q = q_ref[...].astype(jnp.float32).reshape(rows, q_ref.shape[-1])
         q = q * sm_scale
-        k = k_ref[0, 0, :, 0, :].astype(jnp.float32)  # [bs, d]
+        k = k_ref[...].astype(jnp.float32)  # [bs, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, bs]
-        t_local = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
+        t_local = jax.lax.broadcasted_iota(jnp.int32, (g, block_q, bs), 1).reshape(rows, bs)
         q_pos = write + qi * block_q + t_local
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         ok = (k_pos <= q_pos) & (k_pos < kv_len)
@@ -181,7 +187,7 @@ def _paged_prefill_kernel(
         l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p,
-            v_ref[0, 0, :, 0, :].astype(jnp.float32),
+            v_ref[...].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -190,44 +196,37 @@ def _paged_prefill_kernel(
     @pl.when(ji == num_j - 1)
     def _finish():
         out = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0] = out.reshape(block_q, g, o_ref.shape[-1]).astype(o_ref.dtype)
+        o_ref[...] = out.reshape(g, block_q, o_ref.shape[-1]).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("layer_index", "sm_scale", "interpret")
 )
 def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, interpret):
-    """q: [B, Hkv, G, D]; pools: [L, NB, bs, Hkv, D]; tables: [B, nbl]."""
+    """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]."""
     b, hk, g, d = q.shape
     nbl = tables.shape[1]
-    bs = pool_k.shape[2]
-    g_pad = max(8, g)  # sublane minimum
+    bs = pool_k.shape[3]
+    g_pad = round_up(g, sublanes(q.dtype))
     if g_pad != g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
 
     grid = (b, hk, nbl)
     kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale, bs=bs, g_pad=g_pad)
+    # the table ref arrives as a trailing index-map arg: grid step ji reads
+    # physical pool block tbl[b, ji] in place — one [bs, D] tile of head h
+    kv_spec = pl.BlockSpec(
+        (None, None, None, bs, d),
+        lambda b_, h, ji, kvlen, tbl: (layer_index, tbl[b_, ji], h, 0, 0),
+    )
+    q_spec = pl.BlockSpec((None, None, g_pad, d), lambda b_, h, ji, kvlen, tbl: (b_, h, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
-            # the table ref arrives as a trailing index-map arg: grid step
-            # ji reads physical pool block tbl[b, ji] in place
-            in_specs=[
-                pl.BlockSpec((1, 1, g_pad, d), lambda b_, h, ji, kvlen, tbl: (b_, h, 0, 0)),
-                pl.BlockSpec(
-                    (1, 1, bs, 1, d),
-                    lambda b_, h, ji, kvlen, tbl: (layer_index, tbl[b_, ji], 0, h, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, bs, 1, d),
-                    lambda b_, h, ji, kvlen, tbl: (layer_index, tbl[b_, ji], 0, h, 0),
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, g_pad, d), lambda b_, h, ji, kvlen, tbl: (b_, h, 0, 0)
-            ),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((g_pad, d), jnp.float32),
                 pltpu.VMEM((g_pad, 128), jnp.float32),
@@ -246,63 +245,44 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
 def _paged_prefill(
     q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale, block_q, interpret
 ):
-    """q: [B, T, Hkv, G, D]; pools: [L, NB, bs, Hkv, D]; tables: [B, nbl]."""
+    """q: [B, T, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]."""
     b, t, hk, g, d = q.shape
-    t_orig = t
     nbl = tables.shape[1]
-    bs = pool_k.shape[2]
-    block_q = min(block_q, t)
-    if t % block_q:
-        pad = block_q - t % block_q
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
-        t += pad
+    bs = pool_k.shape[3]
+    block_q = min(block_q, round_up(t, sublanes(q.dtype)))
+    t_pad = round_up(t, block_q)
+    # heads-major, group-major queries: the kernel's [g, block_q, d] tile
+    # keeps (block_q, d) as the tiled dims, like the KV pages
+    q = q.transpose(0, 2, 3, 1, 4)  # [B, Hkv, G, T, D]
+    if t_pad != t:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, t_pad - t), (0, 0)))
 
-    grid = (b, hk, t // block_q, nbl)
+    grid = (b, hk, t_pad // block_q, nbl)
     kernel = functools.partial(
         _paged_prefill_kernel, sm_scale=sm_scale, block_q=block_q, bs=bs, g=g
+    )
+    kv_spec = pl.BlockSpec(
+        (None, None, None, bs, d),
+        lambda b_, h, qi, ji, write, kvlen, tbl: (layer_index, tbl[b_, ji], h, 0, 0),
+    )
+    q_spec = pl.BlockSpec(
+        (None, None, g, block_q, d),
+        lambda b_, h, qi, ji, write, kvlen, tbl: (b_, h, 0, qi, 0),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, block_q, 1, g, d),
-                    lambda b_, h, qi, ji, write, kvlen, tbl: (b_, qi, h, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, bs, 1, d),
-                    lambda b_, h, qi, ji, write, kvlen, tbl: (
-                        layer_index,
-                        tbl[b_, ji],
-                        0,
-                        h,
-                        0,
-                    ),
-                ),
-                pl.BlockSpec(
-                    (1, 1, bs, 1, d),
-                    lambda b_, h, qi, ji, write, kvlen, tbl: (
-                        layer_index,
-                        tbl[b_, ji],
-                        0,
-                        h,
-                        0,
-                    ),
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_q, 1, g, d),
-                lambda b_, h, qi, ji, write, kvlen, tbl: (b_, qi, h, 0, 0),
-            ),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((block_q * g, d), jnp.float32),
-                pltpu.VMEM((block_q * g, 128), jnp.float32),
-                pltpu.VMEM((block_q * g, 128), jnp.float32),
+                pltpu.VMEM((g * block_q, d), jnp.float32),
+                pltpu.VMEM((g * block_q, 128), jnp.float32),
+                pltpu.VMEM((g * block_q, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, t, hk, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g, t_pad, d), q.dtype),
         interpret=interpret,
     )(
         write_index.astype(jnp.int32),
@@ -312,7 +292,7 @@ def _paged_prefill(
         pool_k,
         pool_v,
     )
-    return out[:, :t_orig]
+    return out[:, :, :, :t].transpose(0, 3, 1, 2, 4)
 
 
 def paged_attention(
@@ -333,7 +313,7 @@ def paged_attention(
 
     q: ``[B, T, Hkv, G, D]`` UNSCALED grouped queries (this op applies
     ``sm_scale`` so the reference path matches DecoderLayer bitwise);
-    pool_k/pool_v: the full block pools ``[L, NB, bs, Hkv, D]`` with the
+    pool_k/pool_v: the full block pools ``[L, NB, Hkv, bs, D]`` with the
     chunk's K/V already written through the table; tables: ``[B, nbl]``
     logical-to-physical block ids; write_index/kv_len: ``[B]``. Serves both
     decode (T=1) and chunked prefill (T>1). Returns ``[B, T, Hkv, G, D]``.
@@ -351,7 +331,7 @@ def paged_attention(
             layer_index=layer_index, sm_scale=sm_scale,
         )
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.devices()[0].platform == "cpu"
     if q.shape[1] == 1:
         out = _paged_decode(
             q[:, 0], pool_k, pool_v, tables, kv_len,
@@ -398,7 +378,7 @@ def paged_head_attention(
         sm_scale = q.shape[-1] ** -0.5
     axis = MODEL if MODEL in mesh.axis_names else None
     qspec = P(None, None, axis, None, None)  # [B, T, Hkv, G, D]
-    pspec = P(None, None, None, axis, None)  # [L, NB, bs, Hkv, D]
+    pspec = P(None, None, axis, None, None)  # [L, NB, Hkv, bs, D]
     fn = functools.partial(
         paged_attention,
         layer_index=layer_index,

@@ -69,6 +69,16 @@ class MeshSpec:
         return dict(zip(self.axis_names(), dims))
 
 
+def tpu_chip_count() -> int:
+    """TPU chips JAX reports — the denominator of per-chip metrics and the
+    autoscaler's chip budget. A CPU run has none and counts as one unit;
+    a device query that fails raises (a guessed chip count hides the
+    device)."""
+    import jax
+
+    return max(1, sum(d.platform == "tpu" for d in jax.devices()))
+
+
 def local_mesh(axis_names: tuple[str, ...] = (DATA, MODEL), shape: tuple[int, ...] | None = None):
     """Mesh over this process's local devices (the ``entire_tpu_host`` worker
     claim). Default: all chips on one ``model`` axis when shape is None and

@@ -5,9 +5,9 @@ let XLA insert collectives. These helpers keep annotations terse at stage
 call sites, and centralize the host→device transfer (the critical data path
 feeding chips from CPU prep stages, SURVEY.md §7 hard part 3).
 
-Axis names come from parallel/axes.py; ``shard_map`` here is the
-version-compat front door every shard_map call site uses (``jax.shard_map``
-landed after this image's JAX, which only has the experimental API).
+Axis names come from parallel/axes.py; ``shard_map`` here is the front
+door every shard_map call site uses (``jax.shard_map`` with this repo's
+``check_vma=False`` default).
 """
 
 from __future__ import annotations
@@ -20,21 +20,13 @@ from cosmos_curate_tpu.parallel.axes import BATCH_AXES
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across JAX versions: the top-level API when present,
-    else ``jax.experimental.shard_map`` (where ``check_vma`` was named
-    ``check_rep``). Accepts ``jax.sharding.AbstractMesh`` too, so specs can
-    be shape-checked under ``jax.eval_shape`` with zero devices — the
-    mechanism behind ``cosmos-curate-tpu lint --shard-check``."""
+    """``jax.shard_map``. Accepts ``jax.sharding.AbstractMesh`` too, so
+    specs can be shape-checked under ``jax.eval_shape`` with zero devices —
+    the mechanism behind ``cosmos-curate-tpu lint --shard-check``."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )
 
 
@@ -48,6 +40,34 @@ def replicated(mesh):
     from jax.sharding import NamedSharding, PartitionSpec
 
     return NamedSharding(mesh, PartitionSpec())
+
+
+def spec_sharding(mesh, spec):
+    """NamedSharding for a PartitionSpec that may name axes ``mesh`` lacks:
+    those dims replicate (a model-only mesh still places a spec written for
+    the full (dcn, data, model, seq) registry)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def keep(entry):
+        if isinstance(entry, tuple):
+            return tuple(a for a in entry if a in mesh.axis_names) or None
+        return entry if entry in mesh.axis_names else None
+
+    return NamedSharding(mesh, PartitionSpec(*(keep(e) for e in spec)))
+
+
+def place_partitioned(mesh, params, specs):
+    """Device-put a parameter tree over ``mesh`` by ``specs`` — the
+    ``nn.get_partition_spec`` tree of the model's ``nn.with_partitioning``
+    annotations. ``params`` may be boxed or plain (a loaded checkpoint)."""
+    import flax.linen as nn
+    import jax
+
+    return jax.tree.map(
+        lambda x, spec: jax.device_put(x, spec_sharding(mesh, spec)),
+        nn.unbox(params),
+        specs,
+    )
 
 
 def batch_sharding(mesh, batch_axes: str | tuple[str, ...] = BATCH_AXES):

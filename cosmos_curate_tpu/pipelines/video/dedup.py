@@ -149,12 +149,9 @@ def run_dedup(args: DedupPipelineArgs) -> dict:
     logger.info("dedup: %d embeddings (%s, dim %d)", len(ids), model, embeddings.shape[1])
     mesh = None
     if args.use_mesh:
-        try:
-            from cosmos_curate_tpu.parallel.mesh import best_effort_mesh
+        from cosmos_curate_tpu.parallel.mesh import best_effort_mesh
 
-            mesh = best_effort_mesh()
-        except Exception as e:
-            logger.warning("no mesh available (%s); single-device kmeans", e)
+        mesh = best_effort_mesh()
     index = _open_index(args, mesh, model)
     if index is not None:
         # fast path: query the persistent index (which may already contain

@@ -7,107 +7,62 @@ same session. The persistent cache turns every repeat compile — across
 processes, across runs, across the bench's warmup/measure split — into a
 disk hit. The reference has no analogue (CUDA kernels ship precompiled);
 on TPU this is the idiomatic fix for XLA's compile-once-per-process model.
+
+One cache, placeable from outside: where ``JAX_COMPILATION_CACHE_DIR`` is
+set, JAX itself reads it and this module sets no directory in code. Where
+it is unset the cache is ``.jax_cache`` at the root of the checkout — a
+fixed path (the path is part of a cache entry's key, so a directory that
+moves never hits). ``CURATE_COMPILE_CACHE=0`` turns the cache off.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+from pathlib import Path
 
 _LOCK = threading.Lock()
 _ENABLED = False
 
-# Primary knob: CURATE_COMPILE_CACHE = "0"/"off" disables the persistent
-# cache entirely, "1"/"on" enables it at the default (or legacy-env) path,
-# any other value is the cache base directory itself. Unset = enabled at
-# the default path (compiles are paid once per machine, not per process).
+# "0"/"off" disables the persistent cache entirely; anything else (or
+# unset) leaves it on. WHERE it lives is JAX_COMPILATION_CACHE_DIR's job.
 COMPILE_CACHE_ENV = "CURATE_COMPILE_CACHE"
-# Legacy path-only override, kept for existing deployments.
-CACHE_DIR_ENV = "CURATE_JAX_CACHE_DIR"
-DEFAULT_CACHE_DIR = "/tmp/curate_jax_cache"
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
-def resolve_cache_base(path: str | None = None) -> str | None:
-    """The cache base dir per the knobs, or None when disabled.
-
-    Precedence: explicit ``path`` arg > CURATE_COMPILE_CACHE (off/on/path)
-    > CURATE_JAX_CACHE_DIR > the default. An explicit arg wins even over
-    an env-level "off" — the caller asked for a specific cache."""
-    if path:
-        return path
-    knob = os.environ.get(COMPILE_CACHE_ENV, "").strip()
-    if knob.lower() in ("0", "off", "false", "no"):
+def cache_dir() -> str | None:
+    """The cache directory in use, or None when the knob turns it off."""
+    if os.environ.get(COMPILE_CACHE_ENV, "").strip().lower() in ("0", "off", "false", "no"):
         return None
-    if knob and knob.lower() not in ("1", "on", "true", "yes"):
-        return knob  # a path
-    return os.environ.get(CACHE_DIR_ENV) or DEFAULT_CACHE_DIR
+    return os.environ.get(JAX_CACHE_DIR_ENV) or CHECKOUT_CACHE_DIR
 
 
-def _host_fingerprint() -> str:
-    """A short tag of the CPU feature set AND the jax/jaxlib identity.
-    XLA:CPU AOT cache entries embed the compile-time target features;
-    loading them under a different feature profile logs 'could lead to
-    SIGILL' and can actually crash. The features XLA picks depend on the
-    jaxlib BUILD, not just /proc/cpuinfo (observed on this box: entries
-    compiled with +prefer-no-scatter/+prefer-no-gather by one jaxlib were
-    loaded by another with the same cpuinfo flags), so the key must include
-    which jaxlib produced the entry."""
-    import hashlib
-    import platform
-
-    # cache epoch: bump to orphan every entry written before the key grew
-    # the jaxlib identity (stale pre-epoch entries caused the SIGILL-risk
-    # loader errors in MULTICHIP_r04)
-    bits = f"v2:{platform.machine()}:{platform.processor()}"
-    try:
-        with open("/proc/cpuinfo") as fh:
-            for line in fh:
-                if line.startswith("flags"):
-                    bits += ":" + line.split(":", 1)[1].strip()
-                    break
-    except OSError:
-        pass
-    try:
-        import jax
-        import jaxlib
-
-        bits += f":{jax.__version__}:{jaxlib.__version__}:{jaxlib.__file__}"
-        # build identity, not just version: a force-reinstalled same-version
-        # wheel built with different target features lands at the same path
-        # — stat the package's native extensions so the key tracks the
-        # actual compiled artifacts
-        from pathlib import Path
-
-        pkg = Path(jaxlib.__file__).parent
-        for so in sorted(pkg.glob("*.so")) + sorted(pkg.glob("**/xla_extension*.so")):
-            st = so.stat()
-            bits += f":{so.name}:{st.st_size}:{int(st.st_mtime)}"
-    except Exception:
-        pass
-    return hashlib.sha256(bits.encode()).hexdigest()[:10]
-
-
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Idempotently point jax at a persistent compilation cache directory.
+def enable_persistent_cache() -> str | None:
+    """Idempotently turn the persistent compilation cache on.
 
     Must run before the first compile to capture it; callers at natural
-    chokepoints (registry.load_params, DevicePipeline construction, bench,
-    dryrun) make that true for every model path. Returns the cache dir in
-    use, or None when CURATE_COMPILE_CACHE disables the cache.
+    chokepoints (registry.load_params, DevicePipeline construction,
+    chip_smoke) make that true for every model path. Returns the cache dir
+    in use, or None when CURATE_COMPILE_CACHE disables the cache.
     """
     global _ENABLED
-    base = resolve_cache_base(path)
-    if base is None:
-        return None
-    cache_dir = os.path.join(base, _host_fingerprint())
+    path = cache_dir()
     with _LOCK:
         if _ENABLED:
-            return cache_dir
+            return path
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Default min compile time is 1s; embed/caption programs compile in
-        # 0.5-40s, so lower the floor to catch the small-but-repeated ones.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+        if path is None:
+            jax.config.update("jax_enable_compilation_cache", False)
+        else:
+            if not os.environ.get(JAX_CACHE_DIR_ENV):
+                # only the in-checkout default is set in code: a directory
+                # given from outside is JAX's own to read
+                jax.config.update("jax_compilation_cache_dir", path)
+            # Default min compile time is 1s; embed/caption programs compile
+            # in 0.5-40s, so lower the floor to catch the small-but-repeated
+            # ones.
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
         _ENABLED = True
-    return cache_dir
+    return path

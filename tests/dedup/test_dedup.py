@@ -65,19 +65,17 @@ class TestKMeans:
         np.testing.assert_array_equal(a0, a1)
         np.testing.assert_array_equal(c0, c1)
 
-    def test_broken_mesh_degrades_cleanly(self, rng):
-        """A mesh the batch cannot ride falls back to single-device (with a
-        warning) instead of crashing the dedup run — identical results."""
+    def test_mesh_the_batch_cannot_ride_raises(self, rng):
+        """A multi-device mesh that cannot take the batch is an error: the
+        run must not quietly use less of the machine than it was given."""
 
         class _BrokenMesh:
             size = 2  # looks multi-device, fails at shard time
             axis_names = ()
 
         data, _ = _clustered_data(rng, n_per=16)
-        c0, a0 = kmeans_fit(data, 3, iters=10, seed=0)
-        c1, a1 = kmeans_fit(data, 3, iters=10, seed=0, mesh=_BrokenMesh())
-        np.testing.assert_array_equal(a0, a1)
-        np.testing.assert_array_equal(c0, c1)
+        with pytest.raises(AttributeError):  # JAX's own, from the device put
+            kmeans_fit(data, 3, iters=10, seed=0, mesh=_BrokenMesh())
 
 
 class TestSemanticDedup:

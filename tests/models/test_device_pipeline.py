@@ -2,6 +2,9 @@
 drains, donation fallback on CPU, compile-cache knob, and embedding-stage
 equivalence with the old synchronous path. All on CPU with tiny shapes."""
 
+import os
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -347,38 +350,59 @@ class TestDonation:
 
 
 class TestCompileCacheKnob:
-    def _fresh(self, monkeypatch):
+    """One cache, placeable from outside: JAX_COMPILATION_CACHE_DIR where
+    it is set (and then nothing is set in code), else a fixed directory in
+    the checkout."""
+
+    @pytest.fixture
+    def jc(self, monkeypatch):
         from cosmos_curate_tpu.utils import jax_cache
 
         monkeypatch.setattr(jax_cache, "_ENABLED", False)
+        monkeypatch.delenv(jax_cache.COMPILE_CACHE_ENV, raising=False)
         return jax_cache
 
-    def test_knob_off(self, monkeypatch):
-        jc = self._fresh(monkeypatch)
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """What the code under test sets through jax.config.update."""
+        seen = []
+        monkeypatch.setattr(jax.config, "update", lambda k, v: seen.append((k, v)))
+        return seen
+
+    def test_env_dir_honoured_and_nothing_set_in_code(self, jc, updates, monkeypatch, tmp_path):
+        monkeypatch.setenv(jc.JAX_CACHE_DIR_ENV, str(tmp_path / "cc"))
+        assert jc.enable_persistent_cache() == str(tmp_path / "cc")
+        assert "jax_compilation_cache_dir" not in dict(updates)
+
+    def test_unset_uses_fixed_checkout_path(self, jc, updates, monkeypatch):
+        monkeypatch.delenv(jc.JAX_CACHE_DIR_ENV, raising=False)
+        repo = Path(__file__).resolve().parents[2]
+        assert jc.enable_persistent_cache() == str(repo / ".jax_cache")
+        assert dict(updates)["jax_compilation_cache_dir"] == str(repo / ".jax_cache")
+
+    def test_same_path_from_two_processes(self, jc, monkeypatch):
+        """No pid, no fingerprint, no temp dir: a second process (another
+        cwd) resolves the very same directory, or its entries never hit."""
+        import subprocess
+        import sys
+
+        monkeypatch.delenv(jc.JAX_CACHE_DIR_ENV, raising=False)
+        repo = Path(__file__).resolve().parents[2]
+        code = "from cosmos_curate_tpu.utils import jax_cache; print(jax_cache.cache_dir())"
+        env = {k: v for k, v in os.environ.items() if k != jc.JAX_CACHE_DIR_ENV}
+        env["PYTHONPATH"] = str(repo)
+        out = subprocess.run(
+            [sys.executable, "-c", code], cwd="/", env=env, capture_output=True,
+            text=True, check=True,
+        ).stdout.strip()
+        assert out == jc.cache_dir() == str(repo / ".jax_cache")
+
+    def test_knob_off(self, jc, updates, monkeypatch, tmp_path):
+        monkeypatch.setenv(jc.JAX_CACHE_DIR_ENV, str(tmp_path / "cc"))
         monkeypatch.setenv(jc.COMPILE_CACHE_ENV, "0")
-        assert jc.resolve_cache_base() is None
+        assert jc.cache_dir() is None
         assert jc.enable_persistent_cache() is None
-
-    def test_knob_path(self, monkeypatch, tmp_path):
-        jc = self._fresh(monkeypatch)
-        monkeypatch.setenv(jc.COMPILE_CACHE_ENV, str(tmp_path / "cc"))
-        base = jc.resolve_cache_base()
-        assert base == str(tmp_path / "cc")
-        got = jc.enable_persistent_cache()
-        assert got is not None and got.startswith(base)
-
-    def test_knob_on_uses_default_or_legacy(self, monkeypatch):
-        jc = self._fresh(monkeypatch)
-        monkeypatch.setenv(jc.COMPILE_CACHE_ENV, "1")
-        monkeypatch.delenv(jc.CACHE_DIR_ENV, raising=False)
-        assert jc.resolve_cache_base() == jc.DEFAULT_CACHE_DIR
-        monkeypatch.setenv(jc.CACHE_DIR_ENV, "/tmp/legacy_cc")
-        assert jc.resolve_cache_base() == "/tmp/legacy_cc"
-
-    def test_explicit_arg_wins_over_off(self, monkeypatch):
-        jc = self._fresh(monkeypatch)
-        monkeypatch.setenv(jc.COMPILE_CACHE_ENV, "off")
-        assert jc.resolve_cache_base("/tmp/explicit") == "/tmp/explicit"
+        assert updates == [("jax_enable_compilation_cache", False)]
 
 
 class TestEmbeddingStageEquivalence:

@@ -2,7 +2,7 @@
 parity with the slot-row engine's math (tiny config, CPU).
 
 The parity reference below reproduces the OLD slot-row engine exactly: one
-request at a time through a private contiguous ``[L, 1, S, Hkv, Dh]`` cache
+request at a time through a private contiguous ``[L, 1, Hkv, S, Dh]`` cache
 (the unchanged model's own layout), prefilled in one shot and greedily
 decoded token by token. The paged engine — block tables, shared refcounted
 prefix blocks, copy-on-write tails, batched admission, chunked prefill —
@@ -79,14 +79,12 @@ def slot_row_reference(eng: CaptionEngine, req: CaptionRequest, cache_len: int) 
         ck = cache_k[:, slots]
         cv = cache_v[:, slots]
         logits, nk, nv = model.apply(
-            params, embeds, ck, cv, rope_pos, write_index, write_index + t_valid
+            params, embeds, ck, cv, rope_pos, write_index, write_index + t_valid,
+            logits_at=t_valid - 1,
         )
         cache_k = cache_k.at[:, slots].set(nk)
         cache_v = cache_v.at[:, slots].set(nv)
-        last = jnp.take_along_axis(
-            logits, (t_valid - 1)[:, None, None].astype(jnp.int32), axis=1
-        )[:, 0]
-        return last, cache_k, cache_v
+        return logits[:, 0], cache_k, cache_v
 
     @partial(jax.jit, donate_argnums=(1, 2))
     def decode(params, cache_k, cache_v, tokens, positions, rope_positions):

@@ -23,11 +23,11 @@ from cosmos_curate_tpu.ops.paged_attention import (
 
 def _dense_reference(q, k_cache, v_cache, write_index, kv_len, sm_scale):
     """Grouped causal attention against CONTIGUOUS caches — independent of
-    the pool/table plumbing under test. q: [B,T,Hk,G,D]; caches [B,S,Hk,D]."""
+    the pool/table plumbing under test. q: [B,T,Hk,G,D]; caches [B,Hk,S,D]."""
     b, t, hk, g, d = q.shape
-    s = k_cache.shape[1]
+    s = k_cache.shape[2]
     logits = jnp.einsum(
-        "btkgd,bskd->bkgts",
+        "btkgd,bksd->bkgts",
         q.astype(jnp.float32) * sm_scale,
         k_cache.astype(jnp.float32),
     )
@@ -37,7 +37,7 @@ def _dense_reference(q, k_cache, v_cache, write_index, kv_len, sm_scale):
     written = k_pos < kv_len[:, None, None, None, None]
     logits = jnp.where(causal & written, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bkgts,bskd->btkgd", probs, v_cache.astype(jnp.float32))
+    return jnp.einsum("bkgts,bksd->btkgd", probs, v_cache.astype(jnp.float32))
 
 
 def _fragmented_case(rng, *, b, t, hk, g, d, nbl, bs, n_blocks, dtype=jnp.float32):
@@ -46,13 +46,17 @@ def _fragmented_case(rng, *, b, t, hk, g, d, nbl, bs, n_blocks, dtype=jnp.float3
     the logical contiguous caches those tables describe."""
     l = 2  # two layers so layer_index != 0 is exercised
     layer = 1
-    pool_k = jnp.asarray(rng.standard_normal((l, n_blocks, bs, hk, d)), dtype)
-    pool_v = jnp.asarray(rng.standard_normal((l, n_blocks, bs, hk, d)), dtype)
+    pool_k = jnp.asarray(rng.standard_normal((l, n_blocks, hk, bs, d)), dtype)
+    pool_v = jnp.asarray(rng.standard_normal((l, n_blocks, hk, bs, d)), dtype)
     ids = rng.permutation(np.arange(1, n_blocks))[: b * nbl]
     tables = jnp.asarray(ids.reshape(b, nbl), jnp.int32)
     q = jnp.asarray(rng.standard_normal((b, t, hk, g, d)), dtype)
-    k_cache = np.asarray(pool_k)[layer][np.asarray(tables)].reshape(b, nbl * bs, hk, d)
-    v_cache = np.asarray(pool_v)[layer][np.asarray(tables)].reshape(b, nbl * bs, hk, d)
+
+    def contiguous(pool):  # [B, nbl, Hk, bs, D] -> [B, Hk, S, D]
+        blocks = np.asarray(pool)[layer][np.asarray(tables)]
+        return blocks.swapaxes(1, 2).reshape(b, hk, nbl * bs, d)
+
+    k_cache, v_cache = contiguous(pool_k), contiguous(pool_v)
     return q, pool_k, pool_v, tables, layer, jnp.asarray(k_cache), jnp.asarray(v_cache)
 
 
@@ -177,8 +181,11 @@ class TestInterpretKernel:
 class TestHeadParallel:
     def test_sharded_heads_bit_equal_to_single_device(self, cpu_mesh):
         """shard_map over the model axis (Hkv sharded, tables replicated)
-        must be BIT-equal to the unsharded op: head planes never interact
-        in attention, so sharding cannot change a single float."""
+        must be BIT-equal to the unsharded op run on each shard's head
+        plane: head planes never interact in attention, so sharding adds
+        nothing to the arithmetic. (Against the unsharded op over ALL
+        heads at once XLA's CPU dot may pick another summation order for
+        the larger head batch — that comparison owes float agreement.)"""
         rng = np.random.default_rng(6)
         b, hk, g, d, nbl, bs = 2, 4, 2, 16, 3, 8  # hk divides model axis (4)
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
@@ -189,10 +196,18 @@ class TestHeadParallel:
             cpu_mesh, q, pk, pv, tables, kv_len - 1, kv_len,
             layer_index=layer, use_kernel=False,
         )
+        planes = [
+            paged_attention(
+                q[:, :, h : h + 1], pk[:, :, h : h + 1], pv[:, :, h : h + 1],
+                tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False,
+            )
+            for h in range(hk)
+        ]
+        assert np.array_equal(np.asarray(sharded), np.concatenate(planes, axis=2))
         single = paged_attention(
             q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False
         )
-        assert np.array_equal(np.asarray(sharded), np.asarray(single))
+        np.testing.assert_allclose(np.asarray(sharded), np.asarray(single), atol=1e-6, rtol=0)
 
 
 def test_env_gate(monkeypatch):
@@ -202,29 +217,3 @@ def test_env_gate(monkeypatch):
     assert not use_paged_kernel()
     monkeypatch.delenv("CURATE_PAGED_KERNEL")
     assert use_paged_kernel() == (jax.devices()[0].platform == "tpu")
-
-
-@pytest.mark.tpu
-def test_kernel_numerics_on_chip():
-    """ROADMAP 4b first rung: the COMPILED kernel (not interpreter) vs the
-    gather-equivalent reference, on real hardware. Self-skips off-TPU so
-    default CPU runs stay green without deselection."""
-    if jax.devices()[0].platform != "tpu":
-        pytest.skip("requires TPU hardware")
-    rng = np.random.default_rng(7)
-    b, hk, g, d, nbl, bs = 4, 4, 8, 128, 8, 16
-    q, pk, pv, tables, layer, _, _ = _fragmented_case(
-        rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs,
-        n_blocks=b * nbl + 4, dtype=jnp.bfloat16,
-    )
-    kv_len = jnp.asarray(rng.integers(1, nbl * bs + 1, b), jnp.int32)
-    got = paged_attention(
-        q, pk, pv, tables, kv_len - 1, kv_len,
-        layer_index=layer, use_kernel=True, interpret=False,
-    )
-    want = paged_attention(
-        q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False
-    )
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=3e-2, rtol=3e-2
-    )

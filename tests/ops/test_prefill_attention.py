@@ -13,9 +13,9 @@ from cosmos_curate_tpu.ops.prefill_attention import prefill_attention
 def _reference(q, k_cache, v_cache, write_index, kv_len):
     """Mirror of models/vlm/model.py DecoderLayer's XLA attention path."""
     b, t, hk, g, d = q.shape
-    s = k_cache.shape[1]
+    s = k_cache.shape[2]
     qf = q.astype(np.float64) * d**-0.5
-    logits = np.einsum("btkgd,bskd->bkgts", qf, k_cache.astype(np.float64))
+    logits = np.einsum("btkgd,bksd->bkgts", qf, k_cache.astype(np.float64))
     k_pos = np.arange(s)[None, None, None, None, :]
     q_seq = write_index[:, None] + np.arange(t)[None, :]
     causal = k_pos <= q_seq[:, None, None, :, None]
@@ -24,7 +24,7 @@ def _reference(q, k_cache, v_cache, write_index, kv_len):
     logits -= logits.max(axis=-1, keepdims=True)
     probs = np.exp(logits)
     probs /= probs.sum(axis=-1, keepdims=True)
-    out = np.einsum("bkgts,bskd->btkgd", probs, v_cache.astype(np.float64))
+    out = np.einsum("bkgts,bksd->btkgd", probs, v_cache.astype(np.float64))
     return out
 
 
@@ -44,8 +44,8 @@ def test_matches_reference(case):
     write_index = np.asarray(writes, np.int32)
     kv_len = write_index + t + extra
     q = rng.normal(size=(b, t, hk, g, d)).astype(np.float32)
-    k = rng.normal(size=(b, s, hk, d)).astype(np.float32)
-    v = rng.normal(size=(b, s, hk, d)).astype(np.float32)
+    k = rng.normal(size=(b, hk, s, d)).astype(np.float32)
+    v = rng.normal(size=(b, hk, s, d)).astype(np.float32)
     got = prefill_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
         jnp.asarray(write_index), jnp.asarray(kv_len),
@@ -63,12 +63,12 @@ def test_early_exit_blocks_do_not_change_result():
     write = np.asarray([0], np.int32)
     kv_len = write + t
     q = rng.normal(size=(b, t, hk, g, d)).astype(np.float32)
-    k = rng.normal(size=(b, s, hk, d)).astype(np.float32)
-    v = rng.normal(size=(b, s, hk, d)).astype(np.float32)
+    k = rng.normal(size=(b, hk, s, d)).astype(np.float32)
+    v = rng.normal(size=(b, hk, s, d)).astype(np.float32)
     poisoned_k = k.copy()
-    poisoned_k[:, t:] = 1e6
+    poisoned_k[:, :, t:] = 1e6
     poisoned_v = v.copy()
-    poisoned_v[:, t:] = -1e6
+    poisoned_v[:, :, t:] = -1e6
     a = prefill_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
         jnp.asarray(write), jnp.asarray(kv_len), block_q=8, block_k=16, interpret=True,

@@ -1,0 +1,116 @@
+"""The Pallas kernels compiled for a DESCRIBED TPU v5e, at real widths.
+
+Interpret mode checks a kernel's arithmetic and nothing about its layout:
+block shapes the chip's compiler refuses (a K/V tile that slices one head
+out of the second-to-last dimension, a reshape across sublane tiles, too
+much VMEM) pass every CPU test. The TPU compiler is installed next to the
+CPU one and compiles for a chip that is described, not attached, so these
+cases cost no chip time: a kernel change that the chip would refuse fails
+here. Nothing runs — results are ``chip_smoke.py``'s business.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described (not attached) v5e 2x2 host."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it off around these cases
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+# (Hkv, G, D): the default `base` captioner and Qwen2-VL-2B
+WIDTHS = {"base": (8, 2, 64), "qwen2vl-2b": (2, 6, 128)}
+B, T, S, BS, LAYERS, POOL_BLOCKS = 8, 256, 1024, 16, 2, 600
+
+
+def _paged_decode(hk, g, d, arg):
+    from cosmos_curate_tpu.ops.paged_attention import _paged_decode as fn
+
+    pool = arg((LAYERS, POOL_BLOCKS, hk, BS, d), jnp.bfloat16)
+    return (
+        functools.partial(fn, layer_index=1, sm_scale=d**-0.5, interpret=False),
+        (arg((B, hk, g, d), jnp.bfloat16), pool, pool,
+         arg((B, S // BS), jnp.int32), arg((B,), jnp.int32)),
+    )
+
+
+def _paged_prefill(hk, g, d, arg):
+    from cosmos_curate_tpu.ops.paged_attention import _paged_prefill as fn
+
+    pool = arg((LAYERS, POOL_BLOCKS, hk, BS, d), jnp.bfloat16)
+    return (
+        functools.partial(fn, layer_index=1, sm_scale=d**-0.5, block_q=128, interpret=False),
+        (arg((B, T, hk, g, d), jnp.bfloat16), pool, pool,
+         arg((B, S // BS), jnp.int32), arg((B,), jnp.int32), arg((B,), jnp.int32)),
+    )
+
+
+def _decode(hk, g, d, arg):
+    from cosmos_curate_tpu.ops.decode_attention import decode_attention as fn
+
+    cache = arg((B, hk, S, d), jnp.bfloat16)
+    return (
+        functools.partial(fn, interpret=False),
+        (arg((B, hk, g, d), jnp.bfloat16), cache, cache, arg((B,), jnp.int32)),
+    )
+
+
+def _prefill(hk, g, d, arg):
+    from cosmos_curate_tpu.ops.prefill_attention import prefill_attention as fn
+
+    cache = arg((B, hk, S, d), jnp.bfloat16)
+    return (
+        functools.partial(fn, interpret=False),
+        (arg((B, T, hk, g, d), jnp.bfloat16), cache, cache,
+         arg((B,), jnp.int32), arg((B,), jnp.int32)),
+    )
+
+
+def _flash(hk, g, d, arg):
+    from cosmos_curate_tpu.ops.flash_attention import flash_attention as fn
+
+    # 2049 = InternVideo2's 8x256+1 tokens: the ragged tail pads in-kernel
+    x = arg((1, hk * g, 2049, d), jnp.bfloat16)
+    return functools.partial(fn, interpret=False), (x, x, x)
+
+
+KERNELS = {
+    "paged_decode": _paged_decode,
+    "paged_prefill": _paged_prefill,
+    "decode_attention": _decode,
+    "prefill_attention": _prefill,
+    "flash_attention": _flash,
+}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(v5e, kernel, widths):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = KERNELS[kernel](*WIDTHS[widths], arg)
+    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip would
+    assert "tpu_custom_call" in compiled.as_text()
