@@ -48,6 +48,7 @@ vllm_async_stage.py). TPU-first re-design:
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import threading
@@ -266,6 +267,26 @@ class _Lane:
     claims: dict = field(default_factory=dict)
 
 
+# Phases of the engine's two threads, each timed at one site by
+# CaptionEngine._phase(): the span "engine.<name>" on the profiler's clock and
+# the counter "<name>_s" of phase_seconds on the host's. The roots also report
+# their elapsed time (`step_s`, `prep_s`); their self time is `<root>_other_s`.
+_PHASE_ROOTS = ("step", "prep")
+_PHASES = _PHASE_ROOTS + (
+    "lock_wait",
+    "admit",
+    "prefill_build",
+    "prefill_dispatch",
+    "prefill_wait",
+    "prefill_sample",
+    "decode_build",
+    "decode_dispatch",
+    "decode_wait",
+    "decode_sample",
+    "vision_encode",
+)
+
+
 class CaptionEngine:
     def __init__(
         self,
@@ -372,14 +393,15 @@ class CaptionEngine:
         self._pool_v = None
         self.completed: list[CaptionResult] = []
         self._decode_tokens = 0
-        self._decode_time = 0.0
         # dead-work accounting: every decode step runs a lane's FULL slot
         # batch (static shapes); rows without an active slot are wasted.
         # utilization = tokens produced / rows executed
         self._decode_rows = 0
-        # per-phase accounting (seconds): host+vision prep, vision-tower
-        # share of prep, prefill programs (incl. shared-prefix builds),
-        # decode is _decode_time above. Feeds stage_timer caption phases.
+        # per-phase accounting (seconds), all of it through _phase(): self
+        # time per phase name, plus the elapsed time of the two roots (the
+        # stepping thread's `step`, the prep thread's `prep`). Feeds
+        # phase_seconds (stage_timer caption phases, the benchmark's
+        # per-layer metrics).
         # _stats_lock guards every counter '+=': the prep thread (prep /
         # vision / prefix-build counters) and the step thread (prefill /
         # decode counters) would otherwise lose updates racing on the same
@@ -390,9 +412,9 @@ class CaptionEngine:
         # _stats_lock is innermost and leaf-only: never acquire any other
         # engine lock while holding it.
         self._stats_lock = threading.Lock()
-        self._prep_time = 0.0
-        self._vision_time = 0.0
-        self._prefill_time = 0.0
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
+        self._phase_open = threading.local()  # .stack: open phases of one thread
         self._prefill_tokens = 0  # prompt tokens pushed through prefill
         self._vision_encodes = 0
         self._vision_reuses = 0
@@ -427,12 +449,9 @@ class CaptionEngine:
         # served by the paged programs (no gathered working set — the
         # structural assertion that the per-step copy is gone), bytes of
         # contiguous KV view the gather programs would have materialized
-        # and scattered back for the same calls, and the tight wall time of
-        # the decode program call + sync (same site both paths, so
-        # kernel-vs-gather step time is directly comparable)
+        # and scattered back for the same calls
         self._paged_kernel_steps = 0
         self._kv_gather_bytes_avoided = 0
-        self._decode_attn_time = 0.0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -822,6 +841,44 @@ class CaptionEngine:
                     continue
                 self.step()
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Time one phase, once, on both clocks: a ``TraceAnnotation``
+        ``engine.<name>`` (inert unless a profiler session runs) and the
+        host's monotonic clock. The counter gets the phase's SELF time —
+        elapsed less the elapsed of the phases nested in it on this thread —
+        so the phases under a root add up to the root's elapsed time."""
+        try:
+            stack = self._phase_open.stack
+        except AttributeError:
+            stack = self._phase_open.stack = []
+        stack.append(0.0)  # seconds of the phases nested in this one
+        with jax.profiler.TraceAnnotation("engine." + name):
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                elapsed = time.monotonic() - t0
+                nested = stack.pop()
+                if stack:
+                    stack[-1] += elapsed
+                with self._stats_lock:
+                    self._phase_s[name] += elapsed - nested
+                    if name in self._phase_elapsed_s:
+                        self._phase_elapsed_s[name] += elapsed
+
+    @property
+    def _decode_time(self) -> float:
+        # the decode program call + host sync: same site, both attention paths
+        return self._phase_s["decode_dispatch"] + self._phase_s["decode_wait"]
+
+    @property
+    def _prefill_time(self) -> float:
+        # prefill programs (incl. shared-prefix builds), their host sync and
+        # the first-token sampling that ends a prompt
+        p = self._phase_s
+        return p["prefill_dispatch"] + p["prefill_wait"] + p["prefill_sample"]
+
     @property
     def tokens_per_second(self) -> float:
         return self._decode_tokens / self._decode_time if self._decode_time > 0 else 0.0
@@ -944,7 +1001,7 @@ class CaptionEngine:
         kernel-vs-gather comparison the bench caption_attention section
         reports. (Also contained in phase decode_s, which this mirrors at
         the program-call granularity.)"""
-        return self._decode_attn_time
+        return self._decode_time
 
     @property
     def mesh_geometry(self) -> tuple:
@@ -970,7 +1027,7 @@ class CaptionEngine:
                 "kv_block_size_requested": self.block_size_requested,
                 "paged_kernel_steps": self._paged_kernel_steps,
                 "kv_gather_bytes_avoided": self._kv_gather_bytes_avoided,
-                "decode_attention_s": self._decode_attn_time,
+                "decode_attention_s": self._decode_time,
                 "decode_tokens": self._decode_tokens,
                 "decode_s": self._decode_time,
                 "prefill_tokens": self._prefill_tokens,
@@ -1046,17 +1103,26 @@ class CaptionEngine:
 
     @property
     def phase_seconds(self) -> dict[str, float]:
-        """Cumulative per-phase seconds: ``prep`` (host prep incl. the
+        """Cumulative per-phase seconds. ``prep`` (host prep incl. the
         vision share), ``vision_encode`` (vision-tower subset of prep),
-        ``prefill`` (prefill programs + host sync), ``decode`` (decode
-        steps + host sync). Wall minus (prefill + decode) over a drive
-        window is the engine's idle/stall time."""
-        return {
-            "prep_s": self._prep_time,
-            "vision_encode_s": self._vision_time,
-            "prefill_s": self._prefill_time,
-            "decode_s": self._decode_time,
-        }
+        ``prefill`` (prefill programs, host sync, first-token sampling) and
+        ``decode`` (decode programs + host sync) keep their meaning and are
+        derived from the phases of ``_phase()``: ``step_s`` is the time
+        inside ``step()``, and ``lock_wait``, ``admit``, ``prefill_*`` and
+        ``decode_*`` (``build``, ``dispatch``, ``wait``, ``sample``) and
+        ``step_other`` partition it (with ``prep_other`` and
+        ``vision_encode`` where prep runs inline; a shared-prefix build on
+        the prep thread adds to ``prefill_*`` from outside ``step()``).
+        Window minus ``step_s`` is the caller's stall; ``*_wait`` is the
+        stepping thread blocked on the device."""
+        with self._stats_lock:
+            out = {f"{k}_s": v for k, v in self._phase_s.items() if k not in _PHASE_ROOTS}
+            for root in _PHASE_ROOTS:
+                out[f"{root}_s"] = self._phase_elapsed_s[root]
+                out[f"{root}_other_s"] = self._phase_s[root]
+            out["prefill_s"] = self._prefill_time
+            out["decode_s"] = self._decode_time
+        return out
 
     def reset_stats(self) -> None:
         """Zero the throughput counters (e.g. after benchmark warmup) —
@@ -1064,11 +1130,9 @@ class CaptionEngine:
         cache CONTENTS survive (only the hit/miss counters reset)."""
         with self._stats_lock:
             self._decode_tokens = 0
-            self._decode_time = 0.0
             self._decode_rows = 0
-            self._prep_time = 0.0
-            self._vision_time = 0.0
-            self._prefill_time = 0.0
+            self._phase_s = dict.fromkeys(_PHASES, 0.0)
+            self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
             self._prefill_tokens = 0
             self._vision_encodes = 0
             self._vision_reuses = 0
@@ -1084,7 +1148,6 @@ class CaptionEngine:
             self._kv_cow_copies = 0
             self._paged_kernel_steps = 0
             self._kv_gather_bytes_avoided = 0
-            self._decode_attn_time = 0.0
             self._kv_blocks_used_peak = self._allocator.used_blocks
             self._interleaved_steps = 0
             self._owner_decode_tokens.clear()
@@ -1141,24 +1204,28 @@ class CaptionEngine:
         an idle engine prefills at full speed."""
         if not self._built:
             raise RuntimeError("call setup() first")
-        with self._work_cv:
-            self._admit()
-            # cross-job signal: this step's active slots span 2+ owners —
-            # several jobs are decoding in ONE continuous batch
-            step_owners = {
-                s.request.owner for l in self.lanes for s in l.slots.values()
-            }
-            if len(step_owners) > 1:
-                with self._stats_lock:
-                    self._interleaved_steps += 1
-            for lane in self.lanes:
-                if lane.pending:
-                    self._prefill_chunk_step(lane)
-                    while lane.pending and not any(l.slots for l in self.lanes):
+        with self._phase("step"), contextlib.ExitStack() as waiting:
+            waiting.enter_context(self._phase("lock_wait"))
+            with self._work_cv:
+                waiting.close()  # the lock is ours: lock_wait ends here
+                with self._phase("admit"):
+                    self._admit()
+                # cross-job signal: this step's active slots span 2+ owners
+                # — several jobs are decoding in ONE continuous batch
+                step_owners = {
+                    s.request.owner for l in self.lanes for s in l.slots.values()
+                }
+                if len(step_owners) > 1:
+                    with self._stats_lock:
+                        self._interleaved_steps += 1
+                for lane in self.lanes:
+                    if lane.pending:
                         self._prefill_chunk_step(lane)
-                if lane.slots:
-                    self._decode_once(lane)
-            self._work_cv.notify_all()  # ready-queue space may have freed
+                        while lane.pending and not any(l.slots for l in self.lanes):
+                            self._prefill_chunk_step(lane)
+                    if lane.slots:
+                        self._decode_once(lane)
+                self._work_cv.notify_all()  # ready-queue space may have freed
 
     # -- request prep (sync inline, or the background overlap thread) ---
     def _start_prep_thread(self) -> None:
@@ -1264,15 +1331,12 @@ class CaptionEngine:
         return self.waiting.pop(idx)
 
     def _safe_prepare(self, req: CaptionRequest) -> "_Prepared | None":
-        t0 = time.monotonic()
-        try:
-            return self._prepare(req)
-        except Exception:
-            logger.exception("prefill prep failed for %s; dropping", req.request_id)
-            return None
-        finally:
-            with self._stats_lock:
-                self._prep_time += time.monotonic() - t0
+        with self._phase("prep"):
+            try:
+                return self._prepare(req)
+            except Exception:
+                logger.exception("prefill prep failed for %s; dropping", req.request_id)
+                return None
 
     def _should_linger(self) -> bool:
         """True while admission should hold for the in-flight burst's prep:
@@ -1594,15 +1658,14 @@ class CaptionEngine:
                     self._vision_reuses += 1
             else:
                 frames, eff_fps = self._fit_frames_to_budget(req)
-                t0 = time.monotonic()
-                vis = self._encode_images(self.params, jnp.asarray(frames)[None])
-                if isinstance(vis, tuple):  # qwen3: (embeds, deepstack levels)
-                    vis, ds_levels = vis
-                    ds_vis = np.asarray(ds_levels[:, 0], np.float32)  # [L_ds, T_vis, D]
-                vis_embeds = vis[0]
-                jax.block_until_ready(vis_embeds)
+                with self._phase("vision_encode"):
+                    vis = self._encode_images(self.params, jnp.asarray(frames)[None])
+                    if isinstance(vis, tuple):  # qwen3: (embeds, deepstack levels)
+                        vis, ds_levels = vis
+                        ds_vis = np.asarray(ds_levels[:, 0], np.float32)  # [L_ds, T_vis, D]
+                    vis_embeds = vis[0]
+                    jax.block_until_ready(vis_embeds)
                 with self._stats_lock:
-                    self._vision_time += time.monotonic() - t0
                     self._vision_encodes += 1
                 if self.cfg.vision_variant in ("qwen2", "qwen3"):
                     grid_merged = self.cfg.qwen_vision.merged_grid(frames.shape[0])
@@ -1768,27 +1831,28 @@ class CaptionEngine:
             self._prefix_misses += 1
         tp = len(key)
         sp = next_pow2(tp)
-        emb = np.zeros((1, sp, self.cfg.dim), np.float32)
-        emb[0, :tp] = np.asarray(
-            self._embed_tokens(self.params, jnp.asarray(key, jnp.int32)[None])[0],
-            np.float32,
-        )
-        pos = np.zeros((1, sp), np.int32)
-        pos[0, :tp] = np.arange(tp, dtype=np.int32)
-        if self.cfg.mrope_section is not None:
-            # text prefix: all three m-rope components equal
-            pos = np.broadcast_to(pos[..., None], (1, sp, 3))
-        t0 = time.monotonic()
-        k, v = self._prefix_prefill(
-            self.params,
-            jnp.asarray(emb),
-            jnp.asarray(pos),
-            jnp.asarray(tp, jnp.int32),
-        )
-        k, v = k[:, :, :tp], v[:, :, :tp]
-        jax.block_until_ready(v)
+        with self._phase("prefill_build"):
+            emb = np.zeros((1, sp, self.cfg.dim), np.float32)
+            emb[0, :tp] = np.asarray(
+                self._embed_tokens(self.params, jnp.asarray(key, jnp.int32)[None])[0],
+                np.float32,
+            )
+            pos = np.zeros((1, sp), np.int32)
+            pos[0, :tp] = np.arange(tp, dtype=np.int32)
+            if self.cfg.mrope_section is not None:
+                # text prefix: all three m-rope components equal
+                pos = np.broadcast_to(pos[..., None], (1, sp, 3))
+        with self._phase("prefill_dispatch"):
+            k, v = self._prefix_prefill(
+                self.params,
+                jnp.asarray(emb),
+                jnp.asarray(pos),
+                jnp.asarray(tp, jnp.int32),
+            )
+            k, v = k[:, :, :tp], v[:, :, :tp]
+        with self._phase("prefill_wait"):
+            jax.block_until_ready(v)
         with self._stats_lock:
-            self._prefill_time += time.monotonic() - t0
             self._prefill_tokens += tp
         bs = self.block_size
         nb = -(-tp // bs)
@@ -2017,64 +2081,68 @@ class CaptionEngine:
         blocks ([base, base + bucket) can overshoot need): those positions
         map to garbage-block table entries, whose contents are never read
         unmasked."""
-        n = len(items)
-        n_pad = next_pow2(n)  # bounded by next_pow2(lane.n_slots)
-        dim = items[0][2].shape[-1]
-        embeds = np.zeros((n_pad, bucket, dim), np.float32)
-        slots_arr = np.zeros(n_pad, np.int32)
-        t_valids = np.ones(n_pad, np.int32)
-        bases = np.zeros(n_pad, np.int32)
-        mrope = self.cfg.mrope_section is not None
-        rope_shape = (n_pad, bucket, 3) if mrope else (n_pad, bucket)
-        rope_buf = np.zeros(rope_shape, np.int32)
-        ds_buf = (
-            np.zeros((self._ds_levels, n_pad, bucket, dim), np.float32)
-            if self._ds_levels
-            else None
-        )
-        for j, (slot_idx, _req, emb, t_valid, rope_pos, _next, ds, base) in enumerate(
-            items
-        ):
-            embeds[j, :t_valid] = np.asarray(emb, np.float32)[:t_valid]
-            slots_arr[j] = slot_idx
-            t_valids[j] = t_valid
-            bases[j] = base  # shared-prefix rows start past their cached K/V
-            rope_buf[j, :t_valid] = rope_pos[:t_valid]
-            if ds_buf is not None and ds is not None:
-                ds_buf[:, j, :t_valid] = ds[:, :t_valid]
-        for j in range(n, n_pad):  # duplicate row 0 into padding
-            embeds[j] = embeds[0]
-            slots_arr[j] = slots_arr[0]
-            t_valids[j] = t_valids[0]
-            bases[j] = bases[0]
-            rope_buf[j] = rope_buf[0]
-            if ds_buf is not None:
-                ds_buf[:, j] = ds_buf[:, 0]
-        t0 = time.monotonic()
-        tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
-        logits, self._pool_k, self._pool_v = self._prefill_batch(
-            self.params,
-            self._pool_k,
-            self._pool_v,
-            jnp.asarray(tables),
-            jnp.asarray(embeds),
-            jnp.asarray(bases),
-            jnp.asarray(t_valids),
-            jnp.asarray(rope_buf),
-            None if ds_buf is None else jnp.asarray(ds_buf),
-        )
-        logits_np = np.asarray(logits)  # one host sync for the whole group
-        with self._stats_lock:
-            self._prefill_time += time.monotonic() - t0
-            self._prefill_tokens += int(sum(it[3] for it in items))
-            if self._use_paged:
-                self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                    len(tables), lane.length
+        with self._phase("prefill_build"):
+            n = len(items)
+            n_pad = next_pow2(n)  # bounded by next_pow2(lane.n_slots)
+            dim = items[0][2].shape[-1]
+            embeds = np.zeros((n_pad, bucket, dim), np.float32)
+            slots_arr = np.zeros(n_pad, np.int32)
+            t_valids = np.ones(n_pad, np.int32)
+            bases = np.zeros(n_pad, np.int32)
+            mrope = self.cfg.mrope_section is not None
+            rope_shape = (n_pad, bucket, 3) if mrope else (n_pad, bucket)
+            rope_buf = np.zeros(rope_shape, np.int32)
+            ds_buf = (
+                np.zeros((self._ds_levels, n_pad, bucket, dim), np.float32)
+                if self._ds_levels
+                else None
+            )
+            for j, (slot_idx, _req, emb, t_valid, rope_pos, _next, ds, base) in enumerate(
+                items
+            ):
+                embeds[j, :t_valid] = np.asarray(emb, np.float32)[:t_valid]
+                slots_arr[j] = slot_idx
+                t_valids[j] = t_valid
+                bases[j] = base  # shared-prefix rows start past their cached K/V
+                rope_buf[j, :t_valid] = rope_pos[:t_valid]
+                if ds_buf is not None and ds is not None:
+                    ds_buf[:, j, :t_valid] = ds[:, :t_valid]
+            for j in range(n, n_pad):  # duplicate row 0 into padding
+                embeds[j] = embeds[0]
+                slots_arr[j] = slots_arr[0]
+                t_valids[j] = t_valids[0]
+                bases[j] = bases[0]
+                rope_buf[j] = rope_buf[0]
+                if ds_buf is not None:
+                    ds_buf[:, j] = ds_buf[:, 0]
+            tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
+        with self._phase("prefill_dispatch"):
+            logits, self._pool_k, self._pool_v = self._prefill_batch(
+                self.params,
+                self._pool_k,
+                self._pool_v,
+                jnp.asarray(tables),
+                jnp.asarray(embeds),
+                jnp.asarray(bases),
+                jnp.asarray(t_valids),
+                jnp.asarray(rope_buf),
+                None if ds_buf is None else jnp.asarray(ds_buf),
+            )
+        with self._phase("prefill_wait"):
+            logits_np = np.asarray(logits)  # one host sync for the whole group
+        with self._phase("prefill_sample"):
+            with self._stats_lock:
+                self._prefill_tokens += int(sum(it[3] for it in items))
+                if self._use_paged:
+                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
+                        len(tables), lane.length
+                    )
+            for j, (slot_idx, req, _emb, t_valid, _rope, next_rope, _ds, base) in enumerate(
+                items
+            ):
+                self._start_slot(
+                    lane, slot_idx, req, base + t_valid, next_rope, logits_np[j]
                 )
-        for j, (slot_idx, req, _emb, t_valid, _rope, next_rope, _ds, base) in enumerate(
-            items
-        ):
-            self._start_slot(lane, slot_idx, req, base + t_valid, next_rope, logits_np[j])
 
     def _start_slot(
         self,
@@ -2136,158 +2204,160 @@ class CaptionEngine:
         items = list(lane.pending.items())
         if not items:
             return
-        n = len(items)
-        n_pad = next_pow2(n)  # bounded by next_pow2(lane.n_slots)
-        dim = items[0][1].embeds.shape[-1]
-        mrope = self.cfg.mrope_section is not None
-        embeds = np.zeros((n_pad, C, dim), np.float32)
-        slots_arr = np.zeros(n_pad, np.int32)
-        write_idx = np.zeros(n_pad, np.int32)
-        chunk_valid = np.ones(n_pad, np.int32)
-        rope_buf = np.zeros((n_pad, C, 3) if mrope else (n_pad, C), np.int32)
-        ds_buf = (
-            np.zeros((self._ds_levels, n_pad, C, dim), np.float32)
-            if self._ds_levels
-            else None
-        )
-        new_tokens = 0
-        for j, (slot_idx, p) in enumerate(items):
-            take = min(C, p.t_valid - p.progress)
-            start = p.progress
-            if take < C:
-                # final partial chunk: shift back so the C-length buffer
-                # ends exactly at the prompt end. The overlapped rows
-                # rewrite identical K/V (same embeds, same rope, correct
-                # causal mask), and dynamic_update_slice stays in bounds
-                # for shared-prefix bases > 0 and for lane lengths that are
-                # not a multiple of the chunk size.
-                start = p.t_valid - C
-            new_tokens += take
-            embeds[j] = p.embeds[start : start + C]
-            slots_arr[j] = slot_idx
-            write_idx[j] = p.base + start
-            chunk_valid[j] = C if start < p.progress else take
-            rope_buf[j] = p.rope_pos[start : start + C]
-            if ds_buf is not None and p.ds is not None:
-                ds_buf[:, j] = p.ds[:, start : start + C]
-        for j in range(n, n_pad):  # duplicate row 0 (identical writes: safe)
-            embeds[j] = embeds[0]
-            slots_arr[j] = slots_arr[0]
-            write_idx[j] = write_idx[0]
-            chunk_valid[j] = chunk_valid[0]
-            rope_buf[j] = rope_buf[0]
-            if ds_buf is not None:
-                ds_buf[:, j] = ds_buf[:, 0]
-        t0 = time.monotonic()
-        tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
-        logits, self._pool_k, self._pool_v = self._prefill_batch(
-            self.params,
-            self._pool_k,
-            self._pool_v,
-            jnp.asarray(tables),
-            jnp.asarray(embeds),
-            jnp.asarray(write_idx),
-            jnp.asarray(chunk_valid),
-            jnp.asarray(rope_buf),
-            None if ds_buf is None else jnp.asarray(ds_buf),
-        )
+        with self._phase("prefill_build"):
+            n = len(items)
+            n_pad = next_pow2(n)  # bounded by next_pow2(lane.n_slots)
+            dim = items[0][1].embeds.shape[-1]
+            mrope = self.cfg.mrope_section is not None
+            embeds = np.zeros((n_pad, C, dim), np.float32)
+            slots_arr = np.zeros(n_pad, np.int32)
+            write_idx = np.zeros(n_pad, np.int32)
+            chunk_valid = np.ones(n_pad, np.int32)
+            rope_buf = np.zeros((n_pad, C, 3) if mrope else (n_pad, C), np.int32)
+            ds_buf = (
+                np.zeros((self._ds_levels, n_pad, C, dim), np.float32)
+                if self._ds_levels
+                else None
+            )
+            new_tokens = 0
+            for j, (slot_idx, p) in enumerate(items):
+                take = min(C, p.t_valid - p.progress)
+                start = p.progress
+                if take < C:
+                    # final partial chunk: shift back so the C-length buffer
+                    # ends exactly at the prompt end. The overlapped rows
+                    # rewrite identical K/V (same embeds, same rope, correct
+                    # causal mask), and dynamic_update_slice stays in bounds
+                    # for shared-prefix bases > 0 and for lane lengths that are
+                    # not a multiple of the chunk size.
+                    start = p.t_valid - C
+                new_tokens += take
+                embeds[j] = p.embeds[start : start + C]
+                slots_arr[j] = slot_idx
+                write_idx[j] = p.base + start
+                chunk_valid[j] = C if start < p.progress else take
+                rope_buf[j] = p.rope_pos[start : start + C]
+                if ds_buf is not None and p.ds is not None:
+                    ds_buf[:, j] = p.ds[:, start : start + C]
+            for j in range(n, n_pad):  # duplicate row 0 (identical writes: safe)
+                embeds[j] = embeds[0]
+                slots_arr[j] = slots_arr[0]
+                write_idx[j] = write_idx[0]
+                chunk_valid[j] = chunk_valid[0]
+                rope_buf[j] = rope_buf[0]
+                if ds_buf is not None:
+                    ds_buf[:, j] = ds_buf[:, 0]
+            tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
+        with self._phase("prefill_dispatch"):
+            logits, self._pool_k, self._pool_v = self._prefill_batch(
+                self.params,
+                self._pool_k,
+                self._pool_v,
+                jnp.asarray(tables),
+                jnp.asarray(embeds),
+                jnp.asarray(write_idx),
+                jnp.asarray(chunk_valid),
+                jnp.asarray(rope_buf),
+                None if ds_buf is None else jnp.asarray(ds_buf),
+            )
         finished = []
         for j, (slot_idx, p) in enumerate(items):
             p.progress += min(C, p.t_valid - p.progress)
             if p.progress >= p.t_valid:
                 finished.append((j, slot_idx, p))
-        if finished:
-            logits_np = np.asarray(logits)
+        if finished:  # no finished row: nothing to read back, no host sync
+            with self._phase("prefill_wait"):
+                logits_np = np.asarray(logits)
+        with self._phase("prefill_sample"):
             for j, slot_idx, p in finished:
                 del lane.pending[slot_idx]
                 self._start_slot(
                     lane, slot_idx, p.request, p.base + p.t_valid, p.next_rope,
                     logits_np[j],
                 )
-        with self._stats_lock:
-            self._prefill_time += time.monotonic() - t0
-            self._prefill_tokens += new_tokens
-            if self._use_paged:
-                self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                    len(tables), lane.length
-                )
+            with self._stats_lock:
+                self._prefill_tokens += new_tokens
+                if self._use_paged:
+                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
+                        len(tables), lane.length
+                    )
 
     # holds-lock: _lock
     def _decode_once(self, lane: _Lane) -> None:
-        tokens = np.full(lane.n_slots, self.tokenizer.pad_id, np.int32)
-        positions = np.zeros(lane.n_slots, np.int32)
-        rope_positions = np.zeros(lane.n_slots, np.int32)
-        # The decode program scatters K/V for EVERY row (static shapes, no
-        # write mask), so idle rows' write positions must be harmless.
-        # Fully-free rows carry an all-garbage block table — their write
-        # lands in the reserved garbage block — but a row mid-chunked-
-        # prefill holds real prompt K/V: point its write at base +
-        # progress, a cell the NEXT chunk overwrites anyway (the shifted
-        # final chunk covers [t_valid - C, t_valid), which contains it), so
-        # the pad-token garbage can never survive into attention reads.
-        for i, p in lane.pending.items():
-            positions[i] = p.base + p.progress
-        for i, slot in lane.slots.items():
-            tokens[i] = slot.generated[-1]
-            positions[i] = slot.position
-            rope_positions[i] = slot.rope_position
-        t0 = time.monotonic()
-        greedy, logits, self._pool_k, self._pool_v = self._decode(
-            self.params,
-            self._pool_k,
-            self._pool_v,
-            jnp.asarray(lane.table),
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(rope_positions),
-        )
-        greedy_np = np.asarray(greedy)  # ONE host sync for the whole batch
-        dt = time.monotonic() - t0  # program call + sync: same site, both paths
-        with self._stats_lock:
-            self._decode_time += dt
-            self._decode_attn_time += dt
-            self._decode_tokens += len(lane.slots)
-            self._decode_rows += lane.n_slots
-            if self._use_paged:
-                self._paged_kernel_steps += 1
-                self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                    lane.n_slots, lane.length
-                )
-            for slot in lane.slots.values():
-                owner = slot.request.owner
-                self._owner_decode_tokens[owner] = (
-                    self._owner_decode_tokens.get(owner, 0) + 1
-                )
-        # the device argmax suffices only for pure-greedy rows with no
-        # penalties and min_tokens already satisfied
-        needs_logits = any(
-            s.request.sampling.needs_logits(len(s.generated))
-            for s in lane.slots.values()
-        )
-        logits_np = np.asarray(logits) if needs_logits else None
-        for i in list(lane.slots):
-            slot = lane.slots[i]
-            if slot.request.sampling.needs_logits(len(slot.generated)):
-                nxt = sample_token(
-                    logits_np[i],
-                    slot.request.sampling,
-                    # incrementally maintained prompt+output counts; the
-                    # decode loop must not re-unique the history per token
-                    generated=slot.penalty_counts,
-                    num_generated=len(slot.generated),
-                    eos_id=self.tokenizer.eos_id,
-                    rng=slot.rng if slot.rng is not None else self._host_rng,
-                )
-            else:
-                nxt = int(greedy_np[i])
-            slot.generated.append(nxt)
-            if slot.penalty_counts is not None:
-                slot.penalty_counts[nxt] = slot.penalty_counts.get(nxt, 0) + 1
-            if slot.request.sampling.stop:
-                slot.raw += self.tokenizer.decode_bytes([nxt])
-            slot.position += 1
-            slot.rope_position += 1
-            self._maybe_finish(lane, i, slot)
+        with self._phase("decode_build"):
+            tokens = np.full(lane.n_slots, self.tokenizer.pad_id, np.int32)
+            positions = np.zeros(lane.n_slots, np.int32)
+            rope_positions = np.zeros(lane.n_slots, np.int32)
+            # The decode program scatters K/V for EVERY row (static shapes, no
+            # write mask), so idle rows' write positions must be harmless.
+            # Fully-free rows carry an all-garbage block table — their write
+            # lands in the reserved garbage block — but a row mid-chunked-
+            # prefill holds real prompt K/V: point its write at base +
+            # progress, a cell the NEXT chunk overwrites anyway (the shifted
+            # final chunk covers [t_valid - C, t_valid), which contains it), so
+            # the pad-token garbage can never survive into attention reads.
+            for i, p in lane.pending.items():
+                positions[i] = p.base + p.progress
+            for i, slot in lane.slots.items():
+                tokens[i] = slot.generated[-1]
+                positions[i] = slot.position
+                rope_positions[i] = slot.rope_position
+        with self._phase("decode_dispatch"):
+            greedy, logits, self._pool_k, self._pool_v = self._decode(
+                self.params,
+                self._pool_k,
+                self._pool_v,
+                jnp.asarray(lane.table),
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(rope_positions),
+            )
+        with self._phase("decode_wait"):
+            greedy_np = np.asarray(greedy)  # ONE host sync for the whole batch
+        with self._phase("decode_sample"):
+            with self._stats_lock:
+                self._decode_tokens += len(lane.slots)
+                self._decode_rows += lane.n_slots
+                if self._use_paged:
+                    self._paged_kernel_steps += 1
+                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
+                        lane.n_slots, lane.length
+                    )
+                for slot in lane.slots.values():
+                    owner = slot.request.owner
+                    self._owner_decode_tokens[owner] = (
+                        self._owner_decode_tokens.get(owner, 0) + 1
+                    )
+            # the device argmax suffices only for pure-greedy rows with no
+            # penalties and min_tokens already satisfied
+            needs_logits = any(
+                s.request.sampling.needs_logits(len(s.generated))
+                for s in lane.slots.values()
+            )
+            logits_np = np.asarray(logits) if needs_logits else None
+            for i in list(lane.slots):
+                slot = lane.slots[i]
+                if slot.request.sampling.needs_logits(len(slot.generated)):
+                    nxt = sample_token(
+                        logits_np[i],
+                        slot.request.sampling,
+                        # incrementally maintained prompt+output counts; the
+                        # decode loop must not re-unique the history per token
+                        generated=slot.penalty_counts,
+                        num_generated=len(slot.generated),
+                        eos_id=self.tokenizer.eos_id,
+                        rng=slot.rng if slot.rng is not None else self._host_rng,
+                    )
+                else:
+                    nxt = int(greedy_np[i])
+                slot.generated.append(nxt)
+                if slot.penalty_counts is not None:
+                    slot.penalty_counts[nxt] = slot.penalty_counts.get(nxt, 0) + 1
+                if slot.request.sampling.stop:
+                    slot.raw += self.tokenizer.decode_bytes([nxt])
+                slot.position += 1
+                slot.rope_position += 1
+                self._maybe_finish(lane, i, slot)
 
     def _maybe_finish(self, lane: _Lane, slot_idx: int, slot: _Slot) -> None:
         req = slot.request

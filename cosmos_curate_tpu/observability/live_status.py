@@ -29,6 +29,7 @@ detector's opinion for free.
 from __future__ import annotations
 
 import json
+import math
 import os
 import time
 from pathlib import Path
@@ -137,7 +138,7 @@ class LiveStatusPublisher:
             detector = AnomalyDetector()
         self.detector = detector
         self.seq = 0
-        self._last_publish = 0.0
+        self._last_publish = -math.inf  # the first maybe_publish always publishes
         self._started = time.time()
         self._warned = False
 

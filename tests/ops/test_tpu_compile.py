@@ -11,6 +11,7 @@ here. Nothing runs — results are ``chip_smoke.py``'s business.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,3 +115,29 @@ def test_kernel_compiles_for_v5e(v5e, kernel, widths):
     fn, args = KERNELS[kernel](*WIDTHS[widths], arg)
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_prefill"])
+def test_custom_call_is_named_as_the_benchmark_expects(v5e, kernel):
+    """A traced benchmark run finds the paged kernels among the device's
+    operations by the instruction name of their ``tpu_custom_call``: the name
+    of the jitted function that wraps the ``pallas_call``. Renaming a wrapper
+    fails here, on the CPU, and not in a traced run on the chip. (In this
+    file, not beside the harness: one file describes the chip, see above.)"""
+    from perfbench import trace_reduce
+    from perfbench.drivers.caption_engine import KERNELS as TRACE_PATTERNS
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = KERNELS[kernel](*WIDTHS["qwen2vl-2b"], arg)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [
+        trace_reduce.instruction(line.strip())  # as the reducer reads an event's name
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert calls, "the compiled program holds no tpu_custom_call"
+    assert all(re.search(TRACE_PATTERNS[kernel], name) for name in calls), calls
+    others = [rx for key, rx in TRACE_PATTERNS.items() if key != kernel]
+    assert not any(re.search(rx, name) for rx in others for name in calls)
