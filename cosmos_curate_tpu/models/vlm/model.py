@@ -635,7 +635,7 @@ class DecoderLayer(nn.Module):
             # performs — positions past t_valid land in-table and carry
             # identical garbage both ways), then attend straight out of the
             # pool. No gathered view, no scatter-back.
-            from cosmos_curate_tpu.models.vlm.paged_kv import paged_head_update
+            from cosmos_curate_tpu.models.vlm.paged_kv import paged_head_update, paged_update
             from cosmos_curate_tpu.ops.paged_attention import (
                 paged_attention,
                 paged_head_attention,
@@ -649,14 +649,10 @@ class DecoderLayer(nn.Module):
                     layer_index=layer_index,
                 )
             else:
-                bs_blk = cache_k.shape[3]
-                pos = write_index[:, None] + jnp.arange(t)[None, :]  # [B, T]
-                blk = jnp.take_along_axis(block_tables, pos // bs_blk, axis=1)
-                off = pos % bs_blk
-                # [B, T] index pairs around the head slice: each token
-                # writes its [Hkv, Dh] slab, one row per head plane
-                new_k = cache_k.at[layer_index, blk, :, off].set(k.astype(cache_k.dtype))
-                new_v = cache_v.at[layer_index, blk, :, off].set(v.astype(cache_v.dtype))
+                new_k, new_v = paged_update(
+                    cache_k, cache_v, k, v, block_tables, write_index,
+                    layer_index=layer_index,
+                )
             qk = q.reshape(b, t, hk, group, dh)
             if head_parallel:
                 attn = paged_head_attention(

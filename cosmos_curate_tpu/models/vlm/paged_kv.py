@@ -4,21 +4,39 @@ vLLM's PagedAttention block-table design (Kwon et al. 2023 — PAPERS.md)
 re-shaped for XLA's static-shape compilation: KV memory is ONE block pool
 ``[L, n_blocks, Hkv, block_size, Dh]`` and every slot owns a block *table*
 instead of a worst-case-length cache row, so a request's KV footprint is
-``ceil(len / block_size)`` blocks. Rather than a dynamic per-read gather
-inside the attention kernel (hostile to XLA), the engine's prefill/decode
-programs gather each slot's blocks into a contiguous ``[lane_length]`` view
-— the exact shapes the slot-row engine compiled, so greedy outputs stay
-byte-identical — run the unchanged model, and scatter the written blocks
-back.
+``ceil(len / block_size)`` blocks. The engine has two sets of programs over
+that pool (``paged_attention=``). The default (``"auto"``/``"kernel"``)
+writes a chunk's K/V through the block table (:func:`paged_update`) and
+attends straight out of the pool (ops/paged_attention.py): no contiguous
+working set exists. The other, ``"gather"``, is the parity fallback: it
+gathers each slot's blocks into a contiguous ``[lane_length]`` view
+(:func:`gather_block_views`) — the exact shapes the slot-row engine
+compiled, so greedy outputs stay byte-identical — runs the unchanged model,
+and scatters the written blocks back (:func:`scatter_block_views`).
+
+The layout contract between the write and the kernels: the pool has ONE
+device layout from a program's entry to its exit, the one the paged
+kernels' operand demands — row-major ``[L, NB, Hkv, bs, Dh]`` with
+``(bs, Dh)`` tiled, because a kernel block is one head's ``[bs, Dh]`` page
+(``BlockSpec((None, None, None, bs, Dh))``). XLA chooses a scatter's layout
+from its update window: a window that spans ``[Hkv, Dh]`` (the head left as
+a slice, as this write was first phrased) makes those two dimensions minor,
+and every layer then pays a relayout ``copy`` of the whole K and V pool in
+front of its kernel call (2 x 28 x 1.13 ms of an 85 ms Qwen2-VL-2B decode
+step: PERF.md, PR 25). :func:`paged_update` therefore indexes every pool
+dimension but ``Dh``, so an update is one ``[Dh]`` row and the donated pool
+is updated in place; ``tests/ops/test_tpu_compile.py`` compiles write +
+kernel for a described chip and fails on a pool-shaped copy.
 
 Why duplicate scatter indices are safe: shared-prefix blocks appear in MANY
 slots' tables at once (that is the point — zero device copies at
-admission). The scatter that writes views back therefore writes the same
+admission). The gather programs' scatter-back therefore writes the same
 block several times, and XLA leaves the winning order undefined. The
 engine's invariant makes every such write identical: a slot's own K/V
 writes always start at the prefix boundary (copy-on-write gives it a
 private copy of any partially-filled shared tail block first), so shared
-blocks are only ever written back with their unchanged gathered contents.
+blocks are only ever written back with their unchanged gathered contents
+(and :func:`paged_update` never touches them).
 Block 0 is a reserved garbage block: free table entries point at it and
 the decode program's unconditional writes for idle rows land there — its
 contents are never read unmasked.
@@ -31,6 +49,8 @@ in-flight slots defers the free instead of corrupting them.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax.numpy as jnp
 
@@ -150,18 +170,36 @@ def scatter_block_views(pool_k, pool_v, tables, view_k, view_v):
     return pool_k.at[:, tables].set(bk), pool_v.at[:, tables].set(bv)
 
 
-def paged_head_update(mesh, pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
-    """Head-parallel scatter of a chunk's K/V into the pool over the model
-    mesh axis: the pools and the chunk shard on their ``Hkv`` dimension
-    (each shard writes its own head plane), block tables and positions
-    replicate. The positional math is identical to the unsharded layer
-    scatter, so an extent-1 model axis is bit-equal to it. Accepts an
-    ``AbstractMesh`` so shardcheck's ``vlm-paged-head-scatter`` contract
-    traces this call site device-free (analysis/shard_check.py).
+def paged_update(pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
+    """Write a chunk's K/V into the block pools through the block table.
 
     pool_k/v: ``[L, NB, Hkv, bs, Dh]``; k/v: ``[B, T, Hkv, Dh]`` (the
     chunk, rope already applied); tables: ``[B, nbl]``; write_index:
-    ``[B]``. Returns the updated pools."""
+    ``[B]`` (token ``t`` of row ``b`` lands at logical position
+    ``write_index[b] + t``). Returns the updated pools.
+
+    Every pool dimension but ``Dh`` is indexed, the head too, so one
+    update is a ``[Dh]`` row and XLA's scatter keeps the pool in the paged
+    kernels' operand layout (the module docstring's layout contract). No
+    ``unique_indices``: idle rows collide in block 0 by design."""
+    bs = pool_k.shape[3]
+    pos = write_index[:, None] + jnp.arange(k.shape[1])[None, :]  # [B, T]
+    blk = jnp.take_along_axis(tables, pos // bs, axis=1)[:, :, None]
+    off = (pos % bs)[:, :, None]
+    head = jnp.arange(pool_k.shape[2])[None, None, :]  # [1, 1, Hkv]
+    new_k = pool_k.at[layer_index, blk, head, off].set(k.astype(pool_k.dtype))
+    new_v = pool_v.at[layer_index, blk, head, off].set(v.astype(pool_v.dtype))
+    return new_k, new_v
+
+
+def paged_head_update(mesh, pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
+    """:func:`paged_update` head-parallel over the model mesh axis: the
+    pools and the chunk shard on their ``Hkv`` dimension (each shard writes
+    its own head plane), block tables and positions replicate. It is the
+    same function under a ``shard_map``, so an extent-1 model axis is
+    bit-equal to it. Accepts an ``AbstractMesh`` so shardcheck's
+    ``vlm-paged-head-scatter`` contract traces this call site device-free
+    (analysis/shard_check.py)."""
     from jax.sharding import PartitionSpec as P
 
     from cosmos_curate_tpu.parallel.axes import MODEL
@@ -170,19 +208,8 @@ def paged_head_update(mesh, pool_k, pool_v, k, v, tables, write_index, *, layer_
     axis = MODEL if MODEL in mesh.axis_names else None
     pspec = P(None, None, axis, None, None)
     kspec = P(None, None, axis, None)
-
-    def _update(pk, pv, k_, v_, tbl, wi):
-        bs = pk.shape[3]
-        t = k_.shape[1]
-        pos = wi[:, None] + jnp.arange(t)[None, :]  # [B, T]
-        blk = jnp.take_along_axis(tbl, pos // bs, axis=1)
-        off = pos % bs
-        npk = pk.at[layer_index, blk, :, off].set(k_.astype(pk.dtype))
-        npv = pv.at[layer_index, blk, :, off].set(v_.astype(pv.dtype))
-        return npk, npv
-
     return shard_map(
-        _update,
+        functools.partial(paged_update, layer_index=layer_index),
         mesh=mesh,
         in_specs=(pspec, pspec, kspec, kspec, P(None, None), P(None)),
         out_specs=(pspec, pspec),

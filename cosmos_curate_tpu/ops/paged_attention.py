@@ -19,6 +19,16 @@ and scattered it back. This op deletes that copy:
   beyond the chunk's last causal position) are skipped with ``pl.when`` —
   fragmented tables cost nothing extra.
 
+The kernels' ``BlockSpec`` for K and V is one head's page, ``(None, None,
+None, bs, D)`` of the pool ``[L, NB, Hkv, bs, D]``: that pins the pool
+operand to the row-major layout with ``(bs, D)`` tiled. Whatever produces
+the pool inside the same program must leave it in that layout, or XLA puts
+a relayout ``copy`` of the whole pool in front of every call: the write,
+``models/vlm/paged_kv.paged_update``, keeps its side of the contract by
+indexing every dimension but ``D`` (its module docstring; pinned by
+``tests/ops/test_tpu_compile.py``). A change to the page's block shape here
+is a change to that contract.
+
 Off-TPU the default is NOT interpret-mode Pallas but a ``jax.lax``
 reference that mirrors ``DecoderLayer``'s XLA attention lines exactly
 (same einsums, same mask construction, same fp32 softmax), so the engine's

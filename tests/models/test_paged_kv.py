@@ -560,3 +560,81 @@ class TestCrossJobInterleave:
         got = {r.request_id for r in eng.run_until_complete(owner="flood")}
         assert got == {f"f{i}" for i in range(6)}
         assert {r.request_id for r in eng.run_until_complete(owner="late")} == {"late"}
+
+
+class TestPagedUpdate:
+    """``paged_update`` is the one write of a chunk's K/V into the pool
+    (PR 25): the same values in the same cells as the expression it
+    replaced, whose update window spanned ``[Hkv, Dh]`` and so cost a
+    relayout of the whole pool before every paged kernel call."""
+
+    L, NB, HK, BS, D, NBL = 2, 64, 2, 16, 8, 20
+
+    @staticmethod
+    def _old_write(pool, chunk, tables, write_index, layer_index):
+        bs = pool.shape[3]
+        pos = write_index[:, None] + jnp.arange(chunk.shape[1])[None, :]
+        blk = jnp.take_along_axis(tables, pos // bs, axis=1)
+        return pool.at[layer_index, blk, :, pos % bs].set(chunk.astype(pool.dtype))
+
+    def _case(self, t, seed=0):
+        """Four rows: two live rows that share their first (prefix) block
+        and write behind it, from mid-block; two idle rows whose tables
+        are all block 0 and whose writes collide there."""
+        rng = np.random.default_rng(seed)
+        shape = (self.L, self.NB, self.HK, self.BS, self.D)
+        pool_k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        pool_v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        own = rng.permutation(np.arange(2, self.NB))[: 2 * (self.NBL - 1)].reshape(2, -1)
+        tables = np.zeros((4, self.NBL), np.int32)
+        tables[:2, 0] = 1  # the shared prefix block
+        tables[:2, 1:] = own
+        write_index = np.array([self.BS + 5, self.BS, 0, 0], np.int32)
+        k = jnp.asarray(rng.standard_normal((4, t, self.HK, self.D)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((4, t, self.HK, self.D)), jnp.float32)
+        return pool_k, pool_v, k, v, jnp.asarray(tables), jnp.asarray(write_index)
+
+    @pytest.mark.parametrize("t", [1, 256])
+    @pytest.mark.parametrize("which", ["k", "v"])
+    def test_same_cells_as_the_old_expression(self, t, which):
+        from cosmos_curate_tpu.models.vlm.paged_kv import paged_update
+
+        pool_k, pool_v, k, v, tables, write_index = self._case(t)
+        new_k, new_v = paged_update(pool_k, pool_v, k, v, tables, write_index, layer_index=1)
+        pool, chunk, new = (pool_k, k, new_k) if which == "k" else (pool_v, v, new_v)
+        got = np.asarray(new.astype(jnp.float32))
+        want = np.asarray(
+            self._old_write(pool, chunk, tables, write_index, 1).astype(jnp.float32)
+        )
+        # block 0 is the garbage block: colliding writes, undefined winner
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        before = np.asarray(pool.astype(jnp.float32))
+        np.testing.assert_array_equal(got[0], before[0])  # the other layer
+        # and nothing but the 2 * t cells the two live rows own has changed
+        pos = np.asarray(write_index)[:2, None] + np.arange(t)[None, :]
+        blk = np.take_along_axis(np.asarray(tables)[:2], pos // self.BS, axis=1)
+        written = np.zeros((self.NB, self.BS), bool)
+        written[blk, pos % self.BS] = True
+        assert written[2:].sum() == 2 * t and not written[:2].any()
+        written[0] = True  # the idle rows' garbage
+        np.testing.assert_array_equal(
+            got[1].swapaxes(1, 2)[~written], before[1].swapaxes(1, 2)[~written]
+        )
+
+    @pytest.mark.parametrize("t", [1, 256])
+    @pytest.mark.parametrize("extent", [1, 2])
+    def test_head_update_is_the_same_function_under_a_shard_map(self, t, extent):
+        """Extent 1 is the docstring's promise (bit-equal to the unsharded
+        write); extent 2 gives each shard one head plane of the two."""
+        from jax.sharding import Mesh
+
+        from cosmos_curate_tpu.models.vlm.paged_kv import paged_head_update, paged_update
+
+        args = self._case(t, seed=extent)
+        mesh = Mesh(np.array(jax.devices()[:extent]), axis_names=("model",))
+        want = paged_update(*args, layer_index=1)
+        got = jax.jit(partial(paged_head_update, mesh, layer_index=1))(*args)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g[:, 1:].astype(jnp.float32)), np.asarray(w[:, 1:].astype(jnp.float32))
+            )
