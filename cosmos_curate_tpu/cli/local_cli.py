@@ -16,6 +16,7 @@ import sys
 CAPTION_MODEL_CHOICES = (
     "base",
     "qwen25vl-7b",
+    "qwen25vl-tiny-test",
     "qwen2vl-2b",
     "qwen3moe-a3b-lm",
     "qwen3vl-moe-a3b",
@@ -84,7 +85,11 @@ def register(sub: argparse._SubParsersAction) -> None:
         "--caption-model",
         default="base",
         choices=CAPTION_MODEL_CHOICES,
-        help="VLM flavor for every caption-family stage",
+        help="VLM flavor for every caption-family stage. qwen25vl-7b (and its "
+        "test-size stand-in qwen25vl-tiny-test) is served over a 'model' mesh of 4 "
+        "chips of this host, built by the stage; every other flavor takes one chip. "
+        "With fewer chips than the flavor needs, setup fails and says how many it "
+        "needs and found",
     )
     split.add_argument("--enhance-captions", action="store_true")
     split.add_argument("--t5-embeddings", action="store_true")

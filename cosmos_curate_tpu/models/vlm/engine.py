@@ -287,6 +287,18 @@ _PHASES = _PHASE_ROOTS + (
 )
 
 
+def _bytes_per_chip(tree) -> int:
+    """Bytes of ``tree`` resident on one device: a partitioned leaf counts
+    one shard, a repeated (or unplaced) leaf its whole. Read off the
+    arrays' shardings — no device is asked anything."""
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        sharding = getattr(leaf, "sharding", None)
+        shape = sharding.shard_shape(leaf.shape) if sharding is not None else leaf.shape
+        total += int(np.prod(shape)) * leaf.dtype.itemsize
+    return total
+
+
 class CaptionEngine:
     def __init__(
         self,
@@ -391,6 +403,7 @@ class CaptionEngine:
         self._allocator = BlockAllocator(self.kv_pool_blocks)
         self._pool_k = None
         self._pool_v = None
+        self._kv_pool_bytes_per_chip = 0  # until setup() makes the pool
         self.completed: list[CaptionResult] = []
         self._decode_tokens = 0
         # dead-work accounting: every decode step runs a lane's FULL slot
@@ -508,6 +521,7 @@ class CaptionEngine:
 
             value = place_partitioned(self.mesh, value, self._param_specs)
         self._params = value
+        self._param_bytes_per_chip = _bytes_per_chip(value)
 
     # read-only aggregate views over the lanes (public slot id = lane.base
     # + lane-local index, unique across lanes)
@@ -543,10 +557,11 @@ class CaptionEngine:
                 method=self.model.init_everything,
             )
 
-        if self.params is None:
-            self.params = init()
         pool_sharding = None
-        if self.mesh is not None:
+        if self.mesh is None:
+            if self.params is None:
+                self.params = init()
+        else:
             # Place everything ONCE, here: parameters by the model's
             # nn.with_partitioning annotations (read off an abstract init,
             # so a loaded checkpoint's plain tree places the same way), the
@@ -560,11 +575,22 @@ class CaptionEngine:
             from cosmos_curate_tpu.parallel.sharding import spec_sharding
 
             self._param_specs = nn.get_partition_spec(jax.eval_shape(init))
+            if self._params is None:
+                # made split: a flavor served over a mesh need not fit one chip
+                shardings = jax.tree.map(
+                    lambda spec: spec_sharding(self.mesh, spec),
+                    self._param_specs,
+                    is_leaf=lambda x: isinstance(x, P),
+                )
+                self._params = jax.jit(
+                    lambda: nn.unbox(init()), out_shardings=shardings
+                )()
             self.params = self._params  # the setter places
             pool_sharding = spec_sharding(self.mesh, P(None, None, MODEL, None, None))
         self._pool_k, self._pool_v = init_block_pool(
             cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
         )
+        self._kv_pool_bytes_per_chip = _bytes_per_chip((self._pool_k, self._pool_v))
 
         model = self.model
         bs = self.block_size
@@ -1023,6 +1049,10 @@ class CaptionEngine:
             return {
                 "paged_attention": self.paged_attention,
                 "mesh_geometry": self.mesh_geometry,
+                # what ONE chip of the mesh holds: a quarter of what is
+                # partitioned over model=4, the whole of what is repeated
+                "param_bytes_per_chip": self._param_bytes_per_chip,
+                "kv_pool_bytes_per_chip": self._kv_pool_bytes_per_chip,
                 "kv_block_size": self.block_size,
                 "kv_block_size_requested": self.block_size_requested,
                 "paged_kernel_steps": self._paged_kernel_steps,

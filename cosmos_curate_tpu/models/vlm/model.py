@@ -32,6 +32,7 @@ from cosmos_curate_tpu.models.vlm.vision_qwen import (
     QWEN25_VL_7B_VISION,
     QWEN3_VL_MOE_VISION,
     QWEN3_VISION_TINY_TEST,
+    QWEN25_VISION_TINY_TEST,
     QWEN_VISION_TINY_TEST,
     QwenVisionConfig,
     QwenVisionTower,
@@ -255,6 +256,18 @@ class FlavorSpec:
     # worst-case-length pool). Chosen per checkpoint size so the
     # production caption stage runs laned by default.
     kv_lanes: tuple[tuple[int, int], ...] | None = None
+    # Chips of one host the flavor's parameters and KV pool are split over
+    # (the extent of the ``model`` mesh axis the caption stage builds at
+    # setup); 1 = the whole model on one chip, no mesh. Travels with the
+    # checkpoint choice as the lanes do: a 7B in float32 fits no 16 GB chip.
+    model_chips: int = 1
+
+    def __post_init__(self) -> None:
+        if self.model_chips < 1 or self.cfg.n_kv_heads % self.model_chips:
+            raise ValueError(
+                f"{self.model_id}: model_chips={self.model_chips} does not divide "
+                f"n_kv_heads={self.cfg.n_kv_heads} (the KV pool is split by head planes)"
+            )
 
 
 VLM_FLAVORS: dict[str, FlavorSpec] = {}
@@ -282,6 +295,23 @@ VLM_QWEN2VL_TINY_TEST = VLMConfig(
     vision_variant="qwen2",
     qwen_vision=QWEN_VISION_TINY_TEST,
     mrope_section=(2, 3, 3),
+)
+# Qwen2.5-VL-7B's serving shape at test size: windowed tower, qkv bias,
+# untied head, and 4 KV heads so that a model=4 mesh gets a head plane a chip
+VLM_QWEN25VL_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=2,
+    n_heads=8,
+    n_kv_heads=4,
+    head_dim=16,
+    max_seq=128,
+    qkv_bias=True,
+    vision=VIT_TINY_TEST,
+    vision_variant="qwen2",
+    qwen_vision=QWEN25_VISION_TINY_TEST,
+    mrope_section=(2, 3, 3),
+    tied_embeddings=False,
 )
 # chat-template prompts in byte-level test tokens run ~170 ids — the
 # hf_chat test flavor needs the extra context
@@ -329,8 +359,19 @@ VLM_FLAVORS.update(
             hf_chat=True,
             # 7B KV rows are 4x the 2B's — halve the lane budget
             kv_lanes=((1024, 4), (4096, 2)),
+            # 33 GB of float32 parameters: one KV head and 7 query heads a chip
+            model_chips=4,
         ),
         "tiny-test": FlavorSpec(VLM_TINY_TEST, "caption-vlm-tpu", require_weights=False),
+        # the 7B's deployment at test size: served over a model=4 mesh
+        # (four virtual CPU devices do for the chips)
+        "qwen25vl-tiny-test": FlavorSpec(
+            VLM_QWEN25VL_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            kv_lanes=((64, 4), (128, 2)),
+            model_chips=4,
+        ),
         # MoE chat-LM slot for LM-ONLY converted checkpoints (enhancement
         # and other text paths); the full-VL flavor below serves frames
         "qwen3moe-a3b-lm": FlavorSpec(
@@ -582,6 +623,19 @@ class MoEFFN(nn.Module):
         return y.reshape(b, t, d).astype(x.dtype)
 
 
+# jax.named_scope names of the sites where a program partitioned over the
+# ``model`` axis crosses chips: the all-reduce that ends each row-parallel
+# matmul (attention output, MLP down) and the gathers around the embedding
+# table (split by feature) and the head (split by vocabulary). Free at run
+# time; they put a name on a trace's collectives (docs/OBSERVABILITY.md).
+TP_SCOPES = {
+    "attn_out": "tp_reduce.attn_out",
+    "mlp_down": "tp_reduce.mlp_down",
+    "embed": "tp_gather.embed",
+    "head": "tp_gather.head",
+}
+
+
 class DecoderLayer(nn.Module):
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -710,16 +764,20 @@ class DecoderLayer(nn.Module):
                 probs = jax.nn.softmax(logits, axis=-1)
                 attn = jnp.einsum("bkgts,bksd->btkgd", probs.astype(self.dtype), new_v)
         attn = attn.reshape(b, t, h * dh)
-        x = x + dense(cfg.dim, "in", name="o", use_bias=False, dtype=self.dtype)(attn)
+        # the row-parallel matmuls end in an all-reduce over the model axis:
+        # the scope names it in a compiled program and in a device trace
+        with jax.named_scope(TP_SCOPES["attn_out"]):
+            x = x + dense(cfg.dim, "in", name="o", use_bias=False, dtype=self.dtype)(attn)
 
         y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
         if cfg.moe is not None:
             return x + MoEFFN(cfg, dtype=self.dtype, name="moe")(y), new_k, new_v
         up = dense(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False, dtype=self.dtype)(y)
         gate = dense(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False, dtype=self.dtype)(y)
-        down = dense(cfg.dim, "in", name="down", use_bias=False, dtype=self.dtype)(
-            nn.silu(gate) * up
-        )
+        with jax.named_scope(TP_SCOPES["mlp_down"]):
+            down = dense(cfg.dim, "in", name="down", use_bias=False, dtype=self.dtype)(
+                nn.silu(gate) * up
+            )
         return x + down, new_k, new_v
 
 
@@ -792,7 +850,8 @@ class VLM(nn.Module):
         return self.projector(tokens)
 
     def embed_tokens(self, token_ids):
-        return self.embed(token_ids)
+        with jax.named_scope(TP_SCOPES["embed"]):
+            return self.embed(token_ids)
 
     def init_everything(self, frames_u8, token_ids, cache_k, cache_v):
         """Init-only method touching every submodule (flax only creates
@@ -826,9 +885,10 @@ class VLM(nn.Module):
         if logits_at is not None:
             x = jnp.take_along_axis(x, logits_at[:, None, None].astype(jnp.int32), axis=1)
         x = self.ln_f(x)
-        if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
-            return self.lm_head(x.astype(jnp.float32))
-        return self.embed.attend(x.astype(jnp.float32))
+        with jax.named_scope(TP_SCOPES["head"]):
+            if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
+                return self.lm_head(x.astype(jnp.float32))
+            return self.embed.attend(x.astype(jnp.float32))
 
     def __call__(
         self, embeds, cache_k, cache_v, positions, write_index, kv_len, deepstack=None,

@@ -149,6 +149,24 @@ QWEN_VISION_TINY_TEST = QwenVisionConfig(
 )
 
 
+# Qwen2.5-VL's tower at test size: RMSNorm blocks, SwiGLU, 16 px windows of
+# 2 x 2 merge units over a 5 x 5 unit grid (so the last window of a row and
+# of a column is cut), one full-attention block between two windowed ones.
+QWEN25_VISION_TINY_TEST = QwenVisionConfig(
+    depth=3,
+    embed_dim=64,
+    num_heads=4,
+    hidden_size=64,
+    intermediate_size=96,
+    patch_size=4,
+    image_size=40,
+    variant="qwen2_5",
+    window_size=16,
+    fullatt_block_indexes=(1,),
+    tokens_per_second=2.0,
+)
+
+
 def pos_embed_interp_matrix(cfg: QwenVisionConfig, grid: tuple[int, int, int]) -> np.ndarray:
     """Host-side [h*w, side²] bilinear interpolation matrix mapping the
     learned pos-embed table onto ONE temporal slice of the (t, h, w) patch

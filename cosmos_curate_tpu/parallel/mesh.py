@@ -114,6 +114,25 @@ def seq_mesh(n: int):
     return Mesh(np.array(devices[:n]), axis_names=(SEQ,))
 
 
+def model_mesh(n: int, *, what: str = "a model mesh"):
+    """Mesh over the first ``n`` local devices on the ``model`` axis — the
+    tensor-parallel plane a caption flavor of ``model_chips=n`` is served
+    over (models/vlm/model.py ``FlavorSpec``). One constructor, so the
+    caption stage and the chip smoke build the mesh the benchmark's driver
+    builds and share one ``SharedCaptionEngine`` key. ``what`` names the
+    asker in the error a host with fewer chips gets."""
+    import jax
+    from jax.sharding import Mesh
+
+    devices = jax.local_devices()
+    if n > len(devices):
+        raise ValueError(
+            f"{what} is served over {n} chips of one host (its parameters are "
+            f"split over them); this host has {len(devices)}"
+        )
+    return Mesh(np.array(devices[:n]), axis_names=(MODEL,))
+
+
 def best_effort_mesh(spec: MeshSpec | None = None):
     """Build the full (dcn, data, model, seq) mesh over all visible devices,
     resolving -1 axes. Single-host single-chip degenerates to (1,1,1,1)."""

@@ -118,6 +118,8 @@ class _CaptionVLM(ModelInterface):
         specials: dict[str, int] | None = None,
         kv_lanes: tuple[tuple[int, int], ...] | None = None,
         text_only: bool = False,
+        model_chips: int = 1,
+        flavor: str | None = None,
     ) -> None:
         self.cfg = cfg
         self.max_batch = max_batch
@@ -127,6 +129,10 @@ class _CaptionVLM(ModelInterface):
         self.specials = specials
         self.kv_lanes = kv_lanes
         self.text_only = text_only
+        # chips of this host the engine's ``model`` mesh spans (FlavorSpec.
+        # model_chips; 1 = no mesh) and the flavor's name for error messages
+        self.model_chips = model_chips
+        self.flavor = flavor
         self.engine: CaptionEngine | None = None
         self._tokenizer = None
         # encode_prompt memo: the HF BPE is pure-Python and the caption
@@ -241,6 +247,20 @@ class _CaptionVLM(ModelInterface):
             kv_lanes=self.kv_lanes,
             tokenizer=tokenizer,
             loader=loader,
+            mesh=self._serving_mesh(),
+        )
+
+    def _serving_mesh(self):
+        """The ``model`` mesh the flavor is served over, or None for a
+        flavor that fits one chip. The stage holds the whole host
+        (``entire_tpu_host``), so the chips are this process's to take;
+        with too few of them this raises, naming flavor, needed and found."""
+        if self.model_chips == 1:
+            return None
+        from cosmos_curate_tpu.parallel.mesh import model_mesh
+
+        return model_mesh(
+            self.model_chips, what=f"caption model {self.flavor or self.model_id!r}"
         )
 
 
@@ -268,6 +288,8 @@ def resolve_caption_model(
             specials=dict(spec.specials) if spec.specials else None,
             kv_lanes=spec.kv_lanes,
             text_only=spec.text_only,
+            model_chips=spec.model_chips,
+            flavor=model_flavor,
         )
     return _CaptionVLM(cfg or VLM_BASE, max_batch)
 

@@ -40,9 +40,17 @@ from cosmos_curate_tpu.models.vlm.model import VLM_QWEN2VL_TINY_TEST
 
 cfg = dataclasses.replace(VLM_QWEN2VL_TINY_TEST, n_heads=8, n_kv_heads=4)
 chip_smoke.require_tpu = lambda: jax.devices()[0]
-chip_smoke.run_sharded = lambda: chip_smoke.phase_sharded(
-    cfg, ((64, 2),), totals=(40,), n_frames=2, max_new=2
-)
+def run_sharded():
+    chip_smoke.phase_sharded(cfg, ((64, 2),), totals=(40,), n_frames=2, max_new=2)
+    # the 7B's deployment at test size, as the stage builds it, against float32
+    engine = chip_smoke.phase_reference(
+        "qwen25vl-tiny-test", n_frames=4, n_prefix=8, n_prompt=12, n_text=70, tol=0.06,
+        prefill_chunk=16,
+    )
+    chip_smoke.phase_collective_names(engine)
+    engine.shutdown()
+
+chip_smoke.run_sharded = run_sharded
 sys.exit(chip_smoke.main(["--chips", "4"]))
 """
 
@@ -59,4 +67,9 @@ def test_chips4_runs_only_the_sharded_paths_on_four_virtual_devices():
     }
     assert any("1/4 on each device" in l for l in lines)  # the KV pool was spread
     assert any("k-means over mesh" in l for l in lines)
+    for request in ("check-window", "check-text"):  # first step + 8 decode steps each
+        assert any(f"reference: {request} vs the float32 reference" in l for l in lines)
+    assert any("prefilled in chunks of 16" in l for l in lines)
+    named = next(l for l in lines if "collectives: the decode program's, by origin" in l)
+    assert "'tp_reduce.attn_out': 2" in named and "'tp_reduce.mlp_down': 2" in named
     assert not any(phase in l for l in lines for phase in ("kernels:", "split:", "caption-2b:"))
