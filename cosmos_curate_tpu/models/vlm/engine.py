@@ -465,6 +465,12 @@ class CaptionEngine:
         # and scattered back for the same calls
         self._paged_kernel_steps = 0
         self._kv_gather_bytes_avoided = 0
+        # table entries the decode kernel's loop walks (a row's valid
+        # length in pages, summed over the rows of every decode program)
+        # over the entries the rows' tables span (rows x blocks a lane):
+        # the share of the table the kernel touches
+        self._paged_decode_pages_walked = 0
+        self._paged_decode_pages_spanned = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -1056,6 +1062,8 @@ class CaptionEngine:
                 "kv_block_size": self.block_size,
                 "kv_block_size_requested": self.block_size_requested,
                 "paged_kernel_steps": self._paged_kernel_steps,
+                "paged_decode_pages_walked": self._paged_decode_pages_walked,
+                "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
                 "kv_gather_bytes_avoided": self._kv_gather_bytes_avoided,
                 "decode_attention_s": self._decode_time,
                 "decode_tokens": self._decode_tokens,
@@ -1177,6 +1185,8 @@ class CaptionEngine:
             self._prefix_block_refs = 0
             self._kv_cow_copies = 0
             self._paged_kernel_steps = 0
+            self._paged_decode_pages_walked = 0
+            self._paged_decode_pages_spanned = 0
             self._kv_gather_bytes_avoided = 0
             self._kv_blocks_used_peak = self._allocator.used_blocks
             self._interleaved_steps = 0
@@ -2350,6 +2360,11 @@ class CaptionEngine:
                 self._decode_rows += lane.n_slots
                 if self._use_paged:
                     self._paged_kernel_steps += 1
+                    # a row's kv_len is positions + 1 (decode_step_paged)
+                    self._paged_decode_pages_walked += int(
+                        (positions // self.block_size + 1).sum()
+                    )
+                    self._paged_decode_pages_spanned += lane.table.size
                     self._kv_gather_bytes_avoided += self._gather_view_bytes(
                         lane.n_slots, lane.length
                     )

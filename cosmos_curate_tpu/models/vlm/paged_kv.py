@@ -17,11 +17,12 @@ and scatters the written blocks back (:func:`scatter_block_views`).
 The layout contract between the write and the kernels: the pool has ONE
 device layout from a program's entry to its exit, the one the paged
 kernels' operand demands — row-major ``[L, NB, Hkv, bs, Dh]`` with
-``(bs, Dh)`` tiled, because a kernel block is one head's ``[bs, Dh]`` page
-(``BlockSpec((None, None, None, bs, Dh))``). XLA chooses a scatter's layout
-from its update window: a window that spans ``[Hkv, Dh]`` (the head left as
-a slice, as this write was first phrased) makes those two dimensions minor,
-and every layer then pays a relayout ``copy`` of the whole K and V pool in
+``(bs, Dh)`` tiled, because what a kernel fetches is a page: one head's
+``[bs, Dh]`` at prefill (``BlockSpec((None, None, None, bs, Dh))``), all
+heads' ``[Hkv, bs, Dh]`` copied by the decode kernel itself. XLA chooses a
+scatter's layout from its update window: a window that spans ``[Hkv, Dh]``
+(the head left as a slice, as this write was first phrased) makes those two
+dimensions minor, and every layer then pays a relayout ``copy`` of the whole K and V pool in
 front of its kernel call (2 x 28 x 1.13 ms of an 85 ms Qwen2-VL-2B decode
 step: PERF.md, PR 25). :func:`paged_update` therefore indexes every pool
 dimension but ``Dh``, so an update is one ``[Dh]`` row and the donated pool

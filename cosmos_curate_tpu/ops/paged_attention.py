@@ -8,26 +8,42 @@ programs — every prefill chunk and every decode step materialized a
 contiguous ``[L, n_slots, lane_length]`` copy of the whole KV working set
 and scattered it back. This op deletes that copy:
 
-- **table-driven BlockSpecs**: the block table is scalar-prefetched, so the
-  Pallas index map resolves grid step ``j`` to physical pool block
-  ``table[b, j]`` — the kernel reads pool pages in place, nothing is
-  gathered;
+- **the table is scalar-prefetched**, so a kernel resolves table entry
+  ``j`` of row ``b`` to physical pool block ``table[b, j]`` and reads that
+  page in place — nothing is gathered;
 - **logical positions from the table index**: table entry ``j`` covers
   logical positions ``[j*bs, (j+1)*bs)`` regardless of where the block
   lives in the pool, so masking is identical to the contiguous kernels;
-- **early exit**: blocks at/after the row's valid length (and, for prefill,
-  beyond the chunk's last causal position) are skipped with ``pl.when`` —
-  fragmented tables cost nothing extra.
+- **decode walks a row's own pages, several a step** (PR 27): one grid
+  step a row with all of its KV heads, a loop over groups of ``P``
+  consecutive table entries whose trip count comes from that row's valid
+  length, so the work follows the context and not the lane. The pools stay
+  in HBM; the kernel starts one asynchronous copy a page (a page's ``[Hkv,
+  bs, D]`` is contiguous, so one copy serves every head) into one of two
+  ``[Hkv, P * bs, D]`` buffers, the next group in flight while this one is
+  computed, and starts none for an entry at or past the valid length.
+  ``P`` comes from the shapes (``_decode_pages``): at least 128 keys, and
+  more while a buffer stays within 128 KiB. The layer is a prefetched
+  scalar like the table, so a model's layers share one trace of the
+  kernel. Where ``D`` is not a whole number of lane tiles (``base``: 64)
+  Mosaic slices no HBM array, and the pipeline delivers the same groups
+  through ``P`` ``BlockSpec``s a pool instead. That second form compiles
+  at every width but is not the one kernel: at ``D`` = 128 its ``2 * P``
+  pipeline copies a step cost 71-142 us a call against 13-30 for the
+  kernel's own (PR 27, one v5e), 11-12% of both 2B cells' tokens a second;
+- **prefill skips by grid step**: one page a grid step, and blocks at or
+  after the row's valid length or beyond the chunk's last causal position
+  are skipped with ``pl.when`` (ROADMAP S4: what is left of it).
 
-The kernels' ``BlockSpec`` for K and V is one head's page, ``(None, None,
-None, bs, D)`` of the pool ``[L, NB, Hkv, bs, D]``: that pins the pool
-operand to the row-major layout with ``(bs, D)`` tiled. Whatever produces
-the pool inside the same program must leave it in that layout, or XLA puts
-a relayout ``copy`` of the whole pool in front of every call: the write,
-``models/vlm/paged_kv.paged_update``, keeps its side of the contract by
-indexing every dimension but ``D`` (its module docstring; pinned by
-``tests/ops/test_tpu_compile.py``). A change to the page's block shape here
-is a change to that contract.
+Both ways of reaching a page, the decode kernel's copy of ``pool[layer,
+block]`` and a ``BlockSpec`` ``(None, None, ..., bs, D)`` of the pool ``[L,
+NB, Hkv, bs, D]``, pin the pool operand to the row-major layout with ``(bs,
+D)`` tiled. Whatever produces the pool inside the same program must leave
+it in that layout, or XLA puts a relayout ``copy`` of the whole pool in
+front of every call: the write, ``models/vlm/paged_kv.paged_update``, keeps
+its side of the contract by indexing every dimension but ``D`` (its module
+docstring; pinned by ``tests/ops/test_tpu_compile.py``). A change to the
+page's shape here is a change to that contract.
 
 Off-TPU the default is NOT interpret-mode Pallas but a ``jax.lax``
 reference that mirrors ``DecoderLayer``'s XLA attention lines exactly
@@ -56,6 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 from cosmos_curate_tpu.ops.tiling import round_up, sublanes
 
 _NEG_INF = -1e30
+_GROUP_BUFFER_BYTES = 128 * 1024  # one of the decode kernel's four page buffers
 
 
 def use_paged_kernel() -> bool:
@@ -93,50 +110,136 @@ def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_in
     return jnp.einsum("bkgts,bksd->btkgd", probs.astype(q.dtype), new_v)
 
 
+def _decode_pages(bs: int, hk: int, d: int, dtype, nbl: int) -> int:
+    """Table entries a step of the decode kernels covers (``P``): at least
+    128 keys, so a score product fills the MXU's width, whatever that
+    makes of a buffer (``Hkv`` 8 at ``D`` 128: 256 KiB, 1 MiB across the
+    four); as many more as keep one buffer of a group's ``[Hkv, P * bs,
+    D]`` pages (lane padding counted) within 128 KiB, two for K and two
+    for V; never more than the table holds."""
+    page_bytes = hk * bs * round_up(d, 128) * jnp.dtype(dtype).itemsize
+    pages = max(pl.cdiv(128, bs), _GROUP_BUFFER_BYTES // page_bytes)
+    return min(pages, nbl)
+
+
+def _attend_group(q, k, v, k_start, kv_len, acc, m_prev, l_prev):
+    """One online-softmax step of one KV head over a group of keys, all in
+    float32. q: ``[g_pad, D]``, scaled; k, v: ``[N, D]`` of the pool's
+    dtype, the first of them at logical position ``k_start``; keys at or
+    past ``kv_len`` are masked by position. Returns the new state."""
+    s = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # [g_pad, N]
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos < kv_len, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return acc, m_new, l_new
+
+
 def _paged_decode_kernel(
-    kvlen_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, bs, g_pad
+    layer_ref, kvlen_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+    *, sm_scale, bs, pages,
 ):
+    """One grid step is one row with all of its KV heads: a page's
+    ``[Hkv, bs, D]`` is contiguous in the pool, so one copy a page serves
+    every head. The loop walks the row's OWN table in groups of ``pages``
+    entries, as far as its valid length and no further; group ``i + 1`` is
+    in flight while group ``i`` is computed."""
     b = pl.program_id(0)
-    ji = pl.program_id(2)
-    num_j = pl.num_programs(2)
+    layer, kv_len = layer_ref[0], kvlen_ref[b]
+    hk, g_pad, d = q_ref.shape
+    group = pages * bs
+    n_groups = pl.cdiv(kv_len, group)
 
-    @pl.when(ji == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def each_live_page(i, slot, act):
+        # no copy for a table entry at or past the valid length: its block
+        # id is garbage (the engine's block 0, or anything)
+        first = i * pages
 
+        def page(p, carry):
+            block = tbl_ref[b, first + p]
+            rows = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]), (v_hbm, v_buf, sems.at[1, slot])):
+                act(pltpu.make_async_copy(pool.at[layer, block], buf.at[slot, :, rows], sem))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(pl.cdiv(kv_len, bs) - first, 0, pages), page, 0)
+
+    # a dead page's rows of a V buffer are multiplied by p = 0 and have to
+    # be finite for that: the scratch starts as zeros, and a later row finds
+    # an earlier row's pages there (rows run in order on one core: the
+    # call's grid is "arbitrary", see `_paged_decode`)
+    @pl.when(b == 0)
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    each_live_page(0, 0, lambda copy: copy.start())
+    q = q_ref[...].astype(jnp.float32) * sm_scale  # [hk, g_pad, d]
+
+    def two_groups(pair, state):
+        # two groups an iteration, so that each names its buffer statically.
+        # A group past the row's last has no live page: no copy, no wait,
+        # and its keys, all masked, leave the state as it was (p = 0,
+        # alpha = 1); group 0 holds a valid key whenever the loop runs.
+        for slot in (0, 1):
+            i = 2 * pair + slot
+            each_live_page(i + 1, 1 - slot, lambda copy: copy.start())
+            each_live_page(i, slot, lambda copy: copy.wait())
+            state = tuple(
+                _attend_group(q[h], k_buf[slot, h], v_buf[slot, h], i * group, kv_len, *state[h])
+                for h in range(hk)
+            )
+        return state
+
+    init = (
+        jnp.zeros((g_pad, d), jnp.float32),
+        jnp.full((g_pad, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((g_pad, 1), jnp.float32),
+    )
+    state = jax.lax.fori_loop(0, pl.cdiv(n_groups, 2), two_groups, (init,) * hk)
+    for h, (acc, _, l) in enumerate(state):
+        o_ref[h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_decode_blockspec_kernel(
+    layer_ref, kvlen_ref, tbl_ref, q_ref, *refs, sm_scale, bs, pages
+):
+    """The same grouping where the kernel cannot copy for itself (see
+    ``_paged_decode``): grid ``(row, group)``, the group's ``pages`` pages
+    of K and of V delivered by as many ``BlockSpec``s, the softmax state in
+    scratch across a row's grid steps. A group past the valid length costs
+    a grid step and nothing else."""
+    k_refs, v_refs = refs[:pages], refs[pages : 2 * pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * pages :]
+    b, i = pl.program_id(0), pl.program_id(1)
     kv_len = kvlen_ref[b]
-    # table entry ji covers LOGICAL positions [ji*bs, (ji+1)*bs) — the
-    # physical pool block was picked by the BlockSpec index map
-    k_start = ji * bs
+    hk = q_ref.shape[0]
 
-    @pl.when(k_start < kv_len)
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(i * pages * bs < kv_len)
     def _step():
-        q = q_ref[...].astype(jnp.float32) * sm_scale  # [g_pad, d]
-        k = k_ref[...].astype(jnp.float32)  # [bs, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [g_pad, bs]
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (g_pad, bs), 1)
-        s = jnp.where(k_pos < kv_len, s, _NEG_INF)
+        for h in range(hk):
+            k = jnp.concatenate([ref[h] for ref in k_refs], axis=0)  # [pages * bs, d]
+            v = jnp.concatenate([ref[h] for ref in v_refs], axis=0)
+            q = q_ref[h].astype(jnp.float32) * sm_scale
+            acc_ref[h], m_ref[h], l_ref[h] = _attend_group(
+                q, k, v, i * pages * bs, kv_len, acc_ref[h], m_ref[h], l_ref[h]
+            )
 
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p,
-            v_ref[...].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, :1] = m_new
-
-    @pl.when(ji == num_j - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[...] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(
@@ -209,11 +312,11 @@ def _paged_prefill_kernel(
         o_ref[...] = out.reshape(g, block_q, o_ref.shape[-1]).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("layer_index", "sm_scale", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, interpret):
-    """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]."""
+    """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl].
+    ``layer_index`` is a run-time scalar here, prefetched with the table:
+    a model's layers share one trace and one lowering of the kernel."""
     b, hk, g, d = q.shape
     nbl = tables.shape[1]
     bs = pool_k.shape[3]
@@ -221,31 +324,62 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
     if g_pad != g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
 
-    grid = (b, hk, nbl)
-    kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale, bs=bs, g_pad=g_pad)
-    # the table ref arrives as a trailing index-map arg: grid step ji reads
-    # physical pool block tbl[b, ji] in place — one [bs, D] tile of head h
-    kv_spec = pl.BlockSpec(
-        (None, None, None, bs, d),
-        lambda b_, h, ji, kvlen, tbl: (layer_index, tbl[b_, ji], h, 0, 0),
-    )
-    q_spec = pl.BlockSpec((None, None, g_pad, d), lambda b_, h, ji, kvlen, tbl: (b_, h, 0, 0))
+    pages = _decode_pages(bs, hk, d, pool_k.dtype, nbl)
+    q_spec = pl.BlockSpec((None, hk, g_pad, d), lambda b_, *_: (b_, 0, 0, 0))
+    if d % 128 == 0:
+        # the pools stay in HBM and the kernel copies the pages it wants:
+        # the operand is the pool as the write left it, row-major with
+        # (bs, D) tiled
+        kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale, bs=bs, pages=pages)
+        grid = (b,)
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        group_buffer = pltpu.VMEM((2, hk, pages * bs, d), pool_k.dtype)
+        scratch = [group_buffer, group_buffer, pltpu.SemaphoreType.DMA((2, 2))]
+    else:
+        # Mosaic slices no HBM array whose last dimension is under a lane
+        # tile ("Slice shape along dimension 4 must be aligned to tiling
+        # (128), but is 64": `base`, D = 64), so there the pipeline fetches
+        # the group: one BlockSpec a page. An entry at or past the valid
+        # length names the row's last live page again (not fetched twice,
+        # masked by position), so no block past the length is ever read.
+        kernel = functools.partial(
+            _paged_decode_blockspec_kernel, sm_scale=sm_scale, bs=bs, pages=pages
+        )
+        grid = (b, pl.cdiv(nbl, pages))
+
+        def page_spec(p):
+            def index(b_, i, layer, kvlen, tbl):
+                last_live = jnp.maximum(kvlen[b_] - 1, 0) // bs
+                return layer[0], tbl[b_, jnp.minimum(i * pages + p, last_live)], 0, 0, 0
+
+            return pl.BlockSpec((None, None, hk, bs, d), index)
+
+        pool_specs = [page_spec(p) for p in range(pages)] * 2
+        scratch = [
+            pltpu.VMEM((hk, g_pad, d), jnp.float32),
+            pltpu.VMEM((hk, g_pad, 1), jnp.float32),
+            pltpu.VMEM((hk, g_pad, 1), jnp.float32),
+        ]
+    n_pool = len(pool_specs) // 2
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec, *pool_specs],
             out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((g_pad, d), jnp.float32),
-                pltpu.VMEM((g_pad, 128), jnp.float32),
-                pltpu.VMEM((g_pad, 128), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hk, g_pad, d), q.dtype),
+        # never "parallel": scratch carries state from one grid step to the
+        # next (a row's softmax state over its groups; the V buffers zeroed
+        # in the first row), so no core may start in the middle of the grid
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), tables.astype(jnp.int32), q, pool_k, pool_v)
+    )(
+        jnp.asarray(layer_index, jnp.int32).reshape(1), kv_len.astype(jnp.int32),
+        tables.astype(jnp.int32), q, *[pool_k] * n_pool, *[pool_v] * n_pool,
+    )
     return out[:, :, :g]
 
 

@@ -390,7 +390,10 @@ class TestPagedAttentionModes:
         assert stats["kv_block_size"] == 8 == eng.block_size
         assert stats["paged_attention"] == "auto"
         assert stats["mesh_geometry"] == ()
-        for key in ("paged_kernel_steps", "kv_gather_bytes_avoided", "decode_attention_s"):
+        for key in (
+            "paged_kernel_steps", "paged_decode_pages_walked", "paged_decode_pages_spanned",
+            "kv_gather_bytes_avoided", "decode_attention_s",
+        ):
             assert key in stats
 
     def test_kernel_vs_gather_bit_equal_across_lane_buckets(self):
@@ -422,6 +425,31 @@ class TestPagedAttentionModes:
         assert kernel.kv_gather_bytes_avoided > 0
         assert gather.paged_kernel_steps == 0
         assert gather.kv_gather_bytes_avoided == 0
+
+    def test_pages_walked_and_spanned_follow_the_rows_lengths(self):
+        """``paged_decode_pages_walked`` is what the decode kernel's loop
+        covers (each row's valid length in pages, idle rows one page),
+        ``paged_decode_pages_spanned`` what the rows' tables hold: both
+        recomputed here from the arguments of every decode program of a
+        two-lane engine."""
+        eng = self._mode_engine("kernel", **self.GNARLY)
+        decode, seen = eng._decode, []
+
+        def recording(params, pool_k, pool_v, tables, tokens, positions, rope_positions):
+            seen.append((tables.shape, np.asarray(positions)))
+            return decode(params, pool_k, pool_v, tables, tokens, positions, rope_positions)
+
+        eng._decode = recording
+        _drain(eng, [_req("short", text="hi", max_new=4), _req("long", text="w " * 30, max_new=6)])
+        stats, bs = eng.stats(), eng.block_size
+        assert {shape for shape, _ in seen} == {(2, 64 // bs), (2, 128 // bs)}  # both lanes decoded
+        assert stats["paged_kernel_steps"] == len(seen)
+        assert stats["paged_decode_pages_spanned"] == sum(rows * nbl for (rows, nbl), _ in seen)
+        walked = sum(int(np.ceil((pos + 1) / bs).sum()) for _, pos in seen)
+        assert stats["paged_decode_pages_walked"] == walked
+        assert len(seen) * 2 <= walked < stats["paged_decode_pages_spanned"]
+        eng.reset_stats()
+        assert eng.stats()["paged_decode_pages_walked"] == eng.stats()["paged_decode_pages_spanned"] == 0
 
     def test_parity_with_fragmented_block_table(self):
         """Blocks deliberately NON-CONTIGUOUS in the pool — the layout the

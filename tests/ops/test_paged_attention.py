@@ -119,13 +119,32 @@ class TestReferencePath:
 class TestInterpretKernel:
     """The Pallas kernels in interpreter mode vs the reference path."""
 
-    @pytest.mark.parametrize("b,hk,g,d,nbl,bs", [(2, 2, 4, 16, 4, 16), (1, 2, 6, 32, 3, 8)])
-    def test_decode_kernel_matches_reference(self, b, hk, g, d, nbl, bs):
+    # kv_len None: drawn per row. Where D is a whole number of lane tiles
+    # the kernel copies its own pages in a loop over the row's table; where
+    # it is not, the pipeline delivers the same groups (``_paged_decode``).
+    # Either way a group is P table entries (``_decode_pages``: 8 of 16
+    # tokens at these widths, the whole table where it is shorter), so
+    # nbl = 20 is groups of 8, 8 and 4.
+    @pytest.mark.parametrize(
+        "b,hk,g,d,nbl,bs,kv_len",
+        [
+            pytest.param(2, 2, 4, 16, 4, 16, None, id="one-group"),
+            pytest.param(1, 2, 6, 32, 3, 8, None, id="g6-bs8"),
+            pytest.param(2, 2, 4, 128, 20, 16, [131, 257], id="ragged-groups"),
+            pytest.param(3, 2, 6, 128, 20, 16, [1, 320, 128], id="idle-row-beside-full-lane"),
+            pytest.param(2, 1, 7, 128, 40, 16, [640, 333], id="one-kv-head-tp4-shard"),
+            pytest.param(2, 8, 2, 64, 20, 16, [200, 17], id="d64-base"),
+            pytest.param(3, 2, 4, 16, 20, 16, [320, 1, 129], id="d16-ragged-groups"),
+        ],
+    )
+    def test_decode_kernel_matches_reference(self, b, hk, g, d, nbl, bs, kv_len):
         rng = np.random.default_rng(3)
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
             rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
         )
-        kv_len = jnp.asarray(rng.integers(1, nbl * bs + 1, b), jnp.int32)
+        if kv_len is None:
+            kv_len = rng.integers(1, nbl * bs + 1, b)
+        kv_len = jnp.asarray(kv_len, jnp.int32)
         write = kv_len - 1
         got = paged_attention(
             q, pk, pv, tables, write, kv_len,
@@ -135,6 +154,81 @@ class TestInterpretKernel:
             q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("d", [128, 64], ids=["own-copies", "pipeline-d64"])
+    def test_decode_kernel_never_reads_a_page_past_the_valid_length(self, d, dtype):
+        """Every table entry at or past a row's valid length points at a
+        block of NaN (the engine points them at its garbage block 0): the
+        kernel fetches no such entry, so nothing of that block can reach
+        the result, not even multiplied by a zero probability. The
+        reference gathers whole tables, so it reads the clean one."""
+        rng = np.random.default_rng(7)
+        b, hk, g, nbl, bs = 3, 2, 4, 20, 16
+        q, pk, pv, tables, layer, _, _ = _fragmented_case(
+            rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2, dtype=dtype
+        )
+        kv_len = jnp.asarray([1, 130, 257], jnp.int32)  # 1, 9 and 17 live pages of 20
+        want = paged_attention(
+            q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False
+        )
+        poison = 0  # the engine's garbage block: no table of the case maps it
+        assert poison not in np.asarray(tables)
+        pk, pv = pk.at[:, poison].set(jnp.nan), pv.at[:, poison].set(jnp.nan)
+        dead = np.arange(nbl)[None, :] * bs >= np.asarray(kv_len)[:, None]
+        tables = jnp.where(dead, poison, tables)
+        got = np.asarray(
+            paged_attention(
+                q, pk, pv, tables, kv_len - 1, kv_len,
+                layer_index=layer, use_kernel=True, interpret=True,
+            ),
+            np.float32,
+        )
+        assert np.isfinite(got).all()
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize(
+        "bs,hk,d,dtype,nbl,pages",
+        [
+            pytest.param(16, 2, 128, jnp.bfloat16, 256, 16, id="qwen2vl-2b-4096"),
+            pytest.param(16, 2, 128, jnp.bfloat16, 64, 16, id="qwen2vl-2b-1024"),
+            pytest.param(16, 1, 128, jnp.bfloat16, 256, 32, id="qwen25vl-7b-shard"),
+            pytest.param(16, 8, 64, jnp.bfloat16, 64, 8, id="base-d64"),
+            pytest.param(8, 2, 32, jnp.float32, 3, 3, id="shorter-table"),
+        ],
+    )
+    def test_pages_a_group_come_from_the_shapes(self, bs, hk, d, dtype, nbl, pages):
+        from cosmos_curate_tpu.ops.paged_attention import _decode_pages
+
+        got = _decode_pages(bs, hk, d, dtype, nbl)
+        assert got == pages
+        assert got * bs >= min(128, nbl * bs)  # a group fills the MXU's 128 columns
+
+    def test_decode_layers_share_one_trace(self):
+        """The layer is a scalar the decode kernel reads at run time, so a
+        model's 28 calls trace and lower the kernel once (a static index
+        made it 28 times, which a program pays at every set-up: the
+        persistent cache keeps compiled programs, not traces)."""
+        from cosmos_curate_tpu.ops.paged_attention import _paged_decode
+
+        rng = np.random.default_rng(8)
+        b, hk, g, d, nbl, bs = 2, 2, 4, 128, 10, 16
+        q, pk, pv, tables, _, _, _ = _fragmented_case(
+            rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        kv_len = jnp.asarray([150, 33], jnp.int32)
+        _paged_decode.clear_cache()
+        for layer in (0, 1):
+            got = paged_attention(
+                q, pk, pv, tables, kv_len - 1, kv_len,
+                layer_index=layer, use_kernel=True, interpret=True,
+            )
+            want = paged_attention(
+                q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False
+            )
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+        assert _paged_decode._cache_size() == 1
 
     def test_prefill_kernel_matches_reference_offset_and_ragged_t(self):
         """write_index > 0 plus a chunk length that does not tile block_q:

@@ -41,19 +41,27 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-# (Hkv, G, D): the default `base` captioner and Qwen2-VL-2B
-WIDTHS = {"base": (8, 2, 64), "qwen2vl-2b": (2, 6, 128)}
+# (Hkv, G, D): the default `base` captioner, Qwen2-VL-2B, one chip's share
+# of Qwen2.5-VL-7B over model=4 (one KV head, 7 query heads), and the
+# Qwen3 MoE flavors (four KV heads at D 128: the widest page the decode
+# kernel copies for itself)
+WIDTHS = {
+    "base": (8, 2, 64),
+    "qwen2vl-2b": (2, 6, 128),
+    "qwen25vl-7b-shard": (1, 7, 128),
+    "qwen3-moe-a3b": (4, 8, 128),
+}
 B, T, S, BS, LAYERS, POOL_BLOCKS = 8, 256, 1024, 16, 2, 600
 
 
-def _paged_decode(hk, g, d, arg):
+def _paged_decode(hk, g, d, arg, rows=B, lane=S):
     from cosmos_curate_tpu.ops.paged_attention import _paged_decode as fn
 
     pool = arg((LAYERS, POOL_BLOCKS, hk, BS, d), jnp.bfloat16)
     return (
         functools.partial(fn, layer_index=1, sm_scale=d**-0.5, interpret=False),
-        (arg((B, hk, g, d), jnp.bfloat16), pool, pool,
-         arg((B, S // BS), jnp.int32), arg((B,), jnp.int32)),
+        (arg((rows, hk, g, d), jnp.bfloat16), pool, pool,
+         arg((rows, lane // BS), jnp.int32), arg((rows,), jnp.int32)),
     )
 
 
@@ -115,6 +123,20 @@ def test_kernel_compiles_for_v5e(v5e, kernel, widths):
     fn, args = KERNELS[kernel](*WIDTHS[widths], arg)
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_paged_decode_compiles_at_the_widest_lane(v5e, widths):
+    """The 4096 lane is the longest block table a cell runs: 256 entries a
+    row in scalar memory, four rows. The compiler refuses a kernel that
+    wants more VMEM than a call may have, so compiling is the check (the
+    page buffers are sized from the page, not from the table)."""
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = _paged_decode(*WIDTHS[widths], arg, rows=4, lane=4096)
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
 @pytest.mark.parametrize("kernel", ["paged_decode", "paged_prefill"])
