@@ -138,8 +138,8 @@ class EngineMetrics:
         # Paged-attention path signals (ops/paged_attention.py): decode
         # steps served without a gathered KV working set, and the bytes of
         # contiguous view the gather programs would have materialized for
-        # the same calls. kernel_steps == 0 on an engine configured
-        # paged_attention="kernel" means the path regressed to gather.
+        # the same calls. kernel_steps stays 0 only on an engine built with
+        # paged_attention="gather" (the reference programs).
         self.caption_paged_kernel_steps = Counter(
             "caption_paged_kernel_steps_total",
             "decode steps served by the paged-attention programs", labels,

@@ -50,7 +50,6 @@ from __future__ import annotations
 
 import contextlib
 import math
-import os
 import threading
 import time
 from collections import OrderedDict, deque
@@ -326,20 +325,18 @@ class CaptionEngine:
         # interleaved with decode steps
         self.prefill_chunk = min(prefill_chunk, cfg.max_seq)
         self.tokenizer = tokenizer or default_caption_tokenizer()
-        # paged-attention path selection. "auto"/"kernel" run the paged
-        # programs (attention reads the pool through the block table —
-        # ops/paged_attention.py picks Pallas on TPU, the byte-parity XLA
-        # reference elsewhere); "gather" keeps the legacy
-        # gather-view/scatter-back programs as fallback and parity
-        # reference. CURATE_PAGED_ATTENTION overrides the constructor.
-        env_mode = os.environ.get("CURATE_PAGED_ATTENTION")
-        mode = env_mode if env_mode is not None else paged_attention
-        if mode not in ("auto", "kernel", "gather"):
+        # which family of programs: "auto"/"kernel" run the paged programs
+        # (attention reads the pool through the block table; which
+        # implementation is ops/paged_attention.py's decision alone);
+        # "gather" builds the gather-view/scatter-back programs over the
+        # XLA reference, which the parity tests and the benchmark's
+        # `correct` compare against
+        if paged_attention not in ("auto", "kernel", "gather"):
             raise ValueError(
-                f"paged_attention must be auto|kernel|gather, got {mode!r}"
+                f"paged_attention must be auto|kernel|gather, got {paged_attention!r}"
             )
-        self.paged_attention = mode
-        self._use_paged = mode != "gather"
+        self.paged_attention = paged_attention
+        self._use_paged = paged_attention != "gather"
         # optional device mesh: threads into the model so the paged path
         # runs head-parallel over parallel/axes.MODEL when the mesh names
         # that axis (KV pool + heads sharded, block tables replicated)

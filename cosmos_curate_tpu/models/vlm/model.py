@@ -502,28 +502,6 @@ def build_mrope_positions(
     return np.concatenate(parts, axis=0).astype(np.int32), offset
 
 
-def _flash_gate(env_var: str, cache_len: int, min_len: int) -> bool:
-    """Shared Pallas-kernel gate: the env var forces 1/0 (tests use 1 with
-    the interpreter off-TPU); otherwise on-TPU above the length where
-    streaming beats XLA's materialized path."""
-    import os
-
-    env = os.environ.get(env_var)
-    if env is not None:
-        return env == "1"
-    return jax.devices()[0].platform == "tpu" and cache_len >= min_len
-
-
-def _use_flash_decode(cache_len: int) -> bool:
-    return _flash_gate("CURATE_FLASH_DECODE", cache_len, 512)
-
-
-def _use_flash_prefill(cache_len: int) -> bool:
-    # the XLA prefill materializes fp32 [B, Hkv, G, T, S] logits — the HBM
-    # hot spot of long-prompt prefill (ops/prefill_attention.py)
-    return _flash_gate("CURATE_FLASH_PREFILL", cache_len, 1024)
-
-
 class RMSNorm(nn.Module):
     eps: float = 1e-6
 
@@ -648,15 +626,17 @@ class DecoderLayer(nn.Module):
         self, x, cache_k, cache_v, positions, write_index, kv_len,
         block_tables=None, layer_index=0,
     ):
-        """One decoder layer with slot KV cache.
+        """One decoder layer over a KV cache.
 
-        x: [B, T, D]; cache_k/v: [B, Hkv, S, Dh] (heads-major, so a
-        kernel's K/V tile is ``[S-block, Dh]``); positions: [B, T] rope
-        positions (or [B, T, 3] m-rope components — under m-rope, rope
-        position ≠ cache index, so causality derives from write_index, not
-        positions); write_index: [B] offset where this chunk's K/V land;
-        kv_len: [B] valid cache length AFTER writing (= write_index + T for
-        active rows). Returns (y, new_cache_k, new_cache_v).
+        x: [B, T, D]; cache_k/v: [B, Hkv, S, Dh], one row a slot (the
+        ``gather`` programs' view and the shared prefix's build); positions:
+        [B, T] rope positions (or [B, T, 3] m-rope components — under
+        m-rope, rope position ≠ cache index, so causality derives from
+        write_index, not positions); write_index: [B] offset where this
+        chunk's K/V land; kv_len: [B] valid cache length AFTER writing
+        (= write_index + T for active rows). The chunk is written, then
+        attended by the XLA reference (ops/paged_attention.py).
+        Returns (y, new_cache_k, new_cache_v).
 
         Paged mode (``block_tables`` set): cache_k/v are the FULL block
         pools ``[L, NB, Hkv, bs, Dh]`` and block_tables is ``[B, nbl]``.
@@ -666,7 +646,6 @@ class DecoderLayer(nn.Module):
         """
         cfg = self.cfg
         b, t, _ = x.shape
-        s = cache_k.shape[2] if block_tables is None else None
         h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
         y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x)
@@ -682,6 +661,12 @@ class DecoderLayer(nn.Module):
         k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
         v = v.reshape(b, t, hk, dh)
 
+        from cosmos_curate_tpu.ops.paged_attention import (
+            paged_attention,
+            paged_head_attention,
+            reference_attention,
+        )
+
         group = h // hk
         if block_tables is not None:
             # paged path: scatter this chunk's K/V through the block table
@@ -690,10 +675,6 @@ class DecoderLayer(nn.Module):
             # identical garbage both ways), then attend straight out of the
             # pool. No gathered view, no scatter-back.
             from cosmos_curate_tpu.models.vlm.paged_kv import paged_head_update, paged_update
-            from cosmos_curate_tpu.ops.paged_attention import (
-                paged_attention,
-                paged_head_attention,
-            )
             from cosmos_curate_tpu.parallel.axes import MODEL
 
             head_parallel = self.mesh is not None and MODEL in self.mesh.axis_names
@@ -731,38 +712,10 @@ class DecoderLayer(nn.Module):
                 cache_v, v.astype(cache_v.dtype).swapaxes(1, 2), write_index
             )
 
-            # GQA attention of q against the whole (masked) cache. Heads stay
-            # grouped ([B, T, Hkv, G, Dh] vs the KV's [B, Hkv, S, Dh]) — no
-            # jnp.repeat materialization, so HBM traffic is the true KV size
-            # (the decode step is KV-bandwidth-bound; for 12/2 GQA a repeat
-            # would read 6x the bytes).
-            if t == 1 and _use_flash_decode(s):
-                from cosmos_curate_tpu.ops.decode_attention import decode_attention
-
-                out = decode_attention(
-                    q[:, 0].reshape(b, hk, group, dh), new_k, new_v, kv_len
-                )
-                attn = out.astype(self.dtype)[:, None]  # [B, 1, Hkv, G, Dh]
-            elif t > 1 and _use_flash_prefill(s):
-                from cosmos_curate_tpu.ops.prefill_attention import prefill_attention
-
-                attn = prefill_attention(
-                    q.reshape(b, t, hk, group, dh), new_k, new_v, write_index, kv_len
-                ).astype(self.dtype)
-            else:
-                qg = (q * (dh**-0.5)).reshape(b, t, hk, group, dh)
-                logits = jnp.einsum(
-                    "btkgd,bksd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
-                )
-                k_pos = jnp.arange(s)[None, None, None, None, :]  # cache slot index
-                # causality is over cache order (write_index + chunk offset) —
-                # under m-rope the rope positions are NOT monotone in it
-                q_seq = write_index[:, None] + jnp.arange(t)[None, :]  # [B, T]
-                causal = k_pos <= q_seq[:, None, None, :, None]
-                written = k_pos < kv_len[:, None, None, None, None]
-                logits = jnp.where(causal & written, logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1)
-                attn = jnp.einsum("bkgts,bksd->btkgd", probs.astype(self.dtype), new_v)
+            attn = reference_attention(
+                q.reshape(b, t, hk, group, dh), new_k, new_v, write_index, kv_len,
+                sm_scale=dh**-0.5,
+            )
         attn = attn.reshape(b, t, h * dh)
         # the row-parallel matmuls end in an all-reduce over the model axis:
         # the scope names it in a compiled program and in a device trace

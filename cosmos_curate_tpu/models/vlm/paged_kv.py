@@ -5,11 +5,13 @@ re-shaped for XLA's static-shape compilation: KV memory is ONE block pool
 ``[L, n_blocks, Hkv, block_size, Dh]`` and every slot owns a block *table*
 instead of a worst-case-length cache row, so a request's KV footprint is
 ``ceil(len / block_size)`` blocks. The engine has two sets of programs over
-that pool (``paged_attention=``). The default (``"auto"``/``"kernel"``)
-writes a chunk's K/V through the block table (:func:`paged_update`) and
-attends straight out of the pool (ops/paged_attention.py): no contiguous
-working set exists. The other, ``"gather"``, is the parity fallback: it
-gathers each slot's blocks into a contiguous ``[lane_length]`` view
+that pool (``paged_attention=``). The default, ``"auto"``, writes a chunk's
+K/V through the block table (:func:`paged_update`) and attends straight out
+of the pool (ops/paged_attention.py, which alone decides between its Pallas
+kernels and the XLA reference): no contiguous working set exists. The
+other, ``"gather"``, is the reference the parity tests and the benchmark's
+``correct`` compare against: it gathers each slot's blocks into a
+contiguous ``[lane_length]`` view
 (:func:`gather_block_views`) — the exact shapes the slot-row engine
 compiled, so greedy outputs stay byte-identical — runs the unchanged model,
 and scatters the written blocks back (:func:`scatter_block_views`).

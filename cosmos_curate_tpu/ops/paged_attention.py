@@ -45,13 +45,14 @@ its side of the contract by indexing every dimension but ``D`` (its module
 docstring; pinned by ``tests/ops/test_tpu_compile.py``). A change to the
 page's shape here is a change to that contract.
 
-Off-TPU the default is NOT interpret-mode Pallas but a ``jax.lax``
-reference that mirrors ``DecoderLayer``'s XLA attention lines exactly
-(same einsums, same mask construction, same fp32 softmax), so the engine's
-byte-identical parity contract (tests/models/test_paged_kv.py) holds on
-CPU: the reference gathers per-layer blocks for the einsum but never
-scatters a view back. ``CURATE_PAGED_KERNEL=1|0`` forces the Pallas /
-reference path regardless of platform (interpret mode fills in off-TPU).
+Which implementation runs is decided here and nowhere else, from what the
+code can observe: on a TPU the Pallas kernels (``D % 128`` picks the decode
+form, above), elsewhere ``reference_attention``, the plain XLA lines, over
+the row's pages gathered for the einsum (never scattered back); NOT
+interpret-mode Pallas. ``DecoderLayer``'s slot-cache branch (the engine's
+``gather`` programs) calls the same function, so the byte-identical parity
+contract (tests/models/test_paged_kv.py) holds on CPU by construction.
+Tests and ``chip_smoke.py`` pick a side with ``use_kernel=`` / ``interpret=``.
 
 ``paged_head_attention`` wraps the op in a ``shard_map`` over the model
 mesh axis: KV pool and queries shard over heads, block tables and lengths
@@ -62,7 +63,6 @@ replicate — the tensor-parallel form traced by shardcheck's
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -75,39 +75,39 @@ _NEG_INF = -1e30
 _GROUP_BUFFER_BYTES = 128 * 1024  # one of the decode kernel's four page buffers
 
 
-def use_paged_kernel() -> bool:
-    """Platform/env gate for the Pallas path (mirrors ``_flash_gate``):
-    ``CURATE_PAGED_KERNEL=1`` forces the kernel, ``=0`` forces the XLA
-    reference, otherwise the kernel runs on real TPUs only."""
-    env = os.environ.get("CURATE_PAGED_KERNEL")
-    if env is not None:
-        return env == "1"
-    return jax.devices()[0].platform == "tpu"
-
-
-def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale):
-    """Byte-parity XLA path: gathers the slot's blocks for the einsum (no
-    scatter-back) and then replays DecoderLayer's reference attention lines
-    verbatim — same primitive sequence on the same shapes/values, so CPU
-    outputs are bit-equal to the gather programs."""
-    b, t, hk, g, d = q.shape
-    nbl = tables.shape[1]
-    bs = pool_k.shape[3]
-    s = nbl * bs
-    # [B, nbl, Hkv, bs, D] -> the slot-row view [B, Hkv, S, D]
-    new_k = pool_k[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
-    new_v = pool_v[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
+def reference_attention(q, k, v, write_index, kv_len, *, sm_scale):
+    """The XLA attention every kernel here is held to, and the one
+    ``DecoderLayer``'s slot-cache branch runs. q: ``[B, T, Hkv, G, D]``
+    unscaled grouped queries; k, v: the rows' K/V ``[B, Hkv, S, D]`` with
+    this chunk already written; write_index / kv_len: ``[B]``. Heads stay
+    grouped against the KV's ``Hkv`` (no ``jnp.repeat``: the bytes read are
+    the true KV size). Causality is over cache order (``write_index`` +
+    chunk offset): under m-rope the rope positions are not monotone in it.
+    Returns ``[B, T, Hkv, G, D]`` in q's dtype."""
+    t, s = q.shape[1], k.shape[2]
     qg = q * sm_scale
     logits = jnp.einsum(
-        "btkgd,bksd->bkgts", qg.astype(jnp.float32), new_k.astype(jnp.float32)
+        "btkgd,bksd->bkgts", qg.astype(jnp.float32), k.astype(jnp.float32)
     )
-    k_pos = jnp.arange(s)[None, None, None, None, :]
-    q_seq = write_index[:, None] + jnp.arange(t)[None, :]
+    k_pos = jnp.arange(s)[None, None, None, None, :]  # cache slot index
+    q_seq = write_index[:, None] + jnp.arange(t)[None, :]  # [B, T]
     causal = k_pos <= q_seq[:, None, None, :, None]
     written = k_pos < kv_len[:, None, None, None, None]
     logits = jnp.where(causal & written, logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bkgts,bksd->btkgd", probs.astype(q.dtype), new_v)
+    return jnp.einsum("bkgts,bksd->btkgd", probs.astype(q.dtype), v)
+
+
+def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale):
+    """``reference_attention`` over the rows' pages, gathered for the
+    einsum (no scatter-back): the same primitives on the same shapes as the
+    slot-cache branch, so CPU outputs are bit-equal to the gather programs."""
+    b, _, hk, _, d = q.shape
+    s = tables.shape[1] * pool_k.shape[3]
+    # [B, nbl, Hkv, bs, D] -> the slot-row view [B, Hkv, S, D]
+    k = pool_k[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
+    v = pool_v[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
+    return reference_attention(q, k, v, write_index, kv_len, sm_scale=sm_scale)
 
 
 def _decode_pages(bs: int, hk: int, d: int, dtype, nbl: int) -> int:
@@ -456,19 +456,19 @@ def paged_attention(
     """Attention straight out of the paged KV pool, no gathered working set.
 
     q: ``[B, T, Hkv, G, D]`` UNSCALED grouped queries (this op applies
-    ``sm_scale`` so the reference path matches DecoderLayer bitwise);
+    ``sm_scale``, in the kernels as in the reference);
     pool_k/pool_v: the full block pools ``[L, NB, Hkv, bs, D]`` with the
     chunk's K/V already written through the table; tables: ``[B, nbl]``
     logical-to-physical block ids; write_index/kv_len: ``[B]``. Serves both
     decode (T=1) and chunked prefill (T>1). Returns ``[B, T, Hkv, G, D]``.
 
-    ``use_kernel=None`` resolves via :func:`use_paged_kernel` (env override,
-    else TPU-only); the off-kernel path is the byte-parity XLA reference.
+    ``use_kernel=None`` means the Pallas kernels on a TPU and the XLA
+    reference (:func:`reference_attention` over the gathered pages) elsewhere.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if use_kernel is None:
-        use_kernel = use_paged_kernel()
+        use_kernel = jax.devices()[0].platform == "tpu"
     if not use_kernel:
         return _paged_reference(
             q, pool_k, pool_v, tables, write_index, kv_len,

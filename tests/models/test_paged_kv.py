@@ -29,6 +29,7 @@ from cosmos_curate_tpu.models.vlm import (
     VLM_TINY_TEST,
 )
 from cosmos_curate_tpu.models.vlm.model import init_cache
+from tests.ops.test_tpu_compile import WIDTHS  # (Hkv, G, D) of the flavors the kernels serve
 
 TOK = ByteTokenizer()
 PREFIX = "system: you are a terse captioner. user:"
@@ -373,13 +374,28 @@ class TestPagedAttentionModes:
         with pytest.raises(ValueError):
             CaptionEngine(VLM_TINY_TEST, paged_attention="bogus")
 
-    def test_env_override_beats_constructor(self, monkeypatch):
+    @pytest.mark.parametrize(
+        "name",
+        ["CURATE_PAGED_ATTENTION", "CURATE_PAGED_KERNEL", "CURATE_FLASH_DECODE", "CURATE_FLASH_PREFILL"],
+    )
+    def test_a_deleted_switch_left_in_the_environment_is_inert(self, monkeypatch, name):
+        """The four variables that once chose an attention family are read
+        by nothing: what an operator's shell or the benchmark's driver
+        still sets changes neither the programs an engine builds nor a
+        token of its output."""
+
+        def run():
+            eng = self._mode_engine("auto", max_batch=2, kv_lanes=((64, 2),), prefill_chunk=16)
+            got = _drain(eng, [_req("short", text="hi", max_new=4)])
+            return got, eng.stats()
+
+        want, _ = run()
+        monkeypatch.setenv(name, "1")
         monkeypatch.setenv("CURATE_PAGED_ATTENTION", "gather")
-        eng = CaptionEngine(VLM_TINY_TEST, paged_attention="kernel")
-        assert eng.paged_attention == "gather"
-        monkeypatch.setenv("CURATE_PAGED_ATTENTION", "nonsense")
-        with pytest.raises(ValueError):
-            CaptionEngine(VLM_TINY_TEST)
+        got, stats = run()
+        assert stats["paged_attention"] == "auto"
+        assert stats["paged_kernel_steps"] > 0
+        assert got == want
 
     def test_stats_surface_block_size_fallback_and_mode(self):
         # 24 does not divide 64/128 lanes: gcd fallback shrinks it to 8 —
@@ -480,6 +496,58 @@ class TestPagedAttentionModes:
         )
         assert got["frag"] == want
         eng._allocator.decref(held[1::2])
+
+
+class TestSlotBranchAgainstPagedBranch:
+    """``DecoderLayer``'s slot-cache branch (the ``gather`` programs) and
+    its paged branch on the XLA reference run one attention function on the
+    same shapes, so a layer's output and the K/V it wrote are bit-equal at
+    every width the kernels are compiled for, not only at ``VLM_TINY_TEST``."""
+
+    B, S, BS, LAYER = 2, 64, 16, 1
+
+    @pytest.mark.parametrize("widths", sorted(WIDTHS))
+    @pytest.mark.parametrize("t,write", [(1, [37, 5]), (16, [21, 32])], ids=["decode", "chunk-T16"])
+    def test_bit_equal(self, widths, t, write):
+        from cosmos_curate_tpu.models.vlm.model import DecoderLayer, VLMConfig
+        from cosmos_curate_tpu.models.vlm.paged_kv import gather_block_views
+
+        hk, g, d = WIDTHS[widths]
+        cfg = VLMConfig(dim=64, n_heads=hk * g, n_kv_heads=hk, head_dim=d, hidden_mult=2.0)
+        layer = DecoderLayer(cfg)
+        rng = np.random.default_rng(zlib.crc32(widths.encode()) + t)
+        nbl = self.S // self.BS
+        n_blocks = self.B * nbl + 2
+        pool_k, pool_v = (
+            jnp.asarray(rng.standard_normal((2, n_blocks, hk, self.BS, d)), jnp.bfloat16)
+            for _ in range(2)
+        )
+        ids = rng.permutation(np.arange(1, n_blocks))[: self.B * nbl]
+        tables = jnp.asarray(ids.reshape(self.B, nbl), jnp.int32)
+        x = jnp.asarray(rng.standard_normal((self.B, t, cfg.dim)), jnp.bfloat16)
+        write = jnp.asarray(write, jnp.int32)
+        positions = write[:, None] + jnp.arange(t)[None, :]
+        rows_k, rows_v = (c[self.LAYER] for c in gather_block_views(pool_k, pool_v, tables))
+        params = layer.init(
+            jax.random.PRNGKey(0), x, rows_k, rows_v, positions, write, write + t
+        )
+
+        y_slot, new_k, new_v = jax.jit(layer.apply)(
+            params, x, rows_k, rows_v, positions, write, write + t
+        )
+        y_paged, new_pool_k, new_pool_v = jax.jit(
+            partial(layer.apply, layer_index=self.LAYER)
+        )(params, x, pool_k, pool_v, positions, write, write + t, block_tables=tables)
+
+        np.testing.assert_array_equal(
+            np.asarray(y_paged, np.float32), np.asarray(y_slot, np.float32)
+        )
+        paged_k, paged_v = (
+            c[self.LAYER] for c in gather_block_views(new_pool_k, new_pool_v, tables)
+        )
+        np.testing.assert_array_equal(np.asarray(paged_k, np.float32), np.asarray(new_k, np.float32))
+        np.testing.assert_array_equal(np.asarray(paged_v, np.float32), np.asarray(new_v, np.float32))
+        assert not np.array_equal(np.asarray(new_k, np.float32), np.asarray(rows_k, np.float32))
 
 
 class TestSharedEngineMeshGeometry:

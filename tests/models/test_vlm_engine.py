@@ -349,25 +349,6 @@ class TestKVLanes:
         assert outs[0] == outs[1]
 
 
-class TestFlashPrefillPath:
-    def test_greedy_output_identical_with_flash_prefill(self, monkeypatch):
-        """The Pallas prefill kernel (interpreter off-TPU) must be
-        numerically interchangeable with the XLA prefill path."""
-        text = "p " * 30
-
-        def run():
-            eng = CaptionEngine(VLM_TINY_TEST, max_batch=2, prefill_chunk=16)
-            eng.setup()
-            eng.add_request(_req("f", text=text, max_new=8))
-            return eng.run_until_complete()[0].text
-
-        monkeypatch.setenv("CURATE_FLASH_PREFILL", "0")
-        base = run()
-        monkeypatch.setenv("CURATE_FLASH_PREFILL", "1")
-        flash = run()
-        assert base == flash
-
-
 def test_vlm_flavors_resolve():
     from cosmos_curate_tpu.models import registry
     from cosmos_curate_tpu.models.vlm.model import VLM_FLAVORS, vlm_flavor

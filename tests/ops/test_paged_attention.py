@@ -14,11 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cosmos_curate_tpu.ops.paged_attention import (
-    paged_attention,
-    paged_head_attention,
-    use_paged_kernel,
-)
+from cosmos_curate_tpu.ops.paged_attention import paged_attention, paged_head_attention
 
 
 def _dense_reference(q, k_cache, v_cache, write_index, kv_len, sm_scale):
@@ -61,7 +57,9 @@ def _fragmented_case(rng, *, b, t, hk, g, d, nbl, bs, n_blocks, dtype=jnp.float3
 
 
 class TestReferencePath:
-    @pytest.mark.parametrize("b,hk,g,d,nbl,bs", [(2, 2, 4, 16, 4, 16), (3, 1, 2, 32, 2, 8)])
+    @pytest.mark.parametrize(
+        "b,hk,g,d,nbl,bs", [(2, 2, 4, 16, 4, 16), (3, 1, 2, 32, 2, 8), (3, 1, 1, 16, 8, 16)]
+    )
     def test_decode_matches_dense_oracle(self, b, hk, g, d, nbl, bs):
         rng = np.random.default_rng(0)
         q, pk, pv, tables, layer, kc, vc = _fragmented_case(
@@ -130,6 +128,7 @@ class TestInterpretKernel:
         [
             pytest.param(2, 2, 4, 16, 4, 16, None, id="one-group"),
             pytest.param(1, 2, 6, 32, 3, 8, None, id="g6-bs8"),
+            pytest.param(3, 1, 1, 16, 8, 16, None, id="g1-one-kv-head"),
             pytest.param(2, 2, 4, 128, 20, 16, [131, 257], id="ragged-groups"),
             pytest.param(3, 2, 6, 128, 20, 16, [1, 320, 128], id="idle-row-beside-full-lane"),
             pytest.param(2, 1, 7, 128, 40, 16, [640, 333], id="one-kv-head-tp4-shard"),
@@ -230,22 +229,45 @@ class TestInterpretKernel:
             np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
         assert _paged_decode._cache_size() == 1
 
-    def test_prefill_kernel_matches_reference_offset_and_ragged_t(self):
-        """write_index > 0 plus a chunk length that does not tile block_q:
-        the pad rows must not disturb the valid window."""
+    # write_index and valid tokens a row; ``poison``: every table entry past
+    # a row's valid length points at a block of huge finite garbage (the
+    # engine points them at its block 0), which the kernel's skipped grid
+    # steps must keep out of the result while the reference reads the clean
+    # table. A chunk's rows past ``t_valid`` are padding: the engine reads
+    # none of them, but both sides define them the same way.
+    @pytest.mark.parametrize(
+        "t,write,t_valid,poison",
+        [
+            pytest.param(13, [0, 23], [13, 13], False, id="offset-and-ragged-t"),
+            pytest.param(16, [0, 0], [16, 16], False, id="bucket-from-zero"),
+            pytest.param(16, [16, 32], [16, 16], False, id="later-chunks"),
+            pytest.param(16, [0, 20], [16, 9], False, id="padded-chunk"),
+            pytest.param(16, [48, 5], [32, 40], False, id="valid-length-past-the-chunk"),
+            pytest.param(8, [0, 17], [8, 8], True, id="garbage-past-the-valid-length"),
+        ],
+    )
+    def test_prefill_kernel_matches_reference(self, t, write, t_valid, poison):
+        """write_index 0 and > 0, a chunk length that does and does not tile
+        block_q (the pad rows must not disturb the valid window), fewer
+        valid tokens than the chunk holds, and more."""
         rng = np.random.default_rng(4)
-        b, t, hk, g, d, nbl, bs = 2, 13, 2, 3, 16, 4, 16
+        b, hk, g, d, nbl, bs = 2, 2, 3, 16, 6, 16
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
             rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
         )
-        write = jnp.asarray([0, 23], jnp.int32)
-        kv_len = write + t
+        write = jnp.asarray(write, jnp.int32)
+        kv_len = write + jnp.asarray(t_valid, jnp.int32)
+        want = paged_attention(
+            q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False
+        )
+        if poison:
+            assert 0 not in np.asarray(tables)
+            pk, pv = pk.at[:, 0].set(1e6), pv.at[:, 0].set(-1e6)
+            dead = np.arange(nbl)[None, :] * bs >= np.asarray(kv_len)[:, None]
+            tables = jnp.where(dead, 0, tables)
         got = paged_attention(
             q, pk, pv, tables, write, kv_len,
             layer_index=layer, use_kernel=True, interpret=True, block_q=8,
-        )
-        want = paged_attention(
-            q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
 
@@ -304,10 +326,22 @@ class TestHeadParallel:
         np.testing.assert_allclose(np.asarray(sharded), np.asarray(single), atol=1e-6, rtol=0)
 
 
-def test_env_gate(monkeypatch):
-    monkeypatch.setenv("CURATE_PAGED_KERNEL", "1")
-    assert use_paged_kernel()
-    monkeypatch.setenv("CURATE_PAGED_KERNEL", "0")
-    assert not use_paged_kernel()
-    monkeypatch.delenv("CURATE_PAGED_KERNEL")
-    assert use_paged_kernel() == (jax.devices()[0].platform == "tpu")
+def test_kernel_choice_reads_no_environment():
+    """Which attention implementation runs is decided in
+    ``ops/paged_attention.py`` from the platform and the shapes; the model
+    and the kernels consult no environment variable (the four switches that
+    did are gone, and none comes back unnoticed)."""
+    import pathlib
+    import re
+
+    import cosmos_curate_tpu
+
+    root = pathlib.Path(cosmos_curate_tpu.__file__).parent
+    hits = [
+        f"{path.relative_to(root)}:{n}: {line.strip()}"
+        for sub in ("models/vlm", "ops")
+        for path in sorted((root / sub).rglob("*.py"))
+        for n, line in enumerate(path.read_text().splitlines(), 1)
+        if re.search(r"\benviron\b|\bgetenv\b", line)
+    ]
+    assert not hits, hits

@@ -65,35 +65,14 @@ def _paged_decode(hk, g, d, arg, rows=B, lane=S):
     )
 
 
-def _paged_prefill(hk, g, d, arg):
+def _paged_prefill(hk, g, d, arg, rows=B, lane=S):
     from cosmos_curate_tpu.ops.paged_attention import _paged_prefill as fn
 
     pool = arg((LAYERS, POOL_BLOCKS, hk, BS, d), jnp.bfloat16)
     return (
         functools.partial(fn, layer_index=1, sm_scale=d**-0.5, block_q=128, interpret=False),
-        (arg((B, T, hk, g, d), jnp.bfloat16), pool, pool,
-         arg((B, S // BS), jnp.int32), arg((B,), jnp.int32), arg((B,), jnp.int32)),
-    )
-
-
-def _decode(hk, g, d, arg):
-    from cosmos_curate_tpu.ops.decode_attention import decode_attention as fn
-
-    cache = arg((B, hk, S, d), jnp.bfloat16)
-    return (
-        functools.partial(fn, interpret=False),
-        (arg((B, hk, g, d), jnp.bfloat16), cache, cache, arg((B,), jnp.int32)),
-    )
-
-
-def _prefill(hk, g, d, arg):
-    from cosmos_curate_tpu.ops.prefill_attention import prefill_attention as fn
-
-    cache = arg((B, hk, S, d), jnp.bfloat16)
-    return (
-        functools.partial(fn, interpret=False),
-        (arg((B, T, hk, g, d), jnp.bfloat16), cache, cache,
-         arg((B,), jnp.int32), arg((B,), jnp.int32)),
+        (arg((rows, T, hk, g, d), jnp.bfloat16), pool, pool,
+         arg((rows, lane // BS), jnp.int32), arg((rows,), jnp.int32), arg((rows,), jnp.int32)),
     )
 
 
@@ -108,8 +87,6 @@ def _flash(hk, g, d, arg):
 KERNELS = {
     "paged_decode": _paged_decode,
     "paged_prefill": _paged_prefill,
-    "decode_attention": _decode,
-    "prefill_attention": _prefill,
     "flash_attention": _flash,
 }
 
@@ -125,17 +102,26 @@ def test_kernel_compiles_for_v5e(v5e, kernel, widths):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("widths", sorted(WIDTHS))
-def test_paged_decode_compiles_at_the_widest_lane(v5e, widths):
-    """The 4096 lane is the longest block table a cell runs: 256 entries a
-    row in scalar memory, four rows. The compiler refuses a kernel that
-    wants more VMEM than a call may have, so compiling is the check (the
-    page buffers are sized from the page, not from the table)."""
+@pytest.mark.parametrize(
+    "kernel,rows,widths",
+    [
+        *(pytest.param("paged_decode", 4, w, id=f"decode-4-rows-{w}") for w in sorted(WIDTHS)),
+        pytest.param("paged_decode", 2, "qwen25vl-7b-shard", id="decode-2-rows-tp4-cell"),
+        *(pytest.param("paged_prefill", 1, w, id=f"prefill-1-row-{w}") for w in sorted(WIDTHS)),
+    ],
+)
+def test_paged_kernels_compile_at_the_widest_lane(v5e, kernel, rows, widths):
+    """The 4096 lane is the longest block table a cell runs, 256 entries a
+    row in scalar memory, at the shapes the cells give the kernels there:
+    a decode step over the lane's four rows (two in the tp4 cell), a
+    256-token chunk of one row. The compiler refuses a kernel that wants
+    more VMEM than a call may have, so compiling is the check (the page
+    buffers are sized from the page, not from the table)."""
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    fn, args = _paged_decode(*WIDTHS[widths], arg, rows=4, lane=4096)
+    fn, args = KERNELS[kernel](*WIDTHS[widths], arg, rows=rows, lane=4096)
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
