@@ -446,6 +446,22 @@ def _logits_agree(name: str, got: dict, want: dict) -> None:
         log(f"{name}: {rid} first-step logits max_err {err:.4f} (max |logit| {scale:.3f})")
 
 
+def _log_params(name: str, engine) -> None:
+    """What the engine serves from: bytes on one chip and leaves by dtype."""
+    import jax
+
+    by_dtype: dict = {}  # dtype -> [leaves, bytes of the whole leaves]
+    for x in jax.tree.leaves(engine.params):
+        seen = by_dtype.setdefault(str(x.dtype), [0, 0])
+        seen[0] += 1
+        seen[1] += x.nbytes
+    per_chip = engine.stats()["param_bytes_per_chip"]
+    log(
+        f"{name}: param_bytes_per_chip {per_chip} ({per_chip / 2**30:.3f} GiB); leaves by dtype "
+        + ", ".join(f"{d}: {n} ({b / 2**30:.3f} GiB whole)" for d, (n, b) in sorted(by_dtype.items()))
+    )
+
+
 def phase_caption_2b(*, seed: int = 0) -> None:
     """Qwen2-VL-2B, nothing cut: 28 layers, 1536 wide, GQA 12/2, vocab
     151936, the 32x1280 vision tower; the flavor's own KV lanes."""
@@ -461,6 +477,7 @@ def phase_caption_2b(*, seed: int = 0) -> None:
     engine.setup(seed)
     jax.block_until_ready(engine.params)
     log(f"caption-2b: engine set-up {time.monotonic() - t0:.1f}s (seeded init, no compiles yet)")
+    _log_params("caption-2b", engine)
 
     # 200-2000 prompt tokens: four ride the 1024 lane, four the 4096 lane
     totals = (200, 250, 600, 900, 1200, 1500, 1800, 2000)
@@ -524,6 +541,7 @@ def phase_sharded(cfg, lanes, totals, *, n_frames: int = 8, max_new: int = 16, s
     sharded.setup(seed)
     jax.block_until_ready(sharded.params)
     log(f"sharded: both engines set up in {time.monotonic() - t0:.1f}s")
+    _log_params("sharded", sharded)
 
     # placement: every device holds its 1/n of what is partitioned — the
     # parameters by their annotations, the KV pool by its head planes
@@ -610,7 +628,8 @@ def phase_reference(
 ):
     """The flavor's engine, built the way the caption stage builds it (its
     own mesh, lanes and background prep; seeded float32 parameters made
-    split, as the benchmark's driver makes them), against the plain float32
+    split, as the benchmark's driver makes them, which the engine narrows to
+    the types it serves from), against the plain float32
     reference of the whole model: a text request, prefilled whole, and a
     caption window (frames behind a shared prefix), prefilled in chunks while
     the first decodes beside it; first-step logits and ``n_steps`` decode
@@ -643,6 +662,7 @@ def phase_reference(
         f"one chip holds {stats['param_bytes_per_chip'] / 2**30:.3f} GiB of parameters and "
         f"{stats['kv_pool_bytes_per_chip'] / 2**20:.1f} MiB of KV pool"
     )
+    _log_params("reference", engine)
     whole = sum(x.nbytes for x in jax.tree.leaves(engine.params))
     repeated = (stats["param_bytes_per_chip"] * mesh.size - whole) / (mesh.size - 1)
     log(
@@ -747,7 +767,8 @@ def phase_collective_names(engine) -> None:
 def run_sharded() -> None:
     """Qwen2.5-VL-7B widths (3584 wide, 28/4 heads: one KV head per chip),
     untouched. Depth cut 28 -> 4 so that device 0 can ALSO hold the same
-    seeded model whole, in fp32, for the comparison."""
+    seeded model whole (made in fp32, then narrowed to what the engine serves
+    from), for the comparison."""
     from cosmos_curate_tpu.models.vlm.model import vlm_flavor
 
     flavor = vlm_flavor("qwen25vl-7b")

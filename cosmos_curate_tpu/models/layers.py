@@ -8,7 +8,9 @@ so each block needs exactly one ``psum`` (inserted automatically by XLA at
 the sharded->replicated boundary). Replaces the reference's reliance on
 vLLM-internal NCCL TP (SURVEY.md §2.7).
 
-Compute dtype is bf16 by default (MXU-native); params stay f32.
+Compute dtype is bf16 by default (MXU-native). Parameters are stored in
+float32 unless a model passes ``param_dtype`` down to ``dense`` (the caption
+VLM does, so that its engine serves from the type it computes in).
 """
 
 from __future__ import annotations
@@ -24,9 +26,18 @@ from cosmos_curate_tpu.parallel.axes import MODEL as MODEL_AXIS
 Dtype = Any
 
 
-def dense(features: int, shard: str | None, name: str | None = None, use_bias: bool = True, dtype=jnp.bfloat16):
+def dense(
+    features: int,
+    shard: str | None,
+    name: str | None = None,
+    use_bias: bool = True,
+    dtype=jnp.bfloat16,
+    param_dtype=jnp.float32,
+):
     """Dense with kernel sharding: shard='out' partitions output features,
-    'in' partitions input features, None replicates."""
+    'in' partitions input features, None replicates. ``dtype`` is the type
+    the layer computes in, ``param_dtype`` the type its kernel and bias are
+    stored in (flax casts them to ``dtype`` at every call where they differ)."""
     if shard == "out":
         spec = (None, MODEL_AXIS)
         bias_spec = (MODEL_AXIS,)
@@ -44,7 +55,7 @@ def dense(features: int, shard: str | None, name: str | None = None, use_bias: b
         features,
         use_bias=use_bias,
         dtype=dtype,
-        param_dtype=jnp.float32,
+        param_dtype=param_dtype,
         kernel_init=kernel_init,
         bias_init=bias_init,
         name=name,

@@ -54,9 +54,10 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -298,6 +299,46 @@ def _bytes_per_chip(tree) -> int:
     return total
 
 
+def _init_params(model: VLM, seed: int = 0):
+    """``model``'s parameters from ``seed`` (boxed: every leaf carries its
+    partition annotation), through the one method that touches every layer."""
+    cfg = model.cfg
+    size = (
+        cfg.qwen_vision.image_size
+        if cfg.vision_variant in ("qwen2", "qwen3")
+        else cfg.vision.image_size
+    )
+    return model.init(
+        jax.random.PRNGKey(seed),
+        jnp.zeros((1, 1, size, size, 3), jnp.uint8),
+        jnp.zeros((1, 4), jnp.int32),
+        *init_cache(cfg, 1),
+        method=model.init_everything,
+    )
+
+
+@lru_cache(maxsize=8)
+def _abstract_params(model: VLM):
+    """Shape, dtype and partition annotation of every parameter of
+    ``model``, nothing made. Tracing a 2B model's init takes seconds, and
+    engines that share one tree share one model: traced once a process."""
+    return jax.eval_shape(partial(_init_params, model))
+
+
+def _stored_as(leaf, dtype):
+    """``leaf`` in ``dtype``. A leaf of another type is cast (a placed
+    array keeps its sharding) and its source DELETED at once, so that a tree
+    is narrowed with never more than one leaf held twice, whoever else still
+    refers to the wider tree; a leaf already in ``dtype`` is returned as it
+    is, so that engines can share one tree."""
+    if leaf.dtype == dtype:
+        return leaf
+    wide = jnp.asarray(leaf)  # the caller's own array, or a host leaf's copy
+    stored = wide.astype(dtype)
+    wide.delete()
+    return stored
+
+
 class CaptionEngine:
     def __init__(
         self,
@@ -319,6 +360,15 @@ class CaptionEngine:
         paged_attention: str = "auto",
         mesh: Any = None,
     ) -> None:
+        """``params`` (here or assigned to ``.params`` later) are CONSUMED,
+        as a jitted call consumes a donated argument: the engine serves from
+        parameters stored in the type each layer computes in (bfloat16 for
+        the matmuls and the embedding table; float32 for norm scales, the MoE
+        router and an untied head), so ``setup()`` casts every leaf that
+        comes in another type once and deletes its source as it goes. A
+        caller that needs its wider tree afterwards hands in a copy. Leaves
+        already in their serving type are kept as they are, never deleted:
+        ``CaptionEngine(cfg, params=other.params)`` shares one tree."""
         self.cfg = cfg
         self.max_batch = max_batch
         # prompts longer than this prefill in chunks of this size,
@@ -341,11 +391,15 @@ class CaptionEngine:
         # runs head-parallel over parallel/axes.MODEL when the mesh names
         # that axis (KV pool + heads sharded, block tables replicated)
         self.mesh = mesh
-        self.model = VLM(cfg, mesh=mesh)
-        # per-leaf PartitionSpecs once setup() has read them off the model
-        # (mesh engines only): from then on whatever is assigned to
-        # ``params`` — a checkpoint loaded after setup, another engine's
-        # tree — is placed over the mesh, never left on one device
+        # parameters stored in the type they are computed in: see VLM.param_dtype
+        self.model = VLM(cfg, mesh=mesh, param_dtype=VLM.dtype)
+        # the model's abstract parameter tree once setup() has taken it: per
+        # leaf the serving dtype and (mesh engines only) the PartitionSpec.
+        # From then on whatever is assigned to ``params`` — a handed-in tree,
+        # a checkpoint loaded after setup, another engine's tree — is placed
+        # over the mesh and stored in those types, never left on one device
+        # or in a type every program would have to cast from
+        self._param_dtypes: Any = None
         self._param_specs: Any = None
         self.params = params
         self.waiting: list[CaptionRequest] = []
@@ -523,6 +577,8 @@ class CaptionEngine:
             from cosmos_curate_tpu.parallel.sharding import place_partitioned
 
             value = place_partitioned(self.mesh, value, self._param_specs)
+        if value is not None and self._param_dtypes is not None:
+            value = jax.tree.map(_stored_as, nn.unbox(value), self._param_dtypes)
         self._params = value
         self._param_bytes_per_chip = _bytes_per_chip(value)
 
@@ -545,39 +601,28 @@ class CaptionEngine:
     # -- setup ----------------------------------------------------------
     def setup(self, seed: int = 0) -> None:
         cfg = self.cfg
-        size = (
-            cfg.qwen_vision.image_size
-            if cfg.vision_variant in ("qwen2", "qwen3")
-            else cfg.vision.image_size
-        )
-
-        def init():
-            return self.model.init(
-                jax.random.PRNGKey(seed),
-                jnp.zeros((1, 1, size, size, 3), jnp.uint8),
-                jnp.zeros((1, 4), jnp.int32),
-                *init_cache(cfg, 1),
-                method=self.model.init_everything,
-            )
-
+        # Place and narrow everything ONCE, here. The serving model's
+        # abstract init says where each leaf lives and in which type: the
+        # dtype its layer computes in, and on a mesh the model's
+        # nn.with_partitioning annotation (so a loaded checkpoint's plain
+        # tree places the same way). The block pools go by their KV-head
+        # planes: left on the default device, the head-parallel shard_map
+        # would re-distribute the whole pool on every step.
+        abstract = _abstract_params(self.model)
+        self._param_dtypes = jax.tree.map(lambda x: x.dtype, nn.unbox(abstract))
+        # seeded parameters are the float32 model's, narrowed like any other
+        # tree: a seeded engine serves what handing in ``VLM(cfg).init`` would
+        seeded = partial(_init_params, self.model.clone(param_dtype=jnp.float32), seed)
         pool_sharding = None
         if self.mesh is None:
-            if self.params is None:
-                self.params = init()
+            self.params = seeded() if self._params is None else self._params
         else:
-            # Place everything ONCE, here: parameters by the model's
-            # nn.with_partitioning annotations (read off an abstract init,
-            # so a loaded checkpoint's plain tree places the same way), the
-            # block pools by their KV-head planes. Left on the default
-            # device, the head-parallel shard_map would re-distribute the
-            # whole pool on every step.
-            import flax.linen as nn
             from jax.sharding import PartitionSpec as P
 
             from cosmos_curate_tpu.parallel.axes import MODEL
             from cosmos_curate_tpu.parallel.sharding import spec_sharding
 
-            self._param_specs = nn.get_partition_spec(jax.eval_shape(init))
+            self._param_specs = nn.get_partition_spec(abstract)
             if self._params is None:
                 # made split: a flavor served over a mesh need not fit one chip
                 shardings = jax.tree.map(
@@ -586,9 +631,9 @@ class CaptionEngine:
                     is_leaf=lambda x: isinstance(x, P),
                 )
                 self._params = jax.jit(
-                    lambda: nn.unbox(init()), out_shardings=shardings
+                    lambda: nn.unbox(seeded()), out_shardings=shardings
                 )()
-            self.params = self._params  # the setter places
+            self.params = self._params  # the setter places and narrows
             pool_sharding = spec_sharding(self.mesh, P(None, None, MODEL, None, None))
         self._pool_k, self._pool_v = init_block_pool(
             cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding

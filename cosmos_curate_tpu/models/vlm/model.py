@@ -259,7 +259,8 @@ class FlavorSpec:
     # Chips of one host the flavor's parameters and KV pool are split over
     # (the extent of the ``model`` mesh axis the caption stage builds at
     # setup); 1 = the whole model on one chip, no mesh. Travels with the
-    # checkpoint choice as the lanes do: a 7B in float32 fits no 16 GB chip.
+    # checkpoint choice as the lanes do: a 7B's 16.5 GiB of serving
+    # parameters (bfloat16, its head float32) fit no 16 GB chip.
     model_chips: int = 1
 
     def __post_init__(self) -> None:
@@ -359,7 +360,7 @@ VLM_FLAVORS.update(
             hf_chat=True,
             # 7B KV rows are 4x the 2B's — halve the lane budget
             kv_lanes=((1024, 4), (4096, 2)),
-            # 33 GB of float32 parameters: one KV head and 7 query heads a chip
+            # 4.1 GiB of parameters a chip: one KV head and 7 query heads each
             model_chips=4,
         ),
         "tiny-test": FlavorSpec(VLM_TINY_TEST, "caption-vlm-tpu", require_weights=False),
@@ -532,6 +533,9 @@ class MoEFFN(nn.Module):
 
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
+    # storage type of the expert tables (consumed in ``dtype``); the router
+    # computes in float32 and stores float32 (VLM.param_dtype has the rule)
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
@@ -576,7 +580,7 @@ class MoEFFN(nn.Module):
                 nn.initializers.normal(0.02), (MODEL_AXIS, None, None)
             ),
             (e, d, 2 * h),
-            jnp.float32,
+            self.param_dtype,
         )
         down = self.param(
             "down",
@@ -584,7 +588,7 @@ class MoEFFN(nn.Module):
                 nn.initializers.normal(0.02), (MODEL_AXIS, None, None)
             ),
             (e, h, d),
-            jnp.float32,
+            self.param_dtype,
         )
         z = jnp.einsum("ecd,edh->ech", expert_in, gate_up.astype(self.dtype))
         gate, up = jnp.split(z, 2, axis=-1)
@@ -617,6 +621,7 @@ TP_SCOPES = {
 class DecoderLayer(nn.Module):
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
     # optional device mesh: when set and it names the model axis, the paged
     # path runs head-parallel (shard_map over Hkv) — see paged_head_attention
     mesh: object = None
@@ -647,11 +652,13 @@ class DecoderLayer(nn.Module):
         cfg = self.cfg
         b, t, _ = x.shape
         h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        # every projection here computes in ``dtype`` and stores ``param_dtype``
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
 
         y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x)
-        q = dense(h * dh, "out", name="q", use_bias=cfg.qkv_bias, dtype=self.dtype)(y)
-        k = dense(hk * dh, "out", name="k", use_bias=cfg.qkv_bias, dtype=self.dtype)(y)
-        v = dense(hk * dh, "out", name="v", use_bias=cfg.qkv_bias, dtype=self.dtype)(y)
+        q = proj(h * dh, "out", name="q", use_bias=cfg.qkv_bias)(y)
+        k = proj(hk * dh, "out", name="k", use_bias=cfg.qkv_bias)(y)
+        v = proj(hk * dh, "out", name="v", use_bias=cfg.qkv_bias)(y)
         q = q.reshape(b, t, h, dh)
         k = k.reshape(b, t, hk, dh)
         if cfg.qk_norm:  # Qwen3 family: per-HEAD-DIM RMSNorm before rope
@@ -720,23 +727,31 @@ class DecoderLayer(nn.Module):
         # the row-parallel matmuls end in an all-reduce over the model axis:
         # the scope names it in a compiled program and in a device trace
         with jax.named_scope(TP_SCOPES["attn_out"]):
-            x = x + dense(cfg.dim, "in", name="o", use_bias=False, dtype=self.dtype)(attn)
+            x = x + proj(cfg.dim, "in", name="o", use_bias=False)(attn)
 
         y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
         if cfg.moe is not None:
-            return x + MoEFFN(cfg, dtype=self.dtype, name="moe")(y), new_k, new_v
-        up = dense(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False, dtype=self.dtype)(y)
-        gate = dense(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False, dtype=self.dtype)(y)
+            moe = MoEFFN(cfg, dtype=self.dtype, param_dtype=self.param_dtype, name="moe")
+            return x + moe(y), new_k, new_v
+        up = proj(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False)(y)
+        gate = proj(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False)(y)
         with jax.named_scope(TP_SCOPES["mlp_down"]):
-            down = dense(cfg.dim, "in", name="down", use_bias=False, dtype=self.dtype)(
-                nn.silu(gate) * up
-            )
+            down = proj(cfg.dim, "in", name="down", use_bias=False)(nn.silu(gate) * up)
         return x + down, new_k, new_v
 
 
 class VLM(nn.Module):
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
+    # The type in which the parameters of every layer that computes in
+    # ``dtype`` are stored: matmul kernels and biases, the embedding table,
+    # the MoE expert tables, the Qwen vision tower. A layer that computes in
+    # float32 says so itself (``lm_head``, the MoE ``router``, every norm
+    # scale, Qwen3's position table) and stores float32 whatever this is. The
+    # default inits what every loader and converter expects; the caption
+    # engine builds its model with ``param_dtype=dtype`` and serves from
+    # bf16(w), the operands a float32 tree is rounded to at every call.
+    param_dtype: jnp.dtype = jnp.float32
     # optional device mesh threaded to every DecoderLayer: enables the
     # head-parallel paged-attention path (tensor parallelism over Hkv)
     mesh: object = None
@@ -747,11 +762,14 @@ class VLM(nn.Module):
             cfg.vocab,
             cfg.dim,
             dtype=self.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=self.param_dtype,
             embedding_init=nn.with_partitioning(nn.initializers.normal(0.02), (None, MODEL_AXIS)),
         )
         self.layers = [
-            DecoderLayer(cfg, dtype=self.dtype, mesh=self.mesh, name=f"layer_{i}")
+            DecoderLayer(
+                cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
+                name=f"layer_{i}",
+            )
             for i in range(cfg.n_layers)
         ]
         self.ln_f = RMSNorm(eps=cfg.rms_eps, name="ln_f")
@@ -761,15 +779,19 @@ class VLM(nn.Module):
             else dense(cfg.vocab, "out", name="lm_head", use_bias=False, dtype=jnp.float32)
         )
         if cfg.vision_variant in ("qwen2", "qwen3"):
-            self.vision_tower = QwenVisionTower(cfg.qwen_vision, dtype=self.dtype, name="vision")
+            self.vision_tower = QwenVisionTower(
+                cfg.qwen_vision, dtype=self.dtype, param_dtype=self.param_dtype, name="vision"
+            )
             self.projector = None  # the Qwen merger already maps to LM dim
         else:
+            # models/vit.py is shared with the embedding stages and keeps
+            # float32 parameters (a few MB in the flavors that use it)
             self.vision_tower = ViT(cfg.vision, dtype=self.dtype, name="vision")
             self.projector = nn.Sequential(
                 [
-                    dense(cfg.dim * 2, None, use_bias=True, dtype=self.dtype),
+                    dense(cfg.dim * 2, None, dtype=self.dtype, param_dtype=self.param_dtype),
                     nn.gelu,
-                    dense(cfg.dim, None, use_bias=True, dtype=self.dtype),
+                    dense(cfg.dim, None, dtype=self.dtype, param_dtype=self.param_dtype),
                 ],
                 name="projector",
             )

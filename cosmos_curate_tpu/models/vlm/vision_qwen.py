@@ -24,6 +24,7 @@ TPU-first differences from the HF implementation (behavior-preserving):
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import flax.linen as nn
 import jax
@@ -288,6 +289,7 @@ class _VisionRMSNorm(nn.Module):
 class QwenVisionBlock(nn.Module):
     cfg: QwenVisionConfig
     dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
 
     def _norm(self, name: str):
         if self.cfg.variant == "qwen2_5":
@@ -303,10 +305,12 @@ class QwenVisionBlock(nn.Module):
         cfg = self.cfg
         b, s, _ = x.shape
         h, dh = cfg.num_heads, cfg.head_dim
+        # every projection here computes in ``dtype`` and stores ``param_dtype``
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
 
         y = self._norm("ln1")(x)
         # fused qkv (one MXU matmul), as in the checkpoint layout
-        qkv = dense(3 * cfg.embed_dim, "out", name="qkv", use_bias=True, dtype=self.dtype)(y)
+        qkv = proj(3 * cfg.embed_dim, "out", name="qkv")(y)
         q, k, v = jnp.split(qkv.reshape(b, s, 3, h, dh), 3, axis=2)
         q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]  # [B, S, H, Dh]
         cos_ = cos[None, :, None, :]
@@ -322,21 +326,21 @@ class QwenVisionBlock(nn.Module):
         probs = jax.nn.softmax(logits, axis=-1)
         attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(self.dtype), v)
         attn = attn.reshape(b, s, h * dh)
-        x = x + dense(cfg.embed_dim, "in", name="proj", use_bias=True, dtype=self.dtype)(attn)
+        x = x + proj(cfg.embed_dim, "in", name="proj")(attn)
 
         y = self._norm("ln2")(x)
         hdim = cfg.mlp_hidden
         if cfg.variant == "qwen2_5":  # SwiGLU (with biases, HF Qwen2_5_VLMLP)
-            gate = dense(hdim, "out", name="gate", use_bias=True, dtype=self.dtype)(y)
-            up = dense(hdim, "out", name="up", use_bias=True, dtype=self.dtype)(y)
+            gate = proj(hdim, "out", name="gate")(y)
+            up = proj(hdim, "out", name="up")(y)
             y = nn.silu(gate) * up
-            return x + dense(cfg.embed_dim, "in", name="down", use_bias=True, dtype=self.dtype)(y)
-        y = dense(hdim, "out", name="fc1", use_bias=True, dtype=self.dtype)(y)
+            return x + proj(cfg.embed_dim, "in", name="down")(y)
+        y = proj(hdim, "out", name="fc1")(y)
         if cfg.variant == "qwen3":  # HF hidden_act gelu_pytorch_tanh
             y = nn.gelu(y, approximate=True)
         else:
             y = quick_gelu(y)
-        return x + dense(cfg.embed_dim, "in", name="fc2", use_bias=True, dtype=self.dtype)(y)
+        return x + proj(cfg.embed_dim, "in", name="fc2")(y)
 
 
 class QwenVisionTower(nn.Module):
@@ -344,18 +348,20 @@ class QwenVisionTower(nn.Module):
 
     cfg: QwenVisionConfig
     dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
 
     @nn.compact
     def __call__(self, patches, grid: tuple[int, int, int]):
         cfg = self.cfg
         b, s, _ = patches.shape
+        # every projection here computes in ``dtype`` and stores ``param_dtype``
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
         assert s == grid[0] * grid[1] * grid[2], (s, grid)
-        x = dense(
+        x = proj(
             cfg.embed_dim,
             None,
             name="patch_embed",
             use_bias=cfg.variant == "qwen3",  # Qwen3's Conv3d carries a bias
-            dtype=self.dtype,
         )(patches.astype(self.dtype))
         if cfg.variant == "qwen3":
             # learned pos-embed table, bilinearly interpolated to the grid
@@ -391,7 +397,9 @@ class QwenVisionTower(nn.Module):
                 mask = windowed_mask
             else:
                 mask = full_mask
-            x = QwenVisionBlock(cfg, dtype=self.dtype, name=f"block_{i}")(x, cos, sin, mask)
+            x = QwenVisionBlock(
+                cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"block_{i}"
+            )(x, cos, sin, mask)
             if cfg.variant == "qwen3" and i in cfg.deepstack_indexes:
                 # deepstack merger (postshuffle norm): merge-window group
                 # FIRST, LayerNorm over the grouped features, then the MLP
@@ -400,15 +408,9 @@ class QwenVisionTower(nn.Module):
                 d = nn.LayerNorm(
                     epsilon=1e-6, dtype=jnp.float32, name=f"ds{level}_norm"
                 )(d)
-                d = dense(
-                    msz2 * cfg.embed_dim, "out", name=f"ds{level}_fc1",
-                    use_bias=True, dtype=self.dtype,
-                )(d)
+                d = proj(msz2 * cfg.embed_dim, "out", name=f"ds{level}_fc1")(d)
                 d = nn.gelu(d, approximate=False)
-                d = dense(
-                    cfg.hidden_size, "in", name=f"ds{level}_fc2",
-                    use_bias=True, dtype=self.dtype,
-                )(d)
+                d = proj(cfg.hidden_size, "in", name=f"ds{level}_fc2")(d)
                 deepstack.append(d)
         # merger: group each merge-window's msz² consecutive tokens
         if cfg.variant == "qwen2_5":
@@ -416,9 +418,9 @@ class QwenVisionTower(nn.Module):
         else:
             x = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name="ln_q")(x)
         x = x.reshape(b, s // msz2, msz2 * cfg.embed_dim)
-        x = dense(msz2 * cfg.embed_dim, "out", name="merger_fc1", use_bias=True, dtype=self.dtype)(x)
+        x = proj(msz2 * cfg.embed_dim, "out", name="merger_fc1")(x)
         x = nn.gelu(x, approximate=False)
-        x = dense(cfg.hidden_size, "in", name="merger_fc2", use_bias=True, dtype=self.dtype)(x)
+        x = proj(cfg.hidden_size, "in", name="merger_fc2")(x)
         if inverse_unit_perm is not None:
             # undo the window permutation so outputs are t-major row-major
             # (what build_mrope_positions and the engine assume)
