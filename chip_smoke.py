@@ -1,6 +1,6 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py             # one TPU chip: kernels, split, caption-2b
+    python chip_smoke.py             # one TPU chip: kernels, split, caption-2b, caption-hybrid
     python chip_smoke.py --chips 4   # four chips: the sharded paths ONLY
 
 One process does everything, so the chip has one owner (the streaming
@@ -21,7 +21,12 @@ weights:
                  720p videos — fixed-stride split, ViT-B/16 video embedder,
                  the default ``base`` captioner — then a 2-video
                  shot-detection pass under the streaming runner;
-- ``caption-2b`` the caption engine at full Qwen2-VL-2B width AND depth.
+- ``caption-2b`` the caption engine at full Qwen2-VL-2B width AND depth;
+- ``caption-hybrid`` Granite-4.0-H-Micro's widths, one period of its layer
+                 pattern (9 Mamba-2 mixers, 1 attention layer): the
+                 state-space decode kernel and the chunked prefill scan
+                 against the XLA recurrence, then the engine (recurrent store
+                 beside the ``D`` = 64 paged pool) against its ``gather`` path.
 
 ``--chips 4`` runs only what exists across chips and what it is compared
 with: the head-parallel caption engine at Qwen2.5-VL-7B widths against the
@@ -58,7 +63,7 @@ BF16_ATOL = BF16_RTOL = 3e-2
 # the largest logit magnitude.
 LOGITS_REL_TOL = 5e-2
 
-PHASES = ("kernels", "split", "caption-2b")
+PHASES = ("kernels", "split", "caption-2b", "caption-hybrid")
 
 
 _T0 = time.monotonic()
@@ -506,6 +511,86 @@ def phase_caption_2b(*, seed: int = 0) -> None:
     log(f"caption-2b: peak device memory {peak / 2**30:.2f} GiB")
 
 
+# -- phase: caption-hybrid ---------------------------------------------------
+
+
+def phase_caption_hybrid(*, seed: int = 0) -> None:
+    """Granite-4.0-H-Micro, every width, one period of ten layers: first the
+    two state-space operations of ops/ssm.py as the chip runs them against
+    the XLA recurrence, then the engine against its own ``gather`` path."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cosmos_curate_tpu.models.vlm import CaptionEngine, CaptionRequest, SamplingConfig
+    from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+    from cosmos_curate_tpu.ops import ssm
+
+    full = vlm_flavor("granite-4.0-h-micro").cfg
+    cfg = dataclasses.replace(full, n_layers=10, layer_types=full.layer_types[:10])
+    m = cfg.mamba
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    # kernels against XLA: 8 rows of a 12-row store, two of them idle
+    rows = jnp.asarray([3, 5, 0, 7, 1, 0, 9, 11], jnp.int32)
+    store = normal(2, 12, m.n_heads, m.head_dim, m.d_state)
+    a = -jnp.arange(1, m.n_heads + 1, dtype=jnp.float32)
+    d = jnp.ones(m.n_heads)
+    x, dt = normal(8, m.n_heads, m.head_dim), jnp.asarray(rng.uniform(0.001, 0.1, (8, m.n_heads)), jnp.float32)
+    dt = dt * (rows > 0)[:, None]
+    b, c = normal(8, m.d_state), normal(8, m.d_state)
+    y_ref, s_ref = jax.jit(lambda st: ssm.ssm_decode(st, 1, rows, x, dt, a, b, c, d, use_kernel=False))(store)
+    y, s = jax.jit(lambda st: ssm.ssm_decode(st, 1, rows, x, dt, a, b, c, d, use_kernel=True, interpret=False))(store)
+    err = _assert_close("ssm decode kernel y", y, y_ref, atol=1e-3, rtol=1e-4)
+    s_err = _assert_close("ssm decode kernel store", s[:, 1:], s_ref[:, 1:], atol=1e-4, rtol=1e-5)
+    log(f"caption-hybrid: _ssm_decode vs XLA step: y max_err {err:.2e}, store max_err {s_err:.2e}")
+    xs, dts = normal(2, 512, m.n_heads, m.head_dim), jnp.asarray(rng.uniform(0.001, 0.1, (2, 512, m.n_heads)), jnp.float32)
+    bs_, cs_ = normal(2, 512, m.d_state), normal(2, 512, m.d_state)
+    y_ref, s_ref = jax.jit(ssm.ssm_scan_reference)(store[0, :2], xs, dts, a, bs_, cs_, d)
+    y, s = jax.jit(lambda *v: ssm.ssd_chunk_scan(*v, chunk=m.chunk))(store[0, :2], xs, dts, a, bs_, cs_, d)
+    scale = float(jnp.abs(y_ref).max())
+    err = _assert_close("ssd prefill scan y", y, y_ref, atol=BF16_ATOL * scale, rtol=0)
+    s_err = _assert_close("ssd prefill scan state", s, s_ref, atol=2e-3 * float(jnp.abs(s_ref).max()), rtol=0)
+    log(f"caption-hybrid: SSD scan (2 x 512, chunk {m.chunk}) vs the recurrence: y max_err {err:.4f} of {scale:.2f}, state max_err {s_err:.2e}")
+
+    # the engine, kernels against its XLA path: a shared prefix, two chunks, decode
+    def requests(max_new):
+        r = np.random.default_rng(seed + 1)
+        prefix = r.integers(cfg.vocab // 2, cfg.vocab, 64).tolist()
+        return [
+            CaptionRequest(
+                request_id=f"r{i}", prefix_ids=prefix,
+                prompt_ids=r.integers(cfg.vocab // 2, cfg.vocab, n).tolist(),
+                sampling=SamplingConfig(max_new_tokens=max_new),
+            )
+            for i, n in enumerate((144, 400, 592))
+        ]
+
+    lanes = ((1024, 8), (4096, 2))
+    t0 = time.monotonic()
+    engine = CaptionEngine(cfg, kv_lanes=lanes)
+    engine.setup(seed)
+    logits = _capture_first_logits(engine)
+    done = _drain(engine, requests(32))
+    stats = engine.stats()
+    short = {rid: r.num_output_tokens for rid, r in done.items() if r.num_output_tokens != 32}
+    if short or stats["ssm_decode_calls"] <= 0 or stats["prefix_state_snapshots"] < 2:
+        raise AssertionError(f"caption-hybrid: short generations {short}; stats {stats}")
+    log(
+        f"caption-hybrid: 3 x 32 tokens in {time.monotonic() - t0:.1f}s (set-up and compiles included); "
+        f"ssm_decode_calls {stats['ssm_decode_calls']}, prefix_state_snapshots {stats['prefix_state_snapshots']}, "
+        f"recurrent store {stats['recurrent_state_bytes_per_chip'] / 2**30:.2f} GiB"
+    )
+    reference = CaptionEngine(cfg, kv_lanes=lanes, params=engine.params, paged_attention="gather")
+    reference.setup(seed)
+    ref_logits = _capture_first_logits(reference)
+    _drain(reference, requests(1))
+    _logits_agree("caption-hybrid", logits, ref_logits)
+
+
 # -- --chips 4: the sharded paths and what they are compared with ------------
 
 
@@ -819,8 +904,10 @@ def main(argv: list[str] | None = None) -> int:
                     phase_kernels()
                 elif name == "split":
                     phase_split(tmp)
-                else:
+                elif name == "caption-2b":
                     phase_caption_2b()
+                else:
+                    phase_caption_hybrid()
                 log(f"chip_smoke: phase {name} passed in {time.monotonic() - t0:.1f}s")
                 gc.collect()
         finally:

@@ -15,6 +15,8 @@ import sys
 # tests/models/test_vlm_engine.py::test_cli_choices_match_flavors)
 CAPTION_MODEL_CHOICES = (
     "base",
+    "granite-4.0-h-micro",
+    "granite-hybrid-tiny-test",
     "qwen25vl-7b",
     "qwen25vl-tiny-test",
     "qwen2vl-2b",
@@ -88,6 +90,8 @@ def register(sub: argparse._SubParsersAction) -> None:
         help="VLM flavor for every caption-family stage. qwen25vl-7b (and its "
         "test-size stand-in qwen25vl-tiny-test) is served over a 'model' mesh of 4 "
         "chips of this host, built by the stage; every other flavor takes one chip. "
+        "granite-4.0-h-micro (text only: the LM-only passes) is a Mamba-2/attention "
+        "hybrid whose per-request state does not grow with the context. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

@@ -40,6 +40,18 @@ vllm_async_stage.py). TPU-first re-design:
   sharing one engine (models/vlm/shared_engine.py) decode in ONE batch
   instead of serializing whole jobs — Orca-style iteration-level
   scheduling across jobs.
+- **two kinds of state** (hybrid flavors, ``cfg.ssm_layers``): beside the
+  block pool, whose ``L`` then counts the attention layers only, a
+  slot-indexed recurrent store ``[Lm, slots + 1, H, P, N]`` float32 (and the
+  convolutions' tails) holds the Mamba-2 state of every state-space layer, a
+  row a slot, row 0 the garbage row. A row is claimed and released with the
+  slot's blocks; admission zeroes it or copies the shared prefix's snapshot
+  into it (a prefix entry is blocks PLUS the state at exactly its last
+  token); chunked prefill carries it from chunk to chunk; and since a
+  recurrence cannot mask what it did not mean to read, padding never
+  advances it: a prefill's positions past ``t_valid`` and a decode program's
+  idle rows are masked in the recurrence, and a chunked prefill's last chunk
+  is padded at its end instead of shifted back over tokens already taken.
 - **prep/decode overlap** (``async_prep=True``): a background thread runs
   vision encoding + token embedding for waiting requests while the caller's
   ``step()`` loop decodes, so frame prep of request N+1 hides behind decode
@@ -64,7 +76,7 @@ import numpy as np
 
 from cosmos_curate_tpu.models.batching import next_pow2
 from cosmos_curate_tpu.models.tokenizer import ByteTokenizer, default_caption_tokenizer
-from cosmos_curate_tpu.models.vlm.model import VLM, VLMConfig, init_cache
+from cosmos_curate_tpu.models.vlm.model import VLM, VLMConfig, init_cache, init_recurrent_store
 from cosmos_curate_tpu.models.vlm.paged_kv import (
     BlockAllocator,
     PoolExhausted,
@@ -198,6 +210,10 @@ class _PrefixEntry:
     n_full: int  # length // block_size — the directly-shareable prefix
     tail_block: int | None  # blocks[-1] when partially filled, else None
     length: int
+    # hybrid flavors: the state-space layers' state after exactly ``length``
+    # tokens, (ssm [Lm, H, P, N], conv [Lm, (d_conv - 1) * conv_dim]) — copied
+    # into the slot's row of the recurrent store at admission
+    state: tuple | None = None
 
 
 @dataclass
@@ -391,6 +407,10 @@ class CaptionEngine:
         # runs head-parallel over parallel/axes.MODEL when the mesh names
         # that axis (KV pool + heads sharded, block tables replicated)
         self.mesh = mesh
+        # hybrid flavors keep a second kind of per-request state (module docstring)
+        self._recurrent = bool(cfg.ssm_layers)
+        if self._recurrent and mesh is not None:
+            raise ValueError("the recurrent store is not split over a mesh: serve a hybrid on one chip")
         # parameters stored in the type they are computed in: see VLM.param_dtype
         self.model = VLM(cfg, mesh=mesh, param_dtype=VLM.dtype)
         # the model's abstract parameter tree once setup() has taken it: per
@@ -455,6 +475,11 @@ class CaptionEngine:
         self._pool_k = None
         self._pool_v = None
         self._kv_pool_bytes_per_chip = 0  # until setup() makes the pool
+        # the recurrent store (hybrid flavors; None otherwise): slot ``i`` of
+        # lane ``l`` owns row ``1 + l.base + i``
+        self._ssm = None
+        self._conv = None
+        self._recurrent_bytes_per_chip = 0
         self.completed: list[CaptionResult] = []
         self._decode_tokens = 0
         # dead-work accounting: every decode step runs a lane's FULL slot
@@ -522,6 +547,12 @@ class CaptionEngine:
         # the share of the table the kernel touches
         self._paged_decode_pages_walked = 0
         self._paged_decode_pages_spanned = 0
+        # recurrent-store accounting (under _stats_lock): rows held at once,
+        # admissions served from a prefix's state snapshot, calls of the
+        # decode recurrence (one a state-space layer a decode program)
+        self._recurrent_rows_used_peak = 0
+        self._prefix_state_snapshots = 0
+        self._ssm_decode_calls = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -639,6 +670,11 @@ class CaptionEngine:
             cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
         )
         self._kv_pool_bytes_per_chip = _bytes_per_chip((self._pool_k, self._pool_v))
+        if self._recurrent:
+            self._ssm, self._conv = init_recurrent_store(
+                cfg, 1 + sum(l.n_slots for l in self.lanes), dtype=self.model.dtype
+            )
+            self._recurrent_bytes_per_chip = _bytes_per_chip((self._ssm, self._conv))
 
         model = self.model
         bs = self.block_size
@@ -822,12 +858,100 @@ class CaptionEngine:
         self._prefix_prefill = prefix_prefill
         self._write_prefix_blocks = write_prefix_blocks
         self._copy_blocks = copy_blocks
+        if self._recurrent:
+            self._build_recurrent_programs()
         self._built = True
         if self.async_prep:
             # requests may already be waiting (queued before setup)
             with self._work_cv:
                 self._start_prep_thread()
                 self._work_cv.notify_all()
+
+    def _build_recurrent_programs(self) -> None:
+        """A hybrid's programs: the prefill and decode programs with the
+        recurrent store riding along (the store's arguments come after the
+        others', its two arrays after the pools in what is returned), the
+        shared prefix's build with its state snapshot, and the two ways a
+        slot's row starts. They take the place of setup()'s."""
+        cfg, model, use_paged = self.cfg, self.model, self._use_paged
+        if cfg.mrope_section is not None or self._ds_levels:
+            raise ValueError("a hybrid flavor with m-rope or deepstack has no program here")
+
+        def chunk_forward(params, pool_k, pool_v, tables, embeds, rope, write_index, kv_len, **kw):
+            """The forward of both: as in setup()'s four programs, the
+            recurrent store riding along (``recurrent=`` in ``kw``)."""
+            if use_paged:
+                return model.apply(
+                    params, embeds, pool_k, pool_v, rope, write_index, kv_len, tables,
+                    method=model.paged_forward, **kw,
+                )
+            ck, cv = gather_block_views(pool_k, pool_v, tables)
+            logits, nk, nv, ssm, conv = model.apply(
+                params, embeds, ck, cv, rope, write_index, kv_len, **kw
+            )
+            pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, nk, nv)
+            return logits, pool_k, pool_v, ssm, conv
+
+        @partial(jax.jit, donate_argnums=(1, 2, 9, 10))
+        def prefill_batch_recurrent(
+            params, pool_k, pool_v, tables, embeds, write_index, t_valid, rope_pos, ds, ssm, conv, rows
+        ):
+            """prefill_batch(_paged) for a hybrid: ``rows`` [N] are the rows'
+            rows of the recurrent store (ssm, conv), whose states advance over
+            the ``t_valid`` leading positions and no further."""
+            logits, pool_k, pool_v, ssm, conv = chunk_forward(
+                params, pool_k, pool_v, tables, embeds, rope_pos, write_index,
+                write_index + t_valid, deepstack=ds, logits_at=t_valid - 1,
+                recurrent=(ssm, conv, rows, t_valid),
+            )
+            return logits[:, 0], pool_k, pool_v, ssm, conv
+
+        @partial(jax.jit, donate_argnums=(1, 2, 7, 8))
+        def decode_step_recurrent(
+            params, pool_k, pool_v, tables, tokens, positions, rope_positions, ssm, conv, rows
+        ):
+            """decode_step(_paged) for a hybrid: idle rows carry store row 0,
+            the garbage row, and do not advance it either."""
+            embeds = model.apply(params, tokens[:, None], method=model.embed_tokens)
+            logits, pool_k, pool_v, ssm, conv = chunk_forward(
+                params, pool_k, pool_v, tables, embeds, rope_positions[:, None], positions,
+                positions + 1, recurrent=(ssm, conv, rows, (rows > 0).astype(jnp.int32)),
+            )
+            step_logits = logits[:, 0]
+            greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+            return greedy, step_logits, pool_k, pool_v, ssm, conv
+
+        @jax.jit
+        def prefix_prefill_recurrent(params, embeds, rope_pos, t_valid):
+            """prefix_prefill for a hybrid: the prefix's K/V and, from a zero
+            state, the state-space layers' state after exactly ``t_valid``
+            tokens (the padding masked): what a request that shares the
+            prefix starts from."""
+            ck, cv = init_cache(cfg, 1, length=embeds.shape[1])
+            ssm, conv = init_recurrent_store(cfg, 1, dtype=model.dtype)
+            valid = jnp.full((1,), t_valid, jnp.int32)
+            _logits, nk, nv, ssm, conv = model.apply(
+                params, embeds, ck, cv, rope_pos, jnp.zeros((1,), jnp.int32), valid,
+                recurrent=(ssm, conv, jnp.zeros((1,), jnp.int32), valid),
+            )
+            return nk[:, 0], nv[:, 0], ssm[:, 0], conv[:, 0]
+
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def set_state_row(ssm, conv, row, snap_ssm, snap_conv):
+            """A slot's row of the recurrent store starts from a prefix's
+            snapshot: the one device copy of a hybrid's shared admission."""
+            return ssm.at[:, row].set(snap_ssm), conv.at[:, row].set(snap_conv)
+
+        @partial(jax.jit, donate_argnums=(0, 1))
+        def zero_state_row(ssm, conv, row):
+            """…or from zeros, never from the last tenant's state."""
+            return ssm.at[:, row].set(0.0), conv.at[:, row].set(0)
+
+        self._prefill_batch = prefill_batch_recurrent
+        self._decode = decode_step_recurrent
+        self._prefix_prefill = prefix_prefill_recurrent
+        self._set_state_row = set_state_row
+        self._zero_state_row = zero_state_row
 
     # -- public API -----------------------------------------------------
     @property
@@ -1018,7 +1142,7 @@ class CaptionEngine:
         """Device bytes one block pins (K + V across all layers)."""
         cfg = self.cfg
         # bf16 pool: 2 bytes/element, x2 for K and V
-        return 2 * 2 * cfg.n_layers * self.block_size * cfg.n_kv_heads * cfg.head_dim
+        return 2 * 2 * len(cfg.kv_layers) * self.block_size * cfg.n_kv_heads * cfg.head_dim
 
     @property
     def prefix_block_refs(self) -> int:
@@ -1050,7 +1174,7 @@ class CaptionEngine:
         ``length`` gathered positions (K + V, all layers)."""
         cfg = self.cfg
         itemsize = 2 if self._pool_k is None else self._pool_k.dtype.itemsize
-        return 2 * cfg.n_layers * rows * length * cfg.n_kv_heads * cfg.head_dim * itemsize
+        return 2 * len(cfg.kv_layers) * rows * length * cfg.n_kv_heads * cfg.head_dim * itemsize
 
     @property
     def paged_kernel_steps(self) -> int:
@@ -1115,6 +1239,12 @@ class CaptionEngine:
                 "kv_blocks_total": self._allocator.capacity,
                 "kv_blocks_used": self._allocator.used_blocks,
                 "kv_blocks_used_peak": self._kv_blocks_used_peak,
+                # the second kind of state (all zero without state-space layers)
+                "recurrent_state_bytes_per_chip": self._recurrent_bytes_per_chip,
+                "recurrent_rows_total": sum(l.n_slots for l in self.lanes) if self._recurrent else 0,
+                "recurrent_rows_used_peak": self._recurrent_rows_used_peak,
+                "prefix_state_snapshots": self._prefix_state_snapshots,
+                "ssm_decode_calls": self._ssm_decode_calls,
             }
 
     @property
@@ -1231,6 +1361,11 @@ class CaptionEngine:
             self._paged_decode_pages_spanned = 0
             self._kv_gather_bytes_avoided = 0
             self._kv_blocks_used_peak = self._allocator.used_blocks
+            self._recurrent_rows_used_peak = (
+                sum(len(l.claims) for l in self.lanes) if self._recurrent else 0
+            )
+            self._prefix_state_snapshots = 0
+            self._ssm_decode_calls = 0
             self._interleaved_steps = 0
             self._owner_decode_tokens.clear()
             self._owner_requests.clear()
@@ -1623,6 +1758,15 @@ class CaptionEngine:
             chunked = prep.t_suffix > self.prefill_chunk and (
                 decode_active or not group_ok
             )
+            if chunked and self._recurrent:
+                # a recurrence cannot take a token twice, so a hybrid's last
+                # chunk is padded at its end, not shifted back: the padded
+                # chunks must fit the lane, else the prompt goes in one bucket
+                c = self.prefill_chunk
+                if prep.base + -(-prep.t_suffix // c) * c > lane.length:
+                    if not group_ok:
+                        prep = self._materialize_full(prep)
+                    chunked = False
             slot_idx = next(
                 i
                 for i in range(lane.n_slots)
@@ -1925,7 +2069,8 @@ class CaptionEngine:
                 # text prefix: all three m-rope components equal
                 pos = np.broadcast_to(pos[..., None], (1, sp, 3))
         with self._phase("prefill_dispatch"):
-            k, v = self._prefix_prefill(
+            # a hybrid's build also returns its state snapshot (ssm, conv)
+            k, v, *state = self._prefix_prefill(
                 self.params,
                 jnp.asarray(emb),
                 jnp.asarray(pos),
@@ -1971,6 +2116,7 @@ class CaptionEngine:
                     n_full=tp // bs,
                     tail_block=ids[-1] if tp % bs else None,
                     length=tp,
+                    state=tuple(state) or None,
                 )
                 self._prefix_cache[key] = entry
                 while len(self._prefix_cache) > self.prefix_cache_size:
@@ -2016,6 +2162,7 @@ class CaptionEngine:
         view_blocks = -(-need // bs)
         shared: list[int] = []
         cow_src: int | None = None
+        entry = None
         if prep.base:
             with self._prefix_lock:
                 entry = self._prefix_cache.get(prep.prefix_key)
@@ -2065,6 +2212,12 @@ class CaptionEngine:
         row[len(shared) : view_blocks] = private
         claim = _BlockClaim(shared=shared, private=private)
         lane.claims[slot_idx] = claim
+        if self._recurrent:
+            try:
+                self._start_state_row(lane, slot_idx, None if entry is None else entry.state)
+            except BaseException:
+                self._release_claim(lane, slot_idx)
+                raise
         with self._stats_lock:
             self._requests_admitted += 1
             self._kv_blocks_reserved += view_blocks
@@ -2076,10 +2229,48 @@ class CaptionEngine:
             self._kv_blocks_used_peak = max(
                 self._kv_blocks_used_peak, self._allocator.used_blocks
             )
+            if self._recurrent:
+                self._recurrent_rows_used_peak = max(
+                    self._recurrent_rows_used_peak, sum(len(l.claims) for l in self.lanes)
+                )
+                self._prefix_state_snapshots += entry is not None
             self._owner_requests[req.owner] = (
                 self._owner_requests.get(req.owner, 0) + 1
             )
         return claim
+
+    @staticmethod
+    def _state_rows(lane: _Lane, slot_indices) -> np.ndarray:
+        """Rows of the recurrent store that a lane's slots own (row 0 is the
+        garbage row, so slot ``i`` of the lane has row ``1 + base + i``)."""
+        return (1 + lane.base + np.asarray(slot_indices)).astype(np.int32)
+
+    # holds-lock: _lock
+    def _start_state_row(self, lane: _Lane, slot_idx: int, snapshot: tuple | None) -> None:
+        """A freshly claimed slot starts from the shared prefix's state
+        snapshot, or from zeros; never from the last tenant's state."""
+        row = jnp.asarray(self._state_rows(lane, slot_idx))
+        if snapshot is None:
+            self._ssm, self._conv = self._zero_state_row(self._ssm, self._conv, row)
+        else:
+            self._ssm, self._conv = self._set_state_row(self._ssm, self._conv, row, *snapshot)
+
+    # holds-lock: _lock
+    def _run_prefill(self, lane: _Lane, slots_arr, tables, embeds, write_index, t_valid, rope, ds):
+        """One call of the prefill program over host arrays; a hybrid's
+        recurrent store rides along and comes back with the pools."""
+        args = (
+            self.params, self._pool_k, self._pool_v, jnp.asarray(tables), jnp.asarray(embeds),
+            jnp.asarray(write_index), jnp.asarray(t_valid), jnp.asarray(rope),
+            None if ds is None else jnp.asarray(ds),
+        )
+        if not self._recurrent:
+            logits, self._pool_k, self._pool_v = self._prefill_batch(*args)
+        else:
+            logits, self._pool_k, self._pool_v, self._ssm, self._conv = self._prefill_batch(
+                *args, self._ssm, self._conv, jnp.asarray(self._state_rows(lane, slots_arr))
+            )
+        return logits
 
     def _release_claim(self, lane: _Lane, slot_idx: int) -> None:
         """Return a slot's block references to the pool. Private blocks
@@ -2199,16 +2390,8 @@ class CaptionEngine:
                     ds_buf[:, j] = ds_buf[:, 0]
             tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
         with self._phase("prefill_dispatch"):
-            logits, self._pool_k, self._pool_v = self._prefill_batch(
-                self.params,
-                self._pool_k,
-                self._pool_v,
-                jnp.asarray(tables),
-                jnp.asarray(embeds),
-                jnp.asarray(bases),
-                jnp.asarray(t_valids),
-                jnp.asarray(rope_buf),
-                None if ds_buf is None else jnp.asarray(ds_buf),
+            logits = self._run_prefill(
+                lane, slots_arr, tables, embeds, bases, t_valids, rope_buf, ds_buf
             )
         with self._phase("prefill_wait"):
             logits_np = np.asarray(logits)  # one host sync for the whole group
@@ -2305,22 +2488,26 @@ class CaptionEngine:
             for j, (slot_idx, p) in enumerate(items):
                 take = min(C, p.t_valid - p.progress)
                 start = p.progress
-                if take < C:
+                if take < C and not self._recurrent:
                     # final partial chunk: shift back so the C-length buffer
                     # ends exactly at the prompt end. The overlapped rows
                     # rewrite identical K/V (same embeds, same rope, correct
                     # causal mask), and dynamic_update_slice stays in bounds
                     # for shared-prefix bases > 0 and for lane lengths that are
-                    # not a multiple of the chunk size.
+                    # not a multiple of the chunk size. A recurrence cannot
+                    # take a token twice: a hybrid's last chunk starts where
+                    # the one before ended and is padded at its end instead
+                    # (_admit made sure that it fits the lane).
                     start = p.t_valid - C
+                filled = min(C, p.t_valid - start)  # C, but for a hybrid's last chunk
                 new_tokens += take
-                embeds[j] = p.embeds[start : start + C]
+                embeds[j, :filled] = p.embeds[start : start + filled]
                 slots_arr[j] = slot_idx
                 write_idx[j] = p.base + start
                 chunk_valid[j] = C if start < p.progress else take
-                rope_buf[j] = p.rope_pos[start : start + C]
+                rope_buf[j, :filled] = p.rope_pos[start : start + filled]
                 if ds_buf is not None and p.ds is not None:
-                    ds_buf[:, j] = p.ds[:, start : start + C]
+                    ds_buf[:, j, :filled] = p.ds[:, start : start + filled]
             for j in range(n, n_pad):  # duplicate row 0 (identical writes: safe)
                 embeds[j] = embeds[0]
                 slots_arr[j] = slots_arr[0]
@@ -2331,16 +2518,8 @@ class CaptionEngine:
                     ds_buf[:, j] = ds_buf[:, 0]
             tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
         with self._phase("prefill_dispatch"):
-            logits, self._pool_k, self._pool_v = self._prefill_batch(
-                self.params,
-                self._pool_k,
-                self._pool_v,
-                jnp.asarray(tables),
-                jnp.asarray(embeds),
-                jnp.asarray(write_idx),
-                jnp.asarray(chunk_valid),
-                jnp.asarray(rope_buf),
-                None if ds_buf is None else jnp.asarray(ds_buf),
+            logits = self._run_prefill(
+                lane, slots_arr, tables, embeds, write_idx, chunk_valid, rope_buf, ds_buf
             )
         finished = []
         for j, (slot_idx, p) in enumerate(items):
@@ -2385,7 +2564,7 @@ class CaptionEngine:
                 positions[i] = slot.position
                 rope_positions[i] = slot.rope_position
         with self._phase("decode_dispatch"):
-            greedy, logits, self._pool_k, self._pool_v = self._decode(
+            args = (
                 self.params,
                 self._pool_k,
                 self._pool_v,
@@ -2394,12 +2573,25 @@ class CaptionEngine:
                 jnp.asarray(positions),
                 jnp.asarray(rope_positions),
             )
+            if not self._recurrent:
+                greedy, logits, self._pool_k, self._pool_v = self._decode(*args)
+            else:
+                # rows that decode advance their own state; the others (free,
+                # or mid-prefill and holding a real state) carry the garbage
+                # row and are masked in the recurrence besides
+                rows = np.zeros(lane.n_slots, np.int32)
+                active = list(lane.slots)
+                rows[active] = self._state_rows(lane, active)
+                greedy, logits, self._pool_k, self._pool_v, self._ssm, self._conv = (
+                    self._decode(*args, self._ssm, self._conv, jnp.asarray(rows))
+                )
         with self._phase("decode_wait"):
             greedy_np = np.asarray(greedy)  # ONE host sync for the whole batch
         with self._phase("decode_sample"):
             with self._stats_lock:
                 self._decode_tokens += len(lane.slots)
                 self._decode_rows += lane.n_slots
+                self._ssm_decode_calls += len(self.cfg.ssm_layers)
                 if self._use_paged:
                     self._paged_kernel_steps += 1
                     # a row's kv_len is positions + 1 (decode_step_paged)

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from cosmos_curate_tpu.models.layers import MODEL_AXIS, dense
+from cosmos_curate_tpu.models.vlm.mamba2 import Mamba2Mixer
+from cosmos_curate_tpu.ops import ssm as ssm_ops
 from cosmos_curate_tpu.models.vit import VIT_B_16, VIT_TINY_TEST, ViT, ViTConfig, preprocess_frames
 from cosmos_curate_tpu.models.vlm.vision_qwen import (
     QWEN2_VL_2B_VISION,
@@ -54,6 +56,28 @@ class MoEConfig:
     # None = no-drop (capacity = token count) — exact HF equivalence, used
     # by tests and small decode batches
     capacity_factor: float | None = None
+
+
+@dataclass(frozen=True)
+class Mamba2Config:
+    """The Mamba-2 mixer of a hybrid decoder (models/vlm/mamba2.py; HF
+    ``GraniteMoeHybridMambaLayer``). ``d_inner = n_heads * head_dim``; B and C
+    are shared by all heads (one group)."""
+
+    n_heads: int = 64
+    head_dim: int = 64
+    d_state: int = 128
+    d_conv: int = 4
+    # tokens a step of the prefill scan covers (HF ``mamba_chunk_size``)
+    chunk: int = 256
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:  # x | B | C pass through the convolution
+        return self.d_inner + 2 * self.d_state
 
 
 @dataclass(frozen=True)
@@ -90,6 +114,46 @@ class VLMConfig:
     # dims ([THW THW ... TT], preserving frequency continuity) instead of
     # Qwen2-VL's chunked [TTT HHH WWW] sections
     mrope_interleaved: bool = False
+    # Hybrid decoders (Granite-4.0-H): the kind of every layer, "attention"
+    # or "mamba"; None = attention throughout. A "mamba" layer replaces the
+    # attention half of a layer with the Mamba-2 mixer ``mamba`` describes;
+    # its state lives in the engine's recurrent store, not in the KV pool.
+    layer_types: tuple[str, ...] | None = None
+    mamba: Mamba2Config | None = None
+    # False = no position embedding at all (HF ``position_embedding_type:
+    # nope``): the state-space layers carry the order
+    use_rope: bool = True
+    # softmax scale of the attention layers; None = head_dim ** -0.5
+    attention_multiplier: float | None = None
+    # Granite's scalings: x0 = E[ids] * embedding_multiplier, every residual
+    # branch * residual_multiplier, logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
+    def __post_init__(self) -> None:
+        if self.layer_types is None:
+            return
+        if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"attention", "mamba"}:
+            raise ValueError(f"layer_types must name {self.n_layers} 'attention'/'mamba' layers")
+        if "mamba" in self.layer_types and self.mamba is None:
+            raise ValueError("layer_types has a 'mamba' layer and mamba= gives no sizes")
+
+    @property
+    def kv_layers(self) -> tuple[int, ...]:
+        """Indices of the layers that hold K/V: the KV pool's leading
+        dimension counts these, in this order."""
+        if self.layer_types is None:
+            return tuple(range(self.n_layers))
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "attention")
+
+    @property
+    def ssm_layers(self) -> tuple[int, ...]:
+        """Indices of the state-space layers: the recurrent store's leading
+        dimension counts these, in this order."""
+        if self.layer_types is None:
+            return ()
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "mamba")
 
 
 VLM_BASE = VLMConfig()
@@ -228,6 +292,55 @@ VLM_MOE_TINY_TEST = VLMConfig(
     qk_norm=True,
     moe=MoEConfig(n_experts=4, top_k=2, hidden=32),
 )
+# Granite-4.0-H-Micro (HF ``granitemoehybrid``, config.json of
+# ibm-granite/granite-4.0-h-micro): 40 layers in periods of ten, nine Mamba-2
+# mixers and one GQA attention layer (at 5, 15, 25, 35) without any position
+# embedding, every layer followed by the shared SwiGLU (``num_local_experts``
+# 0: no routed experts), Granite's four multipliers, tied head. Text only:
+# the vision slot holds the test-size tower no request may reach.
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+VLM_GRANITE_4_H_MICRO = VLMConfig(
+    vocab=100352,
+    dim=2048,
+    n_layers=40,
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=64,
+    hidden_mult=8192 / 2048,
+    max_seq=4096,
+    qkv_bias=False,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    layer_types=_GRANITE_PERIOD * 4,
+    mamba=Mamba2Config(n_heads=64, head_dim=64, d_state=128, d_conv=4, chunk=256),
+    use_rope=False,
+    attention_multiplier=0.015625,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    logits_scaling=8.0,
+)
+# one period of the same pattern at test size (CPU tests, --rehearse); the
+# scan's chunk is under the engine's test chunk so a prefill crosses chunks
+VLM_GRANITE_HYBRID_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=10,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    layer_types=_GRANITE_PERIOD,
+    mamba=Mamba2Config(n_heads=8, head_dim=16, d_state=16, d_conv=4, chunk=8),
+    use_rope=False,
+    attention_multiplier=1 / 16,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    logits_scaling=8.0,
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -268,6 +381,11 @@ class FlavorSpec:
             raise ValueError(
                 f"{self.model_id}: model_chips={self.model_chips} does not divide "
                 f"n_kv_heads={self.cfg.n_kv_heads} (the KV pool is split by head planes)"
+            )
+        if self.model_chips > 1 and self.cfg.ssm_layers:
+            raise ValueError(
+                f"{self.model_id}: the recurrent store and the Mamba-2 mixer are not "
+                "split over a model mesh; serve a hybrid flavor with model_chips=1"
             )
 
 
@@ -391,6 +509,24 @@ VLM_FLAVORS.update(
         ),
         "qwen3moe-tiny-test": FlavorSpec(
             VLM_MOE_TINY_TEST, "caption-vlm-tpu", require_weights=False
+        ),
+        # hybrid text LM for the LM-only passes (--enhance-captions): 36 of 40
+        # layers keep a fixed-size Mamba-2 state, so a row costs 72 MiB of
+        # recurrent store whatever its context and KV only 8 KiB a token (4
+        # attention layers): many rows a step on one chip. The store, not
+        # the pool, is what the rows cost.
+        "granite-4.0-h-micro": FlavorSpec(
+            VLM_GRANITE_4_H_MICRO,
+            "caption-granite-4.0-h-micro-tpu",
+            text_only=True,
+            kv_lanes=((1024, 48), (4096, 8)),
+        ),
+        "granite-hybrid-tiny-test": FlavorSpec(
+            VLM_GRANITE_HYBRID_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 4), (128, 2)),
         ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
@@ -664,8 +800,9 @@ class DecoderLayer(nn.Module):
         if cfg.qk_norm:  # Qwen3 family: per-HEAD-DIM RMSNorm before rope
             q = RMSNorm(eps=cfg.rms_eps, name="q_norm")(q)
             k = RMSNorm(eps=cfg.rms_eps, name="k_norm")(k)
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
-        k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
+        if cfg.use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
         v = v.reshape(b, t, hk, dh)
 
         from cosmos_curate_tpu.ops.paged_attention import (
@@ -699,12 +836,12 @@ class DecoderLayer(nn.Module):
             if head_parallel:
                 attn = paged_head_attention(
                     self.mesh, qk, new_k, new_v, block_tables, write_index, kv_len,
-                    layer_index=layer_index,
+                    layer_index=layer_index, sm_scale=cfg.attention_multiplier,
                 )
             else:
                 attn = paged_attention(
                     qk, new_k, new_v, block_tables, write_index, kv_len,
-                    layer_index=layer_index,
+                    layer_index=layer_index, sm_scale=cfg.attention_multiplier,
                 )
             attn = attn.astype(self.dtype)
         else:
@@ -721,23 +858,63 @@ class DecoderLayer(nn.Module):
 
             attn = reference_attention(
                 q.reshape(b, t, hk, group, dh), new_k, new_v, write_index, kv_len,
-                sm_scale=dh**-0.5,
+                sm_scale=cfg.attention_multiplier or dh**-0.5,
             )
         attn = attn.reshape(b, t, h * dh)
         # the row-parallel matmuls end in an all-reduce over the model axis:
         # the scope names it in a compiled program and in a device trace
         with jax.named_scope(TP_SCOPES["attn_out"]):
-            x = x + proj(cfg.dim, "in", name="o", use_bias=False)(attn)
+            x = _residual(cfg, x, proj(cfg.dim, "in", name="o", use_bias=False)(attn))
+        return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), new_k, new_v
 
-        y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
-        if cfg.moe is not None:
-            moe = MoEFFN(cfg, dtype=self.dtype, param_dtype=self.param_dtype, name="moe")
-            return x + moe(y), new_k, new_v
-        up = proj(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False)(y)
-        gate = proj(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False)(y)
-        with jax.named_scope(TP_SCOPES["mlp_down"]):
-            down = proj(cfg.dim, "in", name="down", use_bias=False)(nn.silu(gate) * up)
-        return x + down, new_k, new_v
+
+def _residual(cfg: VLMConfig, x, branch):
+    r = cfg.residual_multiplier
+    return x + branch if r == 1.0 else x + branch * r
+
+
+def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype):
+    """``x + ffn(RMSNorm(x))``: the second half of both kinds of layer. Called
+    inside a layer's compact method, so the submodules are that layer's."""
+    y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
+    if cfg.moe is not None:
+        moe = MoEFFN(cfg, dtype=dtype, param_dtype=param_dtype, name="moe")
+        return _residual(cfg, x, moe(y))
+    up = proj(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False)(y)
+    gate = proj(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False)(y)
+    with jax.named_scope(TP_SCOPES["mlp_down"]):
+        down = proj(cfg.dim, "in", name="down", use_bias=False)(nn.silu(gate) * up)
+    return _residual(cfg, x, down)
+
+
+class MambaLayer(nn.Module):
+    """A hybrid decoder's state-space layer: the Mamba-2 mixer
+    (models/vlm/mamba2.py) where ``DecoderLayer`` has attention, then the
+    same FFN half. Its state is two rows of the engine's recurrent store."""
+
+    cfg: VLMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
+
+    @nn.compact
+    def __call__(self, x, ssm, tail, rows, valid, *, layer_index=0, use_kernel=None):
+        """x: [B, T, D]; ssm: states ``[Lm, R, H, P, N]`` float32, this
+        layer's at ``[layer_index, rows]``; tail: the rows' convolution
+        tails ``[B, (d_conv - 1) * conv_dim]``; valid: [B] positions of this
+        chunk that advance the state (the rest is padding). Returns (y, ssm,
+        the new tails)."""
+        cfg = self.cfg
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        mixer = Mamba2Mixer(
+            cfg.mamba, cfg.dim, cfg.rms_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            name="mixer",
+        )
+        y, ssm, tail = mixer(
+            RMSNorm(eps=cfg.rms_eps, name="ln1")(x), ssm, tail, rows, valid,
+            layer_index=layer_index, use_kernel=use_kernel,
+        )
+        x = _residual(cfg, x, y)
+        return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), ssm, tail
 
 
 class VLM(nn.Module):
@@ -766,7 +943,9 @@ class VLM(nn.Module):
             embedding_init=nn.with_partitioning(nn.initializers.normal(0.02), (None, MODEL_AXIS)),
         )
         self.layers = [
-            DecoderLayer(
+            MambaLayer(cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}")
+            if cfg.layer_types is not None and cfg.layer_types[i] == "mamba"
+            else DecoderLayer(
                 cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
                 name=f"layer_{i}",
             )
@@ -826,7 +1005,9 @@ class VLM(nn.Module):
 
     def embed_tokens(self, token_ids):
         with jax.named_scope(TP_SCOPES["embed"]):
-            return self.embed(token_ids)
+            x = self.embed(token_ids)
+        m = self.cfg.embedding_multiplier
+        return x if m == 1.0 else x * m
 
     def init_everything(self, frames_u8, token_ids, cache_k, cache_v):
         """Init-only method touching every submodule (flax only creates
@@ -862,36 +1043,41 @@ class VLM(nn.Module):
         x = self.ln_f(x)
         with jax.named_scope(TP_SCOPES["head"]):
             if self.lm_head is not None:  # untied checkpoints (Qwen2.5-VL-7B)
-                return self.lm_head(x.astype(jnp.float32))
-            return self.embed.attend(x.astype(jnp.float32))
+                logits = self.lm_head(x.astype(jnp.float32))
+            else:
+                logits = self.embed.attend(x.astype(jnp.float32))
+        s = self.cfg.logits_scaling
+        return logits if s == 1.0 else logits / s
 
     def __call__(
         self, embeds, cache_k, cache_v, positions, write_index, kv_len, deepstack=None,
-        logits_at=None,
+        logits_at=None, recurrent=None,
     ):
         """Forward over input *embeddings* (text and vision already spliced).
 
-        embeds: [B, T, D]; cache_k/v: [L, B, Hkv, S, Dh]; deepstack:
+        embeds: [B, T, D]; cache_k/v: [L, B, Hkv, S, Dh], ``L`` counting the
+        attention layers (``cfg.kv_layers``); deepstack:
         optional [L_ds, B, T, D] visual features added to the hidden states
         AFTER each of the first L_ds layers (zeros at text positions — HF
         Qwen3VL deepstack semantics; prefill-only, decode passes None).
         Returns (logits [B, T, vocab] — or [B, 1, vocab] at ``logits_at``
         — new_cache_k, new_cache_v).
+
+        A hybrid (``cfg.ssm_layers``) also takes ``recurrent``, the engine's
+        store as ``(ssm, conv, rows, valid)`` (see ``MambaLayer``), and then
+        returns the two stores after the caches; None runs its state-space
+        layers from a zero state that is dropped (a whole prompt at once).
+        This is the ``gather`` programs' forward: the recurrence runs in
+        plain XLA, token by token (ops/ssm.py).
         """
-        x = embeds.astype(self.dtype)
-        n_ds = 0 if deepstack is None else deepstack.shape[0]
-        new_ks, new_vs = [], []
-        for i, layer in enumerate(self.layers):
-            x, nk, nv = layer(x, cache_k[i], cache_v[i], positions, write_index, kv_len)
-            if i < n_ds:
-                x = x + deepstack[i].astype(x.dtype)
-            new_ks.append(nk)
-            new_vs.append(nv)
-        return self._logits(x, logits_at), jnp.stack(new_ks), jnp.stack(new_vs)
+        return self._forward(
+            embeds, cache_k, cache_v, positions, write_index, kv_len, None, deepstack,
+            logits_at, recurrent,
+        )
 
     def paged_forward(
         self, embeds, pool_k, pool_v, positions, write_index, kv_len, block_tables,
-        deepstack=None, logits_at=None,
+        deepstack=None, logits_at=None, recurrent=None,
     ):
         """Forward straight against the paged KV pool — no working-set view.
 
@@ -900,20 +1086,92 @@ class VLM(nn.Module):
         scatters its chunk through ``block_tables`` [B, nbl] and attends in
         place via ops/paged_attention.py); write_index/kv_len as in
         ``__call__``. Returns (logits, pool_k, pool_v) — the updated pools, never a ``jnp.stack`` of per-layer copies, so XLA
-        donation keeps the scatters in-place.
+        donation keeps the scatters in-place. ``recurrent`` as in
+        ``__call__``; here ops/ssm.py decides how the recurrence runs.
         """
+        return self._forward(
+            embeds, pool_k, pool_v, positions, write_index, kv_len, block_tables, deepstack,
+            logits_at, recurrent,
+        )
+
+    def _forward(
+        self, embeds, cache_k, cache_v, positions, write_index, kv_len, block_tables,
+        deepstack, logits_at, recurrent,
+    ):
+        cfg = self.cfg
+        paged = block_tables is not None
         x = embeds.astype(self.dtype)
         n_ds = 0 if deepstack is None else deepstack.shape[0]
-        for i, layer in enumerate(self.layers):
-            x, pool_k, pool_v = layer(
-                x, pool_k, pool_v, positions, write_index, kv_len,
-                block_tables=block_tables, layer_index=i,
+        new_ks, new_vs = [], []
+        ssm_layers = cfg.ssm_layers
+        if ssm_layers:
+            store_ssm, store_conv, store_rows, valid = recurrent or self._zero_state(
+                x.shape[0], kv_len - write_index
             )
+            use_kernel = None if paged else False
+            # The rows' states are read out of the store ONCE, up front, and
+            # written back ONCE, at the end; the layers work on the copy.
+            # Thirty-six read-modify-writes of a donated 4 GiB store in the
+            # middle of a program are what XLA's rematerialisation, under
+            # memory pressure, ran twice (PERF.md, PR 30: a layer's state
+            # advanced twice). Only the decode kernel walks the store's own
+            # rows in place: a custom call is not rematerialised.
+            tails, new_tails = store_conv[:, store_rows], []
+            in_place = x.shape[1] == 1 and ssm_ops.decode_in_place(use_kernel)
+            ssm, rows = (
+                (store_ssm, store_rows) if in_place
+                else (store_ssm[:, store_rows], jnp.arange(x.shape[0], dtype=jnp.int32))
+            )
+        kv_i = ssm_i = 0  # a layer's index in the KV caches / the recurrent store
+        for i, layer in enumerate(self.layers):
+            if i in ssm_layers:
+                x, ssm, tail = layer(
+                    x, ssm, tails[ssm_i], rows, valid, layer_index=ssm_i, use_kernel=use_kernel,
+                )
+                new_tails.append(tail)
+                ssm_i += 1
+            elif paged:
+                x, cache_k, cache_v = layer(
+                    x, cache_k, cache_v, positions, write_index, kv_len,
+                    block_tables=block_tables, layer_index=kv_i,
+                )
+                kv_i += 1
+            else:
+                x, nk, nv = layer(x, cache_k[kv_i], cache_v[kv_i], positions, write_index, kv_len)
+                new_ks.append(nk)
+                new_vs.append(nv)
+                kv_i += 1
             if i < n_ds:
                 x = x + deepstack[i].astype(x.dtype)
-        return self._logits(x, logits_at), pool_k, pool_v
+        logits = self._logits(x, logits_at)
+        if not paged:
+            cache_k, cache_v = jnp.stack(new_ks), jnp.stack(new_vs)
+        out = (logits, cache_k, cache_v)
+        if recurrent is None:
+            return out
+        if not in_place:
+            ssm = store_ssm.at[:, store_rows].set(ssm)
+        return (*out, ssm, store_conv.at[:, store_rows].set(jnp.stack(new_tails)))
+
+    def _zero_state(self, batch: int, valid):
+        """A scratch recurrent store of ``batch`` rows, all zeros."""
+        ssm, conv = init_recurrent_store(self.cfg, batch, dtype=self.dtype)
+        return ssm, conv, jnp.arange(batch, dtype=jnp.int32), valid
+
+
+def init_recurrent_store(cfg: VLMConfig, rows: int, dtype=jnp.bfloat16):
+    """The state of a hybrid's state-space layers, one row a request:
+    ``ssm`` ``[Lm, rows, H, P, N]`` float32 (a state is rounded once a token
+    for as long as its request lives, so it keeps float32) and ``conv``
+    ``[Lm, rows, (d_conv - 1) * conv_dim]``, the convolution's last inputs in
+    the type they were computed in (a row's taps side by side: a ``[3,
+    conv_dim]`` plane would be padded to a tile of 16 rows on the chip)."""
+    m, lm = cfg.mamba, len(cfg.ssm_layers)
+    ssm = jnp.zeros((lm, rows, m.n_heads, m.head_dim, m.d_state), jnp.float32)
+    conv = jnp.zeros((lm, rows, (m.d_conv - 1) * m.conv_dim), dtype)
+    return ssm, conv
 
 
 def init_cache(cfg: VLMConfig, batch: int, dtype=jnp.bfloat16, length: int | None = None):
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, length or cfg.max_seq, cfg.head_dim)
+    shape = (len(cfg.kv_layers), batch, cfg.n_kv_heads, length or cfg.max_seq, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

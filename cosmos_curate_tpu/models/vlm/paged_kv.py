@@ -136,11 +136,13 @@ class BlockAllocator:
 
 
 def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sharding=None):
-    """The K and V block pools: ``[L, n_blocks, Hkv, block_size, Dh]`` —
+    """The K and V block pools: ``[L, n_blocks, Hkv, block_size, Dh]``, ``L``
+    the layers that hold K/V (a hybrid's state-space layers keep their state
+    in the engine's recurrent store instead, ``model.init_recurrent_store``) —
     heads-major, so one head's page is a contiguous ``[block_size, Dh]``
     tile (the shape the TPU's compiler accepts as a kernel block).
     ``sharding`` creates them already placed (head planes over a mesh)."""
-    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
+    shape = (len(cfg.kv_layers), n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     return jnp.zeros(shape, dtype, device=sharding), jnp.zeros(shape, dtype, device=sharding)
 
 
