@@ -1,6 +1,6 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py             # one TPU chip: kernels, split, caption-2b, caption-hybrid
+    python chip_smoke.py             # one TPU chip: kernels, split, caption-2b, caption-hybrid, caption-latent
     python chip_smoke.py --chips 4   # four chips: the sharded paths ONLY
 
 One process does everything, so the chip has one owner (the streaming
@@ -26,7 +26,12 @@ weights:
                  pattern (9 Mamba-2 mixers, 1 attention layer): the
                  state-space decode kernel and the chunked prefill scan
                  against the XLA recurrence, then the engine (recurrent store
-                 beside the ``D`` = 64 paged pool) against its ``gather`` path.
+                 beside the ``D`` = 64 paged pool) against its ``gather`` path;
+- ``caption-latent`` DeepSeek-V2's widths, its dense layer and two sparse ones
+                 (20 of 160 experts held): the latent-attention kernel and the
+                 grouped matrix product against XLA, then the engine (latent
+                 pool, absorbed attention, sorted dispatch) against its
+                 ``gather`` path and the float32 reference.
 
 ``--chips 4`` runs only what exists across chips and what it is compared
 with: the head-parallel caption engine at Qwen2.5-VL-7B widths against the
@@ -42,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import os
@@ -63,7 +69,7 @@ BF16_ATOL = BF16_RTOL = 3e-2
 # the largest logit magnitude.
 LOGITS_REL_TOL = 5e-2
 
-PHASES = ("kernels", "split", "caption-2b", "caption-hybrid")
+PHASES = ("kernels", "split", "caption-2b", "caption-hybrid", "caption-latent")
 
 
 _T0 = time.monotonic()
@@ -505,6 +511,25 @@ def phase_caption_2b(*, seed: int = 0) -> None:
 # -- phase: caption-hybrid ---------------------------------------------------
 
 
+def _text_requests(cfg, seed: int, max_new: int):
+    """Three seeded text requests behind one shared 64-token prefix: one chunk,
+    two chunks, three chunks of 256."""
+    import numpy as np
+
+    from cosmos_curate_tpu.models.vlm import CaptionRequest, SamplingConfig
+
+    r = np.random.default_rng(seed + 1)
+    prefix = r.integers(cfg.vocab // 2, cfg.vocab, 64).tolist()
+    return [
+        CaptionRequest(
+            request_id=f"r{i}", prefix_ids=prefix,
+            prompt_ids=r.integers(cfg.vocab // 2, cfg.vocab, n).tolist(),
+            sampling=SamplingConfig(max_new_tokens=max_new),
+        )
+        for i, n in enumerate((144, 400, 592))
+    ]
+
+
 def phase_caption_hybrid(*, seed: int = 0) -> None:
     """Granite-4.0-H-Micro, every width, one period of ten layers: first the
     two state-space operations of ops/ssm.py as the chip runs them against
@@ -513,7 +538,7 @@ def phase_caption_hybrid(*, seed: int = 0) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from cosmos_curate_tpu.models.vlm import CaptionEngine, CaptionRequest, SamplingConfig
+    from cosmos_curate_tpu.models.vlm import CaptionEngine
     from cosmos_curate_tpu.models.vlm.model import vlm_flavor
     from cosmos_curate_tpu.ops import ssm
 
@@ -548,17 +573,7 @@ def phase_caption_hybrid(*, seed: int = 0) -> None:
     log(f"caption-hybrid: SSD scan (2 x 512, chunk {m.chunk}) vs the recurrence: y max_err {err:.4f} of {scale:.2f}, state max_err {s_err:.2e}")
 
     # the engine, kernels against its XLA path: a shared prefix, two chunks, decode
-    def requests(max_new):
-        r = np.random.default_rng(seed + 1)
-        prefix = r.integers(cfg.vocab // 2, cfg.vocab, 64).tolist()
-        return [
-            CaptionRequest(
-                request_id=f"r{i}", prefix_ids=prefix,
-                prompt_ids=r.integers(cfg.vocab // 2, cfg.vocab, n).tolist(),
-                sampling=SamplingConfig(max_new_tokens=max_new),
-            )
-            for i, n in enumerate((144, 400, 592))
-        ]
+    requests = functools.partial(_text_requests, cfg, seed)
 
     lanes = ((1024, 8), (4096, 2))
     t0 = time.monotonic()
@@ -580,6 +595,93 @@ def phase_caption_hybrid(*, seed: int = 0) -> None:
     ref_logits = _capture_first_logits(reference)
     _drain(reference, requests(1))
     _logits_agree("caption-hybrid", logits, ref_logits)
+
+
+def phase_caption_latent(*, seed: int = 0) -> None:
+    """DeepSeek-V2's widths over its dense layer and two sparse ones (this
+    chip's 20 experts of 160): the latent-attention kernel and the grouped
+    matrix product as the chip runs them against their XLA forms, then the
+    engine against its own ``gather`` path and against the float32 reference
+    (perfbench/reference/deepseek_v2.py)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cosmos_curate_tpu.models.vlm import CaptionEngine
+    from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+    from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul
+    from cosmos_curate_tpu.ops.latent_attention import latent_attention
+    from perfbench.reference import deepseek_v2 as ref
+
+    cfg = dataclasses.replace(vlm_flavor("deepseek-v2-ep8").cfg, n_layers=3)
+    mla, moe = cfg.mla, cfg.moe
+    rng = np.random.default_rng(seed)
+    width, used, bs, nbl = mla.cache_width, mla.kv_lora_rank + mla.qk_rope_head_dim, 16, 64
+
+    def rows_of(*shape):  # latent rows and absorbed queries: the padding lanes are zeros
+        x = rng.normal(size=(*shape, width)).astype(np.float32)
+        x[..., used:] = 0
+        return jnp.asarray(x, jnp.bfloat16)
+
+    pool = rows_of(2, 600, 1, bs)
+    for what, b, t, write in (("decode", 8, 1, (5, 17, 300, 1022, 0, 64, 511, 255)), ("prefill", 2, 256, (64, 512))):
+        tables = jnp.asarray(np.stack([rng.permutation(599)[:nbl] + 1 for _ in range(b)]), jnp.int32)
+        write = jnp.asarray(write, jnp.int32)
+        valid = write + (t if what == "decode" else jnp.asarray([t, t - 68]))
+        q = rows_of(b, t, cfg.n_heads)
+        attend = functools.partial(
+            latent_attention, layer_index=1, sm_scale=mla.softmax_scale, v_width=mla.kv_lora_rank
+        )
+        want = jax.jit(functools.partial(attend, use_kernel=False))(q, pool, tables, write, valid)
+        t0 = time.monotonic()
+        got = jax.jit(functools.partial(attend, use_kernel=True, interpret=False))(q, pool, tables, write, valid)
+        live = (np.arange(t)[None] < np.asarray(valid - write)[:, None])[..., None, None]
+        err = _assert_close(f"latent {what} kernel", np.where(live, got, 0), np.where(live, want, 0), atol=3e-2, rtol=3e-2)
+        log(f"caption-latent: mla_{what} kernel vs XLA, {b} x {t} queries: max_err {err:.4f} ({time.monotonic() - t0:.1f}s)")
+
+    first, count = moe.held_experts
+    sizes = jnp.asarray(rng.multinomial(700, np.ones(count) / count), jnp.int32)
+    lhs = jnp.asarray(rng.normal(size=(1536, cfg.dim)), jnp.bfloat16)
+    table = jnp.asarray(rng.normal(size=(count, cfg.dim, 2 * moe.hidden)) * 0.02, jnp.bfloat16)
+    want = jax.jit(functools.partial(grouped_matmul, use_kernel=False))(lhs, table, sizes)
+    got = jax.jit(functools.partial(grouped_matmul, use_kernel=True, interpret=False))(lhs, table, sizes)
+    err = _assert_close("grouped matmul", got[:700], want[:700], atol=3e-2, rtol=3e-2)
+    log(f"caption-latent: grouped matmul (gmm) vs ragged_dot, 700 of 1536 rows over {count} tables: max_err {err:.4f}")
+
+    requests = functools.partial(_text_requests, cfg, seed)
+
+    lanes = ((1024, 16), (4096, 2))
+    t0 = time.monotonic()
+    engine = CaptionEngine(cfg, kv_lanes=lanes)
+    engine.setup(seed)
+    logits = _capture_first_logits(engine)
+    done = _drain(engine, requests(32))
+    stats = engine.stats()
+    # (a seeded model's argmax may be the EOS id: a short generation is no fault)
+    lengths = {rid: r.num_output_tokens for rid, r in done.items()}
+    if max(lengths.values()) != 32 or stats["mla_decode_calls"] <= 0 or stats["expert_assignments_held"] <= 0:
+        raise AssertionError(f"caption-latent: generations {lengths}; stats {stats}")
+    log(
+        f"caption-latent: 3 x 32 tokens in {time.monotonic() - t0:.1f}s (set-up and compiles included); "
+        f"mla_decode_calls {stats['mla_decode_calls']}, expert_assignments_held {stats['expert_assignments_held']}, "
+        f"decode_programs_ahead {stats['decode_programs_ahead']} of {stats['paged_kernel_steps']}, "
+        f"latent pool {stats['latent_pool_bytes_per_chip'] / 2**30:.2f} GiB"
+    )
+    _log_params("caption-latent", engine)
+    reference = CaptionEngine(cfg, kv_lanes=lanes, params=engine.params, paged_attention="gather")
+    reference.setup(seed)
+    ref_logits = _capture_first_logits(reference)
+    _drain(reference, requests(1))
+    _logits_agree("caption-latent", logits, ref_logits)
+    kwargs = ref.model_kwargs(cfg)
+    for r in requests(1):
+        want, margin = ref.last_logits(engine.params, jnp.asarray(r.prefix_ids + r.prompt_ids, jnp.int32), **kwargs)
+        want = np.asarray(want)
+        err = float(np.abs(logits[r.request_id] - want).max() / np.abs(want).max())
+        log(f"caption-latent: {r.request_id} first-step logits vs float32 reference: rel err {err:.4f} (routing margin {float(margin):.3f})")
+        # under a margin of 0.1 another choice of expert is rounding, and moves the logits by tens of per cent
+        if float(margin) >= 0.1 and not err <= 0.1:
+            raise AssertionError(f"caption-latent: {r.request_id} is {err:.3f} from the reference")
 
 
 # -- --chips 4: the sharded paths and what they are compared with ------------
@@ -897,8 +999,10 @@ def main(argv: list[str] | None = None) -> int:
                     phase_split(tmp)
                 elif name == "caption-2b":
                     phase_caption_2b()
-                else:
+                elif name == "caption-hybrid":
                     phase_caption_hybrid()
+                else:
+                    phase_caption_latent()
                 log(f"chip_smoke: phase {name} passed in {time.monotonic() - t0:.1f}s")
                 gc.collect()
         finally:

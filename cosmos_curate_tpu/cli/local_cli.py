@@ -15,6 +15,8 @@ import sys
 # tests/models/test_vlm_engine.py::test_cli_choices_match_flavors)
 CAPTION_MODEL_CHOICES = (
     "base",
+    "deepseek-v2-ep8",
+    "deepseek-v2-tiny-test",
     "granite-4.0-h-micro",
     "granite-hybrid-tiny-test",
     "qwen25vl-7b",
@@ -92,6 +94,8 @@ def register(sub: argparse._SubParsersAction) -> None:
         "chips of this host, built by the stage; every other flavor takes one chip. "
         "granite-4.0-h-micro (text only: the LM-only passes) is a Mamba-2/attention "
         "hybrid whose per-request state does not grow with the context. "
+        "deepseek-v2-ep8 (text only) is one chip's share of DeepSeek-V2 served "
+        "expert-parallel over 8: its layers return that chip's partial sums. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

@@ -97,6 +97,7 @@ from cosmos_curate_tpu.models.vlm.paged_kv import (
     PoolExhausted,
     gather_block_views,
     init_block_pool,
+    init_latent_pool,
     scatter_block_views,
 )
 
@@ -453,6 +454,7 @@ class CaptionEngine:
         owner_inflight_cap: int | None = None,
         paged_attention: str = "auto",
         mesh: Any = None,
+        max_prefill_rows: int | None = None,
     ) -> None:
         """``params`` (here or assigned to ``.params`` later) are CONSUMED,
         as a jitted call consumes a donated argument: the engine serves from
@@ -468,6 +470,13 @@ class CaptionEngine:
         # prompts longer than this prefill in chunks of this size,
         # interleaved with decode steps
         self.prefill_chunk = min(prefill_chunk, cfg.max_seq)
+        # prompts one prefill program takes at most; the rest of a lane's
+        # waiting prompts take the next program (None: all at once). What
+        # bounds a program's scratch where a lane has hundreds of slots
+        # (FlavorSpec.prefill_rows)
+        if max_prefill_rows is not None and max_prefill_rows < 1:
+            raise ValueError(f"max_prefill_rows must be at least 1, got {max_prefill_rows}")
+        self.max_prefill_rows = max_prefill_rows
         self.tokenizer = tokenizer or default_caption_tokenizer()
         # which family of programs: "auto"/"kernel" run the paged programs
         # (attention reads the pool through the block table; which
@@ -636,6 +645,14 @@ class CaptionEngine:
         self._recurrent_rows_used_peak = 0
         self._prefix_state_snapshots = 0
         self._ssm_decode_calls = 0
+        # sparse experts with a sorted dispatch (DeepSeek-V2): the assignments
+        # that landed on the experts held here, summed over the layers of every
+        # decode program ON THE DEVICE (``_build_counted_decode``) and read when
+        # ``stats()`` is; calls of the latent decode kernel (one a layer a
+        # decode program; under _stats_lock)
+        self._counts_experts = cfg.moe is not None and cfg.moe.dispatch == "sorted"
+        self._expert_held = None
+        self._mla_decode_calls = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -706,6 +723,12 @@ class CaptionEngine:
     def pending(self) -> dict[int, _PendingPrefill]:
         return {l.base + i: p for l in self.lanes for i, p in l.pending.items()}
 
+    def param_template(self) -> Any:
+        """Shape, dtype and partition annotation of every parameter, nothing
+        made: the structure a loader restores a checkpoint into BEFORE
+        ``setup()`` has seeded anything (``SharedCaptionEngine.get``)."""
+        return _abstract_params(self.model)
+
     def kv_bytes(self) -> int:
         """Total device bytes the KV block pool pins."""
         if self._pool_k is None:
@@ -726,7 +749,14 @@ class CaptionEngine:
         self._param_dtypes = jax.tree.map(lambda x: x.dtype, nn.unbox(abstract))
         # seeded parameters are the float32 model's, narrowed like any other
         # tree: a seeded engine serves what handing in ``VLM(cfg).init`` would
-        seeded = partial(_init_params, self.model.clone(param_dtype=jnp.float32), seed)
+        # ...unless that float32 tree would not fit the device at all (a
+        # DeepSeek-V2 cut: 18 GB): then in the serving types directly
+        wide = sum(x.size for x in jax.tree.leaves(abstract)) * 4
+        limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        seed_model = self.model if self.mesh is None and limit and wide > limit else (
+            self.model.clone(param_dtype=jnp.float32)
+        )
+        seeded = partial(_init_params, seed_model, seed)
         pool_sharding = None
         if self.mesh is None:
             self.params = seeded() if self._params is None else self._params
@@ -749,9 +779,14 @@ class CaptionEngine:
                 )()
             self.params = self._params  # the setter places and narrows
             pool_sharding = spec_sharding(self.mesh, P(None, None, MODEL, None, None))
-        self._pool_k, self._pool_v = init_block_pool(
-            cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
-        )
+        if cfg.mla is not None:
+            if self.mesh is not None:
+                raise ValueError("a latent pool is not split over a mesh: serve the flavor on one chip")
+            self._pool_k, self._pool_v = init_latent_pool(cfg, self.kv_pool_blocks, self.block_size)
+        else:
+            self._pool_k, self._pool_v = init_block_pool(
+                cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
+            )
         self._kv_pool_bytes_per_chip = _bytes_per_chip((self._pool_k, self._pool_v))
         if self._recurrent:
             self._ssm, self._conv = init_recurrent_store(
@@ -918,7 +953,9 @@ class CaptionEngine:
             pad = ((0, 0), (0, 0), (0, ids.shape[0] * bs - tp), (0, 0))
 
             def blocks(x, dtype):  # -> [L, nb, Hkv, bs, Dh]
-                return jnp.pad(x.astype(dtype), pad).reshape(l, hk, -1, bs, dh).swapaxes(1, 2)
+                # (nothing inferred, x's own width: a latent flavor's V has width 0)
+                x = jnp.pad(x.astype(dtype), pad)
+                return x.reshape(l, hk, ids.shape[0], bs, x.shape[-1]).swapaxes(1, 2)
 
             pool_k = pool_k.at[:, ids].set(blocks(pk, pool_k.dtype))
             pool_v = pool_v.at[:, ids].set(blocks(pv, pool_v.dtype))
@@ -955,12 +992,59 @@ class CaptionEngine:
         self._copy_blocks = copy_blocks
         if self._recurrent:
             self._build_recurrent_programs()
+        if self._counts_experts:
+            self._build_counted_decode()
         self._built = True
         if self.async_prep:
             # requests may already be waiting (queued before setup)
             with self._work_cv:
                 self._start_prep_thread()
                 self._work_cv.notify_all()
+
+    def _build_counted_decode(self) -> None:
+        """The decode program of sparse experts with a sorted dispatch: setup()'s
+        with one rider, the device's count of the assignments that landed on the
+        experts held here (``MoEFFN._sorted_experts`` sows one a layer). Two
+        int32s, ``[units, 2**30s]``, so that it never wraps; not donated, so a
+        reader of ``stats()`` may hold an old one while the next is made."""
+        cfg, model, use_paged = self.cfg, self.model, self._use_paged
+        if self._recurrent or cfg.mrope_section is not None:
+            raise ValueError("sorted expert dispatch beside a recurrent store or m-rope has no program here")
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def decode_step_counted(params, pool_k, pool_v, tables, tokens, positions, rope_positions, held):
+            embeds = model.apply(params, tokens[:, None], method=model.embed_tokens)
+            args = (rope_positions[:, None], positions, positions + 1)
+            if use_paged:
+                (logits, pool_k, pool_v), aux = model.apply(
+                    params, embeds, pool_k, pool_v, *args, tables,
+                    method=model.paged_forward, mutable=["intermediates"],
+                )
+            else:
+                ck, cv = gather_block_views(pool_k, pool_v, tables)
+                (logits, nk, nv), aux = model.apply(
+                    params, embeds, ck, cv, *args, mutable=["intermediates"]
+                )
+                pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, nk, nv)
+            units = held[0] + sum(jax.tree.leaves(aux["intermediates"]))
+            held = jnp.stack([units % 2**30, held[1] + units // 2**30])
+            step_logits = logits[:, 0]
+            greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+            return greedy, step_logits, pool_k, pool_v, held
+
+        self._decode = decode_step_counted
+        self._expert_held = jnp.zeros(2, jnp.int32)
+
+    @property
+    def expert_assignments_held(self) -> int:
+        """Assignments that landed on the experts held here, over the layers of
+        every decode program since the last ``reset_stats()``: read off the
+        device now (waits for the last program dispatched)."""
+        held = self._expert_held
+        if held is None:
+            return 0
+        units, big = (int(v) for v in np.asarray(held))
+        return big * 2**30 + units
 
     def _build_recurrent_programs(self) -> None:
         """A hybrid's programs: the prefill and decode programs with the
@@ -1250,8 +1334,8 @@ class CaptionEngine:
     def kv_block_bytes(self) -> int:
         """Device bytes one block pins (K + V across all layers)."""
         cfg = self.cfg
-        # bf16 pool: 2 bytes/element, x2 for K and V
-        return 2 * 2 * len(cfg.kv_layers) * self.block_size * cfg.n_kv_heads * cfg.head_dim
+        # bf16 pool: 2 bytes/element; a row holds K and V, or one latent row
+        return 2 * len(cfg.kv_layers) * self.block_size * cfg.cache_row_elems
 
     @property
     def prefix_block_refs(self) -> int:
@@ -1283,7 +1367,7 @@ class CaptionEngine:
         ``length`` gathered positions (K + V, all layers)."""
         cfg = self.cfg
         itemsize = 2 if self._pool_k is None else self._pool_k.dtype.itemsize
-        return 2 * len(cfg.kv_layers) * rows * length * cfg.n_kv_heads * cfg.head_dim * itemsize
+        return len(cfg.kv_layers) * rows * length * cfg.cache_row_elems * itemsize
 
     @property
     def paged_kernel_steps(self) -> int:
@@ -1326,6 +1410,7 @@ class CaptionEngine:
         surface). Includes both sides of the block-size fallback: the
         constructor-requested size and the gcd-shrunk divisor actually
         used, so cross-run bench comparisons can detect a silent shrink."""
+        held = self.expert_assignments_held  # a device read: outside the lock
         with self._stats_lock:
             return {
                 "paged_attention": self.paged_attention,
@@ -1356,6 +1441,12 @@ class CaptionEngine:
                 "recurrent_rows_used_peak": self._recurrent_rows_used_peak,
                 "prefix_state_snapshots": self._prefix_state_snapshots,
                 "ssm_decode_calls": self._ssm_decode_calls,
+                # latent attention and sorted experts (zero elsewhere)
+                "latent_pool_bytes_per_chip": (
+                    self._kv_pool_bytes_per_chip if self.cfg.mla is not None else 0
+                ),
+                "mla_decode_calls": self._mla_decode_calls,
+                "expert_assignments_held": held,
             }
 
     @property
@@ -1479,6 +1570,9 @@ class CaptionEngine:
             )
             self._prefix_state_snapshots = 0
             self._ssm_decode_calls = 0
+            self._mla_decode_calls = 0
+            if self._expert_held is not None:
+                self._expert_held = jnp.zeros(2, jnp.int32)
             self._interleaved_steps = 0
             self._owner_decode_tokens.clear()
             self._owner_requests.clear()
@@ -1952,32 +2046,34 @@ class CaptionEngine:
             )
             # reserve the slot so this loop's later iterations see it taken
             lane.reserved.add(slot_idx)
-        for (lane_i, bucket), items in sorted(groups.items()):
+        for (lane_i, bucket), group in sorted(groups.items()):
             lane = self.lanes[lane_i]
-            for slot_idx, *_ in items:  # release the reservations
+            for slot_idx, *_ in group:  # release the reservations
                 lane.reserved.discard(slot_idx)
-            try:
-                self._prefill_group(lane, bucket, items)
-            except Exception:
-                if len(items) == 1:
-                    logger.exception(
-                        "prefill failed for %s; dropping", items[0][1].request_id
-                    )
-                    self._release_claim(lane, items[0][0])
-                    continue
-                # isolate the offender: retry each request as its own group
-                logger.exception(
-                    "batched prefill failed for %d requests; retrying singly",
-                    len(items),
-                )
-                for item in items:
-                    try:
-                        self._prefill_group(lane, bucket, [item])
-                    except Exception:
+            cap = self.max_prefill_rows or len(group)
+            for items in (group[i : i + cap] for i in range(0, len(group), cap)):
+                try:
+                    self._prefill_group(lane, bucket, items)
+                except Exception:
+                    if len(items) == 1:
                         logger.exception(
-                            "prefill failed for %s; dropping", item[1].request_id
+                            "prefill failed for %s; dropping", items[0][1].request_id
                         )
-                        self._release_claim(lane, item[0])
+                        self._release_claim(lane, items[0][0])
+                        continue
+                    # isolate the offender: retry each request as its own group
+                    logger.exception(
+                        "batched prefill failed for %d requests; retrying singly",
+                        len(items),
+                    )
+                    for item in items:
+                        try:
+                            self._prefill_group(lane, bucket, [item])
+                        except Exception:
+                            logger.exception(
+                                "prefill failed for %s; dropping", item[1].request_id
+                            )
+                            self._release_claim(lane, item[0])
 
     def _prepare(self, req: CaptionRequest, allow_prefix: bool = True) -> _Prepared:
         """Vision encode + token embed for one request.
@@ -2592,7 +2688,8 @@ class CaptionEngine:
         """Advance every pending chunked prefill by one chunk (one batched
         program call); rows finishing their prompt enter the decode batch."""
         C = self.prefill_chunk
-        items = list(lane.pending.items())
+        # (the first max_prefill_rows of them: the others take the next step's)
+        items = list(lane.pending.items())[: self.max_prefill_rows]
         if not items:
             return
         with self._phase("prefill_build"):
@@ -2769,7 +2866,11 @@ class CaptionEngine:
                 jnp.asarray(positions),
                 jnp.asarray(rope_positions),
             )
-            if not self._recurrent:
+            if self._counts_experts:
+                greedy, logits, self._pool_k, self._pool_v, self._expert_held = self._decode(
+                    *args, self._expert_held
+                )
+            elif not self._recurrent:
                 greedy, logits, self._pool_k, self._pool_v = self._decode(*args)
             else:
                 # rows that decode advance their own state; the others (free,
@@ -2807,6 +2908,8 @@ class CaptionEngine:
                 self._decode_rows += lane.n_slots
                 self._decode_rows_discarded += len(flight.rows) - len(emitted)
                 self._ssm_decode_calls += len(self.cfg.ssm_layers)
+                if self.cfg.mla is not None:
+                    self._mla_decode_calls += len(self.cfg.kv_layers)
                 if self._use_paged:
                     self._paged_kernel_steps += 1
                     # a row's kv_len is positions + 1 (decode_step_paged); a

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
+import math
 
 import flax.linen as nn
 import jax
@@ -28,6 +29,7 @@ import numpy as np
 from cosmos_curate_tpu.models.layers import MODEL_AXIS, dense
 from cosmos_curate_tpu.models.vlm.mamba2 import Mamba2Mixer
 from cosmos_curate_tpu.ops import ssm as ssm_ops
+from cosmos_curate_tpu.ops.tiling import round_up
 from cosmos_curate_tpu.models.vit import VIT_B_16, VIT_TINY_TEST, ViT, ViTConfig, preprocess_frames
 from cosmos_curate_tpu.models.vlm.vision_qwen import (
     QWEN2_VL_2B_VISION,
@@ -56,6 +58,111 @@ class MoEConfig:
     # None = no-drop (capacity = token count) — exact HF equivalence, used
     # by tests and small decode batches
     capacity_factor: float | None = None
+    # -- the DeepSeek-V2 class (HF ``DeepseekV2MoE``); the defaults are Qwen3's --
+    # one more SwiGLU of this width that EVERY token visits, added to the
+    # routed sum (HF ``n_shared_experts * moe_intermediate_size``); 0 = none
+    shared_hidden: int = 0
+    # leading layers that keep the dense SwiGLU (HF ``first_k_dense_replace``)
+    first_dense: int = 0
+    # group-limited routing (HF ``topk_method: group_limited_greedy``): the
+    # experts are ``n_group`` runs of consecutive experts, a token keeps the
+    # ``topk_group`` groups whose best expert scores highest and takes its
+    # top-k among those only; 1 group = plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    # HF ``norm_topk_prob`` and ``routed_scaling_factor``: the top-k scores
+    # renormalised to sum to one or left as they are, then times the factor
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # "queue": GShard's fixed queues (``capacity_factor``). "sorted": exact
+    # and proportional, the assignments sorted by expert into one grouped
+    # matrix product (ops/grouped_matmul.py): no queue, no drop, and rows
+    # that do not share a queue cannot move each other's results
+    dispatch: str = "queue"
+    # the experts THIS program holds, (first, count), of an expert-parallel
+    # deployment's ``n_experts`` (sorted dispatch only). The router keeps all
+    # its outputs and its top-k; the layer returns the held experts' part of
+    # the sum (plus the shared expert), what such a chip contributes before
+    # the exchange. None = all of them
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self) -> None:
+        if self.dispatch not in ("queue", "sorted"):
+            raise ValueError(f"dispatch must be queue|sorted, got {self.dispatch!r}")
+        if self.n_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(f"{self.n_experts} experts do not make {self.n_group} groups, top {self.topk_group}")
+        if self.dispatch == "queue" and (self.held is not None or self.shared_hidden or self.first_dense):
+            raise ValueError("held / shared / leading dense layers need dispatch='sorted'")
+        if self.dispatch == "sorted" and self.capacity_factor is not None:
+            raise ValueError("sorted dispatch drops nothing: it takes no capacity_factor")
+        first, count = self.held_experts
+        if first < 0 or count < 1 or first + count > self.n_experts:
+            raise ValueError(f"held={self.held} is no run of the {self.n_experts} experts")
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2; HF ``DeepseekV2Attention``):
+    low-rank queries, ONE compressed row a token in the cache (``kv_lora_rank``
+    latent values and a ``qk_rope_head_dim`` key shared by all heads: decoupled
+    rope), per-head keys and values up-projected from it. The rope carries
+    YaRN's frequency interpolation, whose numbers are the last six."""
+
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # HF ``rope_scaling`` of type "yarn"; factor 1 = plain rope
+    yarn_factor: float = 1.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    @property
+    def cache_width(self) -> int:
+        """Lanes of a cached row: ``[c_kv | k_rope]`` padded with zeros to whole
+        128-lane tiles (576 -> 640), the only width the chip stores or Mosaic
+        slices (models/vlm/paged_kv.py::init_latent_pool)."""
+        return round_up(self.kv_lora_rank + self.qk_rope_head_dim, 128)
+
+    @property
+    def softmax_scale(self) -> float:
+        """``(nope + rope) ** -0.5`` times YaRN's ``mscale(all_dim) ** 2``."""
+        m = yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """HF ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(mla: MLAConfig, theta: float) -> np.ndarray:
+    """The rope frequencies of the ``qk_rope_head_dim`` decoupled dims under
+    YaRN (HF ``DeepseekV2YarnRotaryEmbedding``): extrapolated (plain) for the
+    fast dims, interpolated (/ factor) for the slow ones, a linear ramp
+    between the dims that turn ``beta_fast`` and ``beta_slow`` times over the
+    ORIGINAL context. Depends on the factor and that context alone, not on
+    ``max_seq``. float32 ``[rope_dim / 2]``."""
+    dim = mla.qk_rope_head_dim
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if mla.yarn_factor <= 1:
+        return extra.astype(np.float32)
+
+    def dim_of(rotations: float) -> float:
+        return dim * math.log(mla.yarn_original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(mla.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(mla.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 0.001), 0, 1)
+    return (extra / mla.yarn_factor * ramp + extra * (1 - ramp)).astype(np.float32)
 
 
 @dataclass(frozen=True)
@@ -120,6 +227,10 @@ class VLMConfig:
     # its state lives in the engine's recurrent store, not in the KV pool.
     layer_types: tuple[str, ...] | None = None
     mamba: Mamba2Config | None = None
+    # latent attention (DeepSeek-V2) in place of GQA in every attention layer:
+    # its sizes and YaRN's numbers; None = ``DecoderLayer``'s attention. Such a
+    # flavor's cache is one latent row a token a layer (``cache_row_elems``)
+    mla: MLAConfig | None = None
     # False = no position embedding at all (HF ``position_embedding_type:
     # nope``): the state-space layers carry the order
     use_rope: bool = True
@@ -146,6 +257,15 @@ class VLMConfig:
         if self.layer_types is None:
             return tuple(range(self.n_layers))
         return tuple(i for i, kind in enumerate(self.layer_types) if kind == "attention")
+
+    @property
+    def cache_row_elems(self) -> int:
+        """Elements a token holds in the pool in ONE layer: K and V of every KV
+        head, or a latent flavor's one row (its zero padding counted: the pool
+        stores it)."""
+        if self.mla is not None:
+            return self.mla.cache_width
+        return 2 * self.n_kv_heads * self.head_dim
 
     @property
     def ssm_layers(self) -> tuple[int, ...]:
@@ -341,6 +461,69 @@ VLM_GRANITE_HYBRID_TINY_TEST = VLMConfig(
     residual_multiplier=0.22,
     logits_scaling=8.0,
 )
+# DeepSeek-V2 (HF ``deepseek_v2``, config.json of deepseek-ai/DeepSeek-V2) as ONE
+# CHIP OF AN 8-WAY EXPERT-PARALLEL DEPLOYMENT sees it: every width as published
+# (5120; 128 latent-attention heads, ranks 1536 / 512, head sizes 128 + 64 / 128,
+# YaRN 40 over 4096; a dense SwiGLU of 12288 in layer 0, then 160 routed experts
+# of 1536, top 6 of the top 3 of 8 groups, unnormalised times 16, and a shared
+# SwiGLU of 3072), the router whole, and of the rest this chip's share: one
+# routing group of 20 consecutive experts (group 0), a vocabulary slice of
+# 12,800 rows, and the first 7 of the 60 layers (a pipeline stage). Attention and
+# the shared expert are replicated in that deployment, so they are whole here.
+# The layer runs without its exchange: its output is this chip's partial sum.
+# Text only: the vision slot holds the test-size tower no request may reach.
+_DEEPSEEK_V2_MLA = MLAConfig(
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, yarn_factor=40.0, yarn_original_max=4096, yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0, yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
+)
+VLM_DEEPSEEK_V2_EP8 = VLMConfig(
+    vocab=12800,
+    dim=5120,
+    n_layers=7,
+    n_heads=128,
+    n_kv_heads=128,
+    head_dim=128,
+    hidden_mult=12288 / 5120,
+    max_seq=4096,
+    rope_theta=10000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    tied_embeddings=False,
+    mla=_DEEPSEEK_V2_MLA,
+    moe=MoEConfig(
+        n_experts=160, top_k=6, hidden=1536, shared_hidden=3072, first_dense=1, n_group=8,
+        topk_group=3, norm_topk_prob=False, routed_scaling_factor=16.0, dispatch="sorted",
+        held=(0, 20),
+    ),
+)
+# the same mechanisms at test size: 4 groups of 4 experts (this chip: group 1),
+# top 3 of the top 2 groups, a shared expert, one leading dense layer, latent
+# attention at small ranks, YaRN on (original context 32, so the ramp is inside
+# the eight rotary dims)
+VLM_DEEPSEEK_V2_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=3,
+    n_heads=4,
+    n_kv_heads=4,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    tied_embeddings=False,
+    mla=MLAConfig(
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        yarn_factor=4.0, yarn_original_max=32, yarn_beta_fast=4.0, yarn_beta_slow=1.0,
+        yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
+    ),
+    moe=MoEConfig(
+        n_experts=16, top_k=3, hidden=32, shared_hidden=48, first_dense=1, n_group=4,
+        topk_group=2, norm_topk_prob=False, routed_scaling_factor=4.0, dispatch="sorted",
+        held=(4, 4),
+    ),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -375,12 +558,23 @@ class FlavorSpec:
     # checkpoint choice as the lanes do: a 7B's 16.5 GiB of serving
     # parameters (bfloat16, its head float32) fit no 16 GB chip.
     model_chips: int = 1
+    # Prompts one prefill program takes at most (``CaptionEngine``'s
+    # ``max_prefill_rows``; None = as many as a lane has waiting). Travels
+    # with the lanes: a lane of 256 slots can have dozens of prompts waiting
+    # after a stall, and a program's scratch grows with rows x chunk tokens
+    # (DeepSeek-V2: 64 rows x 256 tokens wanted 10.5 GB, PERF.md PR 33).
+    prefill_rows: int | None = None
 
     def __post_init__(self) -> None:
         if self.model_chips < 1 or self.cfg.n_kv_heads % self.model_chips:
             raise ValueError(
                 f"{self.model_id}: model_chips={self.model_chips} does not divide "
                 f"n_kv_heads={self.cfg.n_kv_heads} (the KV pool is split by head planes)"
+            )
+        if self.model_chips > 1 and self.cfg.mla is not None:
+            raise ValueError(
+                f"{self.model_id}: a latent pool has one head plane and is not split "
+                "over a model mesh; serve a latent-attention flavor with model_chips=1"
             )
         if self.model_chips > 1 and self.cfg.ssm_layers:
             raise ValueError(
@@ -528,6 +722,25 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 4), (128, 2)),
         ),
+        # a large sparse text LM served expert-parallel, seen from one of its
+        # eight chips (the LM-only passes, --enhance-captions): a latent row
+        # costs 8,960 B a position over the 7 layers, so 256 short-lane rows
+        # are 2.2 GiB, and 256 rows give each of the 20 held experts the ten
+        # assignments a step that 32 rows a chip give it in the deployment
+        "deepseek-v2-ep8": FlavorSpec(
+            VLM_DEEPSEEK_V2_EP8,
+            "caption-deepseek-v2-ep8-tpu",
+            text_only=True,
+            kv_lanes=((1024, 256), (4096, 8)),
+            prefill_rows=8,  # 2,048 tokens a prefill program: 0.75 GB of scratch
+        ),
+        "deepseek-v2-tiny-test": FlavorSpec(
+            VLM_DEEPSEEK_V2_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 4), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -570,9 +783,11 @@ def apply_rope(
     theta: float,
     mrope_section: tuple[int, int, int] | None = None,
     mrope_interleaved: bool = False,
+    freqs=None,
 ) -> jnp.ndarray:
     """x: [B, T, H, D]; positions: [B, T] absolute positions, or [B, T, 3]
-    (t, h, w) multimodal positions under m-rope.
+    (t, h, w) multimodal positions under m-rope. ``freqs`` ([D/2]) takes the
+    place of the plain ``theta`` frequencies (YaRN: ``yarn_inv_freq``).
 
     M-rope (HF apply_multimodal_rotary_pos_emb semantics): each of the D/2
     rotary frequency dims takes its angle from one position component,
@@ -580,7 +795,8 @@ def apply_rope(
     interleaved for Qwen3-VL). With all three components equal (any
     pure-text span) both layouts reduce exactly to standard 1D rope.
     """
-    freqs = rope_frequencies(x.shape[-1], theta)  # [D/2]
+    if freqs is None:
+        freqs = rope_frequencies(x.shape[-1], theta)  # [D/2]
     if positions.ndim == 3:
         if mrope_section is None:
             raise ValueError("3-component positions require mrope_section")
@@ -650,6 +866,27 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(x.dtype)
 
 
+def route(moe: MoEConfig, logits):
+    """The router's choice for every token. logits: ``[N, E]`` float32. Softmax
+    over ALL experts, the groups that lose zeroed (group-limited routing: a
+    group's score is its best expert's), top-k of what is left, renormalised
+    or not, times the scaling factor. A tie goes to the lower index, in the
+    groups as in the experts. Returns (weights ``[N, k]``, experts ``[N, k]``)."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    if moe.n_group > 1:
+        n, e = probs.shape
+        best = probs.reshape(n, moe.n_group, e // moe.n_group).max(axis=-1)
+        _, groups = jax.lax.top_k(best, moe.topk_group)  # [N, topk_group]
+        kept = jnp.zeros_like(best, dtype=bool).at[jnp.arange(n)[:, None], groups].set(True)
+        probs = jnp.where(jnp.repeat(kept, e // moe.n_group, axis=1), probs, 0.0)
+    top_w, top_i = jax.lax.top_k(probs, moe.top_k)
+    if moe.norm_topk_prob:
+        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+    if moe.routed_scaling_factor != 1.0:
+        top_w = top_w * moe.routed_scaling_factor
+    return top_w, top_i
+
+
 class MoEFFN(nn.Module):
     """Expert-parallel sparse FFN, GShard-style static dispatch.
 
@@ -683,9 +920,11 @@ class MoEFFN(nn.Module):
         logits = dense(e, None, name="router", use_bias=False, dtype=jnp.float32)(
             tokens.astype(jnp.float32)
         )
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(probs, k)  # [N, k]
-        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+        with jax.named_scope("moe.route"):
+            top_w, top_i = route(moe, logits)  # [N, k]
+        if moe.dispatch == "sorted":
+            y = self._sorted_experts(tokens, top_w, top_i)
+            return y.reshape(b, t, d).astype(x.dtype)
         if moe.capacity_factor is None:
             cap = n
         else:
@@ -739,6 +978,53 @@ class MoEFFN(nn.Module):
         ).astype(jnp.float32)
         y = (out_a * top_w.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
         return y.reshape(b, t, d).astype(x.dtype)
+
+    def _sorted_experts(self, tokens, top_w, top_i):
+        """Exact dispatch whose cost follows the assignments that land on the
+        experts held here: the ``N * k`` assignments are sorted by expert (those
+        of absent experts last, in a group no table belongs to), each held
+        expert's run of rows goes through its SwiGLU in one grouped matrix
+        product (ops/grouped_matmul.py: a tile of rows is visited only where an
+        assignment lies), and every token sums its own rows, weighted. The
+        length is static at its worst case: group-limited routing lets a
+        token's whole top-k fall into the one group held here, so no bound
+        under ``N * k`` holds. Nothing is queued and nothing dropped: a row's
+        result depends on its own token alone. Returns ``[N, D]`` float32, the
+        shared expert (every token's, counted once) added."""
+        from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul
+
+        moe = self.cfg.moe
+        n, d = tokens.shape
+        k, h = moe.top_k, moe.hidden
+        first, count = moe.held_experts
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        init = nn.with_partitioning(nn.initializers.normal(0.02), (MODEL_AXIS, None, None))
+        gate_up = self.param("gate_up", init, (count, d, 2 * h), self.param_dtype)
+        down = self.param("down", init, (count, h, d), self.param_dtype)
+        with jax.named_scope("moe.experts"):
+            local = top_i.reshape(-1) - first  # [A], token-major
+            expert = jnp.where((local >= 0) & (local < count), local, count)
+            order = jnp.argsort(expert, stable=True)
+            sizes = jnp.zeros(count + 1, jnp.int32).at[expert].add(1)[:count]
+            held = sizes.sum()
+            # read with stats(), never in the step loop (engine: expert_assignments_held)
+            self.sow("intermediates", "held", held)
+            rows = tokens.astype(self.dtype)[order // k]  # [A, D], expert-major
+            z = grouped_matmul(rows, gate_up.astype(self.dtype), sizes)
+            gate, up = jnp.split(z, 2, axis=-1)
+            out = grouped_matmul(nn.silu(gate) * up, down.astype(self.dtype), sizes)
+            # rows past the held assignments belong to no expert: whatever the
+            # product left there is not a number anybody computed
+            out = jnp.where(jnp.arange(n * k)[:, None] < held, out, 0).astype(jnp.float32)
+            back = jnp.zeros(n * k, jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+            y = (out[back] * top_w.reshape(-1)[:, None]).reshape(n, k, d).sum(axis=1)
+        if moe.shared_hidden:
+            with jax.named_scope("moe.shared"):
+                x = tokens.astype(self.dtype)
+                up = proj(moe.shared_hidden, "out", name="shared_up", use_bias=False)(x)
+                gate = proj(moe.shared_hidden, "out", name="shared_gate", use_bias=False)(x)
+                y = y + proj(d, "in", name="shared_down", use_bias=False)(nn.silu(gate) * up)
+        return y
 
 
 # jax.named_scope names of the sites where a program partitioned over the
@@ -873,11 +1159,12 @@ def _residual(cfg: VLMConfig, x, branch):
     return x + branch if r == 1.0 else x + branch * r
 
 
-def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype):
-    """``x + ffn(RMSNorm(x))``: the second half of both kinds of layer. Called
-    inside a layer's compact method, so the submodules are that layer's."""
+def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype, dense_ffn=False):
+    """``x + ffn(RMSNorm(x))``: the second half of every kind of layer. Called
+    inside a layer's compact method, so the submodules are that layer's.
+    ``dense_ffn``: a sparse model's leading layer that keeps the dense SwiGLU."""
     y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense_ffn:
         moe = MoEFFN(cfg, dtype=dtype, param_dtype=param_dtype, name="moe")
         return _residual(cfg, x, moe(y))
     up = proj(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False)(y)
@@ -885,6 +1172,99 @@ def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype):
     with jax.named_scope(TP_SCOPES["mlp_down"]):
         down = proj(cfg.dim, "in", name="down", use_bias=False)(nn.silu(gate) * up)
     return _residual(cfg, x, down)
+
+
+class LatentAttentionLayer(nn.Module):
+    """A decoder layer with multi-head latent attention (DeepSeek-V2) where
+    ``DecoderLayer`` has GQA, then the same FFN half. The cache holds one row a
+    token, ``[c_kv | k_rope | zeros]`` (``MLAConfig.cache_width``), and
+    attention runs ABSORBED against it (ops/latent_attention.py): ``W_UK`` and
+    ``W_UV`` are the two halves of the one stored ``kv_b`` table, folded into
+    the query and the output at trace time, so prefill and decode are one set
+    of weights and no per-head K or V ever exists. Rope is the half-split
+    layout the other layers use; the converter owns the permutation from HF's
+    interleaved pairs (models/convert_deepseek.py)."""
+
+    cfg: VLMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
+    dense_ffn: bool = False  # a leading layer of a sparse model (``moe.first_dense``)
+
+    @nn.compact
+    def __call__(
+        self, x, cache, cache_v, positions, write_index, kv_len,
+        block_tables=None, layer_index=0,
+    ):
+        """x: [B, T, D]; cache: the slots' latent rows ``[B, 1, S, W]``, or in
+        paged mode (``block_tables`` set) the whole latent pool ``[L, NB, 1,
+        bs, W]``; cache_v: the zero-width companion every program threads
+        (paged_kv.init_latent_pool), handed back untouched. The rest as
+        ``DecoderLayer``. Returns (y, the updated cache, cache_v)."""
+        from cosmos_curate_tpu.models.vlm.paged_kv import latent_update
+        from cosmos_curate_tpu.ops.latent_attention import (
+            latent_attention,
+            latent_reference_attention,
+        )
+
+        cfg, mla = self.cfg, self.cfg.mla
+        b, t, _ = x.shape
+        h, c = cfg.n_heads, mla.kv_lora_rank
+        dn, dr, dv, w = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim, mla.cache_width
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        freqs = jnp.asarray(yarn_inv_freq(mla, cfg.rope_theta))
+        # what YaRN puts on cos and sin: mscale / mscale(all_dim), 1 when they agree
+        rope_gain = yarn_mscale(mla.yarn_factor, mla.yarn_mscale) / yarn_mscale(
+            mla.yarn_factor, mla.yarn_mscale_all_dim
+        )
+
+        def rope(v):  # [B, T, heads, dr]
+            v = apply_rope(v, positions, cfg.rope_theta, freqs=freqs)
+            return v if rope_gain == 1.0 else v * rope_gain
+
+        y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x)
+        c_q = RMSNorm(eps=cfg.rms_eps, name="q_a_norm")(
+            proj(mla.q_lora_rank, None, name="q_a", use_bias=False)(y)
+        )
+        q = proj(h * (dn + dr), "out", name="q_b", use_bias=False)(c_q).reshape(b, t, h, dn + dr)
+        q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
+        kv = proj(c + dr, None, name="kv_a", use_bias=False)(y)
+        c_kv = RMSNorm(eps=cfg.rms_eps, name="kv_a_norm")(kv[..., :c])
+        k_rope = rope(kv[..., None, c:])[:, :, 0]
+        row = jnp.concatenate(
+            [c_kv, k_rope, jnp.zeros((b, t, w - c - dr), c_kv.dtype)], axis=-1
+        )  # [B, T, W]
+        # HF ``kv_b_proj``, stored once: [C, H, nope | v] are W_UK and W_UV
+        kv_b = self.param(
+            "kv_b", nn.with_partitioning(nn.initializers.xavier_uniform(), (None, MODEL_AXIS)),
+            (c, h * (dn + dv)), self.param_dtype,
+        ).astype(self.dtype).reshape(c, h, dn + dv)
+        w_uk, w_uv = kv_b[..., :dn], kv_b[..., dn:]
+        q_abs = jnp.concatenate(
+            [
+                jnp.einsum("bthd,chd->bthc", q_nope, w_uk), q_rope,
+                jnp.zeros((b, t, h, w - c - dr), q_rope.dtype),
+            ],
+            axis=-1,
+        )  # [B, T, H, W]: scores against a cached row are one dot product
+        with jax.named_scope("mla.decode" if t == 1 else "mla.prefill"):
+            if block_tables is not None:
+                cache = latent_update(cache, row, block_tables, write_index, layer_index=layer_index)
+                u = latent_attention(
+                    q_abs, cache, block_tables, write_index, kv_len, layer_index=layer_index,
+                    sm_scale=mla.softmax_scale, v_width=c,
+                )
+            else:
+                cache = jax.vmap(
+                    lambda rows, chunk, idx: jax.lax.dynamic_update_slice(rows, chunk, (0, idx, 0))
+                )(cache, row.astype(cache.dtype)[:, None], write_index)
+                u = latent_reference_attention(
+                    q_abs, cache[:, 0], write_index, kv_len, sm_scale=mla.softmax_scale, v_width=c,
+                )
+        attn = jnp.einsum("bthc,chd->bthd", u.astype(self.dtype), w_uv).reshape(b, t, h * dv)
+        with jax.named_scope(TP_SCOPES["attn_out"]):
+            x = _residual(cfg, x, proj(cfg.dim, "in", name="o", use_bias=False)(attn))
+        ffn = _ffn_half(cfg, x, proj, self.dtype, self.param_dtype, dense_ffn=self.dense_ffn)
+        return ffn, cache, cache_v
 
 
 class MambaLayer(nn.Module):
@@ -942,13 +1322,21 @@ class VLM(nn.Module):
             param_dtype=self.param_dtype,
             embedding_init=nn.with_partitioning(nn.initializers.normal(0.02), (None, MODEL_AXIS)),
         )
+        def attention_layer(i):
+            if cfg.mla is None:
+                return DecoderLayer(
+                    cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
+                    name=f"layer_{i}",
+                )
+            return LatentAttentionLayer(
+                cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}",
+                dense_ffn=cfg.moe is not None and i < cfg.moe.first_dense,
+            )
+
         self.layers = [
             MambaLayer(cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}")
             if cfg.layer_types is not None and cfg.layer_types[i] == "mamba"
-            else DecoderLayer(
-                cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
-                name=f"layer_{i}",
-            )
+            else attention_layer(i)
             for i in range(cfg.n_layers)
         ]
         self.ln_f = RMSNorm(eps=cfg.rms_eps, name="ln_f")
@@ -1173,5 +1561,11 @@ def init_recurrent_store(cfg: VLMConfig, rows: int, dtype=jnp.bfloat16):
 
 
 def init_cache(cfg: VLMConfig, batch: int, dtype=jnp.bfloat16, length: int | None = None):
+    """Slot-row caches ``[L, batch, Hkv, length, Dh]`` for K and V; a latent
+    flavor's one row a token and its zero-width companion
+    (paged_kv.init_latent_pool has the why)."""
+    if cfg.mla is not None:
+        shape = (len(cfg.kv_layers), batch, 1, length or cfg.max_seq)
+        return jnp.zeros((*shape, cfg.mla.cache_width), dtype), jnp.zeros((*shape, 0), dtype)
     shape = (len(cfg.kv_layers), batch, cfg.n_kv_heads, length or cfg.max_seq, cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

@@ -146,6 +146,34 @@ def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sha
     return jnp.zeros(shape, dtype, device=sharding), jnp.zeros(shape, dtype, device=sharding)
 
 
+def init_latent_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16):
+    """The pool of a latent-attention flavor (``cfg.mla``): ONE array ``[L,
+    n_blocks, 1, block_size, W]``, a row a token a layer holding ``[c_kv |
+    k_rope | zeros]`` (``MLAConfig.cache_width``: 512 + 64 padded to 640, a
+    whole number of lane tiles; the chip stores a 576-wide array in 640 lanes
+    anyway and Mosaic slices none, PERF.md PR 33). The keys and the values of
+    absorbed attention are both read out of that row, so nothing is stored
+    twice. It has the K pool's five dimensions with one head plane: block
+    tables, the allocator, prefix blocks, copy-on-write and the ``gather``
+    programs' views treat it as they treat a K pool. The engine's programs
+    thread a V pool too; a latent flavor's is the same shape at WIDTH ZERO, no
+    bytes, so that no program grows a second signature."""
+    shape = (len(cfg.kv_layers), n_blocks, 1, block_size)
+    return jnp.zeros((*shape, cfg.mla.cache_width), dtype), jnp.zeros((*shape, 0), dtype)
+
+
+def latent_update(pool, rows, tables, write_index, *, layer_index=0):
+    """Write a chunk's latent rows into the latent pool through the block
+    table: :func:`paged_update`'s rule (index every pool dimension but the
+    last, so one update is a ``[W]`` row and the donated pool stays in the
+    kernel's operand layout) for the one array there is. pool: ``[L, NB, 1,
+    bs, W]``; rows: ``[B, T, W]``; tables: ``[B, nbl]``; write_index: ``[B]``."""
+    bs = pool.shape[3]
+    pos = write_index[:, None] + jnp.arange(rows.shape[1])[None, :]  # [B, T]
+    blk = jnp.take_along_axis(tables, pos // bs, axis=1)
+    return pool.at[layer_index, blk, 0, pos % bs].set(rows.astype(pool.dtype))
+
+
 def gather_block_views(pool_k, pool_v, tables):
     """Per-slot contiguous KV views through the block tables.
 
@@ -155,9 +183,10 @@ def gather_block_views(pool_k, pool_v, tables):
     programs are unchanged."""
     l, _, hk, bs, dh = pool_k.shape
     n, nbl = tables.shape
-    # [L, N, nbl, Hkv, bs, Dh] -> blocks of one head side by side
+    # [L, N, nbl, Hkv, bs, Dh] -> blocks of one head side by side (V by its
+    # own width: a latent flavor's V pool has width 0)
     vk = pool_k[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
-    vv = pool_v[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
+    vv = pool_v[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, pool_v.shape[-1])
     return vk, vv
 
 
@@ -171,7 +200,7 @@ def scatter_block_views(pool_k, pool_v, tables, view_k, view_v):
     l, _, hk, bs, dh = pool_k.shape
     n, nbl = tables.shape
     bk = view_k.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3)
-    bv = view_v.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3)
+    bv = view_v.reshape(l, n, hk, nbl, bs, pool_v.shape[-1]).swapaxes(2, 3)
     return pool_k.at[:, tables].set(bk), pool_v.at[:, tables].set(bv)
 
 

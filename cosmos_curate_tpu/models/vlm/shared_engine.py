@@ -90,6 +90,7 @@ class SharedCaptionEngine:
         model_id: str,
         max_batch: int = 8,
         kv_lanes: tuple | None = None,
+        prefill_rows: int | None = None,
         tokenizer: Any = None,
         dtype: str = "bfloat16",
         async_prep: bool = True,
@@ -98,11 +99,15 @@ class SharedCaptionEngine:
     ) -> CaptionEngine:
         """The shared engine for (model, dtype, mesh, sharding geometry),
         building + setting it up on first use. ``loader`` (called once,
-        with the fresh engine) returns the params to serve — weight loading
-        stays the caller's policy (require_weights etc.) without the
-        registry re-running it per stage. ``mesh`` selects the head-parallel
-        paged-attention geometry and is part of the key: differently
-        sharded engines never share."""
+        with the fresh engine, BEFORE its ``setup()``: no seeded tree exists
+        yet, and ``engine.param_template()`` has the structure a checkpoint
+        restores into) returns the params to serve, or None to serve seeded
+        ones — weight loading stays the caller's policy (require_weights
+        etc.) without the registry re-running it per stage. ``setup()`` then
+        places and narrows what was loaded, or seeds: a flavor whose float32
+        tree fits no chip beside its loaded one is never seeded first.
+        ``mesh`` selects the head-parallel paged-attention geometry and is
+        part of the key: differently sharded engines never share."""
         key = cls.key_for(cfg, model_id, dtype, mesh=mesh)
 
         def existing() -> "CaptionEngine | None":
@@ -146,14 +151,15 @@ class SharedCaptionEngine:
                 max_batch=max_batch,
                 tokenizer=tokenizer,
                 kv_lanes=kv_lanes,
+                max_prefill_rows=prefill_rows,
                 # production engines prep in the background so vision
                 # encoding of request N+1 overlaps decode of request N
                 async_prep=async_prep,
                 mesh=mesh,
             )
-            engine.setup()
             if loader is not None:
                 engine.params = loader(engine)
+            engine.setup()
             with cls._lock:
                 cls._engines[key] = engine
                 cls._building.pop(key, None)

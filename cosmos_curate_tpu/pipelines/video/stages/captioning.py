@@ -120,6 +120,7 @@ class _CaptionVLM(ModelInterface):
         text_only: bool = False,
         model_chips: int = 1,
         flavor: str | None = None,
+        prefill_rows: int | None = None,
     ) -> None:
         self.cfg = cfg
         self.max_batch = max_batch
@@ -128,6 +129,7 @@ class _CaptionVLM(ModelInterface):
         self.hf_chat = hf_chat
         self.specials = specials
         self.kv_lanes = kv_lanes
+        self.prefill_rows = prefill_rows  # FlavorSpec.prefill_rows
         self.text_only = text_only
         # chips of this host the engine's ``model`` mesh spans (FlavorSpec.
         # model_chips; 1 = no mesh) and the flavor's name for error messages
@@ -233,18 +235,21 @@ class _CaptionVLM(ModelInterface):
         tokenizer = self.tokenizer
 
         def loader(engine: CaptionEngine):
-            def init(seed: int):
-                return engine.params
-
-            return registry.load_params(
-                self.model_id, init, require=self.require_weights
+            # asked before the engine has seeded anything: a checkpoint is
+            # restored into the template's structure; where there is none
+            # (and the flavor may go without) the engine seeds at setup()
+            template = engine.param_template()
+            params = registry.load_params(
+                self.model_id, lambda seed: template, require=self.require_weights
             )
+            return None if params is template else params
 
         self.engine = SharedCaptionEngine.get(
             self.cfg,
             model_id=self.model_id,
             max_batch=self.max_batch,
             kv_lanes=self.kv_lanes,
+            prefill_rows=self.prefill_rows,
             tokenizer=tokenizer,
             loader=loader,
             mesh=self._serving_mesh(),
@@ -290,6 +295,7 @@ def resolve_caption_model(
             text_only=spec.text_only,
             model_chips=spec.model_chips,
             flavor=model_flavor,
+            prefill_rows=spec.prefill_rows,
         )
     return _CaptionVLM(cfg or VLM_BASE, max_batch)
 

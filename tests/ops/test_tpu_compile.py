@@ -228,3 +228,122 @@ def test_pool_keeps_the_kernels_layout_through_the_write(v5e, t, widths):
         counts.append(len(copies))
     assert counts[1] <= counts[0], f"pool-shaped copies grow with depth: {counts}"
     assert counts[0] <= 4, counts  # `base`: four at the program's boundary; 2B: none
+
+
+# -- the latent pool (DeepSeek-V2: one row of 640 lanes a token, 128 heads) ---
+
+MLA_HEADS, MLA_WIDTH, MLA_VALUES = 128, 640, 512
+
+
+def _latent_rows(arg, rows, t, lane, width=MLA_WIDTH):
+    from cosmos_curate_tpu.ops.latent_attention import _latent_rows as fn
+
+    return (
+        functools.partial(
+            fn, layer_index=1, sm_scale=0.1147, v_width=MLA_VALUES, rows_per_table=t, interpret=False
+        ),
+        (arg((rows * t, MLA_HEADS, width), jnp.bfloat16),
+         arg((LAYERS, POOL_BLOCKS, 1, BS, width), jnp.bfloat16),
+         arg((rows, lane // BS), jnp.int32), arg((rows * t,), jnp.int32)),
+    )
+
+
+@pytest.mark.parametrize(
+    "rows,t,lane,name",
+    [
+        pytest.param(256, 1, 1024, "mla_decode", id="decode-256-rows-short-lane"),
+        pytest.param(8, 1, 4096, "mla_decode", id="decode-8-rows-long-lane"),
+        pytest.param(8, 256, 1024, "mla_prefill", id="prefill-8-rows-T256"),
+        pytest.param(1, 256, 4096, "mla_prefill", id="prefill-1-row-long-lane"),
+    ],
+)
+def test_latent_kernel_compiles_for_v5e_under_its_trace_name(v5e, rows, t, lane, name):
+    """The shapes the DeepSeek-V2 cell gives the kernel: a decode step over a
+    lane's rows (256 tables of 64 entries in scalar memory), a prefill group
+    of 8 chunks as 2,048 query rows. The custom call carries the name the
+    benchmark's trace reduction looks for."""
+    from perfbench import trace_reduce
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = _latent_rows(arg, rows, t, lane)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [
+        trace_reduce.instruction(line.strip().removeprefix("ROOT "))  # alone, it is the program's root
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert calls and all(re.search(rf"^{name}(\.\d+)?$", c) for c in calls), calls
+
+
+def test_mosaic_slices_no_latent_row_of_576_lanes(v5e):
+    """Why the row is padded to 640: the chip stores a 576-wide array in 640
+    lanes anyway, and Mosaic refuses to slice it (PERF.md, PR 33)."""
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = _latent_rows(arg, 8, 1, 1024, width=576)
+    with pytest.raises(ValueError, match="not whole lane tiles"):
+        jax.jit(fn).lower(*args)
+
+
+@pytest.mark.parametrize("t", [1, T], ids=["decode", "prefill-T256"])
+def test_latent_pool_keeps_the_kernels_layout_through_the_write(v5e, t):
+    """``latent_update`` leaves the one array in the layout the kernel's
+    operand demands: no layer pays a pool-shaped ``copy`` between its write
+    and its kernel, and none appears with depth."""
+    from cosmos_curate_tpu.models.vlm.paged_kv import latent_update
+    from cosmos_curate_tpu.ops.latent_attention import _latent_rows as kernel
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows = B if t == 1 else 1
+
+    def program(layers, pool, q, chunk, tables, write_index):
+        own = (write_index[:, None] + jnp.arange(1, t + 1)[None, :]).reshape(-1)
+        for layer in range(layers):
+            pool = latent_update(pool, chunk, tables, write_index, layer_index=layer)
+            u = kernel(
+                q.reshape(rows * t, MLA_HEADS, MLA_WIDTH), pool, tables, own, layer_index=layer,
+                sm_scale=0.1147, v_width=MLA_VALUES, rows_per_table=t, interpret=False,
+            ).reshape(rows, t, MLA_HEADS, MLA_VALUES)
+            # the next layer's queries and rows depend on this layer's attention
+            q = q + jnp.pad(u, ((0, 0), (0, 0), (0, 0), (0, MLA_WIDTH - MLA_VALUES)))
+            chunk = chunk + u[:, :, 0].sum(axis=-1, keepdims=True)
+        return q, pool
+
+    counts = []
+    for layers in (LAYERS, 2 * LAYERS):
+        shape = (layers, POOL_BLOCKS, 1, BS, MLA_WIDTH)
+        hlo = jax.jit(functools.partial(program, layers), donate_argnums=(0,)).lower(
+            arg(shape, jnp.bfloat16), arg((rows, t, MLA_HEADS, MLA_WIDTH), jnp.bfloat16),
+            arg((rows, t, MLA_WIDTH), jnp.bfloat16), arg((rows, S // BS), jnp.int32),
+            arg((rows,), jnp.int32),
+        ).compile().as_text()
+        copies, feeding = _pool_copies(hlo, shape)
+        assert not feeding, f"{layers} layers: pool copies feed the kernel: {feeding}"
+        counts.append(len(copies))
+    assert counts == [0, 0], counts
+
+
+@pytest.mark.parametrize(
+    "m,k,n", [(1536, 5120, 3072), (1536, 1536, 5120), (12288, 5120, 3072)],
+    ids=["decode-gate-up", "decode-down", "prefill-gate-up"],
+)
+def test_grouped_matmul_compiles_for_v5e_without_copying_the_tables(v5e, m, k, n):
+    """DeepSeek-V2's 20 held experts at the cell's row counts: the tiles chosen
+    in ops/grouped_matmul.py fit a call's VMEM, the tables go into the kernel
+    as they are stored, and the custom call is named ``gmm``."""
+    from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    hlo = jax.jit(functools.partial(grouped_matmul, use_kernel=True, interpret=False)).lower(
+        arg((m, k), jnp.bfloat16), arg((20, k, n), jnp.bfloat16), arg((20,), jnp.int32)
+    ).compile().as_text()
+    assert re.search(r"%gmm(\.\d+)? = .*custom-call", hlo)
+    assert not re.search(r"= bf16\[20," + f"{k},{n}" + r"\]\{[^}]*\} copy\(", hlo)
