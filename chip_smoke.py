@@ -139,55 +139,78 @@ def _assert_program_holds(name: str, lowered, *ops: str) -> None:
 # -- phase: kernels ----------------------------------------------------------
 
 
-def phase_kernels(*, hkv: int = 2, group: int = 6, head_dim: int = 128, seed: int = 0) -> None:
-    """Each kernel, compiled for this chip, against the XLA reference
-    (``reference_attention`` over the gathered pages / the einsum lines of
-    ``layers.Attention``) on the same chip: Qwen2-VL-2B shapes, contexts 1k
-    and 4k, 16-token pages, block tables fragmented so logical order never
-    matches pool order."""
+def _paged_kernels_against_xla(what: str, hkv: int, group: int, head_dim: int, rng) -> None:
+    """The two paged kernels at one flavor's widths, out of a pool stored as
+    the engine stores it (``heads_per_row`` KV heads a 128-lane row), against
+    the XLA reference over the same K/V one head a row: contexts 1k and 4k,
+    16-token pages, block tables fragmented so logical order never matches
+    pool order. One line each for decode and prefill."""
     import functools
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from cosmos_curate_tpu.ops.flash_attention import flash_attention
-    from cosmos_curate_tpu.ops.paged_attention import _paged_reference, paged_attention
+    from cosmos_curate_tpu.ops.paged_attention import (
+        _paged_reference,
+        heads_per_row,
+        join_rows,
+        paged_attention,
+    )
 
     bs, layers, layer, chunk = 16, 2, 1, 256
-    rng = np.random.default_rng(seed)
+    r = heads_per_row(hkv, head_dim)
     for context, rows in ((1024, 8), (4096, 4)):
         nbl = context // bs
         n_blocks = rows * nbl + 8  # block 0 is the engine's garbage block
         shape = (layers, n_blocks, hkv, bs, head_dim)
-        pool_k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-        pool_v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        plain_k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        plain_v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        pool_k, pool_v = join_rows(plain_k, r), join_rows(plain_v, r)
         ids = rng.permutation(np.arange(1, n_blocks))[: rows * nbl]
         tables = jnp.asarray(ids.reshape(rows, nbl), jnp.int32)
+        where = f"{what} ({hkv} x {head_dim}, {r} a row) ctx {context}"
 
         # decode: one token per row at ragged valid lengths
         kv_len = jnp.asarray(rng.integers(context // 2, context + 1, rows), jnp.int32)
         q1 = jnp.asarray(rng.standard_normal((rows, 1, hkv, group, head_dim)), jnp.bfloat16)
-        args = (q1, pool_k, pool_v, tables, kv_len - 1, kv_len)
-        want = _paged_reference(*args, layer_index=layer, sm_scale=head_dim**-0.5)
+        args = (tables, kv_len - 1, kv_len)
+        want = _paged_reference(q1, plain_k, plain_v, *args, layer_index=layer, sm_scale=head_dim**-0.5)
         paged = jax.jit(
             functools.partial(
                 paged_attention, layer_index=layer, use_kernel=True, interpret=False
             )
         )
-        _assert_program_holds("paged decode", paged.lower(*args), "tpu_custom_call")
-        err = _assert_close(f"paged_decode@{context}", paged(*args), want)
-        log(f"kernels: paged_decode      ctx {context} max_err {err:.4f}")
+        _assert_program_holds("paged decode", paged.lower(q1, pool_k, pool_v, *args), "tpu_custom_call")
+        err = _assert_close(f"paged_decode@{where}", paged(q1, pool_k, pool_v, *args), want)
+        log(f"kernels: paged_decode      {where} max_err {err:.4f}")
 
         # chunked prefill: a chunk written mid-context, causal inside it
         write = jnp.asarray(rng.integers(0, context - chunk + 1, rows), jnp.int32)
         qt = jnp.asarray(
             rng.standard_normal((rows, chunk, hkv, group, head_dim)), jnp.bfloat16
         )
-        args = (qt, pool_k, pool_v, tables, write, write + chunk)
-        want = _paged_reference(*args, layer_index=layer, sm_scale=head_dim**-0.5)
-        err = _assert_close(f"paged_prefill@{context}", paged(*args), want)
-        log(f"kernels: paged_prefill     ctx {context} max_err {err:.4f}")
+        args = (tables, write, write + chunk)
+        want = _paged_reference(qt, plain_k, plain_v, *args, layer_index=layer, sm_scale=head_dim**-0.5)
+        err = _assert_close(f"paged_prefill@{where}", paged(qt, pool_k, pool_v, *args), want)
+        log(f"kernels: paged_prefill     {where} max_err {err:.4f}")
+
+
+def phase_kernels(*, hkv: int = 2, group: int = 6, head_dim: int = 128, seed: int = 0) -> None:
+    """Each kernel, compiled for this chip, against the XLA reference
+    (``reference_attention`` over the gathered pages / the einsum lines of
+    ``layers.Attention``) on the same chip: the paged kernels at Qwen2-VL-2B's
+    widths and at Granite-4.0-H-Micro's (64-wide heads, two a pool row), then
+    flash attention at the 2B's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cosmos_curate_tpu.ops.flash_attention import flash_attention
+
+    rng = np.random.default_rng(seed)
+    _paged_kernels_against_xla("qwen2vl-2b", hkv, group, head_dim, rng)
+    _paged_kernels_against_xla("granite-4.0-h-micro", 8, 4, 64, rng)
 
     # encoder self-attention (layers.Attention above FLASH_MIN_SEQ); 2049 is
     # InternVideo2's 8x256+1 tokens — the ragged tail pads inside the op
@@ -579,6 +602,23 @@ def phase_caption_hybrid(*, seed: int = 0) -> None:
     t0 = time.monotonic()
     engine = CaptionEngine(cfg, kv_lanes=lanes)
     engine.setup(seed)
+    # the pool holds two 64-wide KV heads a 128-lane row, so no program copies
+    # it whole: read off the compiled decode program of the short lane
+    zeros = jnp.zeros(lanes[0][1], jnp.int32)
+    compiled = engine._decode.lower(
+        engine.params, engine._pool_k, engine._pool_v, jnp.asarray(engine.lanes[0].table),
+        zeros, zeros, zeros, engine._ssm, engine._conv, zeros,
+    ).compile().as_text()
+    from scripts.pool_copies import whole_array_copies
+
+    pool = "bf16[" + ",".join(map(str, engine._pool_k.shape)) + "]"
+    copies = {k: n for k, n in whole_array_copies(compiled).items() if k[1].startswith(pool)}
+    log(
+        f"caption-hybrid: KV pool {engine._pool_k.shape}, {engine.stats()['kv_heads_per_pool_row']} heads a row; "
+        f"the compiled decode program holds {sum(copies.values())} pool-shaped copies {copies}"
+    )
+    if copies or engine._pool_k.shape[-1] != 128:
+        raise AssertionError(f"caption-hybrid: the decode program copies the pool: {copies}")
     logits = _capture_first_logits(engine)
     done = _drain(engine, requests(32))
     stats = engine.stats()

@@ -562,6 +562,9 @@ class CaptionEngine:
         self._pool_k = None
         self._pool_v = None
         self._kv_pool_bytes_per_chip = 0  # until setup() makes the pool
+        # KV heads side by side in one pool row (paged_kv.init_block_pool:
+        # two where head_dim is 64, so that a row is a whole lane tile)
+        self._kv_heads_per_pool_row = 1
         # the recurrent store (hybrid flavors; None otherwise): slot ``i`` of
         # lane ``l`` owns row ``1 + l.base + i``
         self._ssm = None
@@ -787,6 +790,7 @@ class CaptionEngine:
             self._pool_k, self._pool_v = init_block_pool(
                 cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
             )
+            self._kv_heads_per_pool_row = cfg.n_kv_heads // self._pool_k.shape[2]
         self._kv_pool_bytes_per_chip = _bytes_per_chip((self._pool_k, self._pool_v))
         if self._recurrent:
             self._ssm, self._conv = init_recurrent_store(
@@ -796,6 +800,11 @@ class CaptionEngine:
 
         model = self.model
         bs = self.block_size
+        # the `gather` programs' views and the shared prefix's blocks are
+        # the pool's pages split into head planes, and joined again
+        from cosmos_curate_tpu.ops.paged_attention import join_rows
+
+        r = self._kv_heads_per_pool_row
 
         @jax.jit
         def encode_images(params, frames_u8):
@@ -826,7 +835,7 @@ class CaptionEngine:
             slot-row shapes — byte-identical math), writes every row's
             cells in one program, scatters the blocks back, and returns
             each row's logits at its last valid position: [N, V]."""
-            ck, cv = gather_block_views(pool_k, pool_v, tables)
+            ck, cv = gather_block_views(pool_k, pool_v, tables, r)
             logits, nk, nv = model.apply(
                 params,
                 embeds,
@@ -858,7 +867,7 @@ class CaptionEngine:
             if mrope:
                 # decode is always text: all three components equal
                 rp = jnp.broadcast_to(rp[..., None], (*rp.shape, 3))
-            ck, cv = gather_block_views(pool_k, pool_v, tables)
+            ck, cv = gather_block_views(pool_k, pool_v, tables, r)
             logits, nk, nv = model.apply(
                 params,
                 embeds,
@@ -952,10 +961,10 @@ class CaptionEngine:
             l, hk, tp, dh = pk.shape
             pad = ((0, 0), (0, 0), (0, ids.shape[0] * bs - tp), (0, 0))
 
-            def blocks(x, dtype):  # -> [L, nb, Hkv, bs, Dh]
+            def blocks(x, dtype):  # -> [L, nb, Hkv / r, bs, r * Dh], the pool's pages
                 # (nothing inferred, x's own width: a latent flavor's V has width 0)
                 x = jnp.pad(x.astype(dtype), pad)
-                return x.reshape(l, hk, ids.shape[0], bs, x.shape[-1]).swapaxes(1, 2)
+                return join_rows(x.reshape(l, hk, ids.shape[0], bs, x.shape[-1]).swapaxes(1, 2), r)
 
             pool_k = pool_k.at[:, ids].set(blocks(pk, pool_k.dtype))
             pool_v = pool_v.at[:, ids].set(blocks(pv, pool_v.dtype))
@@ -1007,7 +1016,7 @@ class CaptionEngine:
         experts held here (``MoEFFN._sorted_experts`` sows one a layer). Two
         int32s, ``[units, 2**30s]``, so that it never wraps; not donated, so a
         reader of ``stats()`` may hold an old one while the next is made."""
-        cfg, model, use_paged = self.cfg, self.model, self._use_paged
+        cfg, model, use_paged, r = self.cfg, self.model, self._use_paged, self._kv_heads_per_pool_row
         if self._recurrent or cfg.mrope_section is not None:
             raise ValueError("sorted expert dispatch beside a recurrent store or m-rope has no program here")
 
@@ -1021,7 +1030,7 @@ class CaptionEngine:
                     method=model.paged_forward, mutable=["intermediates"],
                 )
             else:
-                ck, cv = gather_block_views(pool_k, pool_v, tables)
+                ck, cv = gather_block_views(pool_k, pool_v, tables, r)
                 (logits, nk, nv), aux = model.apply(
                     params, embeds, ck, cv, *args, mutable=["intermediates"]
                 )
@@ -1052,7 +1061,7 @@ class CaptionEngine:
         others', its two arrays after the pools in what is returned), the
         shared prefix's build with its state snapshot, and the two ways a
         slot's row starts. They take the place of setup()'s."""
-        cfg, model, use_paged = self.cfg, self.model, self._use_paged
+        cfg, model, use_paged, r = self.cfg, self.model, self._use_paged, self._kv_heads_per_pool_row
         if cfg.mrope_section is not None or self._ds_levels:
             raise ValueError("a hybrid flavor with m-rope or deepstack has no program here")
 
@@ -1064,7 +1073,7 @@ class CaptionEngine:
                     params, embeds, pool_k, pool_v, rope, write_index, kv_len, tables,
                     method=model.paged_forward, **kw,
                 )
-            ck, cv = gather_block_views(pool_k, pool_v, tables)
+            ck, cv = gather_block_views(pool_k, pool_v, tables, r)
             logits, nk, nv, ssm, conv = model.apply(
                 params, embeds, ck, cv, rope, write_index, kv_len, **kw
             )
@@ -1419,6 +1428,8 @@ class CaptionEngine:
                 # partitioned over model=4, the whole of what is repeated
                 "param_bytes_per_chip": self._param_bytes_per_chip,
                 "kv_pool_bytes_per_chip": self._kv_pool_bytes_per_chip,
+                # KV heads side by side in one 128-lane pool row (1: a row a head)
+                "kv_heads_per_pool_row": self._kv_heads_per_pool_row,
                 "kv_block_size": self.block_size,
                 "kv_block_size_requested": self.block_size_requested,
                 "paged_kernel_steps": self._paged_kernel_steps,

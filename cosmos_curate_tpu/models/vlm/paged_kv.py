@@ -2,9 +2,10 @@
 
 vLLM's PagedAttention block-table design (Kwon et al. 2023 — PAPERS.md)
 re-shaped for XLA's static-shape compilation: KV memory is ONE block pool
-``[L, n_blocks, Hkv, block_size, Dh]`` and every slot owns a block *table*
-instead of a worst-case-length cache row, so a request's KV footprint is
-``ceil(len / block_size)`` blocks. The engine has two sets of programs over
+``[L, n_blocks, Hkv / r, block_size, r * Dh]`` (``r`` KV heads a row: below)
+and every slot owns a block *table* instead of a worst-case-length cache
+row, so a request's KV footprint is ``ceil(len / block_size)`` blocks. The
+engine has two sets of programs over
 that pool (``paged_attention=``). The default, ``"auto"``, writes a chunk's
 K/V through the block table (:func:`paged_update`) and attends straight out
 of the pool (ops/paged_attention.py, which alone decides between its Pallas
@@ -18,16 +19,38 @@ and scatters the written blocks back (:func:`scatter_block_views`).
 
 The layout contract between the write and the kernels: the pool has ONE
 device layout from a program's entry to its exit, the one the paged
-kernels' operand demands — row-major ``[L, NB, Hkv, bs, Dh]`` with
-``(bs, Dh)`` tiled, because what a kernel fetches is a page: one head's
-``[bs, Dh]`` at prefill (``BlockSpec((None, None, None, bs, Dh))``), all
-heads' ``[Hkv, bs, Dh]`` copied by the decode kernel itself. XLA chooses a
+kernels' operand demands — row-major with the last two dimensions tiled,
+because what a kernel fetches is a page: one head row's ``[bs, W]`` at
+prefill (``BlockSpec((None, None, None, bs, W))``), all of them copied by
+the decode kernel itself. Two things keep it.
+
+**The row is a whole lane tile** (PR 34). The chip tiles a bfloat16 array's
+last two dimensions ``(16, 128)``. ``Dh`` = 128 fills a tile's lanes; a row
+of ``Dh`` = 64 fills half, and XLA then holds the pool in two forms, lanes
+padded for the kernels and compressed at the program's boundary, and
+copies the whole pool from one to the other around the kernel calls (20
+copies a decode program of Granite-4.0-H, two thirds of its device time).
+So where ``Dh`` < 128 divides 128 and ``r = 128 // Dh`` divides the KV
+heads a chip holds (``ops/paged_attention.heads_per_row``: the decision
+lives there), :func:`init_block_pool` makes the pool ``[L, NB, Hkv / r, bs,
+r * Dh]``: ``r`` consecutive heads side by side in one 128-lane row, the
+same bytes, nothing padded. A token's ``[Hkv, Dh]`` is already that in
+memory, so the write reshapes and moves nothing; the kernels see fewer,
+wider heads (``ops/paged_attention.paged_attention``); the ``gather``
+programs' views, the shared prefix's blocks and the XLA reference pack and
+unpack by reshape (``join_rows`` / ``split_rows``), so the model sees
+``[L, N, Hkv, S, Dh]`` as ever. The allocator, the tables, prefix blocks and
+``copy_blocks`` deal in whole blocks and do not know. ``r`` = 1 (``Dh`` =
+128; the test-size flavors' 16 with two heads, which make no tile) is the
+pool as it was before.
+
+**The write indexes every dimension but the last.** XLA chooses a
 scatter's layout from its update window: a window that spans ``[Hkv, Dh]``
 (the head left as a slice, as this write was first phrased) makes those two
 dimensions minor, and every layer then pays a relayout ``copy`` of the whole K and V pool in
 front of its kernel call (2 x 28 x 1.13 ms of an 85 ms Qwen2-VL-2B decode
 step: PERF.md, PR 25). :func:`paged_update` therefore indexes every pool
-dimension but ``Dh``, so an update is one ``[Dh]`` row and the donated pool
+dimension but the last, so an update is one row and the donated pool
 is updated in place; ``tests/ops/test_tpu_compile.py`` compiles write +
 kernel for a described chip and fails on a pool-shaped copy.
 
@@ -136,13 +159,21 @@ class BlockAllocator:
 
 
 def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sharding=None):
-    """The K and V block pools: ``[L, n_blocks, Hkv, block_size, Dh]``, ``L``
-    the layers that hold K/V (a hybrid's state-space layers keep their state
-    in the engine's recurrent store instead, ``model.init_recurrent_store``) —
-    heads-major, so one head's page is a contiguous ``[block_size, Dh]``
-    tile (the shape the TPU's compiler accepts as a kernel block).
+    """The K and V block pools: ``[L, n_blocks, Hkv / r, block_size, r * Dh]``,
+    ``L`` the layers that hold K/V (a hybrid's state-space layers keep their
+    state in the engine's recurrent store instead,
+    ``model.init_recurrent_store``) — heads-major, so one head row's page is
+    a contiguous ``[block_size, r * Dh]`` tile (the shape the TPU's compiler
+    accepts as a kernel block). ``r`` KV heads share a row where that makes
+    the row one whole 128-lane tile (``Dh`` 64: two; the module docstring
+    has why), judged on the heads ONE chip holds; else ``r`` = 1.
     ``sharding`` creates them already placed (head planes over a mesh)."""
+    from cosmos_curate_tpu.ops.paged_attention import heads_per_row
+
     shape = (len(cfg.kv_layers), n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
+    held = cfg.n_kv_heads if sharding is None else sharding.shard_shape(shape)[2]
+    r = heads_per_row(held, cfg.head_dim)
+    shape = (*shape[:2], cfg.n_kv_heads // r, block_size, r * cfg.head_dim)
     return jnp.zeros(shape, dtype, device=sharding), jnp.zeros(shape, dtype, device=sharding)
 
 
@@ -174,53 +205,68 @@ def latent_update(pool, rows, tables, write_index, *, layer_index=0):
     return pool.at[layer_index, blk, 0, pos % bs].set(rows.astype(pool.dtype))
 
 
-def gather_block_views(pool_k, pool_v, tables):
+def gather_block_views(pool_k, pool_v, tables, heads_per_row: int = 1):
     """Per-slot contiguous KV views through the block tables.
 
-    pool_k/v: ``[L, NB, Hkv, bs, Dh]``; tables: ``[N, nbl]`` int32 block
-    ids. Returns ``[L, N, Hkv, nbl * bs, Dh]`` views — the same shape the
-    slot-row engine's cache rows had, so the model and its compiled
-    programs are unchanged."""
-    l, _, hk, bs, dh = pool_k.shape
+    pool_k/v: ``[L, NB, Hkv / r, bs, r * Dh]``, ``r`` = ``heads_per_row``
+    (a pool cannot say of itself whether a 128-lane row is one head or
+    two: its maker does); tables: ``[N, nbl]`` int32 block ids. Returns
+    ``[L, N, Hkv, nbl * bs, Dh]`` views — the same shape the slot-row
+    engine's cache rows had, so the model and its compiled programs are
+    unchanged."""
+    from cosmos_curate_tpu.ops.paged_attention import split_rows
+
+    l, _, hp, bs, w = pool_k.shape
     n, nbl = tables.shape
+    hk, dh = hp * heads_per_row, w // heads_per_row
     # [L, N, nbl, Hkv, bs, Dh] -> blocks of one head side by side (V by its
     # own width: a latent flavor's V pool has width 0)
-    vk = pool_k[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
-    vv = pool_v[:, tables].swapaxes(2, 3).reshape(l, n, hk, nbl * bs, pool_v.shape[-1])
-    return vk, vv
+    vk = split_rows(pool_k[:, tables], heads_per_row).swapaxes(2, 3).reshape(l, n, hk, nbl * bs, dh)
+    vv = split_rows(pool_v[:, tables], heads_per_row).swapaxes(2, 3)
+    return vk, vv.reshape(l, n, hk, nbl * bs, vv.shape[-1])
 
 
 def scatter_block_views(pool_k, pool_v, tables, view_k, view_v):
-    """Write updated per-slot views back into the pool blocks.
+    """Write updated per-slot views back into the pool blocks (packed again
+    where the pool holds several heads a row: the views' ``Hkv`` over the
+    pool's says how many).
 
     Duplicate table entries (shared prefix blocks, garbage padding) write
     identical values by the engine's copy-on-write invariant — see the
     module docstring — so the scatter's undefined duplicate-write order
     cannot change pool contents."""
-    l, _, hk, bs, dh = pool_k.shape
+    from cosmos_curate_tpu.ops.paged_attention import join_rows
+
+    l, _, hk, _, dh = view_k.shape
     n, nbl = tables.shape
-    bk = view_k.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3)
-    bv = view_v.reshape(l, n, hk, nbl, bs, pool_v.shape[-1]).swapaxes(2, 3)
+    bs, r = pool_k.shape[3], hk // pool_k.shape[2]
+    bk = join_rows(view_k.reshape(l, n, hk, nbl, bs, dh).swapaxes(2, 3), r)
+    bv = join_rows(view_v.reshape(l, n, hk, nbl, bs, view_v.shape[-1]).swapaxes(2, 3), r)
     return pool_k.at[:, tables].set(bk), pool_v.at[:, tables].set(bv)
 
 
 def paged_update(pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
     """Write a chunk's K/V into the block pools through the block table.
 
-    pool_k/v: ``[L, NB, Hkv, bs, Dh]``; k/v: ``[B, T, Hkv, Dh]`` (the
+    pool_k/v: ``[L, NB, Hkv / r, bs, r * Dh]``; k/v: ``[B, T, Hkv, Dh]`` (the
     chunk, rope already applied); tables: ``[B, nbl]``; write_index:
     ``[B]`` (token ``t`` of row ``b`` lands at logical position
     ``write_index[b] + t``). Returns the updated pools.
 
-    Every pool dimension but ``Dh`` is indexed, the head too, so one
-    update is a ``[Dh]`` row and XLA's scatter keeps the pool in the paged
-    kernels' operand layout (the module docstring's layout contract). No
-    ``unique_indices``: idle rows collide in block 0 by design."""
+    A token's ``[Hkv, Dh]`` read as ``[Hkv / r, r * Dh]`` is the pool's rows
+    (adjacent heads are adjacent in memory: nothing moves). Every pool
+    dimension but the last is indexed, the head row too, so one update is a
+    whole row and XLA's scatter keeps the pool in the paged kernels' operand
+    layout (the module docstring's layout contract). No ``unique_indices``:
+    idle rows collide in block 0 by design."""
     bs = pool_k.shape[3]
+    if pool_k.shape[-1] != k.shape[-1]:  # several heads a row
+        rows = (*k.shape[:2], pool_k.shape[2], pool_k.shape[4])
+        k, v = k.reshape(rows), v.reshape(rows)
     pos = write_index[:, None] + jnp.arange(k.shape[1])[None, :]  # [B, T]
     blk = jnp.take_along_axis(tables, pos // bs, axis=1)[:, :, None]
     off = (pos % bs)[:, :, None]
-    head = jnp.arange(pool_k.shape[2])[None, None, :]  # [1, 1, Hkv]
+    head = jnp.arange(pool_k.shape[2])[None, None, :]  # [1, 1, Hkv / r]
     new_k = pool_k.at[layer_index, blk, head, off].set(k.astype(pool_k.dtype))
     new_v = pool_v.at[layer_index, blk, head, off].set(v.astype(pool_v.dtype))
     return new_k, new_v
@@ -229,7 +275,8 @@ def paged_update(pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
 def paged_head_update(mesh, pool_k, pool_v, k, v, tables, write_index, *, layer_index=0):
     """:func:`paged_update` head-parallel over the model mesh axis: the
     pools and the chunk shard on their ``Hkv`` dimension (each shard writes
-    its own head plane), block tables and positions replicate. It is the
+    its own head planes; a pool row's ``r`` heads live on one chip, see
+    :func:`init_block_pool`), block tables and positions replicate. It is the
     same function under a ``shard_map``, so an extent-1 model axis is
     bit-equal to it. Accepts an ``AbstractMesh`` so shardcheck's
     ``vlm-paged-head-scatter`` contract traces this call site device-free

@@ -25,29 +25,57 @@ and scattered it back. This op deletes that copy:
   ``P`` comes from the shapes (``_decode_pages``): at least 128 keys, and
   more while a buffer stays within 128 KiB. The layer is a prefetched
   scalar like the table, so a model's layers share one trace of the
-  kernel. Where ``D`` is not a whole number of lane tiles (``base``: 64)
-  Mosaic slices no HBM array, and the pipeline delivers the same groups
-  through ``P`` ``BlockSpec``s a pool instead. That second form compiles
-  at every width but is not the one kernel: at ``D`` = 128 its ``2 * P``
-  pipeline copies a step cost 71-142 us a call against 13-30 for the
-  kernel's own (PR 27, one v5e), 11-12% of both 2B cells' tokens a second;
+  kernel. Where ``D`` makes no whole lane tile and cannot be packed into
+  one (below: the test-size flavors, ``D`` = 16 with two KV heads) Mosaic
+  slices no HBM array, and the pipeline delivers the same groups through
+  ``P`` ``BlockSpec``s a pool instead (``_paged_decode_blockspec_kernel``).
+  That second form compiles at every width and is kept for exactly those
+  widths: at ``D`` = 128 its ``2 * P`` pipeline copies a step cost 71-142
+  us a call against 13-30 for the kernel's own (PR 27, one v5e), and at
+  ``D`` = 64 it ran at 8% of its roofline behind a pool XLA stored twice
+  over (PR 30: below);
 - **prefill skips by grid step**: one page a grid step, and blocks at or
   after the row's valid length or beyond the chunk's last causal position
   are skipped with ``pl.when`` (ROADMAP S4: what is left of it).
 
+**A pool row is a whole lane tile** (PR 34). The pool is ``[L, NB, Hp, bs,
+W]`` bfloat16 and the chip tiles its last two dimensions ``(16, 128)``. At
+``D`` = 128 a head's row is one tile row: ``Hp`` = ``Hkv``, ``W`` = ``D``. A
+row of ``D`` = 64 would be half of one: XLA then keeps the pool twice over,
+compressed (the block dimension minor-most) at the program's boundary and
+padded to 128 lanes for the Pallas operand, and under memory pressure
+changes between the two around every layer's kernel call (Granite-4.0-H:
+20 whole-pool copies a decode program, 3.35 of 5.3 s of device time; PERF.md
+PR 34). So where ``D`` < 128 divides 128 and ``r = 128 // D`` divides the KV
+heads a chip holds (:func:`heads_per_row`), ``r`` consecutive KV heads share
+a row: ``Hp`` = ``Hkv / r``, ``W`` = ``r * D``, the same bytes with no lane
+padding, head ``j`` of a row's ``r`` in lanes ``[j * D, (j + 1) * D)``. The
+kernels never learn of it. :func:`paged_attention` reads ``r`` off the
+shapes at its door (``pool.shape[-1] // q.shape[-1]``) and hands them
+``Hkv / r`` heads of ``r * G`` query rows and ``r * D`` lanes: a head's
+queries sit in their own lanes of the row and zeros in the others, so a
+product with the row is that head's own score, and of the ``[r * G, r *
+D]`` output the ``r`` diagonal ``[G, D]`` blocks are kept. On a 128-wide
+MXU a contraction of 64 costs the passes of one of 128; the bytes moved are
+the true K/V bytes. ``r`` = 1 is the pool as it always was, and its
+programs are byte for byte what they were. The XLA reference splits the
+pages it gathered back into head planes (:func:`split_rows`) and runs the
+same lines on the same shapes as ever.
+
 Both ways of reaching a page, the decode kernel's copy of ``pool[layer,
-block]`` and a ``BlockSpec`` ``(None, None, ..., bs, D)`` of the pool ``[L,
-NB, Hkv, bs, D]``, pin the pool operand to the row-major layout with ``(bs,
-D)`` tiled. Whatever produces the pool inside the same program must leave
-it in that layout, or XLA puts a relayout ``copy`` of the whole pool in
-front of every call: the write, ``models/vlm/paged_kv.paged_update``, keeps
-its side of the contract by indexing every dimension but ``D`` (its module
-docstring; pinned by ``tests/ops/test_tpu_compile.py``). A change to the
-page's shape here is a change to that contract.
+block]`` and a ``BlockSpec`` ``(None, None, ..., bs, W)`` of the pool, pin
+the pool operand to the row-major layout with ``(bs, W)`` tiled. Whatever
+produces the pool inside the same program must leave it in that layout, or
+XLA puts a relayout ``copy`` of the whole pool in front of every call: the
+write, ``models/vlm/paged_kv.paged_update``, keeps its side of the contract
+by indexing every dimension but the last (its module docstring; pinned by
+``tests/ops/test_tpu_compile.py``, which counts pool-shaped copies at every
+width that is served: none). A change to the page's shape here is a change
+to that contract.
 
 Which implementation runs is decided here and nowhere else, from what the
-code can observe: on a TPU the Pallas kernels (``D % 128`` picks the decode
-form, above), elsewhere ``reference_attention``, the plain XLA lines, over
+code can observe: on a TPU the Pallas kernels (the row's width ``% 128``
+picks the decode form, above), elsewhere ``reference_attention``, the plain XLA lines, over
 the row's pages gathered for the einsum (never scattered back); NOT
 interpret-mode Pallas. ``DecoderLayer``'s slot-cache branch (the engine's
 ``gather`` programs) calls the same function, so the byte-identical parity
@@ -73,6 +101,66 @@ from cosmos_curate_tpu.ops.tiling import round_up, sublanes
 
 _NEG_INF = -1e30
 _GROUP_BUFFER_BYTES = 128 * 1024  # one of the decode kernel's four page buffers
+_LANES = 128  # the minor dimension of a tile, whatever the type
+
+
+def heads_per_row(n_kv_heads: int, head_dim: int) -> int:
+    """``r``: KV heads stored side by side in one pool row (the module
+    docstring's layout contract). ``128 // head_dim`` where that makes the
+    row one whole lane tile and divides ``n_kv_heads``, the heads ONE chip
+    holds; otherwise 1, the pool as ``[.., Hkv, bs, D]``. Padding heads
+    with zeros to force a tile is not done: it would store bytes nobody
+    reads."""
+    if head_dim >= _LANES or _LANES % head_dim or n_kv_heads % (_LANES // head_dim):
+        return 1
+    return _LANES // head_dim
+
+
+def split_rows(pages: jax.Array, r: int) -> jax.Array:
+    """Pool pages ``[..., Hp, bs, r * D]`` as head planes ``[..., Hp * r, bs,
+    D]``: what the XLA reference and the ``gather`` programs' views are
+    made of. A copy (a row's heads interleave in memory), of pages that
+    were gathered, and so copied, anyway."""
+    if r == 1:
+        return pages
+    *lead, hp, bs, w = pages.shape
+    return pages.reshape(*lead, hp, bs, r, w // r).swapaxes(-3, -2).reshape(*lead, hp * r, bs, w // r)
+
+
+def join_rows(planes: jax.Array, r: int) -> jax.Array:
+    """:func:`split_rows` undone: head planes ``[..., Hkv, bs, D]`` as pool
+    pages ``[..., Hkv / r, bs, r * D]``."""
+    if r == 1:
+        return planes
+    *lead, hk, bs, d = planes.shape
+    return planes.reshape(*lead, hk // r, r, bs, d).swapaxes(-3, -2).reshape(*lead, hk // r, bs, r * d)
+
+
+def _queries_by_row(q: jax.Array, r: int) -> jax.Array:
+    """Grouped queries ``[B, T, Hkv, G, D]`` against a pool of ``r`` heads a
+    row: ``[B, T, Hkv / r, r * G, r * D]``, head ``j`` of a row in query
+    rows ``[j * G, (j + 1) * G)`` and lanes ``[j * D, (j + 1) * D)``, zeros
+    in the other heads' lanes, so that a product with the pool's row is the
+    head's own score and nothing of its neighbours'."""
+    if r == 1:
+        return q
+    b, t, hk, g, d = q.shape
+    q = q.reshape(b, t, hk // r, r, g, d)
+    own = jnp.eye(r, dtype=q.dtype)[:, None, :, None]  # [r, 1, r, 1]
+    return (q[:, :, :, :, :, None, :] * own).reshape(b, t, hk // r, r * g, r * d)
+
+
+def _outputs_by_head(out: jax.Array, r: int) -> jax.Array:
+    """What the kernels return for :func:`_queries_by_row`'s queries, ``[B,
+    T, Hkv / r, r * G, r * D]``, cut back to ``[B, T, Hkv, G, D]``: query
+    rows of head ``j`` keep lanes ``[j * D, (j + 1) * D)``, their own head's
+    values (the other lanes hold their probabilities times a neighbour's)."""
+    if r == 1:
+        return out
+    b, t, hp, rg, rd = out.shape
+    g, d = rg // r, rd // r
+    out = out.reshape(b, t, hp, r, g, r, d)
+    return jnp.stack([out[:, :, :, j, :, j] for j in range(r)], axis=3).reshape(b, t, hp * r, g, d)
 
 
 def reference_attention(q, k, v, write_index, kv_len, *, sm_scale):
@@ -104,9 +192,11 @@ def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_in
     slot-cache branch, so CPU outputs are bit-equal to the gather programs."""
     b, _, hk, _, d = q.shape
     s = tables.shape[1] * pool_k.shape[3]
-    # [B, nbl, Hkv, bs, D] -> the slot-row view [B, Hkv, S, D]
-    k = pool_k[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
-    v = pool_v[layer_index][tables].swapaxes(1, 2).reshape(b, hk, s, d)
+    r = pool_k.shape[-1] // d
+    # [B, nbl, Hkv, bs, D] (a packed pool's pages split into head planes
+    # first) -> the slot-row view [B, Hkv, S, D]
+    k = split_rows(pool_k[layer_index][tables], r).swapaxes(1, 2).reshape(b, hk, s, d)
+    v = split_rows(pool_v[layer_index][tables], r).swapaxes(1, 2).reshape(b, hk, s, d)
     return reference_attention(q, k, v, write_index, kv_len, sm_scale=sm_scale)
 
 
@@ -214,7 +304,12 @@ def _paged_decode_blockspec_kernel(
     ``_paged_decode``): grid ``(row, group)``, the group's ``pages`` pages
     of K and of V delivered by as many ``BlockSpec``s, the softmax state in
     scratch across a row's grid steps. A group past the valid length costs
-    a grid step and nothing else."""
+    a grid step and nothing else. Kept for exactly the widths that make no
+    whole lane tile even packed (``heads_per_row`` = 1 under 128 lanes:
+    ``tiny-test`` and the other test-size flavors, ``D`` = 16 with 2 or 4
+    KV heads, which a chip serves too: ``local split --caption-model
+    tiny-test``); every served width (``base`` and Granite packed, the
+    Qwens) takes ``_paged_decode_kernel``."""
     k_refs, v_refs = refs[:pages], refs[pages : 2 * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * pages :]
     b, i = pl.program_id(0), pl.program_id(1)
@@ -314,7 +409,9 @@ def _paged_prefill_kernel(
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, interpret):
-    """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl].
+    """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]
+    (against a pool of ``r`` heads a row these are ``Hkv / r``, ``r * G``
+    and ``r * D``: ``paged_attention``).
     ``layer_index`` is a run-time scalar here, prefetched with the table:
     a model's layers share one trace and one lowering of the kernel."""
     b, hk, g, d = q.shape
@@ -338,7 +435,8 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
     else:
         # Mosaic slices no HBM array whose last dimension is under a lane
         # tile ("Slice shape along dimension 4 must be aligned to tiling
-        # (128), but is 64": `base`, D = 64), so there the pipeline fetches
+        # (128), but is 64"), so for a row that packing could not make a
+        # whole tile (the test-size flavors, D = 16) the pipeline fetches
         # the group: one BlockSpec a page. An entry at or past the valid
         # length names the row's last live page again (not fetched twice,
         # masked by position), so no block past the length is ever read.
@@ -457,10 +555,12 @@ def paged_attention(
 
     q: ``[B, T, Hkv, G, D]`` UNSCALED grouped queries (this op applies
     ``sm_scale``, in the kernels as in the reference);
-    pool_k/pool_v: the full block pools ``[L, NB, Hkv, bs, D]`` with the
-    chunk's K/V already written through the table; tables: ``[B, nbl]``
-    logical-to-physical block ids; write_index/kv_len: ``[B]``. Serves both
-    decode (T=1) and chunked prefill (T>1). Returns ``[B, T, Hkv, G, D]``.
+    pool_k/pool_v: the full block pools ``[L, NB, Hkv / r, bs, r * D]``
+    (``r`` KV heads a row, read off these shapes: the module docstring;
+    ``sm_scale`` defaults from the true ``D``) with the chunk's K/V already
+    written through the table; tables: ``[B, nbl]`` logical-to-physical
+    block ids; write_index/kv_len: ``[B]``. Serves both decode (T=1) and
+    chunked prefill (T>1). Returns ``[B, T, Hkv, G, D]``.
 
     ``use_kernel=None`` means the Pallas kernels on a TPU and the XLA
     reference (:func:`reference_attention` over the gathered pages) elsewhere.
@@ -476,16 +576,19 @@ def paged_attention(
         )
     if interpret is None:
         interpret = jax.devices()[0].platform == "cpu"
+    r = pool_k.shape[-1] // q.shape[-1]  # KV heads a pool row: the module docstring
+    q = _queries_by_row(q, r)
     if q.shape[1] == 1:
         out = _paged_decode(
             q[:, 0], pool_k, pool_v, tables, kv_len,
             layer_index=layer_index, sm_scale=sm_scale, interpret=interpret,
+        )[:, None]
+    else:
+        out = _paged_prefill(
+            q, pool_k, pool_v, tables, write_index, kv_len,
+            layer_index=layer_index, sm_scale=sm_scale, block_q=block_q, interpret=interpret,
         )
-        return out[:, None]
-    return _paged_prefill(
-        q, pool_k, pool_v, tables, write_index, kv_len,
-        layer_index=layer_index, sm_scale=sm_scale, block_q=block_q, interpret=interpret,
-    )
+    return _outputs_by_head(out, r)
 
 
 def paged_head_attention(
@@ -506,8 +609,10 @@ def paged_head_attention(
     """Head-parallel paged attention over the model mesh axis.
 
     Queries, KV pools, and the output shard on their ``Hkv`` dimension over
-    ``parallel/axes.MODEL``; block tables and lengths replicate (every shard
-    walks the same table against its own head plane — attention is
+    ``parallel/axes.MODEL`` (the pools' is ``Hkv / r`` where ``r`` heads
+    share a row: ``r`` was judged on the heads one chip holds, so a row
+    never straddles two chips); block tables and lengths replicate (every
+    shard walks the same table against its own head plane — attention is
     embarrassingly parallel over KV heads). Accepts an ``AbstractMesh`` so
     shardcheck's ``vlm-paged-head-attention`` contract traces this call
     site device-free. On a mesh without the model axis (or extent 1) the
@@ -522,7 +627,7 @@ def paged_head_attention(
         sm_scale = q.shape[-1] ** -0.5
     axis = MODEL if MODEL in mesh.axis_names else None
     qspec = P(None, None, axis, None, None)  # [B, T, Hkv, G, D]
-    pspec = P(None, None, axis, None, None)  # [L, NB, Hkv, bs, D]
+    pspec = P(None, None, axis, None, None)  # [L, NB, Hkv / r, bs, r * D]
     fn = functools.partial(
         paged_attention,
         layer_index=layer_index,

@@ -5,6 +5,7 @@ programs with ops/ssm.py forced onto its TPU side: the chunked SSD prefill and
 the Pallas decode kernel in interpret mode) and ``gather`` (the recurrence in
 plain XLA)."""
 
+import dataclasses
 import re
 
 import flax.linen as nn
@@ -29,9 +30,19 @@ STATE_TOL = 0.015
 CHUNK = 16
 
 
+# the tiny preset with Granite-4.0-H-Micro's attention heads (8 KV heads of 64,
+# 4 query heads each): the pool holds two KV heads a 128-lane row
+CFG_GRANITE_HEADS = dataclasses.replace(CFG, n_heads=32, n_kv_heads=8, head_dim=64, attention_multiplier=1 / 64)
+
+
 @pytest.fixture(scope="module")
 def params():
     return nn.unbox(_init_params(VLM(CFG), seed=5))
+
+
+@pytest.fixture(scope="module")
+def params_granite_heads():
+    return nn.unbox(_init_params(VLM(CFG_GRANITE_HEADS), seed=6))
 
 
 def _ids(seed, n):
@@ -63,11 +74,11 @@ class Spy:
         engine._start_slot, engine._decode = start_slot, decode_step
 
 
-def _engine(kind, params, monkeypatch, lanes=((64, 4), (128, 2))):
+def _engine(kind, params, monkeypatch, lanes=((64, 4), (128, 2)), cfg=CFG):
     if kind == "kernel":
         monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
     engine = CaptionEngine(
-        CFG, kv_lanes=lanes, params=jax.tree.map(jnp.copy, params), prefill_chunk=CHUNK,
+        cfg, kv_lanes=lanes, params=jax.tree.map(jnp.copy, params), prefill_chunk=CHUNK,
         paged_attention="gather" if kind == "gather" else "kernel", block_size=8,
     )
     engine.setup()
@@ -81,9 +92,9 @@ def _request(name, prompt, prefix=(), max_new=1, share=True):
     )
 
 
-def _reference(params, ids, positions):
+def _reference(params, ids, positions, cfg=CFG):
     return np.asarray(
-        ref.logits_at(params, jnp.asarray(ids, jnp.int32), positions, **ref.model_kwargs(CFG))
+        ref.logits_at(params, jnp.asarray(ids, jnp.int32), positions, **ref.model_kwargs(cfg))
     )
 
 
@@ -91,20 +102,20 @@ def _rel(got, want):
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
-def _assert_decode_matches(params, spy, engine_tokens, name, prompt):
+def _assert_decode_matches(params, spy, engine_tokens, name, prompt, cfg=CFG):
     """The first-step logits and every decode step's against the reference's
     full forward over prompt + generated ids, and the state the request left
     in its row of the store (a released row keeps it until the next claim)
     against the reference's after the same ids."""
     generated = engine_tokens[name]
     ids = list(prompt) + generated[:-1]
-    want = _reference(params, ids, list(range(len(prompt) - 1, len(ids))))
+    want = _reference(params, ids, list(range(len(prompt) - 1, len(ids))), cfg)
     got = np.stack([spy.first[name], *spy.steps.get(name, [])])
     assert got.shape == want.shape
     assert _rel(got, want) < TOL
     state = np.asarray(spy.engine._ssm[0, spy.row[name]])
     want_state = np.asarray(
-        ref.first_ssm_state(params, jnp.asarray(ids, jnp.int32), **ref.model_kwargs(CFG))
+        ref.first_ssm_state(params, jnp.asarray(ids, jnp.int32), **ref.model_kwargs(cfg))
     )
     assert _rel(state, want_state) < STATE_TOL
 
@@ -196,16 +207,47 @@ def test_slot_reused_after_a_longer_tenant(kind, params, monkeypatch):
     _assert_decode_matches(params, spy, tokens, "short", short)
 
 
-def test_kernel_engine_agrees_with_gather_engine(params, monkeypatch):
+@pytest.mark.parametrize("heads", ["tiny", "granite-heads"])
+def test_kernel_engine_agrees_with_gather_engine(heads, request, monkeypatch):
+    """Token for token, at the tiny preset's heads (a pool row a head) and at
+    Granite's (two a row)."""
+    cfg, params = {
+        "tiny": (CFG, "params"), "granite-heads": (CFG_GRANITE_HEADS, "params_granite_heads")
+    }[heads]
+    params = request.getfixturevalue(params)
     prompt, prefix = _ids(9, 30), _ids(10, 8)
-    firsts = {}
+    firsts, tokens = {}, {}
     for kind in KINDS:
         with monkeypatch.context() as m:
-            engine, spy = _engine(kind, params, m)
-            engine.add_request(_request("x", prompt, prefix=prefix, max_new=2))
-            _run(engine)
+            engine, spy = _engine(kind, params, m, cfg=cfg)
+            assert engine.stats()["kv_heads_per_pool_row"] == (1 if cfg is CFG else 2)
+            engine.add_request(_request("x", prompt, prefix=prefix, max_new=6))
+            tokens[kind] = _run(engine)["x"]
             firsts[kind] = spy.first["x"]
     assert _rel(firsts["kernel"], firsts["gather"]) < TOL / 2
+    assert tokens["kernel"] == tokens["gather"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_two_kv_heads_a_pool_row_against_the_reference(kind, params_granite_heads, monkeypatch):
+    """Granite's attention heads on the tiny preset, so the KV pool is ``[1,
+    NB, 4, 8, 128]``: a prompt behind a shared prefix (its blocks written by
+    ``write_prefix_blocks``, twice read: the build and a hit), prefilled in
+    chunks while another row decodes, then decoded, against the float32
+    reference, which knows of no pool."""
+    cfg, params = CFG_GRANITE_HEADS, params_granite_heads
+    engine, spy = _engine(kind, params, monkeypatch, cfg=cfg)
+    assert engine._pool_k.shape == (1, engine.kv_pool_blocks, 4, 8, 128)
+    prefix, first, long = _ids(11, 16), _ids(12, 12), _ids(13, 37)
+    engine.add_request(_request("a", first, prefix=prefix, max_new=12))
+    while not engine.slots:
+        engine.step()
+    engine.add_request(_request("b", long, prefix=prefix, max_new=4))
+    tokens = _run(engine)
+    assert engine.prefix_cache_hits >= 1
+    _assert_decode_matches(params, spy, tokens, "b", prefix + long, cfg)
+    _assert_decode_matches(params, spy, tokens, "a", prefix + first, cfg)
+    engine.shutdown()
 
 
 def test_a_hybrid_is_refused_over_a_mesh():

@@ -10,6 +10,7 @@ must produce byte-identical text, across lane buckets and under m-rope:
 paging is a memory-management change, not an approximation.
 """
 
+import dataclasses
 import threading
 import zlib
 from functools import partial
@@ -60,6 +61,20 @@ def _drain(eng, reqs):
     for r in reqs:
         eng.add_request(r)
     return {r.request_id: r.text for r in eng.run_until_complete()}
+
+
+# (Hkv, G, D) of the tiny preset, and of the two served flavors whose pool
+# holds two KV heads a 128-lane row, on the tiny preset's other widths
+HEAD_WIDTHS = {"tiny-test": (2, 2, 16), "base": WIDTHS["base"], "granite-4.0-h-micro": WIDTHS["granite-4.0-h-micro"]}
+
+
+def _with_heads(name):
+    """(the tiny preset with ``name``'s attention heads, KV heads a pool row)."""
+    from cosmos_curate_tpu.ops.paged_attention import heads_per_row
+
+    hk, g, d = HEAD_WIDTHS[name]
+    cfg = dataclasses.replace(VLM_TINY_TEST, n_heads=hk * g, n_kv_heads=hk, head_dim=d)
+    return cfg, heads_per_row(hk, d)
 
 
 def slot_row_reference(eng: CaptionEngine, req: CaptionRequest, cache_len: int) -> str:
@@ -363,8 +378,8 @@ class TestPagedAttentionModes:
     GNARLY = dict(max_batch=4, kv_lanes=((64, 2), (128, 2)), prefill_chunk=16)
 
     @staticmethod
-    def _mode_engine(mode, params=None, **kw):
-        eng = CaptionEngine(VLM_TINY_TEST, paged_attention=mode, **kw)
+    def _mode_engine(mode, params=None, cfg=VLM_TINY_TEST, **kw):
+        eng = CaptionEngine(cfg, paged_attention=mode, **kw)
         eng.setup()
         if params is not None:
             eng.params = params
@@ -412,13 +427,24 @@ class TestPagedAttentionModes:
         ):
             assert key in stats
 
-    def test_kernel_vs_gather_bit_equal_across_lane_buckets(self):
+    @pytest.mark.parametrize("heads", sorted(HEAD_WIDTHS))
+    def test_kernel_vs_gather_bit_equal_across_lane_buckets(self, heads):
         """Same prompts through both program families, spanning both lane
         buckets and chunked prefill: greedy texts AND every written pool
         cell must match bitwise (block 0 is the garbage block — idle rows
-        park writes there and the two families park different garbage)."""
-        kernel = self._mode_engine("kernel", **self.GNARLY)
-        gather = self._mode_engine("gather", kernel.params, **self.GNARLY)
+        park writes there and the two families park different garbage).
+        At the tiny preset's own heads (a pool row a head) and at `base`'s
+        and Granite's, whose pool holds two 64-wide heads a row: the paged
+        programs read that pool in place, the gather programs through views
+        split into head planes, and both against the slot-row reference,
+        which knows of no pool."""
+        cfg, r = _with_heads(heads)
+        kernel = self._mode_engine("kernel", cfg=cfg, **self.GNARLY)
+        gather = self._mode_engine("gather", kernel.params, cfg=cfg, **self.GNARLY)
+        assert kernel.stats()["kv_heads_per_pool_row"] == gather.stats()["kv_heads_per_pool_row"] == r
+        assert kernel._pool_k.shape[2:] == (cfg.n_kv_heads // r, kernel.block_size, r * cfg.head_dim)
+        if r > 1:
+            assert kernel._pool_k.shape[-1] == 128
 
         def reqs():
             return [
@@ -430,6 +456,7 @@ class TestPagedAttentionModes:
         got_k = _drain(kernel, reqs())
         got_g = _drain(gather, reqs())
         assert got_k == got_g
+        assert got_k["mid"] == slot_row_reference(kernel, reqs()[2], 128)
         np.testing.assert_array_equal(
             np.asarray(kernel._pool_k)[:, 1:], np.asarray(gather._pool_k)[:, 1:]
         )
@@ -511,6 +538,7 @@ class TestSlotBranchAgainstPagedBranch:
     def test_bit_equal(self, widths, t, write):
         from cosmos_curate_tpu.models.vlm.model import DecoderLayer, VLMConfig
         from cosmos_curate_tpu.models.vlm.paged_kv import gather_block_views
+        from cosmos_curate_tpu.ops.paged_attention import heads_per_row, join_rows
 
         hk, g, d = WIDTHS[widths]
         cfg = VLMConfig(dim=64, n_heads=hk * g, n_kv_heads=hk, head_dim=d, hidden_mult=2.0)
@@ -518,16 +546,23 @@ class TestSlotBranchAgainstPagedBranch:
         rng = np.random.default_rng(zlib.crc32(widths.encode()) + t)
         nbl = self.S // self.BS
         n_blocks = self.B * nbl + 2
-        pool_k, pool_v = (
+        a_head_a_row = [
             jnp.asarray(rng.standard_normal((2, n_blocks, hk, self.BS, d)), jnp.bfloat16)
             for _ in range(2)
-        )
+        ]
+        # the pool as the engine stores it: two 64-wide heads a row
+        r = heads_per_row(hk, d)
+        assert r == (2 if d == 64 else 1)
+        pool_k, pool_v = (join_rows(pool, r) for pool in a_head_a_row)
         ids = rng.permutation(np.arange(1, n_blocks))[: self.B * nbl]
         tables = jnp.asarray(ids.reshape(self.B, nbl), jnp.int32)
         x = jnp.asarray(rng.standard_normal((self.B, t, cfg.dim)), jnp.bfloat16)
         write = jnp.asarray(write, jnp.int32)
         positions = write[:, None] + jnp.arange(t)[None, :]
-        rows_k, rows_v = (c[self.LAYER] for c in gather_block_views(pool_k, pool_v, tables))
+        rows_k, rows_v = (c[self.LAYER] for c in gather_block_views(pool_k, pool_v, tables, r))
+        assert rows_k.shape == (self.B, hk, self.S, d)
+        for got, want in zip((rows_k, rows_v), gather_block_views(*a_head_a_row, tables)):
+            np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want[self.LAYER], np.float32))
         params = layer.init(
             jax.random.PRNGKey(0), x, rows_k, rows_v, positions, write, write + t
         )
@@ -543,8 +578,17 @@ class TestSlotBranchAgainstPagedBranch:
             np.asarray(y_paged, np.float32), np.asarray(y_slot, np.float32)
         )
         paged_k, paged_v = (
-            c[self.LAYER] for c in gather_block_views(new_pool_k, new_pool_v, tables)
+            c[self.LAYER] for c in gather_block_views(new_pool_k, new_pool_v, tables, r)
         )
+        # and out of the same K/V one head a row: the same to the bit
+        y_unpacked, *unpacked = jax.jit(partial(layer.apply, layer_index=self.LAYER))(
+            params, x, *a_head_a_row, positions, write, write + t, block_tables=tables
+        )
+        np.testing.assert_array_equal(np.asarray(y_paged, np.float32), np.asarray(y_unpacked, np.float32))
+        for got, want in zip((new_pool_k, new_pool_v), unpacked):
+            np.testing.assert_array_equal(
+                np.asarray(got[:, 1:], np.float32), np.asarray(join_rows(want, r)[:, 1:], np.float32)
+            )
         np.testing.assert_array_equal(np.asarray(paged_k, np.float32), np.asarray(new_k, np.float32))
         np.testing.assert_array_equal(np.asarray(paged_v, np.float32), np.asarray(new_v, np.float32))
         assert not np.array_equal(np.asarray(new_k, np.float32), np.asarray(rows_k, np.float32))
@@ -718,6 +762,34 @@ class TestPagedUpdate:
         )
 
     @pytest.mark.parametrize("t", [1, 256])
+    @pytest.mark.parametrize("widths", ["base", "granite-4.0-h-micro"])
+    def test_two_heads_a_row_hold_the_same_cells(self, t, widths):
+        """The write into a pool of two 64-wide heads a row (the chunk's
+        ``[Hkv, Dh]`` read as ``[Hkv / 2, 128]``, nothing moved) leaves what
+        the write into the same pool one head a row leaves, packed."""
+        from cosmos_curate_tpu.models.vlm.paged_kv import paged_update
+        from cosmos_curate_tpu.ops.paged_attention import heads_per_row, join_rows
+
+        hk, _, d = WIDTHS[widths]
+        r = heads_per_row(hk, d)
+        rng = np.random.default_rng(t)
+        pools = [
+            jnp.asarray(rng.standard_normal((self.L, self.NB, hk, self.BS, d)), jnp.bfloat16)
+            for _ in range(2)
+        ]
+        _, _, _, _, tables, write_index = self._case(t)
+        k, v = (jnp.asarray(rng.standard_normal((4, t, hk, d)), jnp.float32) for _ in range(2))
+        want = paged_update(*pools, k, v, tables, write_index, layer_index=1)
+        got = jax.jit(partial(paged_update, layer_index=1))(
+            *(join_rows(pool, r) for pool in pools), k, v, tables, write_index
+        )
+        for g, w in zip(got, want):
+            assert g.shape == (self.L, self.NB, hk // 2, self.BS, 128)
+            np.testing.assert_array_equal(
+                np.asarray(g[:, 1:], np.float32), np.asarray(join_rows(w, r)[:, 1:], np.float32)
+            )
+
+    @pytest.mark.parametrize("t", [1, 256])
     @pytest.mark.parametrize("extent", [1, 2])
     def test_head_update_is_the_same_function_under_a_shard_map(self, t, extent):
         """Extent 1 is the docstring's promise (bit-equal to the unsharded
@@ -734,3 +806,65 @@ class TestPagedUpdate:
             np.testing.assert_array_equal(
                 np.asarray(g[:, 1:].astype(jnp.float32)), np.asarray(w[:, 1:].astype(jnp.float32))
             )
+
+
+class TestPoolViews:
+    """What the ``gather`` programs and the shared prefix see of a pool that
+    holds ``r`` KV heads a row: head planes, whatever ``r``."""
+
+    @pytest.mark.parametrize("widths", sorted(WIDTHS))
+    def test_views_round_trip_through_the_pool(self, widths):
+        from cosmos_curate_tpu.models.vlm.model import VLMConfig
+        from cosmos_curate_tpu.models.vlm.paged_kv import (
+            gather_block_views, init_block_pool, scatter_block_views,
+        )
+
+        hk, g, d = WIDTHS[widths]
+        cfg = VLMConfig(n_layers=2, n_heads=hk * g, n_kv_heads=hk, head_dim=d)
+        pool_k, pool_v = init_block_pool(cfg, 12, 16)
+        r = hk // pool_k.shape[2]
+        assert pool_k.shape == (2, 12, hk // r, 16, r * d)
+        assert pool_k.shape[-1] % 128 == 0 or d == 16
+        rng = np.random.default_rng(zlib.crc32(widths.encode()))
+        tables = jnp.asarray(rng.permutation(np.arange(1, 12))[:6].reshape(2, 3), jnp.int32)
+        views = [jnp.asarray(rng.standard_normal((2, 2, hk, 48, d)), jnp.bfloat16) for _ in range(2)]
+        pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, *views)
+        assert pool_k.shape == (2, 12, hk // r, 16, r * d)
+        for got, want in zip(gather_block_views(pool_k, pool_v, tables, r), views):
+            np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+        # token 20 of slot 1, head 3 of 8: block 1 of its table, offset 4, row 3 // r, lanes of head 3 % r
+        if hk == 8:
+            cell = pool_k[1, tables[1, 1], 3 // r, 4, (3 % r) * d : (3 % r + 1) * d]
+            np.testing.assert_array_equal(np.asarray(cell, np.float32), np.asarray(views[0][1, 1, 3, 20], np.float32))
+        # untouched blocks stay zero
+        free = sorted(set(range(12)) - set(np.asarray(tables).ravel().tolist()))
+        assert not np.asarray(pool_k[:, jnp.asarray(free)], np.float32).any()
+
+    @pytest.mark.parametrize("heads", sorted(HEAD_WIDTHS))
+    def test_a_prefix_written_into_its_blocks_reads_back_equal(self, heads):
+        """``write_prefix_blocks`` (the one device write of a prefix build)
+        against what ``prefix_prefill`` returned, read back through the
+        views the ``gather`` programs see and through the block ids."""
+        from cosmos_curate_tpu.models.vlm.paged_kv import gather_block_views
+
+        cfg, r = _with_heads(heads)
+        eng = CaptionEngine(cfg, max_batch=2, kv_lanes=((64, 2),), prefill_chunk=16)
+        eng.setup()
+        tp, sp, bs = 41, 64, eng.block_size  # two full blocks and a tail of 9
+        rng = np.random.default_rng(3)
+        emb = jnp.asarray(rng.standard_normal((1, sp, cfg.dim)), jnp.float32)
+        pos = jnp.arange(sp, dtype=jnp.int32)[None]
+        k, v = eng._prefix_prefill(eng.params, emb, pos, jnp.asarray(tp, jnp.int32))
+        k, v = k[:, :, :tp], v[:, :, :tp]
+        assert k.shape == (cfg.n_layers, cfg.n_kv_heads, tp, cfg.head_dim)
+        ids = [7, 3, 5]
+        pool_k, pool_v = eng._write_prefix_blocks(
+            eng._pool_k, eng._pool_v, k, v, jnp.asarray(ids, jnp.int32)
+        )
+        assert pool_k.shape[2:] == (cfg.n_kv_heads // r, bs, r * cfg.head_dim)
+        views = gather_block_views(pool_k, pool_v, jnp.asarray([ids], jnp.int32), r)
+        for got, want in zip(views, (k, v)):
+            np.testing.assert_array_equal(
+                np.asarray(got[:, 0, :, :tp], np.float32), np.asarray(want.astype(pool_k.dtype), np.float32)
+            )
+            assert not np.asarray(got[:, 0, :, tp:], np.float32).any()  # the tail block's padding

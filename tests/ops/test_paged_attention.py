@@ -14,7 +14,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from cosmos_curate_tpu.ops.paged_attention import paged_attention, paged_head_attention
+from cosmos_curate_tpu.ops.paged_attention import (
+    heads_per_row,
+    join_rows,
+    paged_attention,
+    paged_head_attention,
+    split_rows,
+)
+from tests.ops.test_tpu_compile import WIDTHS  # (Hkv, G, D) of the flavors the kernels serve
 
 
 def _dense_reference(q, k_cache, v_cache, write_index, kv_len, sm_scale):
@@ -155,18 +162,23 @@ class TestInterpretKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-    @pytest.mark.parametrize("d", [128, 64], ids=["own-copies", "pipeline-d64"])
-    def test_decode_kernel_never_reads_a_page_past_the_valid_length(self, d, dtype):
+    @pytest.mark.parametrize(
+        "d,r", [(128, 1), (64, 1), (64, 2)], ids=["own-copies", "pipeline-d64", "own-copies-d64-packed"]
+    )
+    def test_decode_kernel_never_reads_a_page_past_the_valid_length(self, d, r, dtype):
         """Every table entry at or past a row's valid length points at a
         block of NaN (the engine points them at its garbage block 0): the
         kernel fetches no such entry, so nothing of that block can reach
         the result, not even multiplied by a zero probability. The
-        reference gathers whole tables, so it reads the clean one."""
+        reference gathers whole tables, so it reads the clean one. (``r``
+        KV heads a pool row: 64-wide heads in pairs are the kernel that
+        copies for itself, alone the pipeline's.)"""
         rng = np.random.default_rng(7)
         b, hk, g, nbl, bs = 3, 2, 4, 20, 16
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
             rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2, dtype=dtype
         )
+        pk, pv = join_rows(pk, r), join_rows(pv, r)
         kv_len = jnp.asarray([1, 130, 257], jnp.int32)  # 1, 9 and 17 live pages of 20
         want = paged_attention(
             q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False
@@ -194,6 +206,7 @@ class TestInterpretKernel:
             pytest.param(16, 2, 128, jnp.bfloat16, 64, 16, id="qwen2vl-2b-1024"),
             pytest.param(16, 1, 128, jnp.bfloat16, 256, 32, id="qwen25vl-7b-shard"),
             pytest.param(16, 8, 64, jnp.bfloat16, 64, 8, id="base-d64"),
+            pytest.param(16, 4, 128, jnp.bfloat16, 64, 8, id="d64-two-heads-a-row"),
             pytest.param(8, 2, 32, jnp.float32, 3, 3, id="shorter-table"),
         ],
     )
@@ -294,7 +307,116 @@ class TestInterpretKernel:
         )
 
 
+# (Hkv, G, D) whose pool the engine stores two KV heads a 128-lane row
+PACKED_WIDTHS = {name: WIDTHS[name] for name in ("base", "granite-4.0-h-micro")}
+
+
+class TestPackedPool:
+    """A pool of ``r`` KV heads a row (``[L, NB, Hkv / r, bs, r * D]``, made by
+    ``join_rows`` as ``init_block_pool`` shapes it) against the same K/V one
+    head a row: the XLA reference to the bit (it splits the pages it gathered
+    and runs the same lines on the same shapes), the kernels to the tolerance
+    they owe the reference (their groups of pages differ with the page's
+    shape, so their online softmax rounds differently)."""
+
+    @staticmethod
+    def _case(widths, t, dtype):
+        hk, g, d = PACKED_WIDTHS[widths]
+        rng = np.random.default_rng(11 + t)
+        b, nbl, bs = 3, 20, 16
+        q, pk, pv, tables, layer, _, _ = _fragmented_case(
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2, dtype=dtype
+        )
+        kv_len = jnp.asarray([320, 37, 129], jnp.int32)
+        write = kv_len - t
+        r = heads_per_row(hk, d)
+        assert r == 2
+        return (q, pk, pv, tables, write, kv_len), (q, join_rows(pk, r), join_rows(pv, r), tables, write, kv_len), layer
+
+    @pytest.mark.parametrize("t", [1, 16], ids=["decode", "chunk-T16"])
+    @pytest.mark.parametrize("widths", sorted(PACKED_WIDTHS))
+    def test_reference_is_bit_equal_to_the_unpacked_pool(self, widths, t):
+        unpacked, packed, layer = self._case(widths, t, jnp.bfloat16)
+        assert packed[1].shape[2:] == (4, 16, 128)
+        want = paged_attention(*unpacked, layer_index=layer, use_kernel=False)
+        got = paged_attention(*packed, layer_index=layer, use_kernel=False)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("t", [1, 16], ids=["decode", "chunk-T16"])
+    @pytest.mark.parametrize("widths", sorted(PACKED_WIDTHS))
+    def test_kernels_out_of_a_packed_pool_match_the_reference(self, widths, t, dtype):
+        """The decode kernel that copies for itself and the prefill kernel,
+        handed ``Hkv / r`` heads of ``r * G`` query rows and ``r * D`` lanes:
+        a head's zeros in its neighbour's lanes keep the neighbour's keys
+        out of its scores, and its own lanes of the output are its values."""
+        unpacked, packed, layer = self._case(widths, t, dtype)
+        want = paged_attention(*unpacked, layer_index=layer, use_kernel=False)
+        got = paged_attention(
+            *packed, layer_index=layer, use_kernel=True, interpret=True, block_q=8
+        )
+        tol = dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32 else dict(atol=3e-2, rtol=3e-2)
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tol)
+
+    def test_scale_defaults_from_the_true_head_dim(self):
+        unpacked, packed, layer = self._case("base", 1, jnp.float32)
+        want = paged_attention(*unpacked, layer_index=layer, sm_scale=64**-0.5, use_kernel=False)
+        for use_kernel in (False, True):
+            got = paged_attention(*packed, layer_index=layer, use_kernel=use_kernel, interpret=True)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize(
+        "hk,d,r",
+        [
+            pytest.param(8, 64, 2, id="base-and-granite"),
+            pytest.param(2, 128, 1, id="qwen2vl-2b"),
+            pytest.param(1, 128, 1, id="one-head-a-chip"),
+            pytest.param(2, 16, 1, id="tiny-test-makes-no-tile"),
+            pytest.param(8, 16, 8, id="eight-heads-of-16"),
+            pytest.param(3, 64, 1, id="odd-heads-are-not-padded"),
+            pytest.param(4, 96, 1, id="96-divides-no-tile"),
+            pytest.param(4, 256, 1, id="wider-than-a-tile"),
+        ],
+    )
+    def test_heads_a_row_come_from_the_widths(self, hk, d, r):
+        assert heads_per_row(hk, d) == r
+        pages = jnp.arange(5 * hk * 16 * d, dtype=jnp.float32).reshape(5, hk, 16, d)
+        packed = join_rows(pages, r)
+        assert packed.shape == (5, hk // r, 16, r * d)
+        # head j of a row lives in lanes [j * D, (j + 1) * D)
+        for j in range(r):
+            np.testing.assert_array_equal(
+                np.asarray(packed[:, :, :, j * d : (j + 1) * d]), np.asarray(pages[:, j::r])
+            )
+        np.testing.assert_array_equal(np.asarray(split_rows(packed, r)), np.asarray(pages))
+
+
 class TestHeadParallel:
+    def test_sharded_heads_two_a_row_bit_equal_to_single_device(self, cpu_mesh):
+        """Eight 64-wide KV heads over the model axis (4): two a chip, one
+        pool row a chip, the row's heads never on two chips."""
+        rng = np.random.default_rng(12)
+        b, hk, g, d, nbl, bs = 2, 8, 2, 64, 3, 8
+        q, pk, pv, tables, layer, _, _ = _fragmented_case(
+            rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        kv_len = jnp.asarray([nbl * bs, 11], jnp.int32)
+        r = heads_per_row(hk // 4, d)
+        assert r == 2
+        sharded = paged_head_attention(
+            cpu_mesh, q, join_rows(pk, r), join_rows(pv, r), tables, kv_len - 1, kv_len,
+            layer_index=layer, use_kernel=False,
+        )
+        planes = [
+            paged_attention(
+                q[:, :, h : h + 2], pk[:, :, h : h + 2], pv[:, :, h : h + 2],
+                tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False,
+            )
+            for h in range(0, hk, 2)
+        ]
+        assert np.array_equal(np.asarray(sharded), np.concatenate(planes, axis=2))
+
     def test_sharded_heads_bit_equal_to_single_device(self, cpu_mesh):
         """shard_map over the model axis (Hkv sharded, tables replicated)
         must be BIT-equal to the unsharded op run on each shard's head
