@@ -71,6 +71,7 @@ vllm_async_stage.py). TPU-first re-design:
 from __future__ import annotations
 
 import contextlib
+import itertools
 import math
 import threading
 import time
@@ -312,13 +313,14 @@ class _InFlight:
     logits: Any  # [n_slots, V] on the device; read only for a row sampled on the host
     rows: dict
     positions: np.ndarray  # [n_slots] as dispatched: a row's kv length less one
+    program: int  # its number; a thread's own rise in the order it hands them over
 
     def emitted(self, lane: _Lane) -> dict:
         """The rows whose token counts: those whose slot is still the lane's."""
         return {i: s for i, s in self.rows.items() if lane.slots.get(i) is s}
 
 
-# Phases of the engine's two threads, each timed at one site by
+# Phases of the engine's two threads, each timed and counted at one site by
 # CaptionEngine._phase(): the span "engine.<name>" on the profiler's clock and
 # the counter "<name>_s" of phase_seconds on the host's. The roots also report
 # their elapsed time (`step_s`, `prep_s`); their self time is `<root>_other_s`.
@@ -336,6 +338,87 @@ _PHASES = _PHASE_ROOTS + (
     "decode_sample",
     "vision_encode",
 )
+# What phase_seconds carries of a phase beside its seconds: "<name>_<key>", summed
+# over the entries that ran to their end. `n` is the entries themselves, every
+# other key an integer the site hands _phase(). Only what something reads
+# (PERF.md section 3) is kept: any other keyword of _phase() (`lane`, `program`,
+# `frames`, a prefill program's `rows` and `live`) is span metadata only.
+_PHASE_COUNTS = {
+    "step": ("n",),
+    # positions advanced, of those the program's padded rows x T had room for
+    "prefill_dispatch": ("n", "tokens", "room"),
+    "prefill_sample": ("first",),  # prompts finished: a first output token each
+    "decode_dispatch": ("n", "rows", "live", "ahead"),
+    # reads no earlier sync of the thread had passed, and those of them whose
+    # result had landed when the host came for it
+    "decode_wait": ("fresh", "ready"),
+    "decode_sample": ("n", "tokens"),
+}
+# Phases that keep "<name>_exposed_s", their seconds with the device queue
+# provably empty (CaptionEngine._queue_moved): `step`, elapsed like its seconds,
+# and the dispatch phases (which nest nothing), whose part is how far step's can
+# pass the device's idle time. Closing a dispatch phase hands the device its
+# `program`; closing a wait phase proves every program of the thread up to it done.
+_PHASE_EXPOSED = ("step", "prefill_dispatch", "decode_dispatch")
+_HANDS_OVER = ("prefill_dispatch", "decode_dispatch")
+_PROVES = ("prefill_wait", "decode_wait")
+
+
+class _Phase:
+    """One open phase: what ``CaptionEngine._phase`` returns (its account is
+    described there). A class and no generator: it is entered a dozen times a
+    program, and ``contextlib``'s wrapper cost more than everything kept here."""
+
+    __slots__ = ("engine", "name", "counts", "span", "thread", "t0", "empty0")
+
+    def __init__(self, engine: "CaptionEngine", name: str, counts: dict) -> None:
+        self.engine, self.name, self.counts = engine, name, counts
+
+    def __enter__(self) -> "_Phase":
+        eng = self.engine
+        self.thread = eng._phase_thread()
+        self.thread.stack.append(0.0)  # seconds of the phases nested in this one
+        self.span = jax.profiler.TraceAnnotation("engine." + self.name, **self.counts)
+        self.span.__enter__()
+        if self.name in _PHASE_EXPOSED:
+            with eng._stats_lock:  # the two clocks read at one instant
+                self.t0 = time.monotonic()
+                self.empty0 = eng._empty_clock(self.t0)
+        else:
+            self.t0 = time.monotonic()
+        return self
+
+    def moved(self, handed: int = 0, proven: int = 0) -> None:
+        """Inside the phase: this thread handed the device program ``handed``,
+        or a sync showed its programs up to ``proven`` done."""
+        with self.engine._stats_lock:
+            self.engine._queue_moved(time.monotonic(), self.thread, handed, proven)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        eng, name, stack = self.engine, self.name, self.thread.stack
+        with eng._stats_lock:
+            t1 = time.monotonic()
+            elapsed = t1 - self.t0
+            nested = stack.pop()
+            if stack:
+                stack[-1] += elapsed
+            eng._phase_s[name] += elapsed - nested
+            if name in eng._phase_elapsed_s:
+                eng._phase_elapsed_s[name] += elapsed
+            if name in eng._phase_exposed_s:
+                eng._phase_exposed_s[name] += eng._empty_clock(t1) - self.empty0
+            if exc_type is None:  # entries that ran to their end count, and move the queue
+                counts = self.counts
+                if name in eng._phase_n:
+                    account = eng._phase_n[name]
+                    for key in account:
+                        account[key] += 1 if key == "n" else counts.get(key, 0)
+                if name in _HANDS_OVER:
+                    eng._queue_moved(t1, self.thread, handed=counts.get("program", 0))
+                elif name in _PROVES:
+                    eng._queue_moved(t1, self.thread, proven=counts.get("program", 0))
+        self.span.__exit__(exc_type, exc, tb)
+        return False
 
 
 def _bytes_per_chip(tree) -> int:
@@ -571,16 +654,14 @@ class CaptionEngine:
         self._conv = None
         self._recurrent_bytes_per_chip = 0
         self.completed: list[CaptionResult] = []
-        self._decode_tokens = 0
-        # dead-work accounting: every decode step runs a lane's FULL slot
-        # batch (static shapes); rows without an active slot are wasted.
-        # utilization = tokens produced / rows executed
-        self._decode_rows = 0
-        # per-phase accounting (seconds), all of it through _phase(): self
-        # time per phase name, plus the elapsed time of the two roots (the
-        # stepping thread's `step`, the prep thread's `prep`). Feeds
-        # phase_seconds (stage_timer caption phases, the benchmark's
-        # per-layer metrics).
+        # per-phase accounting, all of it through _phase(): self seconds per
+        # phase name, plus the elapsed time of the two roots (the stepping
+        # thread's `step`, the prep thread's `prep`); the seconds the device
+        # queue was provably empty (_PHASE_EXPOSED); entries and the sites'
+        # counts (_PHASE_COUNTS: decode_tokens, prefill_tokens,
+        # paged_kernel_steps, decode_programs_ahead and the dead-work measure
+        # decode_slot_utilization are read from these). Feeds phase_seconds
+        # (stage_timer caption phases, the benchmark's per-layer metrics).
         # _stats_lock guards every counter '+=': the prep thread (prep /
         # vision / prefix-build counters) and the step thread (prefill /
         # decode counters) would otherwise lose updates racing on the same
@@ -591,10 +672,18 @@ class CaptionEngine:
         # _stats_lock is innermost and leaf-only: never acquire any other
         # engine lock while holding it.
         self._stats_lock = threading.Lock()
-        self._phase_s = dict.fromkeys(_PHASES, 0.0)
-        self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
-        self._phase_open = threading.local()  # .stack: open phases of one thread
-        self._prefill_tokens = 0  # prompt tokens pushed through prefill
+        self._zero_phase_account()
+        self._phase_open = threading.local()  # one thread's open phases and programs: _phase_thread
+        # the device-queue clock (under _stats_lock; state, not statistics:
+        # reset_stats leaves it). The chip runs a thread's programs in the
+        # order it handed them over: each gets the next number, and a sync on
+        # one proves the thread's earlier ones done (_phase_thread). `_queue_busy`
+        # counts the threads with a program handed over and not proven: while
+        # it is 0 the device holds nothing of ours, and `_empty_clock` runs.
+        self._programs = itertools.count(1)
+        self._queue_busy = 0
+        self._empty_since: float | None = time.monotonic()
+        self._empty_total = 0.0
         self._vision_encodes = 0
         self._vision_reuses = 0
         # shared-prefix KV cache: LRU over prefix token tuples. Entries are
@@ -624,12 +713,10 @@ class CaptionEngine:
         self._prefix_block_refs = 0
         self._kv_cow_copies = 0
         self._kv_blocks_used_peak = 0
-        # paged-attention accounting (under _stats_lock): decode steps
-        # served by the paged programs (no gathered working set — the
-        # structural assertion that the per-step copy is gone), bytes of
+        # paged-attention accounting (under _stats_lock): bytes of
         # contiguous KV view the gather programs would have materialized
-        # and scattered back for the same calls
-        self._paged_kernel_steps = 0
+        # and scattered back for the calls the paged programs served
+        # (paged_kernel_steps, the calls themselves, is decode_sample's `n`)
         self._kv_gather_bytes_avoided = 0
         # table entries the decode kernel's loop walks (a row's valid
         # length in pages, summed over the rows of every decode program)
@@ -637,10 +724,9 @@ class CaptionEngine:
         # the share of the table the kernel touches
         self._paged_decode_pages_walked = 0
         self._paged_decode_pages_spanned = 0
-        # look-ahead accounting (under _stats_lock): decode programs
-        # dispatched before the lane's previous one was read, and rows of
-        # such programs thrown away (the row ended in the token before)
-        self._decode_programs_ahead = 0
+        # look-ahead accounting (under _stats_lock): rows of programs
+        # dispatched ahead that were thrown away (the row ended in the token
+        # before); the programs themselves are decode_dispatch's `ahead`
         self._decode_rows_discarded = 0
         # recurrent-store accounting (under _stats_lock): rows held at once,
         # admissions served from a prefix's state snapshot, calls of the
@@ -1232,13 +1318,72 @@ class CaptionEngine:
                     continue
                 self.step()
 
-    @contextlib.contextmanager
-    def _phase(self, name: str):
-        """Time one phase, once, on both clocks: a ``TraceAnnotation``
-        ``engine.<name>`` (inert unless a profiler session runs) and the
-        host's monotonic clock. The counter gets the phase's SELF time —
+    def _zero_phase_account(self) -> None:
+        """Every number _phase() keeps, at zero (construction, reset_stats)."""
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
+        self._phase_exposed_s = dict.fromkeys(_PHASE_EXPOSED, 0.0)
+        self._phase_n = {k: dict.fromkeys(keys, 0) for k, keys in _PHASE_COUNTS.items()}
+
+    def _phase_thread(self) -> threading.local:
+        """The calling thread's side of the account: ``stack``, the seconds
+        nested in each phase it has open; ``handed`` / ``proven``, the highest
+        program it handed the device and the highest a sync of its own has
+        shown done (a thread's programs run in the order it handed them over;
+        what another thread's sync proves of them is not used)."""
+        mine = self._phase_open
+        try:
+            mine.stack
+        except AttributeError:
+            mine.stack, mine.handed, mine.proven = [], 0, 0
+        return mine
+
+    # holds-lock: _stats_lock
+    def _empty_clock(self, now: float) -> float:
+        """Seconds, so far, during which no thread had a program on the device."""
+        since = self._empty_since
+        return self._empty_total + (0.0 if since is None else now - since)
+
+    # holds-lock: _stats_lock
+    def _queue_moved(self, now: float, thread: threading.local, handed: int = 0, proven: int = 0) -> None:
+        """``thread`` handed the device program ``handed``, or a sync of its
+        own showed ``proven`` and its programs before it done: stop the empty
+        clock with the first busy thread, start it when the last one is done."""
+        was = thread.proven < thread.handed
+        thread.handed = max(thread.handed, handed)
+        thread.proven = max(thread.proven, proven)
+        busy = thread.proven < thread.handed
+        if busy and not was:
+            if not self._queue_busy:
+                self._empty_total += now - self._empty_since
+                self._empty_since = None
+            self._queue_busy += 1
+        elif was and not busy:
+            self._queue_busy -= 1
+            if not self._queue_busy:
+                self._empty_since = now
+
+    def _phase(self, name: str, **counts) -> "_Phase":
+        """Time and count one phase, once, on both clocks: a
+        ``TraceAnnotation`` ``engine.<name>`` carrying ``counts`` as its
+        metadata (inert, metadata and all, unless a profiler session runs) and
+        the host's monotonic clock. The account gets the phase's SELF time —
         elapsed less the elapsed of the phases nested in it on this thread —
-        so the phases under a root add up to the root's elapsed time.
+        so the phases under a root add up to the root's elapsed time; and of
+        an entry that ran to its end what ``_PHASE_COUNTS`` names: the entry
+        (``<name>_n``) and integers of ``counts``, which a site may also set
+        on the phase it is in (``with ... as phase: phase.counts[...] = ``).
+
+        ``<name>_exposed_s`` (``_PHASE_EXPOSED``) is the part of the phase
+        during which the device queue was provably empty (``_queue_moved``):
+        every program handed over had been shown done by a host sync of the
+        thread that handed it over, so the chip sat idle while the phase ran.
+        Closing a ``*_dispatch`` phase hands over its ``program``, closing a
+        ``*_wait`` phase proves it. ``step_exposed_s`` is a lower bound of the
+        device's idle time, with one excess: a dispatch phase counts as
+        exposed to its close, though its program started somewhat earlier, so
+        it can pass the truth by at most the two dispatch phases' own exposed
+        seconds. Idle behind a program still unread is not seen.
 
         The decode phases of a lane's step: ``decode_build`` (the next
         program's positions and table on the host), ``decode_dispatch`` (the
@@ -1248,24 +1393,7 @@ class CaptionEngine:
         own work, not the whole of it), ``decode_sample`` (tokens to rows,
         ends, results). ``decode_dispatch`` + ``decode_wait`` is still the
         wall time decode costs a step."""
-        try:
-            stack = self._phase_open.stack
-        except AttributeError:
-            stack = self._phase_open.stack = []
-        stack.append(0.0)  # seconds of the phases nested in this one
-        with jax.profiler.TraceAnnotation("engine." + name):
-            t0 = time.monotonic()
-            try:
-                yield
-            finally:
-                elapsed = time.monotonic() - t0
-                nested = stack.pop()
-                if stack:
-                    stack[-1] += elapsed
-                with self._stats_lock:
-                    self._phase_s[name] += elapsed - nested
-                    if name in self._phase_elapsed_s:
-                        self._phase_elapsed_s[name] += elapsed
+        return _Phase(self, name, counts)
 
     @property
     def _decode_time(self) -> float:
@@ -1281,11 +1409,11 @@ class CaptionEngine:
 
     @property
     def tokens_per_second(self) -> float:
-        return self._decode_tokens / self._decode_time if self._decode_time > 0 else 0.0
+        return self.decode_tokens / self._decode_time if self._decode_time > 0 else 0.0
 
     @property
     def decode_tokens(self) -> int:
-        return self._decode_tokens
+        return self._phase_n["decode_sample"]["tokens"]
 
     @property
     def decode_time_s(self) -> float:
@@ -1297,7 +1425,7 @@ class CaptionEngine:
         and shared-prefix builds; cache-inserted prefix copies are NOT
         prefill). With the shared-prefix cache, n requests sharing a
         Tp-token prefix prefill Tp fewer tokens each after the first."""
-        return self._prefill_tokens
+        return self._phase_n["prefill_dispatch"]["tokens"]
 
     @property
     def prefix_cache_hits(self) -> int:
@@ -1384,8 +1512,8 @@ class CaptionEngine:
         read the pool through the block table; NO contiguous working-set
         copy was built or scattered back. Structurally zero under
         ``paged_attention="gather"``; > 0 is the smoke contract that the
-        kernel path was actually taken."""
-        return self._paged_kernel_steps
+        kernel path was actually taken. One a decode program read."""
+        return self._phase_n["decode_sample"]["n"] if self._use_paged else 0
 
     @property
     def kv_gather_bytes_avoided(self) -> int:
@@ -1432,16 +1560,16 @@ class CaptionEngine:
                 "kv_heads_per_pool_row": self._kv_heads_per_pool_row,
                 "kv_block_size": self.block_size,
                 "kv_block_size_requested": self.block_size_requested,
-                "paged_kernel_steps": self._paged_kernel_steps,
-                "decode_programs_ahead": self._decode_programs_ahead,
+                "paged_kernel_steps": self.paged_kernel_steps,
+                "decode_programs_ahead": self._phase_n["decode_dispatch"]["ahead"],
                 "decode_rows_discarded": self._decode_rows_discarded,
                 "paged_decode_pages_walked": self._paged_decode_pages_walked,
                 "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
                 "kv_gather_bytes_avoided": self._kv_gather_bytes_avoided,
                 "decode_attention_s": self._decode_time,
-                "decode_tokens": self._decode_tokens,
+                "decode_tokens": self.decode_tokens,
                 "decode_s": self._decode_time,
-                "prefill_tokens": self._prefill_tokens,
+                "prefill_tokens": self.prefill_tokens,
                 "prefill_s": self._prefill_time,
                 "kv_blocks_total": self._allocator.capacity,
                 "kv_blocks_used": self._allocator.used_blocks,
@@ -1526,18 +1654,25 @@ class CaptionEngine:
 
     @property
     def phase_seconds(self) -> dict[str, float]:
-        """Cumulative per-phase seconds. ``prep`` (host prep incl. the
-        vision share), ``vision_encode`` (vision-tower subset of prep),
-        ``prefill`` (prefill programs, host sync, first-token sampling) and
-        ``decode`` (decode programs + host sync) keep their meaning and are
-        derived from the phases of ``_phase()``: ``step_s`` is the time
-        inside ``step()``, and ``lock_wait``, ``admit``, ``prefill_*`` and
-        ``decode_*`` (``build``, ``dispatch``, ``wait``, ``sample``) and
-        ``step_other`` partition it (with ``prep_other`` and
-        ``vision_encode`` where prep runs inline; a shared-prefix build on
-        the prep thread adds to ``prefill_*`` from outside ``step()``).
-        Window minus ``step_s`` is the caller's stall; ``*_wait`` is the
-        stepping thread blocked on the device."""
+        """The cumulative account of ``_phase()``, whole. SECONDS, under the
+        names they always had: ``prep`` (host prep incl. the vision share),
+        ``vision_encode`` (vision-tower subset of prep), ``prefill``
+        (prefill programs, host sync, first-token sampling) and ``decode``
+        (decode programs + host sync) keep their meaning and are derived
+        from the phases: ``step_s`` is the time inside ``step()``, and
+        ``lock_wait``, ``admit``, ``prefill_*`` and ``decode_*`` (``build``,
+        ``dispatch``, ``wait``, ``sample``) and ``step_other`` partition it
+        (with ``prep_other`` and ``vision_encode`` where prep runs inline; a
+        shared-prefix build on the prep thread adds to ``prefill_*`` from
+        outside ``step()``). Window minus ``step_s`` is the caller's stall;
+        ``*_wait`` is the stepping thread blocked on the device.
+
+        EXPOSED SECONDS: ``step_exposed_s``, the part of ``step_s`` that ran
+        with the device queue provably empty (see ``_phase``), and the two
+        ``*_dispatch_exposed_s``, the part of it that may not have been idle.
+        COUNTS (integers), ``<phase>_<key>`` for each key of ``_PHASE_COUNTS``:
+        ``step_n`` steps, ``decode_dispatch_n`` + ``prefill_dispatch_n``
+        programs, and what their sites counted of them."""
         with self._stats_lock:
             out = {f"{k}_s": v for k, v in self._phase_s.items() if k not in _PHASE_ROOTS}
             for root in _PHASE_ROOTS:
@@ -1545,6 +1680,9 @@ class CaptionEngine:
                 out[f"{root}_other_s"] = self._phase_s[root]
             out["prefill_s"] = self._prefill_time
             out["decode_s"] = self._decode_time
+            out.update({f"{k}_exposed_s": v for k, v in self._phase_exposed_s.items()})
+            for k, account in self._phase_n.items():
+                out.update({f"{k}_{key}": v for key, v in account.items()})
         return out
 
     def reset_stats(self) -> None:
@@ -1552,11 +1690,7 @@ class CaptionEngine:
         the counter set and its reset stay in one place. Shared-prefix
         cache CONTENTS survive (only the hit/miss counters reset)."""
         with self._stats_lock:
-            self._decode_tokens = 0
-            self._decode_rows = 0
-            self._phase_s = dict.fromkeys(_PHASES, 0.0)
-            self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
-            self._prefill_tokens = 0
+            self._zero_phase_account()
             self._vision_encodes = 0
             self._vision_reuses = 0
             self._prefix_hits = 0
@@ -1569,8 +1703,6 @@ class CaptionEngine:
             self._kv_worstcase_tokens = 0
             self._prefix_block_refs = 0
             self._kv_cow_copies = 0
-            self._paged_kernel_steps = 0
-            self._decode_programs_ahead = 0
             self._decode_rows_discarded = 0
             self._paged_decode_pages_walked = 0
             self._paged_decode_pages_spanned = 0
@@ -1627,9 +1759,11 @@ class CaptionEngine:
     @property
     def decode_slot_utilization(self) -> float:
         """Fraction of executed decode rows that produced a token (the
-        static-batch dead-work measure; lanes raise it by keeping batches
-        near their occupancy)."""
-        return self._decode_tokens / self._decode_rows if self._decode_rows else 0.0
+        static-batch dead-work measure: a decode program runs a lane's FULL
+        slot batch, rows without an active slot are wasted; lanes raise it by
+        keeping batches near their occupancy)."""
+        rows = self._phase_n["decode_dispatch"]["rows"]
+        return self.decode_tokens / rows if rows else 0.0
 
     # -- engine internals ----------------------------------------------
     def step(self) -> None:
@@ -2117,13 +2251,16 @@ class CaptionEngine:
                     self._vision_reuses += 1
             else:
                 frames, eff_fps = self._fit_frames_to_budget(req)
-                with self._phase("vision_encode"):
+                program = next(self._programs)
+                with self._phase("vision_encode", frames=len(frames), program=program) as phase:
                     vis = self._encode_images(self.params, jnp.asarray(frames)[None])
+                    phase.moved(handed=program)  # the tower feeds the same device queue
                     if isinstance(vis, tuple):  # qwen3: (embeds, deepstack levels)
                         vis, ds_levels = vis
                         ds_vis = np.asarray(ds_levels[:, 0], np.float32)  # [L_ds, T_vis, D]
                     vis_embeds = vis[0]
                     jax.block_until_ready(vis_embeds)
+                    phase.moved(proven=program)
                 with self._stats_lock:
                     self._vision_encodes += 1
                 if self.cfg.vision_variant in ("qwen2", "qwen3"):
@@ -2301,7 +2438,8 @@ class CaptionEngine:
             if self.cfg.mrope_section is not None:
                 # text prefix: all three m-rope components equal
                 pos = np.broadcast_to(pos[..., None], (1, sp, 3))
-        with self._phase("prefill_dispatch"):
+        program = next(self._programs)
+        with self._phase("prefill_dispatch", rows=1, live=1, tokens=tp, room=sp, program=program):
             # a hybrid's build also returns its state snapshot (ssm, conv)
             k, v, *state = self._prefix_prefill(
                 self.params,
@@ -2310,10 +2448,8 @@ class CaptionEngine:
                 jnp.asarray(tp, jnp.int32),
             )
             k, v = k[:, :, :tp], v[:, :, :tp]
-        with self._phase("prefill_wait"):
+        with self._phase("prefill_wait", program=program):
             jax.block_until_ready(v)
-        with self._stats_lock:
-            self._prefill_tokens += tp
         bs = self.block_size
         nb = -(-tp // bs)
         with self._lock:
@@ -2622,15 +2758,18 @@ class CaptionEngine:
                 if ds_buf is not None:
                     ds_buf[:, j] = ds_buf[:, 0]
             tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
-        with self._phase("prefill_dispatch"):
+        program = next(self._programs)
+        with self._phase(
+            "prefill_dispatch", rows=n_pad, live=n, tokens=int(sum(it[3] for it in items)),
+            room=n_pad * bucket, lane=lane.length, program=program,
+        ):
             logits = self._run_prefill(
                 lane, slots_arr, tables, embeds, bases, t_valids, rope_buf, ds_buf
             )
-        with self._phase("prefill_wait"):
+        with self._phase("prefill_wait", program=program):
             logits_np = np.asarray(logits)  # one host sync for the whole group
-        with self._phase("prefill_sample"):
+        with self._phase("prefill_sample", first=n):
             with self._stats_lock:
-                self._prefill_tokens += int(sum(it[3] for it in items))
                 if self._use_paged:
                     self._kv_gather_bytes_avoided += self._gather_view_bytes(
                         len(tables), lane.length
@@ -2751,7 +2890,11 @@ class CaptionEngine:
                 if ds_buf is not None:
                     ds_buf[:, j] = ds_buf[:, 0]
             tables = lane.table[slots_arr]  # [n_pad, nbl]; padding rows = row 0
-        with self._phase("prefill_dispatch"):
+        program = next(self._programs)
+        with self._phase(
+            "prefill_dispatch", rows=n_pad, live=n, tokens=new_tokens, room=n_pad * C,
+            lane=lane.length, program=program,
+        ):
             logits = self._run_prefill(
                 lane, slots_arr, tables, embeds, write_idx, chunk_valid, rope_buf, ds_buf
             )
@@ -2761,9 +2904,9 @@ class CaptionEngine:
             if p.progress >= p.t_valid:
                 finished.append((j, slot_idx, p))
         if finished:  # no finished row: nothing to read back, no host sync
-            with self._phase("prefill_wait"):
+            with self._phase("prefill_wait", program=program):
                 logits_np = np.asarray(logits)
-        with self._phase("prefill_sample"):
+        with self._phase("prefill_sample", first=len(finished)):
             for j, slot_idx, p in finished:
                 del lane.pending[slot_idx]
                 self._start_slot(
@@ -2771,7 +2914,6 @@ class CaptionEngine:
                     logits_np[j],
                 )
             with self._stats_lock:
-                self._prefill_tokens += new_tokens
                 if self._use_paged:
                     self._kv_gather_bytes_avoided += self._gather_view_bytes(
                         len(tables), lane.length
@@ -2867,7 +3009,11 @@ class CaptionEngine:
             # a copy: the table's rows change (a release, an admission) while
             # a program that was given them may not have read them yet
             table = lane.table.copy()
-        with self._phase("decode_dispatch"):
+        program = next(self._programs)
+        with self._phase(
+            "decode_dispatch", rows=lane.n_slots, live=len(lane.slots), ahead=ahead,
+            lane=lane.length, program=program,
+        ):
             args = (
                 self.params,
                 self._pool_k,
@@ -2894,11 +3040,9 @@ class CaptionEngine:
                     self._decode(*args, self._ssm, self._conv, jnp.asarray(rows))
                 )
         lane.inflight = _InFlight(
-            greedy=greedy, logits=logits, rows=dict(lane.slots), positions=positions
+            greedy=greedy, logits=logits, rows=dict(lane.slots), positions=positions,
+            program=program,
         )
-        if prev is not None:
-            with self._stats_lock:
-                self._decode_programs_ahead += 1
 
     # holds-lock: _lock
     def _decode_collect(self, lane: _Lane, flight: _InFlight) -> None:
@@ -2910,19 +3054,21 @@ class CaptionEngine:
         program before it reads them. The counters count emitted tokens."""
         if lane.inflight is flight:
             lane.inflight = None
-        with self._phase("decode_wait"):
+        # fresh: no earlier sync of this thread (a finished prefill chunk's) has
+        # passed the program; ready: of those, the host came after the result
+        fresh = flight.program > self._phase_thread().proven
+        ready = fresh and flight.greedy.is_ready()
+        with self._phase("decode_wait", fresh=int(fresh), ready=int(ready), program=flight.program):
             greedy_np = np.asarray(flight.greedy)  # ONE host sync for the whole batch
-        with self._phase("decode_sample"):
+        with self._phase("decode_sample") as phase:
             emitted = flight.emitted(lane)
+            phase.counts["tokens"] = len(emitted)
             with self._stats_lock:
-                self._decode_tokens += len(emitted)
-                self._decode_rows += lane.n_slots
                 self._decode_rows_discarded += len(flight.rows) - len(emitted)
                 self._ssm_decode_calls += len(self.cfg.ssm_layers)
                 if self.cfg.mla is not None:
                     self._mla_decode_calls += len(self.cfg.kv_layers)
                 if self._use_paged:
-                    self._paged_kernel_steps += 1
                     # a row's kv_len is positions + 1 (decode_step_paged); a
                     # discarded row counts as the idle row it should have been
                     positions = flight.positions

@@ -651,6 +651,14 @@ def render_report(report: dict) -> str:
                 f"idle_frac {agg.get('idle_frac', 0.0):.3f}  "
                 f"prefix_hits {agg.get('prefix_cache_hits', 0)}"
             )
+            if agg.get("step_n"):
+                lines.append(
+                    f"  {'':<40} programs/step {agg.get('programs_per_step', 0.0):.2f} "
+                    f"({agg.get('decode_dispatch_n', 0)} decode + "
+                    f"{agg.get('prefill_dispatch_n', 0)} prefill over {agg['step_n']} steps)  "
+                    f"exposed {agg.get('step_exposed_s', 0.0):.2f}s (dispatch's own "
+                    f"{agg.get('decode_dispatch_exposed_s', 0.0) + agg.get('prefill_dispatch_exposed_s', 0.0):.2f}s)"
+                )
             if agg.get("kv_blocks_total"):
                 lines.append(
                     f"  {'':<40} kv_blocks {agg.get('kv_blocks_peak', 0)}/"

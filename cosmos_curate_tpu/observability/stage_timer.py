@@ -363,6 +363,10 @@ _CAPTION: dict[str, dict] = {}
 
 _CAPTION_PHASE_KEYS = (
     "prep_s", "vision_encode_s", "prefill_s", "decode_s", "idle_s", "wall_s",
+    # seconds inside step() with the engine's device queue provably empty
+    # (CaptionEngine._phase: host work the chip sat idle for), and the part of
+    # them inside the dispatch phases, which is how far they can overstate
+    "step_exposed_s", "decode_dispatch_exposed_s", "prefill_dispatch_exposed_s",
 )
 _CAPTION_COUNT_KEYS = (
     "requests", "prefill_tokens", "prefix_cache_hits", "prefix_cache_misses",
@@ -375,6 +379,8 @@ _CAPTION_COUNT_KEYS = (
     # without a gathered working set + the view bytes never materialized
     "paged_kernel_steps", "kv_gather_bytes_avoided",
     "decode_tokens",
+    # the engine's phase account: steps, and the programs it handed the device
+    "step_n", "decode_dispatch_n", "prefill_dispatch_n",
 )
 # absolute occupancy gauges riding each drive record: totals overwrite,
 # peaks take the max across drives
@@ -432,7 +438,9 @@ def caption_phase_summaries() -> dict[str, dict]:
     wall for the stage's drives: ≈0 means the engine was prefilling or
     decoding for the whole window (prep fully hidden); large values mean
     the stage starved the engine between batches. ``owners`` carries the
-    per-owner sub-aggregates (cross-job accounting)."""
+    per-owner sub-aggregates (cross-job accounting). ``programs_per_step``
+    is the programs the engine handed the device (decode + prefill) over
+    its steps: each reads every parameter, whatever rows it carries."""
     out: dict[str, dict] = {}
     with _CAPTION_LOCK:
         items = {
@@ -448,6 +456,11 @@ def caption_phase_summaries() -> dict[str, dict]:
             "drives": agg["drives"],
             "owners": agg["owners"],
             "idle_frac": round(agg["idle_s"] / wall, 4) if wall > 0 else 0.0,
+            "programs_per_step": (
+                round((agg["decode_dispatch_n"] + agg["prefill_dispatch_n"]) / agg["step_n"], 3)
+                if agg["step_n"]
+                else 0.0
+            ),
         }
     return out
 

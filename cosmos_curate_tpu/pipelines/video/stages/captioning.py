@@ -448,9 +448,8 @@ class CaptionStage(Stage[SplitPipeTask, SplitPipeTask]):
         so treat per-stage attribution as approximate there. ``idle_s`` is
         wall minus device phases: the engine-stall time the overlap rework
         exists to shrink."""
-        phases = {
-            k: engine.phase_seconds[k] - phases0[k] for k in engine.phase_seconds
-        }
+        phases1 = engine.phase_seconds  # seconds, exposed seconds and counts: the whole account
+        phases = {k: v - phases0[k] for k, v in phases1.items()}
         now = self._engine_counts(engine)
         counts = {k: now[k] - stats0[k] for k in now}
         busy = phases["prefill_s"] + phases["decode_s"]

@@ -1,9 +1,12 @@
 """The caption engine's step phases: one helper (`CaptionEngine._phase`) gives
 every phase of `step()` a counter on the host's clock and a span on the
-profiler's. No profiler trace is started in this process (PERF.md §6: a later
+profiler's, counts what its site hands it, and reads the device-queue clock
+(`step_exposed_s`: seconds with nothing handed over and not shown done).
+No profiler trace is started in this process (PERF.md §6: a later
 pyarrow thread dies with SIGSEGV); the spans are read off a recorder put in
 `jax.profiler.TraceAnnotation`'s place."""
 
+import sys
 import threading
 
 import numpy as np
@@ -33,9 +36,20 @@ STEP_LEAVES = (
     "decode_wait_s",
     "decode_sample_s",
 )
-ALL_KEYS = {
+SECONDS = {
     *ROOTS, *DERIVED, *STEP_LEAVES, "step_other_s", "prep_other_s", "vision_encode_s",
 }
+# seconds the device queue was provably empty: the step, and the dispatch phases' part
+EXPOSED = {"step_exposed_s", "prefill_dispatch_exposed_s", "decode_dispatch_exposed_s"}
+# what the sites count, each for a reader: entries, and integers handed to `_phase`
+COUNTS = {
+    "step_n", "prefill_dispatch_n", "decode_dispatch_n", "decode_sample_n",
+    "prefill_dispatch_tokens", "prefill_dispatch_room", "prefill_sample_first",
+    "decode_dispatch_rows", "decode_dispatch_live", "decode_dispatch_ahead",
+    "decode_wait_fresh", "decode_wait_ready", "decode_sample_tokens",
+}
+ALL_KEYS = SECONDS | EXPOSED | COUNTS
+KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]
 
 
 def _req(rid, text="describe", frames=False, max_new=8, prefix=""):
@@ -62,7 +76,7 @@ def engine():
 
 
 def _self_time_sum(phases: dict) -> float:
-    return sum(v for k, v in phases.items() if k not in ROOTS + DERIVED)
+    return sum(v for k, v in phases.items() if k in SECONDS and k not in ROOTS + DERIVED)
 
 
 def _drive(eng, kind: str) -> int:
@@ -89,7 +103,7 @@ class TestCounters:
     def test_keys(self, engine):
         assert set(engine.phase_seconds) == ALL_KEYS
 
-    @pytest.mark.parametrize("kind", ["whole_prompt", "chunked", "vision", "shared_prefix"])
+    @pytest.mark.parametrize("kind", KINDS)
     def test_leaves_partition_the_step(self, engine, kind):
         engine.reset_stats()
         steps = _drive(engine, kind) + (kind == "chunked")
@@ -147,6 +161,7 @@ class TestCounters:
         assert engine.phase_seconds["step_s"] > 0
         engine.reset_stats()
         assert engine.phase_seconds == dict.fromkeys(ALL_KEYS, 0.0)
+        assert all(isinstance(engine.phase_seconds[k], int) for k in COUNTS)
         assert engine.stats()["decode_s"] == 0 and engine.stats()["prefill_s"] == 0
 
     def test_phase_outside_a_root_and_after_an_error(self, engine):
@@ -245,13 +260,374 @@ class TestProgramInFlight:
         assert abs(_self_time_sum(ph) - ph["step_s"]) <= 1e-6 * len([n for n in names if n == "engine.step"])
 
 
+class _OldLines:
+    """The counters as the engine kept them before `_phase` took counts: one
+    hand-placed `+=` each, at the site it had, replayed by spies around the
+    same calls."""
+
+    def __init__(self, eng, monkeypatch):
+        self.eng = eng
+        self.decode_tokens = self.decode_rows = self.paged_kernel_steps = 0
+        self.decode_programs_ahead = self.prefill_tokens = 0
+        self.decode_calls = self.prefill_calls = 0
+        collect, dispatch = eng._decode_collect, eng._decode_dispatch
+        group, chunk = eng._prefill_group, eng._prefill_chunk_step
+        decode, run_prefill, prefix = eng._decode, eng._run_prefill, eng._prefix_prefill
+
+        def spy_collect(lane, flight):
+            self.decode_tokens += len(flight.emitted(lane))
+            self.decode_rows += lane.n_slots
+            self.paged_kernel_steps += 1
+            return collect(lane, flight)
+
+        def spy_dispatch(lane, prev, tokens):
+            self.decode_programs_ahead += prev is not None
+            return dispatch(lane, prev, tokens)
+
+        def spy_group(lane, bucket, items):
+            out = group(lane, bucket, items)
+            self.prefill_tokens += int(sum(it[3] for it in items))
+            return out
+
+        def spy_chunk(lane):
+            items = list(lane.pending.values())[: eng.max_prefill_rows]
+            new = sum(min(eng.prefill_chunk, p.t_valid - p.progress) for p in items)
+            out = chunk(lane)
+            self.prefill_tokens += new
+            return out
+
+        def spy_decode(*a):
+            self.decode_calls += 1
+            return decode(*a)
+
+        def spy_run_prefill(*a):
+            self.prefill_calls += 1
+            return run_prefill(*a)
+
+        def spy_prefix(params, emb, pos, tp):
+            self.prefill_calls += 1
+            self.prefill_tokens += int(tp)
+            return prefix(params, emb, pos, tp)
+
+        for name, spy in (
+            ("_decode_collect", spy_collect), ("_decode_dispatch", spy_dispatch),
+            ("_prefill_group", spy_group), ("_prefill_chunk_step", spy_chunk),
+            ("_decode", spy_decode), ("_run_prefill", spy_run_prefill), ("_prefix_prefill", spy_prefix),
+        ):
+            monkeypatch.setattr(eng, name, spy)
+
+
+class TestCounts:
+    """`_phase` books what its site hands it, an entry at a time: the counts of
+    a drive are the programs that were called, and the counters the engine
+    always reported are read from them unchanged."""
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_entries_are_the_steps_and_the_programs(self, engine, monkeypatch, kind):
+        engine.clear_prefix_cache()
+        engine.reset_stats()
+        old = _OldLines(engine, monkeypatch)
+        steps = _drive(engine, kind) + (kind == "chunked")
+        ph, stats = engine.phase_seconds, engine.stats()
+        assert ph["step_n"] == steps
+        assert ph["decode_dispatch_n"] == old.decode_calls > 0
+        assert ph["prefill_dispatch_n"] == old.prefill_calls > 0
+        assert ph["decode_sample_n"] == old.decode_calls  # every program dispatched was read, once
+        assert 0 < ph["decode_dispatch_live"] <= ph["decode_dispatch_rows"]
+        assert ph["decode_dispatch_rows"] == 4 * old.decode_calls  # one lane of max_batch=4
+        assert 0 < ph["prefill_dispatch_tokens"] <= ph["prefill_dispatch_room"]
+        assert ph["decode_sample_tokens"] == stats["decode_tokens"] > 0
+        assert ph["prefill_dispatch_tokens"] == stats["prefill_tokens"]
+        assert ph["prefill_sample_first"] == (2 if kind == "chunked" else 1)  # a first token a request
+        assert 0 <= ph["decode_wait_ready"] <= ph["decode_wait_fresh"] <= ph["decode_sample_n"]
+        mine = engine._phase_thread()
+        assert not engine.has_work() and mine.handed == mine.proven > 0 and engine._queue_busy == 0
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_the_old_counters_read_what_their_own_lines_gave(self, engine, monkeypatch, kind):
+        engine.clear_prefix_cache()
+        engine.reset_stats()
+        old = _OldLines(engine, monkeypatch)
+        _drive(engine, kind)
+        stats = engine.stats()
+        assert stats["decode_tokens"] == old.decode_tokens == engine.decode_tokens
+        assert stats["prefill_tokens"] == old.prefill_tokens == engine.prefill_tokens
+        assert stats["paged_kernel_steps"] == old.paged_kernel_steps == engine.paged_kernel_steps
+        assert stats["decode_programs_ahead"] == old.decode_programs_ahead
+        assert engine.decode_slot_utilization == old.decode_tokens / old.decode_rows
+
+    def test_an_entry_that_raised_counts_seconds_and_nothing_else(self, engine):
+        engine.reset_stats()
+        handed = engine._phase_thread().handed
+        with pytest.raises(ValueError):
+            with engine._phase("decode_dispatch", rows=4, live=1, ahead=1, program=handed + 50):
+                raise ValueError("boom")
+        ph = engine.phase_seconds
+        assert ph["decode_dispatch_s"] > 0
+        assert ph["decode_dispatch_n"] == ph["decode_dispatch_rows"] == ph["decode_dispatch_ahead"] == 0
+        assert engine._phase_thread().handed == handed and engine._queue_busy == 0  # nothing was handed over
+
+    def test_a_keyword_no_count_names_is_metadata_only(self, engine, spans):
+        engine.reset_stats()
+        with engine._phase("decode_sample", tokens=3, lane=64):
+            pass
+        with engine._phase("vision_encode", frames=2, program=7):
+            pass
+        with engine._phase("prefill_dispatch", rows=2, live=1, tokens=5, room=8):
+            pass
+        ph = engine.phase_seconds
+        assert (ph["decode_sample_n"], ph["decode_sample_tokens"]) == (1, 3)
+        assert (ph["prefill_dispatch_n"], ph["prefill_dispatch_tokens"], ph["prefill_dispatch_room"]) == (1, 5, 8)
+        assert set(ph) == ALL_KEYS  # no `lane`, `frames`, `rows` or `live` of a prefill: a reader each, or none kept
+        assert _Recorder.meta[:2] == [
+            ("engine.decode_sample", {"tokens": 3, "lane": 64}),
+            ("engine.vision_encode", {"frames": 2, "program": 7}),
+        ]
+
+    def test_a_site_may_count_inside_its_phase(self, engine):
+        """`decode_sample` learns its tokens inside the phase: `_phase` hands
+        the open phase back, and what the site sets on it is booked at exit."""
+        engine.reset_stats()
+        with engine._phase("decode_sample") as phase:
+            phase.counts["tokens"] = 4
+        ph = engine.phase_seconds
+        assert (ph["decode_sample_n"], ph["decode_sample_tokens"]) == (1, 4)
+
+    def test_the_spans_of_one_program_share_its_number(self, engine, spans):
+        engine.add_request(_req("m0", text="hi", max_new=6))
+        while engine.has_work():
+            engine.step()
+        engine.completed.clear()
+        sent = [kw for n, kw in _Recorder.meta if n == "engine.decode_dispatch"]
+        read = [kw["program"] for n, kw in _Recorder.meta if n == "engine.decode_wait"]
+        assert [kw["program"] for kw in sent] == read == sorted(read)
+        assert all(kw["lane"] == engine.lanes[0].length and kw["rows"] == 4 for kw in sent)
+        (prefill,) = [kw for n, kw in _Recorder.meta if n == "engine.prefill_dispatch"]
+        (wait,) = [kw for n, kw in _Recorder.meta if n == "engine.prefill_wait"]
+        assert prefill["program"] == wait["program"] == sent[0]["program"] - 1
+        assert prefill["room"] % prefill["rows"] == 0 and prefill["room"] >= prefill["tokens"] > 0
+        assert prefill["rows"] >= prefill["live"] == 1  # a prefill's rows and prompts: on the span alone
+
+    def test_ready_counts_the_fresh_reads_that_found_the_result_there(self, engine, monkeypatch):
+        collect = engine._decode_collect
+
+        def late(lane, flight):  # the host arrives after the program is done
+            jax.block_until_ready(flight.greedy)
+            return collect(lane, flight)
+
+        monkeypatch.setattr(engine, "_decode_collect", late)
+        engine.reset_stats()
+        _drive(engine, "whole_prompt")
+        ph = engine.phase_seconds
+        assert ph["decode_wait_ready"] == ph["decode_wait_fresh"] == ph["decode_sample_n"] > 0
+        # a program that an earlier sync has passed already is neither fresh nor a late
+        # arrival: the chunked drive reads a finished chunk, then the decode program before it
+        engine.reset_stats()
+        _drive(engine, "chunked")
+        ph = engine.phase_seconds
+        assert ph["decode_wait_ready"] == ph["decode_wait_fresh"] == ph["decode_sample_n"] - 1 > 0
+
+    def test_a_read_that_waits_is_fresh_and_not_ready(self, engine, monkeypatch):
+        collect = engine._decode_collect
+
+        class NotReady:  # the token vector of a program the device still runs
+            def __init__(self, array):
+                self.array = array
+
+            def is_ready(self):
+                return False
+
+            def __array__(self, *a, **kw):
+                return np.asarray(self.array)
+
+        def early(lane, flight):
+            flight.greedy = NotReady(flight.greedy)
+            return collect(lane, flight)
+
+        monkeypatch.setattr(engine, "_decode_collect", early)
+        engine.reset_stats()
+        _drive(engine, "whole_prompt")
+        ph = engine.phase_seconds
+        assert ph["decode_wait_ready"] == 0 and ph["decode_wait_fresh"] == ph["decode_sample_n"] > 0
+
+
+class TestDeviceQueueClock:
+    """No thread with a program handed over and not shown done means the device
+    holds nothing of this engine: the seconds `step` ran that way are its
+    `step_exposed_s`, the dispatch phases' part of them their own."""
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_exposed_seconds_are_part_of_the_phase(self, engine, kind):
+        engine.reset_stats()
+        _drive(engine, kind)
+        ph = engine.phase_seconds
+        for key in EXPOSED:
+            assert 0.0 <= ph[key] <= ph[key.replace("_exposed_s", "_s")], key
+        # the dispatch phases' exposed seconds lie inside the step's, and a wait never does:
+        # its program is in the queue until the wait ends
+        own = ph["prefill_dispatch_exposed_s"] + ph["decode_dispatch_exposed_s"]
+        assert own <= ph["step_exposed_s"] <= ph["step_s"] - ph["prefill_wait_s"] + 1e-9
+        assert own > 0  # the queue was empty when the drive's first program was dispatched
+
+    def test_a_lane_that_reads_first_exposes_its_build_and_dispatch(self, engine):
+        engine.add_request(
+            CaptionRequest(
+                request_id="t0", prompt_ids=ByteTokenizer().encode("hi"),
+                sampling=SamplingConfig(max_new_tokens=8, temperature=0.7, seed=1),
+            )
+        )
+        while not engine.slots:
+            engine.step()
+        engine.reset_stats()
+        for _ in range(4):
+            engine.step()
+        ph = engine.phase_seconds
+        assert ph["decode_dispatch_ahead"] == 0 and ph["decode_dispatch_n"] == 4
+        assert ph["decode_dispatch_exposed_s"] == ph["decode_dispatch_s"] > 0
+        # from a read to the close of the next dispatch nothing is queued; between that
+        # and the next step's read the device holds the program
+        host = ph["decode_sample_s"] + ph["decode_build_s"] + ph["decode_dispatch_s"]
+        assert host <= ph["step_exposed_s"] + 1e-9
+        assert ph["step_exposed_s"] <= ph["step_s"] - ph["decode_wait_s"] - ph["lock_wait_s"] - ph["admit_s"] + 1e-9
+        while engine.has_work():
+            engine.step()
+        engine.completed.clear()
+
+    def test_a_lane_with_a_program_ahead_exposes_nothing(self, engine):
+        engine.add_request(_req("g0", text="hi", max_new=16))
+        while not engine.slots:
+            engine.step()
+        engine.reset_stats()
+        for _ in range(5):
+            engine.step()
+        ph = engine.phase_seconds
+        assert ph["decode_dispatch_ahead"] == ph["decode_dispatch_n"] == 5
+        assert all(ph[k] == 0.0 for k in EXPOSED), ph
+        while engine.has_work():
+            engine.step()
+        engine.completed.clear()
+        assert engine._queue_busy == 0
+
+    def test_reset_stats_leaves_the_queue_as_it_is(self, engine):
+        engine.add_request(_req("q0", text="hi", max_new=8))
+        while not engine.slots:
+            engine.step()
+        mine = engine._phase_thread()
+        handed, proven = mine.handed, mine.proven
+        assert handed > proven and engine._queue_busy == 1  # the decode program in flight
+        engine.reset_stats()
+        assert (mine.handed, mine.proven, engine._queue_busy) == (handed, proven, 1)
+        assert engine._empty_since is None
+        while engine.has_work():
+            engine.step()
+        engine.completed.clear()
+        assert mine.handed == mine.proven > handed
+        assert engine._queue_busy == 0 and engine._empty_since is not None
+
+    def test_a_sync_proves_every_program_before_it(self, engine):
+        """A prefill chunk nobody reads is proven by the next read of anything
+        the thread handed over after it."""
+        engine.add_request(_req("s0", text="hi", max_new=12))
+        engine.step()
+        engine.add_request(_req("c0", text="b " * 20, max_new=3))
+        engine.step()
+        engine.reset_stats()
+        engine.step()  # a chunk, unread, then a decode program; the decode before them is read
+        ph, mine = engine.phase_seconds, engine._phase_thread()
+        assert ph["prefill_dispatch_n"] == 1 and ph["prefill_sample_first"] == 0
+        chunk = mine.proven + 1
+        assert mine.handed == chunk + 1 == engine.lanes[0].inflight.program
+        engine.step()  # reads that decode program: the chunk before it is done too
+        assert mine.proven > chunk
+        while engine.has_work():
+            engine.step()
+        engine.completed.clear()
+        assert engine._queue_busy == 0
+
+    def test_the_prep_thread_feeds_the_same_queue(self):
+        eng = CaptionEngine(VLM_TINY_TEST, max_batch=2, async_prep=True)
+        eng.setup()
+        try:
+            eng.add_request(_req("v0", frames=True, max_new=3))
+            assert [r.request_id for r in eng.run_until_complete()] == ["v0"]
+            ph, mine = eng.phase_seconds, eng._phase_thread()
+            assert ph["vision_encode_s"] > 0 and eng._queue_busy == 0
+            # the tower took a number on its own thread: this thread's are the rest
+            assert next(eng._programs) - 1 == ph["decode_dispatch_n"] + ph["prefill_dispatch_n"] + 1
+            assert mine.handed == mine.proven > 0
+            assert 0 <= ph["step_exposed_s"] <= ph["step_s"]
+        finally:
+            eng.shutdown()
+
+    def test_a_thread_that_takes_its_number_early_still_holds_the_queue(self, engine):
+        """The tower's number is drawn before its frames are put: meanwhile the
+        step thread hands over and proves higher numbers. The queue is a pair a
+        thread, so the tower's program still stops the empty clock."""
+        engine.reset_stats()
+        tower = next(engine._programs)
+        later = next(engine._programs)
+        with engine._phase("decode_dispatch", rows=4, live=1, ahead=0, program=later):
+            pass
+        with engine._phase("decode_wait", fresh=1, ready=0, program=later):
+            pass
+        assert engine._queue_busy == 0 and engine._empty_since is not None
+        done = threading.Event()
+
+        def prep():
+            with engine._phase("vision_encode", frames=2, program=tower) as phase:
+                phase.moved(handed=tower)
+                assert engine._queue_busy == 1 and engine._empty_since is None
+                with engine._phase("step"):  # anything that runs meanwhile is covered
+                    pass
+                phase.moved(proven=tower)
+            done.set()
+
+        t = threading.Thread(target=prep)
+        t.start()
+        t.join(timeout=30)
+        assert done.is_set() and engine._queue_busy == 0 and engine._empty_since is not None
+        assert engine.phase_seconds["step_exposed_s"] == 0.0
+
+    def test_two_threads_lose_no_entry_and_leave_the_queue_empty(self, engine):
+        engine.reset_stats()
+        rounds, workers = 300, 12
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            def work():
+                for _ in range(rounds):
+                    program = next(engine._programs)
+                    with engine._phase("prefill_dispatch", rows=2, live=1, tokens=3, room=8, program=program):
+                        pass
+                    with engine._phase("prefill_wait", program=program):
+                        pass
+
+            threads = [threading.Thread(target=work) for _ in range(workers)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        ph = engine.phase_seconds
+        total = rounds * workers
+        assert ph["prefill_dispatch_n"] == total
+        assert ph["prefill_dispatch_tokens"] == 3 * total and ph["prefill_dispatch_room"] == 8 * total
+        assert engine._queue_busy == 0 and engine._empty_since is not None
+        assert 0.0 <= ph["prefill_dispatch_exposed_s"] <= ph["prefill_dispatch_s"]
+
+
 class _Recorder:
     """Stands in for jax.profiler.TraceAnnotation: (event, name, thread)."""
 
     log: list = []
 
-    def __init__(self, name, **_kw):
+    meta: list = []  # (name, the keywords it was given)
+
+    def __init__(self, name, **kw):
         self.name = name
+        _Recorder.meta.append((name, kw))
 
     def __enter__(self):
         _Recorder.log.append(("open", self.name, threading.current_thread().name))
@@ -264,7 +640,7 @@ class _Recorder:
 
 @pytest.fixture()
 def spans(monkeypatch):
-    _Recorder.log = []
+    _Recorder.log, _Recorder.meta = [], []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
     return _Recorder.log
 

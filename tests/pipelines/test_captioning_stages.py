@@ -84,6 +84,27 @@ def test_phase_breakdown_recorded(captioned_output):
     assert agg["prefix_cache_hits"] >= 1
 
 
+def test_the_phase_account_reaches_the_run_report(captioned_output):
+    """The engine's counts and its device-queue clock ride the same deltas:
+    steps, the programs handed to the device and the seconds inside step()
+    with the queue provably empty, in the aggregate and on the report's lines."""
+    from cosmos_curate_tpu.observability.flight_recorder import render_report
+    from cosmos_curate_tpu.observability.stage_timer import caption_phase_summaries
+
+    agg = caption_phase_summaries()["CaptionStage"]
+    assert agg["step_n"] > 0 and agg["decode_dispatch_n"] > 0 and agg["prefill_dispatch_n"] > 0
+    assert agg["decode_dispatch_n"] == agg["paged_kernel_steps"]  # a drained drive read every program
+    assert agg["programs_per_step"] == round(
+        (agg["decode_dispatch_n"] + agg["prefill_dispatch_n"]) / agg["step_n"], 3
+    )
+    # the dispatch phases' own part (the prep thread's prefix build books its own outside step())
+    own = agg["decode_dispatch_exposed_s"] + agg["prefill_dispatch_exposed_s"]
+    assert own > 0 and 0 < agg["step_exposed_s"] <= agg["wall_s"]
+    text = render_report({"caption_phases": {"CaptionStage": agg}})
+    assert f"programs/step {agg['programs_per_step']:.2f}" in text
+    assert f"exposed {agg['step_exposed_s']:.2f}s (dispatch's own {own:.2f}s)" in text
+
+
 def test_prompt_encoded_once_across_windows(monkeypatch):
     """Satellite: _make_request must not re-tokenize the identical prompt
     per window — the encode runs once per stage, then requests copy the
