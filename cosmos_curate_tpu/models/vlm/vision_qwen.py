@@ -12,8 +12,10 @@ TPU-first differences from the HF implementation (behavior-preserving):
 - **Static shapes.** HF flattens all images of a request into one ragged
   sequence partitioned by ``cu_seqlens``; here a batch is a dense
   ``[B, S, patch_dim]`` array with one static ``(t, h, w)`` grid per
-  compiled program (the caption engine buckets by shape anyway), so
-  attention is one big batched MXU matmul instead of per-image splits.
+  compiled program (the caption engine buckets by shape anyway), and the
+  ``cu_seqlens`` segments (temporal slices, Qwen2.5-VL's windows) are
+  static runs of that grid: attention is one batched matmul over
+  ``[B, segments, L]``, never over the whole ``S x S``.
 - **Patchify as a matmul.** The Conv3d with kernel == stride over
   pre-extracted patches is exactly a dense layer on the flattened patch
   vector — one ``[B*S, patch_dim] @ [patch_dim, embed]`` MXU call.
@@ -247,8 +249,8 @@ def window_partition(cfg: QwenVisionConfig, grid: tuple[int, int, int]):
     (spatial_merge_size² consecutive tokens) are regrouped into
     window-major order; returns (token_perm [S], window segment id per
     permuted token [S]) — static arrays the jitted program closes over.
-    Frame (t) boundaries are preserved by the permutation, so the per-frame
-    full-attention mask formula is unchanged.
+    Frame (t) boundaries are preserved by the permutation, so the
+    full-attention blocks' segments (one per temporal slice) are unchanged.
     """
     t, h, w = grid
     msz = cfg.spatial_merge_size
@@ -286,6 +288,42 @@ class _VisionRMSNorm(nn.Module):
         return (scale * normed).astype(x.dtype)
 
 
+def _segment_attention(q, k, v, seg_lens: np.ndarray):
+    """Softmax attention inside each of the consecutive token runs that
+    ``seg_lens`` (static) measures out: q, k, v ``[B, S, H, Dh]`` ->
+    ``[B, S, H * Dh]``. Float32 logits and softmax, probabilities in v's
+    type, as HF computes a ``cu_seqlens`` segment.
+
+    Runs of one length are a reshape to ``[B, n_seg, L, H, Dh]``. Runs of
+    several lengths (windows the grid's edge cuts) are gathered into
+    ``n_seg`` rows of the longest, the slots past a run's end masked as
+    keys and dropped as queries.
+    """
+    b, _, h, dh = q.shape
+    n_seg, longest = len(seg_lens), int(seg_lens.max())
+    live = np.arange(longest)[None, :] < seg_lens[:, None]  # [n_seg, L]
+    ragged = not live.all()
+    if ragged:
+        # a slot past its run's end reads the run's last token: any token
+        # would do, its logits are masked and its output row is dropped
+        starts = np.cumsum(seg_lens) - seg_lens
+        offsets = np.minimum(np.arange(longest), seg_lens[:, None] - 1)
+        slots = (starts[:, None] + offsets).reshape(-1)
+        q, k, v = q[:, slots], k[:, slots], v[:, slots]
+    q, k, v = (a.reshape(b, n_seg, longest, h, dh) for a in (q, k, v))
+    logits = jnp.einsum(
+        "bnqhd,bnkhd->bnhqk", q.astype(jnp.float32) * dh**-0.5, k.astype(jnp.float32)
+    )
+    if ragged:
+        logits = jnp.where(live[None, :, None, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    attn = jnp.einsum("bnhqk,bnkhd->bnqhd", probs.astype(v.dtype), v)
+    attn = attn.reshape(b, n_seg * longest, h * dh)
+    if ragged:
+        attn = attn[:, np.flatnonzero(live)]
+    return attn
+
+
 class QwenVisionBlock(nn.Module):
     cfg: QwenVisionConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -297,11 +335,13 @@ class QwenVisionBlock(nn.Module):
         return nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name=name)
 
     @nn.compact
-    def __call__(self, x, cos, sin, block_mask):
-        """x: [B, S, E]; cos/sin: [S, head_dim] rope tables; block_mask:
-        [S, S] bool — HF splits attention at cu_seqlens boundaries (per
-        temporal frame, or per window for qwen2_5's windowed blocks), which
-        for our static grid is a block-diagonal mask."""
+    def __call__(self, x, cos, sin, seg_lens):
+        """x: [B, S, E]; cos/sin: [S, head_dim] rope tables; seg_lens:
+        static int array, the lengths of the consecutive runs of tokens
+        that attend to one another. HF splits attention at cu_seqlens
+        boundaries (per temporal slice, or per window for qwen2_5's
+        windowed blocks); for our static grid those are these runs, and
+        ``np.cumsum(seg_lens)`` is HF's ``cu_seqlens``."""
         cfg = self.cfg
         b, s, _ = x.shape
         h, dh = cfg.num_heads, cfg.head_dim
@@ -319,13 +359,8 @@ class QwenVisionBlock(nn.Module):
         q = (qf * cos_ + _rotate_half(qf) * sin_).astype(self.dtype)
         k = (kf * cos_ + _rotate_half(kf) * sin_).astype(self.dtype)
 
-        logits = jnp.einsum(
-            "bqhd,bkhd->bhqk", q.astype(jnp.float32) * dh**-0.5, k.astype(jnp.float32)
-        )
-        logits = jnp.where(block_mask[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(self.dtype), v)
-        attn = attn.reshape(b, s, h * dh)
+        with jax.named_scope("vision.attention"):
+            attn = _segment_attention(q, k, v, seg_lens)
         x = x + proj(cfg.embed_dim, "in", name="proj")(attn)
 
         y = self._norm("ln2")(x)
@@ -344,7 +379,12 @@ class QwenVisionBlock(nn.Module):
 
 
 class QwenVisionTower(nn.Module):
-    """[B, S, patch_dim] pixel patches -> [B, S/merge², hidden_size]."""
+    """[B, S, patch_dim] pixel patches -> [B, S/merge², hidden_size].
+
+    Every block is handed the segments it attends inside, as static run
+    lengths read off the grid (temporal slices; for qwen2_5's windowed
+    blocks ``window_partition``'s windows): HF's ``cu_seqlens`` semantics,
+    with no ``[S, S]`` mask anywhere in the program."""
 
     cfg: QwenVisionConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -376,10 +416,9 @@ class QwenVisionTower(nn.Module):
             pos = jnp.tile(interp @ table, (grid[0], 1))  # temporal repeat
             x = (x.astype(jnp.float32) + pos).astype(self.dtype)
         angles = rotary_tables(cfg, grid)
-        # per-frame full attention (HF cu_seqlens semantics)
-        frame = np.arange(s) // (grid[1] * grid[2])
-        full_mask = jnp.asarray(frame[:, None] == frame[None, :])
-        windowed_mask = None
+        # HF cu_seqlens semantics: full attention inside a temporal slice
+        full_lens = np.full(grid[0], grid[1] * grid[2])
+        window_lens = None
         inverse_unit_perm = None
         if cfg.variant == "qwen2_5":
             # static window permutation: tokens regroup window-major; all
@@ -387,19 +426,16 @@ class QwenVisionTower(nn.Module):
             token_perm, seg, unit_perm = window_partition(cfg, grid)
             x = x[:, token_perm]
             angles = angles[token_perm]
-            windowed_mask = jnp.asarray(seg[:, None] == seg[None, :])
+            window_lens = np.unique(seg, return_counts=True)[1]  # seg never decreases
             inverse_unit_perm = np.argsort(unit_perm)
         cos, sin = jnp.cos(jnp.asarray(angles)), jnp.sin(jnp.asarray(angles))
         msz2 = cfg.spatial_merge_size**2
         deepstack = []
         for i in range(cfg.depth):
-            if cfg.variant == "qwen2_5" and i not in cfg.fullatt_block_indexes:
-                mask = windowed_mask
-            else:
-                mask = full_mask
+            windowed = cfg.variant == "qwen2_5" and i not in cfg.fullatt_block_indexes
             x = QwenVisionBlock(
                 cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"block_{i}"
-            )(x, cos, sin, mask)
+            )(x, cos, sin, window_lens if windowed else full_lens)
             if cfg.variant == "qwen3" and i in cfg.deepstack_indexes:
                 # deepstack merger (postshuffle norm): merge-window group
                 # FIRST, LayerNorm over the grouped features, then the MLP

@@ -7,6 +7,8 @@ same code path with real weights (reference serves these checkpoints via
 vLLM, cosmos_curate/models/vllm_qwen.py:122-260).
 """
 
+from dataclasses import replace
+
 import numpy as np
 import pytest
 
@@ -15,7 +17,13 @@ transformers = pytest.importorskip("transformers")
 import jax
 import jax.numpy as jnp
 
+from cosmos_curate_tpu.models.vlm import vision_qwen
 from cosmos_curate_tpu.models.vlm.vision_qwen import (
+    QWEN2_VL_2B_VISION,
+    QWEN3_VISION_TINY_TEST,
+    QWEN25_VISION_TINY_TEST,
+    QWEN25_VL_7B_VISION,
+    QWEN_VISION_TINY_TEST,
     QwenVisionConfig,
     QwenVisionTower,
     frames_to_patches,
@@ -581,3 +589,92 @@ class TestQwen3VisionParity:
             np.testing.assert_allclose(
                 np.asarray(got_ds[lvl, 0]), want_ds[lvl].numpy(), atol=2e-4, rtol=1e-3
             )
+
+
+# Qwen2.5-VL's own window of 4 x 4 merge units (112 px / 2 / 14) at test
+# widths: (1, 6, 14) cuts it to runs of 48 and 36 tokens
+_WINDOWS_OF_FOUR_UNITS = replace(QWEN25_VISION_TINY_TEST, window_size=32)
+
+
+class TestSegmentAttention:
+    """Every block attends inside the static runs the tower hands it (HF's
+    cu_seqlens segments) and nothing of shape [S, S] is left in the program."""
+
+    @pytest.mark.parametrize(
+        "cfg, grid",
+        [
+            pytest.param(QWEN_VISION_TINY_TEST, (2, 4, 4), id="qwen2"),
+            pytest.param(QWEN25_VISION_TINY_TEST, (2, 8, 8), id="qwen2_5-whole-windows-of-16"),
+            pytest.param(QWEN25_VISION_TINY_TEST, (1, 6, 6), id="qwen2_5-cut-16-8-8-4"),
+            pytest.param(QWEN25_VISION_TINY_TEST, (1, 6, 14), id="qwen2_5-cut-16x3-8x4-4"),
+            pytest.param(_WINDOWS_OF_FOUR_UNITS, (1, 6, 14), id="qwen2_5-cut-48-36"),
+            pytest.param(QWEN3_VISION_TINY_TEST, (2, 4, 4), id="qwen3"),
+        ],
+    )
+    def test_equals_the_dense_masked_product(self, monkeypatch, cfg, grid):
+        s = grid[0] * grid[1] * grid[2]
+        calls = []
+        inner = vision_qwen._segment_attention
+
+        def spy(q, k, v, seg_lens):
+            out = inner(q, k, v, seg_lens)
+            calls.append((q, k, v, np.asarray(seg_lens), out))
+            return out
+
+        monkeypatch.setattr(vision_qwen, "_segment_attention", spy)
+        tower = QwenVisionTower(cfg, dtype=jnp.float32)
+        patches = jax.random.normal(jax.random.PRNGKey(31), (2, s, cfg.patch_dim))
+        params = tower.init(jax.random.PRNGKey(37), patches, grid)
+        # seeded biases and norm scales, so that no block is near the identity
+        leaves, tree = jax.tree.flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(41), len(leaves))
+        params = jax.tree.unflatten(
+            tree, [p + 0.3 * jax.random.normal(k, p.shape) for p, k in zip(leaves, keys)]
+        )
+        calls.clear()
+        tower.apply(params, patches, grid)
+        assert len(calls) == cfg.depth
+
+        # the masks HF's cu_seqlens stand for, written out densely
+        frame = np.arange(s) // (grid[1] * grid[2])
+        full = frame[:, None] == frame[None, :]
+        windowed_blocks = set()
+        if cfg.variant == "qwen2_5":
+            _perm, seg, _units = vision_qwen.window_partition(cfg, grid)
+            window = seg[:, None] == seg[None, :]
+            windowed_blocks = set(range(cfg.depth)) - set(cfg.fullatt_block_indexes)
+            assert windowed_blocks and set(cfg.fullatt_block_indexes)
+        for i, (q, k, v, seg_lens, got) in enumerate(calls):
+            mask = window if i in windowed_blocks else full
+            assert seg_lens.sum() == s
+            assert q.dtype == jnp.float32 and q.shape == (2, s, cfg.num_heads, cfg.head_dim)
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q * cfg.head_dim**-0.5, k)
+            probs = jax.nn.softmax(jnp.where(mask[None, None], logits, -1e30), axis=-1)
+            want = np.asarray(jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(2, s, -1))
+            # the same float32 sums in another order: 1e-5 of the output's scale
+            # (an element near zero is a difference of larger terms)
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=1e-5, atol=1e-5 * np.abs(want).max()
+            )
+
+    @pytest.mark.parametrize(
+        "cfg",
+        [
+            pytest.param(replace(QWEN2_VL_2B_VISION, depth=2), id="qwen2vl-2b"),
+            # one windowed block (64 runs of 64), one full (16 runs of 256)
+            pytest.param(
+                replace(QWEN25_VL_7B_VISION, depth=2, fullatt_block_indexes=(1,)), id="qwen25vl-7b"
+            ),
+        ],
+    )
+    def test_no_s_by_s_value_at_the_cells_grid(self, cfg):
+        """The towers of both windows-32f cells, widths as published and
+        depth cut to 2, lowered (not run) at 32 frames of 224 px."""
+        grid = cfg.grid(32)
+        assert grid == (16, 16, 16)
+        tower = QwenVisionTower(cfg, dtype=jnp.bfloat16)
+        patches = jax.ShapeDtypeStruct((1, 4096, cfg.patch_dim), jnp.bfloat16)
+        params = jax.eval_shape(lambda p: tower.init(jax.random.PRNGKey(0), p, grid), patches)
+        text = jax.jit(lambda prm, p: tower.apply(prm, p, grid)).lower(params, patches).as_text()
+        assert "4096x1280x" in text  # the program is the tower's, at the cells' size
+        assert "4096x4096" not in text
