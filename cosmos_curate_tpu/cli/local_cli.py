@@ -27,6 +27,8 @@ CAPTION_MODEL_CHOICES = (
     "qwen3moe-tiny-test",
     "qwen-chat-tiny-test",
     "tiny-test",
+    "trinity-large-ep8",
+    "trinity-tiny-test",
 )
 
 
@@ -96,6 +98,8 @@ def register(sub: argparse._SubParsersAction) -> None:
         "hybrid whose per-request state does not grow with the context. "
         "deepseek-v2-ep8 (text only) is one chip's share of DeepSeek-V2 served "
         "expert-parallel over 8: its layers return that chip's partial sums. "
+        "trinity-large-ep8 (text only) is the same share of Trinity-Large (afmoe): "
+        "window and full attention layers over two KV pools, requests up to 12,287 positions. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

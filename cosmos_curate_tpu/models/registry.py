@@ -70,6 +70,7 @@ for _mid, _desc in [
     ("caption-qwen3vl-moe-a3b-tpu", "Qwen3-VL-MoE-A3B captioner: deepstack vision + sparse LM (converted checkpoint slot)"),
     ("caption-granite-4.0-h-micro-tpu", "Granite-4.0-H-Micro hybrid (Mamba-2 + attention) text LM (converted checkpoint slot)"),
     ("caption-deepseek-v2-ep8-tpu", "DeepSeek-V2, one chip's share of an 8-way expert-parallel deployment (converted checkpoint slot)"),
+    ("caption-trinity-large-ep8-tpu", "Trinity-Large (afmoe), one chip's share of an 8-way expert-parallel deployment (converted checkpoint slot)"),
     ("t5-encoder-tpu", "text encoder for caption embeddings"),
     ("ocr-detector-tpu", "overlay-text region detector (Flax FCN)"),
     ("ocr-recognizer-tpu", "text recognizer CRNN with CTC decoding"),

@@ -52,6 +52,22 @@ vllm_async_stage.py). TPU-first re-design:
   advances it: a prefill's positions past ``t_valid`` and a decode program's
   idle rows are masked in the recurrence, and a chunked prefill's last chunk
   is padded at its end instead of shifted back over tokens already taken.
+- **two pools** (flavors that mix window and full attention layers,
+  ``cfg.window_layers``): the full layers' K/V lives in the pool above, whose
+  ``L`` then counts them alone; the window layers' in a second pool ``[L_win,
+  NB_w, ...]`` with an allocator and a table a row of its own. A window layer
+  never looks further back than its window, so a row holds at most a RING of
+  ``ceil((window + prefill bucket) / block_size) + 1`` blocks there, whatever
+  its lane: the table entry of logical block ``j`` names ring block ``j % ring``
+  (the wrap lives in the table's CONTENTS, written once at admission; the
+  kernels and the write index a table by position as ever and skip what lies
+  behind the window). A chunk's write lands a whole ring behind its newest
+  position, which no query of the chunk can see. A row whose whole life fits
+  the ring never wraps: its window table reads like its other table, shared
+  prefix blocks included. A row that will wrap would write over them, so it
+  takes private copies of the prefix's window blocks at admission instead.
+  Such a flavor's prompts always prefill in chunks (a bucket longer than the
+  ring's slack would overwrite what it still reads).
 - **one decode program of look-ahead**: a lane's decode program N+1 is
   dispatched before program N's tokens are read on the host (``_decode_once``:
   dispatch, then collect the one before). N+1's positions are the host's
@@ -231,16 +247,21 @@ class _PrefixEntry:
     # tokens, (ssm [Lm, H, P, N], conv [Lm, (d_conv - 1) * conv_dim]) — copied
     # into the slot's row of the recurrent store at admission
     state: tuple | None = None
+    # two pools: the same positions' blocks in the window pool
+    wblocks: list[int] = field(default_factory=list)
 
 
 @dataclass
 class _BlockClaim:
     """The pool blocks one admitted slot holds: ``shared`` prefix blocks it
     incref'd (freed back to the prefix entry's refcount on release) and
-    ``private`` blocks it owns outright (freed on release)."""
+    ``private`` blocks it owns outright (freed on release); ``window``, the
+    same of the window pool (shared and private together: both are one
+    decref)."""
 
     shared: list[int]
     private: list[int]
+    window: list[int] = field(default_factory=list)
 
     @property
     def all_blocks(self) -> list[int]:
@@ -291,6 +312,9 @@ class _Lane:
     # [n_slots, length // block_size] int32 pool block ids (host-side; a
     # snapshot rides into every prefill/decode program call)
     table: np.ndarray | None = None
+    # two pools: the same rows over the WINDOW pool, same shape; a row that
+    # wraps repeats its ring of blocks along it (module docstring)
+    wtable: np.ndarray | None = None
     slots: dict = field(default_factory=dict)
     pending: dict = field(default_factory=dict)
     # slot indices claimed by _admit's current grouping pass (released when
@@ -517,6 +541,16 @@ def _handoff_works(mesh, put_sharding) -> bool:
     return False
 
 
+def default_block_size(kv_lanes) -> int:
+    """Positions a KV block where the caller names none: 16, and 128 once a
+    lane passes 4,096 positions. The prefill kernel fetches one block of keys
+    a grid step, so a long row costs it a step a table entry; at 128 a
+    12,288-position row is 96 entries where it would be 768 (28.9 ms of
+    attention a 256-token chunk at 16, 5.05 at 128: PERF.md, PR 38). Every
+    lane of an engine shares the one size."""
+    return 128 if max(int(length) for length, _ in kv_lanes) > 4096 else 16
+
+
 class CaptionEngine:
     def __init__(
         self,
@@ -532,7 +566,7 @@ class CaptionEngine:
         prefix_cache_size: int = 8,
         min_prefix_len: int = 4,
         admission_linger_s: float = 0.05,
-        block_size: int = 16,
+        block_size: int | None = None,
         kv_pool_blocks: int | None = None,
         owner_inflight_cap: int | None = None,
         paged_attention: str = "auto",
@@ -579,6 +613,12 @@ class CaptionEngine:
         self.mesh = mesh
         # hybrid flavors keep a second kind of per-request state (module docstring)
         self._recurrent = bool(cfg.ssm_layers)
+        # ...or a second pool for their window layers (module docstring)
+        self._windowed = bool(cfg.window_layers)
+        if self._windowed and (mesh is not None or not self._use_paged):
+            raise ValueError(
+                "window layers are served on one chip by the paged programs: no mesh, no 'gather'"
+            )
         if self._recurrent and mesh is not None:
             raise ValueError("the recurrent store is not split over a mesh: serve a hybrid on one chip")
         # parameters stored in the type they are computed in: see VLM.param_dtype
@@ -600,6 +640,8 @@ class CaptionEngine:
         # must equal the lane length EXACTLY for shape parity with the
         # slot-row programs): shrink the block size to the largest common
         # divisor when a lane length doesn't tile
+        if block_size is None:
+            block_size = default_block_size(spec)
         bs = max(1, int(block_size))
         for length, _ in spec:
             bs = math.gcd(bs, int(length))
@@ -644,6 +686,24 @@ class CaptionEngine:
         self._allocator = BlockAllocator(self.kv_pool_blocks)
         self._pool_k = None
         self._pool_v = None
+        # the window pool (None and 0 for every flavor without window layers)
+        self._wallocator = None
+        self._wpool_k = None
+        self._wpool_v = None
+        self._ring_blocks = 0
+        self._window_pool_bytes_per_chip = 0
+        if self._windowed:
+            # the longest chunk a program writes: a prefill chunk, or the bucket
+            # of a prompt no longer than one
+            longest = next_pow2(self.prefill_chunk)
+            self._ring_blocks = -(-(cfg.sliding_window + longest) // bs) + 1
+            rings = [min(l.length // bs, self._ring_blocks) for l in self.lanes]
+            for lane in self.lanes:
+                lane.wtable = np.zeros_like(lane.table)
+            reserve = kv_pool_blocks - 1 - lane_blocks  # the prefix entries' room, as above
+            self._wallocator = BlockAllocator(
+                1 + sum(r * l.n_slots for r, l in zip(rings, self.lanes)) + max(reserve, 0)
+            )
         self._kv_pool_bytes_per_chip = 0  # until setup() makes the pool
         # KV heads side by side in one pool row (paged_kv.init_block_pool:
         # two where head_dim is 64, so that a row is a whole lane tile)
@@ -822,7 +882,8 @@ class CaptionEngine:
         """Total device bytes the KV block pool pins."""
         if self._pool_k is None:
             return 0
-        return self._pool_k.nbytes + self._pool_v.nbytes
+        window = self._wpool_k.nbytes + self._wpool_v.nbytes if self._windowed else 0
+        return self._pool_k.nbytes + self._pool_v.nbytes + window
 
     # -- setup ----------------------------------------------------------
     def setup(self, seed: int = 0) -> None:
@@ -874,10 +935,18 @@ class CaptionEngine:
             self._pool_k, self._pool_v = init_latent_pool(cfg, self.kv_pool_blocks, self.block_size)
         else:
             self._pool_k, self._pool_v = init_block_pool(
-                cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding
+                cfg, self.kv_pool_blocks, self.block_size, sharding=pool_sharding,
+                n_layers=len(cfg.full_layers),
             )
             self._kv_heads_per_pool_row = cfg.n_kv_heads // self._pool_k.shape[2]
-        self._kv_pool_bytes_per_chip = _bytes_per_chip((self._pool_k, self._pool_v))
+        if self._windowed:
+            self._wpool_k, self._wpool_v = init_block_pool(
+                cfg, self._wallocator.n_blocks, self.block_size, n_layers=len(cfg.window_layers)
+            )
+            self._window_pool_bytes_per_chip = _bytes_per_chip((self._wpool_k, self._wpool_v))
+        self._kv_pool_bytes_per_chip = (
+            _bytes_per_chip((self._pool_k, self._pool_v)) + self._window_pool_bytes_per_chip
+        )
         if self._recurrent:
             self._ssm, self._conv = init_recurrent_store(
                 cfg, 1 + sum(l.n_slots for l in self.lanes), dtype=self.model.dtype
@@ -1472,7 +1541,8 @@ class CaptionEngine:
         """Device bytes one block pins (K + V across all layers)."""
         cfg = self.cfg
         # bf16 pool: 2 bytes/element; a row holds K and V, or one latent row
-        return 2 * len(cfg.kv_layers) * self.block_size * cfg.cache_row_elems
+        # (of the pool every flavor has: the full layers')
+        return 2 * len(cfg.full_layers) * self.block_size * cfg.cache_row_elems
 
     @property
     def prefix_block_refs(self) -> int:
@@ -1556,6 +1626,12 @@ class CaptionEngine:
                 # partitioned over model=4, the whole of what is repeated
                 "param_bytes_per_chip": self._param_bytes_per_chip,
                 "kv_pool_bytes_per_chip": self._kv_pool_bytes_per_chip,
+                # window and full layers mixed: the two pools it is the sum of
+                # (every other flavor: one pool, and no window pool)
+                "full_pool_bytes_per_chip": (
+                    self._kv_pool_bytes_per_chip - self._window_pool_bytes_per_chip
+                ),
+                "window_pool_bytes_per_chip": self._window_pool_bytes_per_chip,
                 # KV heads side by side in one 128-lane pool row (1: a row a head)
                 "kv_heads_per_pool_row": self._kv_heads_per_pool_row,
                 "kv_block_size": self.block_size,
@@ -1727,7 +1803,7 @@ class CaptionEngine:
         fully free."""
         with self._lock, self._prefix_lock:
             for entry in self._prefix_cache.values():
-                self._allocator.decref(entry.blocks)
+                self._drop_prefix(entry)
             self._prefix_cache.clear()
 
     def shutdown(self) -> None:
@@ -2120,8 +2196,10 @@ class CaptionEngine:
             # decoding there is nothing to protect, so admit the whole
             # prompt as one bucketed prefill and skip the per-step drip.
             decode_active = any(l.slots for l in self.lanes)
+            # (two pools: a write longer than the ring's slack would land on
+            # positions its own queries still see, so never one long bucket)
             chunked = prep.t_suffix > self.prefill_chunk and (
-                decode_active or not group_ok
+                decode_active or not group_ok or self._windowed
             )
             if chunked and self._recurrent:
                 # a recurrence cannot take a token twice, so a hybrid's last
@@ -2452,6 +2530,8 @@ class CaptionEngine:
             jax.block_until_ready(v)
         bs = self.block_size
         nb = -(-tp // bs)
+        if self._windowed and nb > self._ring_blocks:
+            return None, False  # longer than a row's ring of window blocks: served uncached
         with self._lock:
             with self._prefix_lock:
                 raced = self._prefix_cache.get(key)
@@ -2464,15 +2544,27 @@ class CaptionEngine:
                         self._prefix_misses -= 1
                         self._prefix_hits += 1
                     return raced, True
-                if not self._allocator.can_alloc(nb):
-                    self._evict_prefixes_for(nb)
-                if not self._allocator.can_alloc(nb):
+                nw = nb if self._windowed else 0
+                if not self._can_alloc(nb, nw):
+                    self._evict_prefixes_for(nb, n_window=nw)
+                if not self._can_alloc(nb, nw):
                     logger.warning(
                         "prefix cache: pool exhausted; serving %d-token "
                         "prefix uncached", tp,
                     )
                     return None, False
                 ids = self._allocator.alloc(nb)
+                wids = self._wallocator.alloc(nw) if nw else []
+                if self._windowed:
+                    # the build's K/V count every attention layer in order: each
+                    # kind's go to its own pool
+                    at = self.cfg.kv_layers.index
+                    full = np.array([at(i) for i in self.cfg.full_layers])
+                    win = np.array([at(i) for i in self.cfg.window_layers])
+                    self._wpool_k, self._wpool_v = self._write_prefix_blocks(
+                        self._wpool_k, self._wpool_v, k[win], v[win], jnp.asarray(wids, jnp.int32)
+                    )
+                    k, v = k[full], v[full]
                 self._pool_k, self._pool_v = self._write_prefix_blocks(
                     self._pool_k,
                     self._pool_v,
@@ -2486,29 +2578,45 @@ class CaptionEngine:
                     tail_block=ids[-1] if tp % bs else None,
                     length=tp,
                     state=tuple(state) or None,
+                    wblocks=wids,
                 )
                 self._prefix_cache[key] = entry
                 while len(self._prefix_cache) > self.prefix_cache_size:
                     _k2, evicted = self._prefix_cache.popitem(last=False)
                     # referenced blocks defer their free to the last slot
-                    self._allocator.decref(evicted.blocks)
+                    self._drop_prefix(evicted)
                     with self._stats_lock:
                         self._prefix_evictions += 1
                 return entry, False
 
+    def _can_alloc(self, n_blocks: int, n_window: int = 0) -> bool:
+        """Whether the pool has ``n_blocks`` free and, where there are two, the
+        window pool ``n_window``."""
+        return self._allocator.can_alloc(n_blocks) and (
+            not n_window or self._wallocator.can_alloc(n_window)
+        )
+
+    def _drop_prefix(self, entry: _PrefixEntry) -> None:
+        """The LRU's own references to a prefix's blocks, in both pools."""
+        self._allocator.decref(entry.blocks)
+        if entry.wblocks:
+            self._wallocator.decref(entry.wblocks)
+
     # holds-lock: _lock, _prefix_lock
-    def _evict_prefixes_for(self, n_blocks: int, exclude: tuple | None = None) -> None:
-        """Evict idle LRU prefixes until ``n_blocks`` are allocatable (or
-        the cache is empty — referenced blocks free only when their last
-        slot releases). ``exclude`` protects the entry a claim in progress
-        is about to reference. Engine + prefix locks held by caller."""
+    def _evict_prefixes_for(
+        self, n_blocks: int, exclude: tuple | None = None, n_window: int = 0
+    ) -> None:
+        """Evict idle LRU prefixes until ``n_blocks`` (and ``n_window`` of the
+        window pool) are allocatable (or the cache is empty — referenced
+        blocks free only when their last slot releases). ``exclude`` protects
+        the entry a claim in progress is about to reference. Engine + prefix
+        locks held by caller."""
         for key in list(self._prefix_cache):
-            if self._allocator.can_alloc(n_blocks):
+            if self._can_alloc(n_blocks, n_window):
                 return
             if key == exclude:
                 continue
-            evicted = self._prefix_cache.pop(key)
-            self._allocator.decref(evicted.blocks)
+            self._drop_prefix(self._prefix_cache.pop(key))
             with self._stats_lock:
                 self._prefix_evictions += 1
 
@@ -2542,7 +2650,21 @@ class CaptionEngine:
             shared = list(entry.blocks[: entry.n_full])
             cow_src = entry.tail_block
         private_needed = view_blocks - len(shared)
-        if not self._allocator.can_alloc(private_needed):
+        # two pools: the row's ring in the window pool, min(need, ring) blocks.
+        # A row that never wraps shares the prefix's window blocks like the
+        # others; one that will would write over them, and copies them instead
+        wshared: list[int] = []
+        wcopy: list[int] = []
+        wview = 0
+        if self._windowed:
+            wview = min(view_blocks, self._ring_blocks)
+            if entry is not None and view_blocks <= self._ring_blocks:
+                wshared = list(entry.wblocks[: entry.n_full])
+                wcopy = entry.wblocks[entry.n_full :]  # the tail block, if partly filled
+            elif entry is not None:
+                wcopy = entry.wblocks
+        wprivate_needed = wview - len(wshared)
+        if not self._can_alloc(private_needed, wprivate_needed):
             if not any(l.claims for l in self.lanes):
                 # nothing in flight will ever free blocks — the pool is
                 # held by idle prefix entries. Evict them (sparing the one
@@ -2551,15 +2673,31 @@ class CaptionEngine:
                     self._evict_prefixes_for(
                         private_needed,
                         exclude=prep.prefix_key if prep.base else None,
+                        n_window=wprivate_needed,
                     )
-            if not self._allocator.can_alloc(private_needed):
+            if not self._can_alloc(private_needed, wprivate_needed):
                 raise PoolExhausted(
                     f"{private_needed} KV blocks needed, "
                     f"{self._allocator.free_blocks} free of {self._allocator.capacity}"
+                    + (
+                        f"; {wprivate_needed} window blocks, {self._wallocator.free_blocks} "
+                        f"free of {self._wallocator.capacity}" if self._windowed else ""
+                    )
                 )
         self._allocator.incref(shared)
         private = self._allocator.alloc(private_needed)
+        wprivate: list[int] = []
+        if self._windowed:
+            self._wallocator.incref(wshared)
+            wprivate = self._wallocator.alloc(wprivate_needed)
         try:
+            if wcopy:
+                self._wpool_k, self._wpool_v = self._copy_blocks(
+                    self._wpool_k,
+                    self._wpool_v,
+                    jnp.asarray(wcopy, jnp.int32),
+                    jnp.asarray(wprivate[: len(wcopy)], jnp.int32),
+                )
             if cow_src is not None:
                 # the suffix extends INTO the partially-filled shared tail
                 # block: copy-on-write one block — the only device copy on
@@ -2574,12 +2712,19 @@ class CaptionEngine:
             # a failed CoW dispatch must hand the references back, or the
             # shared pool shrinks permanently on every transient error
             self._allocator.decref(shared + private)
+            if self._windowed:
+                self._wallocator.decref(wshared + wprivate)
             raise
         row = lane.table[slot_idx]
         row[:] = 0
         row[: len(shared)] = shared
         row[len(shared) : view_blocks] = private
-        claim = _BlockClaim(shared=shared, private=private)
+        if self._windowed:
+            # logical block j in ring block j % ring: the wrap is here and nowhere else
+            wrow = lane.wtable[slot_idx]
+            wrow[:] = 0
+            wrow[:view_blocks] = np.resize(np.asarray(wshared + wprivate, np.int32), view_blocks)
+        claim = _BlockClaim(shared=shared, private=private, window=wshared + wprivate)
         lane.claims[slot_idx] = claim
         if self._recurrent:
             try:
@@ -2628,18 +2773,36 @@ class CaptionEngine:
     def _run_prefill(self, lane: _Lane, slots_arr, tables, embeds, write_index, t_valid, rope, ds):
         """One call of the prefill program over host arrays; a hybrid's
         recurrent store rides along and comes back with the pools."""
+        tables = jnp.asarray(tables)
+        if self._windowed:
+            tables = (tables, jnp.asarray(lane.wtable[slots_arr]))
         args = (
-            self.params, self._pool_k, self._pool_v, jnp.asarray(tables), jnp.asarray(embeds),
+            self.params, *self._pools(), tables, jnp.asarray(embeds),
             jnp.asarray(write_index), jnp.asarray(t_valid), jnp.asarray(rope),
             None if ds is None else jnp.asarray(ds),
         )
         if not self._recurrent:
-            logits, self._pool_k, self._pool_v = self._prefill_batch(*args)
+            logits, *pools = self._prefill_batch(*args)
+            self._keep_pools(*pools)
         else:
             logits, self._pool_k, self._pool_v, self._ssm, self._conv = self._prefill_batch(
                 *args, self._ssm, self._conv, jnp.asarray(self._state_rows(lane, slots_arr))
             )
         return logits
+
+    def _pools(self) -> tuple:
+        """(K, V) as the programs take them: the pool's two arrays, or where
+        there are two pools a pair each, (the full layers', the window layers')."""
+        if not self._windowed:
+            return self._pool_k, self._pool_v
+        return (self._pool_k, self._wpool_k), (self._pool_v, self._wpool_v)
+
+    # holds-lock: _lock
+    def _keep_pools(self, pool_k, pool_v) -> None:
+        """What a program hands back in ``_pools()``'s place."""
+        if self._windowed:
+            (pool_k, self._wpool_k), (pool_v, self._wpool_v) = pool_k, pool_v
+        self._pool_k, self._pool_v = pool_k, pool_v
 
     def _release_claim(self, lane: _Lane, slot_idx: int) -> None:
         """Return a slot's block references to the pool. Private blocks
@@ -2652,6 +2815,9 @@ class CaptionEngine:
             return
         self._allocator.decref(claim.all_blocks)
         lane.table[slot_idx, :] = 0
+        if claim.window:
+            self._wallocator.decref(claim.window)
+            lane.wtable[slot_idx, :] = 0
         self._work_cv.notify_all()  # pool-blocked admissions may now fit
 
     def fit_max_new_tokens(
@@ -3009,6 +3175,8 @@ class CaptionEngine:
             # a copy: the table's rows change (a release, an admission) while
             # a program that was given them may not have read them yet
             table = lane.table.copy()
+            if self._windowed:
+                table = (table, lane.wtable.copy())
         program = next(self._programs)
         with self._phase(
             "decode_dispatch", rows=lane.n_slots, live=len(lane.slots), ahead=ahead,
@@ -3016,19 +3184,18 @@ class CaptionEngine:
         ):
             args = (
                 self.params,
-                self._pool_k,
-                self._pool_v,
-                jnp.asarray(table),
+                *self._pools(),
+                jax.tree.map(jnp.asarray, table),
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
                 jnp.asarray(rope_positions),
             )
             if self._counts_experts:
-                greedy, logits, self._pool_k, self._pool_v, self._expert_held = self._decode(
-                    *args, self._expert_held
-                )
+                greedy, logits, *pools, self._expert_held = self._decode(*args, self._expert_held)
+                self._keep_pools(*pools)
             elif not self._recurrent:
-                greedy, logits, self._pool_k, self._pool_v = self._decode(*args)
+                greedy, logits, *pools = self._decode(*args)
+                self._keep_pools(*pools)
             else:
                 # rows that decode advance their own state; the others (free,
                 # or mid-prefill and holding a real state) carry the garbage
@@ -3074,10 +3241,19 @@ class CaptionEngine:
                     positions = flight.positions
                     for i in flight.rows.keys() - emitted.keys():
                         positions[i] = 0
-                    self._paged_decode_pages_walked += int(
-                        (positions // self.block_size + 1).sum()
-                    )
-                    self._paged_decode_pages_spanned += lane.table.size
+                    last = positions // self.block_size
+                    if not self._windowed:
+                        self._paged_decode_pages_walked += int((last + 1).sum())
+                        self._paged_decode_pages_spanned += lane.table.size
+                    else:
+                        # two pools: layer by layer. A window layer's walk starts
+                        # at the page of its oldest visible key (kv_len - window)
+                        first = np.maximum(positions + 1 - self.cfg.sliding_window, 0) // self.block_size
+                        n_full, n_win = len(self.cfg.full_layers), len(self.cfg.window_layers)
+                        self._paged_decode_pages_walked += int(
+                            n_full * (last + 1).sum() + n_win * (last - first + 1).sum()
+                        )
+                        self._paged_decode_pages_spanned += (n_full + n_win) * lane.table.size
                     self._kv_gather_bytes_avoided += self._gather_view_bytes(
                         lane.n_slots, lane.length
                     )

@@ -17,6 +17,7 @@ models behind the plugin ABC). This is our own Flax architecture, TPU-first:
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from functools import partial
 import math
@@ -85,10 +86,22 @@ class MoEConfig:
     # the sum (plus the shared expert), what such a chip contributes before
     # the exchange. None = all of them
     held: tuple[int, int] | None = None
+    # -- the afmoe class (HF ``AfmoeTokenChoiceRouter``) --
+    # what the router's outputs become before the choice: "softmax" over all
+    # experts, or "sigmoid", each expert's score on its own
+    score_func: str = "softmax"
+    # a stored float32 ``[n_experts]`` bias added to the scores for the CHOICE
+    # and not for the weights (the load balancer's handle on the router: HF
+    # ``expert_bias``); False = none stored
+    selection_bias: bool = False
 
     def __post_init__(self) -> None:
         if self.dispatch not in ("queue", "sorted"):
             raise ValueError(f"dispatch must be queue|sorted, got {self.dispatch!r}")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"score_func must be softmax|sigmoid, got {self.score_func!r}")
+        if self.score_func == "sigmoid" and self.n_group > 1:
+            raise ValueError("sigmoid scores under a group limit: no flavor here defines that routing")
         if self.n_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"{self.n_experts} experts do not make {self.n_group} groups, top {self.topk_group}")
         if self.dispatch == "queue" and (self.held is not None or self.shared_hidden or self.first_dense):
@@ -225,7 +238,12 @@ class VLMConfig:
     # or "mamba"; None = attention throughout. A "mamba" layer replaces the
     # attention half of a layer with the Mamba-2 mixer ``mamba`` describes;
     # its state lives in the engine's recurrent store, not in the KV pool.
+    # Window and full attention mixed (afmoe; HF's own two words):
+    # "sliding_attention" layers see ``sliding_window`` positions and keep
+    # their K/V in the engine's window pool, "full_attention" layers (like
+    # "attention") see every earlier position out of the pool every flavor has.
     layer_types: tuple[str, ...] | None = None
+    sliding_window: int | None = None
     mamba: Mamba2Config | None = None
     # latent attention (DeepSeek-V2) in place of GQA in every attention layer:
     # its sizes and YaRN's numbers; None = ``DecoderLayer``'s attention. Such a
@@ -234,6 +252,15 @@ class VLMConfig:
     # False = no position embedding at all (HF ``position_embedding_type:
     # nope``): the state-space layers carry the order
     use_rope: bool = True
+    # False = rope on the "sliding_attention" layers only, none on the
+    # "full_attention" ones (afmoe: the window layers carry the order)
+    full_attention_rope: bool = True
+    # afmoe's attention: the heads' outputs times sigmoid of one more
+    # projection of the layer's input (``g``, as wide as ``q``) before ``o``
+    attention_gate: bool = False
+    # afmoe's "sandwich": a second RMSNorm on each BRANCH, after ``o`` and after
+    # the FFN, before the residual sum (``post_attn_norm``, ``post_mlp_norm``)
+    sandwich_norm: bool = False
     # softmax scale of the attention layers; None = head_dim ** -0.5
     attention_multiplier: float | None = None
     # Granite's scalings: x0 = E[ids] * embedding_multiplier, every residual
@@ -245,18 +272,40 @@ class VLMConfig:
     def __post_init__(self) -> None:
         if self.layer_types is None:
             return
-        if len(self.layer_types) != self.n_layers or set(self.layer_types) - {"attention", "mamba"}:
-            raise ValueError(f"layer_types must name {self.n_layers} 'attention'/'mamba' layers")
+        kinds = {"attention", "mamba", "sliding_attention", "full_attention"}
+        if len(self.layer_types) != self.n_layers or set(self.layer_types) - kinds:
+            raise ValueError(f"layer_types must name {self.n_layers} layers out of {sorted(kinds)}")
         if "mamba" in self.layer_types and self.mamba is None:
             raise ValueError("layer_types has a 'mamba' layer and mamba= gives no sizes")
+        if self.window_layers and not self.sliding_window:
+            raise ValueError("layer_types has a 'sliding_attention' layer and sliding_window= is not set")
+        if self.window_layers and (self.mla is not None or self.ssm_layers):
+            raise ValueError("window layers beside latent attention or state-space layers: no program here")
 
     @property
     def kv_layers(self) -> tuple[int, ...]:
-        """Indices of the layers that hold K/V: the KV pool's leading
-        dimension counts these, in this order."""
+        """Indices of the layers that hold K/V, in order: a slot cache's leading
+        dimension counts these. They split into ``full_layers``, which the
+        KV pool's leading dimension counts, and ``window_layers`` (none but
+        in a flavor that mixes the two), which the window pool's does."""
         if self.layer_types is None:
             return tuple(range(self.n_layers))
-        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "attention")
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind != "mamba")
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """Indices of the "sliding_attention" layers, in order."""
+        if self.layer_types is None:
+            return ()
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "sliding_attention")
+
+    @property
+    def full_layers(self) -> tuple[int, ...]:
+        """Indices of the attention layers that see their whole context, in order."""
+        return tuple(i for i in self.kv_layers if i not in self.window_layers)
+
+    def rope_in_layer(self, i: int) -> bool:
+        return self.use_rope and (self.full_attention_rope or i in self.window_layers)
 
     @property
     def cache_row_elems(self) -> int:
@@ -524,6 +573,78 @@ VLM_DEEPSEEK_V2_TINY_TEST = VLMConfig(
         held=(4, 4),
     ),
 )
+# Trinity-Large-Preview (HF ``afmoe``, config.json of arcee-ai/Trinity-Large-Preview)
+# as ONE CHIP OF AN 8-WAY EXPERT-PARALLEL DEPLOYMENT sees it: every width as
+# published (3072; 48 query / 8 KV heads x 128 with per-head q/k norms and an
+# output gate; window 4096 on the "sliding_attention" layers, which alone carry
+# rope; a dense SwiGLU of 12288 in the leading layer, then 256 routed experts of
+# 3072, top 4 by sigmoid score plus a stored selection bias, renormalised times
+# 2.448, and one shared SwiGLU of 3072; a second norm on each branch; the
+# embedding times sqrt(3072)), the router whole, and of the rest this chip's
+# share: 32 consecutive experts (0-31), a vocabulary slice of 25,024 rows, and 5
+# of the 60 layers: the first five entries of the published ``layer_types`` (S S
+# S F S), of them ONE leading dense layer (6 published: they count once) and four
+# sparse ones, a whole period of three window layers to one full. Attention and
+# the shared expert are replicated in that deployment, so they are whole here;
+# the layer runs without its exchange. Text only. The first flavor whose
+# requests pass 4,096 positions: its K/V lives in two pools (engine.py).
+_TRINITY_LAYERS = ("sliding_attention",) * 3 + ("full_attention", "sliding_attention")
+VLM_TRINITY_LARGE_EP8 = VLMConfig(
+    vocab=25024,
+    dim=3072,
+    n_layers=5,
+    n_heads=48,
+    n_kv_heads=8,
+    head_dim=128,
+    hidden_mult=12288 / 3072,
+    max_seq=12288,
+    rope_theta=10000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    tied_embeddings=False,
+    qk_norm=True,
+    layer_types=_TRINITY_LAYERS,
+    sliding_window=4096,
+    full_attention_rope=False,
+    attention_gate=True,
+    sandwich_norm=True,
+    embedding_multiplier=math.sqrt(3072),
+    moe=MoEConfig(
+        n_experts=256, top_k=4, hidden=3072, shared_hidden=3072, first_dense=1,
+        norm_topk_prob=True, routed_scaling_factor=2.448, dispatch="sorted", held=(0, 32),
+        score_func="sigmoid", selection_bias=True,
+    ),
+)
+# the same mechanisms at test size: a dense leading window layer, then window,
+# full, window; a window of 10 (no multiple of the engine's test block of 4, so
+# its edge falls inside a page); 8 experts of which 4 (2-5) are held
+VLM_TRINITY_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    tied_embeddings=False,
+    qk_norm=True,
+    layer_types=("sliding_attention", "sliding_attention", "full_attention", "sliding_attention"),
+    sliding_window=10,
+    full_attention_rope=False,
+    attention_gate=True,
+    sandwich_norm=True,
+    embedding_multiplier=8.0,
+    moe=MoEConfig(
+        n_experts=8, top_k=2, hidden=32, shared_hidden=32, first_dense=1, norm_topk_prob=True,
+        routed_scaling_factor=2.448, dispatch="sorted", held=(2, 4), score_func="sigmoid",
+        selection_bias=True,
+    ),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -741,6 +862,28 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 4), (128, 2)),
         ),
+        # a large sparse text LM with window and full attention layers mixed,
+        # served expert-parallel, seen from one of its eight chips (the LM-only
+        # passes over LONG text: a per-video digest of every window's caption).
+        # A position costs 4 KiB a layer; a window layer's row is bounded to
+        # window + chunk positions whatever the lane (17.5 MiB a layer), so 24
+        # rows of 12,288 cost 2.6 GiB of window pool + 1.4 of full pool where
+        # one pool over five layers would want 6.9. 40 decoding rows give the
+        # 32 held experts the 20 assignments a step that 5 rows a chip give them
+        "trinity-large-ep8": FlavorSpec(
+            VLM_TRINITY_LARGE_EP8,
+            "caption-trinity-large-ep8-tpu",
+            text_only=True,
+            kv_lanes=((4096, 16), (12288, 24)),
+            prefill_rows=4,  # 1,024 tokens a prefill program
+        ),
+        "trinity-tiny-test": FlavorSpec(
+            VLM_TRINITY_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 2), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -866,12 +1009,23 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(x.dtype)
 
 
-def route(moe: MoEConfig, logits):
+def route(moe: MoEConfig, logits, bias=None):
     """The router's choice for every token. logits: ``[N, E]`` float32. Softmax
     over ALL experts, the groups that lose zeroed (group-limited routing: a
     group's score is its best expert's), top-k of what is left, renormalised
     or not, times the scaling factor. A tie goes to the lower index, in the
-    groups as in the experts. Returns (weights ``[N, k]``, experts ``[N, k]``)."""
+    groups as in the experts. With ``score_func="sigmoid"`` (afmoe) an expert's
+    score is its own sigmoid, the top-k is taken of score + ``bias`` (``[E]``
+    float32, stored; None = no bias) and the WEIGHTS are the unbiased scores of
+    the chosen, renormalised with the published ``1e-20`` in the sum. Returns
+    (weights ``[N, k]``, experts ``[N, k]``)."""
+    if moe.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(scores if bias is None else scores + bias, moe.top_k)
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+        if moe.norm_topk_prob:
+            top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
+        return top_w * moe.routed_scaling_factor, top_i
     probs = jax.nn.softmax(logits, axis=-1)
     if moe.n_group > 1:
         n, e = probs.shape
@@ -920,8 +1074,12 @@ class MoEFFN(nn.Module):
         logits = dense(e, None, name="router", use_bias=False, dtype=jnp.float32)(
             tokens.astype(jnp.float32)
         )
+        bias = (
+            self.param("router_bias", nn.initializers.zeros, (e,), jnp.float32)
+            if moe.selection_bias else None
+        )
         with jax.named_scope("moe.route"):
-            top_w, top_i = route(moe, logits)  # [N, k]
+            top_w, top_i = route(moe, logits, bias)  # [N, k]
         if moe.dispatch == "sorted":
             y = self._sorted_experts(tokens, top_w, top_i)
             return y.reshape(b, t, d).astype(x.dtype)
@@ -1047,6 +1205,12 @@ class DecoderLayer(nn.Module):
     # optional device mesh: when set and it names the model axis, the paged
     # path runs head-parallel (shard_map over Hkv) — see paged_head_attention
     mesh: object = None
+    # a "sliding_attention" layer's window (its cache is then the window pool
+    # and ``block_tables`` that pool's table); None = the whole context
+    window: int | None = None
+    # rope in THIS layer (``VLMConfig.rope_in_layer``); None = ``cfg.use_rope``
+    use_rope: bool | None = None
+    dense_ffn: bool = False  # a leading layer of a sparse model (``moe.first_dense``)
 
     @nn.compact
     def __call__(
@@ -1086,17 +1250,49 @@ class DecoderLayer(nn.Module):
         if cfg.qk_norm:  # Qwen3 family: per-HEAD-DIM RMSNorm before rope
             q = RMSNorm(eps=cfg.rms_eps, name="q_norm")(q)
             k = RMSNorm(eps=cfg.rms_eps, name="k_norm")(k)
-        if cfg.use_rope:
+        if cfg.use_rope if self.use_rope is None else self.use_rope:
             q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
         v = v.reshape(b, t, hk, dh)
+        # a "sliding_attention" layer hands its window on; where the kinds mix
+        # each is named in a compiled program (no other flavor's programs
+        # carry these scopes, nor the keyword)
+        edge = {} if self.window is None else {"window": self.window}
+        kind = contextlib.nullcontext() if not cfg.window_layers else jax.named_scope(
+            "attn.full" if self.window is None else "attn.window"
+        )
+        with kind:
+            attn, new_k, new_v = self._write_and_attend(
+                q, k, v, cache_k, cache_v, write_index, kv_len, block_tables, layer_index, edge
+            )
+        attn = attn.reshape(b, t, h * dh)
+        if cfg.attention_gate:
+            with jax.named_scope("attn.gate"):
+                gate = proj(h * dh, "out", name="g", use_bias=False)(y)
+                attn = attn * jax.nn.sigmoid(gate)
+        # the row-parallel matmuls end in an all-reduce over the model axis:
+        # the scope names it in a compiled program and in a device trace
+        with jax.named_scope(TP_SCOPES["attn_out"]):
+            branch = proj(cfg.dim, "in", name="o", use_bias=False)(attn)
+            if cfg.sandwich_norm:
+                branch = RMSNorm(eps=cfg.rms_eps, name="post_attn_norm")(branch)
+            x = _residual(cfg, x, branch)
+        ffn = _ffn_half(cfg, x, proj, self.dtype, self.param_dtype, dense_ffn=self.dense_ffn)
+        return ffn, new_k, new_v
 
+    def _write_and_attend(self, q, k, v, cache_k, cache_v, write_index, kv_len, block_tables, layer_index, edge):
+        """This chunk's K/V written into the cache, then attention over it
+        (``edge``: the layer's window, if it has one). Returns (attn ``[B, T, H,
+        Dh]``-shaped values as the branches leave them, new_k, new_v)."""
         from cosmos_curate_tpu.ops.paged_attention import (
             paged_attention,
             paged_head_attention,
             reference_attention,
         )
 
+        cfg = self.cfg
+        b, t, h, dh = q.shape
+        hk = cfg.n_kv_heads
         group = h // hk
         if block_tables is not None:
             # paged path: scatter this chunk's K/V through the block table
@@ -1108,6 +1304,8 @@ class DecoderLayer(nn.Module):
             from cosmos_curate_tpu.parallel.axes import MODEL
 
             head_parallel = self.mesh is not None and MODEL in self.mesh.axis_names
+            if head_parallel and edge:
+                raise ValueError("window layers are not served over a mesh")
             if head_parallel:
                 new_k, new_v = paged_head_update(
                     self.mesh, cache_k, cache_v, k, v, block_tables, write_index,
@@ -1127,31 +1325,25 @@ class DecoderLayer(nn.Module):
             else:
                 attn = paged_attention(
                     qk, new_k, new_v, block_tables, write_index, kv_len,
-                    layer_index=layer_index, sm_scale=cfg.attention_multiplier,
+                    layer_index=layer_index, sm_scale=cfg.attention_multiplier, **edge,
                 )
-            attn = attn.astype(self.dtype)
-        else:
-            # scatter this chunk into the cache at each row's write_index
-            def write_row(cache, chunk, idx):
-                return jax.lax.dynamic_update_slice(cache, chunk, (0, idx, 0))
+            return attn.astype(self.dtype), new_k, new_v
 
-            new_k = jax.vmap(write_row)(
-                cache_k, k.astype(cache_k.dtype).swapaxes(1, 2), write_index
-            )
-            new_v = jax.vmap(write_row)(
-                cache_v, v.astype(cache_v.dtype).swapaxes(1, 2), write_index
-            )
+        # scatter this chunk into the cache at each row's write_index
+        def write_row(cache, chunk, idx):
+            return jax.lax.dynamic_update_slice(cache, chunk, (0, idx, 0))
 
-            attn = reference_attention(
-                q.reshape(b, t, hk, group, dh), new_k, new_v, write_index, kv_len,
-                sm_scale=cfg.attention_multiplier or dh**-0.5,
-            )
-        attn = attn.reshape(b, t, h * dh)
-        # the row-parallel matmuls end in an all-reduce over the model axis:
-        # the scope names it in a compiled program and in a device trace
-        with jax.named_scope(TP_SCOPES["attn_out"]):
-            x = _residual(cfg, x, proj(cfg.dim, "in", name="o", use_bias=False)(attn))
-        return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), new_k, new_v
+        new_k = jax.vmap(write_row)(
+            cache_k, k.astype(cache_k.dtype).swapaxes(1, 2), write_index
+        )
+        new_v = jax.vmap(write_row)(
+            cache_v, v.astype(cache_v.dtype).swapaxes(1, 2), write_index
+        )
+        attn = reference_attention(
+            q.reshape(b, t, hk, group, dh), new_k, new_v, write_index, kv_len,
+            sm_scale=cfg.attention_multiplier or dh**-0.5, **edge,
+        )
+        return attn, new_k, new_v
 
 
 def _residual(cfg: VLMConfig, x, branch):
@@ -1164,14 +1356,18 @@ def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype, dense_ffn=False):
     inside a layer's compact method, so the submodules are that layer's.
     ``dense_ffn``: a sparse model's leading layer that keeps the dense SwiGLU."""
     y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
+
+    def branch(out):  # the sandwich's second slice of bread, where a flavor has it
+        return RMSNorm(eps=cfg.rms_eps, name="post_mlp_norm")(out) if cfg.sandwich_norm else out
+
     if cfg.moe is not None and not dense_ffn:
         moe = MoEFFN(cfg, dtype=dtype, param_dtype=param_dtype, name="moe")
-        return _residual(cfg, x, moe(y))
+        return _residual(cfg, x, branch(moe(y)))
     up = proj(int(cfg.dim * cfg.hidden_mult), "out", name="up", use_bias=False)(y)
     gate = proj(int(cfg.dim * cfg.hidden_mult), "out", name="gate", use_bias=False)(y)
     with jax.named_scope(TP_SCOPES["mlp_down"]):
         down = proj(cfg.dim, "in", name="down", use_bias=False)(nn.silu(gate) * up)
-    return _residual(cfg, x, down)
+    return _residual(cfg, x, branch(down))
 
 
 class LatentAttentionLayer(nn.Module):
@@ -1323,14 +1519,18 @@ class VLM(nn.Module):
             embedding_init=nn.with_partitioning(nn.initializers.normal(0.02), (None, MODEL_AXIS)),
         )
         def attention_layer(i):
-            if cfg.mla is None:
-                return DecoderLayer(
-                    cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
-                    name=f"layer_{i}",
+            dense_ffn = cfg.moe is not None and i < cfg.moe.first_dense
+            if cfg.mla is not None:
+                return LatentAttentionLayer(
+                    cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}",
+                    dense_ffn=dense_ffn,
                 )
-            return LatentAttentionLayer(
-                cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}",
-                dense_ffn=cfg.moe is not None and i < cfg.moe.first_dense,
+            # where window and full layers mix each is told its kind; for every
+            # other flavor these are the defaults
+            return DecoderLayer(
+                cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
+                name=f"layer_{i}", window=cfg.sliding_window if i in cfg.window_layers else None,
+                use_rope=cfg.rope_in_layer(i), dense_ffn=dense_ffn,
             )
 
         self.layers = [
@@ -1510,6 +1710,12 @@ class VLM(nn.Module):
                 (store_ssm, store_rows) if in_place
                 else (store_ssm[:, store_rows], jnp.arange(x.shape[0], dtype=jnp.int32))
             )
+        # window and full layers mixed, paged: ``cache_k`` / ``cache_v`` /
+        # ``block_tables`` are pairs, (the pool every flavor has, the window
+        # pool), each with its own table; a layer's index counts its own kind
+        two_pools = paged and bool(cfg.window_layers)
+        if two_pools:
+            pools = {False: [cache_k[0], cache_v[0], block_tables[0], 0], True: [cache_k[1], cache_v[1], block_tables[1], 0]}
         kv_i = ssm_i = 0  # a layer's index in the KV caches / the recurrent store
         for i, layer in enumerate(self.layers):
             if i in ssm_layers:
@@ -1518,6 +1724,13 @@ class VLM(nn.Module):
                 )
                 new_tails.append(tail)
                 ssm_i += 1
+            elif two_pools:
+                own = pools[i in cfg.window_layers]
+                x, own[0], own[1] = layer(
+                    x, own[0], own[1], positions, write_index, kv_len,
+                    block_tables=own[2], layer_index=own[3],
+                )
+                own[3] += 1
             elif paged:
                 x, cache_k, cache_v = layer(
                     x, cache_k, cache_v, positions, write_index, kv_len,
@@ -1532,6 +1745,8 @@ class VLM(nn.Module):
             if i < n_ds:
                 x = x + deepstack[i].astype(x.dtype)
         logits = self._logits(x, logits_at)
+        if two_pools:
+            cache_k, cache_v = (pools[False][0], pools[True][0]), (pools[False][1], pools[True][1])
         if not paged:
             cache_k, cache_v = jnp.stack(new_ks), jnp.stack(new_vs)
         out = (logits, cache_k, cache_v)

@@ -158,7 +158,9 @@ class BlockAllocator:
         return self._refs[block_id]
 
 
-def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sharding=None):
+def init_block_pool(
+    cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sharding=None, n_layers: int | None = None
+):
     """The K and V block pools: ``[L, n_blocks, Hkv / r, block_size, r * Dh]``,
     ``L`` the layers that hold K/V (a hybrid's state-space layers keep their
     state in the engine's recurrent store instead,
@@ -167,10 +169,14 @@ def init_block_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16, sha
     accepts as a kernel block). ``r`` KV heads share a row where that makes
     the row one whole 128-lane tile (``Dh`` 64: two; the module docstring
     has why), judged on the heads ONE chip holds; else ``r`` = 1.
-    ``sharding`` creates them already placed (head planes over a mesh)."""
+    ``sharding`` creates them already placed (head planes over a mesh).
+    ``n_layers``: a flavor that mixes window and full attention layers keeps
+    two such pools, one a kind, each with the layers of its kind (the engine's
+    module docstring); None = every layer that holds K/V."""
     from cosmos_curate_tpu.ops.paged_attention import heads_per_row
 
-    shape = (len(cfg.kv_layers), n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
+    layers = len(cfg.kv_layers) if n_layers is None else n_layers
+    shape = (layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     held = cfg.n_kv_heads if sharding is None else sharding.shard_shape(shape)[2]
     r = heads_per_row(held, cfg.head_dim)
     shape = (*shape[:2], cfg.n_kv_heads // r, block_size, r * cfg.head_dim)

@@ -62,6 +62,19 @@ programs are byte for byte what they were. The XLA reference splits the
 pages it gathered back into head planes (:func:`split_rows`) and runs the
 same lines on the same shapes as ever.
 
+**A window** (``window=W``, the sliding-attention layers of a decoder that
+mixes them with full layers): query ``i`` sees keys ``j`` with ``i - W < j <=
+i``. The mathematics is one more comparison in ``reference_attention``. The
+kernels also stop doing work for what it masks: a decode row's walk starts at
+the group of pages that holds position ``kv_len - W`` (its first group and its
+trip count from ``kv_len``; no copy for a page wholly behind the window, the
+partial first page masked), and the prefill kernel's grid spans only the pages
+a block of queries can see (``W + block_q`` positions of them, whatever the
+table spans; the edge pages masked). A table entry still covers positions ``[j
+* bs, (j + 1) * bs)``: a window layer's table may name the same physical block
+at entries a whole ring apart (``models/vlm/engine.py``: the window pool), and
+nothing here knows. ``window=None`` traces exactly what it traced before.
+
 Both ways of reaching a page, the decode kernel's copy of ``pool[layer,
 block]`` and a ``BlockSpec`` ``(None, None, ..., bs, W)`` of the pool, pin
 the pool operand to the row-major layout with ``(bs, W)`` tiled. Whatever
@@ -163,7 +176,7 @@ def _outputs_by_head(out: jax.Array, r: int) -> jax.Array:
     return jnp.stack([out[:, :, :, j, :, j] for j in range(r)], axis=3).reshape(b, t, hp * r, g, d)
 
 
-def reference_attention(q, k, v, write_index, kv_len, *, sm_scale):
+def reference_attention(q, k, v, write_index, kv_len, *, sm_scale, window=None):
     """The XLA attention every kernel here is held to, and the one
     ``DecoderLayer``'s slot-cache branch runs. q: ``[B, T, Hkv, G, D]``
     unscaled grouped queries; k, v: the rows' K/V ``[B, Hkv, S, D]`` with
@@ -171,7 +184,8 @@ def reference_attention(q, k, v, write_index, kv_len, *, sm_scale):
     grouped against the KV's ``Hkv`` (no ``jnp.repeat``: the bytes read are
     the true KV size). Causality is over cache order (``write_index`` +
     chunk offset): under m-rope the rope positions are not monotone in it.
-    Returns ``[B, T, Hkv, G, D]`` in q's dtype."""
+    ``window``: a query sees the ``window`` newest positions up to its own and
+    nothing older. Returns ``[B, T, Hkv, G, D]`` in q's dtype."""
     t, s = q.shape[1], k.shape[2]
     qg = q * sm_scale
     logits = jnp.einsum(
@@ -181,12 +195,15 @@ def reference_attention(q, k, v, write_index, kv_len, *, sm_scale):
     q_seq = write_index[:, None] + jnp.arange(t)[None, :]  # [B, T]
     causal = k_pos <= q_seq[:, None, None, :, None]
     written = k_pos < kv_len[:, None, None, None, None]
-    logits = jnp.where(causal & written, logits, _NEG_INF)
+    seen = causal & written
+    if window is not None:
+        seen &= k_pos > q_seq[:, None, None, :, None] - window
+    logits = jnp.where(seen, logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bkgts,bksd->btkgd", probs.astype(q.dtype), v)
 
 
-def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale):
+def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale, window=None):
     """``reference_attention`` over the rows' pages, gathered for the
     einsum (no scatter-back): the same primitives on the same shapes as the
     slot-cache branch, so CPU outputs are bit-equal to the gather programs."""
@@ -197,7 +214,7 @@ def _paged_reference(q, pool_k, pool_v, tables, write_index, kv_len, *, layer_in
     # first) -> the slot-row view [B, Hkv, S, D]
     k = split_rows(pool_k[layer_index][tables], r).swapaxes(1, 2).reshape(b, hk, s, d)
     v = split_rows(pool_v[layer_index][tables], r).swapaxes(1, 2).reshape(b, hk, s, d)
-    return reference_attention(q, k, v, write_index, kv_len, sm_scale=sm_scale)
+    return reference_attention(q, k, v, write_index, kv_len, sm_scale=sm_scale, window=window)
 
 
 def _decode_pages(bs: int, hk: int, d: int, dtype, nbl: int) -> int:
@@ -212,16 +229,18 @@ def _decode_pages(bs: int, hk: int, d: int, dtype, nbl: int) -> int:
     return min(pages, nbl)
 
 
-def _attend_group(q, k, v, k_start, kv_len, acc, m_prev, l_prev):
+def _attend_group(q, k, v, k_start, kv_len, acc, m_prev, l_prev, first_key=None):
     """One online-softmax step of one KV head over a group of keys, all in
     float32. q: ``[g_pad, D]``, scaled; k, v: ``[N, D]`` of the pool's
     dtype, the first of them at logical position ``k_start``; keys at or
-    past ``kv_len`` are masked by position. Returns the new state."""
+    past ``kv_len`` (and, under a window, before ``first_key``) are masked by
+    position. Returns the new state."""
     s = jax.lax.dot_general(
         q, k.astype(jnp.float32), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [g_pad, N]
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(k_pos < kv_len, s, _NEG_INF)
+    seen = k_pos < kv_len if first_key is None else (k_pos < kv_len) & (k_pos >= first_key)
+    s = jnp.where(seen, s, _NEG_INF)
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
@@ -234,23 +253,30 @@ def _attend_group(q, k, v, k_start, kv_len, acc, m_prev, l_prev):
 
 def _paged_decode_kernel(
     layer_ref, kvlen_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-    *, sm_scale, bs, pages,
+    *, sm_scale, bs, pages, window=None,
 ):
     """One grid step is one row with all of its KV heads: a page's
     ``[Hkv, bs, D]`` is contiguous in the pool, so one copy a page serves
     every head. The loop walks the row's OWN table in groups of ``pages``
     entries, as far as its valid length and no further; group ``i + 1`` is
-    in flight while group ``i`` is computed."""
+    in flight while group ``i`` is computed. Under a ``window`` the walk
+    starts at the group that holds the row's oldest visible key, and no page
+    wholly before that key is copied."""
     b = pl.program_id(0)
     layer, kv_len = layer_ref[0], kvlen_ref[b]
     hk, g_pad, d = q_ref.shape
     group = pages * bs
     n_groups = pl.cdiv(kv_len, group)
+    if window is not None:
+        first_key = jnp.maximum(kv_len - window, 0)  # the one query is at kv_len - 1
+        first_group = first_key // group
+    edge = {} if window is None else {"first_key": first_key}
 
     def each_live_page(i, slot, act):
         # no copy for a table entry at or past the valid length: its block
         # id is garbage (the engine's block 0, or anything)
         first = i * pages
+        behind = 0 if window is None else jnp.clip(first_key // bs - first, 0, pages)
 
         def page(p, carry):
             block = tbl_ref[b, first + p]
@@ -259,7 +285,7 @@ def _paged_decode_kernel(
                 act(pltpu.make_async_copy(pool.at[layer, block], buf.at[slot, :, rows], sem))
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(pl.cdiv(kv_len, bs) - first, 0, pages), page, 0)
+        jax.lax.fori_loop(behind, jnp.clip(pl.cdiv(kv_len, bs) - first, 0, pages), page, 0)
 
     # a dead page's rows of a V buffer are multiplied by p = 0 and have to
     # be finite for that: the scratch starts as zeros, and a later row finds
@@ -269,20 +295,23 @@ def _paged_decode_kernel(
     def _():
         v_buf[...] = jnp.zeros_like(v_buf)
 
-    each_live_page(0, 0, lambda copy: copy.start())
+    each_live_page(0 if window is None else first_group, 0, lambda copy: copy.start())
     q = q_ref[...].astype(jnp.float32) * sm_scale  # [hk, g_pad, d]
 
     def two_groups(pair, state):
         # two groups an iteration, so that each names its buffer statically.
         # A group past the row's last has no live page: no copy, no wait,
         # and its keys, all masked, leave the state as it was (p = 0,
-        # alpha = 1); group 0 holds a valid key whenever the loop runs.
+        # alpha = 1); the first group walked (group 0, or the one with the
+        # window's oldest key) holds a valid key whenever the loop runs.
         for slot in (0, 1):
             i = 2 * pair + slot
+            if window is not None:
+                i = first_group + i
             each_live_page(i + 1, 1 - slot, lambda copy: copy.start())
             each_live_page(i, slot, lambda copy: copy.wait())
             state = tuple(
-                _attend_group(q[h], k_buf[slot, h], v_buf[slot, h], i * group, kv_len, *state[h])
+                _attend_group(q[h], k_buf[slot, h], v_buf[slot, h], i * group, kv_len, *state[h], **edge)
                 for h in range(hk)
             )
         return state
@@ -292,13 +321,14 @@ def _paged_decode_kernel(
         jnp.full((g_pad, 1), _NEG_INF, jnp.float32),
         jnp.zeros((g_pad, 1), jnp.float32),
     )
-    state = jax.lax.fori_loop(0, pl.cdiv(n_groups, 2), two_groups, (init,) * hk)
+    walked = n_groups if window is None else n_groups - first_group
+    state = jax.lax.fori_loop(0, pl.cdiv(walked, 2), two_groups, (init,) * hk)
     for h, (acc, _, l) in enumerate(state):
         o_ref[h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_decode_blockspec_kernel(
-    layer_ref, kvlen_ref, tbl_ref, q_ref, *refs, sm_scale, bs, pages
+    layer_ref, kvlen_ref, tbl_ref, q_ref, *refs, sm_scale, bs, pages, window=None
 ):
     """The same grouping where the kernel cannot copy for itself (see
     ``_paged_decode``): grid ``(row, group)``, the group's ``pages`` pages
@@ -322,14 +352,20 @@ def _paged_decode_blockspec_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(i * pages * bs < kv_len)
+    live = i * pages * bs < kv_len
+    edge = {}
+    if window is not None:  # a group wholly behind the window costs a grid step too
+        edge["first_key"] = jnp.maximum(kv_len - window, 0)
+        live &= (i + 1) * pages * bs > edge["first_key"]
+
+    @pl.when(live)
     def _step():
         for h in range(hk):
             k = jnp.concatenate([ref[h] for ref in k_refs], axis=0)  # [pages * bs, d]
             v = jnp.concatenate([ref[h] for ref in v_refs], axis=0)
             q = q_ref[h].astype(jnp.float32) * sm_scale
             acc_ref[h], m_ref[h], l_ref[h] = _attend_group(
-                q, k, v, i * pages * bs, kv_len, acc_ref[h], m_ref[h], l_ref[h]
+                q, k, v, i * pages * bs, kv_len, acc_ref[h], m_ref[h], l_ref[h], **edge
             )
 
     @pl.when(i == pl.num_programs(1) - 1)
@@ -353,6 +389,7 @@ def _paged_prefill_kernel(
     block_q,
     bs,
     g,
+    window=None,
 ):
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -368,6 +405,10 @@ def _paged_prefill_kernel(
     write = write_ref[b]
     kv_len = kvlen_ref[b]
     k_start = ji * bs
+    if window is not None:
+        # the grid's last dimension counts pages from the first one this block
+        # of queries can see (`_window_first_page`, the K/V index map alike)
+        k_start = k_start + _window_first_page(write, qi, block_q, bs, window) * bs
     rows = g * block_q
     last_pos = write + qi * block_q + block_q - 1
 
@@ -386,6 +427,8 @@ def _paged_prefill_kernel(
         q_pos = write + qi * block_q + t_local
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
         ok = (k_pos <= q_pos) & (k_pos < kv_len)
+        if window is not None:
+            ok &= k_pos > q_pos - window
         s = jnp.where(ok, s, _NEG_INF)
 
         m_prev = m_ref[:, :1]
@@ -407,8 +450,14 @@ def _paged_prefill_kernel(
         o_ref[...] = out.reshape(g, block_q, o_ref.shape[-1]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, interpret):
+def _window_first_page(write, qi, block_q: int, bs: int, window: int):
+    """The page that holds the oldest key the first query of block ``qi`` of a
+    chunk written at ``write`` can see under ``window``."""
+    return jnp.maximum(write + qi * block_q - window + 1, 0) // bs
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "window"))
+def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, interpret, window=None):
     """q: [B, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]
     (against a pool of ``r`` heads a row these are ``Hkv / r``, ``r * G``
     and ``r * D``: ``paged_attention``).
@@ -428,6 +477,8 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
         # the operand is the pool as the write left it, row-major with
         # (bs, D) tiled
         kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale, bs=bs, pages=pages)
+        if window is not None:
+            kernel = functools.partial(kernel, window=window)
         grid = (b,)
         pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         group_buffer = pltpu.VMEM((2, hk, pages * bs, d), pool_k.dtype)
@@ -443,12 +494,17 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
         kernel = functools.partial(
             _paged_decode_blockspec_kernel, sm_scale=sm_scale, bs=bs, pages=pages
         )
+        if window is not None:
+            kernel = functools.partial(kernel, window=window)
         grid = (b, pl.cdiv(nbl, pages))
 
         def page_spec(p):
             def index(b_, i, layer, kvlen, tbl):
                 last_live = jnp.maximum(kvlen[b_] - 1, 0) // bs
-                return layer[0], tbl[b_, jnp.minimum(i * pages + p, last_live)], 0, 0, 0
+                entry = jnp.minimum(i * pages + p, last_live)
+                if window is not None:  # ...nor one wholly behind the window
+                    entry = jnp.maximum(entry, jnp.maximum(kvlen[b_] - window, 0) // bs)
+                return layer[0], tbl[b_, entry], 0, 0, 0
 
             return pl.BlockSpec((None, None, hk, bs, d), index)
 
@@ -482,10 +538,11 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
 
 
 @functools.partial(
-    jax.jit, static_argnames=("layer_index", "sm_scale", "block_q", "interpret")
+    jax.jit, static_argnames=("layer_index", "sm_scale", "block_q", "interpret", "window")
 )
 def _paged_prefill(
-    q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale, block_q, interpret
+    q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale, block_q, interpret,
+    window=None,
 ):
     """q: [B, T, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]."""
     b, t, hk, g, d = q.shape
@@ -507,6 +564,18 @@ def _paged_prefill(
         (None, None, None, bs, d),
         lambda b_, h, qi, ji, write, kvlen, tbl: (layer_index, tbl[b_, ji], h, 0, 0),
     )
+    if window is not None:
+        # a block of queries sees at most `window + block_q - 1` positions: the
+        # grid spans the pages that can hold them (one more where they straddle
+        # a page's edge), counted from the first it can see, not the table
+        kernel = functools.partial(kernel, window=window)
+        grid = (*grid[:3], min(nbl, pl.cdiv(window + block_q - 1, bs) + 1))
+
+        def window_page(b_, h, qi, ji, write, kvlen, tbl):
+            entry = _window_first_page(write[b_], qi, block_q, bs, window) + ji
+            return layer_index, tbl[b_, jnp.minimum(entry, nbl - 1)], h, 0, 0
+
+        kv_spec = pl.BlockSpec((None, None, None, bs, d), window_page)
     q_spec = pl.BlockSpec(
         (None, None, g, block_q, d),
         lambda b_, h, qi, ji, write, kvlen, tbl: (b_, h, 0, qi, 0),
@@ -550,6 +619,7 @@ def paged_attention(
     block_q: int = 128,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Attention straight out of the paged KV pool, no gathered working set.
 
@@ -560,7 +630,8 @@ def paged_attention(
     ``sm_scale`` defaults from the true ``D``) with the chunk's K/V already
     written through the table; tables: ``[B, nbl]`` logical-to-physical
     block ids; write_index/kv_len: ``[B]``. Serves both decode (T=1) and
-    chunked prefill (T>1). Returns ``[B, T, Hkv, G, D]``.
+    chunked prefill (T>1). ``window``: a sliding-attention layer's (the module
+    docstring); None = every earlier position. Returns ``[B, T, Hkv, G, D]``.
 
     ``use_kernel=None`` means the Pallas kernels on a TPU and the XLA
     reference (:func:`reference_attention` over the gathered pages) elsewhere.
@@ -569,10 +640,11 @@ def paged_attention(
         sm_scale = q.shape[-1] ** -0.5
     if use_kernel is None:
         use_kernel = jax.devices()[0].platform == "tpu"
+    edge = {} if window is None else {"window": int(window)}
     if not use_kernel:
         return _paged_reference(
             q, pool_k, pool_v, tables, write_index, kv_len,
-            layer_index=layer_index, sm_scale=sm_scale,
+            layer_index=layer_index, sm_scale=sm_scale, **edge,
         )
     if interpret is None:
         interpret = jax.devices()[0].platform == "cpu"
@@ -581,12 +653,13 @@ def paged_attention(
     if q.shape[1] == 1:
         out = _paged_decode(
             q[:, 0], pool_k, pool_v, tables, kv_len,
-            layer_index=layer_index, sm_scale=sm_scale, interpret=interpret,
+            layer_index=layer_index, sm_scale=sm_scale, interpret=interpret, **edge,
         )[:, None]
     else:
         out = _paged_prefill(
             q, pool_k, pool_v, tables, write_index, kv_len,
             layer_index=layer_index, sm_scale=sm_scale, block_q=block_q, interpret=interpret,
+            **edge,
         )
     return _outputs_by_head(out, r)
 

@@ -69,7 +69,11 @@ def pytest_collection_modifyitems(items):
     (PERF.md section 7); every other assertion of the test holds for the new
     configuration (tests/perfbench/test_deepseek_cell.py repeats them)."""
     for item in items:
-        if item.nodeid.endswith("test_catalog.py::test_config_file[deepseek-v2-ep8]"):
+        # (Trinity, PR 38, cuts depth too: tests/perfbench/test_trinity_cell.py repeats them)
+        if item.nodeid.endswith((
+            "test_catalog.py::test_config_file[deepseek-v2-ep8]",
+            "test_catalog.py::test_config_file[trinity-large-ep8]",
+        )):
             item.add_marker(pytest.mark.xfail(
                 reason="test_catalog's WIDTH_KEYS matches 'hidden' in num_hidden_layers, a depth", strict=False,
             ))

@@ -311,6 +311,144 @@ class TestInterpretKernel:
 PACKED_WIDTHS = {name: WIDTHS[name] for name in ("base", "granite-4.0-h-micro")}
 
 
+class TestWindow:
+    """``window=W``: query ``i`` sees keys ``i - W < j <= i``. The reference
+    against a dense oracle with the same mask; the kernels (both decode forms,
+    and prefill) against the reference at the window's edges and at page edges;
+    and no page wholly behind the window is fetched."""
+
+    @staticmethod
+    def _dense(q, k_cache, v_cache, write, kv_len, sm_scale, window):
+        s = k_cache.shape[2]
+        logits = jnp.einsum("btkgd,bksd->bkgts", q.astype(jnp.float32) * sm_scale, k_cache.astype(jnp.float32))
+        k_pos = jnp.arange(s)[None, None, None, None, :]
+        q_pos = (write[:, None] + jnp.arange(q.shape[1])[None, :])[:, None, None, :, None]
+        seen = (k_pos <= q_pos) & (k_pos > q_pos - window) & (k_pos < kv_len[:, None, None, None, None])
+        probs = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1)
+        return jnp.einsum("bkgts,bksd->btkgd", probs, v_cache.astype(jnp.float32))
+
+    @pytest.mark.parametrize("t", [1, 24], ids=["decode", "chunk"])
+    def test_reference_matches_a_dense_oracle(self, t):
+        rng = np.random.default_rng(5)
+        b, hk, g, d, nbl, bs, window = 3, 2, 3, 16, 8, 16, 40
+        q, pk, pv, tables, layer, kc, vc = _fragmented_case(
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        kv_len = jnp.asarray([30, 41, 128], jnp.int32)
+        write = kv_len - t
+        got = paged_attention(q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False, window=window)
+        want = self._dense(q, kc, vc, write, kv_len, d**-0.5, window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+        # and a window no shorter than the context is no window
+        free = paged_attention(q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False)
+        wide = paged_attention(q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False, window=128)
+        np.testing.assert_allclose(np.asarray(wide), np.asarray(free), atol=1e-6)
+
+    # W = 40 over pages of 16, groups of 8 pages (128 keys): the edges W - 1, W,
+    # W + 1; a first key on a page's first and last slot; a first group past 0
+    @pytest.mark.parametrize(
+        "hk,g,d", [(2, 4, 128), (2, 2, 16), (4, 2, 64)], ids=["own-copies", "pipeline-d16", "d64-packed"]
+    )
+    @pytest.mark.parametrize(
+        "window,kv_len",
+        [
+            pytest.param(40, [39, 40, 41], id="edges-of-the-window"),
+            pytest.param(40, [56, 55, 57], id="first-key-at-page-edges"),
+            pytest.param(40, [1, 300, 169], id="idle-row-and-later-groups"),
+            pytest.param(130, [320, 131, 258], id="window-over-a-group"),
+            pytest.param(16, [16, 17, 32], id="window-of-one-page"),
+        ],
+    )
+    def test_decode_kernels_match_the_reference_and_skip_what_is_behind(self, hk, g, d, window, kv_len):
+        rng = np.random.default_rng(9)
+        b, nbl, bs = 3, 20, 16
+        q, pk, pv, tables, layer, _, _ = _fragmented_case(
+            rng, b=b, t=1, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        r = heads_per_row(hk, d)
+        pk, pv = join_rows(pk, r), join_rows(pv, r)
+        kv_len = jnp.asarray(kv_len, jnp.int32)
+        want = paged_attention(
+            q, pk, pv, tables, kv_len - 1, kv_len, layer_index=layer, use_kernel=False, window=window
+        )
+        # every entry wholly behind a row's window, or at or past its length,
+        # names a block of NaN: the kernels fetch none of them
+        page = np.arange(nbl)[None, :]
+        lens = np.asarray(kv_len)[:, None]
+        dead = (page * bs >= lens) | ((page + 1) * bs <= np.maximum(lens - window, 0))
+        assert dead.any() and 0 not in np.asarray(tables)
+        pk, pv = pk.at[:, 0].set(jnp.nan), pv.at[:, 0].set(jnp.nan)
+        got = paged_attention(
+            q, pk, pv, jnp.where(dead, 0, tables), kv_len - 1, kv_len,
+            layer_index=layer, use_kernel=True, interpret=True, window=window,
+        )
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize(
+        "window,t,write,t_valid",
+        [
+            pytest.param(40, 16, [24, 25, 23], [16, 16, 16], id="edges-of-the-window"),
+            pytest.param(40, 32, [0, 64, 100], [32, 32, 20], id="from-zero-later-and-padded"),
+            pytest.param(16, 24, [8, 40, 96], [24, 24, 24], id="window-under-the-chunk"),
+            pytest.param(200, 16, [0, 90, 112], [16, 16, 16], id="window-over-the-context"),
+        ],
+    )
+    def test_prefill_kernel_matches_the_reference_and_skips_what_is_behind(self, window, t, write, t_valid):
+        rng = np.random.default_rng(10)
+        b, hk, g, d, nbl, bs = 3, 2, 3, 16, 8, 16
+        q, pk, pv, tables, layer, _, _ = _fragmented_case(
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        write = jnp.asarray(write, jnp.int32)
+        kv_len = write + jnp.asarray(t_valid, jnp.int32)
+        want = paged_attention(
+            q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False, window=window
+        )
+        page = np.arange(nbl)[None, :]
+        dead = (page * bs >= np.asarray(kv_len)[:, None]) | (
+            (page + 1) * bs <= np.maximum(np.asarray(write)[:, None] - window + 1, 0)
+        )
+        pk, pv = pk.at[:, 0].set(1e6), pv.at[:, 0].set(-1e6)
+        got = paged_attention(
+            q, pk, pv, jnp.where(dead, 0, tables), write, kv_len,
+            layer_index=layer, use_kernel=True, interpret=True, block_q=8, window=window,
+        )
+        valid = np.arange(t)[None, :] < np.asarray(t_valid)[:, None]  # padding rows are nobody's
+        np.testing.assert_allclose(
+            np.asarray(got)[valid], np.asarray(want)[valid], atol=2e-5, rtol=1e-4
+        )
+
+    def test_a_table_that_repeats_a_ring_of_blocks_reads_the_newest(self):
+        """The engine's window table: logical block ``j`` in ring block ``j %
+        ring``. Under the window the kernels and the reference read only
+        entries whose ring block still holds their own positions."""
+        rng = np.random.default_rng(11)
+        hk, g, d, bs, ring, nbl, window = 2, 2, 16, 4, 6, 20, 10
+        kv_len = jnp.asarray([77], jnp.int32)
+        blocks = np.arange(1, ring + 1)
+        tables = jnp.asarray(np.resize(blocks, nbl)[None], jnp.int32)
+        # the ring as 77 positions written in order leave it, and the
+        # contiguous cache those positions would make
+        k_all = rng.standard_normal((nbl * bs, hk, d)).astype(np.float32)
+        v_all = rng.standard_normal((nbl * bs, hk, d)).astype(np.float32)
+        pk, pv = np.zeros((1, ring + 1, hk, bs, d), np.float32), np.zeros((1, ring + 1, hk, bs, d), np.float32)
+        for pos in range(77):
+            block, off = blocks[(pos // bs) % ring], pos % bs
+            pk[0, block, :, off], pv[0, block, :, off] = k_all[pos], v_all[pos]
+        q = jnp.asarray(rng.standard_normal((1, 1, hk, g, d)), jnp.float32)
+        want = self._dense(
+            q, jnp.asarray(k_all.transpose(1, 0, 2)[None]), jnp.asarray(v_all.transpose(1, 0, 2)[None]),
+            kv_len - 1, kv_len, d**-0.5, window,
+        )
+        for kernel in (False, True):
+            got = paged_attention(
+                q, jnp.asarray(pk), jnp.asarray(pv), tables, kv_len - 1, kv_len,
+                use_kernel=kernel, interpret=True, window=window,
+            )
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+
+
 class TestPackedPool:
     """A pool of ``r`` KV heads a row (``[L, NB, Hkv / r, bs, r * D]``, made by
     ``join_rows`` as ``init_block_pool`` shapes it) against the same K/V one

@@ -136,6 +136,40 @@ def test_paged_kernels_compile_at_the_widest_lane(v5e, kernel, rows, widths):
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
+@pytest.mark.parametrize(
+    "kernel,rows,lane",
+    [
+        pytest.param("paged_decode", 24, 12288, id="decode-24-rows-of-12288"),
+        pytest.param("paged_decode", 16, 4096, id="decode-16-rows-of-4096"),
+        pytest.param("paged_prefill", 4, 12288, id="prefill-4-rows-of-12288"),
+        pytest.param("paged_prefill", 1, 4096, id="prefill-1-row-of-4096"),
+    ],
+)
+@pytest.mark.parametrize("window", [4096, None], ids=["window-layer", "full-layer"])
+def test_windowed_kernels_compile_at_trinitys_widths(v5e, kernel, rows, lane, window):
+    """Trinity-Large's attention (8 KV heads x 6 query heads x 128) at its two
+    lanes, the first past 4,096 positions, in the blocks of 128 the engine takes
+    for such lanes: 96 table entries a row in scalar memory, a page of 128 keys
+    a copy, the window's first-page arithmetic in the index maps and loops."""
+
+    from cosmos_curate_tpu.ops.paged_attention import paged_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    from cosmos_curate_tpu.models.vlm.engine import default_block_size
+
+    hk, g, d, t = 8, 6, 128, 1 if kernel == "paged_decode" else T
+    bs = default_block_size(((4096, 16), (12288, 24)))
+    pool = arg((4, 100, hk, bs, d), jnp.bfloat16)
+    fn = functools.partial(paged_attention, layer_index=1, use_kernel=True, interpret=False, window=window)
+    args = (
+        arg((rows, t, hk, g, d), jnp.bfloat16), pool, pool, arg((rows, lane // bs), jnp.int32),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32),
+    )
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("kernel", ["paged_decode", "paged_prefill"])
 def test_custom_call_is_named_as_the_benchmark_expects(v5e, kernel):
     """A traced benchmark run finds the paged kernels among the device's
