@@ -139,12 +139,17 @@ def _assert_program_holds(name: str, lowered, *ops: str) -> None:
 # -- phase: kernels ----------------------------------------------------------
 
 
-def _paged_kernels_against_xla(what: str, hkv: int, group: int, head_dim: int, rng) -> None:
+def _paged_kernels_against_xla(
+    what: str, hkv: int, group: int, head_dim: int, rng, *, bs: int = 16,
+    cases=((1024, 8), (4096, 4)), window: int | None = None,
+) -> None:
     """The two paged kernels at one flavor's widths, out of a pool stored as
     the engine stores it (``heads_per_row`` KV heads a 128-lane row), against
-    the XLA reference over the same K/V one head a row: contexts 1k and 4k,
-    16-token pages, block tables fragmented so logical order never matches
-    pool order. One line each for decode and prefill."""
+    the XLA reference over the same K/V one head a row: ``cases`` of (context,
+    rows), pages of ``bs`` positions, block tables fragmented so logical order
+    never matches pool order; under a ``window`` the chunks' writes are spread
+    over the lane, so some lie inside it and some past it. One line each for
+    decode and prefill."""
     import functools
 
     import jax
@@ -158,9 +163,10 @@ def _paged_kernels_against_xla(what: str, hkv: int, group: int, head_dim: int, r
         paged_attention,
     )
 
-    bs, layers, layer, chunk = 16, 2, 1, 256
+    layers, layer, chunk = 2, 1, 256
     r = heads_per_row(hkv, head_dim)
-    for context, rows in ((1024, 8), (4096, 4)):
+    edge = {} if window is None else {"window": window}
+    for context, rows in cases:
         nbl = context // bs
         n_blocks = rows * nbl + 8  # block 0 is the engine's garbage block
         shape = (layers, n_blocks, hkv, bs, head_dim)
@@ -169,16 +175,19 @@ def _paged_kernels_against_xla(what: str, hkv: int, group: int, head_dim: int, r
         pool_k, pool_v = join_rows(plain_k, r), join_rows(plain_v, r)
         ids = rng.permutation(np.arange(1, n_blocks))[: rows * nbl]
         tables = jnp.asarray(ids.reshape(rows, nbl), jnp.int32)
-        where = f"{what} ({hkv} x {head_dim}, {r} a row) ctx {context}"
+        where = f"{what} ({hkv} x {head_dim}, {r} a row, blocks of {bs}, window {window}) ctx {context}"
+        reference = functools.partial(
+            _paged_reference, layer_index=layer, sm_scale=head_dim**-0.5, **edge
+        )
 
         # decode: one token per row at ragged valid lengths
         kv_len = jnp.asarray(rng.integers(context // 2, context + 1, rows), jnp.int32)
         q1 = jnp.asarray(rng.standard_normal((rows, 1, hkv, group, head_dim)), jnp.bfloat16)
         args = (tables, kv_len - 1, kv_len)
-        want = _paged_reference(q1, plain_k, plain_v, *args, layer_index=layer, sm_scale=head_dim**-0.5)
+        want = reference(q1, plain_k, plain_v, *args)
         paged = jax.jit(
             functools.partial(
-                paged_attention, layer_index=layer, use_kernel=True, interpret=False
+                paged_attention, layer_index=layer, use_kernel=True, interpret=False, **edge
             )
         )
         _assert_program_holds("paged decode", paged.lower(q1, pool_k, pool_v, *args), "tpu_custom_call")
@@ -186,22 +195,73 @@ def _paged_kernels_against_xla(what: str, hkv: int, group: int, head_dim: int, r
         log(f"kernels: paged_decode      {where} max_err {err:.4f}")
 
         # chunked prefill: a chunk written mid-context, causal inside it
-        write = jnp.asarray(rng.integers(0, context - chunk + 1, rows), jnp.int32)
+        if window is None:
+            write = rng.integers(0, context - chunk + 1, rows)
+        else:  # the first row inside the window, the last at the lane's end
+            write = np.linspace(window // 2, context - chunk, rows).astype(np.int64)
+        write = jnp.asarray(write, jnp.int32)
         qt = jnp.asarray(
             rng.standard_normal((rows, chunk, hkv, group, head_dim)), jnp.bfloat16
         )
         args = (tables, write, write + chunk)
-        want = _paged_reference(qt, plain_k, plain_v, *args, layer_index=layer, sm_scale=head_dim**-0.5)
+        want = reference(qt, plain_k, plain_v, *args)
         err = _assert_close(f"paged_prefill@{where}", paged(qt, pool_k, pool_v, *args), want)
         log(f"kernels: paged_prefill     {where} max_err {err:.4f}")
+
+
+def _prefill_call_ms(hkv: int, group: int, head_dim: int, rng, *, bs: int, lane: int, rows: int,
+                     write: int, window: int | None, reps: int = 16) -> float:
+    """Device milliseconds of ONE call of the paged prefill kernel over a
+    256-token chunk of ``rows`` rows written at ``write``: ``reps`` calls
+    chained inside one program (each call's queries depend on the call
+    before), the program's time over ``reps``, so the host's dispatch (0.7 ms
+    a call from here) stays out of it."""
+    import functools
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cosmos_curate_tpu.ops.paged_attention import paged_attention
+
+    chunk, nbl = 256, lane // bs
+    shape = (1, rows * nbl + 1, hkv, bs, head_dim)
+    pool_k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    pool_v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(np.arange(1, rows * nbl + 1)).reshape(rows, nbl), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((rows, chunk, hkv, group, head_dim)), jnp.bfloat16)
+    at = jnp.full((rows,), write, jnp.int32)
+    edge = {} if window is None else {"window": window}
+    attend = functools.partial(paged_attention, layer_index=0, use_kernel=True, interpret=False, **edge)
+
+    @jax.jit
+    def chain(q, pool_k, pool_v, tables, at):
+        out = attend(q, pool_k, pool_v, tables, at, at + chunk)
+        for _ in range(reps - 1):
+            out = attend(q + out * jnp.asarray(1e-6, q.dtype), pool_k, pool_v, tables, at, at + chunk)
+        return out
+
+    chain(q, pool_k, pool_v, tables, at).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(4):
+        out = chain(q, pool_k, pool_v, tables, at)
+    out.block_until_ready()
+    return (time.perf_counter() - t0) / 4 / reps * 1e3
 
 
 def phase_kernels(*, hkv: int = 2, group: int = 6, head_dim: int = 128, seed: int = 0) -> None:
     """Each kernel, compiled for this chip, against the XLA reference
     (``reference_attention`` over the gathered pages / the einsum lines of
     ``layers.Attention``) on the same chip: the paged kernels at Qwen2-VL-2B's
-    widths and at Granite-4.0-H-Micro's (64-wide heads, two a pool row), then
-    flash attention at the 2B's."""
+    widths, at Granite-4.0-H-Micro's (64-wide heads, two a pool row) and at
+    Trinity-Large's (8 x 6 x 128 in blocks of 128 over the 12,288 lane, full
+    and under the window of 4096, crossed), the prefill kernel's device time
+    a call at Trinity's shapes in blocks of 128 and of 16 and at the 2B's
+    (the microbenchmark of PERF.md PR 39; the kernel before it read 4.98 /
+    12.08 ms full and 4.20 / 4.37 under the window at 128, 30.5 / 74.2 and
+    26.3 / 27.4 at 16, and 0.51 ms at the 2B's), then flash attention at the
+    2B's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -211,6 +271,22 @@ def phase_kernels(*, hkv: int = 2, group: int = 6, head_dim: int = 128, seed: in
     rng = np.random.default_rng(seed)
     _paged_kernels_against_xla("qwen2vl-2b", hkv, group, head_dim, rng)
     _paged_kernels_against_xla("granite-4.0-h-micro", 8, 4, 64, rng)
+    for window in (None, 4096):
+        _paged_kernels_against_xla(
+            "trinity-large-ep8", 8, 6, 128, rng, bs=128, cases=((12288, 2),), window=window
+        )
+    for bs in (128, 16):
+        for window in (None, 4096):
+            ms = [
+                _prefill_call_ms(8, 6, 128, rng, bs=bs, lane=12288, rows=4, write=write, window=window)
+                for write in (3840, 12032)
+            ]
+            log(
+                f"kernels: paged_prefill     trinity-large-ep8 4 rows x 256, blocks of {bs}, window {window}: "
+                f"{ms[0]:.3f} ms a call at 4k context, {ms[1]:.3f} at 12k"
+            )
+    ms = _prefill_call_ms(hkv, group, head_dim, rng, bs=16, lane=4096, rows=1, write=1024, window=None)
+    log(f"kernels: paged_prefill     qwen2vl-2b 1 row x 256, blocks of 16: {ms:.3f} ms a call at 1.3k context")
 
     # encoder self-attention (layers.Attention above FLASH_MIN_SEQ); 2049 is
     # InternVideo2's 8x256+1 tokens — the ragged tail pads inside the op

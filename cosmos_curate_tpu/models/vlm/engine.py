@@ -543,11 +543,19 @@ def _handoff_works(mesh, put_sharding) -> bool:
 
 def default_block_size(kv_lanes) -> int:
     """Positions a KV block where the caller names none: 16, and 128 once a
-    lane passes 4,096 positions. The prefill kernel fetches one block of keys
-    a grid step, so a long row costs it a step a table entry; at 128 a
-    12,288-position row is 96 entries where it would be 768 (28.9 ms of
-    attention a 256-token chunk at 16, 5.05 at 128: PERF.md, PR 38). Every
-    lane of an engine shares the one size."""
+    lane passes 4,096 positions. THE RULE NOW OUTLIVES ITS REASON: it was
+    written when the prefill kernel fetched one block of keys a grid step, so
+    that a 12,288-position row cost it 768 steps a head a block of queries
+    at 16 and 96 at 128 (28.9 ms of attention a 256-token chunk a row
+    against 5.05: PERF.md, PR 38). Since PR 39 that kernel walks groups of a
+    row's own pages, and four rows' chunk at a 12k context takes it 5.09 ms
+    in blocks of 16 and 2.71 in blocks of 128 (74.2 and 12.1 before; one v5e,
+    PERF.md PR 39: at 16 the 8 KV heads' 4 KiB copies are what is left).
+    Taking the rule out moves Trinity's pools, its cell's `correct`
+    instruction and `engine.window_pool_gib`: the next `simplicity` PR's
+    (ROADMAP S4), with a page copy that serves all of a page's heads if the
+    factor of 1.9 is to go first. Every lane of an engine shares the one
+    size."""
     return 128 if max(int(length) for length, _ in kv_lanes) > 4096 else 16
 
 
@@ -784,6 +792,12 @@ class CaptionEngine:
         # the share of the table the kernel touches
         self._paged_decode_pages_walked = 0
         self._paged_decode_pages_spanned = 0
+        # ...and the prefill kernel's: the entries its loops walk (a block
+        # of queries from the page of its oldest visible key to the page of
+        # its newest, within the row's valid length) over blocks of queries
+        # x entries a row: what one page a grid step over the table stepped
+        self._paged_prefill_pages_walked = 0
+        self._paged_prefill_pages_spanned = 0
         # look-ahead accounting (under _stats_lock): rows of programs
         # dispatched ahead that were thrown away (the row ended in the token
         # before); the programs themselves are decode_dispatch's `ahead`
@@ -1641,6 +1655,8 @@ class CaptionEngine:
                 "decode_rows_discarded": self._decode_rows_discarded,
                 "paged_decode_pages_walked": self._paged_decode_pages_walked,
                 "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
+                "paged_prefill_pages_walked": self._paged_prefill_pages_walked,
+                "paged_prefill_pages_spanned": self._paged_prefill_pages_spanned,
                 "kv_gather_bytes_avoided": self._kv_gather_bytes_avoided,
                 "decode_attention_s": self._decode_time,
                 "decode_tokens": self.decode_tokens,
@@ -1782,6 +1798,8 @@ class CaptionEngine:
             self._decode_rows_discarded = 0
             self._paged_decode_pages_walked = 0
             self._paged_decode_pages_spanned = 0
+            self._paged_prefill_pages_walked = 0
+            self._paged_prefill_pages_spanned = 0
             self._kv_gather_bytes_avoided = 0
             self._kv_blocks_used_peak = self._allocator.used_blocks
             self._recurrent_rows_used_peak = (
@@ -2773,6 +2791,8 @@ class CaptionEngine:
     def _run_prefill(self, lane: _Lane, slots_arr, tables, embeds, write_index, t_valid, rope, ds):
         """One call of the prefill program over host arrays; a hybrid's
         recurrent store rides along and comes back with the pools."""
+        if self._use_paged and self.cfg.mla is None:
+            self._count_prefill_walk(np.asarray(write_index), np.asarray(t_valid), embeds.shape[1], tables.shape[1])
         tables = jnp.asarray(tables)
         if self._windowed:
             tables = (tables, jnp.asarray(lane.wtable[slots_arr]))
@@ -2789,6 +2809,26 @@ class CaptionEngine:
                 *args, self._ssm, self._conv, jnp.asarray(self._state_rows(lane, slots_arr))
             )
         return logits
+
+    def _count_prefill_walk(self, write_index, t_valid, t: int, nbl: int) -> None:
+        """Book what the paged prefill kernel's loops walk for one program
+        (``paged_prefill_pages_walked`` / ``_spanned``), by the host's
+        arithmetic; where there are two pools layer kind by layer kind, as
+        the decode kernel's are."""
+        from cosmos_curate_tpu.ops.paged_attention import prefill_pages_walked
+
+        walk = partial(
+            prefill_pages_walked, write_index, write_index + t_valid, t, self.block_size, self.model.dtype
+        )
+        walked, blocks = walk()
+        layers = 1
+        if self._windowed:
+            n_full, n_win = len(self.cfg.full_layers), len(self.cfg.window_layers)
+            walked = n_full * walked + n_win * walk(window=self.cfg.sliding_window)[0]
+            layers = n_full + n_win
+        with self._stats_lock:
+            self._paged_prefill_pages_walked += walked
+            self._paged_prefill_pages_spanned += layers * blocks * nbl
 
     def _pools(self) -> tuple:
         """(K, V) as the programs take them: the pool's two arrays, or where

@@ -34,9 +34,27 @@ and scattered it back. This op deletes that copy:
   us a call against 13-30 for the kernel's own (PR 27, one v5e), and at
   ``D`` = 64 it ran at 8% of its roofline behind a pool XLA stored twice
   over (PR 30: below);
-- **prefill skips by grid step**: one page a grid step, and blocks at or
-  after the row's valid length or beyond the chunk's last causal position
-  are skipped with ``pl.when`` (ROADMAP S4: what is left of it).
+- **prefill walks a block of queries' own pages, a group a loop trip**
+  (PR 39): one grid step a (row, KV head, block of ``block_q`` queries), the
+  pools in HBM as for decode, table, ``write_index``, ``kv_len`` and the layer
+  prefetched scalars. The loop walks the row's own table in groups of ``P``
+  entries from the first page the block's first query can see to the page
+  that holds ``min(kv_len, the block's last query + 1) - 1`` and no further:
+  the trip count comes from the row, the causal bound and the window, not
+  from the table's length. One asynchronous copy a live page a pool (this
+  head's ``[bs, D]`` of it) into one of two ``[P * bs, D]`` buffers, group
+  ``i + 1`` in flight while group ``i`` is computed, no copy for an entry
+  past the newest visible key or wholly behind the window (its block id is
+  garbage), no trip for a padding row (``kv_len`` 0). ``P`` comes from the
+  shapes (``_prefill_pages``): 1,024 keys, fewer where a block's float32
+  score tile would pass 3 MiB. q.K is fed to the MXU in the pool's own type
+  where that is bfloat16 (every product exact in the float32 it accumulates
+  in, ``sm_scale`` applied to the float32 scores); the softmax state and p.V
+  are the float32 lines they were. Where ``D`` makes no whole lane tile
+  (``D`` = 16, as above) the pipeline delivers one page a grid step over the
+  table instead (``_paged_prefill_blockspec_kernel``, the kernel every width
+  had before PR 39: a table entry that holds nothing visible still costs
+  its grid step there, 0.25 us, and a live one 2 us).
 
 **A pool row is a whole lane tile** (PR 34). The pool is ``[L, NB, Hp, bs,
 W]`` bfloat16 and the chip tiles its last two dimensions ``(16, 128)``. At
@@ -68,15 +86,20 @@ i``. The mathematics is one more comparison in ``reference_attention``. The
 kernels also stop doing work for what it masks: a decode row's walk starts at
 the group of pages that holds position ``kv_len - W`` (its first group and its
 trip count from ``kv_len``; no copy for a page wholly behind the window, the
-partial first page masked), and the prefill kernel's grid spans only the pages
-a block of queries can see (``W + block_q`` positions of them, whatever the
-table spans; the edge pages masked). A table entry still covers positions ``[j
+partial first page masked), and a block of queries' walk in the prefill
+kernel starts at the page of the oldest key its FIRST query can see
+(``_window_first_page``; groups are counted from that page, so none is
+copied or multiplied that lies wholly behind it, and the keys the block's
+later queries no longer see are masked); the ``BlockSpec`` form's grid spans
+the ``W + block_q`` positions a block can see, whatever the table spans. A
+table entry still covers positions ``[j
 * bs, (j + 1) * bs)``: a window layer's table may name the same physical block
 at entries a whole ring apart (``models/vlm/engine.py``: the window pool), and
 nothing here knows. ``window=None`` traces exactly what it traced before.
 
-Both ways of reaching a page, the decode kernel's copy of ``pool[layer,
-block]`` and a ``BlockSpec`` ``(None, None, ..., bs, W)`` of the pool, pin
+Every way of reaching a page, the decode kernel's copy of ``pool[layer,
+block]`` (all of a page's heads), the prefill kernel's of ``pool[layer, block,
+head]`` and a ``BlockSpec`` ``(None, None, ..., bs, W)`` of the pool, pins
 the pool operand to the row-major layout with ``(bs, W)`` tiled. Whatever
 produces the pool inside the same program must leave it in that layout, or
 XLA puts a relayout ``copy`` of the whole pool in front of every call: the
@@ -88,7 +111,7 @@ to that contract.
 
 Which implementation runs is decided here and nowhere else, from what the
 code can observe: on a TPU the Pallas kernels (the row's width ``% 128``
-picks the decode form, above), elsewhere ``reference_attention``, the plain XLA lines, over
+picks the form of each, above), elsewhere ``reference_attention``, the plain XLA lines, over
 the row's pages gathered for the einsum (never scattered back); NOT
 interpret-mode Pallas. ``DecoderLayer``'s slot-cache branch (the engine's
 ``gather`` programs) calls the same function, so the byte-identical parity
@@ -107,6 +130,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -115,6 +139,8 @@ from cosmos_curate_tpu.ops.tiling import round_up, sublanes
 _NEG_INF = -1e30
 _GROUP_BUFFER_BYTES = 128 * 1024  # one of the decode kernel's four page buffers
 _LANES = 128  # the minor dimension of a tile, whatever the type
+_PREFILL_GROUP_KEYS = 1024  # keys a trip of the prefill kernel's loop covers, where they fit
+_SCORE_TILE_BYTES = 3 * 1024 * 1024  # ...as a float32 score tile of a block of queries
 
 
 def heads_per_row(n_kv_heads: int, head_dim: int) -> int:
@@ -373,24 +399,132 @@ def _paged_decode_blockspec_kernel(
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _prefill_pages(bs: int, rows: int, nbl: int) -> int:
+    """Table entries a trip of the prefill kernel's loop covers (``P``), for a
+    block of ``rows`` query rows (``G * block_q``): 1,024 keys, because a
+    trip's cost is mostly fixed in the keys (the row maxima and sums across
+    lanes, the softmax state's and the accumulator's round trip, the copies'
+    issue: on one v5e at Trinity's widths a 12k context took 6.87 / 4.69 /
+    2.71 / 2.74 ms at 256 / 512 / 1,024 / 2,048 keys, PERF.md PR 39), and
+    fewer, in whole MXU widths of 128, where the float32 score tile ``[rows,
+    P * bs]`` would pass 3 MiB (the kernel holds a handful of arrays of that
+    shape, and a call's VMEM is 16 MiB); at least one page, never more than
+    the table holds."""
+    keys = min(_PREFILL_GROUP_KEYS, max(128, _SCORE_TILE_BYTES // (4 * rows) // 128 * 128))
+    return min(max(1, keys // bs), nbl)
+
+
 def _paged_prefill_kernel(
-    write_ref,
-    kvlen_ref,
-    tbl_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
-    sm_scale,
-    block_q,
-    bs,
-    g,
-    window=None,
+    layer_ref, write_ref, kvlen_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+    k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+    *, sm_scale, block_q, bs, g, pages, window=None,
 ):
+    """One grid step is one block of ``block_q`` queries of one row against
+    one KV head (one pool row). The loop walks the row's OWN table in groups
+    of ``pages`` entries from the first page the block's first query can see
+    (0, or the window's) to the page of the newest key its last query can
+    (``min(kv_len, last query + 1) - 1``), and no further: one copy a live
+    page a pool into one of two ``[pages * bs, D]`` buffers, group ``i + 1``
+    in flight while group ``i`` is computed, none for an entry past that
+    newest key or wholly behind the window. A padding row (``kv_len`` 0) runs
+    no trip. Every key of a group is masked by position (masking only the
+    groups a mask can bite in bought nothing on the chip). Where q and the
+    pool are bfloat16 q.K is fed to the MXU as it is stored, accumulated in
+    float32 (every product exact) with ``sm_scale`` applied to the float32
+    scores; anything else is multiplied in float32. p.V is the float32
+    product it always was. (What Mosaic makes of a float32 x float32
+    ``dot_general`` with no ``precision`` named is ONE bfloat16 pass, by a
+    probe on the chip, PERF.md PR 39: so the old kernel's q * sm_scale
+    reached the MXU rounded to bfloat16, and p does, then as now.)"""
+    b, h, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    layer, write, kv_len = layer_ref[0], write_ref[b], kvlen_ref[b]
+    d = q_ref.shape[-1]
+    rows, group = g * block_q, pages * bs
+    q_first = write + qi * block_q  # the block's first query's position
+    first_page = 0 if window is None else _window_first_page(write, qi, block_q, bs, window)
+    n_pages = pl.cdiv(jnp.minimum(kv_len, q_first + block_q), bs)  # live: [first_page, n_pages)
+    n_groups = pl.cdiv(jnp.maximum(n_pages - first_page, 0), pages)
+    narrow = q_ref.dtype == k_buf.dtype == jnp.bfloat16
+
+    def each_live_page(i, slot, act):
+        first = first_page + i * pages
+
+        def page(p, carry):
+            block = tbl_ref[b, first + p]
+            keys = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]), (v_hbm, v_buf, sems.at[1, slot])):
+                act(pltpu.make_async_copy(pool.at[layer, block, h], buf.at[slot, keys], sem))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, pages), page, 0)
+
+    # a dead page's rows of a V buffer are multiplied by p = 0 and have to be
+    # finite for that: the scratch starts as zeros, and a later step finds an
+    # earlier step's pages there (steps run in order on one core: "arbitrary")
+    @pl.when((b == 0) & (h == 0) & (qi == 0))
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    each_live_page(0, 0, lambda copy: copy.start())
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # rows are group-major: row r is query t_local = r % block_q of query head
+    # r // block_q, so the [g, block_q, d] tile flattens without moving data
+    # (block_q is a whole number of sublane tiles)
+    q = q_ref[...].reshape(rows, d)
+    if not narrow:
+        q = q.astype(jnp.float32) * sm_scale
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, group), 1)
+    # key column less the query's offset in the block: <= q_first - k_start is causal
+    ahead = col - jax.lax.broadcasted_iota(jnp.int32, (g, block_q, group), 1).reshape(rows, group)
+
+    def one_group(i, carry):
+        slot = jax.lax.rem(i, 2)
+        each_live_page(i + 1, 1 - slot, lambda copy: copy.start())
+        each_live_page(i, slot, lambda copy: copy.wait())
+        k, v = k_buf[slot], v_buf[slot]  # [group, d]
+        k_start = (first_page + i * pages) * bs
+        if narrow:
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = s * sm_scale
+        else:
+            s = jax.lax.dot_general(
+                q, k.astype(jnp.float32), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+        ok = (ahead <= q_first - k_start) & (col < kv_len - k_start)
+        if window is not None:
+            ok &= ahead > q_first - window - k_start
+        s = jnp.where(ok, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + p.sum(axis=1, keepdims=True)
+        m_ref[:, :1] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, one_group, 0)
+    out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+    o_ref[...] = out.reshape(g, block_q, d).astype(o_ref.dtype)
+
+
+def _paged_prefill_blockspec_kernel(
+    layer_ref, write_ref, kvlen_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, sm_scale, block_q, bs, g, window=None,
+):
+    """The kernel the pipeline feeds where this one cannot copy for itself
+    (see ``_paged_prefill``): grid ``(row, KV head, block of queries, table
+    entry)``, one page of K and of V a grid step by ``BlockSpec``, the softmax
+    state in scratch across a block's steps, float32 products. An entry that
+    holds nothing the block can see costs a grid step and nothing else. Kept
+    for exactly the widths ``_paged_decode_blockspec_kernel`` is kept for
+    (``D`` = 16: the test-size flavors); every served width takes
+    ``_paged_prefill_kernel``."""
+    del layer_ref  # the index map's
     b = pl.program_id(0)
     qi = pl.program_id(2)
     ji = pl.program_id(3)
@@ -454,6 +588,25 @@ def _window_first_page(write, qi, block_q: int, bs: int, window: int):
     """The page that holds the oldest key the first query of block ``qi`` of a
     chunk written at ``write`` can see under ``window``."""
     return jnp.maximum(write + qi * block_q - window + 1, 0) // bs
+
+
+def _prefill_block_q(t: int, dtype, block_q: int = 128) -> int:
+    """Queries a grid step of the prefill kernels takes of a chunk of ``t``."""
+    return min(block_q, round_up(t, sublanes(dtype)))
+
+
+def prefill_pages_walked(write_index, kv_len, t: int, bs: int, dtype, window=None) -> tuple[int, int]:
+    """The prefill kernel's walk by the host's arithmetic, for a counter:
+    (table entries the loops of one call visit for one KV head, blocks of
+    queries the call has), over the rows of ``write_index`` / ``kv_len``
+    (numpy) for a chunk of ``t`` queries of ``dtype``. A block of queries
+    walks from the page of the oldest key its first query can see to the
+    page of the newest its last one can, within the valid length."""
+    block_q = _prefill_block_q(t, dtype)
+    first = write_index[:, None] + np.arange(0, t, block_q)[None, :]  # [rows, blocks]: their first queries
+    n_pages = -(-np.minimum(kv_len[:, None], first + block_q) // bs)
+    first_page = 0 if window is None else np.maximum(first - window + 1, 0) // bs
+    return int(np.maximum(n_pages - first_page, 0).sum()), first.size
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "window"))
@@ -537,18 +690,20 @@ def _paged_decode(q, pool_k, pool_v, tables, kv_len, *, layer_index, sm_scale, i
     return out[:, :, :g]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("layer_index", "sm_scale", "block_q", "interpret", "window")
-)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_q", "interpret", "window"))
 def _paged_prefill(
     q, pool_k, pool_v, tables, write_index, kv_len, *, layer_index, sm_scale, block_q, interpret,
     window=None,
 ):
-    """q: [B, T, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]."""
+    """q: [B, T, Hkv, G, D]; pools: [L, NB, Hkv, bs, D]; tables: [B, nbl]
+    (against a pool of ``r`` heads a row these are ``Hkv / r``, ``r * G`` and
+    ``r * D``: ``paged_attention``). ``layer_index`` is a run-time scalar,
+    prefetched with the table as in ``_paged_decode``: a model's layers of
+    one kind share one trace and one lowering of the kernel."""
     b, t, hk, g, d = q.shape
     nbl = tables.shape[1]
     bs = pool_k.shape[3]
-    block_q = min(block_q, round_up(t, sublanes(q.dtype)))
+    block_q = _prefill_block_q(t, q.dtype, block_q)
     t_pad = round_up(t, block_q)
     # heads-major, group-major queries: the kernel's [g, block_q, d] tile
     # keeps (block_q, d) as the tiled dims, like the KV pages
@@ -556,46 +711,62 @@ def _paged_prefill(
     if t_pad != t:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, t_pad - t), (0, 0)))
 
-    grid = (b, hk, t_pad // block_q, nbl)
-    kernel = functools.partial(
-        _paged_prefill_kernel, sm_scale=sm_scale, block_q=block_q, bs=bs, g=g
-    )
-    kv_spec = pl.BlockSpec(
-        (None, None, None, bs, d),
-        lambda b_, h, qi, ji, write, kvlen, tbl: (layer_index, tbl[b_, ji], h, 0, 0),
-    )
-    if window is not None:
-        # a block of queries sees at most `window + block_q - 1` positions: the
-        # grid spans the pages that can hold them (one more where they straddle
-        # a page's edge), counted from the first it can see, not the table
-        kernel = functools.partial(kernel, window=window)
-        grid = (*grid[:3], min(nbl, pl.cdiv(window + block_q - 1, bs) + 1))
+    edge = {} if window is None else {"window": window}
+    rows = g * block_q
+    state = [
+        pltpu.VMEM((rows, d), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+    ]
+    if d % 128 == 0:
+        # the pools stay in HBM and the kernel copies the pages it wants, as
+        # the decode kernel does: the operand is the pool as the write left it
+        pages = _prefill_pages(bs, rows, nbl)
+        kernel = functools.partial(
+            _paged_prefill_kernel, sm_scale=sm_scale, block_q=block_q, bs=bs, g=g, pages=pages, **edge
+        )
+        grid = (b, hk, t_pad // block_q)
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        group_buffer = pltpu.VMEM((2, pages * bs, d), pool_k.dtype)
+        scratch = [group_buffer, group_buffer, pltpu.SemaphoreType.DMA((2, 2)), *state]
+    else:
+        # Mosaic slices no HBM array whose last dimension is under a lane
+        # tile (`_paged_decode`): for a row that packing could not make a
+        # whole tile (the test-size flavors, D = 16) the pipeline fetches one
+        # page a grid step, over the table or, under a window, over the pages
+        # a block of queries can see (`window + block_q - 1` positions of
+        # them, one more where they straddle a page's edge)
+        kernel = functools.partial(
+            _paged_prefill_blockspec_kernel, sm_scale=sm_scale, block_q=block_q, bs=bs, g=g, **edge
+        )
+        span = nbl if window is None else min(nbl, pl.cdiv(window + block_q - 1, bs) + 1)
+        grid = (b, hk, t_pad // block_q, span)
 
-        def window_page(b_, h, qi, ji, write, kvlen, tbl):
-            entry = _window_first_page(write[b_], qi, block_q, bs, window) + ji
-            return layer_index, tbl[b_, jnp.minimum(entry, nbl - 1)], h, 0, 0
+        def page(b_, h, qi, ji, layer, write, kvlen, tbl):
+            if window is not None:
+                ji = jnp.minimum(_window_first_page(write[b_], qi, block_q, bs, window) + ji, nbl - 1)
+            return layer[0], tbl[b_, ji], h, 0, 0
 
-        kv_spec = pl.BlockSpec((None, None, None, bs, d), window_page)
-    q_spec = pl.BlockSpec(
-        (None, None, g, block_q, d),
-        lambda b_, h, qi, ji, write, kvlen, tbl: (b_, h, 0, qi, 0),
-    )
+        pool_spec = pl.BlockSpec((None, None, None, bs, d), page)
+        scratch = state
+    q_spec = pl.BlockSpec((None, None, g, block_q, d), lambda b_, h, qi, *_: (b_, h, 0, qi, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((g * block_q, d), jnp.float32),
-                pltpu.VMEM((g * block_q, 128), jnp.float32),
-                pltpu.VMEM((g * block_q, 128), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hk, g, t_pad, d), q.dtype),
+        # never "parallel": scratch carries state from one grid step to the
+        # next (the softmax state over a block's pages; the V buffers zeroed
+        # in the first step), so no core may start in the middle of the grid
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
     )(
+        jnp.asarray(layer_index, jnp.int32).reshape(1),
         write_index.astype(jnp.int32),
         kv_len.astype(jnp.int32),
         tables.astype(jnp.int32),

@@ -423,6 +423,7 @@ class TestPagedAttentionModes:
         assert stats["mesh_geometry"] == ()
         for key in (
             "paged_kernel_steps", "paged_decode_pages_walked", "paged_decode_pages_spanned",
+            "paged_prefill_pages_walked", "paged_prefill_pages_spanned",
             "kv_gather_bytes_avoided", "decode_attention_s",
         ):
             assert key in stats
@@ -493,6 +494,40 @@ class TestPagedAttentionModes:
         assert len(seen) * 2 <= walked < stats["paged_decode_pages_spanned"]
         eng.reset_stats()
         assert eng.stats()["paged_decode_pages_walked"] == eng.stats()["paged_decode_pages_spanned"] == 0
+
+    def test_prefill_pages_walked_and_spanned_follow_the_rows_lengths(self):
+        """``paged_prefill_pages_walked`` is what the prefill kernel's loops
+        cover (for each block of queries the pages up to its newest visible
+        key, within the row's valid length), ``paged_prefill_pages_spanned``
+        what one page a grid step over the table stepped (blocks of queries x
+        entries a row): both recomputed here from the arguments of every
+        prefill program of a two-lane engine, whole prompts and chunks."""
+        from cosmos_curate_tpu.ops.paged_attention import _prefill_block_q
+
+        eng = self._mode_engine("kernel", **self.GNARLY)
+        prefill, seen = eng._prefill_batch, []
+
+        def recording(params, pool_k, pool_v, tables, embeds, write_index, t_valid, rope_pos, ds):
+            seen.append((tables.shape, embeds.shape[1], np.asarray(write_index), np.asarray(t_valid)))
+            return prefill(params, pool_k, pool_v, tables, embeds, write_index, t_valid, rope_pos, ds)
+
+        eng._prefill_batch = recording
+        _drain(eng, [_req("short", text="hi", max_new=4), _req("long", text="w " * 30, max_new=6)])
+        stats, bs = eng.stats(), eng.block_size
+        assert len(seen) >= 2 and any(write.any() for _, _, write, _ in seen)  # a later chunk among them
+        walked = spanned = 0
+        for (rows, nbl), t, write, t_valid in seen:
+            block_q = _prefill_block_q(t, eng.model.dtype)
+            for row in range(rows):
+                for first in range(int(write[row]), int(write[row]) + t, block_q):
+                    newest = min(int(write[row] + t_valid[row]), first + block_q)  # one past it
+                    walked += -(-newest // bs)
+                    spanned += nbl
+        assert stats["paged_prefill_pages_walked"] == walked
+        assert stats["paged_prefill_pages_spanned"] == spanned
+        assert 0 < walked < spanned
+        eng.reset_stats()
+        assert eng.stats()["paged_prefill_pages_walked"] == eng.stats()["paged_prefill_pages_spanned"] == 0
 
     def test_parity_with_fragmented_block_table(self):
         """Blocks deliberately NON-CONTIGUOUS in the pool — the layout the

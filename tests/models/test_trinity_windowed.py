@@ -5,6 +5,7 @@ tokens; the sigmoid router with its selection bias against numpy; and what a
 row that wraps its ring of window blocks may not do to its neighbours."""
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -220,13 +221,34 @@ def test_engine_prefill_then_decode_match_the_reference_in_both_lanes(params, sh
     prefix = _ids(9, seed=11) if shared_prefix else []
     prompts = {"long": _ids(71 - len(prefix), seed=5), "short": _ids(14, seed=6)}
     steps = 12
+    run_prefill, chunks = engine._run_prefill, []
+
+    def recording(lane, slots_arr, tables, embeds, write_index, t_valid, rope, ds):
+        chunks.append((tables.shape, embeds.shape[1], np.asarray(write_index), np.asarray(t_valid)))
+        return run_prefill(lane, slots_arr, tables, embeds, write_index, t_valid, rope, ds)
+
+    engine._run_prefill = recording
     try:
         for name, ids in prompts.items():
             engine.add_request(CaptionRequest(
                 name, ids, prefix_ids=list(prefix), sampling=SamplingConfig(max_new_tokens=steps + 1)
             ))
         engine.run_until_complete()
-        assert engine.stats()["paged_decode_pages_walked"] < engine.stats()["paged_decode_pages_spanned"]
+        stats = engine.stats()
+        assert stats["paged_decode_pages_walked"] < stats["paged_decode_pages_spanned"]
+        # the prefill kernel's walk, kind by kind: a full layer stops at the
+        # chunk's newest key, a window layer also starts at its oldest
+        from cosmos_curate_tpu.ops.paged_attention import prefill_pages_walked
+
+        full = win = spanned = 0
+        for (rows, nbl), t, write, t_valid in chunks:
+            walk = functools.partial(prefill_pages_walked, write, write + t_valid, t, BLOCK, engine.model.dtype)
+            full, win = full + walk()[0], win + walk(window=CFG.sliding_window)[0]
+            spanned += walk()[1] * nbl
+        n_full, n_win = len(CFG.full_layers), len(CFG.window_layers)
+        assert 0 < win < full < spanned  # walked < spanned in both kinds
+        assert stats["paged_prefill_pages_walked"] == n_full * full + n_win * win
+        assert stats["paged_prefill_pages_spanned"] == (n_full + n_win) * spanned
     finally:
         engine.shutdown()
     assert engine._allocator.free_blocks == engine._allocator.capacity

@@ -63,6 +63,44 @@ def _fragmented_case(rng, *, b, t, hk, g, d, nbl, bs, n_blocks, dtype=jnp.float3
     return q, pool_k, pool_v, tables, layer, jnp.asarray(k_cache), jnp.asarray(v_cache)
 
 
+# The prefill kernel's two forms (``_paged_prefill``): where ``D`` makes no
+# whole lane tile the pipeline feeds one page a grid step; at ``D`` = 128 the
+# kernel copies a group of its row's own pages a loop trip, out of pages of 16
+# and of 128 positions. (hk, g, d, nbl, bs): every lane is 384 positions long.
+PREFILL_FORMS = {
+    "pipeline-d16": (2, 3, 16, 24, 16),
+    "own-copies-bs16": (2, 3, 128, 24, 16),
+    "own-copies-bs128": (2, 3, 128, 3, 128),
+}
+# the pipeline's kernel multiplies in float32 whatever the pool holds: one type does
+FORMS_AND_TYPES = [
+    pytest.param(form, dtype, id=f"{form}-{name}")
+    for form in sorted(PREFILL_FORMS)
+    for dtype, name in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16"))
+    if not (form == "pipeline-d16" and dtype == jnp.bfloat16)
+]
+
+
+@pytest.fixture
+def groups_of_64_keys(monkeypatch):
+    """A served group is 1,024 keys, more than a CPU test wants to multiply:
+    64 here, so 4 pages of 16 (one page of 128) a loop trip, and a context of
+    150 positions is an odd number of groups."""
+    import importlib
+
+    module = importlib.import_module("cosmos_curate_tpu.ops.paged_attention")
+    monkeypatch.setattr(module, "_PREFILL_GROUP_KEYS", 64)
+    module._paged_prefill.clear_cache()
+    yield
+    module._paged_prefill.clear_cache()
+
+
+def _tolerance(dtype):
+    """float32 pools: today's; bfloat16: what
+    ``test_bf16_kernel_within_online_softmax_tolerance`` holds decode to."""
+    return dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32 else dict(atol=3e-2, rtol=3e-2)
+
+
 class TestReferencePath:
     @pytest.mark.parametrize(
         "b,hk,g,d,nbl,bs", [(2, 2, 4, 16, 4, 16), (3, 1, 2, 32, 2, 8), (3, 1, 1, 16, 8, 16)]
@@ -243,11 +281,16 @@ class TestInterpretKernel:
         assert _paged_decode._cache_size() == 1
 
     # write_index and valid tokens a row; ``poison``: every table entry past
-    # a row's valid length points at a block of huge finite garbage (the
-    # engine points them at its block 0), which the kernel's skipped grid
-    # steps must keep out of the result while the reference reads the clean
-    # table. A chunk's rows past ``t_valid`` are padding: the engine reads
-    # none of them, but both sides define them the same way.
+    # a row's valid length points at a block of garbage (the engine points
+    # them at its block 0), which the kernel must keep out of the result
+    # while the reference reads the clean table: huge and finite where the
+    # pipeline feeds the kernel (a skipped grid step's page is still
+    # fetched), NaN where the kernel copies for itself (it starts no copy of
+    # such an entry). A chunk's rows past ``t_valid`` are padding: the engine
+    # reads none of them, but both sides define them the same way, except in
+    # a row with NO valid key (a padding row, ``kv_len`` 0), where the kernel
+    # runs no trip and returns zeros.
+    @pytest.mark.parametrize("form,dtype", FORMS_AND_TYPES)
     @pytest.mark.parametrize(
         "t,write,t_valid,poison",
         [
@@ -257,16 +300,21 @@ class TestInterpretKernel:
             pytest.param(16, [0, 20], [16, 9], False, id="padded-chunk"),
             pytest.param(16, [48, 5], [32, 40], False, id="valid-length-past-the-chunk"),
             pytest.param(8, [0, 17], [8, 8], True, id="garbage-past-the-valid-length"),
+            pytest.param(16, [0, 30], [0, 16], True, id="padding-row-beside-one-group"),
+            pytest.param(16, [150, 64], [16, 16], True, id="three-groups-and-two"),
+            pytest.param(24, [299, 200], [24, 11], True, id="mid-page-writes-five-groups-padded"),
         ],
     )
-    def test_prefill_kernel_matches_reference(self, t, write, t_valid, poison):
+    def test_prefill_kernel_matches_reference(self, groups_of_64_keys, t, write, t_valid, poison, form, dtype):
         """write_index 0 and > 0, a chunk length that does and does not tile
         block_q (the pad rows must not disturb the valid window), fewer
-        valid tokens than the chunk holds, and more."""
+        valid tokens than the chunk holds, and more; a walk of one group, of
+        an even and of an odd number."""
+        hk, g, d, nbl, bs = PREFILL_FORMS[form]
         rng = np.random.default_rng(4)
-        b, hk, g, d, nbl, bs = 2, 2, 3, 16, 6, 16
+        b = 2
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
-            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2, dtype=dtype
         )
         write = jnp.asarray(write, jnp.int32)
         kv_len = write + jnp.asarray(t_valid, jnp.int32)
@@ -275,14 +323,61 @@ class TestInterpretKernel:
         )
         if poison:
             assert 0 not in np.asarray(tables)
-            pk, pv = pk.at[:, 0].set(1e6), pv.at[:, 0].set(-1e6)
+            bad = 1e6 if d == 16 else jnp.nan
+            pk, pv = pk.at[:, 0].set(bad), pv.at[:, 0].set(-bad)
             dead = np.arange(nbl)[None, :] * bs >= np.asarray(kv_len)[:, None]
             tables = jnp.where(dead, 0, tables)
-        got = paged_attention(
-            q, pk, pv, tables, write, kv_len,
-            layer_index=layer, use_kernel=True, interpret=True, block_q=8,
+        got = np.asarray(
+            paged_attention(
+                q, pk, pv, tables, write, kv_len,
+                layer_index=layer, use_kernel=True, interpret=True, block_q=8,
+            ),
+            np.float32,
         )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+        assert np.isfinite(got).all()
+        keyed = np.asarray(kv_len) > 0
+        np.testing.assert_allclose(got[keyed], np.asarray(want, np.float32)[keyed], **_tolerance(dtype))
+        assert not got[~keyed].any()
+
+    @pytest.mark.parametrize(
+        "bs,rows,nbl,pages",
+        [
+            pytest.param(128, 768, 96, 8, id="trinity-12288"),
+            pytest.param(16, 768, 768, 64, id="trinity-12288-in-blocks-of-16"),
+            pytest.param(16, 768, 256, 64, id="qwen2vl-2b-4096"),
+            pytest.param(16, 896, 256, 48, id="qwen25vl-7b-shard"),
+            pytest.param(16, 1024, 64, 48, id="granite-two-heads-a-row"),
+            pytest.param(16, 768, 20, 20, id="shorter-table"),
+            pytest.param(256, 48, 4, 4, id="pages-of-256"),
+        ],
+    )
+    def test_prefill_pages_a_group_come_from_the_shapes(self, bs, rows, nbl, pages):
+        from cosmos_curate_tpu.ops.paged_attention import _prefill_pages
+
+        got = _prefill_pages(bs, rows, nbl)
+        assert got == pages
+        # whole MXU widths of keys, a float32 score tile within 3 MiB
+        assert got * bs % 128 == 0 or got == nbl
+        assert rows * got * bs * 4 <= 3 * 1024 * 1024 or got == 1
+
+    def test_prefill_layers_share_one_trace(self):
+        """The layer is a scalar the prefill kernels read at run time too."""
+        from cosmos_curate_tpu.ops.paged_attention import _paged_prefill
+
+        rng = np.random.default_rng(8)
+        b, t, hk, g, d, nbl, bs = 2, 16, 2, 3, 128, 10, 16
+        q, pk, pv, tables, _, _, _ = _fragmented_case(
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+        )
+        write = jnp.asarray([100, 3], jnp.int32)
+        _paged_prefill.clear_cache()
+        for layer in (0, 1):
+            got = paged_attention(
+                q, pk, pv, tables, write, write + t, layer_index=layer, use_kernel=True, interpret=True
+            )
+            want = paged_attention(q, pk, pv, tables, write, write + t, layer_index=layer, use_kernel=False)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
+        assert _paged_prefill._cache_size() == 1
 
     def test_bf16_kernel_within_online_softmax_tolerance(self):
         """bf16 online softmax (kernel) vs dense softmax (reference) differ
@@ -385,6 +480,9 @@ class TestWindow:
         assert np.isfinite(np.asarray(got)).all()
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
 
+    # groups of 64 keys: a window of 100 starts mid-group; ``write`` past the
+    # window: whole groups behind it, their entries poisoned with the dead ones
+    @pytest.mark.parametrize("form,dtype", FORMS_AND_TYPES)
     @pytest.mark.parametrize(
         "window,t,write,t_valid",
         [
@@ -392,32 +490,44 @@ class TestWindow:
             pytest.param(40, 32, [0, 64, 100], [32, 32, 20], id="from-zero-later-and-padded"),
             pytest.param(16, 24, [8, 40, 96], [24, 24, 24], id="window-under-the-chunk"),
             pytest.param(200, 16, [0, 90, 112], [16, 16, 16], id="window-over-the-context"),
+            pytest.param(100, 16, [150, 201, 333], [16, 16, 9], id="window-starts-mid-group"),
+            pytest.param(130, 24, [0, 256, 360], [0, 24, 24], id="padding-row-and-writes-past-the-window"),
         ],
     )
-    def test_prefill_kernel_matches_the_reference_and_skips_what_is_behind(self, window, t, write, t_valid):
+    def test_prefill_kernel_matches_the_reference_and_skips_what_is_behind(
+        self, groups_of_64_keys, window, t, write, t_valid, form, dtype
+    ):
+        hk, g, d, nbl, bs = PREFILL_FORMS[form]
         rng = np.random.default_rng(10)
-        b, hk, g, d, nbl, bs = 3, 2, 3, 16, 8, 16
+        b = 3
         q, pk, pv, tables, layer, _, _ = _fragmented_case(
-            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2
+            rng, b=b, t=t, hk=hk, g=g, d=d, nbl=nbl, bs=bs, n_blocks=b * nbl + 2, dtype=dtype
         )
         write = jnp.asarray(write, jnp.int32)
         kv_len = write + jnp.asarray(t_valid, jnp.int32)
         want = paged_attention(
             q, pk, pv, tables, write, kv_len, layer_index=layer, use_kernel=False, window=window
         )
+        # every entry at or past a row's valid length, and every entry wholly
+        # behind its first query's window, names a block of garbage (NaN
+        # where the kernel copies for itself: it starts no copy of either)
         page = np.arange(nbl)[None, :]
         dead = (page * bs >= np.asarray(kv_len)[:, None]) | (
             (page + 1) * bs <= np.maximum(np.asarray(write)[:, None] - window + 1, 0)
         )
-        pk, pv = pk.at[:, 0].set(1e6), pv.at[:, 0].set(-1e6)
-        got = paged_attention(
-            q, pk, pv, jnp.where(dead, 0, tables), write, kv_len,
-            layer_index=layer, use_kernel=True, interpret=True, block_q=8, window=window,
+        assert dead.any() and 0 not in np.asarray(tables)
+        bad = 1e6 if d == 16 else jnp.nan
+        pk, pv = pk.at[:, 0].set(bad), pv.at[:, 0].set(-bad)
+        got = np.asarray(
+            paged_attention(
+                q, pk, pv, jnp.where(dead, 0, tables), write, kv_len,
+                layer_index=layer, use_kernel=True, interpret=True, block_q=8, window=window,
+            ),
+            np.float32,
         )
+        assert np.isfinite(got).all()
         valid = np.arange(t)[None, :] < np.asarray(t_valid)[:, None]  # padding rows are nobody's
-        np.testing.assert_allclose(
-            np.asarray(got)[valid], np.asarray(want)[valid], atol=2e-5, rtol=1e-4
-        )
+        np.testing.assert_allclose(got[valid], np.asarray(want, np.float32)[valid], **_tolerance(dtype))
 
     def test_a_table_that_repeats_a_ring_of_blocks_reads_the_newest(self):
         """The engine's window table: logical block ``j`` in ring block ``j %
@@ -494,8 +604,7 @@ class TestPackedPool:
         got = paged_attention(
             *packed, layer_index=layer, use_kernel=True, interpret=True, block_q=8
         )
-        tol = dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32 else dict(atol=3e-2, rtol=3e-2)
-        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tol)
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **_tolerance(dtype))
 
     def test_scale_defaults_from_the_true_head_dim(self):
         unpacked, packed, layer = self._case("base", 1, jnp.float32)

@@ -127,7 +127,8 @@ def test_paged_kernels_compile_at_the_widest_lane(v5e, kernel, rows, widths):
     a decode step over the lane's four rows (two in the tp4 cell), a
     256-token chunk of one row. The compiler refuses a kernel that wants
     more VMEM than a call may have, so compiling is the check (the page
-    buffers are sized from the page, not from the table)."""
+    buffers are sized from the page and the block of queries, not from the
+    table)."""
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -137,20 +138,24 @@ def test_paged_kernels_compile_at_the_widest_lane(v5e, kernel, rows, widths):
 
 
 @pytest.mark.parametrize(
-    "kernel,rows,lane",
+    "kernel,rows,lane,blocks",
     [
-        pytest.param("paged_decode", 24, 12288, id="decode-24-rows-of-12288"),
-        pytest.param("paged_decode", 16, 4096, id="decode-16-rows-of-4096"),
-        pytest.param("paged_prefill", 4, 12288, id="prefill-4-rows-of-12288"),
-        pytest.param("paged_prefill", 1, 4096, id="prefill-1-row-of-4096"),
+        pytest.param("paged_decode", 24, 12288, None, id="decode-24-rows-of-12288"),
+        pytest.param("paged_decode", 16, 4096, None, id="decode-16-rows-of-4096"),
+        pytest.param("paged_prefill", 4, 12288, None, id="prefill-4-rows-of-12288"),
+        pytest.param("paged_prefill", 1, 4096, None, id="prefill-1-row-of-4096"),
+        pytest.param("paged_prefill", 4, 12288, 16, id="prefill-4-rows-of-12288-in-blocks-of-16"),
     ],
 )
 @pytest.mark.parametrize("window", [4096, None], ids=["window-layer", "full-layer"])
-def test_windowed_kernels_compile_at_trinitys_widths(v5e, kernel, rows, lane, window):
+def test_windowed_kernels_compile_at_trinitys_widths(v5e, kernel, rows, lane, blocks, window):
     """Trinity-Large's attention (8 KV heads x 6 query heads x 128) at its two
     lanes, the first past 4,096 positions, in the blocks of 128 the engine takes
     for such lanes: 96 table entries a row in scalar memory, a page of 128 keys
-    a copy, the window's first-page arithmetic in the index maps and loops."""
+    a copy, the window's first-page arithmetic in the loops. And the prefill
+    chunk in blocks of 16 too, 768 entries a row: the size the engine's rule was
+    written to keep the old one-page-a-grid-step kernel away from, which the
+    kernel that walks groups of pages no longer minds (PERF.md PR 39)."""
 
     from cosmos_curate_tpu.ops.paged_attention import paged_attention
 
@@ -160,8 +165,8 @@ def test_windowed_kernels_compile_at_trinitys_widths(v5e, kernel, rows, lane, wi
     from cosmos_curate_tpu.models.vlm.engine import default_block_size
 
     hk, g, d, t = 8, 6, 128, 1 if kernel == "paged_decode" else T
-    bs = default_block_size(((4096, 16), (12288, 24)))
-    pool = arg((4, 100, hk, bs, d), jnp.bfloat16)
+    bs = blocks or default_block_size(((4096, 16), (12288, 24)))
+    pool = arg((4, 12800 // bs, hk, bs, d), jnp.bfloat16)
     fn = functools.partial(paged_attention, layer_index=1, use_kernel=True, interpret=False, window=window)
     args = (
         arg((rows, t, hk, g, d), jnp.bfloat16), pool, pool, arg((rows, lane // bs), jnp.int32),
