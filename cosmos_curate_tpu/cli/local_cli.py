@@ -19,6 +19,8 @@ CAPTION_MODEL_CHOICES = (
     "deepseek-v2-tiny-test",
     "granite-4.0-h-micro",
     "granite-hybrid-tiny-test",
+    "keye-tiny-test",
+    "keye-vl2-a3b-ep8",
     "qwen25vl-7b",
     "qwen25vl-tiny-test",
     "qwen2vl-2b",
@@ -100,6 +102,9 @@ def register(sub: argparse._SubParsersAction) -> None:
         "expert-parallel over 8: its layers return that chip's partial sums. "
         "trinity-large-ep8 (text only) is the same share of Trinity-Large (afmoe): "
         "window and full attention layers over two KV pools, requests up to 12,287 positions. "
+        "keye-vl2-a3b-ep8 (text only, no converter yet: it needs staged weights) is the same share of "
+        "Keye-VL-2.0-30B-A3B's language model: an indexer picks the 2,048 positions a query attends "
+        "to, index keys beside the KV pool, requests up to 32,767 positions. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

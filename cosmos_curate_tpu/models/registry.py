@@ -71,6 +71,7 @@ for _mid, _desc in [
     ("caption-granite-4.0-h-micro-tpu", "Granite-4.0-H-Micro hybrid (Mamba-2 + attention) text LM (converted checkpoint slot)"),
     ("caption-deepseek-v2-ep8-tpu", "DeepSeek-V2, one chip's share of an 8-way expert-parallel deployment (converted checkpoint slot)"),
     ("caption-trinity-large-ep8-tpu", "Trinity-Large (afmoe), one chip's share of an 8-way expert-parallel deployment (converted checkpoint slot)"),
+    ("caption-keye-vl2-a3b-ep8-tpu", "Keye-VL-2.0-30B-A3B's language model (learned sparse attention), one chip's share of an 8-way expert-parallel deployment (checkpoint slot; no converter yet)"),
     ("t5-encoder-tpu", "text encoder for caption embeddings"),
     ("ocr-detector-tpu", "overlay-text region detector (Flax FCN)"),
     ("ocr-recognizer-tpu", "text recognizer CRNN with CTC decoding"),

@@ -68,6 +68,18 @@ vllm_async_stage.py). TPU-first re-design:
   takes private copies of the prefix's window blocks at admission instead.
   Such a flavor's prompts always prefill in chunks (a bucket longer than the
   ring's slack would overwrite what it still reads).
+- **index keys beside the pool** (flavors with a learned indexer,
+  ``cfg.indexer``): every position keeps, beside its K/V, ONE small index key a
+  layer in a second array ``[L, NB, 1, bs, W]`` with the pool's own block
+  dimension and NO table of its own: block ``j`` of a row's table holds its
+  positions' K, V and index keys, so one allocator and one table serve the
+  three arrays, a prefix block that rows share shares its index keys, and a
+  copy on write takes them along. The programs take the K pool's place as the
+  pair (K, index keys) (``_pools``), write both through the table, score a
+  query's visible positions against the index keys, and attend to the
+  ``top_k`` best: a decode step reads the K/V of the chosen positions only
+  (``ops/sparse_attention.py``). They also hand out what every layer's last
+  query chose, a bit a position (``_choice_digest``), for whoever compares it.
 - **one decode program of look-ahead**: a lane's decode program N+1 is
   dispatched before program N's tokens are read on the host (``_decode_once``:
   dispatch, then collect the one before). N+1's positions are the host's
@@ -114,6 +126,7 @@ from cosmos_curate_tpu.models.vlm.paged_kv import (
     PoolExhausted,
     gather_block_views,
     init_block_pool,
+    init_index_pool,
     init_latent_pool,
     scatter_block_views,
 )
@@ -338,6 +351,7 @@ class _InFlight:
     rows: dict
     positions: np.ndarray  # [n_slots] as dispatched: a row's kv length less one
     program: int  # its number; a thread's own rise in the order it hands them over
+    choice: Any = None  # an indexer's program: what its rows chose, [layers, n_slots, lane / 32] on the device
 
     def emitted(self, lane: _Lane) -> dict:
         """The rows whose token counts: those whose slot is still the lane's."""
@@ -541,6 +555,14 @@ def _handoff_works(mesh, put_sharding) -> bool:
     return False
 
 
+def _count_held(held, aux):
+    """A decode program's rider: the device's count of the assignments that landed
+    on the experts held here (``MoEFFN._sorted_experts`` sows one a layer) added
+    to ``held``, two int32s ``[units, 2**30s]`` so that it never wraps."""
+    units = held[0] + sum(jax.tree.leaves(aux["intermediates"]))
+    return jnp.stack([units % 2**30, held[1] + units // 2**30])
+
+
 def default_block_size(kv_lanes) -> int:
     """Positions a KV block where the caller names none: 16, and 128 once a
     lane passes 4,096 positions. THE RULE NOW OUTLIVES ITS REASON: it was
@@ -629,6 +651,12 @@ class CaptionEngine:
             )
         if self._recurrent and mesh is not None:
             raise ValueError("the recurrent store is not split over a mesh: serve a hybrid on one chip")
+        # ...or a second array a position beside K/V, the indexer's keys (module docstring)
+        self._indexed = cfg.indexer is not None
+        if self._indexed and (mesh is not None or not self._use_paged):
+            raise ValueError(
+                "an indexer is served on one chip by the paged programs: no mesh, no 'gather'"
+            )
         # parameters stored in the type they are computed in: see VLM.param_dtype
         self.model = VLM(cfg, mesh=mesh, param_dtype=VLM.dtype)
         # the model's abstract parameter tree once setup() has taken it: per
@@ -694,6 +722,13 @@ class CaptionEngine:
         self._allocator = BlockAllocator(self.kv_pool_blocks)
         self._pool_k = None
         self._pool_v = None
+        # the index keys (None and 0 for every flavor without an indexer): the
+        # K pool's blocks, through the same tables; and what the last program's
+        # rows chose, as the program handed it out ([layers, rows, lane / 32]
+        # uint32 on the device, a bit a position; read by whoever asks)
+        self._pool_i = None
+        self._index_pool_bytes_per_chip = 0
+        self._choice_digest = None
         # the window pool (None and 0 for every flavor without window layers)
         self._wallocator = None
         self._wpool_k = None
@@ -816,6 +851,12 @@ class CaptionEngine:
         self._counts_experts = cfg.moe is not None and cfg.moe.dispatch == "sorted"
         self._expert_held = None
         self._mla_decode_calls = 0
+        # an indexer's decode steps (under _stats_lock), by the host's
+        # arithmetic: calls of the chosen-set attention (one a layer a decode
+        # program), the positions its rows could see and those they read K/V of
+        self._sparse_decode_calls = 0
+        self._sparse_decode_positions_live = 0
+        self._sparse_decode_positions_chosen = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -897,7 +938,8 @@ class CaptionEngine:
         if self._pool_k is None:
             return 0
         window = self._wpool_k.nbytes + self._wpool_v.nbytes if self._windowed else 0
-        return self._pool_k.nbytes + self._pool_v.nbytes + window
+        index = self._pool_i.nbytes if self._indexed else 0
+        return self._pool_k.nbytes + self._pool_v.nbytes + window + index
 
     # -- setup ----------------------------------------------------------
     def setup(self, seed: int = 0) -> None:
@@ -958,8 +1000,12 @@ class CaptionEngine:
                 cfg, self._wallocator.n_blocks, self.block_size, n_layers=len(cfg.window_layers)
             )
             self._window_pool_bytes_per_chip = _bytes_per_chip((self._wpool_k, self._wpool_v))
+        if self._indexed:
+            self._pool_i = init_index_pool(cfg, self.kv_pool_blocks, self.block_size)
+            self._index_pool_bytes_per_chip = _bytes_per_chip(self._pool_i)
         self._kv_pool_bytes_per_chip = (
             _bytes_per_chip((self._pool_k, self._pool_v)) + self._window_pool_bytes_per_chip
+            + self._index_pool_bytes_per_chip
         )
         if self._recurrent:
             self._ssm, self._conv = init_recurrent_store(
@@ -1144,9 +1190,10 @@ class CaptionEngine:
             """Copy-on-write: duplicate blocks ``src`` into ``dst`` ([m]
             each) — used ONLY when a request must extend a partially-filled
             shared prefix tail block (one block, not the whole prefix)."""
-            pool_k = pool_k.at[:, dst].set(pool_k[:, src])
-            pool_v = pool_v.at[:, dst].set(pool_v[:, src])
-            return pool_k, pool_v
+            def copy(pool):  # (an indexer flavor's ``pool_k`` is the pair (K, index keys))
+                return pool.at[:, dst].set(pool[:, src])
+
+            return jax.tree.map(copy, pool_k), jax.tree.map(copy, pool_v)
 
         self._host_rng = np.random.default_rng(seed)
         # where and how a host vector lands when it is put: what a token
@@ -1170,7 +1217,9 @@ class CaptionEngine:
         self._copy_blocks = copy_blocks
         if self._recurrent:
             self._build_recurrent_programs()
-        if self._counts_experts:
+        if self._indexed:
+            self._build_indexed_programs()
+        elif self._counts_experts:
             self._build_counted_decode()
         self._built = True
         if self.async_prep:
@@ -1204,14 +1253,73 @@ class CaptionEngine:
                     params, embeds, ck, cv, *args, mutable=["intermediates"]
                 )
                 pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, nk, nv)
-            units = held[0] + sum(jax.tree.leaves(aux["intermediates"]))
-            held = jnp.stack([units % 2**30, held[1] + units // 2**30])
             step_logits = logits[:, 0]
             greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
-            return greedy, step_logits, pool_k, pool_v, held
+            return greedy, step_logits, pool_k, pool_v, _count_held(held, aux)
 
         self._decode = decode_step_counted
         self._expert_held = jnp.zeros(2, jnp.int32)
+
+    def _build_indexed_programs(self) -> None:
+        """The programs of a flavor with a learned indexer: setup()'s paged
+        prefill and the counted decode with ``pool_k`` the PAIR (K, index keys)
+        and one more thing handed out, what each row's last query chose in every
+        layer (``[layers, rows, lane / 32]`` uint32, a bit a position: the model
+        sows it as ``choice/digest``); the prefix's build, which also returns the
+        prefix's index keys, and their write into the prefix's blocks."""
+        cfg, model, bs = self.cfg, self.model, self.block_size
+        if not self._counts_experts or self._recurrent:
+            raise ValueError("an indexer beside dense FFNs or a recurrent store has no program here")
+        mrope = cfg.mrope_section is not None
+
+        def choice_of(aux):
+            layers = aux["choice"]
+            # the last sown: a seeded tree carries what ``init`` sowed before it
+            return jnp.stack([layers[f"layer_{i}"]["digest"][-1] for i in range(cfg.n_layers)])
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def prefill_batch_indexed(params, pool_k, pool_v, tables, embeds, write_index, t_valid, rope_pos, ds=None):
+            (logits, pool_k, pool_v), aux = model.apply(
+                params, embeds, pool_k, pool_v, rope_pos, write_index, write_index + t_valid, tables,
+                logits_at=t_valid - 1, method=model.paged_forward, mutable=["choice"],
+            )
+            return logits[:, 0], pool_k, pool_v, choice_of(aux)
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def decode_step_indexed(params, pool_k, pool_v, tables, tokens, positions, rope_positions, held):
+            embeds = model.apply(params, tokens[:, None], method=model.embed_tokens)
+            rp = rope_positions[:, None]
+            if mrope:  # decode is always text: all three components equal
+                rp = jnp.broadcast_to(rp[..., None], (*rp.shape, 3))
+            (logits, pool_k, pool_v), aux = model.apply(
+                params, embeds, pool_k, pool_v, rp, positions, positions + 1, tables,
+                method=model.paged_forward, mutable=["intermediates", "choice"],
+            )
+            step_logits = logits[:, 0]
+            greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+            return greedy, step_logits, pool_k, pool_v, choice_of(aux), _count_held(held, aux)
+
+        @jax.jit
+        def prefix_prefill_indexed(params, embeds, rope_pos, t_valid):
+            ck, cv = init_cache(cfg, 1, length=embeds.shape[1])
+            _logits, (nk, ni), nv = model.apply(
+                params, embeds, ck, cv, rope_pos, jnp.zeros((1,), jnp.int32),
+                jnp.full((1,), t_valid, jnp.int32),
+            )
+            return nk[:, 0], nv[:, 0], ni[:, 0]
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def write_index_blocks(pool_i, keys, ids):
+            """A prefix's index keys ``[L, 1, Tp, W]`` into its blocks ``ids``."""
+            l, _, tp, w = keys.shape
+            keys = jnp.pad(keys.astype(pool_i.dtype), ((0, 0), (0, 0), (0, ids.shape[0] * bs - tp), (0, 0)))
+            return pool_i.at[:, ids].set(keys.reshape(l, 1, ids.shape[0], bs, w).swapaxes(1, 2))
+
+        self._prefill_batch = prefill_batch_indexed
+        self._decode = decode_step_indexed
+        self._expert_held = jnp.zeros(2, jnp.int32)
+        self._prefix_prefill = prefix_prefill_indexed
+        self._write_index_blocks = write_index_blocks
 
     @property
     def expert_assignments_held(self) -> int:
@@ -1644,6 +1752,7 @@ class CaptionEngine:
                 # (every other flavor: one pool, and no window pool)
                 "full_pool_bytes_per_chip": (
                     self._kv_pool_bytes_per_chip - self._window_pool_bytes_per_chip
+                    - self._index_pool_bytes_per_chip
                 ),
                 "window_pool_bytes_per_chip": self._window_pool_bytes_per_chip,
                 # KV heads side by side in one 128-lane pool row (1: a row a head)
@@ -1678,6 +1787,12 @@ class CaptionEngine:
                 ),
                 "mla_decode_calls": self._mla_decode_calls,
                 "expert_assignments_held": held,
+                # a learned indexer (all zero without one): its keys' array as
+                # stored, and its decode steps' positions seen and read
+                "index_pool_bytes_per_chip": self._index_pool_bytes_per_chip,
+                "sparse_decode_calls": self._sparse_decode_calls,
+                "sparse_decode_positions_live": self._sparse_decode_positions_live,
+                "sparse_decode_positions_chosen": self._sparse_decode_positions_chosen,
             }
 
     @property
@@ -1808,6 +1923,9 @@ class CaptionEngine:
             self._prefix_state_snapshots = 0
             self._ssm_decode_calls = 0
             self._mla_decode_calls = 0
+            self._sparse_decode_calls = 0
+            self._sparse_decode_positions_live = 0
+            self._sparse_decode_positions_chosen = 0
             if self._expert_held is not None:
                 self._expert_held = jnp.zeros(2, jnp.int32)
             self._interleaved_steps = 0
@@ -2544,6 +2662,8 @@ class CaptionEngine:
                 jnp.asarray(tp, jnp.int32),
             )
             k, v = k[:, :, :tp], v[:, :, :tp]
+            # an indexer's build also returns the prefix's index keys
+            index_keys = state.pop()[:, :, :tp] if self._indexed else None
         with self._phase("prefill_wait", program=program):
             jax.block_until_ready(v)
         bs = self.block_size
@@ -2590,6 +2710,10 @@ class CaptionEngine:
                     v,
                     jnp.asarray(ids, jnp.int32),
                 )
+                if self._indexed:  # the same blocks hold the prefix's index keys
+                    self._pool_i = self._write_index_blocks(
+                        self._pool_i, index_keys, jnp.asarray(ids, jnp.int32)
+                    )
                 entry = _PrefixEntry(
                     blocks=ids,
                     n_full=tp // bs,
@@ -2720,12 +2844,11 @@ class CaptionEngine:
                 # the suffix extends INTO the partially-filled shared tail
                 # block: copy-on-write one block — the only device copy on
                 # the whole admission path
-                self._pool_k, self._pool_v = self._copy_blocks(
-                    self._pool_k,
-                    self._pool_v,
-                    jnp.asarray([cow_src], jnp.int32),
-                    jnp.asarray([private[0]], jnp.int32),
-                )
+                src, dst = jnp.asarray([cow_src], jnp.int32), jnp.asarray([private[0]], jnp.int32)
+                if self._indexed:  # the block's index keys go with its K/V
+                    (self._pool_k, self._pool_i), self._pool_v = self._copy_blocks(*self._pools(), src, dst)
+                else:
+                    self._pool_k, self._pool_v = self._copy_blocks(self._pool_k, self._pool_v, src, dst)
         except BaseException:
             # a failed CoW dispatch must hand the references back, or the
             # shared pool shrinks permanently on every transient error
@@ -2833,13 +2956,18 @@ class CaptionEngine:
     def _pools(self) -> tuple:
         """(K, V) as the programs take them: the pool's two arrays, or where
         there are two pools a pair each, (the full layers', the window layers')."""
+        if self._indexed:  # K's place holds the pair (K, index keys)
+            return (self._pool_k, self._pool_i), self._pool_v
         if not self._windowed:
             return self._pool_k, self._pool_v
         return (self._pool_k, self._wpool_k), (self._pool_v, self._wpool_v)
 
     # holds-lock: _lock
-    def _keep_pools(self, pool_k, pool_v) -> None:
-        """What a program hands back in ``_pools()``'s place."""
+    def _keep_pools(self, pool_k, pool_v, choice=None) -> None:
+        """What a program hands back in ``_pools()``'s place (an indexer's
+        programs: and what their rows chose)."""
+        if self._indexed:
+            (pool_k, self._pool_i), self._choice_digest = pool_k, choice
         if self._windowed:
             (pool_k, self._wpool_k), (pool_v, self._wpool_v) = pool_k, pool_v
         self._pool_k, self._pool_v = pool_k, pool_v
@@ -3248,7 +3376,7 @@ class CaptionEngine:
                 )
         lane.inflight = _InFlight(
             greedy=greedy, logits=logits, rows=dict(lane.slots), positions=positions,
-            program=program,
+            program=program, choice=self._choice_digest,
         )
 
     # holds-lock: _lock
@@ -3282,6 +3410,15 @@ class CaptionEngine:
                     for i in flight.rows.keys() - emitted.keys():
                         positions[i] = 0
                     last = positions // self.block_size
+                    if self._indexed:
+                        # a row's step reads the index keys of the positions it
+                        # can see and the K/V of those it chose, layer by layer
+                        layers, seen = len(self.cfg.kv_layers), positions[list(emitted)] + 1
+                        self._sparse_decode_calls += layers
+                        self._sparse_decode_positions_live += layers * int(seen.sum())
+                        self._sparse_decode_positions_chosen += layers * int(
+                            np.minimum(seen, self.cfg.indexer.top_k).sum()
+                        )
                     if not self._windowed:
                         self._paged_decode_pages_walked += int((last + 1).sum())
                         self._paged_decode_pages_spanned += lane.table.size

@@ -201,6 +201,33 @@ class Mamba2Config:
 
 
 @dataclass(frozen=True)
+class IndexerConfig:
+    """A learned indexer beside GQA (DeepSeek-Sparse-Attention; HF ``sa_config``):
+    every layer scores the earlier positions for each query with ``n_heads``
+    small heads against ONE index key a position, ``I(t, s) = sum_j w_t[j] *
+    relu(qI_t[j] . kI_s)``, and the query attends to the ``top_k`` positions
+    that score highest (every position while there are no more than that). The
+    index keys are a second array a position beside K/V (``cache_width`` lanes a
+    row: paged_kv.init_index_pool); ops/sparse_attention.py has the programs."""
+
+    n_heads: int = 16
+    head_dim: int = 64
+    top_k: int = 2048
+
+    @property
+    def cache_width(self) -> int:
+        """Lanes of a cached index key: ``[kI | zeros]`` in whole 128-lane tiles,
+        the only width the chip stores or Mosaic slices (as ``MLAConfig``'s)."""
+        return round_up(self.head_dim, 128)
+
+    @property
+    def weight_scale(self) -> float:
+        """What the heads' weights are multiplied by: ``n_heads ** -0.5`` times the
+        index heads' own softmax scale ``head_dim ** -0.5``."""
+        return self.n_heads**-0.5 * self.head_dim**-0.5
+
+
+@dataclass(frozen=True)
 class VLMConfig:
     vocab: int = 512
     dim: int = 1024
@@ -249,6 +276,10 @@ class VLMConfig:
     # its sizes and YaRN's numbers; None = ``DecoderLayer``'s attention. Such a
     # flavor's cache is one latent row a token a layer (``cache_row_elems``)
     mla: MLAConfig | None = None
+    # a learned indexer in every attention layer: each query attends to the
+    # ``top_k`` positions its index heads score highest; None = every position.
+    # Such a flavor's caches are a pair where others have K: (K, index keys)
+    indexer: IndexerConfig | None = None
     # False = no position embedding at all (HF ``position_embedding_type:
     # nope``): the state-space layers carry the order
     use_rope: bool = True
@@ -270,6 +301,8 @@ class VLMConfig:
     logits_scaling: float = 1.0
 
     def __post_init__(self) -> None:
+        if self.indexer is not None and (self.mla is not None or self.window_layers or self.ssm_layers):
+            raise ValueError("an indexer beside latent attention, window layers or state-space layers: no program here")
         if self.layer_types is None:
             return
         kinds = {"attention", "mamba", "sliding_attention", "full_attention"}
@@ -645,6 +678,58 @@ VLM_TRINITY_TINY_TEST = VLMConfig(
         selection_bias=True,
     ),
 )
+# Keye-VL-2.0-30B-A3B's language model (HF ``KeyeVL2``, config.json of
+# Kwai-Keye/Keye-VL-2.0-30B-A3B) as ONE CHIP OF AN 8-WAY EXPERT-PARALLEL DEPLOYMENT
+# sees it: every width as published (2048; 32 query / 4 KV heads x 128 with
+# per-head q/k norms, rope 1e7 in m-rope sections 16/24/24; every layer sparse:
+# 128 routed experts of 768, softmax over all of them, top 8 renormalised, no
+# shared expert; and in every layer an INDEXER of 16 heads x 64 against one index
+# key a position that picks the 2,048 positions a query attends to), the router
+# whole, and of the rest this chip's share: 16 consecutive experts (0-15), a
+# vocabulary slice of 18,992 rows, and 8 of the 48 layers (every layer is of one
+# kind). Attention, indexer and router are replicated in that deployment, so
+# they are whole here; the layer runs without its exchange. Text only (the
+# tower is not modelled). The first flavor whose lanes reach 32,768 positions,
+# and the first with a second array a position beside K/V (engine.py).
+VLM_KEYE_VL2_A3B_EP8 = VLMConfig(
+    vocab=18992,
+    dim=2048,
+    n_layers=8,
+    n_heads=32,
+    n_kv_heads=4,
+    head_dim=128,
+    hidden_mult=6144 / 2048,
+    max_seq=32768,
+    rope_theta=10_000_000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    mrope_section=(16, 24, 24),
+    tied_embeddings=False,
+    qk_norm=True,
+    indexer=IndexerConfig(n_heads=16, head_dim=64, top_k=2048),
+    moe=MoEConfig(n_experts=128, top_k=8, hidden=768, norm_topk_prob=True, dispatch="sorted", held=(0, 16)),
+)
+# the same mechanisms at test size: a top-k of 32 that both lanes (64, 128) pass
+# (a smaller one makes a toy of the comparison with float32: of 12 positions
+# out of 77 the one that bfloat16 scores pick differently moves a logit by a
+# third); 16 experts in eight shares of 2 (this chip: experts 2-3)
+VLM_KEYE_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    mrope_section=(2, 3, 3),
+    tied_embeddings=False,
+    qk_norm=True,
+    indexer=IndexerConfig(n_heads=4, head_dim=8, top_k=32),
+    moe=MoEConfig(n_experts=16, top_k=4, hidden=32, norm_topk_prob=True, dispatch="sorted", held=(2, 2)),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -701,6 +786,11 @@ class FlavorSpec:
             raise ValueError(
                 f"{self.model_id}: the recurrent store and the Mamba-2 mixer are not "
                 "split over a model mesh; serve a hybrid flavor with model_chips=1"
+            )
+        if self.model_chips > 1 and self.cfg.indexer is not None:
+            raise ValueError(
+                f"{self.model_id}: the index-key array has one head plane and is not split "
+                "over a model mesh; serve an indexer flavor with model_chips=1"
             )
 
 
@@ -879,6 +969,32 @@ VLM_FLAVORS.update(
         ),
         "trinity-tiny-test": FlavorSpec(
             VLM_TRINITY_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 2), (128, 2)),
+        ),
+        # a sparse text LM with a learned indexer in every layer, served
+        # expert-parallel, seen from one of its eight chips (the LM-only passes
+        # over VERY long text: every window's caption of a multi-hour video in
+        # one prompt). A position costs 2,048 B of K/V and 256 B of index key a
+        # layer (64 values in a whole 128-lane row); 4 x 8,192 + 12 x 32,768
+        # positions are 7.0 + 0.9 GiB over 8 layers. 16 decoding rows give the
+        # 16 held experts the 16 assignments a step that 2 rows a chip give them
+        "keye-vl2-a3b-ep8": FlavorSpec(
+            VLM_KEYE_VL2_A3B_EP8,
+            "caption-keye-vl2-a3b-ep8-tpu",
+            text_only=True,
+            kv_lanes=((8192, 4), (32768, 12)),
+            # 1,024 tokens a prefill program: a row's chunk keeps [256, 32768]
+            # float32 index scores and their mask (60 MB a row a layer), and a
+            # step's two decode programs cost what 2.5 rows of prefill cost
+            # whatever decodes, so the prompts (63 chunks a mean request) want
+            # several rows a program (PERF.md, PR 40)
+            prefill_rows=4,
+        ),
+        "keye-tiny-test": FlavorSpec(
+            VLM_KEYE_TINY_TEST,
             "caption-vlm-tpu",
             require_weights=False,
             text_only=True,
@@ -1262,9 +1378,14 @@ class DecoderLayer(nn.Module):
             "attn.full" if self.window is None else "attn.window"
         )
         with kind:
-            attn, new_k, new_v = self._write_and_attend(
-                q, k, v, cache_k, cache_v, write_index, kv_len, block_tables, layer_index, edge
-            )
+            if cfg.indexer is not None:
+                attn, new_k, new_v = self._index_and_attend(
+                    y, q, k, v, cache_k, cache_v, positions, write_index, kv_len, block_tables, layer_index
+                )
+            else:
+                attn, new_k, new_v = self._write_and_attend(
+                    q, k, v, cache_k, cache_v, write_index, kv_len, block_tables, layer_index, edge
+                )
         attn = attn.reshape(b, t, h * dh)
         if cfg.attention_gate:
             with jax.named_scope("attn.gate"):
@@ -1344,6 +1465,92 @@ class DecoderLayer(nn.Module):
             sm_scale=cfg.attention_multiplier or dh**-0.5, **edge,
         )
         return attn, new_k, new_v
+
+    def _index_and_attend(self, y, q, k, v, caches, cache_v, positions, write_index, kv_len, block_tables, layer_index):
+        """A layer with an indexer (``cfg.indexer``): this chunk's K/V AND its
+        index keys written, every query's scores of the positions it can see,
+        its choice, and attention over the chosen positions only
+        (ops/sparse_attention.py). ``y``: the layer's normed input, which feeds
+        the indexer's three projections as it feeds q, k and v; ``caches``: the
+        pair (K, index keys), slot rows ``[B, 1, S, W]`` or the paged array
+        ``[L, NB, 1, bs, W]`` beside the K pool. A decode step (one query a
+        row) reads the K/V of its chosen positions and no others; a longer chunk
+        attends densely under its queries' masks. What each row's last query
+        chose is sown as ``choice/digest`` (``pack_choice``: a bit a position): a
+        program that asks for the collection hands it out, no other computes it.
+        Returns (attn, (new K, new index keys), new V)."""
+        from cosmos_curate_tpu.models.vlm.paged_kv import latent_update, paged_update
+        from cosmos_curate_tpu.ops import sparse_attention as sparse
+
+        cfg, ix = self.cfg, self.cfg.indexer
+        b, t, h, dh = q.shape
+        hk = cfg.n_kv_heads
+        cache_k, cache_i = caches
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        sm_scale = cfg.attention_multiplier or dh**-0.5
+        with jax.named_scope("attn.index"):
+            # text positions: under m-rope the three components are equal there
+            pos = positions[..., 0] if positions.ndim == 3 else positions
+            qi = proj(ix.n_heads * ix.head_dim, "out", name="index_q", use_bias=False)(y)
+            qi = apply_rope(qi.reshape(b, t, ix.n_heads, ix.head_dim), pos, cfg.rope_theta)
+            ki = proj(ix.head_dim, None, name="index_k", use_bias=False)(y)
+            ki = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32, name="index_k_norm")(ki).astype(self.dtype)
+            ki = apply_rope(ki[:, :, None], pos, cfg.rope_theta)[:, :, 0]  # ONE key head
+            w = dense(ix.n_heads, None, name="index_w", use_bias=False, dtype=jnp.float32)(
+                y.astype(jnp.float32)
+            ) * ix.weight_scale
+            row = jnp.pad(ki, ((0, 0), (0, 0), (0, ix.cache_width - ix.head_dim)))
+        qk = q.reshape(b, t, hk, h // hk, dh)
+        paged = block_tables is not None
+        if paged:
+            cache_i = latent_update(cache_i, row, block_tables, write_index, layer_index=layer_index)
+            new_k, new_v = paged_update(cache_k, cache_v, k, v, block_tables, write_index, layer_index=layer_index)
+            with jax.named_scope("attn.index_score"):
+                scores = sparse.index_scores(
+                    qi, w, cache_i, block_tables, write_index, kv_len, layer_index=layer_index
+                )
+        else:  # slot rows: the chunk at each row's write index, in all three
+
+            def write_row(cache, chunk):
+                put = lambda rows, new, idx: jax.lax.dynamic_update_slice(rows, new, (0, idx, 0))  # noqa: E731
+                return jax.vmap(put)(cache, chunk.astype(cache.dtype), write_index)
+
+            cache_i = write_row(cache_i, row[:, None])
+            new_k, new_v = write_row(cache_k, k.swapaxes(1, 2)), write_row(cache_v, v.swapaxes(1, 2))
+            with jax.named_scope("attn.index_score"):
+                scores = sparse.index_scores_reference(
+                    qi, w, cache_i[:, 0, :, : ix.head_dim], write_index, kv_len
+                )
+        if paged and t == 1:
+            with jax.named_scope("attn.select"):
+                chosen_at, valid, *numbers = sparse.decode_positions(scores[:, 0], ix.top_k)
+            with jax.named_scope("attn.sparse"):
+                attn = sparse.sparse_decode_attention(
+                    qk[:, 0], new_k, new_v, block_tables, chosen_at, valid, layer_index=layer_index,
+                    sm_scale=sm_scale,
+                )[:, None]
+            last = sparse.chosen_mask(scores[:, 0], *numbers)
+        else:
+            with jax.named_scope("attn.select"):
+                if paged:  # (how many positions a query can choose from bounds the kernel's work)
+                    seen = jnp.minimum(kv_len[:, None], write_index[:, None] + jnp.arange(t)[None, :] + 1)
+                    numbers = sparse.select_threshold(scores, ix.top_k, seen)
+                else:
+                    numbers = sparse.select_threshold_reference(scores, ix.top_k)
+                chosen = sparse.chosen_mask(scores, *numbers)
+            with jax.named_scope("attn.sparse"):
+                if paged:
+                    attn = sparse.sparse_prefill_attention(
+                        qk, new_k, new_v, block_tables, write_index, kv_len, chosen,
+                        layer_index=layer_index, sm_scale=sm_scale,
+                    )
+                else:
+                    attn = sparse.sparse_reference_attention(qk, new_k, new_v, chosen, sm_scale=sm_scale)
+            last = jnp.take_along_axis(
+                chosen, jnp.clip(kv_len - write_index - 1, 0, t - 1)[:, None, None], axis=1
+            )[:, 0]  # [B, S]: the row's last valid query's set
+        self.sow("choice", "digest", sparse.pack_choice(last))
+        return attn.astype(self.dtype), (new_k, cache_i), new_v
 
 
 def _residual(cfg: VLMConfig, x, branch):
@@ -1738,7 +1945,9 @@ class VLM(nn.Module):
                 )
                 kv_i += 1
             else:
-                x, nk, nv = layer(x, cache_k[kv_i], cache_v[kv_i], positions, write_index, kv_len)
+                # (an indexer flavor's ``cache_k`` is the pair (K, index keys))
+                own_k = jax.tree.map(lambda c: c[kv_i], cache_k)
+                x, nk, nv = layer(x, own_k, cache_v[kv_i], positions, write_index, kv_len)
                 new_ks.append(nk)
                 new_vs.append(nv)
                 kv_i += 1
@@ -1748,7 +1957,7 @@ class VLM(nn.Module):
         if two_pools:
             cache_k, cache_v = (pools[False][0], pools[True][0]), (pools[False][1], pools[True][1])
         if not paged:
-            cache_k, cache_v = jnp.stack(new_ks), jnp.stack(new_vs)
+            cache_k, cache_v = jax.tree.map(lambda *layers: jnp.stack(layers), *new_ks), jnp.stack(new_vs)
         out = (logits, cache_k, cache_v)
         if recurrent is None:
             return out
@@ -1783,4 +1992,7 @@ def init_cache(cfg: VLMConfig, batch: int, dtype=jnp.bfloat16, length: int | Non
         shape = (len(cfg.kv_layers), batch, 1, length or cfg.max_seq)
         return jnp.zeros((*shape, cfg.mla.cache_width), dtype), jnp.zeros((*shape, 0), dtype)
     shape = (len(cfg.kv_layers), batch, cfg.n_kv_heads, length or cfg.max_seq, cfg.head_dim)
+    if cfg.indexer is not None:  # beside K a row's index keys: the caches' first is the pair
+        index = jnp.zeros((*shape[:2], 1, shape[3], cfg.indexer.cache_width), dtype)
+        return (jnp.zeros(shape, dtype), index), jnp.zeros(shape, dtype)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

@@ -199,6 +199,23 @@ def init_latent_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16):
     return jnp.zeros((*shape, cfg.mla.cache_width), dtype), jnp.zeros((*shape, 0), dtype)
 
 
+def init_index_pool(cfg, n_blocks: int, block_size: int, dtype=jnp.bfloat16):
+    """The index keys of a flavor with a learned indexer (``cfg.indexer``): a
+    SECOND array a position beside K/V, ``[L, n_blocks, 1, block_size, W]``, one
+    key of ``indexer.head_dim`` values a position a layer in the first lanes
+    of a row of ``W`` = ``IndexerConfig.cache_width`` (64 values in a whole
+    128-lane row, zeros above: a 64-wide minor dimension is half a lane tile,
+    which XLA stores padded or twice over, PR 34's lesson; the row costs 256 B
+    where the key is 128, counted by ``stats()``'s ``index_pool_bytes_per_chip``;
+    two positions a row, or 8-bit keys, would halve it: ROADMAP R8). It has the
+    K pool's block dimension and no table of its own: block ``j`` of a row's
+    table holds its positions' K, V AND index keys, so the allocator, prefix
+    blocks and copy-on-write treat the three arrays as one
+    (:func:`latent_update` writes it: one row a position, as a latent pool)."""
+    shape = (len(cfg.kv_layers), n_blocks, 1, block_size, cfg.indexer.cache_width)
+    return jnp.zeros(shape, dtype)
+
+
 def latent_update(pool, rows, tables, write_index, *, layer_index=0):
     """Write a chunk's latent rows into the latent pool through the block
     table: :func:`paged_update`'s rule (index every pool dimension but the

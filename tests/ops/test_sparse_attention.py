@@ -1,0 +1,223 @@
+"""``ops/sparse_attention.py``: each Pallas kernel in interpret mode against its
+XLA lines (ties included), the choice against a sort in numpy, a decode step's
+gather against the dense lines under its mask, and the kernels compiled for a
+described v5e at the served widths."""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cosmos_curate_tpu.ops import sparse_attention as sa
+
+L, NB, BS, W, DI, HI = 2, 40, 4, 128, 8, 4
+HK, G, D = 2, 2, 16
+B, NBL = 3, 8
+S = NBL * BS
+# (T, write offsets, valid lengths): a decode step, a whole chunk, a chunk that
+# starts inside a block and one row of which is short of the others
+CHUNKS = {
+    "decode": (1, [5, 17, 30], [6, 18, 31]),
+    "chunk-of-8": (8, [0, 9, 20], [8, 17, 26]),
+    "ragged-chunk": (12, [3, 0, 18], [15, 7, 30]),
+}
+
+
+@pytest.fixture(scope="module")
+def pools():
+    rng = np.random.default_rng(0)
+    keys = jnp.asarray(rng.normal(size=(L, NB, 1, BS, DI)), jnp.bfloat16)
+    pool_i = jnp.zeros((L, NB, 1, BS, W), jnp.bfloat16).at[..., :DI].set(keys)
+    pool_k = jnp.asarray(rng.normal(size=(L, NB, HK, BS, D)), jnp.bfloat16)
+    pool_v = jnp.asarray(rng.normal(size=(L, NB, HK, BS, D)), jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(NB - 1)[: B * NBL].reshape(B, NBL) + 1, jnp.int32)
+    return pool_i, pool_k, pool_v, tables
+
+
+def _chunk(name, seed=1):
+    t, write, kv_len = CHUNKS[name]
+    rng = np.random.default_rng(seed)
+    qi = jnp.asarray(rng.normal(size=(B, t, HI, DI)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(B, t, HI)), jnp.float32)
+    return t, qi, w, jnp.asarray(write, jnp.int32), jnp.asarray(kv_len, jnp.int32)
+
+
+def _scores(pools, name, ties=False):
+    pool_i, _, _, tables = pools
+    t, qi, w, write, kv_len = _chunk(name)
+    scores = sa.index_scores(qi, w, pool_i, tables, write, kv_len, layer_index=1, use_kernel=False)
+    if ties:  # whole numbers: many equal scores, the choice must break them by position
+        scores = jnp.where(jnp.isfinite(scores), jnp.round(scores) + 0.0, scores)  # (+ 0.0: no -0.0, the op's contract)
+    live = jnp.minimum(kv_len[:, None], write[:, None] + jnp.arange(t)[None] + 1)
+    return scores, live, write, kv_len
+
+
+def _numpy_choice(scores, k):
+    """The definition: the k highest scores of a row, a tie to the lower position."""
+    out = np.zeros(scores.shape, bool)
+    for idx in np.ndindex(scores.shape[:-1]):
+        row = scores[idx]
+        order = sorted(range(row.size), key=lambda s: (-row[s], s))
+        taken = [s for s in order if np.isfinite(row[s])][:k]
+        out[idx][taken] = True
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(CHUNKS))
+def test_index_score_kernel_matches_its_xla_lines(pools, name):
+    pool_i, _, _, tables = pools
+    _, qi, w, write, kv_len = _chunk(name)
+    want = np.asarray(sa.index_scores(qi, w, pool_i, tables, write, kv_len, layer_index=1, use_kernel=False))
+    got = np.asarray(sa.index_scores(qi, w, pool_i, tables, write, kv_len, layer_index=1, use_kernel=True, interpret=True))
+    seen = np.isfinite(want)
+    assert (np.isfinite(got) == seen).all()  # -inf exactly where a query may not choose
+    np.testing.assert_allclose(got[seen], want[seen], atol=1e-5)
+    t = qi.shape[1]
+    pos = np.arange(S)[None, None]
+    q_pos = np.asarray(write)[:, None, None] + np.arange(t)[None, :, None]
+    assert (seen == ((pos <= q_pos) & (pos < np.asarray(kv_len)[:, None, None]))).all()
+
+
+def test_index_scores_are_the_formula(pools):
+    """sum_j w[j] * relu(q[j] . k), in float64 numpy, out of the paged array."""
+    pool_i, _, _, tables = pools
+    _, qi, w, write, kv_len = _chunk("ragged-chunk")
+    got = np.asarray(sa.index_scores(qi, w, pool_i, tables, write, kv_len, layer_index=1, use_kernel=False))
+    keys = np.asarray(pool_i[1], np.float64)[np.asarray(tables)][:, :, 0].reshape(B, S, W)[..., :DI]
+    dots = np.einsum("bthd,bsd->bths", np.asarray(qi, np.float64), keys)
+    want = (np.maximum(dots, 0) * np.asarray(w, np.float64)[..., None]).sum(axis=2)
+    seen = np.isfinite(got)
+    np.testing.assert_allclose(got[seen], want[seen], atol=1e-4)
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("k", [3, 6, 40])
+@pytest.mark.parametrize("name", sorted(CHUNKS))
+def test_select_kernel_and_sort_choose_what_the_definition_chooses(pools, name, k, ties):
+    scores, live, _, _ = _scores(pools, name, ties)
+    want = _numpy_choice(np.asarray(scores), k)
+    by_sort = sa.chosen_mask(scores, *sa.select_threshold(scores, k, live, use_kernel=False))
+    by_kernel = sa.chosen_mask(scores, *sa.select_threshold(scores, k, live, use_kernel=True, interpret=True))
+    np.testing.assert_array_equal(np.asarray(by_sort), want)
+    np.testing.assert_array_equal(np.asarray(by_kernel), want)
+    assert (want.sum(-1) == np.minimum(np.asarray(live), k)).all()  # every position while there are no more than k
+
+
+def test_order_key_keeps_the_order_of_floats():
+    x = np.array([-np.inf, -3.5, -1e-30, 0.0, 1e-30, 2.0, 7.25, np.inf], np.float32)
+    keys = np.asarray(sa.order_key(jnp.asarray(x)))
+    assert (np.diff(keys.astype(np.int64)) > 0).all() and keys[0] == sa.KEY_UNSEEN
+
+
+@pytest.mark.parametrize("name", sorted(CHUNKS))
+def test_prefill_kernel_matches_dense_attention_under_the_mask(pools, name):
+    _, pool_k, pool_v, tables = pools
+    scores, live, write, kv_len = _scores(pools, name, ties=True)
+    chosen = sa.chosen_mask(scores, *sa.select_threshold(scores, 6, live, use_kernel=False))
+    t = scores.shape[1]
+    q = jnp.asarray(np.random.default_rng(4).normal(size=(B, t, HK, G, D)), jnp.bfloat16)
+    call = functools.partial(
+        sa.sparse_prefill_attention, q, pool_k, pool_v, tables, write, kv_len, chosen, layer_index=1
+    )
+    want, got = np.asarray(call(use_kernel=False), np.float32), np.asarray(call(use_kernel=True, interpret=True), np.float32)
+    np.testing.assert_allclose(got, want, atol=2e-2)  # the kernel hands back bfloat16
+    # and the mask bites: attention over every visible position is something else
+    seen = jnp.isfinite(scores)
+    dense = np.asarray(sa.sparse_prefill_attention(q, pool_k, pool_v, tables, write, kv_len, seen, layer_index=1, use_kernel=False))
+    if t > 1:
+        assert np.abs(dense - want).max() > 0.05
+
+
+def test_decode_reads_the_chosen_positions_and_no_others(pools):
+    """The gather's result is the dense lines under the chosen set's mask; a
+    position that was not chosen can hold anything (NaN) and change nothing."""
+    _, pool_k, pool_v, tables = pools
+    scores, _, write, kv_len = _scores(pools, "decode", ties=True)
+    k = 6
+    positions, valid, tau, p_star = sa.decode_positions(scores[:, 0], k)
+    chosen = sa.chosen_mask(scores[:, 0], tau, p_star)
+    np.testing.assert_array_equal(np.asarray(chosen), _numpy_choice(np.asarray(scores[:, 0]), k))
+    for b in range(B):  # the positions ARE the set
+        assert sorted(np.asarray(positions[b])[np.asarray(valid[b])]) == np.nonzero(np.asarray(chosen[b]))[0].tolist()
+    q = jnp.asarray(np.random.default_rng(5).normal(size=(B, HK, G, D)), jnp.bfloat16)
+    got = sa.sparse_decode_attention(q, pool_k, pool_v, tables, positions, valid, layer_index=1)
+    want = sa.sparse_prefill_attention(
+        q[:, None], pool_k, pool_v, tables, write, kv_len, chosen[:, None], layer_index=1, use_kernel=False
+    )[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # poison every position of the rows' blocks that was NOT chosen
+    flat = np.asarray(pool_k, np.float32).copy()
+    for b in range(B):
+        for s in range(S):
+            if not bool(chosen[b, s]):
+                flat[1, int(tables[b, s // BS]), :, s % BS] = np.nan
+    poisoned = sa.sparse_decode_attention(q, jnp.asarray(flat, jnp.bfloat16), pool_v, tables, positions, valid, layer_index=1)
+    np.testing.assert_allclose(np.asarray(poisoned), np.asarray(got), atol=1e-6)
+
+
+def test_a_row_with_fewer_positions_than_k_takes_them_all(pools):
+    scores, _, _, kv_len = _scores(pools, "decode")
+    positions, valid, tau, p_star = sa.decode_positions(scores[:, 0], 12)
+    assert np.asarray(valid.sum(-1)).tolist() == np.minimum(np.asarray(kv_len), 12).tolist()
+    assert int(tau[0]) == sa.KEY_UNSEEN  # row 0 sees 6 positions: nothing is left out
+
+
+def test_pack_choice_is_a_bit_a_position():
+    chosen = np.zeros((2, 70), bool)
+    chosen[0, [0, 31, 32, 69]] = True
+    chosen[1, 5] = True
+    words = np.asarray(sa.pack_choice(jnp.asarray(chosen)))
+    assert words.shape == (2, 3) and words.dtype == np.uint32
+    back = ((words[..., None] >> np.arange(32, dtype=np.uint32)) & 1).reshape(2, -1)[:, :70].astype(bool)
+    np.testing.assert_array_equal(back, chosen)
+
+
+# -- the chip's compiler, no chip ---------------------------------------------
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("lane", [8192, 32768])
+@pytest.mark.parametrize("kernel", ["index_score", "select", "prefill"])
+def test_kernel_compiles_for_v5e_at_the_served_widths(v5e, kernel, lane):
+    """Keye's widths (4 KV heads x 8 x 128, 16 index heads x 64 in 128 lanes,
+    blocks of 128, a 256-token chunk of two rows) in both of its lanes: block
+    shapes and VMEM the chip's compiler would refuse fail here."""
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows, t, bs, blocks = 2, 256, 128, 600
+    pool, pool_i = arg((8, blocks, 4, bs, 128), jnp.bfloat16), arg((8, blocks, 1, bs, 128), jnp.bfloat16)
+    tables, vec = arg((rows, lane // bs), jnp.int32), arg((rows,), jnp.int32)
+    if kernel == "index_score":
+        fn = functools.partial(sa.index_scores, layer_index=1, use_kernel=True, interpret=False)
+        args = (arg((rows, t, 16, 64), jnp.bfloat16), arg((rows, t, 16), jnp.float32), pool_i, tables, vec, vec)
+    elif kernel == "select":
+        fn = lambda s, n: sa.select_threshold(s, 2048, n, use_kernel=True, interpret=False)  # noqa: E731
+        args = (arg((rows, t, lane), jnp.float32), arg((rows, t), jnp.int32))
+    else:
+        fn = functools.partial(sa.sparse_prefill_attention, layer_index=1, use_kernel=True, interpret=False)
+        args = (arg((rows, t, 4, 8, 128), jnp.bfloat16), pool, pool, tables, vec, vec, arg((rows, t, lane), jnp.bool_))
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
