@@ -77,8 +77,10 @@ vllm_async_stage.py). TPU-first re-design:
   copy on write takes them along. The programs take the K pool's place as the
   pair (K, index keys) (``_pools``), write both through the table, score a
   query's visible positions against the index keys, and attend to the
-  ``top_k`` best: a decode step reads the K/V of the chosen positions only
-  (``ops/sparse_attention.py``). They also hand out what every layer's last
+  ``top_k`` best: a decode step walks the row's live pages under the chosen
+  set's mask, or past a lane length gathers the chosen positions' K/V only
+  (``ops/sparse_attention.py::decode_attention``; ``stats()`` counts the rows
+  of each and the positions read). They also hand out what every layer's last
   query chose, a bit a position (``_choice_digest``), for whoever compares it.
 - **one decode program of look-ahead**: a lane's decode program N+1 is
   dispatched before program N's tokens are read on the host (``_decode_once``:
@@ -853,10 +855,16 @@ class CaptionEngine:
         self._mla_decode_calls = 0
         # an indexer's decode steps (under _stats_lock), by the host's
         # arithmetic: calls of the chosen-set attention (one a layer a decode
-        # program), the positions its rows could see and those they read K/V of
+        # program), the positions its rows could see, those the softmax kept
+        # (``min(context, top_k)``) and those whose K/V the step READ, and the
+        # rows by the path they took (ops/sparse_attention.py::decode_walks:
+        # the walk reads a row's every live position, the gather the chosen)
         self._sparse_decode_calls = 0
         self._sparse_decode_positions_live = 0
         self._sparse_decode_positions_chosen = 0
+        self._sparse_decode_positions_read = 0
+        self._sparse_decode_rows_walked = 0
+        self._sparse_decode_rows_gathered = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
         # (owner_inflight_cap; None = ceil(total slots / active owners))
@@ -1793,6 +1801,9 @@ class CaptionEngine:
                 "sparse_decode_calls": self._sparse_decode_calls,
                 "sparse_decode_positions_live": self._sparse_decode_positions_live,
                 "sparse_decode_positions_chosen": self._sparse_decode_positions_chosen,
+                "sparse_decode_positions_read": self._sparse_decode_positions_read,
+                "sparse_decode_rows_walked": self._sparse_decode_rows_walked,
+                "sparse_decode_rows_gathered": self._sparse_decode_rows_gathered,
             }
 
     @property
@@ -1926,6 +1937,9 @@ class CaptionEngine:
             self._sparse_decode_calls = 0
             self._sparse_decode_positions_live = 0
             self._sparse_decode_positions_chosen = 0
+            self._sparse_decode_positions_read = 0
+            self._sparse_decode_rows_walked = 0
+            self._sparse_decode_rows_gathered = 0
             if self._expert_held is not None:
                 self._expert_held = jnp.zeros(2, jnp.int32)
             self._interleaved_steps = 0
@@ -3412,13 +3426,22 @@ class CaptionEngine:
                     last = positions // self.block_size
                     if self._indexed:
                         # a row's step reads the index keys of the positions it
-                        # can see and the K/V of those it chose, layer by layer
+                        # can see and, layer by layer, the K/V of those it chose
+                        # (the gather) or of all of them under the choice's mask
+                        # (the walk): the lane's length says which
+                        from cosmos_curate_tpu.ops.sparse_attention import decode_walks
+
                         layers, seen = len(self.cfg.kv_layers), positions[list(emitted)] + 1
+                        live, chosen = int(seen.sum()), int(np.minimum(seen, self.cfg.indexer.top_k).sum())
+                        walks = decode_walks(lane.length, self.block_size, self.cfg.head_dim)
                         self._sparse_decode_calls += layers
-                        self._sparse_decode_positions_live += layers * int(seen.sum())
-                        self._sparse_decode_positions_chosen += layers * int(
-                            np.minimum(seen, self.cfg.indexer.top_k).sum()
-                        )
+                        self._sparse_decode_positions_live += layers * live
+                        self._sparse_decode_positions_chosen += layers * chosen
+                        self._sparse_decode_positions_read += layers * (live if walks else chosen)
+                        if walks:
+                            self._sparse_decode_rows_walked += layers * len(seen)
+                        else:
+                            self._sparse_decode_rows_gathered += layers * len(seen)
                     if not self._windowed:
                         self._paged_decode_pages_walked += int((last + 1).sum())
                         self._paged_decode_pages_spanned += lane.table.size

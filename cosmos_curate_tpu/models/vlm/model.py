@@ -1474,8 +1474,11 @@ class DecoderLayer(nn.Module):
         the indexer's three projections as it feeds q, k and v; ``caches``: the
         pair (K, index keys), slot rows ``[B, 1, S, W]`` or the paged array
         ``[L, NB, 1, bs, W]`` beside the K pool. A decode step (one query a
-        row) reads the K/V of its chosen positions and no others; a longer chunk
-        attends densely under its queries' masks. What each row's last query
+        row) walks its row's live pages under the chosen set's mask where the
+        kernel runs and the lane is short enough for that to be the cheaper
+        read, and gathers the K/V of its chosen positions and no others elsewhere
+        (``sparse.decode_attention``: the one choice); a longer chunk attends
+        densely under its queries' masks. What each row's last query
         chose is sown as ``choice/digest`` (``pack_choice``: a bit a position): a
         program that asks for the collection hands it out, no other computes it.
         Returns (attn, (new K, new index keys), new V)."""
@@ -1521,14 +1524,12 @@ class DecoderLayer(nn.Module):
                 scores = sparse.index_scores_reference(
                     qi, w, cache_i[:, 0, :, : ix.head_dim], write_index, kv_len
                 )
-        if paged and t == 1:
-            with jax.named_scope("attn.select"):
-                chosen_at, valid, *numbers = sparse.decode_positions(scores[:, 0], ix.top_k)
-            with jax.named_scope("attn.sparse"):
-                attn = sparse.sparse_decode_attention(
-                    qk[:, 0], new_k, new_v, block_tables, chosen_at, valid, layer_index=layer_index,
-                    sm_scale=sm_scale,
-                )[:, None]
+        if paged and t == 1:  # (the scopes `attn.select` and `attn.sparse` are opened inside)
+            attn, *numbers = sparse.decode_attention(
+                qk[:, 0], new_k, new_v, block_tables, kv_len, scores[:, 0], ix.top_k, layer_index=layer_index,
+                sm_scale=sm_scale,
+            )
+            attn = attn[:, None]
             last = sparse.chosen_mask(scores[:, 0], *numbers)
         else:
             with jax.named_scope("attn.select"):

@@ -11,8 +11,8 @@ scores every position it can see with a few small heads,
 and attends, with its real heads, to the ``k`` positions that score highest: a
 tie goes to the lower position, and while a query sees no more than ``k``
 positions it sees them all, which is the dense paged path's mathematics. Four
-steps, each with its XLA lines and three of them, on a TPU, a Pallas kernel
-under a pinned name (a device trace's rows find it by that name):
+steps, each with its XLA lines and, on a TPU, a Pallas kernel under a pinned
+name (a device trace's rows find it by that name):
 
 - :func:`index_scores` (``_sparse_index_score``): one grid step a (row, block of
   queries); the loop walks the row's OWN table in groups of pages as far as the
@@ -35,19 +35,34 @@ under a pinned name (a device trace's rows find it by that name):
   bfloat16 tile a group of keys, copied beside the K/V pages): the same
   mathematics, every live page still read. A kernel that walks only chosen
   positions is ROADMAP R8's.
-- :func:`decode_positions` + :func:`sparse_decode_attention`: a decode step's
-  one query a row takes its positions from ``lax.top_k`` (16 rows of 32,768:
-  0.25 ms a layer) and reads ONLY those positions' K/V out of the pools:
-  position -> (block, offset) through the row's table, one XLA gather a pool.
-  NO Pallas kernel: Mosaic copies no slice of an HBM array under its tiling's
-  8 rows (``Slice shape along dimension 3 must be aligned to tiling (8)``, the
-  compiler for a described v5e, PR 40), and eight rows a chosen position are
-  the context's bytes again; XLA's gather moves a 256-byte row in 12 ns. A
-  pool whose positions are a leading dimension would let a kernel copy them
-  (ROADMAP R8).
+- :func:`decode_attention`: a decode step's one query a row, its choice and its
+  attention, by one of two reads (:func:`decode_walks`, below):
+  **the walk** (``_sparse_decode``): the choice from the threshold kernel at one
+  query a row (two numbers a row; 16 rows of a 32,768 lane 0.058 ms where
+  ``lax.top_k`` took 0.40), then the paged decode kernel's walk of the row's
+  OWN live pages (one grid step a row, one copy a page for all of its KV heads,
+  groups of 1,024 keys through two buffers, online softmax in float32) with the
+  chosen set as a mask on each group's scores: the row's float32 index scores
+  come in whole through a ``BlockSpec``, ``(tau, p_star)`` are prefetched scalars
+  and :func:`chosen_mask`'s test runs on the group's slice. It reads every live
+  position's K/V to attend to 2,048 of them, at HBM speed: 12 rows at 10-30
+  thousand positions 0.70 ms a layer, 87% of the bytes' time at 819 GB/s
+  (PERF.md, PR 41; groups of 128 / 256 / 512 / 2,048 keys 1.23 / 0.99 / 0.72 /
+  0.71; ``_sparse_prefill`` at one query a row 1.26).
+  **The gather** (:func:`decode_positions` + :func:`sparse_decode_attention`):
+  positions from ``lax.top_k``, then ONLY those positions' K/V out of the pools:
+  position -> (block, offset) through the row's table, one XLA gather a pool,
+  2.1 ms a layer for the same 12 rows WHATEVER their contexts (a 256-byte row in
+  13 ns). No kernel can do that read: Mosaic copies no slice of an HBM array
+  under its tiling's 8 rows (``Slice shape along dimension 3 must be aligned to
+  tiling (8)``, the compiler for a described v5e, PR 40). A pool whose
+  positions are a leading dimension would let a kernel copy them (ROADMAP R8).
 
 Which side runs is decided here and nowhere else: on a TPU the kernels (where
-the block size makes whole tiles: ``_kernel_ok``), elsewhere the XLA lines.
+the block size makes whole tiles: ``_kernel_ok``), elsewhere the XLA lines; and
+between a decode step's two reads by the lane's length (``_WALK_MAX_LANE``: the
+walk's cost grows with a row's context, the gather's does not), so off the chip a
+decode step is the gather's XLA lines, as it always was.
 Tests pick with ``use_kernel=`` / ``interpret=``, or patch ``_on_tpu``.
 """
 
@@ -61,10 +76,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from cosmos_curate_tpu.ops.tiling import round_up
+from cosmos_curate_tpu.ops.tiling import round_up, sublanes
 
 _NEG_INF = -1e30  # attention's mask: finite, so that a row wholly masked stays a number
-_GROUP_KEYS = 1024  # positions a trip of the scoring and prefill kernels' loops covers
+_GROUP_KEYS = 1024  # positions a trip of the scoring, prefill and decode kernels' loops covers
+# the longest lane whose decode steps WALK their rows' live pages (the gather
+# beyond): the walk costs 2.76 ns a live position a layer (12 rows: 0.57 / 1.11 /
+# 1.64 / 2.17 ms at 16 / 32 / 48 / 64 thousand positions each, 91% of HBM peak),
+# the gather 2.08 ms whatever the contexts, so they cross at 63 thousand
+# positions a row, and with the choice each needs (the threshold kernel 0.09 ms,
+# ``lax.top_k`` 0.88 at this lane, 1.97 at 131,072) at 87 thousand: a lane of
+# 65,536 never holds such a row, a lane of 131,072 does (past 118 thousand:
+# 4.47 ms against 4.05 at the full lane); one v5e, PERF.md, PR 41
+_WALK_MAX_LANE = 65536
 _INT_MIN = -(2**31)
 # order_key(-inf): what no query may choose
 KEY_UNSEEN = (0xFF800000 ^ 0x7FFFFFFF) - 2**32
@@ -260,12 +284,16 @@ def select_threshold_reference(scores, k: int):
     return order_key(vals[..., -1]), idx[..., -1].astype(jnp.int32)
 
 
+def _in_choice(key, pos, tau, p_star):
+    """The test both forms of the mask make: a key above the threshold, or equal
+    to it at a position up to ``p_star``; never what no query may choose."""
+    return (key > KEY_UNSEEN) & ((key > tau) | ((key == tau) & (pos <= p_star)))
+
+
 def chosen_mask(scores, tau, p_star):
     """``[B, T, S]`` bool: the positions a query attends to, from its two numbers."""
-    key = order_key(scores)
     pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-    tau, p_star = tau[..., None], p_star[..., None]
-    return (key > KEY_UNSEEN) & ((key > tau) | ((key == tau) & (pos <= p_star)))
+    return _in_choice(order_key(scores), pos, tau[..., None], p_star[..., None])
 
 
 def _select_kernel(live_ref, s_ref, o_ref, key_ref, *, k, chunk, pos_bits):
@@ -514,7 +542,7 @@ def sparse_prefill_attention(
     return sparse_reference_attention(q, k, v, chosen, sm_scale=sm_scale)
 
 
-# -- (c) a decode step's attention over the chosen positions only ----------------
+# -- (c) a decode step's attention over the chosen set: the gather, the walk, the choice
 
 
 def decode_positions(scores, k: int):
@@ -568,3 +596,148 @@ def sparse_decode_attention(q, pool_k, pool_v, tables, positions, valid, *, laye
     k = pool_k.reshape(-1, d)[row]
     v = pool_v.reshape(-1, d)[row]
     return sparse_reference_attention(q[:, None], k, v, valid[:, None], sm_scale=sm_scale)[:, 0]
+
+
+def _sparse_decode_kernel(
+    layer_ref, kvlen_ref, tau_ref, pstar_ref, tbl_ref, q_ref, s_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+    *, sm_scale, bs, pages,
+):
+    """``ops/paged_attention.py::_paged_decode_kernel``'s walk (one grid step a
+    row with all of its KV heads, one copy a live page a pool since a page's
+    ``[Hkv, bs, D]`` is contiguous, groups of ``pages`` table entries through two
+    buffers, as far as the row's valid length and no further) under the chosen
+    set's mask: the row's float32 index scores come in whole (``s_ref``: ``[1,
+    S]``), the choice as two prefetched numbers, and a group's mask is
+    :func:`chosen_mask`'s test on its slice of the scores (``-inf``, so masked,
+    at and past the valid length)."""
+    b = pl.program_id(0)
+    layer, kv_len, tau, p_star = layer_ref[0], kvlen_ref[b], tau_ref[b], pstar_ref[b]
+    hk, g_pad, d = q_ref.shape
+    group = pages * bs
+    n_pages = pl.cdiv(kv_len, bs)
+
+    def each_live_page(i, slot, act):
+        first = i * pages
+
+        def page(p, carry):  # no copy for an entry at or past the valid length: its block id is garbage
+            block = tbl_ref[b, first + p]
+            rows = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]), (v_hbm, v_buf, sems.at[1, slot])):
+                act(pltpu.make_async_copy(pool.at[layer, block], buf.at[slot, :, rows], sem))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, pages), page, 0)
+
+    @pl.when(b == 0)
+    def _():  # a dead page's V rows are multiplied by p = 0 and have to be finite for that
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    each_live_page(0, 0, lambda copy: copy.start())
+    q = q_ref[...]  # [hk, g_pad, d], the pool's type
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, group), 1)
+
+    def one_group(i, state):
+        slot = jax.lax.rem(i, 2)
+        each_live_page(i + 1, 1 - slot, lambda copy: copy.start())
+        each_live_page(i, slot, lambda copy: copy.wait())
+        k_start = i * group
+        key = order_key(s_ref[:, pl.ds(pl.multiple_of(k_start, group), group)])  # [1, group]
+        chosen = _in_choice(key, col + k_start, tau, p_star)
+        new = []
+        for h, (acc, m_prev, l_prev) in enumerate(state):
+            s = jax.lax.dot_general(
+                q[h], k_buf[slot, h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # [g_pad, group]
+            s = jnp.where(chosen, s * sm_scale, _NEG_INF)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p, v_buf[slot, h].astype(jnp.float32), (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            new.append((acc, m_new, l_new))
+        return tuple(new)
+
+    init = (
+        jnp.zeros((g_pad, d), jnp.float32),
+        jnp.full((g_pad, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((g_pad, 1), jnp.float32),
+    )
+    state = jax.lax.fori_loop(0, pl.cdiv(n_pages, pages), one_group, (init,) * hk)
+    for h, (acc, _, l) in enumerate(state):
+        o_ref[h] = acc / jnp.maximum(l, 1e-30)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "pages", "interpret"))
+def _sparse_decode(q, pool_k, pool_v, tables, kv_len, scores, tau, p_star, *, layer_index, sm_scale, pages, interpret):
+    b, hk, g, d = q.shape
+    nbl = tables.shape[1]
+    bs = pool_k.shape[3]
+    g_pad = round_up(g, sublanes(pool_k.dtype))
+    q = jnp.pad(q.astype(pool_k.dtype), ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
+    q_spec = pl.BlockSpec((None, hk, g_pad, d), lambda b_, *_: (b_, 0, 0, 0))
+    # a row's scores as a block of their own: a one-row slice of a tiled [B, S]
+    # array is a copy Mosaic refuses (under 8 rows)
+    s_spec = pl.BlockSpec((None, 1, nbl * bs), lambda b_, *_: (b_, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    group_buffer = pltpu.VMEM((2, hk, pages * bs, d), pool_k.dtype)
+    out = pl.pallas_call(
+        functools.partial(_sparse_decode_kernel, sm_scale=sm_scale, bs=bs, pages=pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b,),
+            in_specs=[q_spec, s_spec, any_spec, any_spec],
+            out_specs=q_spec,
+            scratch_shapes=[group_buffer, group_buffer, pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g_pad, d), jnp.float32),
+        # never "parallel": the V buffers zeroed in the first row serve every later one
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(
+        jnp.asarray(layer_index, jnp.int32).reshape(1), kv_len.astype(jnp.int32), tau.astype(jnp.int32),
+        p_star.astype(jnp.int32), tables.astype(jnp.int32), q, scores.astype(jnp.float32)[:, None], pool_k, pool_v,
+    )
+    return out[:, :, :g]
+
+
+def decode_walks(lane: int, bs: int, head_dim: int, *, use_kernel=None, interpret=None) -> bool:
+    """The one choice between the walk and the gather for a decode step over a
+    lane of ``lane`` positions (``tables.shape[1] * bs``, static): the walk where
+    the kernel runs at all (a TPU, whole tiles) and the lane is no longer than
+    ``_WALK_MAX_LANE``. The engine's counters ask the same question."""
+    use_kernel, interpret = _choose(use_kernel, interpret, bs)
+    return use_kernel and (interpret or head_dim % 128 == 0) and lane <= _WALK_MAX_LANE
+
+
+def decode_attention(
+    q, pool_k, pool_v, tables, kv_len, scores, k: int, *, layer_index=0, sm_scale=None,
+    use_kernel=None, interpret=None,
+):
+    """A decode step's choice and its attention over the chosen set, one query a
+    row. q: ``[B, Hkv, G, D]``; pools: ``[L, NB, Hkv, bs, D]``; tables: ``[B,
+    nbl]``; scores: ``[B, nbl * bs]`` float32 (:func:`index_scores` of the one
+    query, ``-inf`` at and past ``kv_len``). Returns (attention ``[B, Hkv, G, D]``
+    float32, tau ``[B]``, p_star ``[B]``): :func:`chosen_mask` of the two numbers
+    is the set the softmax saw. What feeds the mask stands under the scope
+    ``attn.select``, the attention under ``attn.sparse``."""
+    sm_scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
+    nbl, bs = tables.shape[1], pool_k.shape[3]
+    if decode_walks(nbl * bs, bs, q.shape[-1], use_kernel=use_kernel, interpret=interpret):
+        interpret = _choose(True, interpret)[1]
+        with jax.named_scope("attn.select"):
+            tau, p_star = _sparse_select(scores, kv_len, k=k, interpret=interpret)
+        with jax.named_scope("attn.sparse"):
+            attn = _sparse_decode(
+                q, pool_k, pool_v, tables, kv_len, scores, tau, p_star, layer_index=layer_index,
+                sm_scale=float(sm_scale), pages=_group_pages(bs, nbl), interpret=interpret,
+            )
+        return attn, tau, p_star
+    with jax.named_scope("attn.select"):
+        positions, valid, tau, p_star = decode_positions(scores, k)
+    with jax.named_scope("attn.sparse"):
+        attn = sparse_decode_attention(
+            q, pool_k, pool_v, tables, positions, valid, layer_index=layer_index, sm_scale=sm_scale
+        )
+    return attn, tau, p_star

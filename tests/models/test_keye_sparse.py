@@ -310,7 +310,8 @@ def test_engine_prefill_then_decode_match_the_reference(params, n, lane):
 def test_the_kernels_in_the_engine_are_the_xla_lines(params, monkeypatch):
     """The same requests with ``ops/sparse_attention.py`` on its Pallas kernels
     (interpret mode): the scoring, threshold and masked-prefill kernels in the
-    prefill programs. Logits as on the XLA lines, sets identical."""
+    prefill programs (and the decode programs' walk: the test below). Logits as
+    on the XLA lines, sets identical."""
     prompts = {"a": _ids(60, seed=3), "b": _ids(40, seed=4)}
 
     def serve():
@@ -339,6 +340,47 @@ def test_the_kernels_in_the_engine_are_the_xla_lines(params, monkeypatch):
         np.testing.assert_array_equal(kernels.first_sets[name], plain.first_sets[name])
         assert _rel(kernels.first[name], plain.first[name]) < 0.02
         assert kernels.tokens[name] == plain.tokens[name]
+
+
+@pytest.mark.parametrize("n,lane", [(40, 64), (100, 128)], ids=["the-short-lane", "the-long-lane"])
+def test_a_decode_step_walks_on_the_kernels_and_gathers_on_the_xla_lines(params, monkeypatch, n, lane):
+    """Prefill and 8 decode steps twice, on the XLA lines (a decode step gathers
+    its chosen positions) and on the Pallas kernels in interpret mode (it walks
+    its row's live pages under the chosen set's mask, ``_sparse_decode``): the
+    same tokens, the same ``choice/digest`` at every step, and counters that say
+    which path ran and what it read."""
+    steps = 8
+
+    def serve():
+        engine = _engine(params)
+        spy = _Spy(engine)
+        try:
+            engine.add_request(CaptionRequest("r", _ids(n, seed=n), sampling=SamplingConfig(max_new_tokens=steps + 1)))
+            engine.run_until_complete()
+            return spy, engine.stats()
+        finally:
+            engine.shutdown()
+
+    plain, gathered = serve()
+    called = []
+    walk = sparse._sparse_decode
+    monkeypatch.setattr(sparse, "_sparse_decode", lambda *a, **kw: (called.append(a[3].shape[1] * BLOCK), walk(*a, **kw))[1])
+    monkeypatch.setattr(sparse, "_on_tpu", lambda: True)
+    kernels, walked = serve()
+    assert set(called) == {lane}  # traced once a decode program of the request's lane
+    assert kernels.tokens["r"] == plain.tokens["r"] and len(plain.steps["r"]) == steps
+    for step in range(steps):
+        np.testing.assert_array_equal(kernels.step_sets["r"][step], plain.step_sets["r"][step])
+        assert _rel(kernels.steps["r"][step], plain.steps["r"][step]) < 0.02
+    contexts = [n + 1 + step for step in range(steps)]
+    live = CFG.n_layers * sum(contexts)
+    chosen = CFG.n_layers * sum(min(c, TOP_K) for c in contexts)
+    for stats in (gathered, walked):  # what they read before, whichever path ran
+        assert stats["sparse_decode_positions_live"] == live and stats["sparse_decode_positions_chosen"] == chosen
+    rows = CFG.n_layers * steps
+    assert (gathered["sparse_decode_rows_gathered"], gathered["sparse_decode_rows_walked"]) == (rows, 0)
+    assert (walked["sparse_decode_rows_gathered"], walked["sparse_decode_rows_walked"]) == (0, rows)
+    assert gathered["sparse_decode_positions_read"] == chosen and walked["sparse_decode_positions_read"] == live
 
 
 @pytest.mark.parametrize("prefix_len", [8, 9], ids=["two-whole-blocks", "a-partial-tail-block"])

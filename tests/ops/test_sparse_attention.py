@@ -1,7 +1,8 @@
 """``ops/sparse_attention.py``: each Pallas kernel in interpret mode against its
 XLA lines (ties included), the choice against a sort in numpy, a decode step's
-gather against the dense lines under its mask, and the kernels compiled for a
-described v5e at the served widths."""
+gather and its walk against the dense lines under their mask, the one choice
+between the two, and the kernels compiled for a described v5e at the served
+widths."""
 
 import functools
 import os
@@ -175,6 +176,105 @@ def test_pack_choice_is_a_bit_a_position():
     np.testing.assert_array_equal(back, chosen)
 
 
+
+# -- a decode step's walk under the chosen set's mask ---------------------------
+
+WALK_G, WALK_NBL = 8, 4  # Keye's eight query rows a KV head; a lane of four pages
+
+
+def _walk_case(bs, ties):
+    """Five rows of a four-page lane against a top-k of ``bs + bs // 2``: a context
+    below it, a dead row between live ones, one AT it, one past it whose last page
+    is partly filled, and the whole lane. Table entries past a row's valid length
+    name a block of NaNs: a kernel that copied one would say so."""
+    rng = np.random.default_rng(bs + ties)
+    k, lane = bs + bs // 2, WALK_NBL * bs
+    kv_len = np.array([bs - 3, 0, k, 2 * bs + bs // 3, lane], np.int32)
+    nb = len(kv_len) * WALK_NBL + 2
+    poison = nb - 1
+    pool_k = np.asarray(rng.normal(size=(L, nb, HK, bs, D)), np.float32)
+    pool_v = np.asarray(rng.normal(size=(L, nb, HK, bs, D)), np.float32)
+    pool_k[:, poison], pool_v[:, poison] = np.nan, np.nan
+    tables = rng.permutation(nb - 2)[: len(kv_len) * WALK_NBL].reshape(-1, WALK_NBL) + 1
+    tables = np.where(np.arange(WALK_NBL)[None] * bs < kv_len[:, None], tables, poison)
+    scores = rng.normal(size=(len(kv_len), lane)) * 2
+    if ties:  # whole numbers: the k-th largest score is shared, the mask must break it by position
+        scores = np.round(scores)
+    scores = np.where(np.arange(lane)[None] < kv_len[:, None], scores + 0.0, -np.inf)
+    q = jnp.asarray(rng.normal(size=(len(kv_len), HK, WALK_G, D)), jnp.bfloat16)
+    return (
+        q, jnp.asarray(pool_k, jnp.bfloat16), jnp.asarray(pool_v, jnp.bfloat16), jnp.asarray(tables, jnp.int32),
+        jnp.asarray(kv_len), jnp.asarray(scores, jnp.float32), k,
+    )
+
+
+@pytest.mark.parametrize("pages", [1, 2, WALK_NBL], ids=["a-page-a-trip", "two-pages-a-trip", "the-lane-a-trip"])
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("bs", [16, 128])
+def test_decode_walk_is_dense_attention_under_the_mask_and_the_gather(bs, ties, pages):
+    q, pool_k, pool_v, tables, kv_len, scores, k = _walk_case(bs, ties)
+    live = np.asarray(kv_len) > 0
+    positions, valid, tau, p_star = sa.decode_positions(scores, k)
+    chosen = sa.chosen_mask(scores, tau, p_star)
+    assert np.asarray(chosen.sum(-1)).tolist() == np.minimum(np.asarray(kv_len), k).tolist()
+    if ties:  # the threshold's score is shared with a position the set leaves out
+        key = np.asarray(sa.order_key(scores))
+        assert any(((key[b] == int(tau[b])) & ~np.asarray(chosen[b])).any() for b in (3, 4))
+    got = sa._sparse_decode(
+        q, pool_k, pool_v, tables, kv_len, scores, tau, p_star, layer_index=1, sm_scale=D**-0.5, pages=pages,
+        interpret=True,
+    )
+    got = np.asarray(got)
+    assert got.shape == (5, HK, WALK_G, D) and got.dtype == np.float32 and np.isfinite(got).all()
+    assert not got[1].any()  # the dead row walked nothing
+    # the XLA lines over the rows' own pages (a dead entry's NaNs replaced: the mask multiplies them)
+    safe = jnp.where(jnp.arange(WALK_NBL)[None] * bs < kv_len[:, None], tables, 0)
+    k_rows, v_rows = sa._pages_in_order(pool_k, safe, 1), sa._pages_in_order(pool_v, safe, 1)
+    want = sa.sparse_reference_attention(q[:, None], k_rows, v_rows, chosen[:, None], sm_scale=D**-0.5)[:, 0]
+    np.testing.assert_allclose(got[live], np.asarray(want)[live], atol=2e-5)
+    gathered = sa.sparse_decode_attention(q, pool_k, pool_v, tables, positions, valid, layer_index=1)
+    np.testing.assert_allclose(got[live], np.asarray(gathered)[live], atol=2e-5)
+    # and the mask bites: attention over every live position is something else
+    dense = sa.sparse_reference_attention(q[:, None], k_rows, v_rows, jnp.isfinite(scores)[:, None], sm_scale=D**-0.5)
+    assert np.abs(np.asarray(dense)[3:, 0] - got[3:]).max() > 0.05
+
+
+@pytest.mark.parametrize(
+    "lane,bs,head_dim,on_tpu,interpret,walks",
+    [
+        (sa._WALK_MAX_LANE, 128, 128, True, False, True),
+        (sa._WALK_MAX_LANE + 128, 128, 128, True, False, False),
+        (8192, 128, 128, False, False, False),
+        (8192, 4, 128, True, False, False),
+        (8192, 128, 64, True, False, False),
+        (64, 4, 16, True, True, True),
+    ],
+    ids=["at-the-crossover", "a-page-past-it", "off-the-chip", "blocks-under-a-tile", "half-a-lane-tile", "interpret-mode"],
+)
+def test_the_one_choice_between_the_walk_and_the_gather(monkeypatch, lane, bs, head_dim, on_tpu, interpret, walks):
+    monkeypatch.setattr(sa, "_on_tpu", lambda: on_tpu)
+    assert sa.decode_walks(lane, bs, head_dim, interpret=interpret) is walks
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["the-gather", "the-walk"])
+def test_decode_attention_takes_the_path_the_choice_names(monkeypatch, use_kernel):
+    q, pool_k, pool_v, tables, kv_len, scores, k = _walk_case(16, True)
+    called = []
+    for name in ("_sparse_decode", "_sparse_select", "sparse_decode_attention", "decode_positions"):
+        fn = getattr(sa, name)
+        monkeypatch.setattr(sa, name, lambda *a, _f=fn, _n=name, **kw: (called.append(_n), _f(*a, **kw))[1])
+    attn, tau, p_star = sa.decode_attention(
+        q, pool_k, pool_v, tables, kv_len, scores, k, layer_index=1, use_kernel=use_kernel, interpret=True
+    )
+    assert called == (["_sparse_select", "_sparse_decode"] if use_kernel else ["decode_positions", "sparse_decode_attention"])
+    want_tau, want_p = sa.decode_positions(scores, k)[2:]
+    live = np.asarray(kv_len) > k  # (a row that leaves nothing out may name any threshold under its scores)
+    np.testing.assert_array_equal(
+        np.asarray(sa.chosen_mask(scores, tau, p_star)), np.asarray(sa.chosen_mask(scores, want_tau, want_p))
+    )
+    assert (np.asarray(tau)[live] == np.asarray(want_tau)[live]).all() and attn.shape == q.shape
+
+
 # -- the chip's compiler, no chip ---------------------------------------------
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -199,7 +299,7 @@ def v5e():
 
 
 @pytest.mark.parametrize("lane", [8192, 32768])
-@pytest.mark.parametrize("kernel", ["index_score", "select", "prefill"])
+@pytest.mark.parametrize("kernel", ["index_score", "select", "prefill", "decode"])
 def test_kernel_compiles_for_v5e_at_the_served_widths(v5e, kernel, lane):
     """Keye's widths (4 KV heads x 8 x 128, 16 index heads x 64 in 128 lanes,
     blocks of 128, a 256-token chunk of two rows) in both of its lanes: block
@@ -217,6 +317,9 @@ def test_kernel_compiles_for_v5e_at_the_served_widths(v5e, kernel, lane):
     elif kernel == "select":
         fn = lambda s, n: sa.select_threshold(s, 2048, n, use_kernel=True, interpret=False)  # noqa: E731
         args = (arg((rows, t, lane), jnp.float32), arg((rows, t), jnp.int32))
+    elif kernel == "decode":  # a decode step's rows: the threshold kernel at one query a row, then the walk
+        fn = lambda *a: sa.decode_attention(*a, 2048, layer_index=1, use_kernel=True, interpret=False)[0]  # noqa: E731
+        args = (arg((rows, 4, 8, 128), jnp.bfloat16), pool, pool, tables, vec, arg((rows, lane), jnp.float32))
     else:
         fn = functools.partial(sa.sparse_prefill_attention, layer_index=1, use_kernel=True, interpret=False)
         args = (arg((rows, t, 4, 8, 128), jnp.bfloat16), pool, pool, tables, vec, vec, arg((rows, t, lane), jnp.bool_))
