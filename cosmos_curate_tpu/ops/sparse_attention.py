@@ -23,13 +23,19 @@ name (a device trace's rows find it by that name):
   (the future, the lane past the row's length) reads ``-inf``.
 - :func:`select_threshold` (``_sparse_select``): the choice WITHOUT a sort. The
   float32 scores map to int32 keys of the same order (:func:`order_key`); the
-  ``k``-th largest key is found bit by bit (32 counts of ``key >= candidate``
-  over the row's live lanes, held in VMEM), then among the keys EQUAL to it the
-  position up to which they are taken (one more bisection, over positions). A
-  query's choice is then two numbers ``(tau, p_star)`` and an elementwise test
-  (:func:`chosen_mask`); XLA's sort-based ``top_k`` of 2,048 out of 32,768 for a
-  256-query chunk took 5.8 ms on one v5e, this arithmetic in XLA 1.0 ms (PERF.md,
-  PR 40). A block of queries that sees no more than ``k`` positions costs nothing.
+  ``k``-th largest key is found bit by bit (counts of ``key >= candidate`` over
+  the live lanes of a block of 16 queries, held in VMEM) ONLY AS FAR AS THE SET
+  IS OPEN: once every query of the block has exactly ``k`` keys at or above its
+  prefix the search ends (float32 scores of 10-30 thousand positions part at
+  bit 22-26 of 32) and one pass reads the two numbers off the set; the second
+  bisection, over the positions of the keys EQUAL to the threshold, runs only
+  in a block where such a tie straddles ``k``. A query's choice is then two
+  numbers ``(tau, p_star)`` and an elementwise test (:func:`chosen_mask`);
+  XLA's sort-based ``top_k`` of 2,048 out of 32,768 for a 256-query chunk took
+  5.8 ms on one v5e, this arithmetic in XLA 1.0 ms (PERF.md, PR 40), the kernel
+  with all its 49 passes 0.65 ms at 30 thousand positions and 0.29 since it
+  stops (PR 42). A block of queries that sees no more than ``k`` positions
+  costs nothing.
 - :func:`sparse_prefill_attention` (``_sparse_prefill``): a chunk's attention as
   the paged prefill kernel's dense walk UNDER THE CHOSEN SET'S MASK (an additive
   bfloat16 tile a group of keys, copied beside the K/V pages): the same
@@ -93,6 +99,19 @@ _INT_MIN = -(2**31)
 # order_key(-inf): what no query may choose
 KEY_UNSEEN = (0xFF800000 ^ 0x7FFFFFFF) - 2**32
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the threshold kernel's block of queries and how often its value search asks
+# whether it may stop. Every counting pass ends in a sum across lanes and a
+# handful of operations on one number a query that the next pass waits for,
+# about 0.17 us whatever the context where a block's 15 loop trips at 30
+# thousand positions take 0.23, and a block of 16 queries pays that once where
+# two blocks of 8 pay it twice (a 256-query chunk at 30.5k: 0.343 ms at 8 rows
+# and trips of 2,048 lanes, 0.293 at 16 rows x 1,024, 0.276 at 32 x 512, where
+# a decode step's 12 rows take 0.043 ms against 0.034: they fill half a block).
+# The flag is a vector made a scalar, which the loop has to wait for: read
+# every bit 0.400 ms, every second 0.379, every fourth 0.387 (8 rows, 1,024
+# lanes); one v5e, PERF.md, PR 42
+_SELECT_ROWS = 16
+_BITS_A_CHECK = 2
 
 
 def _on_tpu() -> bool:
@@ -297,31 +316,40 @@ def chosen_mask(scores, tau, p_star):
 
 
 def _select_kernel(live_ref, s_ref, o_ref, key_ref, *, k, chunk, pos_bits):
-    """One grid step is eight queries and the lanes any of them can see
-    (``live_ref``: the most, prefetched), in chunks of ``chunk`` lanes. The
-    answers leave in lanes 0 (``tau``) and 1 (``p_star``) of a 128-lane row."""
+    """One grid step is a block of queries (``_SELECT_ROWS``) and the lanes any
+    of them can see (``live_ref``: the most, prefetched), in chunks of ``chunk``
+    lanes. It counts only as long as the answer is open. The value search
+    carries, a query, how many keys stand at or above its prefix, and ends when
+    that is ``k`` for every query of the block (the set is settled, whatever
+    the lower bits of ``tau`` are; it asks every ``_BITS_A_CHECK`` bits); one
+    closing pass then reads ``tau`` (the least key of the set) and ``p_star``
+    (the last position that holds it). Only a block in which, after all 32
+    bits, some query still has more than ``k`` keys at or above ``tau`` (a tie
+    straddles ``k``) counts on: how many of the equal keys it needs, and the
+    position of the last of those, bit by bit. A query with fewer than ``k``
+    keys to choose from leaves nothing out, is not searched for and answers
+    ``(KEY_UNSEEN, s)`` as a block of such queries does. The answers leave in
+    lanes 0 (``tau``) and 1 (``p_star``) of a 128-lane row; lanes 2 and 3 say
+    what the block did: value passes run, and whether the tie search ran."""
     n_live = live_ref[pl.program_id(0)]
     n_chunks = pl.cdiv(n_live, chunk)
     rows, s = s_ref.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
 
-    def answer(tau, p_star):
-        o_ref[...] = jnp.where(lane == 0, tau, jnp.where(lane == 1, p_star, 0))
+    def answer(tau, p_star, passes, tied):
+        o_ref[...] = jnp.where(
+            lane == 0, tau, jnp.where(lane == 1, p_star, jnp.where(lane == 2, passes, jnp.where(lane == 3, tied, 0)))
+        )
 
     @pl.when(n_live <= k)
     def _():  # nothing to leave out
-        answer(jnp.int32(KEY_UNSEEN), jnp.int32(s))
+        answer(jnp.int32(KEY_UNSEEN), jnp.int32(s), 0, 0)
 
     @pl.when(n_live > k)
     def _():
         def cols(c):
             return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
-        def to_keys(c, carry):
-            key_ref[:, cols(c)] = order_key(s_ref[:, cols(c)])
-            return carry
-
-        jax.lax.fori_loop(0, n_chunks, to_keys, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1)
 
         def count(pred):
@@ -331,25 +359,76 @@ def _select_kernel(live_ref, s_ref, o_ref, key_ref, *, k, chunk, pos_bits):
             acc = jax.lax.fori_loop(0, n_chunks, body, jnp.zeros((rows, chunk), jnp.int32))
             return acc.sum(axis=1, keepdims=True)
 
-        def value_bit(bit, ans):  # in the order of unsigned keys (key ^ INT_MIN), the top bit first
+        def to_keys(c, acc):
+            key = order_key(s_ref[:, cols(c)])
+            key_ref[:, cols(c)] = key
+            return acc + (key > KEY_UNSEEN).astype(jnp.int32)
+
+        # the keys a query may choose from; with fewer than k it takes them all
+        n_seen = jax.lax.fori_loop(0, n_chunks, to_keys, jnp.zeros((rows, chunk), jnp.int32)).sum(axis=1, keepdims=True)
+        searched = n_seen >= k
+
+        def still_open(cnt):  # a scalar: some searched query's set still holds more than k keys
+            return jnp.max(jnp.where(searched & (cnt != k), 1, 0))
+
+        def value_bit(bit, ans, cnt):  # in the order of unsigned keys (key ^ INT_MIN), the top bit first
             cand = ans | jnp.left_shift(jnp.int32(1), 31 - bit)
             threshold = cand ^ jnp.int32(_INT_MIN)
-            return jnp.where(count(lambda key, _: key >= threshold) >= k, cand, ans)
+            # of the keys a query may choose (what it may not lies under every one of them)
+            above = jnp.minimum(count(lambda key, _: key >= threshold), n_seen)
+            take = above >= k
+            return jnp.where(take, cand, ans), jnp.where(take, above, cnt)
 
-        tau = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((rows, 1), jnp.int32)) ^ jnp.int32(_INT_MIN)
-        need = k - count(lambda key, _: key > tau)  # of the keys equal to tau, from the lowest position
+        def value_bits(state):
+            bit, ans, cnt, _ = state
+            for i in range(_BITS_A_CHECK):
+                ans, cnt = value_bit(bit + i, ans, cnt)
+            return bit + _BITS_A_CHECK, ans, cnt, still_open(cnt)
 
-        def position_bit(bit, ans):
-            cand = ans | jnp.left_shift(jnp.int32(1), pos_bits - 1 - bit)
-            return jnp.where(count(lambda key, pos: (key == tau) & (pos < cand)) < need, cand, ans)
+        passes, ans, _, tied = jax.lax.while_loop(
+            lambda state: (state[0] < 32) & (state[3] > 0),
+            value_bits,
+            (jnp.int32(0), jnp.zeros((rows, 1), jnp.int32), n_seen, still_open(n_seen)),
+        )
 
-        answer(tau, jax.lax.fori_loop(0, pos_bits, position_bit, jnp.zeros((rows, 1), jnp.int32)))
+        def searched_or_all(tau, p_star):
+            return jnp.where(searched, tau, KEY_UNSEEN), jnp.where(searched, p_star, s)
+
+        @pl.when(tied == 0)
+        def _():  # the set {choosable key >= prefix} holds exactly k keys: its least key, that key's last position
+            floor = jnp.maximum(ans ^ jnp.int32(_INT_MIN), jnp.int32(KEY_UNSEEN + 1))
+
+            def last_least(c, carry):
+                least, at = carry
+                key = key_ref[:, cols(c)]
+                better = (key >= floor) & (key <= least)  # (a later chunk is a higher position)
+                return jnp.where(better, key, least), jnp.where(better, c * chunk + col, at)
+
+            least, at = jax.lax.fori_loop(
+                0, n_chunks, last_least,
+                (jnp.full((rows, chunk), jnp.iinfo(jnp.int32).max, jnp.int32), jnp.full((rows, chunk), -1, jnp.int32)),
+            )
+            tau = least.min(axis=1, keepdims=True)
+            p_star = jnp.where(least == tau, at, -1).max(axis=1, keepdims=True)
+            answer(*searched_or_all(tau, p_star), passes, 0)
+
+        @pl.when(tied > 0)
+        def _():  # all 32 bits ran, so the prefix IS tau, and some query has more than k keys at or above it
+            tau = ans ^ jnp.int32(_INT_MIN)
+            need = k - count(lambda key, _: key > tau)  # of the keys equal to tau, from the lowest position
+
+            def position_bit(bit, ans):
+                cand = ans | jnp.left_shift(jnp.int32(1), pos_bits - 1 - bit)
+                return jnp.where(count(lambda key, pos: (key == tau) & (pos < cand)) < need, cand, ans)
+
+            p_star = jax.lax.fori_loop(0, pos_bits, position_bit, jnp.zeros((rows, 1), jnp.int32))
+            answer(*searched_or_all(tau, p_star), passes, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _sparse_select(scores, n_live, *, k, interpret):
+@functools.partial(jax.jit, static_argnames=("k", "interpret", "with_passes"))
+def _sparse_select(scores, n_live, *, k, interpret, with_passes=False):
     r, s = scores.shape
-    rows = 8
+    rows = _SELECT_ROWS
     r_pad = round_up(r, rows)
     chunk = math.gcd(s, _GROUP_KEYS)
     scores = jnp.pad(scores, ((0, r_pad - r), (0, 0)), constant_values=-jnp.inf)
@@ -367,23 +446,32 @@ def _sparse_select(scores, n_live, *, k, interpret):
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(live, scores)
-    return out[:r, 0], out[:r, 1]
+    return tuple(out[:r, i] for i in range(4 if with_passes else 2))
 
 
-def select_threshold(scores, k: int, n_live, *, use_kernel=None, interpret=None):
+def select_threshold(scores, k: int, n_live, *, use_kernel=None, interpret=None, with_passes=False):
     """The choice of every query as two numbers: ``tau``, the :func:`order_key`
     of its ``k``-th largest score, and ``p_star``, the position up to which the
     scores EQUAL to ``tau`` are taken (ties go to the lower position).
     scores: ``[B, T, S]`` with ``-inf`` for what may not be chosen; n_live: ``[B,
     T]``, how many positions each query may choose from (a bound on the work,
     not part of the answer). Returns ``(tau, p_star)``, each ``[B, T]`` int32;
-    :func:`chosen_mask` turns them into the set."""
+    :func:`chosen_mask` turns them into the set. A query that leaves nothing out
+    (no more than ``k`` positions to choose from) may name any threshold under
+    its scores. ``with_passes`` (the kernel's own account, for tests and
+    ``scripts/select_passes.py``; a program of its own) adds two ``[B, T]``
+    arrays: the value passes the query's block of ``_SELECT_ROWS`` ran (0-32) and
+    whether it ran the tie search."""
     use_kernel, interpret = _choose(use_kernel, interpret)
     if not use_kernel:
+        if with_passes:
+            raise ValueError("with_passes reads the kernel's counters: use_kernel must hold")
         return select_threshold_reference(scores, k)
     b, t, s = scores.shape
-    tau, p_star = _sparse_select(scores.reshape(b * t, s), n_live.reshape(b * t), k=k, interpret=interpret)
-    return tau.reshape(b, t), p_star.reshape(b, t)
+    out = _sparse_select(
+        scores.reshape(b * t, s), n_live.reshape(b * t), k=k, interpret=interpret, with_passes=with_passes
+    )
+    return tuple(x.reshape(b, t) for x in out)
 
 
 # -- (d) a prefill chunk's attention under the chosen set -----------------------

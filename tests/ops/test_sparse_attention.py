@@ -56,14 +56,16 @@ def _scores(pools, name, ties=False):
     return scores, live, write, kv_len
 
 
+def _numpy_order(row):
+    """The definition: what a query may choose, the highest score first, a tie to the lower position."""
+    return [s for s in sorted(range(row.size), key=lambda s: (-float(row[s]), s)) if np.isfinite(row[s])]
+
+
 def _numpy_choice(scores, k):
-    """The definition: the k highest scores of a row, a tie to the lower position."""
+    """The k first of the definition's order, as a mask."""
     out = np.zeros(scores.shape, bool)
     for idx in np.ndindex(scores.shape[:-1]):
-        row = scores[idx]
-        order = sorted(range(row.size), key=lambda s: (-row[s], s))
-        taken = [s for s in order if np.isfinite(row[s])][:k]
-        out[idx][taken] = True
+        out[idx][_numpy_order(scores[idx])[:k]] = True
     return out
 
 
@@ -94,17 +96,141 @@ def test_index_scores_are_the_formula(pools):
     np.testing.assert_allclose(got[seen], want[seen], atol=1e-4)
 
 
-@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
-@pytest.mark.parametrize("k", [3, 6, 40])
-@pytest.mark.parametrize("name", sorted(CHUNKS))
+def _numpy_numbers(scores, k):
+    """``(tau, p_star)`` by the definition: the key and the position of the k-th
+    in the order (score down, position up); rows with fewer than k to choose
+    from get None."""
+    def key(x):
+        i = int(np.float32(x).view(np.int32))
+        return i if i >= 0 else i ^ 0x7FFFFFFF
+
+    out = {}
+    for idx in np.ndindex(scores.shape[:-1]):
+        order = _numpy_order(scores[idx])
+        out[idx] = (key(scores[idx][order[k - 1]]), order[k - 1]) if len(order) >= k else None
+    return out
+
+
+def _bits(*patterns):
+    return np.array(patterns, np.uint32).view(np.float32)
+
+
+CRAFT_S, CRAFT_K = 64, 8
+ROWS = sa._SELECT_ROWS  # the queries a block of the threshold kernel holds
+
+
+def _asked(depth):
+    """The value passes a block runs whose last query settles after ``depth``
+    bits: the kernel asks whether it may stop every ``_BITS_A_CHECK`` bits."""
+    step = sa._BITS_A_CHECK
+    return -(-depth // step) * step
+
+
+def _crafted(name):
+    """Scores that force one of the threshold kernel's exits: ``[1, T, 64]``
+    against a top-k of 8, ``T`` queries in blocks of ``ROWS``. Returns (scores,
+    live, the value passes each block must have run or None, whether each block
+    must have run the tie search)."""
+    rng = np.random.default_rng(CRAFTED.index(name))
+    t, live = ROWS, np.full(ROWS, 40)
+
+    def spread(n, low, high):  # n distinct values, shuffled
+        return rng.permutation(np.linspace(low, high, n)).astype(np.float32)
+
+    def rows(values_of):  # a row: its live values in position order, -inf after them
+        out = np.full((t, CRAFT_S), -np.inf, np.float32)
+        for r in range(t):
+            out[r, : live[r]] = values_of(r)
+        return out
+
+    def ordinary(r):
+        return rng.standard_normal(live[r]).astype(np.float32) + 0.0
+
+    passes, tied = None, [False]
+    if name == "part-in-the-first-bit":  # k above zero, the others below: the sign bit settles the set
+        scores = rows(lambda r: rng.permutation(np.concatenate([spread(CRAFT_K, 1, 2), spread(32, -2, -1)])))
+        passes = [_asked(1)]
+    elif name == "equal-but-for-the-last-bit":  # the k-th and the next key part in bit 0: all 32 passes, no tie
+        pair = _bits(0x3F800001, 0x3F800000)
+        scores = rows(lambda r: rng.permutation(np.concatenate([spread(CRAFT_K - 1, 2, 3), pair, spread(31, 0.1, 0.9)])))
+        passes = [32]
+    elif name == "every-score-equal":
+        scores = rows(lambda r: np.full(live[r], 1.5, np.float32))
+        passes, tied = [32], [True]
+    elif name == "a-tie-in-one-row-of-two-blocks":  # row 3 needs 3 of 6 equal scores; every other row ties nowhere
+        t, live = 2 * ROWS, np.full(2 * ROWS, 40)
+        tying = np.concatenate([spread(5, 2, 3), np.full(6, 1.0, np.float32), spread(29, -1, 0.5)])
+        scores = rows(lambda r: rng.permutation(tying) if r == 3 else ordinary(r))
+        tied = [True, False]
+    elif name == "k-keys-exactly":  # row 2 may choose from exactly k: it takes them all, and its numbers are the sort's
+        live[2] = CRAFT_K
+        scores = rows(ordinary)
+    elif name == "denormals-and-padding":
+        tiny = np.concatenate([_bits(*range(1, 21)), [np.float32(0.0)], _bits(*range(0x80000001, 0x80000014))])
+        scores = rows(lambda r: rng.permutation(tiny))
+    elif name == "just-over-k":
+        live = np.full(ROWS, CRAFT_K + 1)
+        scores = rows(ordinary)
+    elif name == "the-block-that-straddles-k":  # a causal chunk: its first queries see fewer than k, one k, the rest more
+        live = np.arange(CRAFT_K - 3, CRAFT_K - 3 + ROWS)
+        scores = rows(ordinary)
+    elif name == "rows-at-different-depths":  # row 5 settles at bit 11 (21 passes), every other row at the sign bit
+        pair = _bits(0x3F800800, 0x3F800000)
+        deep = np.concatenate([spread(CRAFT_K - 1, 2, 3), pair, spread(31, 0.1, 0.9)])
+        shallow = np.concatenate([spread(CRAFT_K, 1, 2), spread(32, -2, -1)])
+        scores = rows(lambda r: rng.permutation(deep) if r == 5 else rng.permutation(shallow))
+        passes = [_asked(21)]
+    return scores[None], live[None], passes, tied
+
+
+CRAFTED = (
+    "part-in-the-first-bit", "equal-but-for-the-last-bit", "every-score-equal", "a-tie-in-one-row-of-two-blocks",
+    "k-keys-exactly", "denormals-and-padding", "just-over-k", "the-block-that-straddles-k", "rows-at-different-depths",
+)
+SELECT_CASES = [
+    pytest.param(name, k, ties, id=f"{name}-{k}-{'ties' if ties else 'distinct'}")
+    for ties in (False, True) for k in (3, 6, 40) for name in sorted(CHUNKS)
+] + [pytest.param(name, CRAFT_K, None, id=name) for name in CRAFTED]
+
+
+@pytest.mark.parametrize("name,k,ties", SELECT_CASES)
 def test_select_kernel_and_sort_choose_what_the_definition_chooses(pools, name, k, ties):
-    scores, live, _, _ = _scores(pools, name, ties)
+    """The masks are the definition's; wherever a query leaves something out the
+    kernel's two numbers ARE the sort's (and numpy's); and the kernel's own
+    account says which exit it took: never more than 32 value passes, fewer
+    where the keys part early, the tie search only in a block where a tie
+    straddles k."""
+    if name in CHUNKS:
+        scores, live, _, _ = _scores(pools, name, ties)
+        want_passes, want_tied = None, None
+    else:
+        scores, live, want_passes, want_tied = _crafted(name)
+        scores, live = jnp.asarray(scores), jnp.asarray(live, jnp.int32)
     want = _numpy_choice(np.asarray(scores), k)
-    by_sort = sa.chosen_mask(scores, *sa.select_threshold(scores, k, live, use_kernel=False))
-    by_kernel = sa.chosen_mask(scores, *sa.select_threshold(scores, k, live, use_kernel=True, interpret=True))
-    np.testing.assert_array_equal(np.asarray(by_sort), want)
-    np.testing.assert_array_equal(np.asarray(by_kernel), want)
+    by_sort = sa.select_threshold(scores, k, live, use_kernel=False)
+    tau, p_star, passes, tied = (
+        np.asarray(x) for x in sa.select_threshold(scores, k, live, use_kernel=True, interpret=True, with_passes=True)
+    )
+    np.testing.assert_array_equal(np.asarray(sa.chosen_mask(scores, *by_sort)), want)
+    np.testing.assert_array_equal(np.asarray(sa.chosen_mask(scores, jnp.asarray(tau), jnp.asarray(p_star))), want)
     assert (want.sum(-1) == np.minimum(np.asarray(live), k)).all()  # every position while there are no more than k
+    plain = sa.select_threshold(scores, k, live, use_kernel=True, interpret=True)  # the program everybody else runs
+    assert len(plain) == 2 and (np.asarray(plain[0]) == tau).all() and (np.asarray(plain[1]) == p_star).all()
+    live, passes, tied = np.asarray(live).reshape(-1), passes.reshape(-1), tied.reshape(-1) > 0
+    # a block (rows as the kernel lays them: batch x query, flattened) answers at once unless one of its queries chooses
+    starts = range(0, live.size, ROWS)
+    searches = np.repeat([(live[i : i + ROWS] > k).any() for i in starts], ROWS)[: live.size]
+    for flat, (idx, number) in enumerate(_numpy_numbers(np.asarray(scores), k).items()):
+        if live[flat] > k or (live[flat] == k and searches[flat]):
+            assert (int(tau[idx]), int(p_star[idx])) == number, (idx, "the kernel against the definition")
+        if live[flat] > k:
+            assert (int(by_sort[0][idx]), int(by_sort[1][idx])) == number, (idx, "the sort against the definition")
+    assert ((0 <= passes) & (passes <= 32)).all() and not passes[~searches].any() and not tied[~searches].any()
+    assert (passes[tied] == 32).all()
+    if want_passes is not None:
+        assert passes[::ROWS].tolist() == want_passes
+    if want_tied is not None:
+        assert tied.tolist() == np.repeat(want_tied, ROWS).tolist()
 
 
 def test_order_key_keeps_the_order_of_floats():
