@@ -357,16 +357,16 @@ def _stage_quiet_shot_detector(weights_dir: Path, seed: int) -> None:
 
 
 def phase_split(tmp: Path, *, seed: int = 0) -> None:
-    import bench  # the corpus generator (seeded per video); imported, not copied
     from cosmos_curate_tpu.models.registry import WEIGHTS_DIR_ENV
     from cosmos_curate_tpu.models.vlm import SharedCaptionEngine
     from cosmos_curate_tpu.models.vlm.model import vlm_flavor
+    from perfbench.traffic import video_corpus  # the benchmark's generator, seeded per video
 
-    bench.NUM_VIDEOS = 8
-    vids = bench.make_corpus(tmp / "corpus")
-    log(f"split: corpus of {bench.NUM_VIDEOS} videos made")
-    clips_per_video = int(bench.NUM_SCENES * bench.SCENE_FRAMES / 24.0 / bench.STRIDE_S)
-    want = bench.NUM_VIDEOS * clips_per_video
+    # 8 videos of 720p, two 2 s scenes each: four one-second clips a video
+    corpus = dict(width=1280, height=720, fps=24, scenes=2, scene_frames=48, distinct=8, n_videos=8, warm_videos=0)
+    vids, _, _ = video_corpus.make_corpus(corpus, seed, tmp / "corpus")
+    log(f"split: corpus of {corpus['n_videos']} videos made")
+    want = corpus["n_videos"] * (corpus["scenes"] * corpus["scene_frames"] // corpus["fps"])
 
     t0 = time.monotonic()
     summary, metas = _run_split_cli(

@@ -9,7 +9,7 @@ Pass 1 — *extract*: build the current schema of every registered surface
 straight from the code. Wire frames (``engine/remote_plane.py``
 dataclasses) and ``JobRecord`` are introspected with
 ``dataclasses.fields``; JSON documents (journal envelope, DLQ meta, index
-manifests, run_report, live status, node-stats, BENCH rows) are extracted
+manifests, run_report, live status, node-stats) are extracted
 from the writer's AST — dict literals are required fields, conditional
 ``doc["k"] = ...`` assignments are optional fields, dynamic keys become an
 explicit ``<dynamic>`` marker; the object-channel GET tuple's arity and
@@ -409,10 +409,6 @@ def _x_live_status() -> dict[str, dict]:
     }
 
 
-def _x_bench_row() -> dict[str, dict]:
-    return {"row": extract_dict_shape(REPO_ROOT / "bench.py", "main", "record")}
-
-
 SURFACES: tuple[Surface, ...] = (
     Surface(
         "remote-plane", "wire", "cosmos_curate_tpu/engine/remote_plane.py",
@@ -445,9 +441,6 @@ SURFACES: tuple[Surface, ...] = (
     Surface(
         "live-status", "durable", "cosmos_curate_tpu/observability/live_status.py",
         _schema_version("live-status"), _x_live_status,
-    ),
-    Surface(
-        "bench-row", "durable", "bench.py", _schema_version("bench-row"), _x_bench_row,
     ),
 )
 

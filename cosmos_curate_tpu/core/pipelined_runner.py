@@ -251,8 +251,8 @@ class PipelinedRunner(RunnerInterface):
         """Fraction of total host stage work hidden behind other stages:
         ``1 - wall / sum(stage busy seconds)``, clamped at 0. A strictly
         sequential execution scores 0 (wall == summed busy); a perfectly
-        overlapped one approaches ``1 - max/sum``. This is the number bench
-        emits as ``pipeline_overlap_frac``. Computed over the LAST ``run()``
+        overlapped one approaches ``1 - max/sum``. This is the number the run
+        report carries as ``pipeline_overlap_frac``. Computed over the LAST ``run()``
         only (wall and busy from the same run)."""
         busy = self._last_run_busy_s
         if busy <= 0 or self.pipeline_wall_s <= 0:

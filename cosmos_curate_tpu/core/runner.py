@@ -40,8 +40,8 @@ class SequentialRunner(RunnerInterface):
 
     def __init__(self, *, raise_on_error: bool = True) -> None:
         self.raise_on_error = raise_on_error
-        # stage name -> wall seconds of the last run (MFU accounting reads
-        # this; benchmarks/split_benchmark.py)
+        # stage name -> wall seconds of the last run (the run report reads
+        # this; observability/flight_recorder.py)
         self.stage_times: dict[str, float] = {}
         # DLQ parity with the engine: permanently dropped batches persist
         # (engine/dead_letter.py); lazy — a clean run creates nothing

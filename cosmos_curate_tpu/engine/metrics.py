@@ -135,19 +135,13 @@ class EngineMetrics:
             "caption_interleaved_steps_total",
             "decode steps whose active slots spanned 2+ owners", labels,
         )
-        # Paged-attention path signals (ops/paged_attention.py): decode
-        # steps served without a gathered KV working set, and the bytes of
-        # contiguous view the gather programs would have materialized for
-        # the same calls. kernel_steps stays 0 only on an engine built with
-        # paged_attention="gather" (the reference programs).
+        # Paged-attention path signal (ops/paged_attention.py): decode
+        # steps served without a gathered KV working set. Stays 0 only on
+        # an engine built with paged_attention="gather" (the reference
+        # programs).
         self.caption_paged_kernel_steps = Counter(
             "caption_paged_kernel_steps_total",
             "decode steps served by the paged-attention programs", labels,
-        )
-        self.caption_kv_gather_bytes_avoided = Counter(
-            "caption_kv_gather_bytes_avoided_total",
-            "KV working-set bytes not materialized thanks to paged attention",
-            labels,
         )
         # per-owner queue/in-flight gauges for the SHARED engine: which
         # job/stage is occupying or starving the continuous batch
@@ -421,9 +415,6 @@ class EngineMetrics:
         )
         self.caption_paged_kernel_steps.labels(stage).inc(
             max(0, int(phases.get("paged_kernel_steps", 0)))
-        )
-        self.caption_kv_gather_bytes_avoided.labels(stage).inc(
-            max(0, int(phases.get("kv_gather_bytes_avoided", 0)))
         )
         if "kv_blocks_used" in phases:
             self.caption_kv_blocks_used.labels(stage).set(

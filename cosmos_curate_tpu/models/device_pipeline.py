@@ -4,8 +4,7 @@ Every model stage used to run the same synchronous loop: build a host
 batch, ``jax.device_put`` (implicit), compute under jit, and immediately
 block on ``np.asarray`` readback. That serializes four engines that can
 run concurrently — host batch prep, the H2D transfer engine, the MXU, and
-D2H readback — and bench rounds showed the embed stage at ~97% of
-end-to-end wall time as a result.
+D2H readback.
 
 ``DevicePipeline`` is the one sanctioned dispatch point (the sync-readback
 lint rule keeps inline ``np.asarray(jit_fn(...))`` from creeping back):
@@ -32,8 +31,8 @@ lint rule keeps inline ``np.asarray(jit_fn(...))`` from creeping back):
   so bucket-shape compiles are paid once per machine, not per process.
 
 Per-dispatch H2D/compute/readback/gap timings flow through
-``observability.stage_timer.record_dispatch`` so the overlap is measurable
-(bench.py asserts dispatch-gap < 20% of embed-stage wall), not asserted.
+``observability.stage_timer.record_dispatch`` so the overlap is measured,
+not assumed.
 """
 
 from __future__ import annotations

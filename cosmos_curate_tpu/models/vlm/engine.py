@@ -627,16 +627,14 @@ class CaptionEngine:
             raise ValueError(f"max_prefill_rows must be at least 1, got {max_prefill_rows}")
         self.max_prefill_rows = max_prefill_rows
         self.tokenizer = tokenizer or default_caption_tokenizer()
-        # which family of programs: "auto"/"kernel" run the paged programs
+        # which family of programs: "auto" runs the paged programs
         # (attention reads the pool through the block table; which
         # implementation is ops/paged_attention.py's decision alone);
         # "gather" builds the gather-view/scatter-back programs over the
         # XLA reference, which the parity tests and the benchmark's
         # `correct` compare against
-        if paged_attention not in ("auto", "kernel", "gather"):
-            raise ValueError(
-                f"paged_attention must be auto|kernel|gather, got {paged_attention!r}"
-            )
+        if paged_attention not in ("auto", "gather"):
+            raise ValueError(f"paged_attention must be auto|gather, got {paged_attention!r}")
         self.paged_attention = paged_attention
         self._use_paged = paged_attention != "gather"
         # optional device mesh: threads into the model so the paged path
@@ -688,9 +686,9 @@ class CaptionEngine:
                 "block_size %d does not divide every KV lane length; using %d",
                 block_size, bs,
             )
-        # both sides of the fallback are surfaced (stats() / bench row) so
-        # bench comparisons across block sizes aren't apples-to-oranges
-        # when the gcd silently shrank the divisor
+        # both sides of the fallback are surfaced (stats()) so comparisons
+        # across block sizes aren't apples-to-oranges when the gcd silently
+        # shrank the divisor
         self.block_size_requested = int(block_size)
         self.block_size = bs
         base = 0
@@ -806,8 +804,8 @@ class CaptionEngine:
         self._prefix_evictions = 0
         self._prefix_tokens_saved = 0
         # paged-KV accounting (all under _stats_lock): cumulative block
-        # reservations per admitted request (the kv_bytes_per_request bench
-        # field), the worst-case tokens the slot-row engine would have
+        # reservations per admitted request (kv_bytes_reserved_per_request),
+        # the worst-case tokens the slot-row engine would have
         # reserved for the same admissions, shared-prefix block references
         # handed out (the zero-copy successor of insert_prefix dispatches),
         # and copy-on-write tail duplications
@@ -818,15 +816,11 @@ class CaptionEngine:
         self._prefix_block_refs = 0
         self._kv_cow_copies = 0
         self._kv_blocks_used_peak = 0
-        # paged-attention accounting (under _stats_lock): bytes of
-        # contiguous KV view the gather programs would have materialized
-        # and scattered back for the calls the paged programs served
-        # (paged_kernel_steps, the calls themselves, is decode_sample's `n`)
-        self._kv_gather_bytes_avoided = 0
-        # table entries the decode kernel's loop walks (a row's valid
-        # length in pages, summed over the rows of every decode program)
-        # over the entries the rows' tables span (rows x blocks a lane):
-        # the share of the table the kernel touches
+        # paged-attention accounting (under _stats_lock): table entries
+        # the decode kernel's loop walks (a row's valid length in pages,
+        # summed over the rows of every decode program) over the entries
+        # the rows' tables span (rows x blocks a lane): the share of the
+        # table the kernel touches
         self._paged_decode_pages_walked = 0
         self._paged_decode_pages_spanned = 0
         # ...and the prefill kernel's: the entries its loops walk (a block
@@ -1682,30 +1676,12 @@ class CaptionEngine:
         return self._prefix_block_refs
 
     @property
-    def prefix_copy_dispatches(self) -> int:
-        """Whole-prefix device-copy dispatches at admission. Structurally
-        zero since the paged pool: admitted requests REFERENCE prefix
-        blocks through their tables instead of copying them into slot rows
-        (the round-7 jitted insert_prefix path is deleted). Kept as an
-        explicit counter so the bench/smoke contract 'zero prefix
-        device-copy dispatches' is asserted, not assumed."""
-        return 0
-
-    @property
     def kv_cow_copies(self) -> int:
         """Copy-on-write duplications of a partially-filled shared prefix
         tail block (ONE block each — not a prefix copy)."""
         return self._kv_cow_copies
 
     # -- paged-attention accounting --------------------------------------
-    def _gather_view_bytes(self, rows: int, length: int) -> int:
-        """Bytes of contiguous KV working set the gather programs would
-        materialize for one program call over ``rows`` block tables of
-        ``length`` gathered positions (K + V, all layers)."""
-        cfg = self.cfg
-        itemsize = 2 if self._pool_k is None else self._pool_k.dtype.itemsize
-        return len(cfg.kv_layers) * rows * length * cfg.cache_row_elems * itemsize
-
     @property
     def paged_kernel_steps(self) -> int:
         """Decode steps served by the paged-attention programs — attention
@@ -1714,22 +1690,6 @@ class CaptionEngine:
         ``paged_attention="gather"``; > 0 is the smoke contract that the
         kernel path was actually taken. One a decode program read."""
         return self._phase_n["decode_sample"]["n"] if self._use_paged else 0
-
-    @property
-    def kv_gather_bytes_avoided(self) -> int:
-        """Cumulative bytes of per-call contiguous KV working set the
-        gather programs would have materialized (and scattered back) for
-        the prefill/decode calls the paged path served instead."""
-        return self._kv_gather_bytes_avoided
-
-    @property
-    def decode_attention_s(self) -> float:
-        """Tight wall time of decode program calls + host sync, identical
-        measurement site for the paged and gather paths — the
-        kernel-vs-gather comparison the bench caption_attention section
-        reports. (Also contained in phase decode_s, which this mirrors at
-        the program-call granularity.)"""
-        return self._decode_time
 
     @property
     def mesh_geometry(self) -> tuple:
@@ -1743,10 +1703,10 @@ class CaptionEngine:
         )
 
     def stats(self) -> dict:
-        """One-call snapshot of the serving counters (bench row / smoke
-        surface). Includes both sides of the block-size fallback: the
-        constructor-requested size and the gcd-shrunk divisor actually
-        used, so cross-run bench comparisons can detect a silent shrink."""
+        """One-call snapshot of the serving counters. Includes both sides
+        of the block-size fallback: the constructor-requested size and the
+        gcd-shrunk divisor actually used, so cross-run comparisons can
+        detect a silent shrink."""
         held = self.expert_assignments_held  # a device read: outside the lock
         with self._stats_lock:
             return {
@@ -1774,8 +1734,6 @@ class CaptionEngine:
                 "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
                 "paged_prefill_pages_walked": self._paged_prefill_pages_walked,
                 "paged_prefill_pages_spanned": self._paged_prefill_pages_spanned,
-                "kv_gather_bytes_avoided": self._kv_gather_bytes_avoided,
-                "decode_attention_s": self._decode_time,
                 "decode_tokens": self.decode_tokens,
                 "decode_s": self._decode_time,
                 "prefill_tokens": self.prefill_tokens,
@@ -1926,7 +1884,6 @@ class CaptionEngine:
             self._paged_decode_pages_spanned = 0
             self._paged_prefill_pages_walked = 0
             self._paged_prefill_pages_spanned = 0
-            self._kv_gather_bytes_avoided = 0
             self._kv_blocks_used_peak = self._allocator.used_blocks
             self._recurrent_rows_used_peak = (
                 sum(len(l.claims) for l in self.lanes) if self._recurrent else 0
@@ -3117,11 +3074,6 @@ class CaptionEngine:
         with self._phase("prefill_wait", program=program):
             logits_np = np.asarray(logits)  # one host sync for the whole group
         with self._phase("prefill_sample", first=n):
-            with self._stats_lock:
-                if self._use_paged:
-                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                        len(tables), lane.length
-                    )
             for j, (slot_idx, req, _emb, t_valid, _rope, next_rope, _ds, base) in enumerate(
                 items
             ):
@@ -3261,11 +3213,6 @@ class CaptionEngine:
                     lane, slot_idx, p.request, p.base + p.t_valid, p.next_rope,
                     logits_np[j],
                 )
-            with self._stats_lock:
-                if self._use_paged:
-                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                        len(tables), lane.length
-                    )
 
     # holds-lock: _lock
     def _decode_once(self, lane: _Lane) -> None:
@@ -3454,9 +3401,6 @@ class CaptionEngine:
                             n_full * (last + 1).sum() + n_win * (last - first + 1).sum()
                         )
                         self._paged_decode_pages_spanned += (n_full + n_win) * lane.table.size
-                    self._kv_gather_bytes_avoided += self._gather_view_bytes(
-                        lane.n_slots, lane.length
-                    )
                 for slot in emitted.values():
                     owner = slot.request.owner
                     self._owner_decode_tokens[owner] = (

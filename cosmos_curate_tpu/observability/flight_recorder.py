@@ -19,8 +19,7 @@ flight recorder merges all of them at run finalize into a single
 - **device dispatch** and **stage flow** aggregates, verbatim;
 - **drop accounting** — dead-lettered batch counts and the DLQ run dir.
 
-Render it with ``cosmos-curate-tpu report <run>`` (cli/report_cli.py);
-``bench.py`` stamps the report path into every BENCH row.
+Render it with ``cosmos-curate-tpu report <run>`` (cli/report_cli.py).
 """
 
 from __future__ import annotations

@@ -10,8 +10,8 @@ H2D transfer, device compute, D2H readback, and — the number that proves
 or disproves overlap — the *dispatch gap*, the wall time the device sat
 idle between finishing micro-batch k and receiving k+1. A synchronous
 dispatch loop shows gap ≈ host batch-prep time; a pipelined one shows ~0.
-The per-stage aggregates feed bench.py and engine/metrics.py (autoscaler
-and tuning read the exported gauges).
+The per-stage aggregates feed engine/metrics.py (autoscaler and tuning
+read the exported gauges).
 """
 
 from __future__ import annotations
@@ -87,7 +87,7 @@ _FOLDED: dict[str, dict] = {}
 
 # When set, every process that recorded dispatches writes its aggregate
 # summaries to <dir>/dispatch-<pid>.json at exit — how engine WORKERS get
-# their stats back to a parent (bench.py) that wants one merged view.
+# their stats back to a parent (engine/runner.py) that wants one merged view.
 DISPATCH_DUMP_DIR_ENV = "CURATE_DISPATCH_DUMP_DIR"
 _DUMP_REGISTERED = False
 
@@ -142,7 +142,7 @@ def _maybe_register_dump() -> None:
     import atexit
 
     # resolve the env var at EXIT time, not registration time: a process
-    # spanning several phases (bench's cold/warm passes) must dump where
+    # spanning several phases (a cold and a warm pass) must dump where
     # the var points when it dies, not where it pointed at first dispatch
     atexit.register(_dump_summaries, None)
     _DUMP_REGISTERED = True
@@ -356,8 +356,8 @@ def reset_stage_flow() -> None:
 # Caption-engine phase aggregates (pipelines/video/stages/captioning.py et
 # al.): per-stage prep / vision-encode / prefill / decode / idle seconds per
 # engine drive, plus shared-prefix cache traffic. Bounded per-stage
-# aggregates; the caption benchmark and flight recorder read them to
-# attribute the caption critical path.
+# aggregates; the flight recorder reads them to attribute the caption
+# critical path.
 _CAPTION_LOCK = threading.Lock()
 _CAPTION: dict[str, dict] = {}
 
@@ -375,9 +375,9 @@ _CAPTION_COUNT_KEYS = (
     # BLOCK references served copy-free, copy-on-write tail duplications,
     # and decode steps whose active slots spanned 2+ owners
     "prefix_block_refs", "kv_cow_copies", "interleaved_steps",
-    # paged-attention deltas (ops/paged_attention.py): decode steps served
-    # without a gathered working set + the view bytes never materialized
-    "paged_kernel_steps", "kv_gather_bytes_avoided",
+    # paged-attention delta (ops/paged_attention.py): decode steps served
+    # without a gathered working set
+    "paged_kernel_steps",
     "decode_tokens",
     # the engine's phase account: steps, and the programs it handed the device
     "step_n", "decode_dispatch_n", "prefill_dispatch_n",
@@ -510,7 +510,7 @@ def index_op_summaries() -> dict[str, dict]:
     """name -> index aggregate. ``probe_fanout_mean`` is non-empty probed
     shards per query vector (≈ the effective nprobe) — the knob-vs-recall
     signal (raise nprobe, pay more shard matmuls); ``queries_per_sec`` is
-    the headline the bench row carries."""
+    the headline."""
     out: dict[str, dict] = {}
     with _INDEX_LOCK:
         items = {k: dict(v) for k, v in _INDEX.items()}
@@ -543,7 +543,7 @@ def reset_index_ops() -> None:
 # Search-serving aggregates (dedup/index_server.py + service /v1/search):
 # request counts, latency percentiles (bounded reservoir), warm-shard-cache
 # byte traffic, and compaction generations. The SLO surface of the
-# index-server read path: p50/p99 land in run_report.json and BENCH rows;
+# index-server read path: p50/p99 land in run_report.json;
 # the ``search_latency_seconds`` prometheus histogram carries the stream.
 _SEARCH_LOCK = threading.Lock()
 _SEARCH: dict[str, dict] = {}

@@ -361,8 +361,8 @@ def run_split(
         # span this node emits on ONE run span: work-stealing calls
         # runner.run() once per claim batch, and each run() opens its own
         # pipeline.run span — without a shared parent a multi-batch run
-        # fragments into N trace ids and the flight recorder (and bench's
-        # trace_connected) reports a disconnected trace. The root rides the
+        # fragments into N trace ids and the flight recorder reports a
+        # disconnected trace. The root rides the
         # process-level parent, not the contextvar stack, so it survives
         # any thread hop between claim batches.
         attach_traceparent(_os.environ.get(TRACEPARENT_ENV))

@@ -431,9 +431,8 @@ class CaptionStage(Stage[SplitPipeTask, SplitPipeTask]):
             "kv_cow_copies": engine.kv_cow_copies,
             "interleaved_steps": engine.interleaved_decode_steps,
             # paged-attention path: decode steps served without a gathered
-            # KV working set, and the view bytes that never materialized
+            # KV working set
             "paged_kernel_steps": engine.paged_kernel_steps,
-            "kv_gather_bytes_avoided": engine.kv_gather_bytes_avoided,
             # per-OWNER, not engine-wide: under a shared engine another
             # job's tokens decode inside this drive's window, and the run
             # report's owner table must not claim them for this stage

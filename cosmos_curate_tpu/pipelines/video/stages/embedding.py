@@ -24,7 +24,7 @@ from cosmos_curate_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-# Tasks fused per device dispatch (bench.py warms the matching shapes).
+# Tasks fused per device dispatch.
 EMBED_STAGE_TASK_BATCH = 8
 
 

@@ -54,10 +54,6 @@ SCHEMA_VERSIONS: dict[str, int] = {
     "node-stats": 1,
     # observability/live_status.py report/live/status.json
     "live-status": 1,
-    # bench.py final NDJSON metric row (BENCH_r*.json tails). v2: adds the
-    # caption_attention micro-section (paged kernel vs gather decode-step
-    # times) and the paged-attention counters
-    "bench-row": 2,
 }
 
 
@@ -111,20 +107,10 @@ def _manifest_v1_to_v2(doc: dict) -> dict:
     return out
 
 
-def _bench_row_v1_to_v2(doc: dict) -> dict:
-    """v2 added the caption_attention micro-section and paged-attention
-    counters — purely additive; v1 rows carry forward without them (trend
-    tooling treats the keys as absent, not zero)."""
-    out = dict(doc)
-    out[STAMP_KEY] = 2
-    return out
-
-
 MIGRATIONS: dict[tuple[str, int], Callable[[dict], dict]] = {
     ("job-journal", 1): _journal_v1_to_v2,
     ("dlq-meta", 1): _dlq_meta_v1_to_v2,
     ("index-manifest", 1): _manifest_v1_to_v2,
-    ("bench-row", 1): _bench_row_v1_to_v2,
 }
 
 

@@ -44,10 +44,10 @@ def main() -> int:
         }
     )
 
-    import bench  # corpus generator (deterministic; small override here)
+    from perfbench.traffic import video_corpus  # the benchmark's generator, seeded per video
 
-    bench.NUM_VIDEOS = 3
-    vids = bench.make_corpus(tmp)
+    corpus = dict(width=1280, height=720, fps=24, scenes=2, scene_frames=48, distinct=3, n_videos=3, warm_videos=0)
+    vids, _, _ = video_corpus.make_corpus(corpus, 0, tmp)
     print(f"soak: corpus of 3 videos at {vids}", flush=True)
 
     env = {

@@ -79,7 +79,7 @@ def _engine(kind, params, monkeypatch, lanes=((64, 4), (128, 2)), cfg=CFG):
         monkeypatch.setattr(ssm_ops, "_on_tpu", lambda: True)
     engine = CaptionEngine(
         cfg, kv_lanes=lanes, params=jax.tree.map(jnp.copy, params), prefill_chunk=CHUNK,
-        paged_attention="gather" if kind == "gather" else "kernel", block_size=8,
+        paged_attention="gather" if kind == "gather" else "auto", block_size=8,
     )
     engine.setup()
     return engine, Spy(engine)
