@@ -21,6 +21,9 @@ CAPTION_MODEL_CHOICES = (
     "granite-hybrid-tiny-test",
     "keye-tiny-test",
     "keye-vl2-a3b-ep8",
+    "olmo-hybrid-7b",
+    "olmo-hybrid-7b-pp2",
+    "olmo-hybrid-tiny-test",
     "qwen25vl-7b",
     "qwen25vl-tiny-test",
     "qwen2vl-2b",
@@ -105,6 +108,10 @@ def register(sub: argparse._SubParsersAction) -> None:
         "keye-vl2-a3b-ep8 (text only, no converter yet: it needs staged weights) is the same share of "
         "Keye-VL-2.0-30B-A3B's language model: an indexer picks the 2,048 positions a query attends "
         "to, index keys beside the KV pool, requests up to 32,767 positions. "
+        "olmo-hybrid-7b (text only, no converter yet: it needs staged weights) is Olmo-Hybrid-7B: "
+        "gated-delta-rule layers whose matrix state lives in the recurrent store beside 30-head "
+        "attention layers; whole it wants a device of 24 GB or more, olmo-hybrid-7b-pp2 is the "
+        "first of two pipeline stages (16 layers, table and head) and fits a v5e chip. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

@@ -44,7 +44,9 @@ vllm_async_stage.py). TPU-first re-design:
   block pool, whose ``L`` then counts the attention layers only, a
   slot-indexed recurrent store ``[Lm, slots + 1, H, P, N]`` float32 (and the
   convolutions' tails) holds the Mamba-2 state of every state-space layer, a
-  row a slot, row 0 the garbage row. A row is claimed and released with the
+  row a slot, row 0 the garbage row (a gated-delta-rule flavor's store is
+  ``[Ll, slots + 1, dk, H * dv]``: ``init_recurrent_store`` sizes it by the
+  mixer's kind, and nothing else here knows the kind but the counters). A row is claimed and released with the
   slot's blocks; admission zeroes it or copies the shared prefix's snapshot
   into it (a prefix entry is blocks PLUS the state at exactly its last
   token); chunked prefill carries it from chunk to chunk; and since a
@@ -835,10 +837,15 @@ class CaptionEngine:
         self._decode_rows_discarded = 0
         # recurrent-store accounting (under _stats_lock): rows held at once,
         # admissions served from a prefix's state snapshot, calls of the
-        # decode recurrence (one a state-space layer a decode program)
+        # decode recurrence (one a recurrent layer a decode program, booked by
+        # the mixer's kind: Mamba-2's as ``ssm``, the gated delta rule's as
+        # ``delta``), and the delta rule's prefill scan's chunks that held a token
         self._recurrent_rows_used_peak = 0
         self._prefix_state_snapshots = 0
         self._ssm_decode_calls = 0
+        self._delta_decode_calls = 0
+        self._delta_prefill_chunks = 0
+        self._delta = cfg.recurrent_kind == "linear_attention"
         # sparse experts with a sorted dispatch (DeepSeek-V2): the assignments
         # that landed on the experts held here, summed over the layers of every
         # decode program ON THE DEVICE (``_build_counted_decode``) and read when
@@ -1747,6 +1754,8 @@ class CaptionEngine:
                 "recurrent_rows_used_peak": self._recurrent_rows_used_peak,
                 "prefix_state_snapshots": self._prefix_state_snapshots,
                 "ssm_decode_calls": self._ssm_decode_calls,
+                "delta_decode_calls": self._delta_decode_calls,
+                "delta_prefill_chunks": self._delta_prefill_chunks,
                 # latent attention and sorted experts (zero elsewhere)
                 "latent_pool_bytes_per_chip": (
                     self._kv_pool_bytes_per_chip if self.cfg.mla is not None else 0
@@ -1890,6 +1899,8 @@ class CaptionEngine:
             )
             self._prefix_state_snapshots = 0
             self._ssm_decode_calls = 0
+            self._delta_decode_calls = 0
+            self._delta_prefill_chunks = 0
             self._mla_decode_calls = 0
             self._sparse_decode_calls = 0
             self._sparse_decode_positions_live = 0
@@ -2899,6 +2910,12 @@ class CaptionEngine:
             logits, *pools = self._prefill_batch(*args)
             self._keep_pools(*pools)
         else:
+            if self._delta:  # the scan's chunks that hold a token, every row's, a layer each
+                chunk = self.cfg.gated_delta.chunk
+                with self._stats_lock:
+                    self._delta_prefill_chunks += len(self.cfg.ssm_layers) * int(
+                        np.sum(-(-np.asarray(t_valid) // chunk))
+                    )
             logits, self._pool_k, self._pool_v, self._ssm, self._conv = self._prefill_batch(
                 *args, self._ssm, self._conv, jnp.asarray(self._state_rows(lane, slots_arr))
             )
@@ -3361,7 +3378,10 @@ class CaptionEngine:
             phase.counts["tokens"] = len(emitted)
             with self._stats_lock:
                 self._decode_rows_discarded += len(flight.rows) - len(emitted)
-                self._ssm_decode_calls += len(self.cfg.ssm_layers)
+                if self._delta:
+                    self._delta_decode_calls += len(self.cfg.ssm_layers)
+                else:
+                    self._ssm_decode_calls += len(self.cfg.ssm_layers)
                 if self.cfg.mla is not None:
                     self._mla_decode_calls += len(self.cfg.kv_layers)
                 if self._use_paged:

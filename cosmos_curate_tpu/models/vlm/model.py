@@ -18,7 +18,7 @@ models behind the plugin ABC). This is our own Flax architecture, TPU-first:
 from __future__ import annotations
 
 import contextlib
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
 import math
 
@@ -28,7 +28,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from cosmos_curate_tpu.models.layers import MODEL_AXIS, dense
+from cosmos_curate_tpu.models.vlm.gated_delta import GatedDeltaMixer
 from cosmos_curate_tpu.models.vlm.mamba2 import Mamba2Mixer
+from cosmos_curate_tpu.ops import delta_rule as delta_ops
 from cosmos_curate_tpu.ops import ssm as ssm_ops
 from cosmos_curate_tpu.ops.tiling import round_up
 from cosmos_curate_tpu.models.vit import VIT_B_16, VIT_TINY_TEST, ViT, ViTConfig, preprocess_frames
@@ -201,6 +203,28 @@ class Mamba2Config:
 
 
 @dataclass(frozen=True)
+class GatedDeltaConfig:
+    """The gated-delta-rule mixer of a hybrid decoder (models/vlm/gated_delta.py;
+    flash-linear-attention's ``GatedDeltaNet``, HF's ``linear_*`` keys): ``n_heads``
+    heads, each a ``[key_dim, value_dim]`` float32 state; q, k and v each pass
+    through a short convolution of their own."""
+
+    n_heads: int = 30
+    key_dim: int = 96
+    value_dim: int = 192
+    d_conv: int = 4
+    # beta = 2 sigmoid(.) in place of sigmoid(.): a state's eigenvalue along k
+    # is 1 - beta, in (-1, 1) (HF ``linear_allow_neg_eigval``)
+    allow_neg_eigval: bool = True
+    # tokens a step of the prefill scan solves at once (ops/delta_rule.py)
+    chunk: int = 64
+
+    @property
+    def conv_dim(self) -> int:  # q | k | v pass through the convolutions
+        return self.n_heads * (2 * self.key_dim + self.value_dim)
+
+
+@dataclass(frozen=True)
 class IndexerConfig:
     """A learned indexer beside GQA (DeepSeek-Sparse-Attention; HF ``sa_config``):
     every layer scores the earlier positions for each query with ``n_heads``
@@ -225,6 +249,9 @@ class IndexerConfig:
         """What the heads' weights are multiplied by: ``n_heads ** -0.5`` times the
         index heads' own softmax scale ``head_dim ** -0.5``."""
         return self.n_heads**-0.5 * self.head_dim**-0.5
+
+
+_RECURRENT_KINDS = ("mamba", "linear_attention")
 
 
 @dataclass(frozen=True)
@@ -265,6 +292,9 @@ class VLMConfig:
     # or "mamba"; None = attention throughout. A "mamba" layer replaces the
     # attention half of a layer with the Mamba-2 mixer ``mamba`` describes;
     # its state lives in the engine's recurrent store, not in the KV pool.
+    # A "linear_attention" layer (Olmo-Hybrid; HF's own word) replaces it with
+    # the gated-delta-rule mixer ``gated_delta`` describes, whose state lives
+    # in the same store. One decoder has one recurrent kind.
     # Window and full attention mixed (afmoe; HF's own two words):
     # "sliding_attention" layers see ``sliding_window`` positions and keep
     # their K/V in the engine's window pool, "full_attention" layers (like
@@ -272,6 +302,7 @@ class VLMConfig:
     layer_types: tuple[str, ...] | None = None
     sliding_window: int | None = None
     mamba: Mamba2Config | None = None
+    gated_delta: GatedDeltaConfig | None = None
     # latent attention (DeepSeek-V2) in place of GQA in every attention layer:
     # its sizes and YaRN's numbers; None = ``DecoderLayer``'s attention. Such a
     # flavor's cache is one latent row a token a layer (``cache_row_elems``)
@@ -292,6 +323,12 @@ class VLMConfig:
     # afmoe's "sandwich": a second RMSNorm on each BRANCH, after ``o`` and after
     # the FFN, before the residual sum (``post_attn_norm``, ``post_mlp_norm``)
     sandwich_norm: bool = False
+    # False = no RMSNorm BEFORE a branch (``ln1``, ``ln2``). With
+    # ``sandwich_norm`` that is the Olmo 2 / 3 block: ``x + RMSNorm(f(x))``
+    pre_norm: bool = True
+    # ``qk_norm`` over the WHOLE projection, all heads at once (Olmo 2 / 3),
+    # in place of one RMSNorm a head
+    qk_norm_whole: bool = False
     # softmax scale of the attention layers; None = head_dim ** -0.5
     attention_multiplier: float | None = None
     # Granite's scalings: x0 = E[ids] * embedding_multiplier, every residual
@@ -305,11 +342,18 @@ class VLMConfig:
             raise ValueError("an indexer beside latent attention, window layers or state-space layers: no program here")
         if self.layer_types is None:
             return
-        kinds = {"attention", "mamba", "sliding_attention", "full_attention"}
+        kinds = {"attention", "mamba", "linear_attention", "sliding_attention", "full_attention"}
         if len(self.layer_types) != self.n_layers or set(self.layer_types) - kinds:
             raise ValueError(f"layer_types must name {self.n_layers} layers out of {sorted(kinds)}")
         if "mamba" in self.layer_types and self.mamba is None:
             raise ValueError("layer_types has a 'mamba' layer and mamba= gives no sizes")
+        if "linear_attention" in self.layer_types and self.gated_delta is None:
+            raise ValueError("layer_types has a 'linear_attention' layer and gated_delta= gives no sizes")
+        if {"mamba", "linear_attention"} <= set(self.layer_types):
+            raise ValueError(
+                "layer_types mixes 'mamba' and 'linear_attention' layers: the recurrent store "
+                "holds one kind of state, and no program here carries two"
+            )
         if self.window_layers and not self.sliding_window:
             raise ValueError("layer_types has a 'sliding_attention' layer and sliding_window= is not set")
         if self.window_layers and (self.mla is not None or self.ssm_layers):
@@ -323,7 +367,7 @@ class VLMConfig:
         in a flavor that mixes the two), which the window pool's does."""
         if self.layer_types is None:
             return tuple(range(self.n_layers))
-        return tuple(i for i, kind in enumerate(self.layer_types) if kind != "mamba")
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind not in _RECURRENT_KINDS)
 
     @property
     def window_layers(self) -> tuple[int, ...]:
@@ -351,11 +395,18 @@ class VLMConfig:
 
     @property
     def ssm_layers(self) -> tuple[int, ...]:
-        """Indices of the state-space layers: the recurrent store's leading
-        dimension counts these, in this order."""
+        """Indices of the recurrent layers, state-space ("mamba") or linear
+        attention (one kind a decoder: ``recurrent_kind``): the recurrent
+        store's leading dimension counts these, in this order."""
         if self.layer_types is None:
             return ()
-        return tuple(i for i, kind in enumerate(self.layer_types) if kind == "mamba")
+        return tuple(i for i, kind in enumerate(self.layer_types) if kind in _RECURRENT_KINDS)
+
+    @property
+    def recurrent_kind(self) -> str | None:
+        """"mamba" or "linear_attention": what the recurrent store holds; None
+        without recurrent layers."""
+        return self.layer_types[self.ssm_layers[0]] if self.ssm_layers else None
 
 
 VLM_BASE = VLMConfig()
@@ -730,6 +781,64 @@ VLM_KEYE_TINY_TEST = VLMConfig(
     indexer=IndexerConfig(n_heads=4, head_dim=8, top_k=32),
     moe=MoEConfig(n_experts=16, top_k=4, hidden=32, norm_topk_prob=True, dispatch="sorted", held=(2, 2)),
 )
+# Olmo-Hybrid-7B (HF ``olmo_hybrid``, config.json of allenai/Olmo-Hybrid-7B): 32
+# layers in periods of four, three gated-delta-rule mixers (30 heads, a [96, 192]
+# float32 state each, three short convolutions) and one full-attention layer (30
+# query and 30 KV heads x 128, RMSNorm over the whole q and k, no position
+# embedding), every layer followed by a SwiGLU of 11008; the Olmo 2 / 3 block
+# (no norm before a branch, one on its output), untied head. Text only: the
+# vision slot holds the test-size tower no request may reach. Its serving
+# parameters are 14.8 GiB: no 16 GB chip holds them beside a pool, and a
+# recurrent store is not split over a mesh, so on v5e it is served as a
+# pipeline of two stages, of which ``VLM_OLMO_HYBRID_7B_PP2`` is the first:
+# the first 16 layers (four whole periods), the table and the head (the second
+# stage's head rides with the first's table).
+_OLMO_PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+VLM_OLMO_HYBRID_7B = VLMConfig(
+    vocab=100352,
+    dim=3840,
+    n_layers=32,
+    n_heads=30,
+    n_kv_heads=30,
+    head_dim=128,
+    hidden_mult=11008 / 3840,
+    max_seq=4096,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-6,
+    tied_embeddings=False,
+    layer_types=_OLMO_PERIOD * 8,
+    gated_delta=GatedDeltaConfig(n_heads=30, key_dim=96, value_dim=192, d_conv=4, allow_neg_eigval=True, chunk=64),
+    use_rope=False,
+    pre_norm=False,
+    sandwich_norm=True,
+    qk_norm_whole=True,
+)
+VLM_OLMO_HYBRID_7B_PP2 = replace(VLM_OLMO_HYBRID_7B, n_layers=16, layer_types=_OLMO_PERIOD * 4)
+# two periods of the same pattern at test size (CPU tests, --rehearse): dk !=
+# dv, and 4 heads x 24 lanes of state make no whole tile, so the store's
+# layout is exercised; the scan's chunk is under the engine's test chunk so a
+# prefill crosses chunks
+VLM_OLMO_HYBRID_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=8,
+    n_heads=4,
+    n_kv_heads=4,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-6,
+    tied_embeddings=False,
+    layer_types=_OLMO_PERIOD * 2,
+    gated_delta=GatedDeltaConfig(n_heads=4, key_dim=16, value_dim=24, d_conv=4, allow_neg_eigval=True, chunk=8),
+    use_rope=False,
+    pre_norm=False,
+    sandwich_norm=True,
+    qk_norm_whole=True,
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -784,7 +893,7 @@ class FlavorSpec:
             )
         if self.model_chips > 1 and self.cfg.ssm_layers:
             raise ValueError(
-                f"{self.model_id}: the recurrent store and the Mamba-2 mixer are not "
+                f"{self.model_id}: the recurrent store and its mixers are not "
                 "split over a model mesh; serve a hybrid flavor with model_chips=1"
             )
         if self.model_chips > 1 and self.cfg.indexer is not None:
@@ -1000,6 +1109,34 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 2), (128, 2)),
         ),
+        # a 7B-class hybrid text LM (the LM-only passes, --enhance-captions): 24
+        # of 32 layers keep a fixed-size gated-delta-rule state, so a row costs
+        # 53 MB of recurrent store whatever its context and K/V 120 KiB a
+        # position over the 8 attention layers of 30 KV heads. Whole, it needs a
+        # device of 24 GB or more; on v5e it is served as the pipeline below
+        "olmo-hybrid-7b": FlavorSpec(
+            VLM_OLMO_HYBRID_7B,
+            "caption-olmo-hybrid-7b-tpu",
+            text_only=True,
+            kv_lanes=((1024, 40), (4096, 4)),
+        ),
+        # ...seen from the first of its two pipeline stages: 16 layers, table
+        # and head, 8.4 GiB of parameters. A row costs 26.5 MB of state + 0.8 MB
+        # of tails + 60 KiB of K/V a position: 40 + 4 rows are 1.2 GiB of store
+        # and 3.3 GiB of pool
+        "olmo-hybrid-7b-pp2": FlavorSpec(
+            VLM_OLMO_HYBRID_7B_PP2,
+            "caption-olmo-hybrid-7b-pp2-tpu",
+            text_only=True,
+            kv_lanes=((1024, 40), (4096, 4)),
+        ),
+        "olmo-hybrid-tiny-test": FlavorSpec(
+            VLM_OLMO_HYBRID_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 4), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -1116,10 +1253,11 @@ def build_mrope_positions(
 
 class RMSNorm(nn.Module):
     eps: float = 1e-6
+    scale_init: float = 1.0  # what a SEEDED scale starts at (a checkpoint brings its own)
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        scale = self.param("scale", nn.initializers.constant(self.scale_init), (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (normed * scale).astype(x.dtype)
@@ -1357,10 +1495,13 @@ class DecoderLayer(nn.Module):
         # every projection here computes in ``dtype`` and stores ``param_dtype``
         proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
 
-        y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x)
+        y = RMSNorm(eps=cfg.rms_eps, name="ln1")(x) if cfg.pre_norm else x
         q = proj(h * dh, "out", name="q", use_bias=cfg.qkv_bias)(y)
         k = proj(hk * dh, "out", name="k", use_bias=cfg.qkv_bias)(y)
         v = proj(hk * dh, "out", name="v", use_bias=cfg.qkv_bias)(y)
+        if cfg.qk_norm_whole:  # Olmo 2 / 3: one RMSNorm over all heads' width
+            q = RMSNorm(eps=cfg.rms_eps, name="q_norm")(q)
+            k = RMSNorm(eps=cfg.rms_eps, name="k_norm")(k)
         q = q.reshape(b, t, h, dh)
         k = k.reshape(b, t, hk, dh)
         if cfg.qk_norm:  # Qwen3 family: per-HEAD-DIM RMSNorm before rope
@@ -1374,7 +1515,8 @@ class DecoderLayer(nn.Module):
         # each is named in a compiled program (no other flavor's programs
         # carry these scopes, nor the keyword)
         edge = {} if self.window is None else {"window": self.window}
-        kind = contextlib.nullcontext() if not cfg.window_layers else jax.named_scope(
+        mixed = cfg.window_layers or cfg.recurrent_kind == "linear_attention"
+        kind = contextlib.nullcontext() if not mixed else jax.named_scope(
             "attn.full" if self.window is None else "attn.window"
         )
         with kind:
@@ -1396,7 +1538,7 @@ class DecoderLayer(nn.Module):
         with jax.named_scope(TP_SCOPES["attn_out"]):
             branch = proj(cfg.dim, "in", name="o", use_bias=False)(attn)
             if cfg.sandwich_norm:
-                branch = RMSNorm(eps=cfg.rms_eps, name="post_attn_norm")(branch)
+                branch = _branch_norm(cfg, "post_attn_norm")(branch)
             x = _residual(cfg, x, branch)
         ffn = _ffn_half(cfg, x, proj, self.dtype, self.param_dtype, dense_ffn=self.dense_ffn)
         return ffn, new_k, new_v
@@ -1554,6 +1696,17 @@ class DecoderLayer(nn.Module):
         return attn.astype(self.dtype), (new_k, cache_i), new_v
 
 
+def _branch_norm(cfg: VLMConfig, name: str) -> RMSNorm:
+    """The RMSNorm on a branch's OUTPUT (``sandwich_norm``). Where nothing
+    norms a branch's input (the Olmo block) a seeded scale starts at a quarter
+    of the embedding table's 0.02, not at 1: sixteen layers of unit branches
+    on a 0.02 stream amplify a bfloat16 rounding until the logits agree with
+    nothing (0.74 of their largest at the tiny preset over 16 layers, 0.02
+    so; PERF.md, PR 44), and a comparison with a reference would say nothing.
+    A trained model's scales are its checkpoint's."""
+    return RMSNorm(eps=cfg.rms_eps, name=name, scale_init=1.0 if cfg.pre_norm else 0.005)
+
+
 def _residual(cfg: VLMConfig, x, branch):
     r = cfg.residual_multiplier
     return x + branch if r == 1.0 else x + branch * r
@@ -1563,10 +1716,10 @@ def _ffn_half(cfg: VLMConfig, x, proj, dtype, param_dtype, dense_ffn=False):
     """``x + ffn(RMSNorm(x))``: the second half of every kind of layer. Called
     inside a layer's compact method, so the submodules are that layer's.
     ``dense_ffn``: a sparse model's leading layer that keeps the dense SwiGLU."""
-    y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x)
+    y = RMSNorm(eps=cfg.rms_eps, name="ln2")(x) if cfg.pre_norm else x
 
     def branch(out):  # the sandwich's second slice of bread, where a flavor has it
-        return RMSNorm(eps=cfg.rms_eps, name="post_mlp_norm")(out) if cfg.sandwich_norm else out
+        return _branch_norm(cfg, "post_mlp_norm")(out) if cfg.sandwich_norm else out
 
     if cfg.moe is not None and not dense_ffn:
         moe = MoEFFN(cfg, dtype=dtype, param_dtype=param_dtype, name="moe")
@@ -1701,6 +1854,36 @@ class MambaLayer(nn.Module):
         return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), ssm, tail
 
 
+class LinearAttentionLayer(nn.Module):
+    """A hybrid decoder's linear-attention layer: the gated-delta-rule mixer
+    (models/vlm/gated_delta.py) where ``DecoderLayer`` has attention, under
+    the flavor's own norm placement, then the same FFN half. Its state is
+    two rows of the engine's recurrent store, as ``MambaLayer``'s."""
+
+    cfg: VLMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
+
+    @nn.compact
+    def __call__(self, x, ssm, tail, rows, valid, *, layer_index=0, use_kernel=None):
+        """As ``MambaLayer``'s; ssm: states ``[Ll, R, dk, H * dv]`` float32;
+        tail: ``[B, (d_conv - 1) * conv_dim]``, the three convolutions' tails."""
+        cfg = self.cfg
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        mixer = GatedDeltaMixer(
+            cfg.gated_delta, cfg.dim, cfg.rms_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            name="mixer",
+        )
+        y, ssm, tail = mixer(
+            RMSNorm(eps=cfg.rms_eps, name="ln1")(x) if cfg.pre_norm else x, ssm, tail, rows, valid,
+            layer_index=layer_index, use_kernel=use_kernel,
+        )
+        if cfg.sandwich_norm:
+            y = _branch_norm(cfg, "post_attn_norm")(y)
+        x = _residual(cfg, x, y)
+        return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), ssm, tail
+
+
 class VLM(nn.Module):
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -1741,9 +1924,12 @@ class VLM(nn.Module):
                 use_rope=cfg.rope_in_layer(i), dense_ffn=dense_ffn,
             )
 
+        recurrent_layer = {"mamba": MambaLayer, "linear_attention": LinearAttentionLayer}
         self.layers = [
-            MambaLayer(cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}")
-            if cfg.layer_types is not None and cfg.layer_types[i] == "mamba"
+            recurrent_layer[cfg.layer_types[i]](
+                cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}"
+            )
+            if i in cfg.ssm_layers
             else attention_layer(i)
             for i in range(cfg.n_layers)
         ]
@@ -1913,7 +2099,8 @@ class VLM(nn.Module):
             # advanced twice). Only the decode kernel walks the store's own
             # rows in place: a custom call is not rematerialised.
             tails, new_tails = store_conv[:, store_rows], []
-            in_place = x.shape[1] == 1 and ssm_ops.decode_in_place(use_kernel)
+            ops = delta_ops if cfg.recurrent_kind == "linear_attention" else ssm_ops
+            in_place = x.shape[1] == 1 and ops.decode_in_place(use_kernel)
             ssm, rows = (
                 (store_ssm, store_rows) if in_place
                 else (store_ssm[:, store_rows], jnp.arange(x.shape[0], dtype=jnp.int32))
@@ -1973,14 +2160,23 @@ class VLM(nn.Module):
 
 
 def init_recurrent_store(cfg: VLMConfig, rows: int, dtype=jnp.bfloat16):
-    """The state of a hybrid's state-space layers, one row a request:
-    ``ssm`` ``[Lm, rows, H, P, N]`` float32 (a state is rounded once a token
-    for as long as its request lives, so it keeps float32) and ``conv``
-    ``[Lm, rows, (d_conv - 1) * conv_dim]``, the convolution's last inputs in
-    the type they were computed in (a row's taps side by side: a ``[3,
-    conv_dim]`` plane would be padded to a tile of 16 rows on the chip)."""
-    m, lm = cfg.mamba, len(cfg.ssm_layers)
-    ssm = jnp.zeros((lm, rows, m.n_heads, m.head_dim, m.d_state), jnp.float32)
+    """The state of a hybrid's recurrent layers, one row a request, sized by
+    the mixer's kind (``cfg.recurrent_kind``): ``ssm`` float32 (a state is
+    rounded once a token for as long as its request lives, so it keeps
+    float32), ``[Lm, rows, H, P, N]`` for Mamba-2 and ``[Ll, rows, dk, H *
+    dv]`` for the gated delta rule (the heads side by side on the lanes:
+    ops/delta_rule.py has the why), and ``conv`` ``[Lm, rows, (d_conv - 1) *
+    conv_dim]``, the convolutions' last inputs in the type they were
+    computed in (a row's taps side by side: a ``[3, conv_dim]`` plane would
+    be padded to a tile of 16 rows on the chip; the delta rule's three
+    convolutions share the row, q | k | v)."""
+    lm = len(cfg.ssm_layers)
+    if cfg.recurrent_kind == "linear_attention":
+        m = cfg.gated_delta
+        ssm = jnp.zeros((lm, rows, m.key_dim, m.n_heads * m.value_dim), jnp.float32)
+    else:
+        m = cfg.mamba
+        ssm = jnp.zeros((lm, rows, m.n_heads, m.head_dim, m.d_state), jnp.float32)
     conv = jnp.zeros((lm, rows, (m.d_conv - 1) * m.conv_dim), dtype)
     return ssm, conv
 

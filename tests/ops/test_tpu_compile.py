@@ -400,3 +400,49 @@ def test_grouped_matmul_compiles_for_v5e_without_copying_the_tables(v5e, m, k, n
     ).compile().as_text()
     assert re.search(r"%gmm(\.\d+)? = .*custom-call", hlo)
     assert not re.search(r"= bf16\[20," + f"{k},{n}" + r"\]\{[^}]*\} copy\(", hlo)
+
+
+@pytest.mark.parametrize("heads_per_step", [10, 30])
+def test_delta_decode_kernel_compiles_for_v5e_under_its_trace_name(v5e, heads_per_step):
+    """Olmo-Hybrid's widths, the cell's rows: 30 heads x [96, 192] side by
+    side, 5760 lanes; a block of 10 heads is 1920 lanes, walked two heads
+    (three tiles) at a time, the whole row 2.1 MiB a buffer. The custom call
+    carries the name the benchmark's trace reduction looks for."""
+    from cosmos_curate_tpu.ops.delta_rule import _delta_decode
+    from perfbench import trace_reduce
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows, h, dk, dv = 44, 30, 96, 192
+    fn = functools.partial(_delta_decode, heads_per_step=heads_per_step, interpret=False)
+    hlo = jax.jit(fn).lower(
+        arg((12, rows + 1, dk, h * dv)), arg((), jnp.int32), arg((rows,), jnp.int32),
+        arg((rows, h, dk)), arg((rows, h, dk)), arg((rows, h, dv)), arg((rows, h)), arg((rows, h)),
+    ).compile().as_text()
+    calls = [
+        trace_reduce.instruction(line.strip().removeprefix("ROOT "))
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert calls and all(re.search(r"^_?delta_decode(\.\d+)?$", c) for c in calls), calls
+
+
+@pytest.mark.parametrize(
+    "kernel,rows,lane",
+    [
+        pytest.param("paged_decode", 40, 1024, id="decode-40-rows"),
+        pytest.param("paged_decode", 4, 4096, id="decode-4-rows-long-lane"),
+        pytest.param("paged_prefill", 4, 1024, id="prefill-4-rows-T256"),
+        pytest.param("paged_prefill", 1, 4096, id="prefill-1-row-long-lane"),
+    ],
+)
+def test_paged_kernels_compile_at_olmo_hybrids_heads(v5e, kernel, rows, lane):
+    """30 KV heads of 128 with ONE query head each: the widest page any flavor
+    has (a page is 30 x 16 x 128), no power of two, and the only group of 1."""
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = KERNELS[kernel](30, 1, 128, arg, rows=rows, lane=lane)
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
