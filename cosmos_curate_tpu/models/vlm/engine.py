@@ -279,6 +279,9 @@ class _BlockClaim:
     shared: list[int]
     private: list[int]
     window: list[int] = field(default_factory=list)
+    # the request fitted a shorter lane (its home): a row this lane's decode
+    # program is not run for (CaptionEngine._route)
+    guest: bool = False
 
     @property
     def all_blocks(self) -> list[int]:
@@ -342,6 +345,11 @@ class _Lane:
     # the decode program that has been dispatched and not yet read
     inflight: "_InFlight | None" = None
 
+    @property
+    def rows(self) -> int:
+        """Rows taken: decoding, in prefill, or claimed by this admission pass."""
+        return len(self.slots) + len(self.pending) + len(self.reserved)
+
 
 @dataclass
 class _InFlight:
@@ -387,6 +395,9 @@ _PHASES = _PHASE_ROOTS + (
 # `frames`, a prefill program's `rows` and `live`) is span metadata only.
 _PHASE_COUNTS = {
     "step": ("n",),
+    # _route: heads held back from an idle longer lane, and requests admitted
+    # to a lane longer than their home
+    "admit": ("held", "guests"),
     # positions advanced, of those the program's padded rows x T had room for
     "prefill_dispatch": ("n", "tokens", "room"),
     "prefill_sample": ("first",),  # prompts finished: a first output token each
@@ -1737,6 +1748,8 @@ class CaptionEngine:
                 "paged_kernel_steps": self.paged_kernel_steps,
                 "decode_programs_ahead": self._phase_n["decode_dispatch"]["ahead"],
                 "decode_rows_discarded": self._decode_rows_discarded,
+                "admit_held": self._phase_n["admit"]["held"],
+                "admit_guests": self._phase_n["admit"]["guests"],
                 "paged_decode_pages_walked": self._paged_decode_pages_walked,
                 "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
                 "paged_prefill_pages_walked": self._paged_prefill_pages_walked,
@@ -1984,8 +1997,8 @@ class CaptionEngine:
             waiting.enter_context(self._phase("lock_wait"))
             with self._work_cv:
                 waiting.close()  # the lock is ours: lock_wait ends here
-                with self._phase("admit"):
-                    self._admit()
+                with self._phase("admit") as phase:
+                    self._admit(phase.counts)
                 # cross-job signal: this step's active slots span 2+ owners
                 # — several jobs are decoding in ONE continuous batch
                 step_owners = {
@@ -2189,6 +2202,11 @@ class CaptionEngine:
                     return prep
         return None
 
+    def _home(self, need: int) -> _Lane | None:
+        """The shortest lane that holds ``need`` positions. A request is NATIVE
+        to its home and a GUEST in any longer lane."""
+        return next((l for l in self.lanes if l.length >= need), None)
+
     def _route(self, need: int) -> _Lane | None:
         """Pick the lane for a request needing ``need`` positions.
 
@@ -2201,18 +2219,28 @@ class CaptionEngine:
         request that a SHORTER idle lane could serve must not consume the
         LAST free slot of a longer active lane — long-lane slots are
         scarce (e.g. 2 at 4096 for the 7B default) and burning the last
-        one on a short request head-of-line-blocks the next long prompt."""
+        one on a short request head-of-line-blocks the next long prompt.
+
+        A request whose home lane is FULL enters a longer lane only where
+        that lane's program runs anyway or is worth running (``_takes_guest``);
+        otherwise no lane is returned and the request waits for a row of its
+        own lane, as it waits when every lane is full: a decode program reads
+        every parameter whatever its rows, and a full lane in one program
+        makes more tokens a second than a few rows more in two."""
         first_idle = None
         active = None
         active_free = 0
+        home = self._home(need)
+        home_full = home is not None and home.rows >= home.n_slots
         for lane in self.lanes:  # sorted by length
-            occupied = len(lane.slots) + len(lane.pending) + len(lane.reserved)
-            if lane.length < need or occupied >= lane.n_slots:
+            if lane.length < need or lane.rows >= lane.n_slots:
                 continue
-            if occupied and active is None:
+            if home_full and not self._takes_guest(lane):
+                continue
+            if lane.rows and active is None:
                 active = lane
-                active_free = lane.n_slots - occupied
-            elif not occupied and first_idle is None:
+                active_free = lane.n_slots - lane.rows
+            elif not lane.rows and first_idle is None:
                 first_idle = lane
         if active is not None:
             if (
@@ -2223,6 +2251,19 @@ class CaptionEngine:
                 return first_idle
             return active
         return first_idle
+
+    def _takes_guest(self, lane: _Lane) -> bool:
+        """Whether a request whose home lane is full may enter ``lane``, which
+        has a free row. It may where the lane's program runs anyway (a native
+        row is decoding, in prefill or reserved), or where the rows it would
+        carry with the guests that can join now are more than those of the
+        fullest program running: every program costs about one read of the
+        parameters, so one that carries fewer lowers tokens a second. A lane
+        left with guests only takes no more, drains and closes."""
+        if any(not claim.guest for claim in lane.claims.values()):
+            return True
+        joining = min(lane.n_slots - lane.rows, 1 + len(self._ready))
+        return lane.rows + joining > max(l.rows for l in self.lanes)
 
     def _prompt_len_estimate(self, req: CaptionRequest) -> int:
         """Prompt length WITHOUT running the encoders. Routing now sees the
@@ -2235,9 +2276,12 @@ class CaptionEngine:
         return min(n, self._max_len - req.sampling.max_new_tokens - 1)
 
     # holds-lock: _lock
-    def _admit(self) -> None:
+    def _admit(self, counts: dict) -> None:
+        """Move prepared requests into lanes. ``counts`` is the ``admit``
+        phase's (``held``, ``guests``)."""
         if self._should_linger():
             return
+        counts.update(held=0, guests=0)
         # per-owner in-flight counts for the fairness cap (updated as this
         # pass admits, so one pass cannot blow past the cap either)
         inflight: dict[Any, int] = {}
@@ -2252,11 +2296,14 @@ class CaptionEngine:
             if prep is None:
                 break
             req = prep.request
-            need = prep.total + req.sampling.max_new_tokens + 1
-            lane = self._route(min(need, self._max_len))
+            need = min(prep.total + req.sampling.max_new_tokens + 1, self._max_len)
+            lane = self._route(need)
             if lane is None:
                 # head-of-line waits for a slot to free (FIFO); the prep
-                # work is kept, not redone
+                # work is kept, not redone. Held: a longer lane had a row
+                counts["held"] += any(
+                    l.length >= need and l.rows < l.n_slots for l in self.lanes
+                )
                 self._ready.appendleft(prep)
                 break
             lane_budget = lane.length - req.sampling.max_new_tokens - 1
@@ -2336,7 +2383,7 @@ class CaptionEngine:
                 and i not in lane.reserved
             )
             try:
-                self._claim_kv(lane, slot_idx, prep, req)
+                counts["guests"] += self._claim_kv(lane, slot_idx, prep, req).guest
             except PoolExhausted:
                 if prep.base and not any(l.claims for l in self.lanes):
                     # nothing in flight will free blocks and eviction
@@ -2847,7 +2894,10 @@ class CaptionEngine:
             wrow = lane.wtable[slot_idx]
             wrow[:] = 0
             wrow[:view_blocks] = np.resize(np.asarray(wshared + wprivate, np.int32), view_blocks)
-        claim = _BlockClaim(shared=shared, private=private, window=wshared + wprivate)
+        claim = _BlockClaim(
+            shared=shared, private=private, window=wshared + wprivate,
+            guest=self._home(need) is not lane,
+        )
         lane.claims[slot_idx] = claim
         if self._recurrent:
             try:

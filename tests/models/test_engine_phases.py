@@ -47,6 +47,7 @@ COUNTS = {
     "prefill_dispatch_tokens", "prefill_dispatch_room", "prefill_sample_first",
     "decode_dispatch_rows", "decode_dispatch_live", "decode_dispatch_ahead",
     "decode_wait_fresh", "decode_wait_ready", "decode_sample_tokens",
+    "admit_held", "admit_guests",
 }
 ALL_KEYS = SECONDS | EXPOSED | COUNTS
 KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]

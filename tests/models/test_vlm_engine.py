@@ -1,5 +1,7 @@
 """VLM + continuous-batching caption engine tests (tiny config, CPU)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -526,6 +528,259 @@ class TestUtilizationAwareRouting:
         eng.step()
         assert len(eng.lanes[0].slots) + len(eng.lanes[0].pending) == 1
         assert not eng.lanes[1].slots
+
+
+class _Watch:
+    """Steps an engine to its end and keeps, by request id, the lane a
+    request decoded in and its output ids; after every step it holds each
+    lane's claims against the rows taken and each claim's ``guest`` against
+    the request's own need."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.lane_of: dict[str, int] = {}
+        self.ids: dict[str, list[int]] = {}
+        self.most_decode_programs = 0
+        start_slot, maybe_finish = eng._start_slot, eng._maybe_finish
+
+        def started(lane, slot_idx, req, *rest):
+            self.lane_of[req.request_id] = lane.length
+            return start_slot(lane, slot_idx, req, *rest)
+
+        def finishing(lane, slot_idx, slot):
+            self.ids[slot.request.request_id] = list(slot.generated)
+            return maybe_finish(lane, slot_idx, slot)
+
+        eng._start_slot, eng._maybe_finish = started, finishing
+
+    def step(self):
+        eng = self.eng
+        before = eng.phase_seconds["decode_dispatch_n"]
+        eng.step()
+        self.most_decode_programs = max(
+            self.most_decode_programs, eng.phase_seconds["decode_dispatch_n"] - before
+        )
+        for lane in eng.lanes:
+            rows = {**lane.pending, **lane.slots}
+            assert set(lane.claims) == set(rows) and not lane.reserved
+            for i, row in rows.items():
+                req = row.request
+                n = len(req.prefix_ids) + len(req.prompt_ids) + req.sampling.max_new_tokens + 1
+                if req.frames is not None:
+                    n += eng._vision_token_count(req.frames.shape[0])
+                assert lane.claims[i].guest == (eng._home(min(n, eng._max_len)) is not lane)
+
+    def natives(self, lane) -> int:
+        return sum(not c.guest for c in lane.claims.values())
+
+    def finish(self) -> set[str]:
+        while self.eng.has_work():
+            self.step()
+        done, self.eng.completed = self.eng.completed, []
+        assert not any(l.claims for l in self.eng.lanes)
+        return {r.request_id for r in done}
+
+
+def _wait_ready(eng, n):
+    """Background prep has put ``n`` requests in the ready queue."""
+    deadline = time.monotonic() + 60
+    while len(eng._ready) < n:
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+
+
+class TestHomeLaneRouting:
+    """A request whose home lane (the shortest that holds it) is full enters a
+    longer lane only where that lane's decode program runs anyway or is worth
+    running; else it waits for a row of its own lane."""
+
+    @staticmethod
+    def _engine(lanes, **kw):
+        eng = CaptionEngine(VLM_TINY_TEST, max_batch=4, kv_lanes=lanes, **kw)
+        eng.setup()
+        return eng
+
+    @pytest.fixture(scope="class")
+    def one_request_over(self):
+        """Three short requests for two short rows and one long row, run to
+        the end under the rule (False) and under the old fallback (True: every
+        lane takes a guest): the watch, and rows a lane + ready after a step."""
+        runs = {}
+        for old_fallback in (False, True):
+            eng = self._engine(((64, 2), (128, 1)))
+            if old_fallback:
+                eng._takes_guest = lambda lane: True
+            watch = _Watch(eng)
+            for i in range(3):
+                eng.add_request(_req(f"s{i}", text="hi there"[: 2 + 3 * i], max_new=6 + 4 * i))
+            watch.step()
+            first = [l.rows for l in eng.lanes] + [len(eng._ready)]
+            assert watch.finish() == {"s0", "s1", "s2"}
+            runs[old_fallback] = watch, first
+        return runs
+
+    def test_full_home_lane_holds_the_request(self, one_request_over):
+        """(a) Home full, the idle longer lane has fewer rows: held, one decode
+        program a step, started at home when a row frees. (g) ``held`` counts
+        the steps it waited."""
+        watch, first = one_request_over[False]
+        assert first == [2, 0, 1]
+        assert set(watch.lane_of.values()) == {64} and watch.most_decode_programs == 1
+        stats = watch.eng.stats()
+        assert stats["admit_held"] >= 1 and stats["admit_guests"] == 0
+        assert watch.eng.phase_seconds["admit_held"] == stats["admit_held"]
+
+    def test_old_fallback_opened_the_long_lane(self, one_request_over):
+        """(a), the parent's side: the third request opened a second program."""
+        watch, first = one_request_over[True]
+        assert first == [2, 1, 0]
+        assert watch.lane_of["s2"] == 128 and watch.most_decode_programs == 2
+        stats = watch.eng.stats()
+        assert (stats["admit_held"], stats["admit_guests"]) == (0, 1)
+
+    def test_the_wait_changes_no_token(self, one_request_over):
+        """(a) Greedy ids of every request are those of the old fallback."""
+        held, opened = one_request_over[False][0], one_request_over[True][0]
+        assert held.ids == opened.ids
+        assert [len(held.ids[f"s{i}"]) for i in range(3)] == [6, 10, 14]
+
+    def test_guest_joins_a_lane_with_a_native_row_then_the_lane_drains(self):
+        """(b) A native long request opens the long lane and the next short
+        one, its home full, joins it as a guest. (c) Once the native row has
+        ended the lane takes no new guest, drains and closes."""
+        eng = self._engine(((64, 2), (128, 3)))
+        watch = _Watch(eng)
+        short, long = eng.lanes
+        for rid, text, n in (("s0", "hi", 24), ("s1", "ho", 24), ("long", "w " * 40, 2), ("s2", "hu", 24)):
+            eng.add_request(_req(rid, text=text, max_new=n))
+        watch.step()
+        assert short.rows == 2 and long.rows == 2 and watch.natives(long) == 1
+        assert (eng.stats()["admit_held"], eng.stats()["admit_guests"]) == (0, 1)
+        while watch.natives(long):
+            watch.step()
+        assert long.rows == 1 and short.rows == 2  # the guest alone, home still full
+        eng.add_request(_req("s3", text="he", max_new=4))
+        watch.step()
+        assert long.rows == 1 and len(eng._ready) == 1 and eng.stats()["admit_held"] == 1
+        while long.rows:
+            watch.step()
+            assert long.rows <= 1
+        assert watch.finish() == {"s0", "s1", "s2", "s3", "long"}
+        assert watch.lane_of == {"s0": 64, "s1": 64, "long": 128, "s2": 128, "s3": 64}
+        assert eng.stats()["admit_guests"] == 1
+
+    def test_larger_long_lane_opens_for_a_deep_queue(self):
+        """(d) The longer lane is the LARGER one and the ready queue is deep:
+        the guests that can join now outnumber the fullest program's rows, so
+        the lane is opened and filled."""
+        eng = self._engine(((32, 2), (64, 4)), async_prep=True)
+        watch = _Watch(eng)
+        try:
+            for i in range(8):
+                eng.add_request(_req(f"q{i}", text="hi", max_new=4))
+            _wait_ready(eng, 8)
+            watch.step()
+            short, long = eng.lanes
+            assert short.rows == 2 and long.rows == 4 and len(eng._ready) == 2
+            assert (eng.stats()["admit_held"], eng.stats()["admit_guests"]) == (0, 4)
+            assert watch.finish() == {f"q{i}" for i in range(8)}
+        finally:
+            eng.shutdown()
+
+    def test_shallow_queue_does_not_open_the_larger_long_lane(self):
+        """(d), the other side: one request over is held."""
+        eng = self._engine(((32, 2), (64, 4)))
+        watch = _Watch(eng)
+        for i in range(3):
+            eng.add_request(_req(f"q{i}", text="hi", max_new=4))
+        watch.step()
+        assert eng.lanes[0].rows == 2 and eng.lanes[1].rows == 0 and eng.stats()["admit_held"] == 1
+        assert watch.finish() == {"q0", "q1", "q2"}
+        assert set(watch.lane_of.values()) == {32}
+
+    def test_last_free_slot_exception_holds_and_a_guest_may_take_it_when_home_is_full(self):
+        """(e) With its home idle a short request leaves the long lane's last
+        free row alone; with its home full and a native row in the long lane
+        it takes that row, as it always did."""
+        eng = self._engine(((64, 2), (128, 2)))
+        watch = _Watch(eng)
+        for rid, text in (("long", "x" * 90), ("s0", "hi"), ("s1", "ho"), ("s2", "hu")):
+            eng.add_request(_req(rid, text=text, max_new=8))
+        watch.step()
+        assert eng.lanes[0].rows == 2 and eng.lanes[1].rows == 2
+        assert watch.finish() == {"long", "s0", "s1", "s2"}
+        assert watch.lane_of == {"long": 128, "s0": 64, "s1": 64, "s2": 128}
+        assert (eng.stats()["admit_held"], eng.stats()["admit_guests"]) == (0, 1)
+
+    def test_native_rows_survive_a_failed_claim(self):
+        """(f) A native long request whose claim meets an exhausted pool leaves
+        no row behind: the long lane stays closed to the short request behind
+        it, and both are served once blocks are free."""
+        eng = self._engine(((64, 1), (128, 2)))
+        watch = _Watch(eng)
+        can_alloc, refused = eng._can_alloc, []
+
+        def can(n, n_window=0):
+            if n > 4 and len(refused) < 2:  # the long request's claim, twice
+                refused.append(n)
+                return False
+            return can_alloc(n, n_window)
+
+        eng._can_alloc = can
+        for rid, text in (("s0", "hi"), ("long", "x" * 90), ("s1", "ho")):
+            eng.add_request(_req(rid, text=text, max_new=8))
+        watch.step()
+        assert refused and eng.lanes[1].rows == 0 and not eng.lanes[1].claims
+        assert watch.finish() == {"s0", "long", "s1"}
+        assert watch.lane_of["long"] == 128 and eng.stats()["admit_guests"] <= 1
+
+    def test_native_row_of_a_rerouted_multimodal_request(self):
+        """(f) A vision prompt routed to a lane too short for it is re-routed on
+        its actual length: native where it lands, so a guest may join it."""
+        eng = self._engine(((64, 1), (128, 3)))
+        watch = _Watch(eng)
+        route, calls = eng._route, []
+
+        def estimate_first(need):
+            calls.append(need)
+            return eng.lanes[0] if len(calls) == 1 else route(need)
+
+        eng._route = estimate_first
+        eng.add_request(_req("vis", text="w " * 30, frames=True, max_new=8))
+        watch.step()
+        assert len(calls) == 2 and eng.lanes[1].rows == 1 and watch.natives(eng.lanes[1]) == 1
+        eng._route = route
+        for rid in ("s0", "s1"):
+            eng.add_request(_req(rid, text="hi", max_new=4))
+        watch.step()
+        assert eng.lanes[1].rows == 2 and watch.natives(eng.lanes[1]) == 1
+        assert watch.finish() == {"vis", "s0", "s1"}
+
+    def test_reset_stats_keeps_the_rows_and_zeroes_the_counters(self):
+        """(f), (g) ``reset_stats`` zeroes ``held`` and ``guests`` and leaves
+        what a lane holds alone: the next guest still finds the native row."""
+        eng = self._engine(((64, 1), (128, 3)))
+        watch = _Watch(eng)
+        for rid, text in (("s0", "hi"), ("long", "x" * 90), ("s1", "ho")):
+            eng.add_request(_req(rid, text=text, max_new=12))
+        watch.step()
+        assert eng.stats()["admit_guests"] == 1 and watch.natives(eng.lanes[1]) == 1
+        eng.reset_stats()
+        assert (eng.stats()["admit_held"], eng.stats()["admit_guests"]) == (0, 0)
+        assert watch.natives(eng.lanes[1]) == 1
+        eng.add_request(_req("s2", text="hu", max_new=4))
+        watch.step()
+        assert eng.lanes[1].rows == 3 and eng.stats()["admit_guests"] == 1
+        assert watch.finish() == {"s0", "long", "s1", "s2"}
+
+    def test_truncated_request_is_native_to_the_longest_lane(self):
+        """(f) A prompt cut to the longest lane's budget needs that lane whole."""
+        eng = self._engine(((64, 1), (128, 2)))
+        watch = _Watch(eng)
+        eng.add_request(_req("cut", text="y" * 300, max_new=8))
+        watch.step()
+        assert eng.lanes[1].rows == 1 and watch.natives(eng.lanes[1]) == 1
+        assert watch.finish() == {"cut"}
 
 
 class TestPromptBudgetGuard:
