@@ -31,6 +31,8 @@ CAPTION_MODEL_CHOICES = (
     "qwen3vl-moe-a3b",
     "qwen3moe-tiny-test",
     "qwen-chat-tiny-test",
+    "solar-open2-ep8",
+    "solar-open2-tiny-test",
     "tiny-test",
     "trinity-large-ep8",
     "trinity-tiny-test",
@@ -112,6 +114,9 @@ def register(sub: argparse._SubParsersAction) -> None:
         "gated-delta-rule layers whose matrix state lives in the recurrent store beside 30-head "
         "attention layers; whole it wants a device of 24 GB or more, olmo-hybrid-7b-pp2 is the "
         "first of two pipeline stages (16 layers, table and head) and fits a v5e chip. "
+        "solar-open2-ep8 (text only, no converter yet: it needs staged weights) is one chip's share "
+        "of Solar-Open2-250B's first four-layer stage served expert-parallel over 8: three "
+        "Kimi-Delta-Attention layers and one gated attention layer, each over 40 of 320 experts. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

@@ -33,11 +33,13 @@ def dense(
     use_bias: bool = True,
     dtype=jnp.bfloat16,
     param_dtype=jnp.float32,
+    precision=None,
 ):
     """Dense with kernel sharding: shard='out' partitions output features,
     'in' partitions input features, None replicates. ``dtype`` is the type
     the layer computes in, ``param_dtype`` the type its kernel and bias are
-    stored in (flax casts them to ``dtype`` at every call where they differ)."""
+    stored in (flax casts them to ``dtype`` at every call where they differ);
+    ``precision`` is the product's (None: the backend's default)."""
     if shard == "out":
         spec = (None, MODEL_AXIS)
         bias_spec = (MODEL_AXIS,)
@@ -56,6 +58,7 @@ def dense(
         use_bias=use_bias,
         dtype=dtype,
         param_dtype=param_dtype,
+        precision=precision,
         kernel_init=kernel_init,
         bias_init=bias_init,
         name=name,

@@ -570,12 +570,21 @@ def _handoff_works(mesh, put_sharding) -> bool:
     return False
 
 
-def _count_held(held, aux):
+def _count_held(held, aux, live=None):
     """A decode program's rider: the device's count of the assignments that landed
     on the experts held here (``MoEFFN._sorted_experts`` sows one a layer) added
-    to ``held``, two int32s ``[units, 2**30s]`` so that it never wraps."""
+    to ``held``, two int32s ``[units, 2**30s]`` so that it never wraps. With
+    ``live`` (``[rows]`` bool: the recurrent program's, whose idle rows carry
+    store row 0) ``held`` is two such pairs and the second counts the LIVE
+    rows' assignments alone: an idle row's token is routed and multiplied like
+    any other, so the first pair is what the grouped product's cost follows
+    and the second what requests asked of it."""
     units = held[0] + sum(jax.tree.leaves(aux["intermediates"]))
-    return jnp.stack([units % 2**30, held[1] + units // 2**30])
+    total = [units % 2**30, held[1] + units // 2**30]
+    if live is None:
+        return jnp.stack(total)
+    mine = held[2] + jnp.sum(jnp.where(live, sum(jax.tree.leaves(aux["held_by_token"])), 0))
+    return jnp.stack([*total, mine % 2**30, held[3] + mine // 2**30])
 
 
 def default_block_size(kv_lanes) -> int:
@@ -1239,7 +1248,7 @@ class CaptionEngine:
             self._build_recurrent_programs()
         if self._indexed:
             self._build_indexed_programs()
-        elif self._counts_experts:
+        elif self._counts_experts and not self._recurrent:  # (a hybrid's own decode carries the rider)
             self._build_counted_decode()
         self._built = True
         if self.async_prep:
@@ -1255,8 +1264,8 @@ class CaptionEngine:
         int32s, ``[units, 2**30s]``, so that it never wraps; not donated, so a
         reader of ``stats()`` may hold an old one while the next is made."""
         cfg, model, use_paged, r = self.cfg, self.model, self._use_paged, self._kv_heads_per_pool_row
-        if self._recurrent or cfg.mrope_section is not None:
-            raise ValueError("sorted expert dispatch beside a recurrent store or m-rope has no program here")
+        if cfg.mrope_section is not None:
+            raise ValueError("sorted expert dispatch beside m-rope has no program here")
 
         @partial(jax.jit, donate_argnums=(1, 2))
         def decode_step_counted(params, pool_k, pool_v, tables, tokens, positions, rope_positions, held):
@@ -1341,41 +1350,51 @@ class CaptionEngine:
         self._prefix_prefill = prefix_prefill_indexed
         self._write_index_blocks = write_index_blocks
 
-    @property
-    def expert_assignments_held(self) -> int:
-        """Assignments that landed on the experts held here, over the layers of
-        every decode program since the last ``reset_stats()``: read off the
-        device now (waits for the last program dispatched)."""
+    def _held_counts(self) -> tuple[int, int]:
+        """(assignments that landed on the experts held here, those of them that
+        live rows made), over the layers of every decode program since the last
+        ``reset_stats()``: read off the device now (waits for the last program
+        dispatched). Only a hybrid's decode program knows its idle rows (they
+        carry store row 0): elsewhere the second is 0."""
         held = self._expert_held
         if held is None:
-            return 0
-        units, big = (int(v) for v in np.asarray(held))
-        return big * 2**30 + units
+            return 0, 0
+        units, big, *live = (int(v) for v in np.asarray(held))
+        return big * 2**30 + units, (live[1] * 2**30 + live[0] if live else 0)
+
+    @property
+    def expert_assignments_held(self) -> int:
+        return self._held_counts()[0]
 
     def _build_recurrent_programs(self) -> None:
         """A hybrid's programs: the prefill and decode programs with the
         recurrent store riding along (the store's arguments come after the
         others', its two arrays after the pools in what is returned), the
         shared prefix's build with its state snapshot, and the two ways a
-        slot's row starts. They take the place of setup()'s."""
+        slot's row starts. They take the place of setup()'s. Where the experts'
+        dispatch is sorted the decode program also carries the counted decode's
+        rider (``_build_counted_decode``): ``held`` after ``rows``, the new
+        count after the stores."""
         cfg, model, use_paged, r = self.cfg, self.model, self._use_paged, self._kv_heads_per_pool_row
         if cfg.mrope_section is not None or self._ds_levels:
             raise ValueError("a hybrid flavor with m-rope or deepstack has no program here")
+        counted = self._counts_experts
 
         def chunk_forward(params, pool_k, pool_v, tables, embeds, rope, write_index, kv_len, **kw):
             """The forward of both: as in setup()'s four programs, the
-            recurrent store riding along (``recurrent=`` in ``kw``)."""
+            recurrent store riding along (``recurrent=`` in ``kw``; with
+            ``mutable=`` what the model sowed comes back beside the rest)."""
             if use_paged:
                 return model.apply(
                     params, embeds, pool_k, pool_v, rope, write_index, kv_len, tables,
                     method=model.paged_forward, **kw,
                 )
             ck, cv = gather_block_views(pool_k, pool_v, tables, r)
-            logits, nk, nv, ssm, conv = model.apply(
-                params, embeds, ck, cv, rope, write_index, kv_len, **kw
-            )
+            out = model.apply(params, embeds, ck, cv, rope, write_index, kv_len, **kw)
+            (logits, nk, nv, ssm, conv), *sown = out if "mutable" in kw else (out,)
             pool_k, pool_v = scatter_block_views(pool_k, pool_v, tables, nk, nv)
-            return logits, pool_k, pool_v, ssm, conv
+            out = (logits, pool_k, pool_v, ssm, conv)
+            return (out, *sown) if sown else out
 
         @partial(jax.jit, donate_argnums=(1, 2, 9, 10))
         def prefill_batch_recurrent(
@@ -1393,18 +1412,20 @@ class CaptionEngine:
 
         @partial(jax.jit, donate_argnums=(1, 2, 7, 8))
         def decode_step_recurrent(
-            params, pool_k, pool_v, tables, tokens, positions, rope_positions, ssm, conv, rows
+            params, pool_k, pool_v, tables, tokens, positions, rope_positions, ssm, conv, rows, held=None
         ):
             """decode_step(_paged) for a hybrid: idle rows carry store row 0,
             the garbage row, and do not advance it either."""
             embeds = model.apply(params, tokens[:, None], method=model.embed_tokens)
-            logits, pool_k, pool_v, ssm, conv = chunk_forward(
+            out = chunk_forward(
                 params, pool_k, pool_v, tables, embeds, rope_positions[:, None], positions,
                 positions + 1, recurrent=(ssm, conv, rows, (rows > 0).astype(jnp.int32)),
+                **({"mutable": ["intermediates", "held_by_token"]} if counted else {}),
             )
+            (logits, pool_k, pool_v, ssm, conv), *sown = out if counted else (out,)
             step_logits = logits[:, 0]
             greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
-            return greedy, step_logits, pool_k, pool_v, ssm, conv
+            return (greedy, step_logits, pool_k, pool_v, ssm, conv, *(_count_held(held, aux, rows > 0) for aux in sown))
 
         @jax.jit
         def prefix_prefill_recurrent(params, embeds, rope_pos, t_valid):
@@ -1437,6 +1458,8 @@ class CaptionEngine:
         self._prefix_prefill = prefix_prefill_recurrent
         self._set_state_row = set_state_row
         self._zero_state_row = zero_state_row
+        if counted:
+            self._expert_held = jnp.zeros(4, jnp.int32)  # all rows' pair, the live rows' pair
 
     # -- public API -----------------------------------------------------
     @property
@@ -1725,7 +1748,7 @@ class CaptionEngine:
         of the block-size fallback: the constructor-requested size and the
         gcd-shrunk divisor actually used, so cross-run comparisons can
         detect a silent shrink."""
-        held = self.expert_assignments_held  # a device read: outside the lock
+        held, held_live = self._held_counts()  # a device read: outside the lock
         with self._stats_lock:
             return {
                 "paged_attention": self.paged_attention,
@@ -1775,6 +1798,7 @@ class CaptionEngine:
                 ),
                 "mla_decode_calls": self._mla_decode_calls,
                 "expert_assignments_held": held,
+                "expert_assignments_held_live": held_live,
                 # a learned indexer (all zero without one): its keys' array as
                 # stored, and its decode steps' positions seen and read
                 "index_pool_bytes_per_chip": self._index_pool_bytes_per_chip,
@@ -1922,7 +1946,7 @@ class CaptionEngine:
             self._sparse_decode_rows_walked = 0
             self._sparse_decode_rows_gathered = 0
             if self._expert_held is not None:
-                self._expert_held = jnp.zeros(2, jnp.int32)
+                self._expert_held = jnp.zeros_like(self._expert_held)
             self._interleaved_steps = 0
             self._owner_decode_tokens.clear()
             self._owner_requests.clear()
@@ -3386,22 +3410,25 @@ class CaptionEngine:
                 jnp.asarray(positions),
                 jnp.asarray(rope_positions),
             )
-            if self._counts_experts:
-                greedy, logits, *pools, self._expert_held = self._decode(*args, self._expert_held)
-                self._keep_pools(*pools)
-            elif not self._recurrent:
-                greedy, logits, *pools = self._decode(*args)
-                self._keep_pools(*pools)
-            else:
+            if self._recurrent:
                 # rows that decode advance their own state; the others (free,
                 # or mid-prefill and holding a real state) carry the garbage
                 # row and are masked in the recurrence besides
                 rows = np.zeros(lane.n_slots, np.int32)
                 active = list(lane.slots)
                 rows[active] = self._state_rows(lane, active)
-                greedy, logits, self._pool_k, self._pool_v, self._ssm, self._conv = (
-                    self._decode(*args, self._ssm, self._conv, jnp.asarray(rows))
+                rider = (self._expert_held,) if self._counts_experts else ()
+                greedy, logits, self._pool_k, self._pool_v, self._ssm, self._conv, *held = (
+                    self._decode(*args, self._ssm, self._conv, jnp.asarray(rows), *rider)
                 )
+                if held:
+                    self._expert_held = held[0]
+            elif self._counts_experts:
+                greedy, logits, *pools, self._expert_held = self._decode(*args, self._expert_held)
+                self._keep_pools(*pools)
+            else:
+                greedy, logits, *pools = self._decode(*args)
+                self._keep_pools(*pools)
         lane.inflight = _InFlight(
             greedy=greedy, logits=logits, rows=dict(lane.slots), positions=positions,
             program=program, choice=self._choice_digest,

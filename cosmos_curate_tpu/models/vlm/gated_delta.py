@@ -1,4 +1,5 @@
-"""The gated-delta-rule mixer of a hybrid decoder layer (Olmo-Hybrid).
+"""The gated-delta-rule mixer of a hybrid decoder layer (Olmo-Hybrid; with a
+decay a channel, Solar-Open2's Kimi Delta Attention).
 
 After flash-linear-attention's ``GatedDeltaNet`` as HF's ``linear_*`` keys
 configure it (separate q / k / v convolutions, l2-normed q and k, the norm
@@ -11,6 +12,13 @@ BEFORE the gate), for one layer's input ``h``:
     g = -exp(A_log) * softplus(W_a h + dt_bias)                          (per head)
     S_t = exp(g_t) S_{t-1} + k_t (x) (beta_t (v_t - (exp(g_t) S_{t-1})^T k_t)) ; o_t = S_t^T q_t
     out = W_o (RMSNorm_dv(o) * w * silu(W_g h))
+
+Kimi Delta Attention (``KimiDeltaAttention``; ``GatedDeltaConfig.decay_rank``
+and ``gate_rank`` set) is the same mixer with a decay a CHANNEL and a low-rank
+sigmoid gate; everything above holds but these two lines:
+
+    g = -exp(A_log[h]) * softplus(W_f2 (W_f1 h) + dt_bias)     in [H, dk]: S' = Diag(exp(g_t)) S_{t-1}
+    out = W_o (RMSNorm_dv(o) * w * sigmoid(W_g2 (W_g1 h)))
 
 What a request carries from token to token is ``S`` (float32, ``[dk, dv]`` a
 head) and the three convolutions' last ``d_conv - 1`` inputs; both live in
@@ -86,10 +94,17 @@ class GatedDeltaMixer(nn.Module):
 
         token = jnp.arange(t)[None, :, None] < valid[:, None, None]
         beta = jax.nn.sigmoid(proj(nh, "b_proj")(h).astype(f32)) * (2.0 if m.allow_neg_eigval else 1.0)
-        dt_bias = small("dt_bias", _dt_bias_init, (nh,), f32)
-        a = jnp.exp(small("A_log", _a_log_init, (nh,), f32))
-        g = -a * jax.nn.softplus(proj(nh, "a_proj")(h).astype(f32) + dt_bias)
-        beta, g = jnp.where(token, beta, 0.0), jnp.where(token, g, 0.0)
+        if m.decay_rank is None:
+            dt_bias = small("dt_bias", _dt_bias_init, (nh,), f32)
+            a = jnp.exp(small("A_log", _a_log_init, (nh,), f32))
+            g = -a * jax.nn.softplus(proj(nh, "a_proj")(h).astype(f32) + dt_bias)
+            beta, g = jnp.where(token, beta, 0.0), jnp.where(token, g, 0.0)
+        else:  # a decay a channel, out of a low-rank pair
+            dt_bias = small("dt_bias", _dt_bias_init, (nh * dk,), f32)
+            a = jnp.exp(small("A_log", _a_log_init, (nh,), f32))
+            f = proj(nh * dk, "f_b_proj")(proj(m.decay_rank, "f_a_proj")(h)).astype(f32)
+            g = -a[:, None] * jax.nn.softplus(f + dt_bias).reshape(b, t, nh, dk)
+            beta, g = jnp.where(token, beta, 0.0), jnp.where(token[..., None], g, 0.0)
         if t == 1:
             with jax.named_scope("delta.decode"):
                 o, ssm = delta_ops.delta_decode(
@@ -105,8 +120,12 @@ class GatedDeltaMixer(nn.Module):
 
         # RMSNorm over a head's dv, THEN the gate
         with jax.named_scope("delta.gate_norm"):
-            gate = proj(nh * dv, "g_proj")(h).astype(f32).reshape(b, t, nh, dv)
+            if m.gate_rank is None:
+                gate, act = proj(nh * dv, "g_proj")(h), nn.silu
+            else:
+                gate, act = proj(nh * dv, "g_b_proj")(proj(m.gate_rank, "g_a_proj")(h)), jax.nn.sigmoid
+            gate = gate.astype(f32).reshape(b, t, nh, dv)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + self.rms_eps)
-            o = o * small("o_norm_scale", nn.initializers.ones, (dv,), f32) * nn.silu(gate)
+            o = o * small("o_norm_scale", nn.initializers.ones, (dv,), f32) * act(gate)
         out = proj(self.dim, "o_proj")(o.reshape(b, t, nh * dv).astype(self.dtype))
         return out, ssm, new_tail

@@ -96,6 +96,12 @@ class MoEConfig:
     # and not for the weights (the load balancer's handle on the router: HF
     # ``expert_bias``); False = none stored
     selection_bias: bool = False
+    # the router's product in full float32 ("highest"): on the chip a float32
+    # matmul at the default precision multiplies bfloat16 operands, so the
+    # stored float32 kernel is ROUNDED there, and a score moves by what a
+    # bfloat16 router's would (2e-3). None = the backend's default, what the
+    # older sparse flavors were measured with
+    router_precision: str | None = None
 
     def __post_init__(self) -> None:
         if self.dispatch not in ("queue", "sorted"):
@@ -205,9 +211,10 @@ class Mamba2Config:
 @dataclass(frozen=True)
 class GatedDeltaConfig:
     """The gated-delta-rule mixer of a hybrid decoder (models/vlm/gated_delta.py;
-    flash-linear-attention's ``GatedDeltaNet``, HF's ``linear_*`` keys): ``n_heads``
-    heads, each a ``[key_dim, value_dim]`` float32 state; q, k and v each pass
-    through a short convolution of their own."""
+    flash-linear-attention's ``GatedDeltaNet``, HF's ``linear_*`` keys, or its
+    ``KimiDeltaAttention``, HF's ``linear_attn_config``): ``n_heads`` heads, each
+    a ``[key_dim, value_dim]`` float32 state; q, k and v each pass through a
+    short convolution of their own."""
 
     n_heads: int = 30
     key_dim: int = 96
@@ -218,6 +225,19 @@ class GatedDeltaConfig:
     allow_neg_eigval: bool = True
     # tokens a step of the prefill scan solves at once (ops/delta_rule.py)
     chunk: int = 64
+    # -- Kimi Delta Attention (arXiv:2510.26692; flash-linear-attention's
+    # ``KimiDeltaAttention``); the defaults are GatedDeltaNet's --
+    # the decay a CHANNEL, ``[n_heads, key_dim]`` a token, out of a low-rank
+    # pair of this rank (``f_a_proj``, ``f_b_proj``; ``dt_bias`` a channel,
+    # ``A_log`` a head); None = a scalar a head out of ``a_proj``
+    decay_rank: int | None = None
+    # the output gate a SIGMOID of a low-rank pair of this rank (``g_a_proj``,
+    # ``g_b_proj``); None = silu of one full projection (``g_proj``)
+    gate_rank: int | None = None
+
+    def __post_init__(self) -> None:
+        if (self.decay_rank is None) != (self.gate_rank is None):
+            raise ValueError("decay_rank and gate_rank are Kimi Delta Attention's pair: both set or neither")
 
     @property
     def conv_dim(self) -> int:  # q | k | v pass through the convolutions
@@ -839,6 +859,77 @@ VLM_OLMO_HYBRID_TINY_TEST = VLMConfig(
     sandwich_norm=True,
     qk_norm_whole=True,
 )
+# Solar-Open2-250B (HF ``solar_open2``, config.json of upstage/Solar-Open2-250B)
+# as ONE CHIP OF AN 8-WAY EXPERT-PARALLEL STAGE sees it: 48 layers x 4096 in
+# periods of four (one gated GQA layer, 64 query / 8 KV heads x 128, no position
+# embedding, ``o * sigmoid(W_g x)`` before ``W_o``; then three Kimi-Delta-Attention
+# layers: 64 heads, a [128, 128] float32 state each whose decay is a vector over
+# the 128 key channels, three short convolutions, low-rank decay and gate
+# projections of rank 128), EVERY layer followed by 320 routed experts of 1280
+# (sigmoid scores + a stored selection bias, top 8 renormalised) and one shared
+# expert; untied head. Every width as published, the router whole, and of the
+# rest this chip's share: 40 consecutive experts (0-39), a vocabulary slice of
+# 24,576 rows, and ONE period of the twelve (the first of twelve four-layer
+# pipeline stages: 96 v5e chips hold the model). Mixers, attention, router and
+# shared expert are replicated over the eight in that deployment, so they are
+# whole here; the layer runs without its exchange. Text only. The first flavor
+# with a recurrent store BESIDE sorted experts (engine.py: the recurrent
+# programs carry the held-assignment rider).
+_SOLAR_PERIOD = ("full_attention",) + ("linear_attention",) * 3
+VLM_SOLAR_OPEN2_EP8 = VLMConfig(
+    vocab=24576,
+    dim=4096,
+    n_layers=4,
+    n_heads=64,
+    n_kv_heads=8,
+    head_dim=128,
+    hidden_mult=10240 / 4096,  # published; serves no layer (``first_k_dense_replace`` 0)
+    max_seq=4096,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    tied_embeddings=False,
+    layer_types=_SOLAR_PERIOD,
+    gated_delta=GatedDeltaConfig(
+        n_heads=64, key_dim=128, value_dim=128, d_conv=4, allow_neg_eigval=True, chunk=64,
+        decay_rank=128, gate_rank=128,
+    ),
+    use_rope=False,
+    attention_gate=True,
+    moe=MoEConfig(
+        n_experts=320, top_k=8, hidden=1280, shared_hidden=1280, norm_topk_prob=True,
+        routed_scaling_factor=1.0, dispatch="sorted", held=(0, 40), score_func="sigmoid",
+        selection_bias=True, router_precision="highest",
+    ),
+)
+# the same mechanisms at test size: one period; dk != dv; a scan chunk of two
+# sub-blocks of 16, so a prefill chunk of 40 crosses scan chunks AND sub-blocks;
+# 16 experts in eight shares of 2 (this chip: experts 2-3)
+VLM_SOLAR_OPEN2_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    tied_embeddings=False,
+    layer_types=_SOLAR_PERIOD,
+    gated_delta=GatedDeltaConfig(
+        n_heads=4, key_dim=16, value_dim=32, d_conv=4, allow_neg_eigval=True, chunk=32,
+        decay_rank=8, gate_rank=8,
+    ),
+    use_rope=False,
+    attention_gate=True,
+    moe=MoEConfig(
+        n_experts=16, top_k=4, hidden=32, shared_hidden=32, norm_topk_prob=True,
+        dispatch="sorted", held=(2, 2), score_func="sigmoid", selection_bias=True, router_precision="highest",
+    ),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -1137,6 +1228,27 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 4), (128, 2)),
         ),
+        # a 250B-class sparse hybrid text LM served expert-parallel, seen from one
+        # of the eight chips of its first four-layer stage (the LM-only passes,
+        # --enhance-captions): a row costs 12 MiB of state + 0.43 MiB of tails
+        # whatever its context and 4 KiB of K/V a position in the ONE attention
+        # layer; 264 rows are 3.2 GiB of store and 1.1 GiB of pool beside 6.4
+        # GiB of parameters. 256 decoding rows give the 40 held experts the 256
+        # assignments a step that 32 rows a chip give them in the deployment
+        "solar-open2-ep8": FlavorSpec(
+            VLM_SOLAR_OPEN2_EP8,
+            "caption-solar-open2-ep8-tpu",
+            text_only=True,
+            kv_lanes=((1024, 256), (4096, 8)),
+            prefill_rows=8,  # 2,048 tokens a prefill program
+        ),
+        "solar-open2-tiny-test": FlavorSpec(
+            VLM_SOLAR_OPEN2_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 4), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -1325,7 +1437,7 @@ class MoEFFN(nn.Module):
         n = b * t
         e, k, h = moe.n_experts, moe.top_k, moe.hidden
         tokens = x.reshape(n, d)
-        logits = dense(e, None, name="router", use_bias=False, dtype=jnp.float32)(
+        logits = dense(e, None, name="router", use_bias=False, dtype=jnp.float32, precision=moe.router_precision)(
             tokens.astype(jnp.float32)
         )
         bias = (
@@ -1421,6 +1533,9 @@ class MoEFFN(nn.Module):
             held = sizes.sum()
             # read with stats(), never in the step loop (engine: expert_assignments_held)
             self.sow("intermediates", "held", held)
+            # for the program that tells live rows from idle (init would keep the collection as a variable)
+            if self.is_mutable_collection("held_by_token") and not self.is_initializing():
+                self.sow("held_by_token", "held", (expert < count).reshape(n, k).sum(axis=1))
             rows = tokens.astype(self.dtype)[order // k]  # [A, D], expert-major
             z = grouped_matmul(rows, gate_up.astype(self.dtype), sizes)
             gate, up = jnp.split(z, 2, axis=-1)
@@ -2103,7 +2218,7 @@ class VLM(nn.Module):
             in_place = x.shape[1] == 1 and ops.decode_in_place(use_kernel)
             ssm, rows = (
                 (store_ssm, store_rows) if in_place
-                else (store_ssm[:, store_rows], jnp.arange(x.shape[0], dtype=jnp.int32))
+                else (_rows_of(store_ssm, store_rows), jnp.arange(x.shape[0], dtype=jnp.int32))
             )
         # window and full layers mixed, paged: ``cache_k`` / ``cache_v`` /
         # ``block_tables`` are pairs, (the pool every flavor has, the window
@@ -2157,6 +2272,15 @@ class VLM(nn.Module):
         """A scratch recurrent store of ``batch`` rows, all zeros."""
         ssm, conv = init_recurrent_store(self.cfg, batch, dtype=self.dtype)
         return ssm, conv, jnp.arange(batch, dtype=jnp.int32), valid
+
+
+def _rows_of(store, rows):
+    """``store[:, rows]`` (``[L, R, ...]`` -> ``[L, B, ...]``) as ONE DYNAMIC SLICE A
+    ROW. As a gather the chip's compiler copies the whole store to take a
+    program's few rows out of it (3.1 GiB of scratch beside a 265-row store of
+    12 MiB rows, read off an ahead-of-time compile: the compiled text's
+    ``mini-gather-slice``, PERF.md PR 49)."""
+    return jnp.stack([jax.lax.dynamic_index_in_dim(store, r, 1, keepdims=False) for r in rows], axis=1)
 
 
 def init_recurrent_store(cfg: VLMConfig, rows: int, dtype=jnp.bfloat16):

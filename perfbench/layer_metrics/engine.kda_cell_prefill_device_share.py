@@ -1,0 +1,17 @@
+"""Share of the programs' device seconds that the prefill programs took, first
+chip, traced slice, in the cell that holds a recurrent store beside sorted
+experts (a step holds more than one prefill program there, each of which reads
+every parameter): the DEVICE's view of what ``engine.prefill_share`` reads on
+the host's clock, from the trace's line of programs as the driver summed them
+by kind (``program_s``: ``engine.prefill_device_share``'s arithmetic on this
+driver's record). Nothing to read where the driver records no such cell."""
+
+UNIT, LAYER, MOVES, SOURCE = "%", "caption engine", "output_tok_per_s", "device_trace"
+
+
+def read(run):
+    programs = run.get("program_s")
+    total = sum(seconds for seconds, _runs in programs.values()) if programs else 0.0
+    if not total or "kda" not in run:
+        return None
+    return 100.0 * programs["prefill"][0] / total

@@ -76,6 +76,7 @@ def pytest_collection_modifyitems(items):
             "test_catalog.py::test_config_file[trinity-large-ep8]",
             "test_catalog.py::test_config_file[keye-vl2-a3b-ep8]",
             "test_catalog.py::test_config_file[olmo-hybrid-7b-pp2]",
+            "test_catalog.py::test_config_file[solar-open2-ep8]",  # (PR 49: test_solar_open2_cell.py repeats them)
         )):
             item.add_marker(pytest.mark.xfail(
                 reason="test_catalog's WIDTH_KEYS matches 'hidden' in num_hidden_layers, a depth", strict=False,

@@ -428,6 +428,33 @@ def test_delta_decode_kernel_compiles_for_v5e_under_its_trace_name(v5e, heads_pe
     assert calls and all(re.search(r"^_?delta_decode(\.\d+)?$", c) for c in calls), calls
 
 
+@pytest.mark.parametrize("heads_per_step", [16, 32])
+def test_channel_decay_decode_kernel_compiles_for_v5e_under_the_same_trace_name(v5e, heads_per_step):
+    """Solar-Open2's widths, the cell's rows: 64 heads x [128, 128] side by
+    side, 8192 lanes, 4 MiB a row a layer; the decay a third column, ``k | q |
+    a`` in ONE ``[128, 3 * heads]`` block (48 or 96 lanes of a 128-lane tile).
+    The custom call carries the name the benchmark's trace reduction looks
+    for, whichever shape the decay has."""
+    from cosmos_curate_tpu.ops.delta_rule import _delta_decode
+    from perfbench import trace_reduce
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows, h, dk, dv = 256, 64, 128, 128
+    fn = functools.partial(_delta_decode, heads_per_step=heads_per_step, interpret=False)
+    hlo = jax.jit(fn, donate_argnums=(0,)).lower(
+        arg((3, rows + 9, dk, h * dv)), arg((), jnp.int32), arg((rows,), jnp.int32),
+        arg((rows, h, dk)), arg((rows, h, dk)), arg((rows, h, dv)), arg((rows, h, dk)), arg((rows, h)),
+    ).compile().as_text()
+    calls = [
+        trace_reduce.instruction(line.strip().removeprefix("ROOT "))
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert calls and all(re.search(r"^_?delta_decode(\.\d+)?$", c) for c in calls), calls
+
+
 @pytest.mark.parametrize(
     "kernel,rows,lane",
     [
