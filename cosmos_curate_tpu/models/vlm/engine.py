@@ -395,6 +395,9 @@ _PHASES = _PHASE_ROOTS + (
 # `frames`, a prefill program's `rows` and `live`) is span metadata only.
 _PHASE_COUNTS = {
     "step": ("n",),
+    # rounds of preparation, and the requests they carried (_prep_loop takes every
+    # waiting text request it may a round; inline prep is a round of one)
+    "prep": ("n", "requests"),
     # _route: heads held back from an idle longer lane, and requests admitted
     # to a lane longer than their home
     "admit": ("held", "guests"),
@@ -415,6 +418,9 @@ _PHASE_COUNTS = {
 _PHASE_EXPOSED = ("step", "prefill_dispatch", "decode_dispatch")
 _HANDS_OVER = ("prefill_dispatch", "decode_dispatch")
 _PROVES = ("prefill_wait", "decode_wait")
+# The padded lengths of a prep round's one embedding call (_embed_bucket)
+_EMBED_BUCKET_MIN = 128
+_EMBED_BUCKET_STEP = 4096
 
 
 class _Phase:
@@ -905,7 +911,9 @@ class CaptionEngine:
         # deterministic step() semantics for tests.
         self.async_prep = async_prep
         self._ready: "deque[_Prepared]" = deque()
-        self._prep_inflight: CaptionRequest | None = None
+        # the round the prep thread is preparing (popped from `waiting`, not yet
+        # in `_ready`); empty between rounds
+        self._prep_inflight: list[CaptionRequest] = []
         self._prep_thread: threading.Thread | None = None
         self._prep_stop = False
         # admission linger: when EVERY lane is idle and a burst is still
@@ -1252,6 +1260,10 @@ class CaptionEngine:
             self._build_counted_decode()
         self._built = True
         if self.async_prep:
+            # every length a prep round's embedding call can take, compiled here:
+            # none compiles while requests are served
+            for n in self._embed_buckets():
+                embed_tokens(self.params, np.zeros((1, n), np.int32))
             # requests may already be waiting (queued before setup)
             with self._work_cv:
                 self._start_prep_thread()
@@ -1492,10 +1504,7 @@ class CaptionEngine:
     def _prep_requests(self) -> list[CaptionRequest]:
         """Requests past ``waiting`` but not yet admitted (prepared or
         mid-prep in the background thread). Lock held by caller."""
-        reqs = [p.request for p in self._ready]
-        if self._prep_inflight is not None:
-            reqs.append(self._prep_inflight)
-        return reqs
+        return [p.request for p in self._ready] + self._prep_inflight
 
     def has_work(self, owner: Any = None) -> bool:
         """Whether anything of ``owner`` (of anyone, for None) is still to do.
@@ -1858,8 +1867,8 @@ class CaptionEngine:
 
             for r in self.waiting:
                 bucket(r.owner)["waiting"] += 1
-            if self._prep_inflight is not None:
-                bucket(self._prep_inflight.owner)["waiting"] += 1
+            for r in self._prep_inflight:
+                bucket(r.owner)["waiting"] += 1
             for p in self._ready:
                 bucket(p.request.owner)["ready"] += 1
             for lane in self.lanes:
@@ -2059,10 +2068,12 @@ class CaptionEngine:
 
     def _prep_loop(self) -> None:
         """Background prep: vision encode + token embedding for waiting
-        requests, FIFO, overlapping the caller's decode loop. Device
-        compute runs OUTSIDE the engine lock — the lock only guards queue
-        hops, so a decode step never waits on a vision encode and vice
-        versa (device-side serialization is the hardware's business)."""
+        requests, a ROUND at a time (``_take_round``: every text request there
+        is room for, or one request with frames), overlapping the caller's
+        decode loop. Device compute runs OUTSIDE the engine lock — the lock
+        only guards the two queue hops of a round, so a decode step never
+        waits on a vision encode and vice versa (device-side serialization is
+        the hardware's business)."""
         while True:
             with self._work_cv:
                 while not self._prep_stop and (
@@ -2071,14 +2082,41 @@ class CaptionEngine:
                     self._work_cv.wait(0.1)
                 if self._prep_stop:
                     return
-                req = self._pop_waiting_fair()
-                self._prep_inflight = req
-            prep = self._safe_prepare(req)  # no lock: overlaps decode
+                taken = self._prep_inflight = self._take_round()
+            preps = self._prepare_round(taken)  # no lock: overlaps decode
             with self._work_cv:
-                self._prep_inflight = None
-                if prep is not None:
-                    self._ready.append(prep)
+                self._prep_inflight = []
+                self._ready.extend(preps)
                 self._work_cv.notify_all()
+
+    # holds-lock: _lock
+    def _take_round(self) -> list[CaptionRequest]:
+        """The waiting requests one round of preparation takes, in the order
+        ``_pop_waiting_fair`` hands them out (owners rotate, FIFO inside an
+        owner): every text request ``_ready`` has room for under
+        ``_prep_ahead_limit()``, while the ids they embed together fit the
+        longest lane and the largest power-of-two bucket (4,096). The first
+        is taken whatever its length, so a round is one long prompt or many
+        short ones, and its size is read off the queue: one request where one
+        waits. A request with frames is a round of its own: the tower's
+        program takes one request's frames, and no text request is kept behind
+        an encode. Past a few thousand ids a request's own copy and
+        conversion outweigh what a round shares (two waits for the lock, a
+        device round trip), and a second prompt in its round only delays it:
+        two long prompts a round cost a prefill-bound loop 2-5% of its rows."""
+        room = self._prep_ahead_limit() - len(self._ready)
+        budget = min(self._max_len, _EMBED_BUCKET_STEP)
+        taken: list[CaptionRequest] = []
+        while self.waiting and len(taken) < room:
+            head = self.waiting[self._fair_waiting()[1]]
+            need = None if head.frames is not None else len(self._text_ids(head))
+            if taken and (need is None or need > budget):
+                break  # it opens the next round
+            taken.append(self._pop_waiting_fair())
+            if need is None:
+                break
+            budget -= need
+        return taken
 
     # every stage instance mints a fresh owner tag, so a long-lived shared
     # engine would otherwise accumulate owner-keyed state forever (and mint
@@ -2102,8 +2140,7 @@ class CaptionEngine:
             return
         live = {r.owner for r in self.waiting}
         live.update(p.request.owner for p in self._ready)
-        if self._prep_inflight is not None:
-            live.add(self._prep_inflight.owner)
+        live.update(r.owner for r in self._prep_inflight)
         for lane in self.lanes:
             live.update(s.request.owner for s in lane.slots.values())
             live.update(p.request.owner for p in lane.pending.values())
@@ -2131,25 +2168,94 @@ class CaptionEngine:
             return None
         return min(eligible, key=lambda kv: (last_map.get(kv[0], -1), kv[1]))
 
-    def _pop_waiting_fair(self) -> CaptionRequest:
-        """Next waiting request: one pipeline's burst cannot push another
-        pipeline's requests out of the prep pipeline (cross-job fairness
-        starts at prep, since only prepped requests can be admitted).
-        Single-owner queues reduce to plain FIFO. Lock held by caller."""
-        owner, idx = self._fair_head(
+    def _fair_waiting(self) -> tuple[Any, int]:
+        """(owner, index in ``waiting``) of the next waiting request: one
+        pipeline's burst cannot push another pipeline's requests out of the
+        prep pipeline (cross-job fairness starts at prep, since only prepped
+        requests can be admitted). Single-owner queues reduce to plain FIFO.
+        Lock held by caller."""
+        return self._fair_head(
             (r.owner for r in self.waiting), self._owner_last_prep, {}, float("inf")
         )
+
+    def _pop_waiting_fair(self) -> CaptionRequest:
+        """Take ``_fair_waiting()``'s request: its owner has had its turn.
+        Lock held by caller."""
+        owner, idx = self._fair_waiting()
         self._owner_last_prep[owner] = self._prep_seq
         self._prep_seq += 1
         return self.waiting.pop(idx)
 
-    def _safe_prepare(self, req: CaptionRequest) -> "_Prepared | None":
-        with self._phase("prep"):
-            try:
-                return self._prepare(req)
-            except Exception:
-                logger.exception("prefill prep failed for %s; dropping", req.request_id)
-                return None
+    def _safe_prepare(self, req: CaptionRequest, text_embeds=None) -> "_Prepared | None":
+        try:
+            return self._prepare(req, text_embeds=text_embeds)
+        except Exception:
+            logger.exception("prefill prep failed for %s; dropping", req.request_id)
+            return None
+
+    def _prepare_round(self, reqs: list[CaptionRequest]) -> "list[_Prepared]":
+        """Prepare a round (``_take_round``) in the order it was taken: its
+        text is embedded by one call and read by one copy (``_embed_round``),
+        everything else is ``_prepare``'s, a request at a time. A request
+        whose preparation raises is dropped alone; a batched call that raises
+        leaves every request to embed its own ids."""
+        with self._phase("prep", requests=len(reqs)):
+            rows: list = [None] * len(reqs)
+            if reqs[0].frames is None:
+                try:
+                    rows = self._embed_round(reqs)
+                except Exception:
+                    logger.exception(
+                        "embedding %d requests in one call failed; one at a time", len(reqs)
+                    )
+            preps = (self._safe_prepare(req, row) for req, row in zip(reqs, rows))
+            return [p for p in preps if p is not None]
+
+    def _text_ids(self, req: CaptionRequest) -> list[int]:
+        """The ids a text request's preparation embeds: its prompt where the
+        prefix comes from the cache, prefix and prompt where it does not; of
+        an over-long one the tail that ``_prepare`` keeps."""
+        ids = req.prompt_ids if self._shares_prefix(req, 0) else [*req.prefix_ids, *req.prompt_ids]
+        return ids[-(self._max_len - req.sampling.max_new_tokens - 1):]
+
+    def _embed_round(self, reqs: list[CaptionRequest]) -> "list[np.ndarray | None]":
+        """The float32 host embeddings of each text request's ``_text_ids``,
+        from ONE call of the embedding program and ONE device-to-host copy:
+        the ids end to end, padded with a valid id to a length ``setup()`` has
+        compiled (``_embed_bucket``). A token's lookup does not depend on its
+        neighbours, so each request's rows are what a call of its own gives.
+        None for a request with nothing to embed (``_prepare`` refuses it)."""
+        ids = [self._text_ids(r) for r in reqs]
+        total = sum(map(len, ids))
+        if not total:
+            return [None] * len(reqs)
+        flat = np.zeros((1, self._embed_bucket(total)), np.int32)
+        flat[0, :total] = np.concatenate([x for x in ids if x])
+        host = np.asarray(self._embed_tokens(self.params, flat))[0]
+        ends = np.cumsum([len(x) for x in ids])
+        return [
+            host[end - len(x) : end].astype(np.float32) if x else None
+            for x, end in zip(ids, ends)
+        ]
+
+    @staticmethod
+    def _embed_bucket(n: int) -> int:
+        """The padded length of a round's embedding call over ``n`` ids: a power
+        of two from 128 up, past 4,096 the next multiple of it (a padded row is
+        copied to the host like a live one, and a prompt of 9k ids would pad to
+        16k)."""
+        step = _EMBED_BUCKET_STEP
+        return max(_EMBED_BUCKET_MIN, next_pow2(n)) if n <= step else -(-n // step) * step
+
+    def _embed_buckets(self) -> list[int]:
+        """Every length ``_embed_bucket`` can name for a round, which embeds at
+        most the longest lane's length."""
+        top = self._embed_bucket(self._max_len)
+        lengths, n = [], _EMBED_BUCKET_MIN
+        while n <= top:
+            lengths.append(n)
+            n = 2 * n if n < _EMBED_BUCKET_STEP else n + _EMBED_BUCKET_STEP
+        return lengths
 
     def _should_linger(self) -> bool:
         """True while admission should hold for the in-flight burst's prep:
@@ -2161,7 +2267,7 @@ class CaptionEngine:
         if not self._ready or any(l.slots or l.pending for l in self.lanes):
             self._linger_until = None
             return False
-        incoming = len(self.waiting) + (1 if self._prep_inflight is not None else 0)
+        incoming = len(self.waiting) + len(self._prep_inflight)
         free = sum(l.n_slots for l in self.lanes)
         if not incoming or len(self._ready) >= free:
             self._linger_until = None
@@ -2181,8 +2287,7 @@ class CaptionEngine:
         owners = set(inflight)
         owners.update(r.owner for r in self.waiting)
         owners.update(p.request.owner for p in self._ready)
-        if self._prep_inflight is not None:
-            owners.add(self._prep_inflight.owner)
+        owners.update(r.owner for r in self._prep_inflight)
         total = sum(l.n_slots for l in self.lanes)
         if len(owners) <= 1:
             return total
@@ -2221,7 +2326,8 @@ class CaptionEngine:
                 owner, idx = pick
                 self._owner_last_prep[owner] = self._prep_seq
                 self._prep_seq += 1
-                prep = self._safe_prepare(self.waiting.pop(idx))
+                with self._phase("prep", requests=1):
+                    prep = self._safe_prepare(self.waiting.pop(idx))
                 if prep is not None:
                     return prep
         return None
@@ -2487,8 +2593,26 @@ class CaptionEngine:
                             )
                             self._release_claim(lane, item[0])
 
-    def _prepare(self, req: CaptionRequest, allow_prefix: bool = True) -> _Prepared:
-        """Vision encode + token embed for one request.
+    def _shares_prefix(self, req: CaptionRequest, n_vis: int, allow_prefix: bool = True) -> bool:
+        """Whether ``req``'s text prefix comes from the shared-prefix cache,
+        so that only its suffix (``n_vis`` vision tokens + prompt) is embedded."""
+        n_pre = len(req.prefix_ids)
+        return (
+            allow_prefix
+            and self.enable_prefix_cache
+            and req.share_prefix
+            and n_pre >= self.min_prefix_len
+            and n_vis + len(req.prompt_ids) > 0  # suffix must be non-empty
+            # tail-keep truncation cuts into the prefix
+            and n_pre + n_vis + len(req.prompt_ids) <= self._max_len - req.sampling.max_new_tokens - 1
+        )
+
+    def _prepare(
+        self, req: CaptionRequest, allow_prefix: bool = True, text_embeds: np.ndarray | None = None
+    ) -> _Prepared:
+        """Vision encode + token embed for one request. ``text_embeds``: a
+        text request's ``_text_ids`` embedded already, float32 on the host
+        (a prep round's one call, ``_embed_round``).
 
         When the request's text prefix is shareable (``share_prefix``, long
         enough, cache enabled, no truncation needed), only the SUFFIX
@@ -2541,24 +2665,20 @@ class CaptionEngine:
                 )
         n_vis = 0 if vis_embeds is None else int(vis_embeds.shape[0])
         total = n_pre + n_vis + len(req.prompt_ids)
-        use_prefix = (
-            allow_prefix
-            and self.enable_prefix_cache
-            and req.share_prefix
-            and n_pre >= self.min_prefix_len
-            and n_vis + len(req.prompt_ids) > 0  # suffix must be non-empty
-            and total <= budget  # tail-keep truncation cuts into the prefix
-        )
-        parts = []
-        if n_pre and not use_prefix:
-            pre = jnp.asarray(req.prefix_ids, jnp.int32)
-            parts.append(self._embed_tokens(self.params, pre[None])[0])
-        if vis_embeds is not None:
-            parts.append(vis_embeds)
-        if req.prompt_ids:
-            ids = jnp.asarray(req.prompt_ids, jnp.int32)
-            parts.append(self._embed_tokens(self.params, ids[None])[0])
-        embeds = jnp.concatenate(parts, axis=0)
+        use_prefix = self._shares_prefix(req, n_vis, allow_prefix)
+        if text_embeds is not None:
+            embeds = text_embeds
+        else:
+            parts = []
+            if n_pre and not use_prefix:
+                pre = jnp.asarray(req.prefix_ids, jnp.int32)
+                parts.append(self._embed_tokens(self.params, pre[None])[0])
+            if vis_embeds is not None:
+                parts.append(vis_embeds)
+            if req.prompt_ids:
+                ids = jnp.asarray(req.prompt_ids, jnp.int32)
+                parts.append(self._embed_tokens(self.params, ids[None])[0])
+            embeds = jnp.concatenate(parts, axis=0)
         if self.cfg.mrope_section is not None:
             if grid_merged is None and n_vis:
                 # vit-variant vision tokens: treat as a 1 x 1 x n_vis row

@@ -6,8 +6,10 @@ No profiler trace is started in this process (PERF.md §6: a later
 pyarrow thread dies with SIGSEGV); the spans are read off a recorder put in
 `jax.profiler.TraceAnnotation`'s place."""
 
+import dataclasses
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -47,7 +49,7 @@ COUNTS = {
     "prefill_dispatch_tokens", "prefill_dispatch_room", "prefill_sample_first",
     "decode_dispatch_rows", "decode_dispatch_live", "decode_dispatch_ahead",
     "decode_wait_fresh", "decode_wait_ready", "decode_sample_tokens",
-    "admit_held", "admit_guests",
+    "admit_held", "admit_guests", "prep_n", "prep_requests",
 }
 ALL_KEYS = SECONDS | EXPOSED | COUNTS
 KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]
@@ -721,4 +723,343 @@ class TestSpans:
             assert step_side == pytest.approx(ph["step_s"], abs=1e-5)
             assert ph["prep_s"] >= ph["vision_encode_s"] > 0
         finally:
+            eng.shutdown()
+
+
+# -- a round of the prep thread ------------------------------------------------
+
+
+def _text(rid, n=12, prefix="", max_new=4, owner=None, **kw):
+    """A text request of ``n`` prompt ids, its own by ``rid``."""
+    seed = sum(map(ord, rid))
+    return CaptionRequest(
+        request_id=rid,
+        prefix_ids=ByteTokenizer().encode(prefix) if prefix else [],
+        prompt_ids=[3 + (seed + 7 * i) % 200 for i in range(n)],
+        sampling=SamplingConfig(max_new_tokens=max_new),
+        owner=owner,
+        **kw,
+    )
+
+
+def _output_ids(eng) -> dict:
+    """Every finished request's output ids, by request id, as they finish."""
+    ids, maybe_finish = {}, eng._maybe_finish
+
+    def finishing(lane, slot_idx, slot):
+        ids[slot.request.request_id] = list(slot.generated)
+        return maybe_finish(lane, slot_idx, slot)
+
+    eng._maybe_finish = finishing
+    return ids
+
+
+def _rounds(eng) -> list:
+    """The request ids of every round the engine prepares from here on."""
+    rounds, prepare_round = [], eng._prepare_round
+
+    def spy(reqs):
+        rounds.append([r.request_id for r in reqs])
+        return prepare_round(reqs)
+
+    eng._prepare_round = spy
+    return rounds
+
+
+def _queued(reqs, cfg=VLM_TINY_TEST, spy=True, **kw):
+    """An engine with a prep thread that finds ``reqs`` waiting when ``setup()``
+    starts it: its first round takes what it may of ALL of them."""
+    eng = CaptionEngine(cfg, async_prep=True, **{"max_batch": 8, **kw})
+    for r in reqs:
+        eng.add_request(r, owner=r.owner or "me")
+    rounds = _rounds(eng) if spy else None
+    eng.setup()
+    return eng, rounds
+
+
+PREFIX = "system: you rewrite captions, tersely. user: "
+
+
+class TestPrepRounds:
+    """The prep thread takes every waiting text request it may a round: one
+    hold of the lock to take them, one embedding call and one read for their
+    text, one hold to hand them on. What a request is prepared to is what a
+    round of its own gives."""
+
+    @pytest.mark.parametrize("prefix", ["", PREFIX], ids=["no_prefix", "cached_prefix"])
+    @pytest.mark.parametrize("flavor", ["plain_rope", "m_rope"])
+    def test_queued_text_is_one_round_and_decodes_as_one_at_a_time(self, flavor, prefix):
+        from cosmos_curate_tpu.models.vlm.model import VLM_QWEN2VL_TINY_TEST
+
+        cfg = {"plain_rope": VLM_TINY_TEST, "m_rope": VLM_QWEN2VL_TINY_TEST}[flavor]
+        assert (cfg.mrope_section is not None) == (flavor == "m_rope")
+        reqs = lambda: [_text(f"r{i}", n=5 + 3 * i, prefix=prefix, max_new=6) for i in range(5)]
+        one_at_a_time = CaptionEngine(cfg, max_batch=8)  # sync prep: a request a round, its own call
+        one_at_a_time.setup()
+        want = _output_ids(one_at_a_time)
+        for r in reqs():
+            one_at_a_time.add_request(r, owner="me")
+        one_at_a_time.run_until_complete("me")
+        assert one_at_a_time.phase_seconds["prep_n"] == one_at_a_time.phase_seconds["prep_requests"] == 5
+
+        eng, rounds = _queued(reqs(), cfg)
+        try:
+            got = _output_ids(eng)
+            done = eng.run_until_complete("me")
+            ph = eng.phase_seconds
+            assert rounds == [[f"r{i}" for i in range(5)]]
+            assert (ph["prep_n"], ph["prep_requests"]) == (1, 5)
+            assert sorted(r.request_id for r in done) == sorted(want)
+            assert got == want and all(len(v) == 6 for v in got.values())
+            if prefix:
+                assert (eng.prefix_cache_hits, eng.prefix_cache_misses) == (4, 1)
+        finally:
+            eng.shutdown()
+
+    @pytest.mark.parametrize("prefix", ["", PREFIX], ids=["no_prefix", "cached_prefix"])
+    @pytest.mark.parametrize("flavor", ["plain_rope", "m_rope"])
+    def test_a_round_prepares_bit_for_bit_what_a_request_alone_is_prepared_to(self, flavor, prefix):
+        from cosmos_curate_tpu.models.vlm.model import VLM_QWEN2VL_TINY_TEST
+
+        cfg = {"plain_rope": VLM_TINY_TEST, "m_rope": VLM_QWEN2VL_TINY_TEST}[flavor]
+        eng = CaptionEngine(cfg, max_batch=4)
+        eng.setup()
+        assert eng._embed_tokens._cache_size() == 0  # inline prep: no round, no bucket warmed
+        # lengths on both sides of a bucket's edge, and one over the lane's budget (tail kept)
+        over = eng._max_len + 9
+        reqs = [_text(f"q{i}", n=n, prefix=prefix) for i, n in enumerate((1, 40, 70, 9, over))]
+        alone = [eng._prepare(r) for r in reqs]
+        together = eng._prepare_round(reqs[:4]) + eng._prepare_round(reqs[4:])
+        for a, b in zip(alone, together, strict=True):
+            assert a.request is b.request and (a.t_suffix, a.next_rope, a.base, a.prefix_key) == (
+                b.t_suffix, b.next_rope, b.base, b.prefix_key
+            )
+            assert a.embeds.dtype == b.embeds.dtype == np.float32 and a.ds is None and b.ds is None
+            assert np.array_equal(a.embeds, b.embeds) and np.array_equal(a.rope, b.rope)
+        # each request's rows are a copy of their own: none keeps the round's buffer alive
+        assert all(rows.flags.owndata for rows in eng._embed_round(reqs[:4]))
+        assert (alone[0].base > 0) == bool(prefix) and alone[4].t_suffix == eng._max_len - 4 - 1
+
+    def test_a_request_with_frames_is_a_round_of_its_own(self):
+        reqs = [_text("t0"), _text("t1"), _req("v0", frames=True, max_new=3), _text("t2"),
+                _req("v1", frames=True, max_new=3), _req("v2", frames=True, max_new=3)]
+        eng, rounds = _queued(reqs)
+        try:
+            done = eng.run_until_complete("me")
+            assert rounds == [["t0", "t1"], ["v0"], ["t2"], ["v1"], ["v2"]]
+            ph = eng.phase_seconds
+            assert (ph["prep_n"], ph["prep_requests"]) == (5, 6) and len(done) == 6
+            assert eng.vision_encodes == 3
+        finally:
+            eng.shutdown()
+
+    def test_the_longest_lane_is_a_rounds_token_budget(self):
+        # 64 positions: three prompts of 20 ids fit a round, a fourth does not
+        reqs = [_text(f"b{i}", n=20) for i in range(7)] + [_text("b7", n=59)]
+        eng, rounds = _queued(reqs, kv_lanes=((64, 8),))
+        try:
+            assert eng._max_len == 64 and len(eng.run_until_complete("me")) == 8
+            assert rounds == [["b0", "b1", "b2"], ["b3", "b4", "b5"], ["b6"], ["b7"]]
+        finally:
+            eng.shutdown()
+
+    def test_a_long_prompt_is_a_round_of_its_own(self):
+        """Past 4,096 ids together a round takes no second request, whatever the lane."""
+        cfg = dataclasses.replace(VLM_TINY_TEST, max_seq=16384)
+        eng = CaptionEngine(cfg, max_batch=8, kv_lanes=((16384, 8),), async_prep=True)  # never set up
+        lengths = {"a": 448, "b": 3520, "c": 1984, "d": 11200, "e": 448, "f": 5056, "g": 128}
+        eng.waiting.extend(_text(rid, n=n, owner="me") for rid, n in lengths.items())
+        rounds = []
+        with eng._work_cv:
+            while eng.waiting:
+                rounds.append([r.request_id for r in eng._take_round()])
+        assert rounds == [["a", "b"], ["c"], ["d"], ["e"], ["f"], ["g"]]
+
+    def test_room_in_the_ready_queue_bounds_a_round(self):
+        eng, rounds = _queued([_text(f"c{i}") for i in range(7)], max_batch=2)
+        try:
+            assert eng._prep_ahead_limit() == 4
+            deadline = time.monotonic() + 30
+            while len(eng._ready) < 4 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            time.sleep(0.05)  # the thread waits: nothing more is taken while nothing is admitted
+            with eng._work_cv:
+                assert [p.request.request_id for p in eng._ready] == ["c0", "c1", "c2", "c3"]
+                assert rounds == [["c0", "c1", "c2", "c3"]] and len(eng.waiting) == 3
+            assert len(eng.run_until_complete("me")) == 7
+            assert sum(rounds, []) == [f"c{i}" for i in range(7)]  # FIFO across rounds
+        finally:
+            eng.shutdown()
+
+    def test_owners_rotate_inside_a_round(self):
+        reqs = [_text(f"a{i}", owner="A") for i in range(3)] + [_text(f"b{i}", owner="B") for i in range(2)]
+        eng, rounds = _queued(reqs)
+        try:
+            deadline = time.monotonic() + 30
+            while len(eng._ready) < 5 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            with eng._work_cv:
+                assert rounds == [["a0", "b0", "a1", "b1", "a2"]]
+                assert [p.request.request_id for p in eng._ready] == rounds[0]
+            assert len(eng.run_until_complete("A")) == 3 and len(eng.run_until_complete("B")) == 2
+        finally:
+            eng.shutdown()
+
+    def test_every_request_of_a_round_in_flight_is_counted(self):
+        eng, _ = _queued([], spy=False)
+        embed, entered, release = eng._embed_tokens, threading.Event(), threading.Event()
+
+        def held_up(params, ids):
+            entered.set()
+            assert release.wait(30)
+            return embed(params, ids)
+
+        eng._embed_tokens = held_up
+        try:
+            with eng._work_cv:  # the thread sees all five at once
+                for i in range(5):
+                    eng.add_request(_text(f"f{i}", owner="A" if i < 3 else "B"))
+            assert entered.wait(30)
+            with eng._work_cv:  # mid-round: out of `waiting`, not yet ready
+                assert not eng.waiting and not eng._ready and not eng.slots
+                assert [r.request_id for r in eng._prep_requests()] == ["f0", "f3", "f1", "f4", "f2"]
+                # the drivers' closed loop sizes itself by this sum (perfbench: `in_engine`)
+                assert len(eng.waiting) + len(eng._prep_requests()) + len(eng.slots) + len(eng.pending) == 5
+                assert eng.has_work() and eng.has_work("A") and eng.has_work("B") and not eng.has_work("C")
+                owners = eng.owner_stats()
+                assert (owners["A"]["waiting"], owners["B"]["waiting"]) == (3, 2)
+                assert eng._owner_cap({}) == 4  # two owners share the eight rows
+            release.set()
+            assert len(eng.run_until_complete("A")) == 3 and len(eng.run_until_complete("B")) == 2
+            assert not eng.has_work() and eng._prep_inflight == []
+        finally:
+            release.set()
+            eng.shutdown()
+
+    def test_a_request_whose_preparation_raises_is_dropped_alone(self):
+        reqs = [_text("g0"), _text("empty", n=0), _text("g1"), _text("boom"), _text("g2")]
+        eng, rounds = _queued([], spy=True)
+        prepare = eng._prepare
+
+        def failing(req, **kw):
+            if req.request_id == "boom":
+                raise RuntimeError("no preparation for this one")
+            return prepare(req, **kw)
+
+        eng._prepare = failing
+        try:
+            with eng._work_cv:
+                for r in reqs:
+                    eng.add_request(r, owner="me")
+            done = eng.run_until_complete("me")
+            assert sorted(r.request_id for r in done) == ["g0", "g1", "g2"]
+            assert rounds == [["g0", "empty", "g1", "boom", "g2"]] and not eng.has_work()
+            ph = eng.phase_seconds
+            assert (ph["prep_n"], ph["prep_requests"]) == (1, 5)  # what the round carried
+        finally:
+            eng.shutdown()
+
+    def test_a_failed_batched_call_leaves_each_request_its_own(self):
+        reqs = lambda: [_text(f"h{i}", n=6 + i) for i in range(4)]
+        eng, rounds = _queued(reqs())
+        try:
+            want = _output_ids(eng)
+            eng.run_until_complete("me")
+            want = dict(want)
+            assert rounds == [["h0", "h1", "h2", "h3"]]
+
+            def broken(reqs):
+                raise RuntimeError("the batched call failed")
+
+            eng._embed_round = broken
+            got = _output_ids(eng)
+            with eng._work_cv:
+                for r in reqs():
+                    eng.add_request(r, owner="me")
+            assert len(eng.run_until_complete("me")) == 4
+            assert rounds[1:] == [["h0", "h1", "h2", "h3"]]
+            assert {k: got[k] for k in want} == want
+        finally:
+            eng.shutdown()
+
+    def test_no_embedding_shape_is_compiled_after_setup(self):
+        cfg = dataclasses.replace(VLM_TINY_TEST, max_seq=512)
+        eng, rounds = _queued([], cfg, kv_lanes=((64, 4), (512, 4)))
+        try:
+            buckets = eng._embed_buckets()
+            assert buckets == [128, 256, 512] and eng._embed_tokens._cache_size() == 3
+            for burst in ((1, 2, 3), (100, 27), (300,), (129, 130, 131), (500,), (64, 64), (128,)):
+                with eng._work_cv:
+                    for n in burst:
+                        eng.add_request(_text(f"s{n}", n=n, max_new=2), owner="me")
+                assert len(eng.run_until_complete("me")) == len(burst)
+            assert len(rounds) >= 7 and eng._embed_tokens._cache_size() == 3
+        finally:
+            eng.shutdown()
+
+    @pytest.mark.parametrize(
+        "longest, want",
+        [
+            (64, [128]),
+            (1024, [128, 256, 512, 1024]),
+            (4096, [128, 256, 512, 1024, 2048, 4096]),
+            (5000, [128, 256, 512, 1024, 2048, 4096, 8192]),
+            (16384, [128, 256, 512, 1024, 2048, 4096, 8192, 12288, 16384]),
+            (32768, [128, 256, 512, 1024, 2048, 4096, 8192, 12288, 16384, 20480, 24576, 28672, 32768]),
+        ],
+    )
+    def test_the_buckets_cover_every_round(self, longest, want):
+        cfg = dataclasses.replace(VLM_TINY_TEST, max_seq=longest)
+        eng = CaptionEngine(cfg, max_batch=2, kv_lanes=((longest, 2),))
+        assert eng._embed_buckets() == want
+        for n in (n for n in (1, 127, 128, 129, longest // 2 + 1, longest - 1, longest) if n <= longest):
+            bucket = eng._embed_bucket(n)
+            assert bucket in want and n <= bucket
+            assert bucket < max(2 * n, 129) and bucket - n < max(n, 128, 4096)
+
+    def test_a_sync_engine_counts_a_round_a_request(self, engine):
+        engine.reset_stats()
+        for i in range(3):
+            engine.add_request(_text(f"y{i}"))
+        engine.run_until_complete()
+        ph = engine.phase_seconds
+        assert (ph["prep_n"], ph["prep_requests"]) == (3, 3)
+
+    def test_no_request_is_lost_or_counted_twice_while_rounds_race_submitters(self):
+        """Submitters, the prep thread and the stepping thread under a short
+        switch interval: at every look, under the engine's lock, what was
+        submitted is waiting, in a round, ready, in a row, or done."""
+        eng, rounds = _queued([], max_batch=4)
+        submitted = [0]  # moves under the engine's lock, with the queue
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+
+        def submit(name):
+            for i in range(12):
+                with eng._work_cv:
+                    eng.add_request(_text(f"{name}{i}", n=3 + i, max_new=2, owner=name))
+                    submitted[0] += 1
+                time.sleep(0.001 * (i % 3))
+
+        threads = [threading.Thread(target=submit, args=(n,)) for n in "abcdef"]
+        try:
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 120
+            while (any(t.is_alive() for t in threads) or eng.has_work()) and time.monotonic() < deadline:
+                with eng._work_cv:
+                    inside = len(eng.waiting) + len(eng._prep_requests()) + len(eng.slots) + len(eng.pending)
+                    assert inside + len(eng.completed) == submitted[0]
+                    if eng._ready or any(l.slots or l.pending or l.inflight for l in eng.lanes):
+                        eng.step()
+                time.sleep(0)
+            for t in threads:
+                t.join(timeout=10)
+                assert not t.is_alive()
+            assert not eng.has_work() and len(eng.completed) == submitted[0] == 72
+            assert sorted(sum(rounds, [])) == sorted(f"{n}{i}" for n in "abcdef" for i in range(12))
+            ph = eng.phase_seconds
+            assert ph["prep_requests"] == 72 and 1 <= ph["prep_n"] <= 72
+        finally:
+            sys.setswitchinterval(old)
             eng.shutdown()
