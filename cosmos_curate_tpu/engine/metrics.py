@@ -100,6 +100,20 @@ class EngineMetrics:
             "caption_phase_seconds_total",
             "caption engine seconds by phase", labels + ["phase"],
         )
+        # A request's life inside the engine (CaptionEngine._stamp): seconds
+        # requests spent in each interval (queue, prep, row_wait, prefill,
+        # decode) and requests past each boundary (taken, ready, admitted,
+        # first, finished; dropped). rate(seconds) / rate(requests) of an
+        # interval and the boundary that closes it is a request's mean wait.
+        self.caption_request_seconds = Counter(
+            "caption_request_seconds_total",
+            "seconds caption requests spent in each interval of their life",
+            labels + ["interval"],
+        )
+        self.caption_requests = Counter(
+            "caption_requests_total",
+            "caption requests past each boundary of their life", labels + ["boundary"],
+        )
         self.caption_prefix_hits = Counter(
             "caption_prefix_cache_hits_total", "shared-prefix KV cache hits", labels
         )
@@ -394,6 +408,14 @@ class EngineMetrics:
         for phase in ("prep_s", "vision_encode_s", "prefill_s", "decode_s", "idle_s"):
             self.caption_phase_total.labels(stage, phase[:-2]).inc(
                 max(0.0, float(phases.get(phase, 0.0)))
+            )
+        for interval in ("queue", "prep", "row_wait", "prefill", "decode"):
+            self.caption_request_seconds.labels(stage, interval).inc(
+                max(0.0, float(phases.get(f"request_{interval}_s", 0.0)))
+            )
+        for boundary in ("taken", "ready", "admitted", "first", "finished", "dropped"):
+            self.caption_requests.labels(stage, boundary).inc(
+                max(0, int(phases.get(f"request_{boundary}_n", 0)))
             )
         self.caption_prefix_hits.labels(stage).inc(
             max(0, int(phases.get("prefix_cache_hits", 0)))

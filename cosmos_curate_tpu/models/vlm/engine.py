@@ -172,6 +172,9 @@ class CaptionRequest:
     # (the engine fills this after the first encode; a refinement follow-up
     # carrying the identical frames array inherits it automatically).
     vision_features: Any = field(default=None, repr=False)
+    # the stamps of this request's life inside the engine (CaptionEngine._stamp),
+    # handed out as CaptionResult.timing; the engine's own, no argument
+    _life: dict | None = field(default=None, init=False, repr=False, compare=False)
 
 
 @dataclass
@@ -208,6 +211,11 @@ class CaptionResult:
     num_output_tokens: int
     metadata: dict[str, Any] = field(default_factory=dict)
     owner: Any = None
+    # the request's life inside the engine (CaptionEngine._stamp): the six
+    # boundaries (`arrived`, then _LIFE_INTERVALS') in monotonic seconds, `lane` (the length of the lane
+    # that took it) and `<boundary>_step` for the three that happen inside a
+    # step(), the step's ordinal as its `engine.step` span carries it
+    timing: dict[str, Any] = field(default_factory=dict)
 
 
 @dataclass
@@ -418,6 +426,21 @@ _PHASE_COUNTS = {
 _PHASE_EXPOSED = ("step", "prefill_dispatch", "decode_dispatch")
 _HANDS_OVER = ("prefill_dispatch", "decode_dispatch")
 _PROVES = ("prefill_wait", "decode_wait")
+# A request's life: the boundaries CaptionEngine._stamp stamps after `arrived`, in
+# order, each with the boundary that OPENS the interval it closes and what
+# phase_seconds carries of that interval (its seconds summed, and how many it
+# summed): what waited for what is in _stamp's docstring.
+_LIFE_INTERVALS = {
+    "taken": ("arrived", "request_queue_s", "request_taken_n"),
+    "ready": ("taken", "request_prep_s", "request_ready_n"),
+    "admitted": ("ready", "request_row_wait_s", "request_admitted_n"),
+    "first_token": ("admitted", "request_prefill_s", "request_first_n"),
+    "finished": ("first_token", "request_decode_s", "request_finished_n"),
+}
+# ...and the two counts beside them: requests whose preparation raised (they end
+# where they would have become ready) and the finished requests' tokens after
+# the first (what `request_decode_s` is divided by for the gap between tokens)
+_LIFE_COUNTS = ("request_dropped_n", "request_decode_gaps")
 # The padded lengths of a prep round's one embedding call (_embed_bucket)
 _EMBED_BUCKET_MIN = 128
 _EMBED_BUCKET_STEP = 4096
@@ -812,6 +835,11 @@ class CaptionEngine:
         # counts the threads with a program handed over and not proven: while
         # it is 0 the device holds nothing of ours, and `_empty_clock` runs.
         self._programs = itertools.count(1)
+        # ...and a step's ordinal (state like the programs' numbers: reset_stats
+        # leaves it): what the `engine.step` span carries and what a request's
+        # life keeps of the steps that admitted, started and ended it
+        self._steps = itertools.count(1)
+        self._step_ordinal = 0  # guarded-by: _lock (the step that holds it)
         self._queue_busy = 0
         self._empty_since: float | None = time.monotonic()
         self._empty_total = 0.0
@@ -831,16 +859,9 @@ class CaptionEngine:
         self._prefix_misses = 0
         self._prefix_evictions = 0
         self._prefix_tokens_saved = 0
-        # paged-KV accounting (all under _stats_lock): cumulative block
-        # reservations per admitted request (kv_bytes_reserved_per_request),
-        # the worst-case tokens the slot-row engine would have
-        # reserved for the same admissions, shared-prefix block references
-        # handed out (the zero-copy successor of insert_prefix dispatches),
-        # and copy-on-write tail duplications
-        self._requests_admitted = 0
-        self._kv_blocks_reserved = 0
-        self._kv_private_blocks = 0
-        self._kv_worstcase_tokens = 0
+        # paged-KV accounting (all under _stats_lock): shared-prefix block
+        # references handed out (the zero-copy successor of insert_prefix
+        # dispatches), copy-on-write tail duplications, the pool's high-water mark
         self._prefix_block_refs = 0
         self._kv_cow_copies = 0
         self._kv_blocks_used_peak = 0
@@ -1492,6 +1513,7 @@ class CaptionEngine:
         if request.owner is None:
             request.owner = owner if owner is not None else threading.get_ident()
         with self._work_cv:
+            self._stamp(request, "arrived")
             self.waiting.append(request)
             # only a BUILT engine may prep (the thread calls the jitted
             # encoders setup() creates); requests queued before setup()
@@ -1567,6 +1589,9 @@ class CaptionEngine:
         self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
         self._phase_exposed_s = dict.fromkeys(_PHASE_EXPOSED, 0.0)
         self._phase_n = {k: dict.fromkeys(keys, 0) for k, keys in _PHASE_COUNTS.items()}
+        self._life_account = dict.fromkeys(_LIFE_COUNTS, 0)
+        for _opens, seconds, n in _LIFE_INTERVALS.values():
+            self._life_account.update({seconds: 0.0, n: 0})
 
     def _phase_thread(self) -> threading.local:
         """The calling thread's side of the account: ``stack``, the seconds
@@ -1637,6 +1662,52 @@ class CaptionEngine:
         ends, results). ``decode_dispatch`` + ``decode_wait`` is still the
         wall time decode costs a step."""
         return _Phase(self, name, counts)
+
+    def _stamp(self, req: CaptionRequest, boundary: str, lane: _Lane | None = None, gaps: int = 0) -> None:
+        """Stamp ``boundary`` of ``req``'s life on the host's monotonic clock,
+        once, and book the interval it closes into the account ``phase_seconds``
+        hands out (the keys of ``_LIFE_INTERVALS``), where the work happens:
+
+        ``arrived`` (``add_request``, and a follow-up's queueing in
+        ``_maybe_finish``: a new record, so a refinement pass is a life of its
+        own) -> ``taken`` (popped from ``waiting``: by ``_take_round`` for the
+        prep thread, by ``_next_prepared`` inline): it waited for the prep
+        thread, or inline for ``_admit``'s turn -> ``ready`` (the round handed
+        to ``_ready``; inline, ``_safe_prepare`` returned): its round, with the
+        round-mates' embedding, the device round trip and two holds of the
+        lock; a request whose preparation raised ends here as ``dropped``
+        (``request_dropped_n``, no seconds) -> ``admitted`` (``_admit``, once
+        ``_claim_kv`` gave it a row and its blocks, not where it left
+        ``_ready``: a head pushed back is still waiting): it waited for a row
+        of its lane and for K/V blocks -> ``first_token`` (``_start_slot``): its
+        prompt's chunks, beside everyone's decode -> ``finished``
+        (``_maybe_finish``): its ``gaps`` tokens after the first.
+
+        The record is the dict the request carries (``CaptionRequest._life``)
+        and its result hands out (``CaptionResult.timing``): the stamps, ``lane``
+        (its length) and, for the boundaries inside a step, ``<boundary>_step``.
+        A boundary already stamped, or a request that never arrived through
+        ``add_request``, books nothing."""
+        now = time.monotonic()
+        if boundary == "arrived":
+            req._life = {"arrived": now}
+            return
+        life = req._life
+        if life is None or boundary in life:
+            return
+        life[boundary] = now
+        if lane is not None:
+            life["lane"] = lane.length
+            life[boundary + "_step"] = self._step_ordinal
+        with self._stats_lock:
+            account = self._life_account
+            if boundary == "dropped":
+                account["request_dropped_n"] += 1
+                return
+            opens, seconds, n = _LIFE_INTERVALS[boundary]
+            account[seconds] += now - life[opens]
+            account[n] += 1
+            account["request_decode_gaps"] += gaps
 
     @property
     def _decode_time(self) -> float:
@@ -1820,28 +1891,6 @@ class CaptionEngine:
             }
 
     @property
-    def requests_admitted(self) -> int:
-        return self._requests_admitted
-
-    @property
-    def kv_bytes_reserved_per_request(self) -> float:
-        """Mean KV bytes reserved per admitted request (shared references
-        counted at full block size — still strictly below the old
-        worst-case row whenever prompt + max_new undershoots the lane)."""
-        if not self._requests_admitted:
-            return 0.0
-        return self._kv_blocks_reserved * self.kv_block_bytes / self._requests_admitted
-
-    @property
-    def kv_bytes_worstcase_per_request(self) -> float:
-        """What the slot-row engine reserved for the same admissions: each
-        routed lane's FULL row, regardless of actual request length."""
-        if not self._requests_admitted:
-            return 0.0
-        token_bytes = self.kv_block_bytes / self.block_size
-        return self._kv_worstcase_tokens * token_bytes / self._requests_admitted
-
-    @property
     def interleaved_decode_steps(self) -> int:
         """Steps whose active slots spanned 2+ owners — the cross-job
         continuous-batching signal (two pipelines decoding in ONE batch)."""
@@ -1903,7 +1952,14 @@ class CaptionEngine:
         ``*_dispatch_exposed_s``, the part of it that may not have been idle.
         COUNTS (integers), ``<phase>_<key>`` for each key of ``_PHASE_COUNTS``:
         ``step_n`` steps, ``decode_dispatch_n`` + ``prefill_dispatch_n``
-        programs, and what their sites counted of them."""
+        programs, and what their sites counted of them.
+
+        REQUESTS (``_stamp``): ``request_queue_s`` / ``_prep_s`` /
+        ``_row_wait_s`` / ``_prefill_s`` / ``_decode_s``, the five intervals of
+        a request's life summed over the requests that CLOSED one, each beside
+        the count it summed (``request_taken_n``, ``_ready_n``, ``_admitted_n``,
+        ``_first_n``, ``_finished_n``); ``request_dropped_n`` and
+        ``request_decode_gaps`` (the finished requests' tokens after the first)."""
         with self._stats_lock:
             out = {f"{k}_s": v for k, v in self._phase_s.items() if k not in _PHASE_ROOTS}
             for root in _PHASE_ROOTS:
@@ -1914,6 +1970,7 @@ class CaptionEngine:
             out.update({f"{k}_exposed_s": v for k, v in self._phase_exposed_s.items()})
             for k, account in self._phase_n.items():
                 out.update({f"{k}_{key}": v for key, v in account.items()})
+            out.update(self._life_account)
         return out
 
     def reset_stats(self) -> None:
@@ -1928,10 +1985,6 @@ class CaptionEngine:
             self._prefix_misses = 0
             self._prefix_evictions = 0
             self._prefix_tokens_saved = 0
-            self._requests_admitted = 0
-            self._kv_blocks_reserved = 0
-            self._kv_private_blocks = 0
-            self._kv_worstcase_tokens = 0
             self._prefix_block_refs = 0
             self._kv_cow_copies = 0
             self._decode_rows_discarded = 0
@@ -2026,10 +2079,15 @@ class CaptionEngine:
         an idle engine prefills at full speed."""
         if not self._built:
             raise RuntimeError("call setup() first")
-        with self._phase("step"), contextlib.ExitStack() as waiting:
+        # the ordinal is span metadata (no key of _PHASE_COUNTS): with it a gap of
+        # the device trace under `engine.step` names a step, and the `*_step`
+        # entries of CaptionResult.timing the requests that step moved
+        ordinal = next(self._steps)
+        with self._phase("step", ordinal=ordinal), contextlib.ExitStack() as waiting:
             waiting.enter_context(self._phase("lock_wait"))
             with self._work_cv:
                 waiting.close()  # the lock is ours: lock_wait ends here
+                self._step_ordinal = ordinal
                 with self._phase("admit") as phase:
                     self._admit(phase.counts)
                 # cross-job signal: this step's active slots span 2+ owners
@@ -2087,6 +2145,8 @@ class CaptionEngine:
             with self._work_cv:
                 self._prep_inflight = []
                 self._ready.extend(preps)
+                for prep in preps:
+                    self._stamp(prep.request, "ready")
                 self._work_cv.notify_all()
 
     # holds-lock: _lock
@@ -2113,6 +2173,7 @@ class CaptionEngine:
             if taken and (need is None or need > budget):
                 break  # it opens the next round
             taken.append(self._pop_waiting_fair())
+            self._stamp(taken[-1], "taken")
             if need is None:
                 break
             budget -= need
@@ -2191,6 +2252,7 @@ class CaptionEngine:
             return self._prepare(req, text_embeds=text_embeds)
         except Exception:
             logger.exception("prefill prep failed for %s; dropping", req.request_id)
+            self._stamp(req, "dropped")
             return None
 
     def _prepare_round(self, reqs: list[CaptionRequest]) -> "list[_Prepared]":
@@ -2326,9 +2388,12 @@ class CaptionEngine:
                 owner, idx = pick
                 self._owner_last_prep[owner] = self._prep_seq
                 self._prep_seq += 1
+                req = self.waiting.pop(idx)
+                self._stamp(req, "taken")
                 with self._phase("prep", requests=1):
-                    prep = self._safe_prepare(self.waiting.pop(idx))
+                    prep = self._safe_prepare(req)
                 if prep is not None:
+                    self._stamp(req, "ready")
                     return prep
         return None
 
@@ -2534,6 +2599,7 @@ class CaptionEngine:
                     "KV block claim failed for %s; dropping", req.request_id
                 )
                 continue
+            self._stamp(req, "admitted", lane)
             inflight[req.owner] = inflight.get(req.owner, 0) + 1
             self._owner_last_admit[req.owner] = self._admit_seq
             self._admit_seq += 1
@@ -3050,10 +3116,6 @@ class CaptionEngine:
                 self._release_claim(lane, slot_idx)
                 raise
         with self._stats_lock:
-            self._requests_admitted += 1
-            self._kv_blocks_reserved += view_blocks
-            self._kv_private_blocks += len(private)
-            self._kv_worstcase_tokens += lane.length
             self._prefix_block_refs += len(shared)
             if cow_src is not None:
                 self._kv_cow_copies += 1
@@ -3342,6 +3404,7 @@ class CaptionEngine:
         if req.sampling.stop:
             slot.raw += self.tokenizer.decode_bytes([first])
         lane.slots[slot_idx] = slot
+        self._stamp(req, "first_token", lane)
         self._maybe_finish(lane, slot_idx, slot)
 
     # holds-lock: _lock
@@ -3675,6 +3738,7 @@ class CaptionEngine:
                 done = stop_text is not None
         if not done:
             return
+        self._stamp(req, "finished", lane, gaps=len(slot.generated) - 1)
         del lane.slots[slot_idx]
         self._release_claim(lane, slot_idx)
         out_ids = [t for t in slot.generated if t != self.tokenizer.eos_id]
@@ -3691,6 +3755,7 @@ class CaptionEngine:
             num_output_tokens=len(slot.generated),
             metadata=req.metadata,
             owner=req.owner,
+            timing=req._life or {},
         )
         if req.on_complete is not None:
             follow_up = req.on_complete(text)
@@ -3706,6 +3771,7 @@ class CaptionEngine:
                     # already-encoded vision features to the follow-up so
                     # the tower doesn't run twice per window
                     follow_up.vision_features = req.vision_features
+                self._stamp(follow_up, "arrived")
                 self.waiting.append(follow_up)
                 self._work_cv.notify_all()  # wake the prep thread
                 return  # result superseded by the refinement pass

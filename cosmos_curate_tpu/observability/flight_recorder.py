@@ -658,6 +658,17 @@ def render_report(report: dict) -> str:
                     f"exposed {agg.get('step_exposed_s', 0.0):.2f}s (dispatch's own "
                     f"{agg.get('decode_dispatch_exposed_s', 0.0) + agg.get('prefill_dispatch_exposed_s', 0.0):.2f}s)"
                 )
+            if agg.get("request_first_n"):
+                lines.append(
+                    f"  {'':<40} a request's mean: queue {agg.get('request_queue_ms', 0.0):.1f}ms + "
+                    f"prep {agg.get('request_prep_ms', 0.0):.1f}ms + "
+                    f"row_wait {agg.get('request_row_wait_ms', 0.0):.1f}ms + "
+                    f"prefill {agg.get('request_prefill_ms', 0.0):.1f}ms = first token "
+                    f"{agg.get('request_ttft_ms', 0.0):.1f}ms; between tokens "
+                    f"{agg.get('request_itl_ms', 0.0):.2f}ms "
+                    f"({agg.get('request_finished_n', 0)} finished, "
+                    f"{agg.get('request_dropped_n', 0)} dropped)"
+                )
             if agg.get("kv_blocks_total"):
                 lines.append(
                     f"  {'':<40} kv_blocks {agg.get('kv_blocks_peak', 0)}/"

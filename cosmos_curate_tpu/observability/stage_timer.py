@@ -367,6 +367,10 @@ _CAPTION_PHASE_KEYS = (
     # (CaptionEngine._phase: host work the chip sat idle for), and the part of
     # them inside the dispatch phases, which is how far they can overstate
     "step_exposed_s", "decode_dispatch_exposed_s", "prefill_dispatch_exposed_s",
+    # the five intervals of a request's life inside the engine, summed over the
+    # requests that closed one (CaptionEngine._stamp); their counts are below
+    "request_queue_s", "request_prep_s", "request_row_wait_s", "request_prefill_s",
+    "request_decode_s",
 )
 _CAPTION_COUNT_KEYS = (
     "requests", "prefill_tokens", "prefix_cache_hits", "prefix_cache_misses",
@@ -381,7 +385,19 @@ _CAPTION_COUNT_KEYS = (
     "decode_tokens",
     # the engine's phase account: steps, and the programs it handed the device
     "step_n", "decode_dispatch_n", "prefill_dispatch_n",
+    # requests past each boundary of their life, those whose preparation raised,
+    # and the finished requests' tokens after the first
+    "request_taken_n", "request_ready_n", "request_admitted_n", "request_first_n",
+    "request_finished_n", "request_dropped_n", "request_decode_gaps",
 )
+# a request's mean milliseconds in each interval: the sum over the count that closed it
+_CAPTION_REQUEST_MEANS = {
+    "request_queue_ms": ("request_queue_s", "request_taken_n"),
+    "request_prep_ms": ("request_prep_s", "request_ready_n"),
+    "request_row_wait_ms": ("request_row_wait_s", "request_admitted_n"),
+    "request_prefill_ms": ("request_prefill_s", "request_first_n"),
+    "request_itl_ms": ("request_decode_s", "request_decode_gaps"),
+}
 # absolute occupancy gauges riding each drive record: totals overwrite,
 # peaks take the max across drives
 _CAPTION_GAUGE_KEYS = ("kv_blocks_total", "kv_blocks_used")
@@ -440,7 +456,10 @@ def caption_phase_summaries() -> dict[str, dict]:
     the stage starved the engine between batches. ``owners`` carries the
     per-owner sub-aggregates (cross-job accounting). ``programs_per_step``
     is the programs the engine handed the device (decode + prefill) over
-    its steps: each reads every parameter, whatever rows it carries."""
+    its steps: each reads every parameter, whatever rows it carries.
+    ``request_*_ms`` are a request's mean milliseconds waiting for the prep
+    thread, in its round, for a row, in prefill, and between two of its
+    tokens; ``request_ttft_ms`` (mean time to first token) is the first four."""
     out: dict[str, dict] = {}
     with _CAPTION_LOCK:
         items = {
@@ -449,6 +468,10 @@ def caption_phase_summaries() -> dict[str, dict]:
         }
     for name, agg in items.items():
         wall = agg["wall_s"]
+        means = {
+            k: round(1000.0 * agg[s] / agg[n], 3) if agg[n] else 0.0
+            for k, (s, n) in _CAPTION_REQUEST_MEANS.items()
+        }
         out[name] = {
             **{k: round(agg[k], 4) for k in _CAPTION_PHASE_KEYS},
             **{k: agg[k] for k in _CAPTION_COUNT_KEYS},
@@ -461,6 +484,8 @@ def caption_phase_summaries() -> dict[str, dict]:
                 if agg["step_n"]
                 else 0.0
             ),
+            **means,
+            "request_ttft_ms": round(sum(v for k, v in means.items() if k != "request_itl_ms"), 3),
         }
     return out
 

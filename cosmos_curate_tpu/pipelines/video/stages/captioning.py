@@ -100,6 +100,42 @@ class CaptionPrepStage(Stage[SplitPipeTask, SplitPipeTask]):
 _OWNER_SEQ = itertools.count()
 
 
+# The five intervals of a request's life inside the engine (CaptionEngine._stamp),
+# each a span `caption.request.<name>` between two stamps of CaptionResult.timing
+_REQUEST_SPANS = (
+    ("queue", "arrived", "taken"),
+    ("prep", "taken", "ready"),
+    ("row_wait", "ready", "admitted"),
+    ("prefill", "admitted", "first_token"),
+    ("decode", "first_token", "finished"),
+)
+
+
+def _emit_request_spans(results: list, stage: str) -> None:
+    """Five spans a result, children of the span that is open (the drive's
+    `caption.engine`), one `request_id` each: the engine stamped the boundaries
+    on the monotonic clock, the span API keeps wall time, so one offset read
+    here moves them over. The `*_step` attributes are the ordinals of the
+    `engine.step` spans of a device profile (docs/OBSERVABILITY.md)."""
+    from cosmos_curate_tpu.observability.tracing import end_span, start_span
+
+    to_wall = time.time() - time.monotonic()
+    for res in results:
+        t = res.timing
+        if not t:
+            continue
+        for name, opens, closes in _REQUEST_SPANS:
+            span = start_span(
+                f"caption.request.{name}", stage=stage, request_id=res.request_id,
+                lane=t["lane"], prompt_tokens=res.num_prompt_tokens,
+                output_tokens=res.num_output_tokens,
+            )
+            if closes + "_step" in t:
+                span.set_attribute("step", t[closes + "_step"])
+            span.start_s, span.end_s = to_wall + t[opens], to_wall + t[closes]
+            end_span(span)
+
+
 def _owner_tag(name: str) -> str:
     """A unique, human-readable engine-owner tag for one stage instance."""
     return f"{name}#{next(_OWNER_SEQ)}"
@@ -354,7 +390,7 @@ class CaptionStage(Stage[SplitPipeTask, SplitPipeTask]):
 
     def process_data(self, tasks: list[SplitPipeTask]) -> list[SplitPipeTask]:
         from cosmos_curate_tpu.observability import stage_timer
-        from cosmos_curate_tpu.observability.tracing import traced_span
+        from cosmos_curate_tpu.observability.tracing import traced_span, tracing_enabled
 
         engine = self._model.engine
         assert engine is not None, "setup() not called"
@@ -384,6 +420,8 @@ class CaptionStage(Stage[SplitPipeTask, SplitPipeTask]):
             phases["requests"] = len(results)
             for k, v in phases.items():
                 span.set_attribute(f"caption.{k}", round(v, 4) if isinstance(v, float) else v)
+            if tracing_enabled():
+                _emit_request_spans(results, self.name)
         stage_timer.record_caption_phases(self.name, phases)
         try:
             from cosmos_curate_tpu.engine.metrics import get_metrics

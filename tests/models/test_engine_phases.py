@@ -51,6 +51,18 @@ COUNTS = {
     "decode_wait_fresh", "decode_wait_ready", "decode_sample_tokens",
     "admit_held", "admit_guests", "prep_n", "prep_requests",
 }
+# a request's life (`CaptionEngine._stamp`): the interval that closes at each boundary
+# after the first, as (seconds, count), and the two counts beside them
+LIFE = ("arrived", "taken", "ready", "admitted", "first_token", "finished")
+INTERVALS = {
+    "taken": ("request_queue_s", "request_taken_n"),
+    "ready": ("request_prep_s", "request_ready_n"),
+    "admitted": ("request_row_wait_s", "request_admitted_n"),
+    "first_token": ("request_prefill_s", "request_first_n"),
+    "finished": ("request_decode_s", "request_finished_n"),
+}
+SECONDS |= {s for s, _ in INTERVALS.values()}
+COUNTS |= {n for _, n in INTERVALS.values()} | {"request_dropped_n", "request_decode_gaps"}
 ALL_KEYS = SECONDS | EXPOSED | COUNTS
 KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]
 
@@ -79,7 +91,10 @@ def engine():
 
 
 def _self_time_sum(phases: dict) -> float:
-    return sum(v for k, v in phases.items() if k in SECONDS and k not in ROOTS + DERIVED)
+    return sum(
+        v for k, v in phases.items()
+        if k in SECONDS and k not in ROOTS + DERIVED and not k.startswith("request_")
+    )
 
 
 def _drive(eng, kind: str) -> int:
@@ -1063,3 +1078,209 @@ class TestPrepRounds:
         finally:
             sys.setswitchinterval(old)
             eng.shutdown()
+
+
+# -- a request's life ------------------------------------------------------------
+
+
+def _account_is_the_lives(ph: dict, lives: list, since: float = 0.0) -> None:
+    """Every interval's sum and count in ``ph`` are those of the ``lives`` (the
+    ``timing`` records) that closed it at or after ``since``."""
+    for i, closes in enumerate(LIFE[1:]):
+        seconds, n = INTERVALS[closes]
+        closed = [t for t in lives if t.get(closes, -1.0) >= since]
+        assert ph[n] == len(closed), (n, ph[n], len(closed))
+        assert ph[seconds] == pytest.approx(sum(t[closes] - t[LIFE[i]] for t in closed), abs=1e-9), seconds
+
+
+def _lives_are_in_order(lives: list, eng) -> None:
+    for t in lives:
+        stamps = [t[k] for k in LIFE]
+        assert stamps == sorted(stamps), t
+        steps = [t[k + "_step"] for k in LIFE[3:]]
+        assert steps == sorted(steps) and steps[0] >= 1, t
+        assert t["lane"] in [l.length for l in eng.lanes]
+        assert set(t) == {*LIFE, "lane", *(k + "_step" for k in LIFE[3:])}
+
+
+def _conserved(ph: dict, drained: bool) -> None:
+    assert ph["request_taken_n"] >= ph["request_ready_n"] + ph["request_dropped_n"]
+    assert ph["request_admitted_n"] <= ph["request_ready_n"]
+    assert ph["request_finished_n"] <= ph["request_first_n"] <= ph["request_admitted_n"]
+    if drained:
+        assert ph["request_taken_n"] == ph["request_ready_n"] + ph["request_dropped_n"]
+        assert ph["request_ready_n"] == ph["request_admitted_n"] == ph["request_first_n"]
+        assert ph["request_first_n"] == ph["request_finished_n"]
+
+
+def _prepared(eng, n: int) -> None:
+    """Wait, without stepping, until the prep thread has handed on ``n`` requests
+    (inline prep happens under ``step()``: nothing to wait for)."""
+    deadline = time.monotonic() + 60
+    while eng.async_prep and len(eng._ready) < n and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert not eng.async_prep or len(eng._ready) == n
+
+
+@pytest.fixture(scope="module")
+def prep_thread_engine():
+    eng = CaptionEngine(VLM_TINY_TEST, max_batch=4, prefill_chunk=8, async_prep=True)
+    eng.setup()
+    yield eng
+    eng.shutdown()
+
+
+class TestRequestLife:
+    """Six stamps a request on the host's clock, each made once where the work
+    happens; the interval a stamp closes goes into the account `phase_seconds`
+    hands out, and the record itself onto the request's result (`timing`)."""
+
+    @pytest.mark.parametrize("prefill", ["whole_prompt", "chunked"])
+    @pytest.mark.parametrize("prep", ["inline", "prep_thread"])
+    def test_the_account_is_the_sum_of_the_results_stamps(self, engine, prep_thread_engine, prep, prefill):
+        eng = {"inline": engine, "prep_thread": prep_thread_engine}[prep]
+        eng.reset_stats()
+        if prefill == "chunked":  # a decode is in flight: the long prompts go by chunks
+            eng.add_request(_text("s0", n=4, max_new=16))
+            _prepared(eng, 1)
+            while not eng.slots:
+                eng.step()
+        for i in range(3):
+            eng.add_request(_text(f"l{i}", n=20 + i, max_new=3 + i))
+        _prepared(eng, 3)  # the three meet ONE admission: a group, or chunks of one program
+        done = eng.run_until_complete()
+        assert len(done) == 3 + (prefill == "chunked") and not eng.has_work()
+        ph, lives = eng.phase_seconds, [r.timing for r in done]
+        _lives_are_in_order(lives, eng)
+        _account_is_the_lives(ph, lives)
+        _conserved(ph, drained=True)
+        assert ph["request_decode_gaps"] == sum(r.num_output_tokens - 1 for r in done)
+        assert ph["request_dropped_n"] == 0
+        # the path the name says: a prompt's chunks ride several steps, a group one
+        long = [r.timing for r in done if r.request_id != "s0"]
+        took = {t["first_token_step"] - t["admitted_step"] for t in long}
+        assert took == ({2} if prefill == "chunked" else {0}), took
+
+    @pytest.mark.parametrize("prep", ["inline", "prep_thread"])
+    def test_a_head_pushed_back_waits_for_its_row_and_is_booked_once(self, prep):
+        # both need the long lane's one row; the short lane's free row keeps their owner
+        # under its cap, so the second is taken and prepared while the first decodes
+        eng = CaptionEngine(
+            VLM_TINY_TEST, kv_lanes=((32, 1), (128, 1)), async_prep=prep == "prep_thread",
+            admission_linger_s=0.0,
+        )
+        eng.add_request(_text("first", n=30, max_new=8), owner="me")
+        eng.add_request(_text("second", n=30, max_new=2), owner="me")
+        eng.setup()
+        try:
+            pushed_back, admit = [0], eng._admit
+
+            def counting(counts):
+                before = len(eng._ready)
+                admit(counts)
+                pushed_back[0] += bool(eng._ready) and len(eng._ready) >= before and bool(eng.slots)
+
+            eng._admit = counting
+            done = {r.request_id: r.timing for r in eng.run_until_complete("me")}
+            assert pushed_back[0] >= 3  # the one row was taken: the head went back step after step
+            first, second = done["first"], done["second"]
+            # its wait for a row spans the other's whole decode, and ends in the step that freed it
+            assert second["ready"] < first["first_token"] <= first["finished"] <= second["admitted"]
+            assert second["admitted_step"] >= first["finished_step"] > first["admitted_step"]
+            ph = eng.phase_seconds
+            _account_is_the_lives(ph, list(done.values()))
+            _conserved(ph, drained=True)
+            assert ph["request_row_wait_s"] >= first["finished"] - first["first_token"]
+        finally:
+            eng.shutdown()
+
+    def test_a_follow_up_is_a_life_of_its_own(self, engine):
+        engine.reset_stats()
+        first = _text("two-pass", max_new=3)
+        second = _text("two-pass", n=9, max_new=2)
+        first.on_complete = lambda text: second
+        engine.add_request(first)
+        (result,) = engine.run_until_complete()
+        assert result.timing is second._life and result.num_prompt_tokens == 9
+        lives = [first._life, second._life]
+        _lives_are_in_order(lives, engine)
+        # the refinement arrives where the first pass ends, and not before
+        assert first._life["finished"] <= second._life["arrived"]
+        ph = engine.phase_seconds
+        _account_is_the_lives(ph, lives)  # the superseded pass finished once, and is counted once
+        _conserved(ph, drained=True)
+        assert ph["request_finished_n"] == 2 and ph["request_decode_gaps"] == (3 - 1) + (2 - 1)
+
+    @pytest.mark.parametrize("prep", ["inline", "prep_thread"])
+    def test_a_dropped_request_ends_where_it_would_have_become_ready(self, engine, prep_thread_engine, prep, monkeypatch):
+        eng = {"inline": engine, "prep_thread": prep_thread_engine}[prep]
+        eng.reset_stats()
+        prepare = eng._prepare
+
+        def failing(req, **kw):
+            if req.request_id == "boom":
+                raise RuntimeError("no preparation for this one")
+            return prepare(req, **kw)
+
+        monkeypatch.setattr(eng, "_prepare", failing)
+        reqs = [_text("g0"), _text("boom"), _text("g1")]
+        with eng._work_cv:
+            for r in reqs:
+                eng.add_request(r)
+        done = eng.run_until_complete()
+        assert sorted(r.request_id for r in done) == ["g0", "g1"] and not eng.has_work()
+        ph = eng.phase_seconds
+        assert (ph["request_taken_n"], ph["request_ready_n"], ph["request_dropped_n"]) == (3, 2, 1)
+        _conserved(ph, drained=True)
+        dropped = reqs[1]._life
+        assert set(dropped) == {"arrived", "taken", "dropped"} and dropped["taken"] <= dropped["dropped"]
+        _account_is_the_lives(ph, [r._life for r in reqs])  # its queue wait counts, its round does not
+
+    def test_reset_stats_zeroes_the_account_and_no_interval_is_booked_twice(self, engine):
+        engine.reset_stats()
+        reqs = [_text(f"z{i}", max_new=6) for i in range(3)]
+        for r in reqs:
+            engine.add_request(r)
+        while not engine.slots:
+            engine.step()
+        _conserved(engine.phase_seconds, drained=False)
+        assert engine.phase_seconds["request_first_n"] == 3 and engine.phase_seconds["request_finished_n"] == 0
+        engine.reset_stats()
+        since = time.monotonic()
+        assert all(engine.phase_seconds[k] == 0 for k in ALL_KEYS if k.startswith("request_"))
+        done = engine.run_until_complete()
+        ph, lives = engine.phase_seconds, [r.timing for r in done]
+        _lives_are_in_order(lives, engine)  # the ordinals run on across the reset
+        _account_is_the_lives(ph, lives, since)  # only what closed after it: the last interval
+        assert ph["request_finished_n"] == 3 and ph["request_first_n"] == ph["request_taken_n"] == 0
+        assert ph["request_decode_gaps"] == 3 * (6 - 1)
+
+    def test_a_request_that_never_arrived_through_add_request_books_nothing(self, engine):
+        engine.reset_stats()
+        engine.waiting.append(_text("side-door", max_new=2, owner=threading.get_ident()))
+        (result,) = engine.run_until_complete()
+        assert result.timing == {}
+        assert all(engine.phase_seconds[k] == 0 for k in ALL_KEYS if k.startswith("request_"))
+
+    def test_the_step_span_carries_the_ordinal_a_result_names(self, engine, spans):
+        engine.reset_stats()
+        engine.add_request(_text("o0", max_new=4))
+        (result,) = engine.run_until_complete()
+        ordinals = [kw["ordinal"] for name, kw in _Recorder.meta if name == "engine.step"]
+        assert ordinals == list(range(ordinals[0], ordinals[0] + len(ordinals)))  # a step, a number
+        assert len(ordinals) == engine.phase_seconds["step_n"]
+        t = result.timing
+        assert {t["admitted_step"], t["first_token_step"], t["finished_step"]} <= set(ordinals)
+        assert t["finished_step"] == ordinals[-1]  # the step that read its last token ended the drive
+        assert not any("ordinal" in k for k in engine.phase_seconds)  # span metadata: no key sums it
+
+    def test_a_request_and_its_result_pickle_with_their_record(self, engine):
+        import pickle
+
+        engine.reset_stats()
+        req = _text("p0", max_new=2)
+        engine.add_request(req)
+        (result,) = engine.run_until_complete()
+        assert pickle.loads(pickle.dumps(result)).timing == result.timing
+        assert pickle.loads(pickle.dumps(req)) == req  # the record is no part of what a request is
+        assert "_life" not in repr(req) and dataclasses.replace(req, request_id="p1")._life is None

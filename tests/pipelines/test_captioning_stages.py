@@ -1,5 +1,7 @@
 """Caption prep + caption stage integration (tiny VLM, synthetic media)."""
 
+import time
+
 import pytest
 
 from cosmos_curate_tpu.core.runner import SequentialRunner
@@ -255,5 +257,100 @@ def test_two_caption_owners_share_engine_and_interleave(tmp_path):
         assert owners[stage_b.owner]["requests"] == 3
         assert owners[stage_a.owner]["decode_tokens"] > 0
     finally:
+        SharedCaptionEngine.reset()
+        stage_timer.reset_caption_phases()
+
+
+class _SpansInMemory:
+    """A tracing backend that keeps what it is handed."""
+
+    def __init__(self):
+        self.spans = []
+
+    def export(self, span):
+        self.spans.append(span)
+
+    def close(self):
+        pass
+
+
+def _exported_request_counters() -> dict:
+    """`caption_requests_total{boundary}` and `caption_request_seconds_total{interval}` of
+    the caption stage, as the process's Prometheus registry holds them now."""
+    from prometheus_client import REGISTRY
+
+    from cosmos_curate_tpu.engine.metrics import get_metrics
+
+    assert get_metrics().enabled
+    read = lambda name, **labels: REGISTRY.get_sample_value(name, {"stage": "CaptionStage", **labels}) or 0.0
+    out = {b: read("caption_requests_total", boundary=b) for b in ("taken", "ready", "admitted", "first", "finished", "dropped")}
+    out.update({i: read("caption_request_seconds_total", interval=i) for i in ("queue", "prep", "row_wait", "prefill", "decode")})
+    return out
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["tracing_on", "tracing_off"])
+def test_a_drive_emits_five_spans_a_result_under_one_request_id(enabled, tmp_path, monkeypatch):
+    """A request's life inside the engine, as spans of the repo's one span API:
+    five a result, children of the drive's `caption.engine`, sharing the
+    request's id; with tracing off, none (and nothing else changes)."""
+    import numpy as np
+
+    from cosmos_curate_tpu.data.model import Clip, SplitPipeTask, Video, VideoMetadata, Window
+    from cosmos_curate_tpu.models.vlm import SharedCaptionEngine
+    from cosmos_curate_tpu.observability import stage_timer, tracing
+
+    SharedCaptionEngine.reset()
+    stage_timer.reset_caption_phases()
+    tasks = []
+    for i in range(3):
+        win = Window(start_frame=0, end_frame=8)
+        win.frames = np.random.default_rng(i).integers(0, 255, (2, 32, 32, 3), np.uint8)
+        clip = Clip(span=(0.0, 1.0))
+        clip.windows = [win]
+        meta = VideoMetadata(width=32, height=32, fps=8.0, num_frames=8, duration_s=1.0)
+        tasks.append(SplitPipeTask(video=Video(path=f"v{i}.mp4", metadata=meta, clips=[clip])))
+    stage = CaptionStage(cfg=VLM_TINY_TEST, max_batch=4, max_new_tokens=5)
+    stage.model.setup()
+    memory = _SpansInMemory()
+    try:
+        if enabled:
+            tracing.enable_tracing(str(tmp_path / "t.ndjson"))
+            monkeypatch.setattr(tracing, "_backends", [memory])
+        exported0 = _exported_request_counters()
+        t0 = time.time()
+        stage.process_data(tasks)
+        t1 = time.time()
+        ids = {f"{t.video.clips[0].uuid}-0" for t in tasks}
+        life = [s for s in memory.spans if s.name.startswith("caption.request.")]
+        if not enabled:
+            assert not memory.spans
+        else:
+            (drive,) = [s for s in memory.spans if s.name == "caption.engine"]
+            assert len(life) == 5 * len(tasks) and {s.attributes["request_id"] for s in life} == ids
+            for rid in ids:
+                mine = [s for s in life if s.attributes["request_id"] == rid]
+                assert [s.name.rsplit(".", 1)[1] for s in mine] == ["queue", "prep", "row_wait", "prefill", "decode"]
+                assert all((s.parent_id, s.trace_id) == (drive.span_id, drive.trace_id) for s in mine)
+                # end to end on the wall clock, inside the drive, each starting where the last ended
+                assert all(a.end_s == pytest.approx(b.start_s, abs=1e-6) for a, b in zip(mine, mine[1:]))
+                assert t0 - 0.05 <= mine[0].start_s <= mine[-1].end_s <= t1 + 0.05
+                assert all(s.start_s <= s.end_s for s in mine)
+                for s in mine:
+                    assert s.attributes["lane"] in [l.length for l in stage.model.engine.lanes]
+                    assert s.attributes["output_tokens"] >= 1 and s.attributes["prompt_tokens"] > 0
+                assert [s.attributes["step"] for s in mine[2:]] == sorted(s.attributes["step"] for s in mine[2:])
+        # the operator's side reads the same account: the five means and the time to first token
+        agg = stage_timer.caption_phase_summaries()["CaptionStage"]
+        assert agg["request_finished_n"] == agg["request_taken_n"] == 3 and agg["request_dropped_n"] == 0
+        first_four = ("request_queue_ms", "request_prep_ms", "request_row_wait_ms", "request_prefill_ms")
+        assert 0 < agg["request_ttft_ms"] == pytest.approx(sum(agg[k] for k in first_four), abs=0.01)
+        assert agg["request_itl_ms"] > 0 and agg["request_decode_gaps"] > 0
+        # ...and the exporter's one counter pair: requests past a boundary, seconds in an interval
+        exported = {k: v - exported0[k] for k, v in _exported_request_counters().items()}
+        assert [exported[b] for b in ("taken", "ready", "admitted", "first", "finished", "dropped")] == [3, 3, 3, 3, 3, 0]
+        for interval in ("queue", "prep", "row_wait", "prefill", "decode"):
+            assert exported[interval] == pytest.approx(agg[f"request_{interval}_s"], abs=1e-3)
+    finally:
+        tracing.disable_tracing()
         SharedCaptionEngine.reset()
         stage_timer.reset_caption_phases()
