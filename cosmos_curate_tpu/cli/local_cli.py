@@ -21,6 +21,8 @@ CAPTION_MODEL_CHOICES = (
     "granite-hybrid-tiny-test",
     "keye-tiny-test",
     "keye-vl2-a3b-ep8",
+    "lfm2-24b-a2b-pp5",
+    "lfm2-moe-tiny-test",
     "olmo-hybrid-7b",
     "olmo-hybrid-7b-pp2",
     "olmo-hybrid-tiny-test",
@@ -117,6 +119,10 @@ def register(sub: argparse._SubParsersAction) -> None:
         "solar-open2-ep8 (text only, no converter yet: it needs staged weights) is one chip's share "
         "of Solar-Open2-250B's first four-layer stage served expert-parallel over 8: three "
         "Kimi-Delta-Attention layers and one gated attention layer, each over 40 of 320 experts. "
+        "lfm2-24b-a2b-pp5 (text only, no converter yet: it needs staged weights) is the first of "
+        "LFM2-24B-A2B's five pipeline stages: ten layers (eight gated short convolutions, whose "
+        "per-request state is two convolution inputs a channel, and two attention layers) with every "
+        "one of a layer's 64 experts on the chip. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

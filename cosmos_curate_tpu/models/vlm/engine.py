@@ -46,7 +46,9 @@ vllm_async_stage.py). TPU-first re-design:
   convolutions' tails) holds the Mamba-2 state of every state-space layer, a
   row a slot, row 0 the garbage row (a gated-delta-rule flavor's store is
   ``[Ll, slots + 1, dk, H * dv]``: ``init_recurrent_store`` sizes it by the
-  mixer's kind, and nothing else here knows the kind but the counters). A row is claimed and released with the
+  mixer's kind, and nothing else here knows the kind but the counters; a
+  short-convolution flavor's store is TAILS ALONE, ``[Lc, slots + 1, 2 * dim]``
+  beside a state array of no width that rides along unread). A row is claimed and released with the
   slot's blocks; admission zeroes it or copies the shared prefix's snapshot
   into it (a prefix entry is blocks PLUS the state at exactly its last
   token); chunked prefill carries it from chunk to chunk; and since a
@@ -616,6 +618,15 @@ def _count_held(held, aux, live=None):
     return jnp.stack([*total, mine % 2**30, held[3] + mine // 2**30])
 
 
+def _expert_choice(aux):
+    """What a program of a flavor with ``MoEConfig.hand_out_choice`` hands out
+    last: the experts every token's router chose (``MoEFFN`` sows them as
+    ``expert_choice``), ``[sparse layers, rows, T, top_k]`` int32 in the
+    layers' order."""
+    layers = aux["expert_choice"]
+    return jnp.stack([layers[name]["moe"]["top_i"][-1] for name in sorted(layers, key=lambda n: int(n.rpartition("_")[2]))])
+
+
 def default_block_size(kv_lanes) -> int:
     """Positions a KV block where the caller names none: 16, and 128 once a
     lane passes 4,096 positions. THE RULE NOW OUTLIVES ITS REASON: it was
@@ -893,6 +904,7 @@ class CaptionEngine:
         self._delta_decode_calls = 0
         self._delta_prefill_chunks = 0
         self._delta = cfg.recurrent_kind == "linear_attention"
+        self._conv_tail_bytes_per_chip = 0  # the store's tails (all of a short-convolution store)
         # sparse experts with a sorted dispatch (DeepSeek-V2): the assignments
         # that landed on the experts held here, summed over the layers of every
         # decode program ON THE DEVICE (``_build_counted_decode``) and read when
@@ -1070,6 +1082,7 @@ class CaptionEngine:
                 cfg, 1 + sum(l.n_slots for l in self.lanes), dtype=self.model.dtype
             )
             self._recurrent_bytes_per_chip = _bytes_per_chip((self._ssm, self._conv))
+            self._conv_tail_bytes_per_chip = _bytes_per_chip(self._conv)
 
         model = self.model
         bs = self.block_size
@@ -1275,6 +1288,8 @@ class CaptionEngine:
         self._copy_blocks = copy_blocks
         if self._recurrent:
             self._build_recurrent_programs()
+        elif self.cfg.moe is not None and self.cfg.moe.hand_out_choice:
+            raise ValueError("MoEConfig.hand_out_choice: only a hybrid's programs hand the experts' choice out")
         if self._indexed:
             self._build_indexed_programs()
         elif self._counts_experts and not self._recurrent:  # (a hybrid's own decode carries the rider)
@@ -1407,11 +1422,15 @@ class CaptionEngine:
         slot's row starts. They take the place of setup()'s. Where the experts'
         dispatch is sorted the decode program also carries the counted decode's
         rider (``_build_counted_decode``): ``held`` after ``rows``, the new
-        count after the stores."""
+        count after the stores. Where the flavor says so
+        (``MoEConfig.hand_out_choice``) all three programs hand out, LAST, what
+        every token's router chose in every sparse layer (``_expert_choice``):
+        nothing here reads it."""
         cfg, model, use_paged, r = self.cfg, self.model, self._use_paged, self._kv_heads_per_pool_row
         if cfg.mrope_section is not None or self._ds_levels:
             raise ValueError("a hybrid flavor with m-rope or deepstack has no program here")
         counted = self._counts_experts
+        choice = ["expert_choice"] if counted and cfg.moe.hand_out_choice else []
 
         def chunk_forward(params, pool_k, pool_v, tables, embeds, rope, write_index, kv_len, **kw):
             """The forward of both: as in setup()'s four programs, the
@@ -1436,12 +1455,13 @@ class CaptionEngine:
             """prefill_batch(_paged) for a hybrid: ``rows`` [N] are the rows'
             rows of the recurrent store (ssm, conv), whose states advance over
             the ``t_valid`` leading positions and no further."""
-            logits, pool_k, pool_v, ssm, conv = chunk_forward(
+            out = chunk_forward(
                 params, pool_k, pool_v, tables, embeds, rope_pos, write_index,
                 write_index + t_valid, deepstack=ds, logits_at=t_valid - 1,
-                recurrent=(ssm, conv, rows, t_valid),
+                recurrent=(ssm, conv, rows, t_valid), **({"mutable": choice} if choice else {}),
             )
-            return logits[:, 0], pool_k, pool_v, ssm, conv
+            (logits, pool_k, pool_v, ssm, conv), *sown = out if choice else (out,)
+            return (logits[:, 0], pool_k, pool_v, ssm, conv, *(_expert_choice(aux) for aux in sown))
 
         @partial(jax.jit, donate_argnums=(1, 2, 7, 8))
         def decode_step_recurrent(
@@ -1453,12 +1473,15 @@ class CaptionEngine:
             out = chunk_forward(
                 params, pool_k, pool_v, tables, embeds, rope_positions[:, None], positions,
                 positions + 1, recurrent=(ssm, conv, rows, (rows > 0).astype(jnp.int32)),
-                **({"mutable": ["intermediates", "held_by_token"]} if counted else {}),
+                **({"mutable": ["intermediates", "held_by_token", *choice]} if counted else {}),
             )
             (logits, pool_k, pool_v, ssm, conv), *sown = out if counted else (out,)
             step_logits = logits[:, 0]
             greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
-            return (greedy, step_logits, pool_k, pool_v, ssm, conv, *(_count_held(held, aux, rows > 0) for aux in sown))
+            return (
+                greedy, step_logits, pool_k, pool_v, ssm, conv, *(_count_held(held, aux, rows > 0) for aux in sown),
+                *(_expert_choice(aux) for aux in sown if choice),
+            )
 
         @jax.jit
         def prefix_prefill_recurrent(params, embeds, rope_pos, t_valid):
@@ -1469,11 +1492,12 @@ class CaptionEngine:
             ck, cv = init_cache(cfg, 1, length=embeds.shape[1])
             ssm, conv = init_recurrent_store(cfg, 1, dtype=model.dtype)
             valid = jnp.full((1,), t_valid, jnp.int32)
-            _logits, nk, nv, ssm, conv = model.apply(
+            out = model.apply(
                 params, embeds, ck, cv, rope_pos, jnp.zeros((1,), jnp.int32), valid,
-                recurrent=(ssm, conv, jnp.zeros((1,), jnp.int32), valid),
+                recurrent=(ssm, conv, jnp.zeros((1,), jnp.int32), valid), **({"mutable": choice} if choice else {}),
             )
-            return nk[:, 0], nv[:, 0], ssm[:, 0], conv[:, 0]
+            (_logits, nk, nv, ssm, conv), *sown = out if choice else (out,)
+            return (nk[:, 0], nv[:, 0], ssm[:, 0], conv[:, 0], *(_expert_choice(aux)[:, 0] for aux in sown))
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def set_state_row(ssm, conv, row, snap_ssm, snap_conv):
@@ -1866,6 +1890,7 @@ class CaptionEngine:
                 "kv_blocks_used_peak": self._kv_blocks_used_peak,
                 # the second kind of state (all zero without state-space layers)
                 "recurrent_state_bytes_per_chip": self._recurrent_bytes_per_chip,
+                "conv_tail_bytes_per_chip": self._conv_tail_bytes_per_chip,
                 "recurrent_rows_total": sum(l.n_slots for l in self.lanes) if self._recurrent else 0,
                 "recurrent_rows_used_peak": self._recurrent_rows_used_peak,
                 "prefix_state_snapshots": self._prefix_state_snapshots,
@@ -2903,6 +2928,7 @@ class CaptionEngine:
             k, v = k[:, :, :tp], v[:, :, :tp]
             # an indexer's build also returns the prefix's index keys
             index_keys = state.pop()[:, :, :tp] if self._indexed else None
+            del state[2:]  # (a flavor that hands out its experts' choice: nobody's here)
         with self._phase("prefill_wait", program=program):
             jax.block_until_ready(v)
         bs = self.block_size
@@ -3172,7 +3198,8 @@ class CaptionEngine:
                     self._delta_prefill_chunks += len(self.cfg.ssm_layers) * int(
                         np.sum(-(-np.asarray(t_valid) // chunk))
                     )
-            logits, self._pool_k, self._pool_v, self._ssm, self._conv = self._prefill_batch(
+            # (*_: the experts' choice where the flavor hands it out, nobody's here)
+            logits, self._pool_k, self._pool_v, self._ssm, self._conv, *_ = self._prefill_batch(
                 *args, self._ssm, self._conv, jnp.asarray(self._state_rows(lane, slots_arr))
             )
         return logits
@@ -3604,7 +3631,7 @@ class CaptionEngine:
                 greedy, logits, self._pool_k, self._pool_v, self._ssm, self._conv, *held = (
                     self._decode(*args, self._ssm, self._conv, jnp.asarray(rows), *rider)
                 )
-                if held:
+                if held:  # (and after it the experts' choice where the flavor hands it out)
                     self._expert_held = held[0]
             elif self._counts_experts:
                 greedy, logits, *pools, self._expert_held = self._decode(*args, self._expert_held)
@@ -3640,7 +3667,7 @@ class CaptionEngine:
                 self._decode_rows_discarded += len(flight.rows) - len(emitted)
                 if self._delta:
                     self._delta_decode_calls += len(self.cfg.ssm_layers)
-                else:
+                elif self.cfg.recurrent_kind == "mamba":  # (a short convolution calls no recurrence)
                     self._ssm_decode_calls += len(self.cfg.ssm_layers)
                 if self.cfg.mla is not None:
                     self._mla_decode_calls += len(self.cfg.kv_layers)

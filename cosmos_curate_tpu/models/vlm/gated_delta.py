@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from cosmos_curate_tpu.models.layers import dense
-from cosmos_curate_tpu.models.vlm.mamba2 import _dt_bias_init
+from cosmos_curate_tpu.models.vlm.mamba2 import _dt_bias_init, conv_with_tail
 from cosmos_curate_tpu.ops import delta_rule as delta_ops
 
 
@@ -83,10 +83,8 @@ class GatedDeltaMixer(nn.Module):
                 [small(f"{n}_conv", nn.initializers.normal(0.2), (taps, w), f32) for n, w in zip("qkv", widths)],
                 axis=-1,
             )
-            window = jnp.concatenate([tail.reshape(b, taps - 1, m.conv_dim).astype(qkv.dtype), qkv], axis=1)
-            new_tail = jax.vmap(lambda row, v: jax.lax.dynamic_slice_in_dim(row, v, taps - 1))(window, valid)
-            new_tail = new_tail.reshape(b, -1).astype(tail.dtype)
-            qkv = nn.silu(sum(window[:, i : i + t].astype(f32) * w[i] for i in range(taps)))
+            qkv, new_tail = conv_with_tail(qkv, tail, w, valid)
+            qkv = nn.silu(qkv)
             q, k, v = jnp.split(qkv, [widths[0], widths[0] + widths[1]], axis=-1)
             q, k, v = q.reshape(b, t, nh, dk), k.reshape(b, t, nh, dk), v.reshape(b, t, nh, dv)
             q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * dk**-0.5

@@ -48,6 +48,24 @@ def _dt_bias_init(key, shape, dtype, dt_min=0.001, dt_max=0.1):
     return dt + jnp.log(-jnp.expm1(-dt))  # the inverse of softplus
 
 
+def conv_with_tail(x, tail, w, valid):
+    """A causal depthwise convolution over ``[tail | chunk]``, what every mixer
+    here that carries a convolution's tail does (Mamba-2's, the delta rule's
+    three, the short convolution's one). x: ``[B, T, C]``; tail: ``[B, (taps -
+    1) * C]``, the rows' last inputs, a row's taps side by side; w: ``[taps,
+    C]`` float32, ``w[i]`` meeting the input ``taps - 1 - i`` positions back;
+    valid: [B] leading positions of the chunk that are tokens. Returns (the
+    sums ``[B, T, C]`` float32: no bias, no activation; the new tail: the
+    ``taps - 1`` inputs before position ``valid``, so padding never enters it
+    and an idle row (``valid`` 0) keeps its own)."""
+    b, t, c = x.shape
+    taps = w.shape[0]
+    window = jnp.concatenate([tail.reshape(b, taps - 1, c).astype(x.dtype), x], axis=1)
+    new_tail = jax.vmap(lambda row, v: jax.lax.dynamic_slice_in_dim(row, v, taps - 1))(window, valid)
+    new_tail = new_tail.reshape(b, -1).astype(tail.dtype)
+    return sum(window[:, i : i + t].astype(jnp.float32) * w[i] for i in range(taps)), new_tail
+
+
 class Mamba2Mixer(nn.Module):
     cfg: Any  # model.Mamba2Config
     dim: int
@@ -77,11 +95,8 @@ class Mamba2Mixer(nn.Module):
         # the d_conv - 1 inputs before position ``valid``
         w = small("conv_kernel", nn.initializers.normal(0.2), (k, m.conv_dim), f32)
         bias = small("conv_bias", nn.initializers.zeros, (m.conv_dim,), f32)
-        window = jnp.concatenate([tail.reshape(b, k - 1, m.conv_dim).astype(xbc.dtype), xbc], axis=1)
-        new_tail = jax.vmap(lambda row, v: jax.lax.dynamic_slice_in_dim(row, v, k - 1))(window, valid)
-        new_tail = new_tail.reshape(b, -1).astype(tail.dtype)
-        xbc = bias + sum(window[:, i : i + t].astype(f32) * w[i] for i in range(k))
-        xbc = nn.silu(xbc)
+        xbc, new_tail = conv_with_tail(xbc, tail, w, valid)
+        xbc = nn.silu(bias + xbc)
         x, bmat, cmat = jnp.split(xbc, [m.d_inner, m.d_inner + n], axis=-1)
         x = x.reshape(b, t, nh, p)
 

@@ -30,6 +30,7 @@ import numpy as np
 from cosmos_curate_tpu.models.layers import MODEL_AXIS, dense
 from cosmos_curate_tpu.models.vlm.gated_delta import GatedDeltaMixer
 from cosmos_curate_tpu.models.vlm.mamba2 import Mamba2Mixer
+from cosmos_curate_tpu.models.vlm.short_conv import ShortConvMixer
 from cosmos_curate_tpu.ops import delta_rule as delta_ops
 from cosmos_curate_tpu.ops import ssm as ssm_ops
 from cosmos_curate_tpu.ops.tiling import round_up
@@ -77,6 +78,9 @@ class MoEConfig:
     # renormalised to sum to one or left as they are, then times the factor
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # what a sigmoid router adds to the chosen scores' sum before it divides by
+    # it: the model's own constant (afmoe publishes 1e-20, lfm2_moe 1e-6)
+    norm_topk_eps: float = 1e-20
     # "queue": GShard's fixed queues (``capacity_factor``). "sorted": exact
     # and proportional, the assignments sorted by expert into one grouped
     # matrix product (ops/grouped_matmul.py): no queue, no drop, and rows
@@ -102,6 +106,15 @@ class MoEConfig:
     # bfloat16 router's would (2e-3). None = the backend's default, what the
     # older sparse flavors were measured with
     router_precision: str | None = None
+    # the engine's programs also hand out what every token's router CHOSE in
+    # every sparse layer (``[sparse layers, rows, T, top_k]`` int32, their last
+    # output; the engine reads none of it). For whoever holds the programs to a
+    # plain reference where no routing margin can be counted on: with every
+    # expert held (``held=None``) each layer's near-tie counts, a bfloat16
+    # hidden state takes another expert than float32 does at one token in six,
+    # and the only comparison that holds every layer follows the program's own
+    # choice (perfbench/drivers/caption_engine_conv.py). A hybrid's programs only
+    hand_out_choice: bool = False
 
     def __post_init__(self) -> None:
         if self.dispatch not in ("queue", "sorted"):
@@ -112,8 +125,8 @@ class MoEConfig:
             raise ValueError("sigmoid scores under a group limit: no flavor here defines that routing")
         if self.n_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"{self.n_experts} experts do not make {self.n_group} groups, top {self.topk_group}")
-        if self.dispatch == "queue" and (self.held is not None or self.shared_hidden or self.first_dense):
-            raise ValueError("held / shared / leading dense layers need dispatch='sorted'")
+        if self.dispatch == "queue" and (self.held is not None or self.shared_hidden or self.first_dense or self.hand_out_choice):
+            raise ValueError("held / shared / leading dense layers / hand_out_choice need dispatch='sorted'")
         if self.dispatch == "sorted" and self.capacity_factor is not None:
             raise ValueError("sorted dispatch drops nothing: it takes no capacity_factor")
         first, count = self.held_experts
@@ -245,6 +258,16 @@ class GatedDeltaConfig:
 
 
 @dataclass(frozen=True)
+class ShortConvConfig:
+    """The gated short convolution of a hybrid decoder (models/vlm/short_conv.py;
+    HF ``Lfm2ShortConv``): a causal depthwise convolution of ``l_cache`` taps
+    over the model's own width, gated before and after. It has no state matrix:
+    what a request carries is the convolution's last ``l_cache - 1`` inputs."""
+
+    l_cache: int = 3  # taps (HF ``conv_L_cache``)
+
+
+@dataclass(frozen=True)
 class IndexerConfig:
     """A learned indexer beside GQA (DeepSeek-Sparse-Attention; HF ``sa_config``):
     every layer scores the earlier positions for each query with ``n_heads``
@@ -271,7 +294,7 @@ class IndexerConfig:
         return self.n_heads**-0.5 * self.head_dim**-0.5
 
 
-_RECURRENT_KINDS = ("mamba", "linear_attention")
+_RECURRENT_KINDS = ("mamba", "linear_attention", "conv")
 
 
 @dataclass(frozen=True)
@@ -314,7 +337,10 @@ class VLMConfig:
     # its state lives in the engine's recurrent store, not in the KV pool.
     # A "linear_attention" layer (Olmo-Hybrid; HF's own word) replaces it with
     # the gated-delta-rule mixer ``gated_delta`` describes, whose state lives
-    # in the same store. One decoder has one recurrent kind.
+    # in the same store. A "conv" layer (LFM2; HF's own word) replaces it with
+    # the gated short convolution ``short_conv`` describes, which carries its
+    # tails in the store and no state beside them. One decoder has one
+    # recurrent kind.
     # Window and full attention mixed (afmoe; HF's own two words):
     # "sliding_attention" layers see ``sliding_window`` positions and keep
     # their K/V in the engine's window pool, "full_attention" layers (like
@@ -323,6 +349,7 @@ class VLMConfig:
     sliding_window: int | None = None
     mamba: Mamba2Config | None = None
     gated_delta: GatedDeltaConfig | None = None
+    short_conv: ShortConvConfig | None = None
     # latent attention (DeepSeek-V2) in place of GQA in every attention layer:
     # its sizes and YaRN's numbers; None = ``DecoderLayer``'s attention. Such a
     # flavor's cache is one latent row a token a layer (``cache_row_elems``)
@@ -362,17 +389,24 @@ class VLMConfig:
             raise ValueError("an indexer beside latent attention, window layers or state-space layers: no program here")
         if self.layer_types is None:
             return
-        kinds = {"attention", "mamba", "linear_attention", "sliding_attention", "full_attention"}
+        kinds = {"attention", "mamba", "linear_attention", "conv", "sliding_attention", "full_attention"}
         if len(self.layer_types) != self.n_layers or set(self.layer_types) - kinds:
             raise ValueError(f"layer_types must name {self.n_layers} layers out of {sorted(kinds)}")
         if "mamba" in self.layer_types and self.mamba is None:
             raise ValueError("layer_types has a 'mamba' layer and mamba= gives no sizes")
         if "linear_attention" in self.layer_types and self.gated_delta is None:
             raise ValueError("layer_types has a 'linear_attention' layer and gated_delta= gives no sizes")
-        if {"mamba", "linear_attention"} <= set(self.layer_types):
+        if "conv" in self.layer_types and self.short_conv is None:
             raise ValueError(
-                "layer_types mixes 'mamba' and 'linear_attention' layers: the recurrent store "
-                "holds one kind of state, and no program here carries two"
+                "layer_types has a 'conv' layer and short_conv= gives no sizes "
+                "(ShortConvConfig: l_cache, the convolution's taps)"
+            )
+        recurrent = sorted(set(self.layer_types) & set(_RECURRENT_KINDS))
+        if len(recurrent) > 1:
+            raise ValueError(
+                f"layer_types mixes {recurrent} layers: the recurrent store holds one kind of "
+                "state (Mamba-2's, the delta rule's, or a short convolution's tails alone), "
+                "and no program here carries two"
             )
         if self.window_layers and not self.sliding_window:
             raise ValueError("layer_types has a 'sliding_attention' layer and sliding_window= is not set")
@@ -415,17 +449,18 @@ class VLMConfig:
 
     @property
     def ssm_layers(self) -> tuple[int, ...]:
-        """Indices of the recurrent layers, state-space ("mamba") or linear
-        attention (one kind a decoder: ``recurrent_kind``): the recurrent
-        store's leading dimension counts these, in this order."""
+        """Indices of the recurrent layers, state-space ("mamba"), linear
+        attention or short convolution (one kind a decoder:
+        ``recurrent_kind``): the recurrent store's leading dimension counts
+        these, in this order."""
         if self.layer_types is None:
             return ()
         return tuple(i for i, kind in enumerate(self.layer_types) if kind in _RECURRENT_KINDS)
 
     @property
     def recurrent_kind(self) -> str | None:
-        """"mamba" or "linear_attention": what the recurrent store holds; None
-        without recurrent layers."""
+        """"mamba", "linear_attention" or "conv": what the recurrent store
+        holds; None without recurrent layers."""
         return self.layer_types[self.ssm_layers[0]] if self.ssm_layers else None
 
 
@@ -930,6 +965,65 @@ VLM_SOLAR_OPEN2_TINY_TEST = VLMConfig(
         dispatch="sorted", held=(2, 2), score_func="sigmoid", selection_bias=True, router_precision="highest",
     ),
 )
+# LFM2-24B-A2B (HF ``lfm2_moe``, config.json of LiquidAI/LFM2-24B-A2B) as the
+# FIRST OF FIVE PIPELINE STAGES holds it: 40 layers x 2048, thirty gated
+# short-convolution layers (3 taps, no bias) and ten GQA layers (32 query / 8 KV
+# heads x 64, per-head q / k RMSNorm, rope 1e6) in the order c c A c | c c A c ...,
+# two leading dense layers (SwiGLU 11776), then 64 routed experts of 1536 a layer
+# (sigmoid scores, a stored selection bias, top 4 renormalised with 1e-6 in the
+# sum, no shared expert), tied head, vocabulary 65,536. Every width as published
+# and EVERY EXPERT HELD (``held = None``: one chip holds a layer's 64 tables, 1.2
+# GB); the cut is in depth alone: the first ten layers, c c | A c c c | A c c c
+# (the two dense layers and two whole periods), the table and the tied head kept
+# with the first stage. Text only. The first flavor whose recurrent store is
+# TAILS ALONE (a convolution's last two inputs a channel, no state matrix).
+_LFM2_LAYERS = ("conv", "conv") + ("full_attention", "conv", "conv", "conv") * 2
+VLM_LFM2_24B_A2B_PP5 = VLMConfig(
+    vocab=65536,
+    dim=2048,
+    n_layers=10,
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=64,
+    hidden_mult=11776 / 2048,
+    max_seq=4096,
+    rope_theta=1_000_000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    qk_norm=True,
+    layer_types=_LFM2_LAYERS,
+    short_conv=ShortConvConfig(l_cache=3),
+    moe=MoEConfig(
+        n_experts=64, top_k=4, hidden=1536, first_dense=2, norm_topk_prob=True,
+        routed_scaling_factor=1.0, norm_topk_eps=1e-6, dispatch="sorted", score_func="sigmoid",
+        selection_bias=True, router_precision="highest", hand_out_choice=True,
+    ),
+)
+# the same mechanisms at test size: one dense conv layer, then A c c c over 8
+# experts, top 2
+VLM_LFM2_MOE_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=5,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    rope_theta=1_000_000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    rms_eps=1e-5,
+    qk_norm=True,
+    layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+    short_conv=ShortConvConfig(l_cache=3),
+    moe=MoEConfig(
+        n_experts=8, top_k=2, hidden=32, first_dense=1, norm_topk_prob=True, norm_topk_eps=1e-6,
+        dispatch="sorted", score_func="sigmoid", selection_bias=True, router_precision="highest",
+        hand_out_choice=True,
+    ),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -984,8 +1078,9 @@ class FlavorSpec:
             )
         if self.model_chips > 1 and self.cfg.ssm_layers:
             raise ValueError(
-                f"{self.model_id}: the recurrent store and its mixers are not "
-                "split over a model mesh; serve a hybrid flavor with model_chips=1"
+                f"{self.model_id}: the recurrent store (Mamba-2 states, delta-rule states "
+                "or short-convolution tails) and its mixers are not split over a model mesh; "
+                "serve a hybrid flavor with model_chips=1"
             )
         if self.model_chips > 1 and self.cfg.indexer is not None:
             raise ValueError(
@@ -1249,6 +1344,27 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 4), (128, 2)),
         ),
+        # a 24B sparse hybrid text LM seen from the first of its five pipeline
+        # stages (the LM-only passes, --enhance-captions): ten layers with every
+        # one of a layer's 64 experts on the chip, 9.8 GiB of parameters. A row
+        # costs 64 KiB of convolution tails whatever its context and 4 KiB of
+        # K/V a position in the two attention layers: 264 rows are 17 MB of
+        # store and 1.1 GiB of pool. 256 decoding rows give each expert 16
+        # assignments a step, a deployment's own
+        "lfm2-24b-a2b-pp5": FlavorSpec(
+            VLM_LFM2_24B_A2B_PP5,
+            "caption-lfm2-24b-a2b-pp5-tpu",
+            text_only=True,
+            kv_lanes=((1024, 256), (4096, 8)),
+            prefill_rows=8,  # 2,048 tokens a prefill program
+        ),
+        "lfm2-moe-tiny-test": FlavorSpec(
+            VLM_LFM2_MOE_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 4), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -1383,14 +1499,15 @@ def route(moe: MoEConfig, logits, bias=None):
     groups as in the experts. With ``score_func="sigmoid"`` (afmoe) an expert's
     score is its own sigmoid, the top-k is taken of score + ``bias`` (``[E]``
     float32, stored; None = no bias) and the WEIGHTS are the unbiased scores of
-    the chosen, renormalised with the published ``1e-20`` in the sum. Returns
+    the chosen, renormalised with the model's own constant in the sum
+    (``MoEConfig.norm_topk_eps``). Returns
     (weights ``[N, k]``, experts ``[N, k]``)."""
     if moe.score_func == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         _, top_i = jax.lax.top_k(scores if bias is None else scores + bias, moe.top_k)
         top_w = jnp.take_along_axis(scores, top_i, axis=-1)
         if moe.norm_topk_prob:
-            top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-20)
+            top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + moe.norm_topk_eps)
         return top_w * moe.routed_scaling_factor, top_i
     probs = jax.nn.softmax(logits, axis=-1)
     if moe.n_group > 1:
@@ -1446,6 +1563,9 @@ class MoEFFN(nn.Module):
         )
         with jax.named_scope("moe.route"):
             top_w, top_i = route(moe, logits, bias)  # [N, k]
+        # for the program that hands the choice out (``MoEConfig.hand_out_choice``)
+        if self.is_mutable_collection("expert_choice") and not self.is_initializing():
+            self.sow("expert_choice", "top_i", top_i.reshape(b, t, k))
         if moe.dispatch == "sorted":
             y = self._sorted_experts(tokens, top_w, top_i)
             return y.reshape(b, t, d).astype(x.dtype)
@@ -1999,6 +2119,31 @@ class LinearAttentionLayer(nn.Module):
         return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype), ssm, tail
 
 
+class ShortConvLayer(nn.Module):
+    """A hybrid decoder's short-convolution layer (LFM2): the gated short
+    convolution (models/vlm/short_conv.py) where ``DecoderLayer`` has
+    attention, then the same FFN half. What it carries is a row of the
+    recurrent store's tails; the store's state half is empty for this kind and
+    passes through untouched."""
+
+    cfg: VLMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32  # see VLM.param_dtype
+    dense_ffn: bool = False  # a leading layer of a sparse model (``moe.first_dense``)
+
+    @nn.compact
+    def __call__(self, x, ssm, tail, rows, valid, *, layer_index=0, use_kernel=None):
+        """As ``MambaLayer``'s; tail: ``[B, (l_cache - 1) * dim]``."""
+        cfg = self.cfg
+        proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
+        mixer = ShortConvMixer(
+            cfg.short_conv, cfg.dim, dtype=self.dtype, param_dtype=self.param_dtype, name="mixer"
+        )
+        y, tail = mixer(RMSNorm(eps=cfg.rms_eps, name="ln1")(x), tail, valid)
+        x = _residual(cfg, x, y)
+        return _ffn_half(cfg, x, proj, self.dtype, self.param_dtype, dense_ffn=self.dense_ffn), ssm, tail
+
+
 class VLM(nn.Module):
     cfg: VLMConfig
     dtype: jnp.dtype = jnp.bfloat16
@@ -2024,8 +2169,11 @@ class VLM(nn.Module):
             param_dtype=self.param_dtype,
             embedding_init=nn.with_partitioning(nn.initializers.normal(0.02), (None, MODEL_AXIS)),
         )
+        def leading_dense(i):
+            return cfg.moe is not None and i < cfg.moe.first_dense
+
         def attention_layer(i):
-            dense_ffn = cfg.moe is not None and i < cfg.moe.first_dense
+            dense_ffn = leading_dense(i)
             if cfg.mla is not None:
                 return LatentAttentionLayer(
                     cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}",
@@ -2039,14 +2187,14 @@ class VLM(nn.Module):
                 use_rope=cfg.rope_in_layer(i), dense_ffn=dense_ffn,
             )
 
-        recurrent_layer = {"mamba": MambaLayer, "linear_attention": LinearAttentionLayer}
+        def recurrent_layer(i):
+            own = dict(dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}")
+            if cfg.layer_types[i] == "conv":  # (the one recurrent kind a sparse model's leading layers come in)
+                return ShortConvLayer(cfg, dense_ffn=leading_dense(i), **own)
+            return {"mamba": MambaLayer, "linear_attention": LinearAttentionLayer}[cfg.layer_types[i]](cfg, **own)
+
         self.layers = [
-            recurrent_layer[cfg.layer_types[i]](
-                cfg, dtype=self.dtype, param_dtype=self.param_dtype, name=f"layer_{i}"
-            )
-            if i in cfg.ssm_layers
-            else attention_layer(i)
-            for i in range(cfg.n_layers)
+            recurrent_layer(i) if i in cfg.ssm_layers else attention_layer(i) for i in range(cfg.n_layers)
         ]
         self.ln_f = RMSNorm(eps=cfg.rms_eps, name="ln_f")
         self.lm_head = (
@@ -2214,8 +2362,11 @@ class VLM(nn.Module):
             # advanced twice). Only the decode kernel walks the store's own
             # rows in place: a custom call is not rematerialised.
             tails, new_tails = store_conv[:, store_rows], []
-            ops = delta_ops if cfg.recurrent_kind == "linear_attention" else ssm_ops
-            in_place = x.shape[1] == 1 and ops.decode_in_place(use_kernel)
+            if cfg.recurrent_kind == "conv":  # tails alone: the empty state half passes through as it is
+                in_place = True
+            else:
+                ops = delta_ops if cfg.recurrent_kind == "linear_attention" else ssm_ops
+                in_place = x.shape[1] == 1 and ops.decode_in_place(use_kernel)
             ssm, rows = (
                 (store_ssm, store_rows) if in_place
                 else (_rows_of(store_ssm, store_rows), jnp.arange(x.shape[0], dtype=jnp.int32))
@@ -2293,8 +2444,14 @@ def init_recurrent_store(cfg: VLMConfig, rows: int, dtype=jnp.bfloat16):
     conv_dim]``, the convolutions' last inputs in the type they were
     computed in (a row's taps side by side: a ``[3, conv_dim]`` plane would
     be padded to a tile of 16 rows on the chip; the delta rule's three
-    convolutions share the row, q | k | v)."""
+    convolutions share the row, q | k | v). A short convolution ("conv") has
+    tails and no state: its ``ssm`` is ``[Lc, rows, 0]``, an array every
+    program threads and none reads (as a latent pool's zero-width companion),
+    and its ``conv`` ``[Lc, rows, (l_cache - 1) * dim]``."""
     lm = len(cfg.ssm_layers)
+    if cfg.recurrent_kind == "conv":
+        tails = (cfg.short_conv.l_cache - 1) * cfg.dim
+        return jnp.zeros((lm, rows, 0), jnp.float32), jnp.zeros((lm, rows, tails), dtype)
     if cfg.recurrent_kind == "linear_attention":
         m = cfg.gated_delta
         ssm = jnp.zeros((lm, rows, m.key_dim, m.n_heads * m.value_dim), jnp.float32)
