@@ -18,16 +18,24 @@ input term vanishes), and points idle rows at row 0.
 - :func:`ssm_decode`: one token a row. Memory-bound by construction: a row's
   state (2 MiB at Granite-4.0-H's 64 x 64 x 128) is read once and written
   once, against 2 x 64 x 64 x 128 multiply-adds. The Pallas kernel walks the
-  rows of a lane, ``heads_per_step`` heads a grid step, the store aliased to
-  its output so that only the visited blocks move; the state keeps ``d_state``
-  on the lanes, so what is per ``(head, head_dim)`` comes in as columns
-  (``[P, heads]`` blocks, sliced a head at a time and spread over the lanes)
-  and ``y`` leaves as columns.
+  rows of a lane, :func:`heads_a_step` heads a grid step (Granite's whole
+  row), the store aliased to its output so that only the visited blocks
+  move. The state keeps ``d_state`` on the lanes and everything else is
+  shaped to that, so that the block's DMA is all a grid step waits for: the
+  decay is a scalar a (row, head) read from SMEM; ``dt x`` comes as the rows
+  the caller has and is turned over in the kernel (a row repeated down the
+  sublanes, transposed); ``y = H C`` is a float32 product on the MXU at
+  ``highest``, ``C H^T``, and leaves lane-dense. What the kernel must not do
+  is reduce over lanes a head AND slice lanes out to spread them a head a
+  register: the two together take 8.7 us of a 64-head step whose DMA takes
+  6.8, each alone under 3 (PERF.md section 6, PR 55;
+  ``scripts/ssm_decode_probe.py`` times the kernel alone).
 - :func:`ssm_prefill`: a chunk of tokens a row, in the chunked SSD form: within
   a step of ``chunk`` tokens the outputs are two matrix products on the MXU
   (``(C B^T * decay) (dt x)``, bfloat16 operands as every activation here),
   and one state is handed from step to step, always in float32 and contracted
-  at ``highest`` precision. Plain XLA in this PR; a Pallas scan is later work.
+  at ``highest`` precision. Plain XLA: ROADMAP S15 has what a Pallas scan
+  would save (the ``[B, H, chunk, chunk]`` decay).
 
 Which implementation runs is decided here and nowhere else, as in
 ops/paged_attention.py: on a TPU the kernel and the SSD form; elsewhere, and
@@ -151,84 +159,109 @@ def ssm_prefill(store, layer, rows, x, dt, a, b, c, d, *, chunk: int, use_kernel
 
 
 def _ssm_decode_kernel(layer_ref, rows_ref, decay_ref, dtx_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
-    """One grid step is ``hb`` heads of one row. decay_ref / dtx_ref / y_ref:
-    ``[P, hb]`` columns, a head a lane; b_ref / c_ref: ``[1, N]``; state_ref
-    / out_ref: ``[hb, P, N]``, the same block of the aliased store."""
+    """One grid step is ``hb`` heads of one row. decay_ref: ``[B, H]`` in
+    SMEM, a scalar a (row, head); dtx_ref: ``[hb, P]`` rows, as the caller
+    has them; b_ref / c_ref: ``[1, N]``; state_ref / out_ref: ``[hb, P, N]``,
+    the same block of the aliased store; y_ref: ``[1, hb * P]``, the heads'
+    ``P`` outputs side by side. Nothing here reduces over lanes or slices a
+    lane out to spread it: together those outlast the block's DMA."""
     del layer_ref, rows_ref  # the index maps read them
-    p, hb = y_ref.shape
-    b_row, c_row = b_ref[...], c_ref[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (p, hb), 1)
-    decay, dtx = decay_ref[...], dtx_ref[...]
-    ys = jnp.zeros((p, hb), jnp.float32)
+    hb, p, n = state_ref.shape
+    row, first = pl.program_id(0), pl.program_id(1) * hb
+    b_row = b_ref[...]
+    c_rows = jnp.broadcast_to(c_ref[...], (8, n))  # the fewest rows a matmul takes
     for i in range(hb):
-        # a head's column, spread over the state's lanes
-        new = state_ref[i] * decay[:, i : i + 1] + dtx[:, i : i + 1] * b_row  # [P, N]
+        # dt x, a value a state row: the head's [1, P] repeated down the
+        # sublanes and turned over, ``[P, N]`` with every lane of a row alike
+        dtx = jnp.broadcast_to(dtx_ref[i : i + 1, :], (n, p)).T
+        new = state_ref[i] * decay_ref[row, first + i] + dtx * b_row
         out_ref[i] = new
-        ys = jnp.where(lane == i, jnp.sum(new * c_row, axis=1, keepdims=True), ys)
-    y_ref[...] = ys
+        # H C on the otherwise idle MXU, ``c new^T`` as ``q k^T`` in the paged
+        # kernels: lane-dense, float32 in and out at six bfloat16 passes
+        y = jax.lax.dot_general(
+            c_rows, new, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=jnp.float32
+        )
+        y_ref[:, pl.ds(i * p, p)] = y[0:1]
 
 
 @functools.partial(jax.jit, static_argnames=("heads_per_step", "interpret"))
 def _ssm_decode(store, layer, rows, decay, dtx, b, c, *, heads_per_step, interpret):
     """store: ``[Lm, R, H, P, N]``; decay: ``[B, H]``; dtx: ``[B, H, P]``; b,
-    c: ``[B, N]``. ``layer`` is a run-time scalar, prefetched with ``rows``:
-    a model's layers share one trace and one lowering of the kernel. Returns
-    (``H_new C`` ``[B, H, P]``, store)."""
+    c: ``[B, N]``. ``layer`` is a run-time scalar, prefetched with ``rows``
+    and ``decay``: a model's layers share one trace and one lowering of the
+    kernel. Returns (``H_new C`` ``[B, H, P]``, store)."""
     bsz, h, p = dtx.shape
     n = store.shape[-1]
     hb = heads_per_step
     groups = h // hb
-
-    def columns(v):  # [B, H, P] -> [B, H / hb, P, hb]
-        return v.reshape(bsz, groups, hb, p).swapaxes(2, 3)
-
-    column_spec = pl.BlockSpec((None, None, p, hb), lambda i, j, *_: (i, j, 0, 0))
     vector_spec = pl.BlockSpec((None, 1, n), lambda i, j, *_: (i, 0, 0))
     state_spec = pl.BlockSpec(
-        (None, None, hb, p, n), lambda i, j, layer, rows: (layer[0], rows[i], j, 0, 0)
+        (None, None, hb, p, n), lambda i, j, layer, rows, decay: (layer[0], rows[i], j, 0, 0)
     )
     y, store = pl.pallas_call(
         _ssm_decode_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(bsz, groups),
-            in_specs=[column_spec, column_spec, vector_spec, vector_spec, state_spec],
-            out_specs=[column_spec, state_spec],
+            in_specs=[
+                pl.BlockSpec((None, None, hb, p), lambda i, j, *_: (i, j, 0, 0)),
+                vector_spec, vector_spec, state_spec,
+            ],
+            out_specs=[pl.BlockSpec((None, None, 1, hb * p), lambda i, j, *_: (i, j, 0, 0)), state_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, groups, p, hb), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, groups, 1, hb * p), jnp.float32),
             jax.ShapeDtypeStruct(store.shape, store.dtype),
         ],
-        # operand 6 (after the two prefetched scalars and four small inputs)
+        # operand 6 (after the three prefetched arrays and three small inputs)
         # is the store, and it is output 1: only the visited blocks move
         input_output_aliases={6: 1},
         # rows may share the garbage row: no two cores in one row's blocks
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_BYTES
+        ),
         interpret=interpret,
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32),
-        columns(jnp.broadcast_to(decay[..., None], dtx.shape)), columns(dtx),
-        b[:, None, :], c[:, None, :], store,
+        jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32), decay,
+        dtx.reshape(bsz, groups, hb, p), b[:, None, :], c[:, None, :], store,
     )
-    return y.swapaxes(2, 3).reshape(bsz, h, p), store
+    return y.reshape(bsz, h, p), store
+
+
+# of state a grid step of the decode kernel, at most: Granite-4.0-H's whole row
+# (PR 55's probe: 3% under two steps a row)
+_STEP_BYTES = 2**21
+# what the kernel asks for: the step's block in and out, two buffers each, and the small blocks
+_VMEM_BYTES = 16 * 2**20
+
+
+def heads_a_step(h: int, p: int, n: int, at_most: int | None = None) -> int:
+    """Heads a grid step of the decode kernel takes: the most that divide ``h``,
+    up to ``at_most`` (None: as many as ``_STEP_BYTES`` of float32 state hold)."""
+    if at_most is None:
+        at_most = max(1, _STEP_BYTES // (4 * p * n))
+    return max(k for k in range(1, min(at_most, h) + 1) if h % k == 0)
 
 
 def ssm_decode(
     store, layer, rows, x, dt, a, b, c, d, *, use_kernel: bool | None = None,
-    interpret: bool | None = None, heads_per_step: int = 32,
+    interpret: bool | None = None, heads_per_step: int | None = None,
 ):
     """Advance the states ``store[layer, rows]`` by one token a row. x: ``[B,
     H, P]``; dt: ``[B, H]``, 0 for a row that must not move (idle rows point
-    at row 0 and may collide there); b, c: ``[B, N]``. Returns (y ``[B, H,
-    P]`` float32, store)."""
+    at row 0 and may collide there); b, c: ``[B, N]``. ``heads_per_step``: at
+    most so many heads a grid step of the kernel (None: as many as
+    ``_STEP_BYTES`` of state hold; a value is for tests and the probe).
+    Returns (y ``[B, H, P]`` float32, store)."""
     x = x.astype(jnp.float32)
     if not decode_in_place(use_kernel):
         y, state = ssm_step_reference(store[layer, rows], x, dt, a, b, c, d)
         return y, store.at[layer, rows].set(state)
     if interpret is None:
         interpret = jax.devices()[0].platform == "cpu"
+    hb = heads_a_step(x.shape[1], x.shape[2], store.shape[-1], heads_per_step)
     y, store = _ssm_decode(
         store, layer, rows, jnp.exp(dt * a), dt[..., None] * x, b.astype(jnp.float32),
-        c.astype(jnp.float32), heads_per_step=min(heads_per_step, x.shape[1]), interpret=interpret,
+        c.astype(jnp.float32), heads_per_step=hb, interpret=interpret,
     )
     return y + d[:, None] * x, store

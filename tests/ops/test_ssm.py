@@ -81,3 +81,97 @@ def test_prefill_and_decode_default_to_the_recurrence_off_the_tpu():
     yd1, sd1 = ssm.ssm_decode(store, 0, rows, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], d, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(yd0), np.asarray(yd1))
     np.testing.assert_array_equal(np.asarray(sd0), np.asarray(sd1))
+
+
+GRANITE = (64, 64, 128)  # Granite-4.0-H's heads, head_dim, d_state
+
+
+def _decode_inputs(seed, b, h, p, n):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(b, h, p)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.001, 0.3, (b, h)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(0.5, 8.0, (h,)), jnp.float32)
+    b_ = jnp.asarray(rng.normal(size=(b, n)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(b, n)) / n**0.5, jnp.float32)  # y of order 1, as the tolerances assume
+    return x, dt, a, b_, c, jnp.asarray(rng.normal(size=(h,)), jnp.float32)
+
+
+@pytest.mark.parametrize("heads_per_step", [None, 2, 4, 8, 16, 32, 64])
+def test_decode_kernel_at_granites_head_shape(heads_per_step):
+    """64 heads of [64, 128], 3 rows (one of them idle on the garbage row), 2
+    layers: the derived heads a step (None) and every explicit one, the whole
+    row a step among them, against the XLA step at the tolerances above."""
+    h, p, n = GRANITE
+    rng = np.random.default_rng(7)
+    store = jnp.asarray(rng.normal(size=(2, 4, h, p, n)), jnp.float32)
+    rows = jnp.asarray([3, 0, 1], jnp.int32)
+    x, dt, a, b, c, d = _decode_inputs(11, 3, h, p, n)
+    dt = dt * (rows > 0)[:, None]
+    y_ref, s_ref = ssm.ssm_decode(store, 1, rows, x, dt, a, b, c, d, use_kernel=False)
+    y, s = ssm.ssm_decode(
+        store, 1, rows, x, dt, a, b, c, d, use_kernel=True, interpret=True, heads_per_step=heads_per_step
+    )
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(s)[0], np.asarray(store)[0])  # the other layer
+    np.testing.assert_array_equal(np.asarray(s)[1, [0, 2]], np.asarray(store)[1, [0, 2]])  # garbage row, unvisited row
+
+
+def test_decode_kernel_over_24_chained_steps_with_rows_permuted():
+    """One store through 24 decode steps, the batch rows pointing at other
+    store rows every step (and one of them idle in turn): ``y`` at every step
+    and the store after the last within 1e-5 of the XLA step's."""
+    h, p, n = 8, 16, 128
+    rng = np.random.default_rng(24)
+    store = ref = jnp.asarray(rng.normal(size=(2, 6, h, p, n)), jnp.float32)
+    for step in range(24):
+        rows = jnp.asarray(rng.permutation(5)[:4] + 1, jnp.int32).at[step % 4].set(0)
+        x, dt, a, b, c, d = _decode_inputs(100 + step, 4, h, p, n)
+        dt = dt * (rows > 0)[:, None]
+        layer = step % 2
+        y_ref, ref = ssm.ssm_decode(ref, layer, rows, x, dt, a, b, c, d, use_kernel=False)
+        y, store = ssm.ssm_decode(store, layer, rows, x, dt, a, b, c, d, use_kernel=True, interpret=True)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(store), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def test_all_rows_idle_on_the_garbage_row_leave_the_store_bit_for_bit():
+    """What a lane with no request hands in: every row at 0 with dt = 0. The
+    kernel visits the garbage row once a batch row and writes back what it read."""
+    h, p, n = 8, 16, 128
+    rng = np.random.default_rng(5)
+    store = jnp.asarray(rng.normal(size=(2, 4, h, p, n)), jnp.float32)
+    x, dt, a, b, c, d = _decode_inputs(6, 5, h, p, n)
+    rows = jnp.zeros(5, jnp.int32)
+    y, s = ssm.ssm_decode(store, 1, rows, x, dt * 0.0, a, b, c, d, use_kernel=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(store))
+    y_ref, _ = ssm.ssm_decode(store, 1, rows, x, dt * 0.0, a, b, c, d, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("h", [8, 24, 64, 128])
+def test_derived_heads_a_step_divides_the_heads_and_fits_the_kernels_vmem(h):
+    """The state block comes in and goes out, each double-buffered: four
+    buffers of ``hb x P x N`` float32 under what the kernel asks the compiler
+    for, with room for the small blocks; an explicit cap is a cap."""
+    _, p, n = GRANITE
+    hb = ssm.heads_a_step(h, p, n)
+    assert h % hb == 0 and 1 <= hb <= h
+    assert 4 * hb * p * n * 4 <= 0.75 * ssm._VMEM_BYTES
+    assert ssm.heads_a_step(h, p, n, at_most=4) == 4
+    assert ssm.heads_a_step(h, p, n, at_most=10**6) == h
+
+
+def test_the_probe_script_rehearses_on_the_cpu(capsys):
+    """``scripts/ssm_decode_probe.py --rehearse``: the control flow at a tiny
+    size in interpret mode (no time it prints means anything)."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[2] / "scripts" / "ssm_decode_probe.py"
+    spec = importlib.util.spec_from_file_location("ssm_decode_probe", path)
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    assert probe.main(["--rehearse", "--rows", "3", "--heads-per-step", "2", "8"]) == 0
+    out = capsys.readouterr().out
+    assert out.count("us a call") == 2 and "of 819 GB/s" in out

@@ -402,6 +402,36 @@ def test_grouped_matmul_compiles_for_v5e_without_copying_the_tables(v5e, m, k, n
     assert not re.search(r"= bf16\[20," + f"{k},{n}" + r"\]\{[^}]*\} copy\(", hlo)
 
 
+@pytest.mark.parametrize("heads_per_step", [16, 32, 64])
+def test_ssm_decode_kernel_compiles_for_v5e_under_its_trace_name(v5e, heads_per_step):
+    """Granite-4.0-H-Micro's widths, the cell's rows and planes: 64 heads of
+    [64, 128] float32, the whole row (2 MiB a buffer, four buffers) or a part
+    of it a grid step; the decay a third prefetched array, ``dt x`` as rows
+    turned over in the kernel, ``H C`` a float32 product at ``highest`` that
+    leaves lane-dense. The custom call carries the name the benchmark's trace
+    reduction looks for, and the store is aliased, not copied."""
+    from cosmos_curate_tpu.ops.ssm import _ssm_decode, heads_a_step
+    from perfbench import trace_reduce
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows, layers, h, p, n = 48, 36, 64, 64, 128
+    assert heads_a_step(h, p, n) == 64  # what the engine's programs run
+    fn = functools.partial(_ssm_decode, heads_per_step=heads_per_step, interpret=False)
+    hlo = jax.jit(fn, donate_argnums=(0,)).lower(
+        arg((layers, rows + 1, h, p, n)), arg((), jnp.int32), arg((rows,), jnp.int32),
+        arg((rows, h)), arg((rows, h, p)), arg((rows, n)), arg((rows, n)),
+    ).compile().as_text()
+    calls = [
+        trace_reduce.instruction(line.strip().removeprefix("ROOT "))
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert calls and all(re.search(r"^_?ssm_decode(\.\d+)?$", c) for c in calls), calls
+    assert not re.search(r"f32\[36,49,64,64,128\]\{[^}]*\} copy\(", hlo)
+
+
 @pytest.mark.parametrize("heads_per_step", [10, 30])
 def test_delta_decode_kernel_compiles_for_v5e_under_its_trace_name(v5e, heads_per_step):
     """Olmo-Hybrid's widths, the cell's rows: 30 heads x [96, 192] side by
