@@ -23,6 +23,8 @@ CAPTION_MODEL_CHOICES = (
     "keye-vl2-a3b-ep8",
     "lfm2-24b-a2b-pp5",
     "lfm2-moe-tiny-test",
+    "mellum2-12b-a2.5b-pp4",
+    "mellum2-tiny-test",
     "olmo-hybrid-7b",
     "olmo-hybrid-7b-pp2",
     "olmo-hybrid-tiny-test",
@@ -123,6 +125,10 @@ def register(sub: argparse._SubParsersAction) -> None:
         "LFM2-24B-A2B's five pipeline stages: ten layers (eight gated short convolutions, whose "
         "per-request state is two convolution inputs a channel, and two attention layers) with every "
         "one of a layer's 64 experts on the chip. "
+        "mellum2-12b-a2.5b-pp4 (text only, no converter yet: it needs staged weights) is the first of "
+        "Mellum2-12B-A2.5B's four pipeline stages: eight layers (window 1,024 and YaRN full attention "
+        "over two KV pools) with every one of a layer's 64 experts on the chip, requests up to 32,767 "
+        "positions, a shared prefix of any length. "
         "With fewer chips than the flavor needs, setup fails and says how many it "
         "needs and found",
     )

@@ -76,6 +76,7 @@ for _mid, _desc in [
     ("caption-olmo-hybrid-7b-pp2-tpu", "Olmo-Hybrid-7B, the first of two pipeline stages: 16 layers, table and head (checkpoint slot; no converter yet)"),
     ("caption-solar-open2-ep8-tpu", "Solar-Open2-250B (Kimi Delta Attention + gated attention over sparse experts), one chip's share of the first four-layer stage of an 8-way expert-parallel deployment (checkpoint slot; no converter yet)"),
     ("caption-lfm2-24b-a2b-pp5-tpu", "LFM2-24B-A2B (gated short convolutions + attention over 64 sparse experts, all held), the first of five pipeline stages: 10 layers, table and tied head (checkpoint slot; no converter yet)"),
+    ("caption-mellum2-12b-a2.5b-pp4-tpu", "Mellum2-12B-A2.5B-Instruct (window and YaRN full attention over 64 sparse experts, all held), the first of four pipeline stages: 8 layers, table and untied head (checkpoint slot; no converter yet)"),
     ("t5-encoder-tpu", "text encoder for caption embeddings"),
     ("ocr-detector-tpu", "overlay-text region detector (Flax FCN)"),
     ("ocr-recognizer-tpu", "text recognizer CRNN with CTC decoding"),

@@ -70,8 +70,16 @@ vllm_async_stage.py). TPU-first re-design:
   the ring never wraps: its window table reads like its other table, shared
   prefix blocks included. A row that will wrap would write over them, so it
   takes private copies of the prefix's window blocks at admission instead.
+  A shared prefix LONGER than the ring (a window of 1,024 under a 2k
+  instruction) keeps every block in the full pool, shared as ever, and in the
+  window pool only the TAIL a later query can still see (``_PrefixEntry.wfirst``
+  on, ``ceil(window / block_size) + 1`` blocks at most); a row admitted on it
+  wraps by construction and copies that tail into the ring slots of the
+  blocks' logical indices (the ``prefix_tail_copy`` phase).
   Such a flavor's prompts always prefill in chunks (a bucket longer than the
-  ring's slack would overwrite what it still reads).
+  ring's slack would overwrite what it still reads). Where it also holds every
+  expert (``MoEConfig.hand_out_choice``) its three programs hand out the
+  experts' choice last (``_build_choice_programs``), as a hybrid's do.
 - **index keys beside the pool** (flavors with a learned indexer,
   ``cfg.indexer``): every position keeps, beside its K/V, ONE small index key a
   layer in a second array ``[L, NB, 1, bs, W]`` with the pool's own block
@@ -274,8 +282,13 @@ class _PrefixEntry:
     # tokens, (ssm [Lm, H, P, N], conv [Lm, (d_conv - 1) * conv_dim]) — copied
     # into the slot's row of the recurrent store at admission
     state: tuple | None = None
-    # two pools: the same positions' blocks in the window pool
+    # two pools: the same positions' blocks in the window pool, from logical
+    # block ``wfirst`` on. A prefix no longer than a row's ring keeps them all
+    # (``wfirst`` 0); a longer one keeps the TAIL a later query can still see,
+    # ``(length - window) // block_size`` on, which an admitted row copies into
+    # its own ring at the slots of the blocks' logical indices
     wblocks: list[int] = field(default_factory=list)
+    wfirst: int = 0
 
 
 @dataclass
@@ -388,6 +401,7 @@ _PHASE_ROOTS = ("step", "prep")
 _PHASES = _PHASE_ROOTS + (
     "lock_wait",
     "admit",
+    "prefix_tail_copy",
     "prefill_build",
     "prefill_dispatch",
     "prefill_wait",
@@ -411,6 +425,9 @@ _PHASE_COUNTS = {
     # _route: heads held back from an idle longer lane, and requests admitted
     # to a lane longer than their home
     "admit": ("held", "guests"),
+    # two pools: admissions that copied a shared prefix's window blocks into the
+    # row's own ring (a row that wraps shares none), and the blocks they copied
+    "prefix_tail_copy": ("n", "blocks"),
     # positions advanced, of those the program's padded rows x T had room for
     "prefill_dispatch": ("n", "tokens", "room"),
     "prefill_sample": ("first",),  # prompts finished: a first output token each
@@ -911,6 +928,9 @@ class CaptionEngine:
         # ``stats()`` is; calls of the latent decode kernel (one a layer a
         # decode program; under _stats_lock)
         self._counts_experts = cfg.moe is not None and cfg.moe.dispatch == "sorted"
+        # ...and hand out, LAST, what every token's router chose
+        # (``MoEConfig.hand_out_choice``); a hybrid's programs do by themselves
+        self._hands_choice = cfg.moe is not None and cfg.moe.hand_out_choice and not self._recurrent
         self._expert_held = None
         self._mla_decode_calls = 0
         # an indexer's decode steps (under _stats_lock), by the host's
@@ -1288,10 +1308,10 @@ class CaptionEngine:
         self._copy_blocks = copy_blocks
         if self._recurrent:
             self._build_recurrent_programs()
-        elif self.cfg.moe is not None and self.cfg.moe.hand_out_choice:
-            raise ValueError("MoEConfig.hand_out_choice: only a hybrid's programs hand the experts' choice out")
         if self._indexed:
             self._build_indexed_programs()
+        elif self._hands_choice:
+            self._build_choice_programs()
         elif self._counts_experts and not self._recurrent:  # (a hybrid's own decode carries the rider)
             self._build_counted_decode()
         self._built = True
@@ -1335,6 +1355,50 @@ class CaptionEngine:
             return greedy, step_logits, pool_k, pool_v, _count_held(held, aux)
 
         self._decode = decode_step_counted
+        self._expert_held = jnp.zeros(2, jnp.int32)
+
+    def _build_choice_programs(self) -> None:
+        """The paged programs of a flavor without a recurrent store that hands
+        out its experts' choice (``MoEConfig.hand_out_choice``): setup()'s paged
+        prefill, the counted decode and the prefix's build, each with one more
+        output, LAST: what every token's router chose in every sparse layer
+        (``_expert_choice``). Nothing here reads it."""
+        cfg, model = self.cfg, self.model
+        if not self._use_paged or self._indexed or cfg.mrope_section is not None:
+            raise ValueError("MoEConfig.hand_out_choice: the paged programs hand it out, without an indexer or m-rope")
+        choice = ["expert_choice"]
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def prefill_batch_choice(params, pool_k, pool_v, tables, embeds, write_index, t_valid, rope_pos, ds=None):
+            (logits, pool_k, pool_v), aux = model.apply(
+                params, embeds, pool_k, pool_v, rope_pos, write_index, write_index + t_valid, tables,
+                deepstack=ds, logits_at=t_valid - 1, method=model.paged_forward, mutable=choice,
+            )
+            return logits[:, 0], pool_k, pool_v, _expert_choice(aux)
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def decode_step_choice(params, pool_k, pool_v, tables, tokens, positions, rope_positions, held):
+            embeds = model.apply(params, tokens[:, None], method=model.embed_tokens)
+            (logits, pool_k, pool_v), aux = model.apply(
+                params, embeds, pool_k, pool_v, rope_positions[:, None], positions, positions + 1, tables,
+                method=model.paged_forward, mutable=["intermediates", *choice],
+            )
+            step_logits = logits[:, 0]
+            greedy = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+            return greedy, step_logits, pool_k, pool_v, _count_held(held, aux), _expert_choice(aux)
+
+        @jax.jit
+        def prefix_prefill_choice(params, embeds, rope_pos, t_valid):
+            ck, cv = init_cache(cfg, 1, length=embeds.shape[1])
+            (_logits, nk, nv), aux = model.apply(
+                params, embeds, ck, cv, rope_pos, jnp.zeros((1,), jnp.int32),
+                jnp.full((1,), t_valid, jnp.int32), mutable=choice,
+            )
+            return nk[:, 0], nv[:, 0], _expert_choice(aux)[:, 0]
+
+        self._prefill_batch = prefill_batch_choice
+        self._decode = decode_step_choice
+        self._prefix_prefill = prefix_prefill_choice
         self._expert_held = jnp.zeros(2, jnp.int32)
 
     def _build_indexed_programs(self) -> None:
@@ -1853,6 +1917,8 @@ class CaptionEngine:
         gcd-shrunk divisor actually used, so cross-run comparisons can
         detect a silent shrink."""
         held, held_live = self._held_counts()  # a device read: outside the lock
+        with self._prefix_lock:  # (before _stats_lock: the canonical order)
+            window_blocks_held = sum(len(e.wblocks) for e in self._prefix_cache.values())
         with self._stats_lock:
             return {
                 "paged_attention": self.paged_attention,
@@ -1888,6 +1954,12 @@ class CaptionEngine:
                 "kv_blocks_total": self._allocator.capacity,
                 "kv_blocks_used": self._allocator.used_blocks,
                 "kv_blocks_used_peak": self._kv_blocks_used_peak,
+                # the shared-prefix cache, and of two pools the window blocks its
+                # entries hold now and those that admissions copied into rows' rings
+                "prefix_cache_hits": self._prefix_hits,
+                "prefix_tokens_saved": self._prefix_tokens_saved,
+                "prefix_window_blocks_held": window_blocks_held,
+                "prefix_tail_blocks_copied": self._phase_n["prefix_tail_copy"]["blocks"],
                 # the second kind of state (all zero without state-space layers)
                 "recurrent_state_bytes_per_chip": self._recurrent_bytes_per_chip,
                 "conv_tail_bytes_per_chip": self._conv_tail_bytes_per_chip,
@@ -2928,13 +3000,15 @@ class CaptionEngine:
             k, v = k[:, :, :tp], v[:, :, :tp]
             # an indexer's build also returns the prefix's index keys
             index_keys = state.pop()[:, :, :tp] if self._indexed else None
-            del state[2:]  # (a flavor that hands out its experts' choice: nobody's here)
+            del state[2 if self._recurrent else 0 :]  # (a flavor that hands out its experts' choice: nobody's here)
         with self._phase("prefill_wait", program=program):
             jax.block_until_ready(v)
         bs = self.block_size
         nb = -(-tp // bs)
-        if self._windowed and nb > self._ring_blocks:
-            return None, False  # longer than a row's ring of window blocks: served uncached
+        # two pools: of a prefix longer than a row's ring of window blocks the
+        # window pool keeps the tail a later query can still see (the full pool
+        # keeps every block, shared as ever); every block of a shorter one
+        wfirst = (tp - self.cfg.sliding_window) // bs if self._windowed and nb > self._ring_blocks else 0
         with self._lock:
             with self._prefix_lock:
                 raced = self._prefix_cache.get(key)
@@ -2947,7 +3021,7 @@ class CaptionEngine:
                         self._prefix_misses -= 1
                         self._prefix_hits += 1
                     return raced, True
-                nw = nb if self._windowed else 0
+                nw = nb - wfirst if self._windowed else 0
                 if not self._can_alloc(nb, nw):
                     self._evict_prefixes_for(nb, n_window=nw)
                 if not self._can_alloc(nb, nw):
@@ -2965,7 +3039,8 @@ class CaptionEngine:
                     full = np.array([at(i) for i in self.cfg.full_layers])
                     win = np.array([at(i) for i in self.cfg.window_layers])
                     self._wpool_k, self._wpool_v = self._write_prefix_blocks(
-                        self._wpool_k, self._wpool_v, k[win], v[win], jnp.asarray(wids, jnp.int32)
+                        self._wpool_k, self._wpool_v, k[win][:, :, wfirst * bs :], v[win][:, :, wfirst * bs :],
+                        jnp.asarray(wids, jnp.int32),
                     )
                     k, v = k[full], v[full]
                 self._pool_k, self._pool_v = self._write_prefix_blocks(
@@ -2986,6 +3061,7 @@ class CaptionEngine:
                     length=tp,
                     state=tuple(state) or None,
                     wblocks=wids,
+                    wfirst=wfirst,
                 )
                 self._prefix_cache[key] = entry
                 while len(self._prefix_cache) > self.prefix_cache_size:
@@ -3059,17 +3135,19 @@ class CaptionEngine:
         private_needed = view_blocks - len(shared)
         # two pools: the row's ring in the window pool, min(need, ring) blocks.
         # A row that never wraps shares the prefix's window blocks like the
-        # others; one that will would write over them, and copies them instead
+        # others; one that will would write over them, and copies them instead,
+        # each into the ring slot of its LOGICAL index (``wfirst`` on: of a
+        # prefix longer than the ring the entry holds the tail alone)
         wshared: list[int] = []
         wcopy: list[int] = []
-        wview = 0
+        wview = wfirst = 0
         if self._windowed:
             wview = min(view_blocks, self._ring_blocks)
             if entry is not None and view_blocks <= self._ring_blocks:
                 wshared = list(entry.wblocks[: entry.n_full])
                 wcopy = entry.wblocks[entry.n_full :]  # the tail block, if partly filled
             elif entry is not None:
-                wcopy = entry.wblocks
+                wcopy, wfirst = entry.wblocks, entry.wfirst
         wprivate_needed = wview - len(wshared)
         if not self._can_alloc(private_needed, wprivate_needed):
             if not any(l.claims for l in self.lanes):
@@ -3099,12 +3177,11 @@ class CaptionEngine:
             wprivate = self._wallocator.alloc(wprivate_needed)
         try:
             if wcopy:
-                self._wpool_k, self._wpool_v = self._copy_blocks(
-                    self._wpool_k,
-                    self._wpool_v,
-                    jnp.asarray(wcopy, jnp.int32),
-                    jnp.asarray(wprivate[: len(wcopy)], jnp.int32),
-                )
+                into = [wprivate[(wfirst + i) % len(wprivate)] for i in range(len(wcopy))]
+                with self._phase("prefix_tail_copy", blocks=len(wcopy)):
+                    self._wpool_k, self._wpool_v = self._copy_blocks(
+                        self._wpool_k, self._wpool_v, jnp.asarray(wcopy, jnp.int32), jnp.asarray(into, jnp.int32)
+                    )
             if cow_src is not None:
                 # the suffix extends INTO the partially-filled shared tail
                 # block: copy-on-write one block — the only device copy on
@@ -3634,7 +3711,10 @@ class CaptionEngine:
                 if held:  # (and after it the experts' choice where the flavor hands it out)
                     self._expert_held = held[0]
             elif self._counts_experts:
-                greedy, logits, *pools, self._expert_held = self._decode(*args, self._expert_held)
+                out = self._decode(*args, self._expert_held)
+                if self._hands_choice:  # LAST, the experts' choice: nobody's here
+                    out = out[:-1]
+                greedy, logits, *pools, self._expert_held = out
                 self._keep_pools(*pools)
             else:
                 greedy, logits, *pools = self._decode(*args)

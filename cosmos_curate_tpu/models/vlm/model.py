@@ -113,7 +113,8 @@ class MoEConfig:
     # expert held (``held=None``) each layer's near-tie counts, a bfloat16
     # hidden state takes another expert than float32 does at one token in six,
     # and the only comparison that holds every layer follows the program's own
-    # choice (perfbench/drivers/caption_engine_conv.py). A hybrid's programs only
+    # choice (perfbench/drivers/caption_engine_conv.py, caption_engine_mellum.py).
+    # A hybrid's programs, and the paged programs over two pools
     hand_out_choice: bool = False
 
     def __post_init__(self) -> None:
@@ -178,25 +179,49 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def yarn_inv_freq(mla: MLAConfig, theta: float) -> np.ndarray:
-    """The rope frequencies of the ``qk_rope_head_dim`` decoupled dims under
-    YaRN (HF ``DeepseekV2YarnRotaryEmbedding``): extrapolated (plain) for the
-    fast dims, interpolated (/ factor) for the slow ones, a linear ramp
-    between the dims that turn ``beta_fast`` and ``beta_slow`` times over the
-    ORIGINAL context. Depends on the factor and that context alone, not on
-    ``max_seq``. float32 ``[rope_dim / 2]``."""
-    dim = mla.qk_rope_head_dim
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_max: int, beta_fast: float = 32.0, beta_slow: float = 1.0
+) -> np.ndarray:
+    """The rope frequencies of ``dim`` rotary dims under YaRN (HF
+    ``_compute_yarn_parameters``, ``DeepseekV2YarnRotaryEmbedding``):
+    extrapolated (plain) for the fast dims, interpolated (/ factor) for the slow
+    ones, a linear ramp between the dims that turn ``beta_fast`` and
+    ``beta_slow`` times over the ORIGINAL context. Depends on the factor and
+    that context alone, not on ``max_seq``; factor <= 1 is plain rope. The one
+    table of the latent layer's decoupled dims and of a GQA layer's whole head
+    (``YarnConfig``). float32 ``[dim / 2]``."""
     extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if mla.yarn_factor <= 1:
+    if factor <= 1:
         return extra.astype(np.float32)
 
     def dim_of(rotations: float) -> float:
-        return dim * math.log(mla.yarn_original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
 
-    low = max(math.floor(dim_of(mla.yarn_beta_fast)), 0)
-    high = min(math.ceil(dim_of(mla.yarn_beta_slow)), dim - 1)
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), dim - 1)
     ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 0.001), 0, 1)
-    return (extra / mla.yarn_factor * ramp + extra * (1 - ramp)).astype(np.float32)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN on a GQA layer's rope (HF ``rope_parameters`` of ``rope_type: yarn``):
+    ``yarn_inv_freq``'s numbers, and what cos and sin are multiplied by (HF
+    ``attention_factor``; None = ``0.1 ln(factor) + 1``), so that a logit
+    carries its square."""
+
+    factor: float = 1.0
+    original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+    @property
+    def gain(self) -> float:
+        return yarn_mscale(self.factor, 1.0) if self.attention_factor is None else self.attention_factor
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        return yarn_inv_freq(dim, theta, self.factor, self.original_max, self.beta_fast, self.beta_slow)
 
 
 @dataclass(frozen=True)
@@ -364,6 +389,10 @@ class VLMConfig:
     # False = rope on the "sliding_attention" layers only, none on the
     # "full_attention" ones (afmoe: the window layers carry the order)
     full_attention_rope: bool = True
+    # rope parameters by layer type (HF ``rope_parameters.full_attention``): YaRN
+    # on the "full_attention" layers' rope, the "sliding_attention" layers' plain
+    # (both at ``rope_theta``); None = every layer's rope plain
+    full_attention_yarn: YarnConfig | None = None
     # afmoe's attention: the heads' outputs times sigmoid of one more
     # projection of the layer's input (``g``, as wide as ``q``) before ``o``
     attention_gate: bool = False
@@ -412,6 +441,10 @@ class VLMConfig:
             raise ValueError("layer_types has a 'sliding_attention' layer and sliding_window= is not set")
         if self.window_layers and (self.mla is not None or self.ssm_layers):
             raise ValueError("window layers beside latent attention or state-space layers: no program here")
+        if self.full_attention_yarn is not None and (
+            not self.full_attention_rope or not self.use_rope or self.mrope_section is not None or self.mla is not None
+        ):
+            raise ValueError("full_attention_yarn scales the full layers' plain 1D rope: it needs one")
 
     @property
     def kv_layers(self) -> tuple[int, ...]:
@@ -437,6 +470,11 @@ class VLMConfig:
 
     def rope_in_layer(self, i: int) -> bool:
         return self.use_rope and (self.full_attention_rope or i in self.window_layers)
+
+    def yarn_in_layer(self, i: int) -> "YarnConfig | None":
+        """YaRN's numbers for layer ``i``'s rope: the full layers' where the
+        flavor gives any (a window layer never looks past its window)."""
+        return None if i in self.window_layers else self.full_attention_yarn
 
     @property
     def cache_row_elems(self) -> int:
@@ -1024,6 +1062,69 @@ VLM_LFM2_MOE_TINY_TEST = VLMConfig(
         hand_out_choice=True,
     ),
 )
+# Mellum2-12B-A2.5B-Instruct (HF ``mellum``, config.json of
+# JetBrains/Mellum2-12B-A2.5B-Instruct) as THE FIRST OF FOUR PIPELINE STAGES sees
+# it: every width as published (2304; 32 query / 4 KV heads x 128 with per-head
+# q / k RMSNorm; window 1,024 on the "sliding_attention" layers, three to each
+# "full_attention" one; rope theta 500,000 in both kinds, the full layers' under
+# YaRN 16 over 8,192; EVERY layer sparse: 64 routed experts of 896, softmax over
+# all of them, top 8 renormalised, no shared expert and no leading dense layer:
+# ``intermediate_size`` 7168 is read by no layer), EVERY EXPERT HELD (``held =
+# None``: a layer's 64 tables are 0.79 GB), the vocabulary whole and the head
+# untied. The cut is in depth alone: the first eight of 28 layers, S S S F | S S
+# S F. Text only. The first flavor with every expert held whose programs are the
+# PAGED ones over two pools, and the first whose rope differs by layer type.
+_MELLUM2_LAYERS = (("sliding_attention",) * 3 + ("full_attention",)) * 2
+VLM_MELLUM2_12B_PP4 = VLMConfig(
+    vocab=98304,
+    dim=2304,
+    n_layers=8,
+    n_heads=32,
+    n_kv_heads=4,
+    head_dim=128,
+    hidden_mult=7168 / 2304,
+    max_seq=32768,
+    rope_theta=500_000.0,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    tied_embeddings=False,
+    qk_norm=True,
+    layer_types=_MELLUM2_LAYERS,
+    sliding_window=1024,
+    full_attention_yarn=YarnConfig(
+        factor=16.0, original_max=8192, beta_fast=32.0, beta_slow=1.0, attention_factor=1.2772588722239782
+    ),
+    moe=MoEConfig(
+        n_experts=64, top_k=8, hidden=896, norm_topk_prob=True, dispatch="sorted", held=None,
+        hand_out_choice=True, router_precision="highest",
+    ),
+)
+# the same mechanisms at test size: window, window, full, window; a window of 10
+# (no multiple of the engine's test block of 4, so its edge falls inside a page);
+# YaRN 4 over an original context of 32 (the ramp lies inside the eight rotary
+# dims, and positions past 32 are where the interpolated dims differ from plain
+# rope); 8 experts, top 2, all held
+VLM_MELLUM2_TINY_TEST = VLMConfig(
+    vocab=512,
+    dim=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=16,
+    hidden_mult=2.0,
+    max_seq=128,
+    vision=VIT_TINY_TEST,
+    vision_tokens=8,
+    tied_embeddings=False,
+    qk_norm=True,
+    layer_types=("sliding_attention", "sliding_attention", "full_attention", "sliding_attention"),
+    sliding_window=10,
+    full_attention_yarn=YarnConfig(factor=4.0, original_max=32, beta_fast=4.0, beta_slow=1.0),
+    moe=MoEConfig(
+        n_experts=8, top_k=2, hidden=32, norm_topk_prob=True, dispatch="sorted", held=None,
+        hand_out_choice=True, router_precision="highest",
+    ),
+)
 # Named caption-model flavors selectable from pipeline args (CLI
 # --caption-model); each pairs an architecture with its weight-registry id
 # plus the serving knobs that must travel with the checkpoint choice.
@@ -1365,6 +1466,29 @@ VLM_FLAVORS.update(
             text_only=True,
             kv_lanes=((64, 4), (128, 2)),
         ),
+        # a 12B sparse text LM with window and full attention layers mixed, seen
+        # from the first of its four pipeline stages (the LM-only passes over VERY
+        # long text behind a long shared instruction): eight layers with every one
+        # of a layer's 64 experts on the chip, 8.0 GB of parameters with the table
+        # and the float32 head. A position costs 2 KiB of K/V a layer; a window
+        # layer's row is a ring of 11 blocks of 128 whatever the lane, so 28 rows
+        # cost 0.45 GiB of window pool and 4 x 8,192 + 24 x 32,768 positions 3.1
+        # GiB of full pool over the two full layers. 28 decoding rows give each
+        # expert 3.5 assignments a step
+        "mellum2-12b-a2.5b-pp4": FlavorSpec(
+            VLM_MELLUM2_12B_PP4,
+            "caption-mellum2-12b-a2.5b-pp4-tpu",
+            text_only=True,
+            kv_lanes=((8192, 4), (32768, 24)),
+            prefill_rows=4,  # 1,024 tokens a prefill program
+        ),
+        "mellum2-tiny-test": FlavorSpec(
+            VLM_MELLUM2_TINY_TEST,
+            "caption-vlm-tpu",
+            require_weights=False,
+            text_only=True,
+            kv_lanes=((64, 2), (128, 2)),
+        ),
         # hf_chat plumbing under test shapes: exercises HFVocabTokenizer +
         # chat-template request building without a real checkpoint
         "qwen-chat-tiny-test": FlavorSpec(
@@ -1408,10 +1532,12 @@ def apply_rope(
     mrope_section: tuple[int, int, int] | None = None,
     mrope_interleaved: bool = False,
     freqs=None,
+    gain: float = 1.0,
 ) -> jnp.ndarray:
     """x: [B, T, H, D]; positions: [B, T] absolute positions, or [B, T, 3]
     (t, h, w) multimodal positions under m-rope. ``freqs`` ([D/2]) takes the
-    place of the plain ``theta`` frequencies (YaRN: ``yarn_inv_freq``).
+    place of the plain ``theta`` frequencies (YaRN: ``yarn_inv_freq``) and
+    ``gain`` multiplies cos and sin (YaRN's ``attention_factor``).
 
     M-rope (HF apply_multimodal_rotary_pos_emb semantics): each of the D/2
     rotary frequency dims takes its angle from one position component,
@@ -1431,6 +1557,8 @@ def apply_rope(
         angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if gain != 1.0:
+        cos, sin = cos * gain, sin * gain
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -1700,6 +1828,7 @@ class DecoderLayer(nn.Module):
     # rope in THIS layer (``VLMConfig.rope_in_layer``); None = ``cfg.use_rope``
     use_rope: bool | None = None
     dense_ffn: bool = False  # a leading layer of a sparse model (``moe.first_dense``)
+    yarn: YarnConfig | None = None  # YaRN on THIS layer's rope (``VLMConfig.yarn_in_layer``)
 
     @nn.compact
     def __call__(
@@ -1743,8 +1872,11 @@ class DecoderLayer(nn.Module):
             q = RMSNorm(eps=cfg.rms_eps, name="q_norm")(q)
             k = RMSNorm(eps=cfg.rms_eps, name="k_norm")(k)
         if cfg.use_rope if self.use_rope is None else self.use_rope:
-            q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
-            k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved)
+            scaled = {} if self.yarn is None else {
+                "freqs": jnp.asarray(self.yarn.inv_freq(dh, cfg.rope_theta)), "gain": self.yarn.gain,
+            }
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved, **scaled)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_section, cfg.mrope_interleaved, **scaled)
         v = v.reshape(b, t, hk, dh)
         # a "sliding_attention" layer hands its window on; where the kinds mix
         # each is named in a compiled program (no other flavor's programs
@@ -2003,7 +2135,9 @@ class LatentAttentionLayer(nn.Module):
         h, c = cfg.n_heads, mla.kv_lora_rank
         dn, dr, dv, w = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim, mla.cache_width
         proj = partial(dense, dtype=self.dtype, param_dtype=self.param_dtype)
-        freqs = jnp.asarray(yarn_inv_freq(mla, cfg.rope_theta))
+        freqs = jnp.asarray(yarn_inv_freq(
+            dr, cfg.rope_theta, mla.yarn_factor, mla.yarn_original_max, mla.yarn_beta_fast, mla.yarn_beta_slow
+        ))
         # what YaRN puts on cos and sin: mscale / mscale(all_dim), 1 when they agree
         rope_gain = yarn_mscale(mla.yarn_factor, mla.yarn_mscale) / yarn_mscale(
             mla.yarn_factor, mla.yarn_mscale_all_dim
@@ -2184,7 +2318,7 @@ class VLM(nn.Module):
             return DecoderLayer(
                 cfg, dtype=self.dtype, param_dtype=self.param_dtype, mesh=self.mesh,
                 name=f"layer_{i}", window=cfg.sliding_window if i in cfg.window_layers else None,
-                use_rope=cfg.rope_in_layer(i), dense_ffn=dense_ffn,
+                use_rope=cfg.rope_in_layer(i), dense_ffn=dense_ffn, yarn=cfg.yarn_in_layer(i),
             )
 
         def recurrent_layer(i):

@@ -285,7 +285,9 @@ def test_yarn_numbers_of_the_published_config():
     assert round(mla.softmax_scale, 6) == 0.114721
     dim = lambda r: 64 * math.log(4096 / (2 * math.pi * r)) / (2 * math.log(10000))
     assert (math.floor(dim(32)), math.ceil(dim(1))) == (10, 23)
-    inv = yarn_inv_freq(mla, 10000.0)
+    inv = yarn_inv_freq(
+        mla.qk_rope_head_dim, 10000.0, mla.yarn_factor, mla.yarn_original_max, mla.yarn_beta_fast, mla.yarn_beta_slow
+    )
     f = 10000.0 ** (-np.arange(32) / 32)
     np.testing.assert_allclose(inv[:11], f[:11], rtol=1e-6)  # extrapolated: as published
     np.testing.assert_allclose(inv[23:], f[23:] / 40, rtol=1e-6)  # interpolated

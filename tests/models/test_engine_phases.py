@@ -29,6 +29,7 @@ DERIVED = ("prefill_s", "decode_s")
 STEP_LEAVES = (
     "lock_wait_s",
     "admit_s",
+    "prefix_tail_copy_s",  # (two pools: a wrapping row's copy of a shared prefix's window blocks)
     "prefill_build_s",
     "prefill_dispatch_s",
     "prefill_wait_s",
@@ -49,7 +50,7 @@ COUNTS = {
     "prefill_dispatch_tokens", "prefill_dispatch_room", "prefill_sample_first",
     "decode_dispatch_rows", "decode_dispatch_live", "decode_dispatch_ahead",
     "decode_wait_fresh", "decode_wait_ready", "decode_sample_tokens",
-    "admit_held", "admit_guests", "prep_n", "prep_requests",
+    "admit_held", "admit_guests", "prep_n", "prep_requests", "prefix_tail_copy_n", "prefix_tail_copy_blocks",
 }
 # a request's life (`CaptionEngine._stamp`): the interval that closes at each boundary
 # after the first, as (seconds, count), and the two counts beside them

@@ -463,11 +463,12 @@ def test_the_programs_hand_out_what_their_routers_chose(params):
     engine.shutdown()
 
 
-def test_hand_out_choice_is_a_sorted_hybrids():
+def test_hand_out_choice_is_a_sorted_dispatchs_and_a_paged_programs():
     with pytest.raises(ValueError, match="hand_out_choice"):
         MoEConfig(hand_out_choice=True)  # the queue dispatch
+    # without a recurrent store the PAGED programs hand it out (tests/models/test_mellum2_windowed.py): not `gather`
     cfg = dataclasses.replace(
-        vlm_model.VLM_TRINITY_TINY_TEST, moe=dataclasses.replace(vlm_model.VLM_TRINITY_TINY_TEST.moe, hand_out_choice=True)
+        vlm_model.VLM_MOE_TINY_TEST, moe=MoEConfig(n_experts=4, top_k=2, hidden=32, dispatch="sorted", hand_out_choice=True)
     )
-    with pytest.raises(ValueError, match="only a hybrid's programs"):
-        CaptionEngine(cfg, kv_lanes=((64, 2),), block_size=8).setup()
+    with pytest.raises(ValueError, match="the paged programs hand it out"):
+        CaptionEngine(cfg, kv_lanes=((64, 2),), block_size=8, paged_attention="gather").setup()
