@@ -1785,9 +1785,11 @@ class MoEFFN(nn.Module):
             if self.is_mutable_collection("held_by_token") and not self.is_initializing():
                 self.sow("held_by_token", "held", (expert < count).reshape(n, k).sum(axis=1))
             rows = tokens.astype(self.dtype)[order // k]  # [A, D], expert-major
-            z = grouped_matmul(rows, gate_up.astype(self.dtype), sizes)
+            # every table held and every row some table's: the product's tiles follow that
+            whole = moe.held is None
+            z = grouped_matmul(rows, gate_up.astype(self.dtype), sizes, whole=whole)
             gate, up = jnp.split(z, 2, axis=-1)
-            out = grouped_matmul(nn.silu(gate) * up, down.astype(self.dtype), sizes)
+            out = grouped_matmul(nn.silu(gate) * up, down.astype(self.dtype), sizes, whole=whole)
             # rows past the held assignments belong to no expert: whatever the
             # product left there is not a number anybody computed
             out = jnp.where(jnp.arange(n * k)[:, None] < held, out, 0).astype(jnp.float32)

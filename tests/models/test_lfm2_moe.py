@@ -21,6 +21,7 @@ from cosmos_curate_tpu.models.vlm.model import (
     VLM, VLM_LFM2_24B_A2B_PP5, VLM_LFM2_MOE_TINY_TEST as CFG, MoEConfig, MoEFFN, ShortConvConfig,
     VLMConfig, init_cache, init_recurrent_store, route, vlm_flavor,
 )
+from cosmos_curate_tpu.ops import grouped_matmul as gmm_ops
 from perfbench.reference import lfm2_moe as ref
 from tests.models.test_hybrid_engine import Spy, _ids, _rel, _request, _run
 
@@ -103,8 +104,15 @@ def _assert_decode_matches(params, spy, engine_tokens, name, prompt, least=2):
     assert _rms(tails[0], want[0]) < TAIL_TOL and _rms(tails, want) < TAILS_TOL
 
 
-def test_model_against_the_reference_on_logits(params):
-    """The model's own forward over a whole prompt (no engine, no cache kept)."""
+@pytest.mark.parametrize("product", ["ragged-dot", "gmm-k-whole"])
+def test_model_against_the_reference_on_logits(params, product, monkeypatch):
+    """The model's own forward over a whole prompt (no engine, no cache kept);
+    and the same with its experts' products through the Pallas kernel (interpret
+    mode), K whole in a tile as every program of a flavor with ``held=None``
+    runs them on the chip: the same limit."""
+    if product == "gmm-k-whole":
+        monkeypatch.setattr(gmm_ops, "_on_tpu", lambda: True)
+    asked = gmm_ops.tiles.cache_info()
     ids = _ids(11, 48)
     model = VLM(CFG)
     embeds = model.apply(params, jnp.asarray([ids], jnp.int32), method=model.embed_tokens)
@@ -112,6 +120,8 @@ def test_model_against_the_reference_on_logits(params):
         params, embeds, *init_cache(CFG, 1, length=64), jnp.arange(48)[None], jnp.zeros((1,), jnp.int32),
         jnp.full((1,), 48, jnp.int32),
     )
+    now = gmm_ops.tiles.cache_info()  # only the kernel asks for tiles
+    assert (now.hits + now.misses > asked.hits + asked.misses) == (product == "gmm-k-whole")
     want, margin, _ = _reference(params, ids)
     wide = margin >= MARGIN
     assert wide.sum() >= 8

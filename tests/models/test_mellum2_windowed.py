@@ -20,6 +20,7 @@ from cosmos_curate_tpu.models.vlm.model import (
     VLM, VLM_DEEPSEEK_V2_EP8, VLM_MELLUM2_12B_PP4, VLM_MELLUM2_TINY_TEST, VLM_TRINITY_TINY_TEST, YarnConfig,
     init_cache, vlm_flavor, yarn_inv_freq,
 )
+from cosmos_curate_tpu.ops import grouped_matmul as gmm_ops
 from perfbench.reference import mellum2_moe as ref
 
 CFG = VLM_MELLUM2_TINY_TEST
@@ -131,9 +132,17 @@ def test_config_rope_by_layer_type():
 # -- (b) the model against the plain reference -------------------------------------
 
 
-def test_whole_model_logits_and_k_rows_match_the_reference_at_every_position(params):
+@pytest.mark.parametrize("product", ["ragged-dot", "gmm-k-whole"])
+def test_whole_model_logits_and_k_rows_match_the_reference_at_every_position(params, product, monkeypatch):
+    """``gmm-k-whole``: the experts' products through the Pallas kernel in interpret
+    mode, K whole in a tile as a flavor with every expert held runs them on the chip."""
+    if product == "gmm-k-whole":
+        monkeypatch.setattr(gmm_ops, "_on_tpu", lambda: True)
+    asked = gmm_ops.tiles.cache_info()
     ids = _ids(70)  # seven windows deep, twice the YaRN table's original context
     logits, cache = _forward(CFG, params, ids)
+    now = gmm_ops.tiles.cache_info()  # only the kernel asks for tiles
+    assert (now.hits + now.misses > asked.hits + asked.misses) == (product == "gmm-k-whole")
     sizes = ref.model_kwargs(CFG)
     want, _ = ref.logits_at(params, jnp.asarray(ids), list(range(70)), **sizes)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=3e-5)

@@ -383,23 +383,58 @@ def test_latent_pool_keeps_the_kernels_layout_through_the_write(v5e, t):
 
 
 @pytest.mark.parametrize(
-    "m,k,n", [(1536, 5120, 3072), (1536, 1536, 5120), (12288, 5120, 3072)],
-    ids=["decode-gate-up", "decode-down", "prefill-gate-up"],
+    "tables,m,k,n,whole",
+    [
+        (20, 1536, 5120, 3072, False), (20, 1536, 1536, 5120, False), (20, 12288, 5120, 3072, False),
+        (64, 8192, 2048, 3072, True), (64, 8192, 1536, 2048, True), (64, 1024, 2048, 3072, True),
+        (64, 8192, 2304, 1792, True), (64, 8192, 896, 2304, True),
+    ],
+    ids=[
+        "decode-gate-up", "decode-down", "prefill-gate-up", "lfm2-prefill-gate-up-whole", "lfm2-prefill-down-whole",
+        "lfm2-decode-gate-up-whole", "mellum2-prefill-gate-up-whole", "mellum2-prefill-down-whole",
+    ],
 )
-def test_grouped_matmul_compiles_for_v5e_without_copying_the_tables(v5e, m, k, n):
-    """DeepSeek-V2's 20 held experts at the cell's row counts: the tiles chosen
-    in ops/grouped_matmul.py fit a call's VMEM, the tables go into the kernel
-    as they are stored, and the custom call is named ``gmm``."""
-    from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul
+def test_grouped_matmul_compiles_for_v5e_without_copying_the_tables(v5e, tables, m, k, n, whole):
+    """DeepSeek-V2's 20 held experts at the cell's row counts, and LFM2's and
+    Mellum2's 64, every one held, with K whole in a tile (6 MiB of a table a
+    grid step at the most, 14.75 MiB a step): the tiles chosen in ops/grouped_matmul.py fit a
+    call's VMEM, the tables go into the kernel as they are stored, and the
+    custom call is named ``gmm``."""
+    from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul, tiles
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    hlo = jax.jit(functools.partial(grouped_matmul, use_kernel=True, interpret=False)).lower(
-        arg((m, k), jnp.bfloat16), arg((20, k, n), jnp.bfloat16), arg((20,), jnp.int32)
+    assert (tiles(k, n, whole=whole)[1] == k) == (whole or k <= 1024)
+    hlo = jax.jit(functools.partial(grouped_matmul, whole=whole, use_kernel=True, interpret=False)).lower(
+        arg((m, k), jnp.bfloat16), arg((tables, k, n), jnp.bfloat16), arg((tables,), jnp.int32)
     ).compile().as_text()
     assert re.search(r"%gmm(\.\d+)? = .*custom-call", hlo)
-    assert not re.search(r"= bf16\[20," + f"{k},{n}" + r"\]\{[^}]*\} copy\(", hlo)
+    assert not re.search(rf"= bf16\[{tables}," + f"{k},{n}" + r"\]\{[^}]*\} copy\(", hlo)
+
+
+@pytest.mark.parametrize("tokens", [256, 1024], ids=["decode-rows", "prefill-rows"])
+@pytest.mark.parametrize(
+    "preset", ["VLM_DEEPSEEK_V2_EP8", "VLM_TRINITY_LARGE_EP8", "VLM_KEYE_VL2_A3B_EP8", "VLM_SOLAR_OPEN2_EP8"]
+)
+def test_share_held_expert_layers_lower_for_v5e_to_the_parents_text(v5e, preset, tokens):
+    """The four cells whose programs hold a share of their experts, at their
+    real widths, lowered (not compiled) for the described v5e: the text,
+    Mosaic's serialized kernels in it (printed back without their debug
+    locations), is what ``grouped_matmul`` gave before a caller could say
+    ``whole`` (PR 59)."""
+    from cosmos_curate_tpu.models.vlm import model as vlm_model
+    from cosmos_curate_tpu.ops.grouped_matmul import grouped_matmul
+    from tests.ops.test_grouped_matmul import moe_layer_text, parent_grouped_matmul
+
+    cfg = getattr(vlm_model, preset)
+    kernel = dict(use_kernel=True, interpret=False)
+    now = moe_layer_text(cfg, tokens, functools.partial(grouped_matmul, **kernel), sharding=v5e)
+    then = moe_layer_text(cfg, tokens, functools.partial(parent_grouped_matmul, **kernel), sharding=v5e)
+    assert now == then and now.count("tpu_custom_call") == 2
+    # and the check can tell: the same layer saying ``whole`` is another text
+    said = moe_layer_text(cfg, tokens, lambda *a, **kw: grouped_matmul(*a, **kw | kernel | {"whole": True}), sharding=v5e)
+    assert said != now
 
 
 @pytest.mark.parametrize("heads_per_step", [16, 32, 64])
