@@ -157,6 +157,16 @@ class EngineMetrics:
             "caption_paged_kernel_steps_total",
             "decode steps served by the paged-attention programs", labels,
         )
+        # the stage_timer._CAPTION_COUNT_KEYS that have a counter of their own
+        self._caption_counts = {
+            "prefix_cache_hits": self.caption_prefix_hits,
+            "prefix_cache_misses": self.caption_prefix_misses,
+            "prefix_tokens_saved": self.caption_prefix_saved,
+            "prefix_block_refs": self.caption_prefix_block_refs,
+            "kv_cow_copies": self.caption_kv_cow,
+            "interleaved_steps": self.caption_interleaved_steps,
+            "paged_kernel_steps": self.caption_paged_kernel_steps,
+        }
         # per-owner queue/in-flight gauges for the SHARED engine: which
         # job/stage is occupying or starving the continuous batch
         self.caption_owner_queue = Gauge(
@@ -417,27 +427,8 @@ class EngineMetrics:
             self.caption_requests.labels(stage, boundary).inc(
                 max(0, int(phases.get(f"request_{boundary}_n", 0)))
             )
-        self.caption_prefix_hits.labels(stage).inc(
-            max(0, int(phases.get("prefix_cache_hits", 0)))
-        )
-        self.caption_prefix_misses.labels(stage).inc(
-            max(0, int(phases.get("prefix_cache_misses", 0)))
-        )
-        self.caption_prefix_saved.labels(stage).inc(
-            max(0, int(phases.get("prefix_tokens_saved", 0)))
-        )
-        self.caption_prefix_block_refs.labels(stage).inc(
-            max(0, int(phases.get("prefix_block_refs", 0)))
-        )
-        self.caption_kv_cow.labels(stage).inc(
-            max(0, int(phases.get("kv_cow_copies", 0)))
-        )
-        self.caption_interleaved_steps.labels(stage).inc(
-            max(0, int(phases.get("interleaved_steps", 0)))
-        )
-        self.caption_paged_kernel_steps.labels(stage).inc(
-            max(0, int(phases.get("paged_kernel_steps", 0)))
-        )
+        for key, counter in self._caption_counts.items():
+            counter.labels(stage).inc(max(0, int(phases.get(key, 0))))
         if "kv_blocks_used" in phases:
             self.caption_kv_blocks_used.labels(stage).set(
                 max(0, int(phases["kv_blocks_used"]))

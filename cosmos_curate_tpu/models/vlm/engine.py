@@ -418,7 +418,8 @@ _PHASES = _PHASE_ROOTS + (
 # (PERF.md section 3) is kept: any other keyword of _phase() (`lane`, `program`,
 # `frames`, a prefill program's `rows` and `live`) is span metadata only.
 _PHASE_COUNTS = {
-    "step": ("n",),
+    # steps, and those whose active slots spanned two owners or more (jobs in ONE batch)
+    "step": ("n", "interleaved"),
     # rounds of preparation, and the requests they carried (_prep_loop takes every
     # waiting text request it may a round; inline prep is a round of one)
     "prep": ("n", "requests"),
@@ -436,6 +437,7 @@ _PHASE_COUNTS = {
     # result had landed when the host came for it
     "decode_wait": ("fresh", "ready"),
     "decode_sample": ("n", "tokens"),
+    "vision_encode": ("n",),  # calls of the vision tower
 }
 # Phases that keep "<name>_exposed_s", their seconds with the device queue
 # provably empty (CaptionEngine._queue_moved): `step`, elapsed like its seconds,
@@ -460,6 +462,62 @@ _LIFE_INTERVALS = {
 # where they would have become ready) and the finished requests' tokens after
 # the first (what `request_decode_s` is divided by for the gap between tokens)
 _LIFE_COUNTS = ("request_dropped_n", "request_decode_gaps")
+# Every other sum the engine keeps, under the name stats() hands it out by
+# (stages/captioning.py picks the stage's from these by stage_timer.
+# _CAPTION_COUNT_KEYS). A (phase, key) quotes that count of _PHASE_COUNTS; None is
+# a sum of its own, made at its site under _stats_lock in CaptionEngine._counts.
+# _zero_phase_account zeroes all of it: a new counter is a line here and its site.
+_COUNTS = {
+    "decode_tokens": ("decode_sample", "tokens"),
+    # through prefill programs (bucket, chunk, a shared prefix's build): a prefix served from its blocks is none
+    "prefill_tokens": ("prefill_dispatch", "tokens"),
+    "decode_programs_ahead": ("decode_dispatch", "ahead"),
+    "admit_held": ("admit", "held"),
+    "admit_guests": ("admit", "guests"),
+    "prefix_tail_blocks_copied": ("prefix_tail_copy", "blocks"),
+    "interleaved_steps": ("step", "interleaved"),
+    "vision_encodes": ("vision_encode", "n"),
+    "vision_reuses": None,  # refinement passes over the SAME frames: the first pass's features, no call
+    # the shared-prefix cache (LRU over prefix token tuples): look-ups served,
+    # builds, entries dropped, and the prefill tokens the hits did not recompute
+    "prefix_cache_hits": None,
+    "prefix_cache_misses": None,
+    "prefix_cache_evictions": None,
+    "prefix_tokens_saved": None,
+    # whole blocks of a shared prefix referenced by admitted requests (ZERO device
+    # copies), and copy-on-write duplications of its partly filled tail block
+    "prefix_block_refs": None,
+    "kv_cow_copies": None,
+    # rows of programs dispatched ahead and thrown away: the row ended in the token before
+    "decode_rows_discarded": None,
+    # table entries the decode kernel's loop walks (a row's valid length in pages, every
+    # row of every program) over those the rows' tables span (rows x blocks a lane)
+    "paged_decode_pages_walked": None,
+    "paged_decode_pages_spanned": None,
+    # ...and the prefill kernel's: a block of queries walks from the page of its oldest
+    # visible key to that of its newest, over blocks of queries x entries a row
+    "paged_prefill_pages_walked": None,
+    "paged_prefill_pages_spanned": None,
+    # the recurrent store: admissions served from a prefix's state snapshot; calls of the
+    # decode recurrence (one a recurrent layer a program: Mamba-2's `ssm`, the gated delta
+    # rule's `delta`, a short convolution's none); the delta prefill scan's chunks that held a token
+    "prefix_state_snapshots": None,
+    "ssm_decode_calls": None,
+    "delta_decode_calls": None,
+    "delta_prefill_chunks": None,
+    "mla_decode_calls": None,  # the latent decode kernel's: one a layer a decode program
+    # an indexer's decode steps, by the host's arithmetic: calls of the chosen-set attention
+    # (one a layer a program), the positions its rows could see, those the softmax kept
+    # (`min(context, top_k)`), those whose K/V the step READ, and the rows by their path
+    # (ops/sparse_attention.py::decode_walks: the walk reads every live position, the gather the chosen)
+    "sparse_decode_calls": None,
+    "sparse_decode_positions_live": None,
+    "sparse_decode_positions_chosen": None,
+    "sparse_decode_positions_read": None,
+    "sparse_decode_rows_walked": None,
+    "sparse_decode_rows_gathered": None,
+}
+_MIN_PREFIX_LEN = 4  # a shared text prefix shorter than this is not worth a cache entry
 # The padded lengths of a prep round's one embedding call (_embed_bucket)
 _EMBED_BUCKET_MIN = 128
 _EMBED_BUCKET_STEP = 4096
@@ -675,11 +733,9 @@ class CaptionEngine:
         async_prep: bool = False,
         enable_prefix_cache: bool = True,
         prefix_cache_size: int = 8,
-        min_prefix_len: int = 4,
         admission_linger_s: float = 0.05,
         block_size: int | None = None,
         kv_pool_blocks: int | None = None,
-        owner_inflight_cap: int | None = None,
         paged_attention: str = "auto",
         mesh: Any = None,
         max_prefill_rows: int | None = None,
@@ -840,21 +896,21 @@ class CaptionEngine:
         # phase name, plus the elapsed time of the two roots (the stepping
         # thread's `step`, the prep thread's `prep`); the seconds the device
         # queue was provably empty (_PHASE_EXPOSED); entries and the sites'
-        # counts (_PHASE_COUNTS: decode_tokens, prefill_tokens,
-        # paged_kernel_steps, decode_programs_ahead and the dead-work measure
-        # decode_slot_utilization are read from these). Feeds phase_seconds
-        # (stage_timer caption phases, the benchmark's per-layer metrics).
-        # _stats_lock guards every counter '+=': the prep thread (prep /
-        # vision / prefix-build counters) and the step thread (prefill /
-        # decode counters) would otherwise lose updates racing on the same
-        # attributes — and prefill_tokens is the acceptance metric.
+        # counts (_PHASE_COUNTS); and beside them the sums no phase carries
+        # (_COUNTS) and the two high-water marks. Feeds phase_seconds and
+        # stats() (stage_timer caption phases, the benchmark's per-layer
+        # metrics). _stats_lock guards every counter '+=': the prep thread
+        # (prep / vision / prefix-build counters) and the step thread (prefill
+        # / decode counters) would otherwise lose updates racing on the same
+        # entries — and prefill_tokens is the acceptance metric.
         #
         # CANONICAL LOCK ORDER (checked by `lint --concurrency`):
         #   _lock (== _work_cv)  ->  _prefix_lock  ->  _stats_lock
         # _stats_lock is innermost and leaf-only: never acquire any other
         # engine lock while holding it.
         self._stats_lock = threading.Lock()
-        self._zero_phase_account()
+        with self._stats_lock:
+            self._zero_phase_account()
         self._phase_open = threading.local()  # one thread's open phases and programs: _phase_thread
         # the device-queue clock (under _stats_lock; state, not statistics:
         # reset_stats leaves it). The chip runs a thread's programs in the
@@ -871,89 +927,33 @@ class CaptionEngine:
         self._queue_busy = 0
         self._empty_since: float | None = time.monotonic()
         self._empty_total = 0.0
-        self._vision_encodes = 0
-        self._vision_reuses = 0
         # shared-prefix KV cache: LRU over prefix token tuples. Entries are
         # small ([L, Hkv, Tp, Dh] per prefix) next to the lane caches.
         self.enable_prefix_cache = enable_prefix_cache
         self.prefix_cache_size = prefix_cache_size
-        self.min_prefix_len = min_prefix_len
         self._prefix_cache: "OrderedDict[tuple, _PrefixEntry]" = OrderedDict()  # guarded-by: _prefix_lock
         # Middle of the canonical order: taken AFTER _lock (engine mutation)
         # and BEFORE _stats_lock, never the other way around — see the order
         # note at _stats_lock above.
         self._prefix_lock = threading.Lock()
-        self._prefix_hits = 0
-        self._prefix_misses = 0
-        self._prefix_evictions = 0
-        self._prefix_tokens_saved = 0
-        # paged-KV accounting (all under _stats_lock): shared-prefix block
-        # references handed out (the zero-copy successor of insert_prefix
-        # dispatches), copy-on-write tail duplications, the pool's high-water mark
-        self._prefix_block_refs = 0
-        self._kv_cow_copies = 0
-        self._kv_blocks_used_peak = 0
-        # paged-attention accounting (under _stats_lock): table entries
-        # the decode kernel's loop walks (a row's valid length in pages,
-        # summed over the rows of every decode program) over the entries
-        # the rows' tables span (rows x blocks a lane): the share of the
-        # table the kernel touches
-        self._paged_decode_pages_walked = 0
-        self._paged_decode_pages_spanned = 0
-        # ...and the prefill kernel's: the entries its loops walk (a block
-        # of queries from the page of its oldest visible key to the page of
-        # its newest, within the row's valid length) over blocks of queries
-        # x entries a row: what one page a grid step over the table stepped
-        self._paged_prefill_pages_walked = 0
-        self._paged_prefill_pages_spanned = 0
-        # look-ahead accounting (under _stats_lock): rows of programs
-        # dispatched ahead that were thrown away (the row ended in the token
-        # before); the programs themselves are decode_dispatch's `ahead`
-        self._decode_rows_discarded = 0
-        # recurrent-store accounting (under _stats_lock): rows held at once,
-        # admissions served from a prefix's state snapshot, calls of the
-        # decode recurrence (one a recurrent layer a decode program, booked by
-        # the mixer's kind: Mamba-2's as ``ssm``, the gated delta rule's as
-        # ``delta``), and the delta rule's prefill scan's chunks that held a token
-        self._recurrent_rows_used_peak = 0
-        self._prefix_state_snapshots = 0
-        self._ssm_decode_calls = 0
-        self._delta_decode_calls = 0
-        self._delta_prefill_chunks = 0
         self._delta = cfg.recurrent_kind == "linear_attention"
         self._conv_tail_bytes_per_chip = 0  # the store's tails (all of a short-convolution store)
         # sparse experts with a sorted dispatch (DeepSeek-V2): the assignments
         # that landed on the experts held here, summed over the layers of every
         # decode program ON THE DEVICE (``_build_counted_decode``) and read when
-        # ``stats()`` is; calls of the latent decode kernel (one a layer a
-        # decode program; under _stats_lock)
+        # ``stats()`` is
         self._counts_experts = cfg.moe is not None and cfg.moe.dispatch == "sorted"
         # ...and hand out, LAST, what every token's router chose
         # (``MoEConfig.hand_out_choice``); a hybrid's programs do by themselves
         self._hands_choice = cfg.moe is not None and cfg.moe.hand_out_choice and not self._recurrent
         self._expert_held = None
-        self._mla_decode_calls = 0
-        # an indexer's decode steps (under _stats_lock), by the host's
-        # arithmetic: calls of the chosen-set attention (one a layer a decode
-        # program), the positions its rows could see, those the softmax kept
-        # (``min(context, top_k)``) and those whose K/V the step READ, and the
-        # rows by the path they took (ops/sparse_attention.py::decode_walks:
-        # the walk reads a row's every live position, the gather the chosen)
-        self._sparse_decode_calls = 0
-        self._sparse_decode_positions_live = 0
-        self._sparse_decode_positions_chosen = 0
-        self._sparse_decode_positions_read = 0
-        self._sparse_decode_rows_walked = 0
-        self._sparse_decode_rows_gathered = 0
         # cross-job fairness: least-recently-admitted owner goes first, and
         # no owner may hold more than its in-flight share of the slots
-        # (owner_inflight_cap; None = ceil(total slots / active owners))
-        self.owner_inflight_cap = owner_inflight_cap
+        # (_owner_cap: ceil(total slots / active owners))
         self._owner_last_admit: dict[Any, int] = {}
         self._owner_last_prep: dict[Any, int] = {}
         self._admit_seq = 0
         self._prep_seq = 0
-        self._interleaved_steps = 0
         self._owner_decode_tokens: dict[Any, int] = {}
         self._owner_requests: dict[Any, int] = {}
         # async prep: a background thread runs vision encode + embedding for
@@ -1671,8 +1671,11 @@ class CaptionEngine:
                     continue
                 self.step()
 
+    # holds-lock: _stats_lock
     def _zero_phase_account(self) -> None:
-        """Every number _phase() keeps, at zero (construction, reset_stats)."""
+        """Every sum the engine keeps, at zero (construction, reset_stats): what
+        _phase() keeps and ``_COUNTS``' own. The two high-water marks (the pool's
+        blocks, a hybrid's rows held at once) restart from what is held now."""
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._phase_elapsed_s = dict.fromkeys(_PHASE_ROOTS, 0.0)
         self._phase_exposed_s = dict.fromkeys(_PHASE_EXPOSED, 0.0)
@@ -1680,6 +1683,9 @@ class CaptionEngine:
         self._life_account = dict.fromkeys(_LIFE_COUNTS, 0)
         for _opens, seconds, n in _LIFE_INTERVALS.values():
             self._life_account.update({seconds: 0.0, n: 0})
+        self._counts = {name: 0 for name, quoted in _COUNTS.items() if quoted is None}
+        self._kv_blocks_used_peak = self._allocator.used_blocks
+        self._recurrent_rows_used_peak = sum(len(l.claims) for l in self.lanes) if self._recurrent else 0
 
     def _phase_thread(self) -> threading.local:
         """The calling thread's side of the account: ``stack``, the seconds
@@ -1811,48 +1817,36 @@ class CaptionEngine:
 
     @property
     def tokens_per_second(self) -> float:
-        return self.decode_tokens / self._decode_time if self._decode_time > 0 else 0.0
+        tokens = self._phase_n["decode_sample"]["tokens"]
+        return tokens / self._decode_time if self._decode_time > 0 else 0.0
 
-    @property
-    def decode_tokens(self) -> int:
-        return self._phase_n["decode_sample"]["tokens"]
-
-    @property
-    def decode_time_s(self) -> float:
-        return self._decode_time
-
+    # single counters of ``_COUNTS`` by name, for the callers that want one
+    # and not the whole of ``stats()``
     @property
     def prefill_tokens(self) -> int:
-        """Prompt tokens pushed through prefill programs (bucket, chunk,
-        and shared-prefix builds; cache-inserted prefix copies are NOT
-        prefill). With the shared-prefix cache, n requests sharing a
-        Tp-token prefix prefill Tp fewer tokens each after the first."""
+        """With the shared-prefix cache, n requests sharing a Tp-token prefix
+        prefill Tp fewer tokens each after the first."""
         return self._phase_n["prefill_dispatch"]["tokens"]
 
     @property
     def prefix_cache_hits(self) -> int:
-        return self._prefix_hits
+        return self._counts["prefix_cache_hits"]
 
     @property
     def prefix_cache_misses(self) -> int:
-        return self._prefix_misses
-
-    @property
-    def prefix_cache_evictions(self) -> int:
-        return self._prefix_evictions
+        return self._counts["prefix_cache_misses"]
 
     @property
     def prefix_tokens_saved(self) -> int:
-        """Prefill tokens NOT recomputed thanks to shared-prefix hits."""
-        return self._prefix_tokens_saved
+        return self._counts["prefix_tokens_saved"]
 
     @property
     def vision_encodes(self) -> int:
-        return self._vision_encodes
+        return self._phase_n["vision_encode"]["n"]
 
     @property
     def vision_reuses(self) -> int:
-        return self._vision_reuses
+        return self._counts["vision_reuses"]
 
     # -- paged-KV occupancy and cross-job accounting --------------------
     @property
@@ -1870,25 +1864,12 @@ class CaptionEngine:
         return self._kv_blocks_used_peak
 
     @property
-    def kv_block_bytes(self) -> int:
-        """Device bytes one block pins (K + V across all layers)."""
-        cfg = self.cfg
-        # bf16 pool: 2 bytes/element; a row holds K and V, or one latent row
-        # (of the pool every flavor has: the full layers')
-        return 2 * len(cfg.full_layers) * self.block_size * cfg.cache_row_elems
-
-    @property
     def prefix_block_refs(self) -> int:
-        """Cumulative shared-prefix block references handed to admitted
-        requests — each one is a whole block of prefix K/V served with ZERO
-        device copies (the metric that replaced insert_prefix dispatches)."""
-        return self._prefix_block_refs
+        return self._counts["prefix_block_refs"]
 
     @property
     def kv_cow_copies(self) -> int:
-        """Copy-on-write duplications of a partially-filled shared prefix
-        tail block (ONE block each — not a prefix copy)."""
-        return self._kv_cow_copies
+        return self._counts["kv_cow_copies"]
 
     # -- paged-attention accounting --------------------------------------
     @property
@@ -1938,60 +1919,39 @@ class CaptionEngine:
                 "kv_heads_per_pool_row": self._kv_heads_per_pool_row,
                 "kv_block_size": self.block_size,
                 "kv_block_size_requested": self.block_size_requested,
+                # every sum of _COUNTS: its own store, or the phase's it quotes
+                **{
+                    name: self._counts[name] if quoted is None else self._phase_n[quoted[0]][quoted[1]]
+                    for name, quoted in _COUNTS.items()
+                },
                 "paged_kernel_steps": self.paged_kernel_steps,
-                "decode_programs_ahead": self._phase_n["decode_dispatch"]["ahead"],
-                "decode_rows_discarded": self._decode_rows_discarded,
-                "admit_held": self._phase_n["admit"]["held"],
-                "admit_guests": self._phase_n["admit"]["guests"],
-                "paged_decode_pages_walked": self._paged_decode_pages_walked,
-                "paged_decode_pages_spanned": self._paged_decode_pages_spanned,
-                "paged_prefill_pages_walked": self._paged_prefill_pages_walked,
-                "paged_prefill_pages_spanned": self._paged_prefill_pages_spanned,
-                "decode_tokens": self.decode_tokens,
                 "decode_s": self._decode_time,
-                "prefill_tokens": self.prefill_tokens,
                 "prefill_s": self._prefill_time,
                 "kv_blocks_total": self._allocator.capacity,
                 "kv_blocks_used": self._allocator.used_blocks,
                 "kv_blocks_used_peak": self._kv_blocks_used_peak,
-                # the shared-prefix cache, and of two pools the window blocks its
-                # entries hold now and those that admissions copied into rows' rings
-                "prefix_cache_hits": self._prefix_hits,
-                "prefix_tokens_saved": self._prefix_tokens_saved,
+                # of two pools, the window blocks the shared-prefix cache's entries hold now
                 "prefix_window_blocks_held": window_blocks_held,
-                "prefix_tail_blocks_copied": self._phase_n["prefix_tail_copy"]["blocks"],
                 # the second kind of state (all zero without state-space layers)
                 "recurrent_state_bytes_per_chip": self._recurrent_bytes_per_chip,
                 "conv_tail_bytes_per_chip": self._conv_tail_bytes_per_chip,
                 "recurrent_rows_total": sum(l.n_slots for l in self.lanes) if self._recurrent else 0,
                 "recurrent_rows_used_peak": self._recurrent_rows_used_peak,
-                "prefix_state_snapshots": self._prefix_state_snapshots,
-                "ssm_decode_calls": self._ssm_decode_calls,
-                "delta_decode_calls": self._delta_decode_calls,
-                "delta_prefill_chunks": self._delta_prefill_chunks,
                 # latent attention and sorted experts (zero elsewhere)
                 "latent_pool_bytes_per_chip": (
                     self._kv_pool_bytes_per_chip if self.cfg.mla is not None else 0
                 ),
-                "mla_decode_calls": self._mla_decode_calls,
                 "expert_assignments_held": held,
                 "expert_assignments_held_live": held_live,
-                # a learned indexer (all zero without one): its keys' array as
-                # stored, and its decode steps' positions seen and read
+                # a learned indexer's keys' array as stored (zero without one)
                 "index_pool_bytes_per_chip": self._index_pool_bytes_per_chip,
-                "sparse_decode_calls": self._sparse_decode_calls,
-                "sparse_decode_positions_live": self._sparse_decode_positions_live,
-                "sparse_decode_positions_chosen": self._sparse_decode_positions_chosen,
-                "sparse_decode_positions_read": self._sparse_decode_positions_read,
-                "sparse_decode_rows_walked": self._sparse_decode_rows_walked,
-                "sparse_decode_rows_gathered": self._sparse_decode_rows_gathered,
             }
 
     @property
     def interleaved_decode_steps(self) -> int:
         """Steps whose active slots spanned 2+ owners — the cross-job
         continuous-batching signal (two pipelines decoding in ONE batch)."""
-        return self._interleaved_steps
+        return self._phase_n["step"]["interleaved"]
 
     @property
     def owner_decode_tokens(self) -> dict:
@@ -2076,37 +2036,8 @@ class CaptionEngine:
         cache CONTENTS survive (only the hit/miss counters reset)."""
         with self._stats_lock:
             self._zero_phase_account()
-            self._vision_encodes = 0
-            self._vision_reuses = 0
-            self._prefix_hits = 0
-            self._prefix_misses = 0
-            self._prefix_evictions = 0
-            self._prefix_tokens_saved = 0
-            self._prefix_block_refs = 0
-            self._kv_cow_copies = 0
-            self._decode_rows_discarded = 0
-            self._paged_decode_pages_walked = 0
-            self._paged_decode_pages_spanned = 0
-            self._paged_prefill_pages_walked = 0
-            self._paged_prefill_pages_spanned = 0
-            self._kv_blocks_used_peak = self._allocator.used_blocks
-            self._recurrent_rows_used_peak = (
-                sum(len(l.claims) for l in self.lanes) if self._recurrent else 0
-            )
-            self._prefix_state_snapshots = 0
-            self._ssm_decode_calls = 0
-            self._delta_decode_calls = 0
-            self._delta_prefill_chunks = 0
-            self._mla_decode_calls = 0
-            self._sparse_decode_calls = 0
-            self._sparse_decode_positions_live = 0
-            self._sparse_decode_positions_chosen = 0
-            self._sparse_decode_positions_read = 0
-            self._sparse_decode_rows_walked = 0
-            self._sparse_decode_rows_gathered = 0
             if self._expert_held is not None:
                 self._expert_held = jnp.zeros_like(self._expert_held)
-            self._interleaved_steps = 0
             self._owner_decode_tokens.clear()
             self._owner_requests.clear()
 
@@ -2153,7 +2084,7 @@ class CaptionEngine:
         slot batch, rows without an active slot are wasted; lanes raise it by
         keeping batches near their occupancy)."""
         rows = self._phase_n["decode_dispatch"]["rows"]
-        return self.decode_tokens / rows if rows else 0.0
+        return self._phase_n["decode_sample"]["tokens"] / rows if rows else 0.0
 
     # -- engine internals ----------------------------------------------
     def step(self) -> None:
@@ -2180,7 +2111,7 @@ class CaptionEngine:
         # the device trace under `engine.step` names a step, and the `*_step`
         # entries of CaptionResult.timing the requests that step moved
         ordinal = next(self._steps)
-        with self._phase("step", ordinal=ordinal), contextlib.ExitStack() as waiting:
+        with self._phase("step", ordinal=ordinal) as stepping, contextlib.ExitStack() as waiting:
             waiting.enter_context(self._phase("lock_wait"))
             with self._work_cv:
                 waiting.close()  # the lock is ours: lock_wait ends here
@@ -2193,8 +2124,7 @@ class CaptionEngine:
                     s.request.owner for l in self.lanes for s in l.slots.values()
                 }
                 if len(step_owners) > 1:
-                    with self._stats_lock:
-                        self._interleaved_steps += 1
+                    stepping.counts["interleaved"] = 1
                 for lane in self.lanes:
                     if lane.pending:
                         self._prefill_chunk_step(lane)
@@ -2437,12 +2367,9 @@ class CaptionEngine:
         return now < self._linger_until
 
     def _owner_cap(self, inflight: dict) -> int:
-        """Per-owner in-flight slot cap: an explicit ``owner_inflight_cap``,
-        or the fair share of the slot budget across owners that currently
-        have work. A single owner gets the whole engine (admission-order
-        parity with the single-job engine)."""
-        if self.owner_inflight_cap is not None:
-            return max(1, self.owner_inflight_cap)
+        """Per-owner in-flight slot cap: the fair share of the slot budget
+        across owners that currently have work. A single owner gets the whole
+        engine (admission-order parity with the single-job engine)."""
         owners = set(inflight)
         owners.update(r.owner for r in self.waiting)
         owners.update(p.request.owner for p in self._ready)
@@ -2764,7 +2691,7 @@ class CaptionEngine:
             allow_prefix
             and self.enable_prefix_cache
             and req.share_prefix
-            and n_pre >= self.min_prefix_len
+            and n_pre >= _MIN_PREFIX_LEN
             and n_vis + len(req.prompt_ids) > 0  # suffix must be non-empty
             # tail-keep truncation cuts into the prefix
             and n_pre + n_vis + len(req.prompt_ids) <= self._max_len - req.sampling.max_new_tokens - 1
@@ -2802,7 +2729,7 @@ class CaptionEngine:
                 vis_embeds, ds_vis = vf.embeds, vf.ds
                 grid_merged, eff_fps = vf.grid, vf.eff_fps
                 with self._stats_lock:
-                    self._vision_reuses += 1
+                    self._counts["vision_reuses"] += 1
             else:
                 frames, eff_fps = self._fit_frames_to_budget(req)
                 program = next(self._programs)
@@ -2815,8 +2742,6 @@ class CaptionEngine:
                     vis_embeds = vis[0]
                     jax.block_until_ready(vis_embeds)
                     phase.moved(proven=program)
-                with self._stats_lock:
-                    self._vision_encodes += 1
                 if self.cfg.vision_variant in ("qwen2", "qwen3"):
                     grid_merged = self.cfg.qwen_vision.merged_grid(frames.shape[0])
                 req.vision_features = _VisionFeatures(
@@ -2880,7 +2805,7 @@ class CaptionEngine:
             _entry, hit = self._ensure_prefix(key)
             if hit:
                 with self._stats_lock:
-                    self._prefix_tokens_saved += n_pre
+                    self._counts["prefix_tokens_saved"] += n_pre
             return _Prepared(
                 request=req,
                 embeds=np.asarray(embeds, np.float32),
@@ -2969,12 +2894,12 @@ class CaptionEngine:
                 self._prefix_cache.move_to_end(key)
                 if count:
                     with self._stats_lock:
-                        self._prefix_hits += 1
+                        self._counts["prefix_cache_hits"] += 1
                 return entry, True
         if not self.enable_prefix_cache:
             return None, False
         with self._stats_lock:
-            self._prefix_misses += 1
+            self._counts["prefix_cache_misses"] += 1
         tp = len(key)
         sp = next_pow2(tp)
         with self._phase("prefill_build"):
@@ -3018,8 +2943,8 @@ class CaptionEngine:
                         # the outcome is a HIT (the winner's build is
                         # served); reclassify the miss counted up front so
                         # hit-rate stats stay exact under concurrency
-                        self._prefix_misses -= 1
-                        self._prefix_hits += 1
+                        self._counts["prefix_cache_misses"] -= 1
+                        self._counts["prefix_cache_hits"] += 1
                     return raced, True
                 nw = nb - wfirst if self._windowed else 0
                 if not self._can_alloc(nb, nw):
@@ -3069,7 +2994,7 @@ class CaptionEngine:
                     # referenced blocks defer their free to the last slot
                     self._drop_prefix(evicted)
                     with self._stats_lock:
-                        self._prefix_evictions += 1
+                        self._counts["prefix_cache_evictions"] += 1
                 return entry, False
 
     def _can_alloc(self, n_blocks: int, n_window: int = 0) -> bool:
@@ -3101,7 +3026,7 @@ class CaptionEngine:
                 continue
             self._drop_prefix(self._prefix_cache.pop(key))
             with self._stats_lock:
-                self._prefix_evictions += 1
+                self._counts["prefix_cache_evictions"] += 1
 
     # holds-lock: _lock
     def _claim_kv(
@@ -3219,9 +3144,8 @@ class CaptionEngine:
                 self._release_claim(lane, slot_idx)
                 raise
         with self._stats_lock:
-            self._prefix_block_refs += len(shared)
-            if cow_src is not None:
-                self._kv_cow_copies += 1
+            self._counts["prefix_block_refs"] += len(shared)
+            self._counts["kv_cow_copies"] += cow_src is not None
             self._kv_blocks_used_peak = max(
                 self._kv_blocks_used_peak, self._allocator.used_blocks
             )
@@ -3229,7 +3153,7 @@ class CaptionEngine:
                 self._recurrent_rows_used_peak = max(
                     self._recurrent_rows_used_peak, sum(len(l.claims) for l in self.lanes)
                 )
-                self._prefix_state_snapshots += entry is not None
+                self._counts["prefix_state_snapshots"] += entry is not None
             self._owner_requests[req.owner] = (
                 self._owner_requests.get(req.owner, 0) + 1
             )
@@ -3272,7 +3196,7 @@ class CaptionEngine:
             if self._delta:  # the scan's chunks that hold a token, every row's, a layer each
                 chunk = self.cfg.gated_delta.chunk
                 with self._stats_lock:
-                    self._delta_prefill_chunks += len(self.cfg.ssm_layers) * int(
+                    self._counts["delta_prefill_chunks"] += len(self.cfg.ssm_layers) * int(
                         np.sum(-(-np.asarray(t_valid) // chunk))
                     )
             # (*_: the experts' choice where the flavor hands it out, nobody's here)
@@ -3298,8 +3222,8 @@ class CaptionEngine:
             walked = n_full * walked + n_win * walk(window=self.cfg.sliding_window)[0]
             layers = n_full + n_win
         with self._stats_lock:
-            self._paged_prefill_pages_walked += walked
-            self._paged_prefill_pages_spanned += layers * blocks * nbl
+            self._counts["paged_prefill_pages_walked"] += walked
+            self._counts["paged_prefill_pages_spanned"] += layers * blocks * nbl
 
     def _pools(self) -> tuple:
         """(K, V) as the programs take them: the pool's two arrays, or where
@@ -3744,13 +3668,14 @@ class CaptionEngine:
             emitted = flight.emitted(lane)
             phase.counts["tokens"] = len(emitted)
             with self._stats_lock:
-                self._decode_rows_discarded += len(flight.rows) - len(emitted)
+                counts = self._counts
+                counts["decode_rows_discarded"] += len(flight.rows) - len(emitted)
                 if self._delta:
-                    self._delta_decode_calls += len(self.cfg.ssm_layers)
+                    counts["delta_decode_calls"] += len(self.cfg.ssm_layers)
                 elif self.cfg.recurrent_kind == "mamba":  # (a short convolution calls no recurrence)
-                    self._ssm_decode_calls += len(self.cfg.ssm_layers)
+                    counts["ssm_decode_calls"] += len(self.cfg.ssm_layers)
                 if self.cfg.mla is not None:
-                    self._mla_decode_calls += len(self.cfg.kv_layers)
+                    counts["mla_decode_calls"] += len(self.cfg.kv_layers)
                 if self._use_paged:
                     # a row's kv_len is positions + 1 (decode_step_paged); a
                     # discarded row counts as the idle row it should have been
@@ -3768,26 +3693,23 @@ class CaptionEngine:
                         layers, seen = len(self.cfg.kv_layers), positions[list(emitted)] + 1
                         live, chosen = int(seen.sum()), int(np.minimum(seen, self.cfg.indexer.top_k).sum())
                         walks = decode_walks(lane.length, self.block_size, self.cfg.head_dim)
-                        self._sparse_decode_calls += layers
-                        self._sparse_decode_positions_live += layers * live
-                        self._sparse_decode_positions_chosen += layers * chosen
-                        self._sparse_decode_positions_read += layers * (live if walks else chosen)
-                        if walks:
-                            self._sparse_decode_rows_walked += layers * len(seen)
-                        else:
-                            self._sparse_decode_rows_gathered += layers * len(seen)
+                        counts["sparse_decode_calls"] += layers
+                        counts["sparse_decode_positions_live"] += layers * live
+                        counts["sparse_decode_positions_chosen"] += layers * chosen
+                        counts["sparse_decode_positions_read"] += layers * (live if walks else chosen)
+                        counts["sparse_decode_rows_walked" if walks else "sparse_decode_rows_gathered"] += layers * len(seen)
                     if not self._windowed:
-                        self._paged_decode_pages_walked += int((last + 1).sum())
-                        self._paged_decode_pages_spanned += lane.table.size
+                        counts["paged_decode_pages_walked"] += int((last + 1).sum())
+                        counts["paged_decode_pages_spanned"] += lane.table.size
                     else:
                         # two pools: layer by layer. A window layer's walk starts
                         # at the page of its oldest visible key (kv_len - window)
                         first = np.maximum(positions + 1 - self.cfg.sliding_window, 0) // self.block_size
                         n_full, n_win = len(self.cfg.full_layers), len(self.cfg.window_layers)
-                        self._paged_decode_pages_walked += int(
+                        counts["paged_decode_pages_walked"] += int(
                             n_full * (last + 1).sum() + n_win * (last - first + 1).sum()
                         )
-                        self._paged_decode_pages_spanned += (n_full + n_win) * lane.table.size
+                        counts["paged_decode_pages_spanned"] += (n_full + n_win) * lane.table.size
                 for slot in emitted.values():
                     owner = slot.request.owner
                     self._owner_decode_tokens[owner] = (

@@ -455,27 +455,18 @@ class CaptionStage(Stage[SplitPipeTask, SplitPipeTask]):
         return tasks
 
     def _engine_counts(self, engine: CaptionEngine) -> dict:
-        return {
-            "requests": 0,
-            "prefill_tokens": engine.prefill_tokens,
-            "prefix_cache_hits": engine.prefix_cache_hits,
-            "prefix_cache_misses": engine.prefix_cache_misses,
-            "prefix_tokens_saved": engine.prefix_tokens_saved,
-            "vision_encodes": engine.vision_encodes,
-            "vision_reuses": engine.vision_reuses,
-            # paged-KV + cross-job signals (engine-wide counters; per-drive
-            # deltas like the rest)
-            "prefix_block_refs": engine.prefix_block_refs,
-            "kv_cow_copies": engine.kv_cow_copies,
-            "interleaved_steps": engine.interleaved_decode_steps,
-            # paged-attention path: decode steps served without a gathered
-            # KV working set
-            "paged_kernel_steps": engine.paged_kernel_steps,
-            # per-OWNER, not engine-wide: under a shared engine another
-            # job's tokens decode inside this drive's window, and the run
-            # report's owner table must not claim them for this stage
-            "decode_tokens": engine.owner_decode_tokens.get(self.owner, 0),
-        }
+        """The stage's counters (``stage_timer._CAPTION_COUNT_KEYS``) of those
+        ``engine.stats()`` hands out: engine-wide, a drive's deltas like the
+        phases (which carry the rest of those keys themselves)."""
+        from cosmos_curate_tpu.observability import stage_timer
+
+        stats = engine.stats()
+        counts = {k: stats[k] for k in stage_timer._CAPTION_COUNT_KEYS if k in stats}
+        # per-OWNER, not engine-wide: under a shared engine another job's
+        # tokens decode inside this drive's window, and the run report's
+        # owner table must not claim them for this stage
+        counts["decode_tokens"] = engine.owner_decode_tokens.get(self.owner, 0)
+        return counts
 
     def _phase_delta(
         self, engine: CaptionEngine, phases0: dict, stats0: dict, wall: float

@@ -64,6 +64,10 @@ INTERVALS = {
 }
 SECONDS |= {s for s, _ in INTERVALS.values()}
 COUNTS |= {n for _, n in INTERVALS.values()} | {"request_dropped_n", "request_decode_gaps"}
+# ...and beyond what the account held at PR 59: two loose counters that became their
+# phase's (`<phase>_<key>`; `engine._COUNTS` quotes them for `stats()`)
+PHASES_SINCE_PR59 = {"vision_encode_n", "step_interleaved"}
+COUNTS |= PHASES_SINCE_PR59
 ALL_KEYS = SECONDS | EXPOSED | COUNTS
 KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]
 
@@ -155,7 +159,7 @@ class TestCounters:
         # prep stays inclusive of the vision encode nested in it
         assert ph["prep_s"] >= ph["vision_encode_s"] + ph["prep_other_s"] > 0
         stats = engine.stats()
-        assert stats["decode_s"] == ph["decode_s"] == engine.decode_time_s
+        assert stats["decode_s"] == ph["decode_s"] == engine._decode_time
         assert stats["prefill_s"] == ph["prefill_s"]
 
     def test_chunk_step_with_no_finished_row_does_not_wait(self, engine):
@@ -368,7 +372,7 @@ class TestCounts:
         old = _OldLines(engine, monkeypatch)
         _drive(engine, kind)
         stats = engine.stats()
-        assert stats["decode_tokens"] == old.decode_tokens == engine.decode_tokens
+        assert stats["decode_tokens"] == old.decode_tokens == engine.phase_seconds["decode_sample_tokens"]
         assert stats["prefill_tokens"] == old.prefill_tokens == engine.prefill_tokens
         assert stats["paged_kernel_steps"] == old.paged_kernel_steps == engine.paged_kernel_steps
         assert stats["decode_programs_ahead"] == old.decode_programs_ahead
@@ -1285,3 +1289,91 @@ class TestRequestLife:
         assert pickle.loads(pickle.dumps(result)).timing == result.timing
         assert pickle.loads(pickle.dumps(req)) == req  # the record is no part of what a request is
         assert "_life" not in repr(req) and dataclasses.replace(req, request_id="p1")._life is None
+
+
+# -- one account: every counter is a name of one table ---------------------------
+# What `stats()` handed out at PR 59 (the parent of the PR that made the loose counters
+# a table), written out, as `ALL_KEYS` less `PHASES_SINCE_PR59` is what `phase_seconds`
+# did: a key that leaves either fails here.
+STATS_AT_PR59 = {
+    "paged_attention", "mesh_geometry", "param_bytes_per_chip", "kv_pool_bytes_per_chip",
+    "full_pool_bytes_per_chip", "window_pool_bytes_per_chip", "kv_heads_per_pool_row", "kv_block_size",
+    "kv_block_size_requested", "paged_kernel_steps", "decode_programs_ahead", "decode_rows_discarded",
+    "admit_held", "admit_guests", "paged_decode_pages_walked", "paged_decode_pages_spanned",
+    "paged_prefill_pages_walked", "paged_prefill_pages_spanned", "decode_tokens", "decode_s",
+    "prefill_tokens", "prefill_s", "kv_blocks_total", "kv_blocks_used", "kv_blocks_used_peak",
+    "prefix_cache_hits", "prefix_tokens_saved", "prefix_window_blocks_held", "prefix_tail_blocks_copied",
+    "recurrent_state_bytes_per_chip", "conv_tail_bytes_per_chip", "recurrent_rows_total",
+    "recurrent_rows_used_peak", "prefix_state_snapshots", "ssm_decode_calls", "delta_decode_calls",
+    "delta_prefill_chunks", "latent_pool_bytes_per_chip", "mla_decode_calls", "expert_assignments_held",
+    "expert_assignments_held_live", "index_pool_bytes_per_chip", "sparse_decode_calls",
+    "sparse_decode_positions_live", "sparse_decode_positions_chosen", "sparse_decode_positions_read",
+    "sparse_decode_rows_walked", "sparse_decode_rows_gathered",
+}
+# ...and what they hold beyond it: the counters that had a property and no key
+STATS_SINCE = {
+    "prefix_cache_misses", "prefix_cache_evictions", "vision_encodes", "vision_reuses",
+    "prefix_block_refs", "kv_cow_copies", "interleaved_steps",
+}
+# what `stats()` sums beside the table's names (the rest is state and sizes, which
+# `reset_stats()` leaves; the two peaks restart from what is held)
+OTHER_SUMS = {"paged_kernel_steps", "decode_s", "prefill_s", "expert_assignments_held", "expert_assignments_held_live"}
+
+
+def _flavor_kinds() -> dict:
+    from cosmos_curate_tpu.models.vlm import model
+
+    return {
+        "dense": model.VLM_TINY_TEST,
+        "hybrid": model.VLM_GRANITE_HYBRID_TINY_TEST,
+        "latent": model.VLM_DEEPSEEK_V2_TINY_TEST,
+        "indexed": model.VLM_KEYE_TINY_TEST,
+        "windowed": model.VLM_TRINITY_TINY_TEST,
+    }
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid", "latent", "indexed", "windowed"])
+def test_every_counter_is_a_name_of_one_table_and_a_key_handed_out(kind):
+    from cosmos_curate_tpu.models.vlm import engine as engine_module
+
+    eng = CaptionEngine(_flavor_kinds()[kind], kv_lanes=((64, 2), (128, 2)), block_size=8, prefill_chunk=16)
+    eng.setup()
+    rng = np.random.default_rng(0)
+    prefix = [int(t) for t in rng.integers(3, 200, 12)]
+    for i, (n, new) in enumerate([(5, 6), (9, 4), (40, 5), (70, 3), (7, 8)]):
+        eng.add_request(
+            CaptionRequest(
+                request_id=f"r{i}", prefix_ids=list(prefix), prompt_ids=[int(t) for t in rng.integers(3, 200, n)],
+                sampling=SamplingConfig(max_new_tokens=new),
+            ),
+            owner=f"o{i % 2}",
+        )
+    results = eng.run_until_complete(owner="o0") + eng.run_until_complete(owner="o1")
+    stats, phases = eng.stats(), eng.phase_seconds
+    assert set(stats) == STATS_AT_PR59 | STATS_SINCE
+    assert set(phases) == ALL_KEYS  # (PR 59's and PHASES_SINCE_PR59)
+    # the table: every name is handed out; a quoted count IS its phase's; the store holds the rest
+    table = engine_module._COUNTS
+    assert set(table) <= set(stats) and set(eng._counts) == {k for k, quoted in table.items() if quoted is None}
+    for name, quoted in table.items():
+        if quoted is not None:
+            assert quoted[1] in engine_module._PHASE_COUNTS[quoted[0]]
+            assert stats[name] == phases["_".join(quoted)]
+    # the seeded drive moved them: five requests behind one prefix, two owners in one batch
+    generated = sum(r.num_output_tokens for r in results)
+    assert stats["decode_tokens"] == generated - len(results) == sum(eng.owner_decode_tokens.values())
+    assert (stats["prefix_cache_misses"], stats["prefix_cache_hits"]) == (1, 4)
+    assert stats["prefix_block_refs"] == 5 == stats["kv_cow_copies"]  # a 12-token prefix in blocks of 8
+    assert stats["interleaved_steps"] == eng.interleaved_decode_steps > 0
+    assert stats["paged_decode_pages_walked"] > 0 and stats["kv_blocks_used_peak"] > stats["kv_blocks_used"]
+    by_kind = {
+        "dense": (), "hybrid": ("ssm_decode_calls", "prefix_state_snapshots"), "latent": ("mla_decode_calls",),
+        "indexed": ("sparse_decode_calls", "sparse_decode_positions_read"), "windowed": (),
+    }[kind]
+    assert all(stats[k] > 0 for k in by_kind)
+    eng.reset_stats()
+    stats, phases = eng.stats(), eng.phase_seconds
+    assert {k: stats[k] for k in set(table) | OTHER_SUMS if stats[k]} == {}
+    assert {k: v for k, v in phases.items() if v} == {} and eng.owner_decode_tokens == {}
+    assert stats["kv_blocks_used_peak"] == stats["kv_blocks_used"] == eng.kv_blocks_used  # what the prefix holds
+    eng.shutdown()

@@ -362,9 +362,10 @@ class TestRefcountedPrefixBlocks:
         paged.reset_stats()  # the peak restarts at what the prefix cache holds
         held = paged.kv_blocks_used
         _drain(paged, [_req(f"k{i}", text="w " * 15, max_new=4) for i in range(2)])
-        claimed_bytes = (paged.kv_blocks_used_peak - held) * paged.kv_block_bytes
+        block_bytes = paged.kv_bytes() // paged.kv_pool_blocks  # K + V of one block, every layer
+        claimed_bytes = (paged.kv_blocks_used_peak - held) * block_bytes
         # what two whole rows of the 128 lane hold: a slot-row engine's reservation
-        lane_rows_bytes = 2 * (128 // paged.block_size) * paged.kv_block_bytes
+        lane_rows_bytes = 2 * (128 // paged.block_size) * block_bytes
         assert 0 < claimed_bytes < lane_rows_bytes
 
 

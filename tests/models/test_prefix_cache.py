@@ -201,7 +201,7 @@ class TestEvictionAndVariants:
         for r in seq():
             want.update(_drain(full, [r]))
         assert got == want
-        assert cached.prefix_cache_evictions >= 2
+        assert cached.stats()["prefix_cache_evictions"] >= 2
         assert cached.prefix_cache_misses >= 3  # rebuilds after eviction
 
 
@@ -237,7 +237,7 @@ class TestPrepDecodeOverlap:
                 # B's encode: sleep past the linger window, then snapshot
                 # how far decode got while we were "encoding"
                 time.sleep(0.5)
-                seen_during_slow_prep.append(eng.decode_tokens)
+                seen_during_slow_prep.append(eng.stats()["decode_tokens"])
             return inner(params, frames_u8)
 
         eng._encode_images = instrumented
