@@ -416,10 +416,10 @@ _PHASES = _PHASE_ROOTS + (
 # over the entries that ran to their end. `n` is the entries themselves, every
 # other key an integer the site hands _phase(). Only what something reads
 # (PERF.md section 3) is kept: any other keyword of _phase() (`lane`, `program`,
-# `frames`, a prefill program's `rows` and `live`) is span metadata only.
+# `frames`, a prefill program's `rows`) is span metadata only.
 _PHASE_COUNTS = {
     # steps, and those whose active slots spanned two owners or more (jobs in ONE batch)
-    "step": ("n", "interleaved"),
+    "step": ("n", "interleaved", "held"),  # ...and those that held a lane's prefill program back (_prefill_due)
     # rounds of preparation, and the requests they carried (_prep_loop takes every
     # waiting text request it may a round; inline prep is a round of one)
     "prep": ("n", "requests"),
@@ -430,7 +430,7 @@ _PHASE_COUNTS = {
     # row's own ring (a row that wraps shares none), and the blocks they copied
     "prefix_tail_copy": ("n", "blocks"),
     # positions advanced, of those the program's padded rows x T had room for
-    "prefill_dispatch": ("n", "tokens", "room"),
+    "prefill_dispatch": ("n", "tokens", "room", "live"),  # (`live`: the prompts a program carried)
     "prefill_sample": ("first",),  # prompts finished: a first output token each
     "decode_dispatch": ("n", "rows", "live", "ahead"),
     # reads no earlier sync of the thread had passed, and those of them whose
@@ -2104,7 +2104,10 @@ class CaptionEngine:
         behind ``decode_slot_utilization``): chunking exists to protect
         in-flight decode from a long prefill stall, so while NO lane is
         decoding, pending chunks run back to back instead of one per step —
-        an idle engine prefills at full speed."""
+        an idle engine prefills at full speed. While a lane decodes, a flavor
+        that caps a prefill program's rows holds a lane's pending chunks back
+        until the program is worth its read of the parameters
+        (``_prefill_due``; ``step_held`` counts the steps that held one)."""
         if not self._built:
             raise RuntimeError("call setup() first")
         # the ordinal is span metadata (no key of _PHASE_COUNTS): with it a gap of
@@ -2126,13 +2129,52 @@ class CaptionEngine:
                 if len(step_owners) > 1:
                     stepping.counts["interleaved"] = 1
                 for lane in self.lanes:
-                    if lane.pending:
+                    if lane.pending and self._prefill_due(lane):
                         self._prefill_chunk_step(lane)
                         while lane.pending and not any(l.slots for l in self.lanes):
                             self._prefill_chunk_step(lane)
+                    elif lane.pending:
+                        stepping.counts["held"] = 1
                     if lane.slots or lane.inflight is not None:
                         self._decode_once(lane)
                 self._work_cv.notify_all()  # ready-queue space may have freed
+
+    # holds-lock: _lock
+    def _prefill_due(self, lane: _Lane) -> bool:
+        """Whether the lane's pending chunks are dispatched in this step, or
+        held for rows that will join them. A prefill program reads every
+        parameter and every expert table whatever it carries, so where the
+        flavor caps its rows (``max_prefill_rows``; none stated, nothing held)
+        it is dispatched only when (1) it is full, (2) no lane decodes (there is
+        nothing to protect: an idle engine prefills at full speed), or (3)
+        holding does not pay. It pays only an engine that is saturated: more
+        prompts stand in line (prepared, in preparation or waiting) than a
+        program takes, so the queue outlasts this program and throughput is
+        what its callers wait for; a shorter line is a burst's tail or a
+        caller that fills the rows one by one, where a held row only waits (and
+        a caller that waits for a step with NO row pending, as the benchmark's
+        ramp does, would wait for long). Rows can still join through the
+        prompts next in line, one behind each row whose end the host knows
+        ahead (``max_new_tokens``, the lane's length: a row with ``left``
+        tokens to go is its successor's pending row ``left`` steps from now; a
+        row free now was ``_admit``'s to give this step).
+        Holding ``n`` rows a step costs the ``n`` tokens they would have
+        emitted; a program avoided saves about a decode step, the lane's live
+        rows in tokens. So with ``h`` the fewest steps after which the program
+        is full, hold while ``n * h`` is no more than the live rows. Read off
+        the state as it stands each step: a joiner that does not come (another
+        lane's, no blocks) ends the hold when its row's end passes."""
+        cap, n = self.max_prefill_rows, len(lane.pending)
+        if cap is None or n >= cap or not any(l.slots for l in self.lanes):
+            return True
+        need = cap - n
+        if len(self._ready) + len(self._prep_inflight) + len(self.waiting) <= cap or len(lane.slots) < need:
+            return True  # not saturated, or fewer rows decode than the program lacks
+        ends = sorted(
+            min(s.request.sampling.max_new_tokens - len(s.generated), lane.length - 1 - s.position)
+            for s in lane.slots.values()
+        )
+        return n * ends[need - 1] > len(lane.slots)
 
     # -- request prep (sync inline, or the background overlap thread) ---
     def _start_prep_thread(self) -> None:
@@ -2627,7 +2669,14 @@ class CaptionEngine:
             inflight[req.owner] = inflight.get(req.owner, 0) + 1
             self._owner_last_admit[req.owner] = self._admit_seq
             self._admit_seq += 1
-            if chunked:
+            # (a capped flavor, while a lane decodes: a prompt whose bucket IS the
+            # chunk is the chunk program's row too, one chunk padded at its end, so
+            # that what _prefill_due holds and what it fills are one program)
+            bucket = min(next_pow2(prep.t_suffix), lane.length)
+            rides = (
+                decode_active and group_ok and self.max_prefill_rows is not None and bucket == self.prefill_chunk
+            )
+            if chunked or rides:
                 # long prompt: prefill in chunks interleaved with decode
                 lane.pending[slot_idx] = _PendingPrefill(
                     request=req,
@@ -2639,7 +2688,6 @@ class CaptionEngine:
                     base=prep.base,
                 )
                 continue
-            bucket = min(next_pow2(prep.t_suffix), lane.length)
             groups.setdefault((self.lanes.index(lane), bucket), []).append(
                 (
                     slot_idx,
@@ -3472,8 +3520,9 @@ class CaptionEngine:
                     # not a multiple of the chunk size. A recurrence cannot
                     # take a token twice: a hybrid's last chunk starts where
                     # the one before ended and is padded at its end instead
-                    # (_admit made sure that it fits the lane).
-                    start = p.t_valid - C
+                    # (_admit made sure that it fits the lane), as is a FIRST
+                    # chunk shorter than C (a short prompt riding this program).
+                    start = max(p.t_valid - C, 0)
                 filled = min(C, p.t_valid - start)  # C, but for a hybrid's last chunk
                 new_tokens += take
                 embeds[j, :filled] = p.embeds[start : start + filled]

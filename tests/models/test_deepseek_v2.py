@@ -329,7 +329,12 @@ def test_idle_and_stale_rows_do_not_move_a_live_row_to_the_bit(params, monkeypat
 def test_a_prefill_program_takes_at_most_max_prefill_rows_prompts(params):
     """A lane's waiting prompts beyond ``max_prefill_rows`` take the next
     program: what keeps a program's scratch bounded where a lane has hundreds
-    of slots. Same outputs as the engine that takes them all at once."""
+    of slots. Same outputs as the engine that takes them all at once. (Since
+    PR 61 the cap is also what a lane's pending chunks are held FOR while a
+    lane decodes, ``_prefill_due``: here seven prompts meet an idle engine of
+    eight rows and are prefilled whole, two a program, so nothing is ever
+    pending beside a decoding row and nothing is held:
+    ``tests/models/test_engine_prefill_hold.py`` has the cases that are.)"""
     def serve(cap):
         engine = CaptionEngine(
             CFG, kv_lanes=((64, 8),), prefill_chunk=16, block_size=8, max_prefill_rows=cap,

@@ -68,6 +68,9 @@ COUNTS |= {n for _, n in INTERVALS.values()} | {"request_dropped_n", "request_de
 # phase's (`<phase>_<key>`; `engine._COUNTS` quotes them for `stats()`)
 PHASES_SINCE_PR59 = {"vision_encode_n", "step_interleaved"}
 COUNTS |= PHASES_SINCE_PR59
+# ...and PR 61's two: the prompts a prefill program carried (rows a program = `_live` / `_n`),
+# and the steps that held a lane's pending chunks back (`CaptionEngine._prefill_due`)
+COUNTS |= {"prefill_dispatch_live", "step_held"}
 ALL_KEYS = SECONDS | EXPOSED | COUNTS
 KINDS = ["whole_prompt", "chunked", "vision", "shared_prefix"]
 
@@ -285,7 +288,10 @@ class TestProgramInFlight:
 class _OldLines:
     """The counters as the engine kept them before `_phase` took counts: one
     hand-placed `+=` each, at the site it had, replayed by spies around the
-    same calls."""
+    same calls. (`spy_chunk` counts what a chunk program is ABOUT to take: the
+    engine states no cap, so `_prefill_due` holds nothing and a lane with rows
+    pending runs one chunk program every step, as before PR 61; with a cap a
+    step may run none, and `_prefill_chunk_step` is not called in it.)"""
 
     def __init__(self, eng, monkeypatch):
         self.eng = eng
@@ -400,7 +406,8 @@ class TestCounts:
         ph = engine.phase_seconds
         assert (ph["decode_sample_n"], ph["decode_sample_tokens"]) == (1, 3)
         assert (ph["prefill_dispatch_n"], ph["prefill_dispatch_tokens"], ph["prefill_dispatch_room"]) == (1, 5, 8)
-        assert set(ph) == ALL_KEYS  # no `lane`, `frames`, `rows` or `live` of a prefill: a reader each, or none kept
+        assert ph["prefill_dispatch_live"] == 1  # since PR 61 a count: the rows a prefill program carried
+        assert set(ph) == ALL_KEYS  # no `lane`, `frames` or `rows` of a prefill: a reader each, or none kept
         assert _Recorder.meta[:2] == [
             ("engine.decode_sample", {"tokens": 3, "lane": 64}),
             ("engine.vision_encode", {"frames": 2, "program": 7}),

@@ -133,7 +133,9 @@ def test_prompt_over_three_chunks_while_another_row_decodes(kind, params, engine
     """37 tokens in chunks of 16, 16 and 5 (the last padded at its end), the
     chunks interleaved with the decode steps of a request that is already
     running: the pending row is an idle row of those steps, and its tails pass
-    from chunk to chunk through the store."""
+    from chunk to chunk through the store. (The engine states a cap of 2 rows a
+    prefill program; ONE prompt is outstanding and nothing can join it, so
+    ``_prefill_due`` dispatches its chunk in every step, as before PR 61.)"""
     engine, spy = engines[kind]
     before = engine.stats()["prefill_tokens"]
     first, long = _ids(1, 12), _ids(2, 37)
@@ -149,7 +151,8 @@ def test_prompt_over_three_chunks_while_another_row_decodes(kind, params, engine
 
 def test_a_prompt_in_three_chunks_equals_the_same_prompt_in_one(params, engines):
     """The same 37 tokens prefilled whole in one bucket of 64 (an idle engine
-    whose chunk holds them) and in chunks of 16 beside a decoding row."""
+    whose chunk holds them) and in chunks of 16 beside a decoding row: three
+    programs, one a step, none held back (no prompt waits that could join)."""
     prompt = _ids(2, 37)
     whole, spy_whole = _build("paged", params, chunk=64)
     whole.add_request(_request("w", prompt, max_new=2))
@@ -162,6 +165,7 @@ def test_a_prompt_in_three_chunks_equals_the_same_prompt_in_one(params, engines)
     chunked.add_request(_request("c", prompt, max_new=2))
     _run(chunked)
     assert chunked.phase_seconds["prefill_dispatch_n"] - programs == 3
+    assert chunked.phase_seconds["step_held"] == 0
     assert _rel(spy.first["c"], spy_whole.first["w"]) < 0.02  # bfloat16 sums in another order
     tails = [np.asarray(s.engine._conv[:, s.row[n]], np.float32) for s, n in ((spy, "c"), (spy_whole, "w"))]
     assert _rms(*tails) < 0.01

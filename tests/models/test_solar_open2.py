@@ -104,7 +104,9 @@ def test_prompt_over_prefill_chunks_while_another_row_decodes(kind, params, engi
     """100 tokens in chunks of 40, 40 and 20 (the last padded at its end; a scan
     chunk is 32, a sub-block 16), the chunks interleaved with the decode steps
     of a request that is already running: the pending row is an idle row of
-    those steps, and the experts' count rides in them."""
+    those steps, and the experts' count rides in them. (A cap of 2 rows a
+    prefill program is stated; with ONE prompt outstanding and none in line
+    ``_prefill_due`` holds nothing: a chunk program every step, as before PR 61.)"""
     engine, spy = engines[kind]
     before = engine.stats()
     first, long = _ids(1, 12), _ids(2, 100)
@@ -115,6 +117,7 @@ def test_prompt_over_prefill_chunks_while_another_row_decodes(kind, params, engi
     tokens = _run(engine)
     # scan chunks of 32 that held a token: ceil(12 / 32), then 2 + 2 + 1, a linear layer each
     assert _since(engine, before, "prefill_tokens", "delta_prefill_chunks") == [12 + 100, (1 + 5) * len(CFG.ssm_layers)]
+    assert engine.phase_seconds["step_held"] == 0
     _assert_decode_matches(params, spy, tokens, "b", long)
     _assert_decode_matches(params, spy, tokens, "a", first, least=6)
 
